@@ -4,7 +4,7 @@ dense F=4096), same d/L/heads/vocab/seq, benched with the same recipe.
 
 The gap between this number and the moe preset's active-param MFU is the
 structural cost of MoE on this chip (dispatch movements + grouped-GEMM
-rate); BASELINE.md tracks its decomposition round over round.
+rate).
 
 Run: python examples/mixtral/dense_equiv.py [--batch 44]
 """

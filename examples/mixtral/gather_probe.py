@@ -3,8 +3,8 @@
 Decides whether the fused grouped-GEMM kernel (ops/moe_gemm.py) can gather
 token rows in-kernel via scalar-prefetched indices + per-row async DMA —
 killing the materialized [PN, D] dispatch gather and its remat replay —
-without the per-descriptor DMA issue cost eating the win (BASELINE.md r3:
-the queued "in-kernel gather/combine" lever).
+without the per-descriptor DMA issue cost eating the win (the builders' r3
+notes, older than this code: the queued "in-kernel gather/combine" lever).
 
 Arms (loop-in-jit, ITERS serialized iterations per jit call, input scaled
 by (1+1e-9) each iteration to defeat CSE; whole output reduced so nothing

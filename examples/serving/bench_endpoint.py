@@ -21,8 +21,7 @@ endpoint / mean(engine, engine2): at equal occupancy this ratio IS the
 HTTP layer's overhead (queues + handler threads + JSON + socket writes).
 
 The round-4 session could not produce this number (drifting ambient +
-open-loop clients conflated occupancy with overhead; BASELINE.md r4 serve
-table) — this driver is the fixed-occupancy design the verdict asked for.
+open-loop clients conflated occupancy with overhead) — this driver is the fixed-occupancy design the verdict asked for.
 """
 
 from __future__ import annotations

@@ -10,7 +10,7 @@ the per-layer error bounds what each of the 32 layers contributes).
     python examples/serving/quality_int8.py --preset llama-1b --batch 4 --seq 512
     python examples/serving/quality_int8.py --geometry 8b --batch 2 --seq 256
 
-Prints one JSON line per config; BASELINE.md records the table.
+Prints one JSON line per config.
 """
 
 import argparse

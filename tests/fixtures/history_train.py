@@ -29,4 +29,21 @@ for s in range(1, steps + 1):
     os.replace(tmp, metrics_path)
     time.sleep(0.12)
 
+# the executor pushes the step report on its own clock and makes no last
+# push when the child exits: wait until the AM holds the final step (a loaded
+# box can starve the push thread past any fixed sleep), then finish
+from tony_tpu.cluster.rpc import RpcClient, RpcError  # noqa: E402
+
+rpc = RpcClient(os.environ["TONY_AM_HOST"], int(os.environ["TONY_AM_PORT"]),
+                secret=os.environ.get("TONY_AM_SECRET", ""), timeout_s=5.0)
+deadline = time.time() + 60
+while time.time() < deadline:
+    try:
+        seen = [(t.get("metrics") or {}).get("train") or {} for t in rpc.call("get_task_infos")]
+    except (RpcError, OSError):
+        seen = []
+    if any(m.get("step") == steps for m in seen):
+        break
+    time.sleep(0.1)
+
 print(f"fixture: history worker finished {steps} steps")

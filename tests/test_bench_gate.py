@@ -1,9 +1,12 @@
 """``tony bench --gate`` as a repo check (tier-1, docs/history.md).
 
-Every checked-in ``BENCH_*.json`` must satisfy the gate schema, and the
-current trajectory must pass its own gate — a PR that lands a regressed
-bench record (or a malformed one) fails here, which is the whole point of
-turning the perf history into an enforced contract (ROADMAP item 5).
+Every checked-in ``*BENCH_*.json`` must satisfy the gate schema, and each
+trajectory must pass its own gate — a PR that lands a regressed bench record
+(or a malformed one) fails here, which is the whole point of turning the perf
+history into an enforced contract (ROADMAP item 5). The train family
+(``BENCH_*.json``) has no checked-in round — the chip's numbers live in
+``PERF_LEDGER.jsonl`` — so its gate runs over a trajectory built in a
+temporary directory, in the shape ``python bench.py`` prints.
 """
 
 import json
@@ -18,71 +21,93 @@ pytestmark = [pytest.mark.history]
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def _trajectory():
-    traj = gate.load_trajectory(REPO_ROOT)
-    assert traj, "no checked-in BENCH_*.json trajectory"
+def write_train_trajectory(directory) -> None:
+    """Three ``BENCH_r0N.json`` rounds, each wrapping one bench.py line."""
+    for n, (mfu, step_ms) in enumerate(((0.46, 1516.5), (0.48, 1460.0), (0.49, 1435.2)), 1):
+        parsed = {
+            "metric": "llama_train_mfu_1chip_1chip", "value": mfu, "unit": "mfu",
+            "vs_baseline": round(mfu / 0.45, 4), "runs_mfu": [mfu] * 3,
+            "preset": "1chip", "model": "llama", "batch": 12, "seq": 2048,
+            "n_chips": 1, "device_kind": "TPU v5 lite", "warmup_s": 10.0 + n,
+            "tokens_per_sec": round(12 * 2048 / (step_ms / 1000), 1),
+            "step_time_ms": step_ms, "kernel_smoke": "8/8",
+        }
+        with open(os.path.join(str(directory), f"BENCH_r{n:02d}.json"), "w") as f:
+            json.dump({"n": n, "cmd": "python bench.py", "rc": 0, "parsed": parsed}, f)
+
+
+@pytest.fixture(scope="module")
+def train_dir(tmp_path_factory):
+    d = tmp_path_factory.mktemp("train_trajectory")
+    write_train_trajectory(d)
+    return str(d)
+
+
+def _trajectory(train_dir):
+    traj = gate.load_trajectory(train_dir)
+    assert traj, "no BENCH_*.json trajectory"
     return traj
 
 
 class TestCheckedInTrajectory:
-    def test_every_record_satisfies_the_gate_schema(self):
-        for fname, rec in _trajectory():
+    def test_every_record_satisfies_the_gate_schema(self, train_dir):
+        for fname, rec in _trajectory(train_dir):
             errors = gate.validate_record(rec, wrapper=True)
             assert not errors, f"{fname}: {errors}"
 
-    def test_rounds_are_ordered_and_unique(self):
-        rounds = [rec["n"] for _, rec in _trajectory()]
+    def test_rounds_are_ordered_and_unique(self, train_dir):
+        rounds = [rec["n"] for _, rec in _trajectory(train_dir)]
         assert rounds == sorted(rounds)
         assert len(set(rounds)) == len(rounds)
 
-    def test_gate_passes_on_current_trajectory(self):
-        """The newest checked-in record vs the rest of the trajectory: the
-        repo's own perf history must satisfy its own contract."""
-        traj = _trajectory()
+    def test_gate_passes_on_current_trajectory(self, train_dir):
+        """The newest record vs the rest of the trajectory: a perf history
+        must satisfy its own contract."""
+        traj = _trajectory(train_dir)
         result = gate.evaluate(traj[-1][1], traj)
         assert result.passed, "\n" + result.render()
 
-    def test_gate_cli_passes_on_current_trajectory(self, capsys):
+    def test_gate_cli_passes_on_current_trajectory(self, train_dir, capsys):
         from tony_tpu.cli.history import main_bench
 
-        assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT]) == 0
+        assert main_bench(["--gate", "--trajectory-dir", train_dir]) == 0
         assert "PASS" in capsys.readouterr().out
 
-    def test_gate_cli_fails_on_synthetic_regression(self, tmp_path, capsys):
+    def test_gate_cli_fails_on_synthetic_regression(self, train_dir, tmp_path, capsys):
         from tony_tpu.cli.history import main_bench
 
-        traj = _trajectory()
+        traj = _trajectory(train_dir)
         regressed = json.loads(json.dumps(traj[-1][1]))  # deep copy
         regressed["parsed"]["value"] *= 0.8
         regressed["parsed"]["vs_baseline"] *= 0.8
         path = tmp_path / "regressed.json"
         path.write_text(json.dumps(regressed))
-        assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT,
+        assert main_bench(["--gate", "--trajectory-dir", train_dir,
                            "--record", str(path)]) == 1
         assert "REGRESSION" in capsys.readouterr().out
 
-    def test_gate_cli_rejects_malformed_record(self, tmp_path, capsys):
+    def test_gate_cli_rejects_malformed_record(self, train_dir, tmp_path, capsys):
         from tony_tpu.cli.history import main_bench
 
         path = tmp_path / "bad.json"
         path.write_text(json.dumps({"parsed": {"metric": "m"}}))
-        assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT,
+        assert main_bench(["--gate", "--trajectory-dir", train_dir,
                            "--record", str(path)]) == 2
         assert "gate schema" in capsys.readouterr().err
 
-    def test_raw_bench_line_is_gateable(self, capsys):
+    def test_raw_bench_line_is_gateable(self, train_dir, capsys):
         """`python bench.py | tony bench --gate --record -`: a raw bench
         output line (no wrapper) gates directly."""
         from tony_tpu.cli.history import main_bench
 
-        traj = _trajectory()
+        traj = _trajectory(train_dir)
         raw = dict(gate.parsed_of(traj[-1][1]))
         import io
         import sys as _sys
 
         stdin, _sys.stdin = _sys.stdin, io.StringIO(json.dumps(raw))
         try:
-            assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT,
+            assert main_bench(["--gate", "--trajectory-dir", train_dir,
                                "--record", "-"]) == 0
         finally:
             _sys.stdin = stdin
@@ -101,6 +126,7 @@ class TestServeBenchFamily:
 
     def test_family_patterns_do_not_collide(self):
         train = {name for name, _ in gate.load_trajectory(REPO_ROOT)}
+        assert not train  # the train family's rounds live in PERF_LEDGER.jsonl
         serve = {name for name, _ in _serve_trajectory()}
         assert not train & serve
         assert all(n.startswith("SERVE_BENCH_") for n in serve)
@@ -178,11 +204,11 @@ class TestServeBenchFamily:
                                "--record", str(path)]) == 1
             assert "REGRESSION" in capsys.readouterr().out
 
-    def test_serve_records_do_not_gate_against_the_train_family(self):
+    def test_serve_records_do_not_gate_against_the_train_family(self, train_dir):
         """Trajectories compare within one `metric` name only: the serve
         record diffs against nothing in the BENCH_* family."""
         serve_rec = _serve_trajectory()[-1][1]
-        result = gate.evaluate(serve_rec, gate.load_trajectory(REPO_ROOT))
+        result = gate.evaluate(serve_rec, _trajectory(train_dir))
         assert result.passed
         assert any("fresh trajectory" in c.note for c in result.checks)
 
@@ -338,8 +364,8 @@ class TestCbenchFamily:
         bare2["parsed"].pop("machine")
         assert not gate.evaluate(bare2, [("CBENCH_r92.json", bare)]).passed
 
-    def test_cbench_records_do_not_gate_against_other_families(self):
+    def test_cbench_records_do_not_gate_against_other_families(self, train_dir):
         cb_rec = _cbench_trajectory()[-1][1]
-        result = gate.evaluate(cb_rec, gate.load_trajectory(REPO_ROOT))
+        result = gate.evaluate(cb_rec, _trajectory(train_dir))
         assert result.passed
         assert any("fresh trajectory" in c.note for c in result.checks)

@@ -1,0 +1,189 @@
+"""The kernels of the chip's main path, compiled for the chip without the chip.
+
+The TPU's compiler is installed here and compiles for a v5e that is described,
+not attached (on-chip-measurement guide, section 2). Interpret mode cannot see
+what it refuses — a slice not aligned to the tiling, too much fast memory, a
+kernel GSPMD cannot partition — so the kernels `chip_smoke.py` runs are
+compiled here at llama-1b shapes, about two seconds each. A compile that
+passes is not a chip run.
+
+All of it lives in this one file and inside fixtures: only one process may
+load the TPU's library, the driver runs the suite under several xdist workers,
+and every worker imports every test file. Nothing here touches the topology
+at import; the worker that is handed this file describes it in `topo`, every
+test skips from there where it cannot be described, and the compiles run in
+the test's own process.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
+
+from tony_tpu.models import llama
+from tony_tpu.ops import attention as A
+from tony_tpu.ops import decode_attention as DA
+from tony_tpu.ops import moe_gemm as MG
+from tony_tpu.ops import quant as Q
+
+# llama-1b attention geometry (llama.LLAMA_1B) and chip_smoke's sizes
+B, H, HKV, T, DH = 8, 16, 8, 2048, 128
+SLOTS, MAX_LEN = 64, 2048
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever stops the description is the reason
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture()
+def chip(topo, monkeypatch):
+    """One described chip to compile for; the kernels compile for real (the
+    CPU suite's interpret switch is read when a kernel is traced) and nothing
+    is written to a persistent compile cache that could not be read back."""
+    monkeypatch.delenv("TONY_PALLAS_INTERPRET", raising=False)
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+def _s(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_calls(fn, *args) -> int:
+    """Compile ``fn`` for the described chip; how many Mosaic kernels it holds."""
+    return jax.jit(fn).lower(*args).compile().as_text().count("tpu_custom_call")
+
+
+def _flash_grad(**kw):
+    def loss(q, k, v, *seg):
+        extra = {"segment_ids": seg[0]} if seg else {}
+        return A.mha(q, k, v, causal=True, impl="flash", **kw, **extra).astype(jnp.float32).sum()
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
+class TestFlashAttentionTPU:
+    """Was a numerics test that only a TPU backend ran (and the CPU suite
+    skipped forever); the numerics are `bench.py --smoke`'s on the chip, what
+    can be held here is that the public kernel compiles for it."""
+
+    def test_flash_attention_compiles(self, chip):
+        q = _s((3, 4, 512, 64), jnp.bfloat16, chip)
+        assert _kernel_calls(functools.partial(A.flash_attention, causal=True), q, q, q) == 1
+
+
+class TestFlashAtLlama1bShapes:
+    def test_fwd_bwd_resident_dkv(self, chip):
+        q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
+        assert _kernel_calls(_flash_grad(), q, kv, kv) == 3  # fwd, dq, dkv
+
+    def test_fwd_bwd_streaming_dkv(self, chip):
+        # n_rep * Tq past _DKV_RESIDENT_MAX_QROWS takes the streaming dkv grid
+        assert 2 * 4096 > A._DKV_RESIDENT_MAX_QROWS
+        q, kv = _s((2, H, 4096, DH), jnp.bfloat16, chip), _s((2, HKV, 4096, DH), jnp.bfloat16, chip)
+        assert _kernel_calls(_flash_grad(), q, kv, kv) == 3
+
+    def test_packed_segments(self, chip):
+        q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
+        assert _kernel_calls(_flash_grad(), q, kv, kv, _s((B, T), jnp.int32, chip)) == 3
+
+    def test_sliding_window(self, chip):
+        q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
+        assert _kernel_calls(_flash_grad(window=512), q, kv, kv) == 3
+
+
+class TestDecodeAttentionAtServeShapes:
+    def _common(self, chip):
+        return (_s((SLOTS, H, DH), jnp.bfloat16, chip), _s((SLOTS,), jnp.int32, chip),
+                _s((SLOTS, HKV, DH), jnp.bfloat16, chip))
+
+    def test_ragged_decode(self, chip):
+        q, lengths, cur = self._common(chip)
+        cache = _s((SLOTS, HKV, MAX_LEN, DH), jnp.bfloat16, chip)
+
+        def fn(q, ck, cv, lengths, cur_k, cur_v):
+            return DA.ragged_decode_attention(q, ck, cv, lengths, cur_k=cur_k, cur_v=cur_v)
+
+        assert _kernel_calls(fn, q, cache, cache, lengths, cur, cur) == 1
+
+    @pytest.mark.parametrize("page_len", [256, 128, 32])
+    def test_paged_decode_with_chunk_staging(self, chip, page_len):
+        """The engine's decode step: paged pool + the chunk's staged columns."""
+        q, lengths, cur = self._common(chip)
+        max_pages = MAX_LEN // page_len
+        pool = _s((SLOTS * max_pages + 1, HKV, page_len, DH), jnp.bfloat16, chip)
+        table = _s((SLOTS, max_pages), jnp.int32, chip)
+        staged = _s((SLOTS, 8, HKV, DH), jnp.bfloat16, chip)
+
+        def fn(q, kp, vp, lengths, table, cur_k, cur_v, sk, sv, count):
+            return DA.paged_decode_attention(
+                q, kp, vp, lengths, table, cur_k=cur_k, cur_v=cur_v,
+                staged_k=sk, staged_v=sv, staged_count=count)
+
+        assert _kernel_calls(fn, q, pool, pool, lengths, table, cur, cur,
+                             staged, staged, lengths) == 1
+
+
+class TestOtherKernels:
+    def test_int8_matmul_d_model_by_d_ff(self, chip):
+        """d_ff 5504 = 43 x 128: the default 256-wide block does not tile it
+        (int8_matmul then takes the XLA reference, by design), so the kernel
+        is asked for with a block that does."""
+        cfg = llama.LLAMA_1B
+        x = _s((512, cfg.d_model), jnp.bfloat16, chip)
+        qt = Q.QTensor(_s((cfg.d_model, cfg.d_ff), jnp.int8, chip), _s((cfg.d_ff,), jnp.float32, chip))
+        fn = functools.partial(Q.int8_matmul, block_m=256, block_n=128, block_k=512)
+        assert _kernel_calls(fn, x, qt) == 1
+
+    def test_moe_grouped_gemm_fwd_and_bwd(self, chip):
+        """Both kernels ask the compiler for 100 MB of fast memory."""
+        E, D, F, rows, tile = 8, 1024, 2048, 8192, 128
+        xs = _s((rows, D), jnp.bfloat16, chip)
+        wg, wd = _s((E, D, F), jnp.bfloat16, chip), _s((E, F, D), jnp.bfloat16, chip)
+        tg = _s((rows // tile,), jnp.int32, chip)
+
+        def fwd(xs, wg, wu, wd, tg):
+            return MG.moe_swiglu_grouped(xs, wg, wu, wd, tg, tile)
+
+        def loss(xs, wg, wu, wd, tg):
+            return fwd(xs, wg, wu, wd, tg).astype(jnp.float32).sum()
+
+        assert _kernel_calls(fwd, xs, wg, wg, wd, tg) == 1
+        assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2, 3)), xs, wg, wg, wd, tg) >= 1
+
+
+class TestFlashOnAFourChipMesh:
+    def test_flash_under_shard_map_lowers_where_the_bare_call_cannot(self, topo, chip):
+        """GSPMD cannot partition a Mosaic kernel: under any data/fsdp/model
+        mesh of real devices the bare call is refused, which no CPU run sees
+        (there the kernel is plain XLA). `mha_on_mesh` — what the models call
+        — runs it per shard under shard_map, batch over fsdp."""
+        mesh = Mesh(list(topo.devices), ("fsdp",))
+        sharded = NamedSharding(mesh, P("fsdp", None, None, None))
+        q = _s((B, H, T, DH), jnp.bfloat16, sharded)
+        kv = _s((B, HKV, T, DH), jnp.bfloat16, sharded)
+
+        def bare(q, k, v):
+            return A.mha(q, k, v, causal=True, impl="flash")
+
+        def on_mesh(q, k, v):
+            return A.mha_on_mesh(q, k, v, mesh=mesh, causal=True, impl="flash")
+
+        def loss(q, k, v):
+            return on_mesh(q, k, v).astype(jnp.float32).sum()
+
+        with pytest.raises(NotImplementedError, match="shard_map"):
+            jax.jit(bare).lower(q, kv, kv)
+        assert _kernel_calls(on_mesh, q, kv, kv) == 1
+        assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3

@@ -12,7 +12,7 @@ import time
 
 import pytest
 
-from tony_tpu import compat, constants
+from tony_tpu import constants
 from tony_tpu.config import TonyConfig, keys
 from tony_tpu.cluster.client import Client
 from tony_tpu.cluster.session import JobStatus
@@ -110,19 +110,7 @@ class TestLifecycle:
         assert "fixture: ok" in open(log).read()
 
 
-#: the two multi-process gangs below run REAL cross-process collectives on
-#: the CPU backend — a jax without gloo CPU collectives aborts them with
-#: "Multiprocess computations aren't implemented on the CPU backend", which
-#: is an environment capability gap, not a tony regression (the
-#: single-process SPMD and lifecycle e2es still cover the contract)
-_needs_mp_cpu = pytest.mark.skipif(
-    not compat.multiprocess_cpu_supported(),
-    reason="this jax lacks cross-process CPU collectives "
-           "(compat.multiprocess_cpu_supported)")
-
-
 @pytest.mark.e2e
-@_needs_mp_cpu
 class TestDistributedDataPlane:
     def test_gang_forms_jax_process_group_and_reduces(self, tmp_tony_root):
         """The distributed-backend proof: a tony-launched 2-worker gang joins
@@ -143,7 +131,6 @@ class TestDistributedDataPlane:
 
 
 @pytest.mark.e2e
-@_needs_mp_cpu
 class TestMultiProcessSpmdTraining:
     def test_gang_trains_one_model_over_global_mesh(self, tmp_tony_root):
         """Full multi-host training proof: each of 2 workers owns 4 virtual

@@ -671,17 +671,24 @@ class TestHistoryE2E:
         out = capsys.readouterr().out
         assert all(a in out for a in app_ids) and "mfu_p50" in out
 
-        # tony bench --gate: PASS on the real checked-in trajectory...
-        assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT]) == 0
+        # tony bench --gate: PASS on a trajectory of bench.py rounds...
+        traj_dir = tmp_path / "trajectory"
+        traj_dir.mkdir()
+        for n, mfu in enumerate((0.46, 0.48, 0.49), 1):
+            (traj_dir / f"BENCH_r{n:02d}.json").write_text(json.dumps({
+                "n": n, "cmd": "python bench.py", "rc": 0,
+                "parsed": {"metric": "llama_train_mfu_1chip_1chip", "value": mfu,
+                           "unit": "mfu", "vs_baseline": round(mfu / 0.45, 4),
+                           "warmup_s": 10.0 + n, "device_kind": "TPU v5 lite"}}))
+        assert main_bench(["--gate", "--trajectory-dir", str(traj_dir)]) == 0
         # ...and nonzero on a synthetically regressed record
-        regressed = json.load(
-            open(os.path.join(REPO_ROOT, "BENCH_r05.json")))
+        regressed = json.loads((traj_dir / "BENCH_r03.json").read_text())
         regressed["parsed"]["value"] *= 0.5
         regressed["parsed"]["vs_baseline"] *= 0.5
         reg_path = tmp_path / "regressed.json"
         reg_path.write_text(json.dumps(regressed))
         capsys.readouterr()
-        assert main_bench(["--gate", "--trajectory-dir", REPO_ROOT,
+        assert main_bench(["--gate", "--trajectory-dir", str(traj_dir),
                            "--record", str(reg_path)]) == 1
         assert "REGRESSION" in capsys.readouterr().out
 

@@ -491,7 +491,7 @@ class TestStopTransferIfSingle:
     def _shardmapped(self, n):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from tony_tpu.compat import shard_map
+        from jax import shard_map
         from tony_tpu.parallel import collectives
 
         mesh = Mesh(np.array(jax.devices()[:n]).reshape(n), ("ring",))
@@ -514,7 +514,7 @@ class TestStopTransferIfSingle:
     def test_multi_shard_axis_still_transfers(self):
         from jax.sharding import Mesh, PartitionSpec as P
 
-        from tony_tpu.compat import shard_map
+        from jax import shard_map
         from tony_tpu.parallel import collectives
 
         f = self._shardmapped(4)
@@ -535,7 +535,7 @@ class TestStopTransferIfSingle:
         collective launches."""
         from jax.sharding import PartitionSpec as P
 
-        from tony_tpu.compat import shard_map
+        from jax import shard_map
         from tony_tpu.parallel import MeshSpec
         from tony_tpu.parallel.context import ring_attention
 

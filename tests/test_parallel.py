@@ -9,8 +9,10 @@ import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.compat import shard_map, tree_leaves_with_path
+from jax import shard_map
+from jax.tree import leaves_with_path as tree_leaves_with_path
 from tony_tpu.ops.attention import attention_reference
+from tony_tpu.ops.interpret import interpret
 from tony_tpu.parallel import MeshSpec, ShardingRules, fsdp_spec_tree
 from tony_tpu.parallel.context import ring_attention, ulysses_attention
 from tony_tpu.parallel.expert import MoEConfig, capacity, moe_ffn, route
@@ -345,9 +347,7 @@ class TestMoE:
         path — fwd and grads."""
         import dataclasses
 
-        from tony_tpu.ops import moe_gemm
-
-        assert moe_gemm._INTERPRET
+        assert interpret()
         E, D, F = 4, 128, 256
         ks = jax.random.split(jax.random.PRNGKey(47), 5)
         x = (jax.random.normal(ks[0], (2, 16, D)) * 0.5).astype(jnp.bfloat16)
@@ -401,9 +401,7 @@ class TestMoE:
         that actually triggers the kernel (D,F % 128 == 0, bf16)."""
         import dataclasses
 
-        from tony_tpu.ops import moe_gemm
-
-        assert moe_gemm._INTERPRET, "conftest must set TONY_PALLAS_INTERPRET"
+        assert interpret(), "conftest must set TONY_PALLAS_INTERPRET"
         E, D, F = 4, 128, 256
         ks = jax.random.split(jax.random.PRNGKey(21), 5)
         x = (jax.random.normal(ks[0], (2, 16, D)) * 0.5).astype(jnp.bfloat16)

@@ -88,6 +88,18 @@ class TestLocalResourceManager:
         assert env["TPU_CHIPS_PER_TASK"] == "4"
         assert env["TPU_SLICE_NAME"] == "v5e-8"
         assert len(env["TPU_CHIP_COORDS"].split(";")) == 4
+        # what the TPU runtime itself reads: the chips' host indices (row-major
+        # in the slice grid) and "one process, this rectangle"
+        rows, cols = c.slice_topology
+        assert env["TPU_VISIBLE_CHIPS"] == ",".join(
+            str(i) for i in sorted(r * cols + col for r, col in c.chip_coords))
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "2,2,1"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+        one = LocalResourceManager("local:v5e-4").allocate("worker", 0, Resources(chips=1))
+        assert one.device_env()["TPU_VISIBLE_CHIPS"] == "0"
+        assert one.device_env()["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+        assert "TPU_VISIBLE_CHIPS" not in LocalResourceManager("local:cpu").allocate(
+            "worker", 0, Resources()).device_env()
 
     def test_chip_exhaustion_raises(self):
         rm = LocalResourceManager("local:v5e-4")

@@ -13,27 +13,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import shard_map
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.compat import (
-    cpu_devices_configurable,
-    shard_map,
-    tpu_interpret_supported,
-)
 from tony_tpu.ops.attention import attention_reference, repeat_kv
 from tony_tpu.parallel.context import ring_attention
-
-# The whole suite leans on two newer-jax features: the TPU Pallas
-# interpreter (pltpu.InterpretParams — emulated RDMA/semaphores the generic
-# interpret=True path can't provide) and, for the subprocess cases,
-# re-sizing the virtual CPU mesh via the jax_num_cpu_devices config option.
-# On builds missing either, skip cleanly instead of 14 AttributeErrors.
-pytestmark = pytest.mark.skipif(
-    not (tpu_interpret_supported() and cpu_devices_configurable()),
-    reason="jax build lacks pltpu.InterpretParams and/or jax_num_cpu_devices",
-)
-
 
 def _interpret_params():
     from jax.experimental.pallas import tpu as pltpu
@@ -216,7 +201,7 @@ jax.config.update("jax_num_cpu_devices", 16)
 import jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.pallas import tpu as pltpu
-from tony_tpu.compat import shard_map
+from jax import shard_map
 from tony_tpu.ops.ring import ring_attention_pallas
 from tony_tpu.ops.attention import attention_reference, repeat_kv
 
@@ -446,7 +431,7 @@ import functools
 import jax.numpy as jnp, numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from jax.experimental.pallas import tpu as pltpu
-from tony_tpu.compat import shard_map
+from jax import shard_map
 from tony_tpu.ops.ring import ring_attention_pallas, ring_attention_pallas_seg
 from tony_tpu.ops.attention import attention_reference, repeat_kv
 

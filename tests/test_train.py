@@ -82,7 +82,19 @@ class TestMetrics:
         assert t >= 6_000_000
 
     def test_detect_peak_flops_cpu(self):
-        assert detect_peak_flops() > 0
+        """MFU is a device metric: the CPU (any unknown device) has no peak,
+        and a meter given none reports no MFU instead of a nominal one."""
+        with pytest.raises(ValueError, match="device_kind"):
+            detect_peak_flops()
+
+        class V5e:
+            device_kind = "TPU v5 lite"
+
+        assert detect_peak_flops(V5e()) == 197e12
+        m = Throughput(tokens_per_step=10, flops_per_token=10, n_chips=1, peak_flops=None)
+        m.start()
+        m.step()
+        assert "mfu" not in m.report()
 
     def test_throughput_meter(self):
         m = Throughput(tokens_per_step=1000, flops_per_token=1000, n_chips=2, peak_flops=1e6)

@@ -71,8 +71,8 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--steps", type=int, default=3,
                    help="timed runs per candidate (median wins)")
     p.add_argument("--cache", default=None,
-                   help="cache file (default: $TONY_TUNE_CACHE or "
-                        "~/.cache/tony-tpu/tune.json)")
+                   help="cache file to write (default: $TONY_TUNE_CACHE; "
+                        "one of the two is required unless --dry-run)")
     p.add_argument("--dry-run", action="store_true",
                    help="sweep and print, but persist nothing")
     p.add_argument("--json", action="store_true", help="machine-readable output")
@@ -83,6 +83,10 @@ def main(argv: list[str] | None = None) -> int:
 
     from tony_tpu.ops import tune
 
+    if not (args.dry_run or args.cache or tune.default_cache_path()):
+        print(f"tony tune: no cache file to write: pass --cache or set "
+              f"{tune.ENV_CACHE} (or --dry-run)", file=sys.stderr)
+        return 2
     jobs: list[tuple[str, tuple]] = []
     try:
         if args.preset:

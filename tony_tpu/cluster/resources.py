@@ -111,7 +111,17 @@ class Container:
 
     def device_env(self) -> dict[str, str]:
         """TPU placement env injected into the executor (replaces the
-        reference's GPU device plumbing via nvidia-smi/YARN GPU isolation)."""
+        reference's GPU device plumbing via nvidia-smi/YARN GPU isolation).
+
+        Besides the orchestrator's own description of the placement, this
+        exports what the TPU runtime itself reads to bound a process to its
+        chips (the names are libtpu's, as jax's own multi-process test
+        launcher sets them): ``TPU_VISIBLE_CHIPS`` — the chips' indices on
+        their host, row-major in the slice grid — and the process bounds
+        that say "one process, this rectangle of chips". Without them two
+        one-chip containers on one host would each claim every chip. The
+        indices hold where the slice grid is one host's chips (local pools);
+        a pool of several hosts has to translate them per host (ROADMAP R0)."""
         env = {
             constants.ENV_CONTAINER_ID: self.id,
             constants.ENV_TPU_CHIPS_PER_TASK: str(len(self.chip_coords)),
@@ -120,6 +130,13 @@ class Container:
             env[constants.ENV_TPU_SLICE_NAME] = self.slice_name
             env[constants.ENV_TPU_SLICE_TOPOLOGY] = f"{self.slice_topology[0]}x{self.slice_topology[1]}"
             env[constants.ENV_TPU_CHIP_COORDS] = ";".join(f"{r},{c}" for r, c in self.chip_coords)
+            cols = self.slice_topology[1]
+            rows_used = {r for r, _ in self.chip_coords}
+            cols_used = {c for _, c in self.chip_coords}
+            env["TPU_VISIBLE_CHIPS"] = ",".join(
+                str(i) for i in sorted(r * cols + c for r, c in self.chip_coords))
+            env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = f"{len(cols_used)},{len(rows_used)},1"
+            env["TPU_PROCESS_BOUNDS"] = "1,1,1"
         return env
 
 

@@ -516,7 +516,7 @@ TRAIN_INPUT_WAIT_SPAN_MS = "tony.train.input-wait-span-ms"
 # ---------------------------------------------------------------------------
 # Cache of measured block-size winners keyed by (op, device kind, shape,
 # dtype); `tony tune` writes it, every kernel entry point consults it at
-# trace time. Empty → $TONY_TUNE_CACHE or ~/.cache/tony-tpu/tune.json.
+# trace time. Empty → $TONY_TUNE_CACHE; neither → source constants only.
 TUNE_CACHE_FILE = "tony.tune.cache-file"
 # false → kernels ignore the cache (module-constant defaults only); the
 # per-job kill switch when a tuning looks implicated in a regression.

@@ -150,8 +150,9 @@ def hidden_states(params: dict, tokens: jax.Array, cfg: BertConfig, mesh=None,
         q = q.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
         k = k.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
         v = v.reshape(B, T, H, Dh).transpose(0, 2, 1, 3)
-        o = attn_ops.mha(
-            q, k, v, causal=False, impl=cfg.attn_impl, segment_ids=segment_ids
+        o = attn_ops.mha_on_mesh(
+            q, k, v, mesh=mesh, causal=False, impl=cfg.attn_impl,
+            segment_ids=segment_ids,
         )
         o = o.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
         x = L.layer_norm(

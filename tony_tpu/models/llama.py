@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.compat import shard_map
 from tony_tpu.ops import attention as attn_ops
 from tony_tpu.ops import layers as L
 from tony_tpu.parallel.context import ring_attention
@@ -184,7 +183,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
                 )
             qspec = P(BATCH_AXES, "model", "context", None)
             if segment_ids is not None:
-                ring = shard_map(
+                ring = jax.shard_map(
                     partial(
                         ring_attention_pallas_seg, axis_name="context",
                         causal=True, window=cfg.sliding_window,
@@ -196,7 +195,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
                     check_vma=False,
                 )
                 return ring(q, k, v, segment_ids)
-            ring = shard_map(
+            ring = jax.shard_map(
                 partial(
                     ring_attention_pallas, axis_name="context", causal=True,
                     window=cfg.sliding_window,
@@ -234,7 +233,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
             k = attn_ops.repeat_kv(k, n_rep)
             v = attn_ops.repeat_kv(v, n_rep)
             fn = partial(ring_attention, axis_name="context", causal=True)
-        ring = shard_map(
+        ring = jax.shard_map(
             fn,
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -243,9 +242,9 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
             check_vma=False,
         )
         return ring(q, k, v)
-    return attn_ops.mha(
-        q, k, v, causal=True, impl=cfg.attn_impl, segment_ids=segment_ids,
-        window=cfg.sliding_window,
+    return attn_ops.mha_on_mesh(
+        q, k, v, mesh=mesh, causal=True, impl=cfg.attn_impl,
+        segment_ids=segment_ids, window=cfg.sliding_window,
     )
 
 

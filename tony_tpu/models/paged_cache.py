@@ -269,7 +269,7 @@ def insert_paged_prefill(
     class. The previous one-shot index-array scatter
     (`.at[:, fresh_pages].set(span)`) materialized a pool-sized copy per
     admission — the entirety of the paged engine's admission-side deficit
-    vs dense (BASELINE.md r5)."""
+    vs dense (builders' r5 run, older than this code)."""
     L, _, Hkv, page_len, Dh = cache.k.shape
 
     def body(j, kv):

@@ -22,7 +22,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.compat import tpu_compiler_params
+from tony_tpu.ops.interpret import interpret
 
 NEG_INF = -1e30
 
@@ -32,9 +32,6 @@ NEG_INF = -1e30
 # HBM cost of the stats negligible while satisfying the tiling rule.
 _STAT_LANES = 8
 
-# CPU tests run the TPU kernels through the Pallas interpreter (the reference
-# tests multi-node logic without a cluster; same idea for kernels without a chip)
-_INTERPRET = os.environ.get("TONY_PALLAS_INTERPRET", "") == "1"
 
 
 def repeat_kv(k: jax.Array, n_rep: int) -> jax.Array:
@@ -224,10 +221,10 @@ def _flash_fwd_lanes(
             jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
             jax.ShapeDtypeStruct((B * H, Tq, _STAT_LANES), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * B * H * Tq * Tk * D,
             bytes_accessed=2 * (qf.size + kf.size + vf.size) * q.dtype.itemsize,
@@ -532,8 +529,8 @@ def _flash_bwd_impl(
         in_specs=dq_specs,
         out_specs=blk_q,
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        compiler_params=tpu_compiler_params(dimension_semantics=("parallel", "arbitrary")),
-        interpret=_INTERPRET,
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=6 * B * H * Tq * Tk * D,
             bytes_accessed=3 * (qf.size + kf.size) * q.dtype.itemsize,
@@ -582,10 +579,10 @@ def _flash_bwd_impl(
                 jax.ShapeDtypeStruct((B * Hkv, Tk, D), k.dtype),
                 jax.ShapeDtypeStruct((B * Hkv, Tk, D), v.dtype),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=_INTERPRET,
+            interpret=interpret(),
             cost_estimate=cost,
         )(*dkv_operands)
     else:
@@ -670,10 +667,10 @@ def _flash_bwd_impl(
                 jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32),
                 jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32),
             ],
-            compiler_params=tpu_compiler_params(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")
             ),
-            interpret=_INTERPRET,
+            interpret=interpret(),
             cost_estimate=cost,
         )(kb, qrow, *stream_operands)
 
@@ -694,7 +691,8 @@ def _flash_bwd_impl(
 # beats 256/256 on EVERY bench preset, same-session A/Bs: llama-0.87B
 # 46.5→49.0% MFU, llama 2×8192 38.4→46.1%, moe 35.3→37.0%, BERT 34.5→37.7%.
 # (512/512 and bk 1024 fail to compile — VMEM; bq 128 is neutral.)
-# Env-overridable for per-hardware tuning; BASELINE.md records the ladder.
+# (The builders' r3 ladder, older than this code.) Env-overridable for
+# per-hardware tuning.
 _BLOCK_Q = int(os.environ.get("TONY_FLASH_BQ", "256"))
 _BLOCK_K = int(os.environ.get("TONY_FLASH_BK", "512"))
 if _BLOCK_Q < 8 or _BLOCK_Q % 8:
@@ -828,7 +826,8 @@ def remat_block(block_fn, remat: bool, policy: str = "full"):
         # vector-bound gating pipeline) and the fused expert-MLP kernel
         # output ("moe_gemm", ops/moe_gemm.py): [N_rows, D] bf16 per layer
         # — the one activation whose replay would re-run three grouped
-        # GEMMs (A/B'd +0.8 MFU pt on the moe bench preset, BASELINE.md r3).
+        # GEMMs (A/B'd +0.8 MFU pt on the moe bench preset: builders' r3
+        # run, older than this code).
         # TONY_REMAT_EXTRA_NAMES ("a,b") appends further named activations
         # (e.g. moe_disp / moe_combine) — the measurement ladder's knob for
         # per-shape save-vs-replay tradeoffs without code edits.
@@ -844,6 +843,57 @@ def remat_block(block_fn, remat: bool, policy: str = "full"):
     return jax.checkpoint(block_fn)
 
 
+def _flash_selected(impl: str, Tq: int, Tk: int) -> bool:
+    """The one copy of mha's kernel-or-reference rule (see ``mha``)."""
+    if impl not in ("auto", "flash", "reference"):
+        raise ValueError(f"impl must be auto|flash|reference, got {impl!r}")
+    bq, bk = _block_sizes(Tq, Tk)
+    # ragged lengths shrink the blocks; below 128 the kernel grid is
+    # lane-starved and the XLA reference path wins
+    fits = bq >= 128 and bk >= 128 and Tq >= 128
+    if impl == "flash" and not fits:
+        raise ValueError(
+            f"impl='flash' cannot be honoured at Tq={Tq}, Tk={Tk} (blocks "
+            f"{bq}x{bk}; the kernel needs >= 128 rows a block): use "
+            "impl='auto' or 'reference'")
+    return impl == "flash" or (impl == "auto" and fits and jax.default_backend() != "cpu")
+
+
+def mha_on_mesh(
+    q: jax.Array, k: jax.Array, v: jax.Array, *, mesh,
+    segment_ids: jax.Array | None = None, **kw,
+) -> jax.Array:
+    """``mha`` for [B, H, T, D] arrays laid out as every model here lays them
+    out on ``mesh``: batch over data x fsdp, heads over model. GSPMD cannot
+    partition a Mosaic kernel, so where the flash kernel is selected on a
+    mesh of more than one device it runs per shard under a fully manual
+    ``shard_map``; the XLA reference partitions by itself and stays bare."""
+    if mesh is None or mesh.size == 1 or not _flash_selected(
+            kw.get("impl", "auto"), q.shape[2], k.shape[2]):
+        return mha(q, k, v, segment_ids=segment_ids, **kw)
+    from jax.sharding import PartitionSpec as P
+
+    batch = tuple(a for a in ("data", "fsdp") if a in mesh.shape)
+    heads = "model" if "model" in mesh.shape else None
+    n_head_shards = mesh.shape.get("model", 1)
+    if q.shape[1] % n_head_shards:
+        raise ValueError(
+            f"n_heads {q.shape[1]} must divide by the 'model' axis ({n_head_shards})")
+    if k.shape[1] % n_head_shards:  # GQA narrower than the axis: broadcast first
+        n_rep = q.shape[1] // k.shape[1]
+        k, v = repeat_kv(k, n_rep), repeat_kv(v, n_rep)
+    spec = P(batch, heads, None, None)
+    if segment_ids is None:
+        fn, args, in_specs = functools.partial(mha, **kw), (q, k, v), (spec, spec, spec)
+    else:
+        fn = lambda q, k, v, seg: mha(q, k, v, segment_ids=seg, **kw)  # noqa: E731
+        args, in_specs = (q, k, v, segment_ids), (spec, spec, spec, P(batch, None))
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=spec,
+        axis_names=set(mesh.axis_names), check_vma=False,
+    )(*args)
+
+
 def mha(
     q: jax.Array,
     k: jax.Array,
@@ -854,7 +904,10 @@ def mha(
     segment_ids: jax.Array | None = None,
     window: int = 0,
 ) -> jax.Array:
-    """Dispatcher: Pallas flash kernel on TPU, XLA reference elsewhere.
+    """Dispatcher. ``impl="auto"`` selects by what it can observe: the Pallas
+    flash kernel off the CPU where the shape feeds it (>= 128 rows a block),
+    the XLA reference otherwise. An explicit ``"flash"`` that cannot be
+    honoured raises rather than running the reference in its place.
 
     k/v may carry fewer heads than q (GQA/MQA): the flash kernels read kv
     heads in place via index-map aliasing; the reference path broadcasts.
@@ -864,19 +917,13 @@ def mha(
     if q.shape[1] % k.shape[1]:
         raise ValueError(f"n_heads {q.shape[1]} must be divisible by n_kv_heads {k.shape[1]}")
     n_rep = q.shape[1] // k.shape[1]
-    if impl == "auto":
-        impl = "flash" if jax.default_backend() not in ("cpu",) else "reference"
-    if impl == "flash":
-        Tq, Tk = q.shape[2], k.shape[2]
-        bq, bk = _block_sizes(Tq, Tk)
-        # ragged lengths shrink the blocks; below 128 the kernel grid is
-        # lane-starved and the XLA reference path wins
-        if bq >= 128 and bk >= 128 and Tq >= 128:
-            if segment_ids is not None:
-                if Tq != Tk:
-                    raise ValueError(f"segment_ids requires Tq == Tk, got {Tq} vs {Tk}")
-                return _flash_trainable_seg(q, k, v, segment_ids, causal, window)
-            return _flash_trainable(q, k, v, causal, window)
+    Tq, Tk = q.shape[2], k.shape[2]
+    if _flash_selected(impl, Tq, Tk):
+        if segment_ids is not None:
+            if Tq != Tk:
+                raise ValueError(f"segment_ids requires Tq == Tk, got {Tq} vs {Tk}")
+            return _flash_trainable_seg(q, k, v, segment_ids, causal, window)
+        return _flash_trainable(q, k, v, causal, window)
     return attention_reference(
         q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
         causal=causal, segment_ids=segment_ids, window=window,

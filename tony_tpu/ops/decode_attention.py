@@ -33,9 +33,7 @@ import os
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.compat import tpu_compiler_params
-
-_INTERPRET = os.environ.get("TONY_PALLAS_INTERPRET", "") == "1"
+from tony_tpu.ops.interpret import interpret
 
 # cache positions streamed per DMA slab; 256 measured best on v5e (r3-cont
 # ladder at 8×2048-cache slots: 128→533, 256→554, 512→531 tok/s) — bigger
@@ -246,10 +244,10 @@ def ragged_decode_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hkv, n_rep, Dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * S * H * maxT * Dh,
             bytes_accessed=(ck.size + cv.size) * ck.dtype.itemsize // 4,
@@ -374,10 +372,10 @@ def paged_decode_attention(
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hkv, n_rep, Dh), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * S * H * page_table.shape[1] * page_len * Dh,
             bytes_accessed=(kp.size + vp.size) * kp.dtype.itemsize // 4,

@@ -3,7 +3,7 @@
 The XLA path (``jax.lax.ragged_dot``) runs the three expert GEMMs as
 separate megablox custom calls with the [N, F] gate/up activations making
 full HBM round-trips between them, and loses ~40% throughput to multi-group
-handling even on 512-aligned uniform groups (measured, BASELINE.md r3).
+handling even on 512-aligned uniform groups (builders' r3 run, older than this code).
 This kernel computes the whole expert MLP — ``silu(x·Wg) ⊙ (x·Wu) · Wd`` —
 in ONE VMEM pass per row tile:
 
@@ -36,15 +36,14 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tony_tpu.compat import tpu_compiler_params
-
-_INTERPRET = os.environ.get("TONY_PALLAS_INTERPRET", "") == "1"
+from tony_tpu.ops.interpret import interpret
 
 # fwd row-tile; group sizes are padded to multiples of this. 128 is the r3
 # measured optimum on v5e at the bench geometry (same-session ladder:
 # 64→36.2%, 96→38.1%, 128→38.4%, 256→36.9%, 512→36.8% active MFU — less
 # group-padding waste and tighter pipelining beat bigger GEMM tiles).
-# Env-overridable for per-hardware tuning; BASELINE.md records the ladder.
+# (The builders' r3 ladder, older than this code.) Env-overridable for
+# per-hardware tuning.
 TILE_M = int(os.environ.get("TONY_MOE_TILE", "128"))
 # bwd row-tile (more VMEM-hungry: f32 dW accumulators); must divide TILE_M
 # when smaller (the backward splits fwd tiles into bwd tiles)
@@ -199,11 +198,11 @@ def _fwd_call(xs, wg, wu, wd, tile_group, tile):
         _fwd_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((PN, D), xs.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),  # revisit caching needs order
             vmem_limit_bytes=100 * 1024 * 1024,  # weight slabs resident (v5e: 128M)
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * PN * D * F * 3,
             bytes_accessed=(xs.size * 2 + (wg.size + wu.size + wd.size)) * xs.dtype.itemsize,
@@ -244,11 +243,11 @@ def _bwd_call(xs, dy, wg, wu, wd, tile_group, tile):
             jax.ShapeDtypeStruct((E, D, F), jnp.float32),
             jax.ShapeDtypeStruct((E, F, D), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
             vmem_limit_bytes=100 * 1024 * 1024,  # f32 dW accumulators + weight slabs
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * PN * D * F * 8,
             bytes_accessed=(xs.size * 3 + 2 * (wg.size + wu.size + wd.size))

@@ -10,16 +10,12 @@ exists in HBM. Training stays bf16; quantize at export time.
 from __future__ import annotations
 
 import functools
-import os
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.compat import tpu_compiler_params
-
-_INTERPRET = os.environ.get("TONY_PALLAS_INTERPRET", "") == "1"
-
+from tony_tpu.ops.interpret import interpret
 
 class QTensor(NamedTuple):
     """Per-output-channel absmax int8 quantization of a [..., K, N] weight."""
@@ -140,10 +136,10 @@ def int8_matmul(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
-        interpret=_INTERPRET,
+        interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=2 * M * N * K,
             bytes_accessed=M * K * x.dtype.itemsize + K * N + M * N * x.dtype.itemsize,

@@ -36,8 +36,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.compat import axis_size, tpu_compiler_params, tpu_interpret_params
 from tony_tpu.ops.attention import NEG_INF, _STAT_LANES
+from tony_tpu.ops.interpret import interpret
 
 # Registry of Pallas collective_ids in this program. A collective_id names the
 # cross-device barrier-semaphore set; two concurrently-live collective kernels
@@ -51,14 +51,16 @@ RING_ATTENTION_BWD_COLLECTIVE_ID = 8  # backward ring kernel (may overlap fwd
 def default_interpret():
     """InterpretParams when the env asks for emulated kernels, else False
     (same TONY_PALLAS_INTERPRET contract as ops/attention.py)."""
-    if os.environ.get("TONY_PALLAS_INTERPRET", "") == "1":
-        return tpu_interpret_params()
+    if interpret():
+        from jax.experimental.pallas import tpu as pltpu
+
+        return pltpu.InterpretParams()
     return False
 
 
 # ring block caps, env-tunable like the flash kernels' TONY_FLASH_BQ/BK.
-# The flash ladder measured bk 512 > 256 on every single-chip preset (r3,
-# BASELINE.md); the ring's KV block also sets the per-rotation DMA slab, and
+# The builders' r3 flash ladder (older than this code) measured bk 512 > 256
+# on every single-chip preset; the ring's KV block also sets the per-rotation DMA slab, and
 # without multi-chip hardware the 256 default stays unvalidated — retune
 # TONY_RING_BQ/BK on a real slice.
 _RING_BQ = int(os.environ.get("TONY_RING_BQ", "256"))
@@ -303,17 +305,33 @@ def _seg_layouts(segment_ids, axis_name):
     return segq, segk
 
 
+def _refuse_on_tpu() -> None:
+    """The ring kernels have only ever run under the interpreter, and the
+    chip's compiler refuses them (v5e:2x2, 4-way context mesh,
+    [1,16,8192,128], forward and backward): the 8-lane row-statistics layout
+    (``_STAT_LANES``) is sliced below the 128-lane tiling. Until that layout
+    is repaired (ROADMAP R5b) an explicit ``cp_impl="pallas"`` on a TPU
+    backend says so instead of failing somewhere inside Mosaic."""
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "cp_impl='pallas' (the remote-DMA ring kernel) does not compile for "
+            "the TPU yet: 'Mosaic failed to compile TPU kernel: Slice shape "
+            "along dimension 2 must be aligned to tiling (128), but is 8'. Use "
+            "cp_impl='xla' or 'ulysses' (ROADMAP R5b)")
+
+
 def _ring_fwd(q, k, v, axis_name: str, causal: bool, interpret: Any,
               window: int = 0, segment_ids=None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    _refuse_on_tpu()
     B, H, Tl, D = q.shape
     Hkv = k.shape[1]
     if H % Hkv:
         raise ValueError(f"n_heads {H} must be divisible by n_kv_heads {Hkv}")
     n_rep = H // Hkv
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = D ** -0.5
     bq = _pick_block(Tl, _RING_BQ)
@@ -371,7 +389,7 @@ def _ring_fwd(q, k, v, axis_name: str, causal: bool, interpret: Any,
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SemaphoreType.REGULAR((2,)),    # per-slot "free" acks
         ],
-        compiler_params=tpu_compiler_params(collective_id=RING_ATTENTION_COLLECTIVE_ID),
+        compiler_params=pltpu.CompilerParams(collective_id=RING_ATTENTION_COLLECTIVE_ID),
         interpret=interpret if interpret is not None else default_interpret(),
     )(*operands)
     return out.reshape(B, H, Tl, D), lse.reshape(B, H, Tl, _STAT_LANES)
@@ -668,7 +686,7 @@ def _ring_bwd(q, k, v, o, lse, do, axis_name: str, causal: bool, interpret: Any,
     B, H, Tl, D = q.shape
     Hkv = k.shape[1]
     n_rep = H // Hkv
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     scale = D ** -0.5
     bq = _pick_block(Tl, _RING_BQ)
@@ -752,7 +770,7 @@ def _ring_bwd(q, k, v, o, lse, do, axis_name: str, causal: bool, interpret: Any,
             pltpu.SemaphoreType.DMA((2,)),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=tpu_compiler_params(collective_id=RING_ATTENTION_BWD_COLLECTIVE_ID),
+        compiler_params=pltpu.CompilerParams(collective_id=RING_ATTENTION_BWD_COLLECTIVE_ID),
         interpret=interpret if interpret is not None else default_interpret(),
     )(*operands)
     return (

@@ -2,8 +2,8 @@
 
 The hot kernels (flash attention fwd/bwd in ops/attention.py, the fused MoE
 grouped GEMM in ops/moe_gemm.py, the int8 matmul in ops/quant.py) ship with
-block sizes measured ONCE on one device generation (the r3 v5e ladder,
-BASELINE.md) and frozen as module constants. Those constants are the right
+block sizes measured ONCE on one device generation (the builders' r3 v5e
+ladder, older than this code) and frozen as module constants. Those constants are the right
 cold-cache default, but they are not the optimum for every (shape, dtype,
 device) the framework meets — a different chip generation, head dim, or
 sequence length can move the best block by 2+ MFU points, and until now the
@@ -20,10 +20,12 @@ This module closes the loop:
   :func:`lookup` — a cache hit overrides the module-constant default, a miss
   (or ``TONY_TUNE_DISABLE=1``) keeps today's behavior byte-for-byte.
 
-The cache file defaults to ``~/.cache/tony-tpu/tune.json`` and is overridden
-by ``TONY_TUNE_CACHE`` (the executor exports it from ``tony.tune.cache-file``
-so tuned jobs see the same cache on every worker). Lookups happen at trace
-time only — once per compiled shape, never on the step path.
+The cache file is the one ``TONY_TUNE_CACHE`` names (the executor exports it
+from ``tony.tune.cache-file`` so tuned jobs see the same cache on every
+worker). There is no default file: with the variable unset the kernels run
+on the constants in the source, which git carries, and never on a file under
+somebody's home directory. Lookups happen at trace time only — once per
+compiled shape, never on the step path.
 """
 
 from __future__ import annotations
@@ -40,10 +42,8 @@ ENV_DISABLE = constants.ENV_TUNE_DISABLE  # "1" → kernels ignore the cache ent
 
 
 def default_cache_path() -> str:
-    """``$TONY_TUNE_CACHE`` when set, else the per-user cache location."""
-    return os.environ.get(ENV_CACHE) or os.path.join(
-        os.path.expanduser("~"), ".cache", "tony-tpu", "tune.json"
-    )
+    """``$TONY_TUNE_CACHE``, or "" — no cache file, source constants only."""
+    return os.environ.get(ENV_CACHE, "")
 
 
 def device_kind() -> str:
@@ -66,8 +66,8 @@ class TuneCache:
     without a restart of THIS object); writes merge with the on-disk state
     so two concurrent tuners don't clobber each other's ops."""
 
-    def __init__(self, path: str | None = None):
-        self.path = path or default_cache_path()
+    def __init__(self, path: str):
+        self.path = path
         self._disk: dict[str, dict] = {}      # mirror of the file, mtime-tracked
         self._local: dict[str, dict] = {}     # puts not yet saved (win over disk)
         self._mtime: float | None = None
@@ -140,6 +140,8 @@ def shared_cache() -> TuneCache:
     (re-bound when TONY_TUNE_CACHE changes, so tests can redirect it)."""
     global _shared
     path = default_cache_path()
+    if not path:
+        raise ValueError(f"no tune cache file: set {ENV_CACHE} (or pass --cache)")
     if _shared is None or _shared.path != path:
         _shared = TuneCache(path)
     return _shared
@@ -149,9 +151,9 @@ def lookup(op: str, shape: Iterable[int], dtype: Any) -> dict[str, int] | None:
     """The kernel entry points' cache consult: tuned params or None.
 
     Trace-time only (static block sizes); disabled by ``TONY_TUNE_DISABLE=1``
-    and inert (one env read + a failed stat) when nothing was ever tuned.
+    and inert (two env reads) when no cache file is named.
     """
-    if os.environ.get(ENV_DISABLE) == "1":
+    if os.environ.get(ENV_DISABLE) == "1" or not default_cache_path():
         return None
     return shared_cache().get(op, shape, dtype)
 

@@ -11,11 +11,9 @@ from __future__ import annotations
 
 import jax
 
-from tony_tpu.compat import axis_size
-
 
 def ring_size(axis_name: str) -> int:
-    return axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def ring_index(axis_name: str) -> jax.Array:
@@ -25,7 +23,7 @@ def ring_index(axis_name: str) -> jax.Array:
 def rotate(x: jax.Array, axis_name: str, shift: int = 1) -> jax.Array:
     """Send to the next rank on the axis ring (ppermute); the ICI-neighbor
     pattern every ring collective here is built from."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return jax.lax.ppermute(x, axis_name, perm)
 
@@ -57,7 +55,7 @@ def ring_all_reduce_sum(x: jax.Array, axis_name: str) -> jax.Array:
     compute in shard_map bodies (and as the XLA-level analog of the Pallas
     remote-DMA ring in ops/ring kernels).
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if x.shape[0] % n:
         return jax.lax.psum(x, axis_name)
     scattered = psum_scatter(x, axis_name, axis=0)
@@ -83,6 +81,6 @@ def stop_transfer_if_single(transfer, axis_name: str, x: jax.Array, /, *args, **
     The axis size is static under ``shard_map``, so the branch resolves at
     trace time — no ``lax.cond`` in the compiled program.
     """
-    if axis_size(axis_name) <= 1:
+    if jax.lax.axis_size(axis_name) <= 1:
         return x
     return transfer(x, axis_name, *args, **kwargs)

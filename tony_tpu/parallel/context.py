@@ -20,7 +20,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.compat import axis_size
 from tony_tpu.parallel import collectives
 
 NEG_INF = -1e30
@@ -65,7 +64,7 @@ def ring_attention(
     ``axis_name``. Shapes (per shard): q/k/v [B, H, T_local, D] (KV heads
     already broadcast to H). Returns [B, H, T_local, D] in q.dtype.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     B, H, Tl, D = q.shape
     scale = scale if scale is not None else D ** -0.5
@@ -114,7 +113,7 @@ def ulysses_attention(
 
     Inside shard_map; shapes per shard: [B, H, T_local, D] → same.
     """
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if attn_fn is None:
         from tony_tpu.ops.attention import attention_reference
 

@@ -16,7 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from tony_tpu.compat import shard_map
 from tony_tpu.parallel.sharding import constrain
 
 
@@ -195,8 +194,8 @@ def route_ragged(
     token order directly: a counting sort of all N = B·T·K routing choices
     by expert id, built from one cumsum (rank within expert) plus the
     exclusive prefix-sum of per-expert counts — no capacity bound, no
-    drops, no [B,T,E,C] tensors, and no TPU sort (measured 6 MFU pt slower
-    than arithmetic construction, BASELINE.md r2 negative results).
+    drops, no [B,T,E,C] tensors, and no TPU sort (6 MFU pt slower than
+    arithmetic construction in the builders' r2 run, older than this code).
 
     Masked (pad) tokens still occupy group slots — ``jax.lax.ragged_dot``
     computes garbage for rows beyond ``sum(group_sizes)``, so every choice
@@ -256,17 +255,19 @@ def route_ragged(
 
 
 def _kernel_eligible(cfg: MoEConfig, D: int, F: int, dtype) -> bool:
-    """One copy of the fused-kernel eligibility rule (MXU-aligned geometry
-    on a TPU backend or the interpret harness)."""
-    from tony_tpu.ops import moe_gemm
+    """One copy of the fused-kernel eligibility rule: ``dispatch="ragged"``
+    selects by what it can observe — the fused Pallas kernel for MXU-aligned
+    bf16 geometry on a TPU backend (or under the interpret harness), three
+    ``ragged_dot`` grouped GEMMs otherwise; ``"ragged_xla"`` is the explicit
+    ask for the latter."""
+    from tony_tpu.ops.interpret import interpret
 
     return (
         cfg.dispatch == "ragged"
         and D % 128 == 0
         and F % 128 == 0
         and dtype == jnp.bfloat16
-        and (jax.default_backend() not in ("cpu", "gpu", "cuda", "rocm")
-             or moe_gemm._INTERPRET)
+        and (jax.default_backend() == "tpu" or interpret())
     )
 
 
@@ -288,7 +289,8 @@ def _dispatch_gather(x_flat, sort_tok, dest):
     """xs = x_flat[sort_tok] with a GATHER-form backward.
 
     The autodiff transpose of a row gather is a scatter-add, which costs
-    ~1.7× a gather at [N, D] bench shape (BASELINE.md r3 probes). Because
+    ~1.7× a gather at [N, D] bench shape (builders' r3 probes, older than
+    this code). Because
     every token appears exactly top_k times and ``dest`` enumerates those
     appearances, the cotangent is expressible as a gather:
     ``dx[t] = Σ_k dxs[dest[t, k]]`` — no scatter anywhere."""
@@ -492,7 +494,7 @@ def _ragged_expert_ffn_ep(
     act = P(batch_axes or None, None, None)
     wspec = P("expert", None, None)
     tm = token_mask if token_mask is not None else jnp.ones((B, T), bool)
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(act, P(None, None), wspec, wspec, wspec,
@@ -509,10 +511,10 @@ def _ragged_expert_ffn(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, token_
     rows via ``jax.lax.ragged_dot`` (XLA's megablox-style grouped GEMM) —
     the [E,B,C,D] dispatched bank of the capacity schemes never exists.
     Per layer this removes the ~4 extra full-activation HBM round-trips
-    the r2 decomposition charged to the bank (BASELINE.md) plus the
+    the builders' r2 decomposition charged to the bank plus the
     capacity overcompute (N = K·B·T rows exactly, vs 1.25·K·B·T slots).
 
-    Measured layout choices (same-session bench A/Bs, BASELINE.md r3): the
+    Measured layout choices (the builders' same-session r3 A/Bs, older than this code): the
     combine is a GATHER back to choice order, not a scatter-add — under
     remat replay an op's fwd runs twice per step, and gather-fwd (4.4 ms)
     beats scatter-add-fwd (7.6 ms) at [N, D] bench shape. Fusing gate+up

@@ -34,8 +34,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from tony_tpu.compat import axis_size, shard_map
-
 
 def _pipeline_body(
     stage_params: Any,
@@ -55,7 +53,7 @@ def _pipeline_body(
     copy"), and f32 hand-off is numerically lossless between stages.
     Compute inside each stage runs in ``compute_dtype``.
     """
-    S = axis_size(axis_name)
+    S = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     M = microbatches.shape[0]
     mb_shape = microbatches.shape[1:]
@@ -123,7 +121,7 @@ def spmd_pipeline(
     mb = x.astype(wire_dtype).reshape(M, B // M, *x.shape[1:])
 
     param_specs = jax.tree.map(lambda p: P(axis_name, *([None] * (p.ndim - 1))), stage_params)
-    body = shard_map(
+    body = jax.shard_map(
         partial(_pipeline_body, stage_fn=stage_fn, axis_name=axis_name, compute_dtype=compute_dtype),
         mesh=mesh,
         in_specs=(param_specs, P()),
@@ -374,7 +372,7 @@ def spmd_pipeline_1f1b(
     mb_specs = jax.tree.map(
         lambda a: P(None, present or None, *([None] * (a.ndim - 2))), batch_mb
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, rep, rep_head, mb_specs),
@@ -612,7 +610,7 @@ def spmd_pipeline_1f1b_interleaved(
     mb_specs = jax.tree.map(
         lambda a: P(None, present or None, *([None] * (a.ndim - 2))), batch_mb
     )
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(param_specs, rep, rep_head, mb_specs),

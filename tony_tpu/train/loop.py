@@ -23,7 +23,7 @@ from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.obs import trace as obs_trace
 from tony_tpu.parallel import MeshSpec
-from tony_tpu.runtime import init_distributed
+from tony_tpu.runtime import device_facts, enable_compile_cache, init_distributed
 from tony_tpu.train.checkpoint import UrgentSaveSignal, restore_or_init
 from tony_tpu.train.input_pipeline import InputPipeline
 from tony_tpu.train.metrics import detect_peak_flops, flops_per_token_for_batch
@@ -168,6 +168,8 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
                 "schedule (num_chunks) — --pp_chunks > 1 is llama-family only"
             )
     init_distributed()  # no-op off-gang; joins jax.distributed under tony
+    cache_dir = enable_compile_cache()
+    obs_logging.info(f"[train] device {json.dumps(device_facts())} compile_cache={cache_dir}")
     spec = MeshSpec.auto(
         model=loop.model_axis, context=loop.context_axis, expert=loop.expert_axis,
         stage=loop.stage_axis,
@@ -193,6 +195,13 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
     state, ckpt_mgr, start_step = restore_or_init(loop.checkpoint_dir or None, init_state)
     if start_step:
         obs_logging.info(f"[train] resumed from checkpoint step {start_step}", step=start_step)
+    # where the parameters really live: a layout that leaves a chip empty (or
+    # piles everything on the first) shows here, not in the loss
+    per_device: dict[int, int] = {}
+    for leaf in jax.tree.leaves(state.params):
+        for shard in leaf.addressable_shards:
+            per_device[shard.device.id] = per_device.get(shard.device.id, 0) + shard.data.nbytes
+    obs_logging.info(f"[train] param bytes per device: {json.dumps(per_device, sort_keys=True)}")
 
     if loop.stage_axis > 1:
         # pipeline parallelism: the 1F1B schedule produces its own gradients
@@ -219,7 +228,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
         tokens_per_step=loop.batch_size * loop.seq_len,
         flops_per_token=flops_per_token_for_batch(model_cfg, probe, loop.seq_len),
         n_chips=n_chips,
-        peak_flops=detect_peak_flops(),
+        peak_flops=None if jax.default_backend() == "cpu" else detect_peak_flops(),
     )
 
     key = jax.random.PRNGKey(start_step + 1)
@@ -349,6 +358,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
                 jax.block_until_ready(metrics["loss"])
                 first_s = time.perf_counter() - t_first
                 _FIRST_STEP_SECONDS.set(first_s)
+                obs_logging.info(f"[train] first step (compile included) {first_s:.2f}s")
                 if tracer is not None:
                     with tracer.span("train.first_step", step=step) as sp:
                         sp.start_ms -= first_s * 1000.0
@@ -362,7 +372,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
                     "loss": round(float(metrics["loss"]), 4),
                     "grad_norm": round(float(metrics["grad_norm"]), 4),
                     "tokens_per_sec": round(report["tokens_per_sec"], 1),
-                    "mfu": round(report["mfu"], 4),
+                    **({"mfu": round(report["mfu"], 4)} if "mfu" in report else {}),
                     "time": time.strftime("%H:%M:%S"),
                 }
                 obs_logging.info(json.dumps(line), **line)

@@ -170,9 +170,11 @@ def sharded_init(
 
 
 class Throughput:
-    """Wall-clock tokens/s + MFU meter around the jitted step (host side)."""
+    """Wall-clock tokens/s + MFU meter around the jitted step (host side).
+    ``peak_flops=None`` (a CPU run) reports no MFU."""
 
-    def __init__(self, tokens_per_step: int, flops_per_token: int, n_chips: int, peak_flops: float):
+    def __init__(self, tokens_per_step: int, flops_per_token: int, n_chips: int,
+                 peak_flops: float | None):
         self.tokens_per_step = tokens_per_step
         self.flops_per_token = flops_per_token
         self.n_chips = max(n_chips, 1)
@@ -190,12 +192,15 @@ class Throughput:
     def report(self) -> dict:
         dt = time.perf_counter() - (self._t0 or time.perf_counter())
         if dt <= 0 or self.steps == 0:
-            return {"tokens_per_sec": 0.0, "mfu": 0.0, "step_time_ms": 0.0}
-        tps = self.tokens_per_step * self.steps / dt
-        flops = tps * self.flops_per_token
-        return {
+            tps = step_ms = 0.0
+        else:
+            tps = self.tokens_per_step * self.steps / dt
+            step_ms = 1000 * dt / self.steps
+        out = {
             "tokens_per_sec": tps,
             "tokens_per_sec_per_chip": tps / self.n_chips,
-            "step_time_ms": 1000 * dt / self.steps,
-            "mfu": flops / (self.peak_flops * self.n_chips),
+            "step_time_ms": step_ms,
         }
+        if self.peak_flops is not None:
+            out["mfu"] = tps * self.flops_per_token / (self.peak_flops * self.n_chips)
+        return out
