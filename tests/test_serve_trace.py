@@ -1,0 +1,277 @@
+"""The serving engine's account of its own time (docs/observability.md "Where
+a request's TTFT goes"): request stages stamped in the engine, engine phases
+on two clocks, and the two benchmark readers that turn them into metrics.
+
+Tiny config, ``ContinuousBatcher`` and ``EngineServer`` in-process: no fleet,
+no subprocess, no sleep over 0.2 s.
+"""
+
+import json
+import os
+import sys
+import threading
+import time
+
+import jax
+import pytest
+
+from tony_tpu.models import serving, serving_http
+from tony_tpu.models.llama import LLAMA_TINY, init
+from tony_tpu.models.serving import ContinuousBatcher
+from tony_tpu.models.serving_http import EngineServer, RequestStream
+from tony_tpu.obs import trace as obs_trace
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark")
+# at the END of the path: the benchmark's top-level module names (run, check,
+# spec, jobs, reduce ...) must not shadow anything a later test file imports
+if BENCH not in sys.path:
+    sys.path.append(BENCH)
+
+from readers import gap_by_span, registry_delta  # noqa: E402
+
+STAGES = ("queue", "prefill", "emit")
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init(jax.random.PRNGKey(0), LLAMA_TINY)
+
+
+def engine(params, **kw):
+    return ContinuousBatcher(params, LLAMA_TINY, **{"num_slots": 2, "max_len": 64, "decode_chunk": 4, **kw})
+
+
+def finish(out):
+    while True:
+        kind, payload = out.get(timeout=120)
+        if kind != "tokens":
+            assert kind == "done", payload
+            return payload
+
+
+def observed(monkeypatch, histogram):
+    """Every value the histogram is handed from here on, with its labels."""
+    seen, real = [], histogram.observe
+
+    def observe(value, exemplar=None, **labels):
+        seen.append((labels.get("stage"), value))
+        real(value, exemplar=exemplar, **labels)
+
+    monkeypatch.setattr(histogram, "observe", observe)
+    return seen
+
+
+def blocked(reason):
+    return serving._ADMIT_BLOCKED.value(reason=reason)
+
+
+def check_stages(outs, ttfts, stages):
+    """queue + prefill + emit is the TTFT the replica observed, request by
+    request (``outs`` in the order of their first fanouts)."""
+    assert len(ttfts) == len(outs) and len(stages) == 3 * len(outs)
+    for i, (_, ttft) in enumerate(ttfts):
+        triple = stages[3 * i:3 * i + 3]
+        assert tuple(label for label, _ in triple) == STAGES
+        assert all(seconds >= 0 for _, seconds in triple)
+        assert sum(seconds for _, seconds in triple) == pytest.approx(ttft, abs=1e-9)
+    for out, (_, ttft) in zip(outs, ttfts):
+        assert out.submitted_s <= out.req.staged_s <= out.req.slot_s <= out.submitted_s + ttft
+
+
+def test_stages_add_up_to_the_ttft_of_every_request_one_of_which_waited_for_a_slot(params, monkeypatch):
+    ttfts = observed(monkeypatch, serving_http._TTFT)
+    stages = observed(monkeypatch, serving_http._STAGE)
+    slots0 = blocked("slots")
+    srv = EngineServer(engine(params, num_slots=1)).start()
+    try:
+        outs = [srv.submit([1 + i, 2, 3], max_tokens=9) for i in range(3)]
+        assert all(len(finish(out)) == 9 for out in outs)
+    finally:
+        assert srv.stop()
+    check_stages(outs, ttfts, stages)
+    # one slot: the third request stayed in `pending` (its queue stage) while
+    # the first two decoded, and the engine counted passes with nobody admitted
+    assert blocked("slots") > slots0
+    assert outs[2].req.staged_s > outs[0].submitted_s + ttfts[0][1]  # staged after the first one's fanout
+    assert outs[2].req.staged_s - outs[2].submitted_s > outs[0].req.staged_s - outs[0].submitted_s
+
+
+def test_a_request_that_waited_for_pages_is_counted_and_still_adds_up(params, monkeypatch):
+    ttfts = observed(monkeypatch, serving_http._TTFT)
+    stages = observed(monkeypatch, serving_http._STAGE)
+    pages0 = blocked("pages")
+    # 8 + 16 tokens = 3 pages of 8 a request; the pool holds 4: the second
+    # request has a free slot and its prefill done, and waits for pages
+    srv = EngineServer(engine(params, kv="paged", page_len=8, num_pages=5)).start()
+    try:
+        outs = [srv.submit(list(range(1 + i, 9 + i)), max_tokens=16) for i in range(2)]
+        assert all(len(finish(out)) == 16 for out in outs)
+    finally:
+        assert srv.stop()
+    check_stages(outs, ttfts, stages)
+    assert blocked("pages") > pages0
+    waited = outs[1].req
+    assert waited.slot_s - waited.staged_s > outs[0].req.slot_s - outs[0].req.staged_s  # in its prefill stage
+
+
+def test_phases_tile_the_engine_threads_time(params, monkeypatch):
+    """Σ phase seconds is the loop's wall time within 1% (other engine threads
+    of this process, which earlier test files may have left idling, are kept
+    out: the shared counter is replaced by one that takes this thread only)."""
+    by_phase: dict[str, float] = {}
+    wall = []
+
+    class OnThisThread:
+        def inc(self, amount=1.0, **labels):
+            if threading.current_thread() is srv._thread:
+                by_phase[labels["phase"]] = by_phase.get(labels["phase"], 0.0) + amount
+
+    monkeypatch.setattr(serving, "_ENGINE_SECONDS", OnThisThread())
+    srv = EngineServer(engine(params))
+    loop = srv._loop_inner
+
+    def timed():
+        t0 = time.perf_counter()
+        try:
+            loop()
+        finally:
+            wall.append(time.perf_counter() - t0)
+
+    srv._loop_inner = timed
+    srv.start()
+    outs = [srv.submit([1 + i, 2, 3], max_tokens=9) for i in range(5)]
+    assert all(len(finish(out)) == 9 for out in outs)
+    time.sleep(0.2)  # one idle wait of the loop
+    assert srv.stop()
+    assert set(by_phase) == {"intake", "admit", "prefill_wait", "dispatch", "decode_wait", "emit", "idle"}
+    assert all(v >= 0 for v in by_phase.values())
+    assert sum(by_phase.values()) == pytest.approx(wall[0], rel=0.01)
+    assert srv.engine.phase._name is None  # the loop closed its last phase
+
+
+def test_chunk_slot_and_prefill_counters_match_a_hand_count(params):
+    """Two slots, chunks of 4. Pass 1 admits A and B (1 token each) and
+    decodes both to 5: B is done. Pass 2 admits C into B's slot and decodes
+    A to 9 and C to 5: both done. Two chunks, two slots each; three prompts
+    of 3 tokens, each padded to the smallest bucket (16)."""
+    counters = (serving._CHUNKS, serving._DECODE_SLOTS, serving._PREFILL_TOKENS)
+    before = [c.value() for c in counters]
+    eng = engine(params)
+    rids = [eng.submit([1, 2, 3], n) for n in (9, 5, 5)]
+    assert [eng.request(r).max_new_tokens for r in rids] == [9, 5, 5] and eng.request(99) is None
+    passes = 0
+    while eng.step():
+        passes += 1
+    assert [len(eng.done[r]) for r in rids] == [9, 5, 5] and passes == 1
+    assert [c.value() - b for c, b in zip(counters, before)] == [2, 4, 48]
+    assert eng.phase._name is None  # a bare engine leaves no phase open between passes
+    assert eng.request(rids[0]) is None  # done: the engine holds it no longer
+
+
+def read_spans(tmp_path):
+    return [json.loads(line) for p in tmp_path.glob("*.jsonl") for line in open(p).read().splitlines()]
+
+
+def test_span_chain_has_the_four_stages_with_the_stamped_boundaries(params, tmp_path, monkeypatch):
+    tracer = obs_trace.Tracer("trace-1", "serve:0", str(tmp_path))
+    monkeypatch.setattr(obs_trace, "_tracer", tracer)
+    ttfts = observed(monkeypatch, serving_http._TTFT)
+    srv = EngineServer(engine(params)).start()
+    try:
+        out = srv.submit([5, 6, 7], max_tokens=6, request_id="req-7")
+        assert len(finish(out)) == 6
+    finally:
+        assert srv.stop()
+    tracer.close()
+    by_name = {s["name"]: s for s in read_spans(tmp_path)}
+    assert set(by_name) == {"serve.request", "serve.queue", "serve.prefill", "serve.emit", "serve.decode"}
+    root, r = by_name["serve.request"], out.req
+    marks = [out.submitted_s, r.staged_s, r.slot_s, out.submitted_s + ttfts[0][1]]
+    for i, label in enumerate(STAGES):
+        span = by_name["serve." + label]
+        assert span["parent_id"] == root["span_id"] and span["status"] == "ok"
+        assert span["start_ms"] == pytest.approx(marks[i] * 1000, abs=2e-3)
+        assert span["end_ms"] == pytest.approx(marks[i + 1] * 1000, abs=2e-3)
+        assert span["attrs"] == {"rid": "req-7", "prompt_tokens": 3, "prefix_tokens": 0, "slot": r.slot}
+    decode = by_name["serve.decode"]
+    assert decode["parent_id"] == root["span_id"] and decode["start_ms"] >= by_name["serve.emit"]["end_ms"]
+    assert decode["attrs"]["ttft_s"] == pytest.approx(ttfts[0][1], abs=1e-6)
+    assert root["start_ms"] <= by_name["serve.queue"]["start_ms"] + 1 and root["end_ms"] >= decode["end_ms"]
+
+
+def test_a_request_that_dies_before_its_first_token_says_in_which_stage(tmp_path, monkeypatch):
+    tracer = obs_trace.Tracer("trace-1", "serve:0", str(tmp_path))
+    monkeypatch.setattr(obs_trace, "_tracer", tracer)
+    stream = RequestStream(request_id="req-9")
+    stream.submitted_s -= 1.0
+    stream.open_trace()
+    stream.req = serving._Request(0, [1, 2, 3], 4, staged_s=stream.submitted_s + 0.5)
+    stream.finish_trace("error")  # e.g. its deadline passed while its prefill was queued
+    tracer.close()
+    spans = {s["name"]: s for s in read_spans(tmp_path)}
+    assert set(spans) == {"serve.request", "serve.queue", "serve.prefill"}
+    assert (spans["serve.queue"]["status"], spans["serve.prefill"]["status"]) == ("ok", "error")
+    assert spans["serve.queue"]["end_ms"] == spans["serve.prefill"]["start_ms"]
+
+
+# -- the benchmark's readers (plain Python: no job, no trace file) -------------
+def snapshot(ttft, stage_sums, phases, chunks, slots=0, prompt_tokens=0):
+    n = ttft[1]
+    return {"t": 0.0, "metrics": [
+        {"name": "tony_serve_ttft_seconds", "type": "histogram",
+         "samples": [{"labels": {}, "sum": ttft[0], "count": n}]},
+        {"name": "tony_serve_stage_seconds", "type": "histogram",
+         "samples": [{"labels": {"stage": k}, "sum": v, "count": n} for k, v in stage_sums.items()]},
+        {"name": "tony_serve_engine_seconds_total", "type": "counter",
+         "samples": [{"labels": {"phase": k}, "value": v} for k, v in phases.items()]},
+        {"name": "tony_serve_engine_chunks_total", "type": "counter", "samples": [{"labels": {}, "value": chunks}]},
+        {"name": "tony_serve_decode_slots_total", "type": "counter", "samples": [{"labels": {}, "value": slots}]},
+        {"name": "tony_serve_prefill_tokens_total", "type": "counter",
+         "samples": [{"labels": {}, "value": prompt_tokens}]},
+    ]}
+
+
+def metric_args(name):
+    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
+        return json.load(f)["args"]
+
+
+def test_registry_delta_reads_the_change_between_two_snapshots():
+    snap0 = snapshot((10.0, 20), {"queue": 2.0, "prefill": 6.0, "emit": 2.0},
+                     {"intake": 1.0, "admit": 1.0, "dispatch": 2.0, "emit": 1.0, "decode_wait": 14.0,
+                      "prefill_wait": 1.0, "idle": 50.0}, 100, slots=3000, prompt_tokens=40000)
+    snap1 = snapshot((17.0, 30), {"queue": 3.0, "prefill": 10.0, "emit": 4.0},
+                     {"intake": 1.5, "admit": 1.5, "dispatch": 3.0, "emit": 2.0, "decode_wait": 30.0,
+                      "prefill_wait": 2.0, "idle": 51.0}, 200, slots=9200, prompt_tokens=52800)
+    ctx = {"drive": {"snap0": snap0, "snap1": snap1}}
+    means = [registry_delta.read(ctx, **metric_args(m + ".serve"))
+             for m in ("queue_wait_ms", "prefill_stage_ms", "first_emit_ms")]
+    assert means == pytest.approx([100.0, 400.0, 200.0])
+    assert sum(means) == pytest.approx(1000 * (17.0 - 10.0) / (30 - 20))  # the stages close on the TTFT
+    assert registry_delta.read(ctx, **metric_args("chunk_period_ms.serve")) == pytest.approx(200.0)  # 20 s / 100
+    assert registry_delta.read(ctx, **metric_args("host_share_pct.serve")) == pytest.approx(15.0)  # 3 of 20 s
+    assert registry_delta.read(ctx, **metric_args("decode_batch_mean.serve")) == pytest.approx(62.0)  # 6200 / 100
+    assert registry_delta.read(ctx, **metric_args("prefill_tok_per_chunk.serve")) == pytest.approx(128.0)
+    # a program from before the instruments, a missing snapshot, a window with no first token: nothing
+    old = {"t": 0.0, "metrics": snap0["metrics"][:1]}
+    for drive in ({"snap0": old, "snap1": old}, {"snap0": None, "snap1": snap1}, {"snap0": snap1, "snap1": snap1}):
+        assert registry_delta.read({"drive": drive}, **metric_args("queue_wait_ms.serve")) is None
+
+
+def test_gap_by_span_lays_the_gaps_under_the_phase_that_covers_them():
+    # phases as the engine's clock writes them: each ends where the next starts
+    phases = [("dispatch", 4.0, 5.0), ("admit", 0.0, 1.0), ("prefill_wait", 1.0, 3.0), ("admit", 3.0, 4.0),
+              ("decode_wait", 5.0, 9.0), ("emit", 9.0, 10.0)]
+    gaps = [(0.5, 1.5), (3.5, 4.5), (6.0, 6.25), (9.5, 11.0)]
+    by = gap_by_span.gap_seconds_by_phase(gaps, phases)
+    assert by == pytest.approx({"admit": 1.0, "prefill_wait": 0.5, "dispatch": 0.5, "decode_wait": 0.25,
+                                "emit": 0.5, gap_by_span.NONE: 1.0})
+    assert sum(by.values()) == pytest.approx(sum(e - s for s, e in gaps))
+    # a device that is busy except for those gaps, with a `while` over everything (left out, as in reduce.py)
+    busy = [(0.0, 0.5), (1.5, 3.5), (4.5, 6.0), (6.25, 9.5), (11.0, 12.0)]
+    ops = {"/device:TPU:0": [("%fusion.1 = f32[8]{0} fusion(%p)", s, e) for s, e in busy]
+           + [("%while.2 = (s32[]) while(%t), body=%b", 0.0, 12.0)]}
+    got = gap_by_span.summarise(ops, phases)
+    assert got["window_s"] == pytest.approx(12.0) and got["gap_s"] == pytest.approx(by)
+    assert gap_by_span.summarise(ops, []) is None and gap_by_span.summarise({}, phases) is None
+    assert gap_by_span.read({"trace": None}) is None

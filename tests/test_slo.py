@@ -817,7 +817,9 @@ class TestRequestSpanAllocationFree:
         assert obs_trace.start_manual("serve.request", rid="r-1") is None
         stream = RequestStream(request_id="r-1")
         stream.open_trace()
-        stream.begin_stage("serve.prefill")
+        # the stage seconds are computed either way (they feed the histogram);
+        # only the spans are conditional
+        assert [label for label, _ in stream.stages(time.time())] == ["queue"]
         stream.begin_stage("serve.decode", ttft_s=0.1)
         stream.finish_trace("ok")
         assert stream.span is None and stream.stage is None
@@ -827,11 +829,17 @@ class TestRequestSpanAllocationFree:
 
         tracer = obs_trace.Tracer("trace-1", "serve:0", str(tmp_path))
         monkeypatch.setattr(obs_trace, "_tracer", tracer)
+        from tony_tpu.models.serving import _Request
+
         stream = RequestStream(request_id="req-42")
         stream.open_trace()
         root_id = stream.span.span_id
         assert stream.span.attrs["rid"] == "req-42"
-        stream.begin_stage("serve.prefill")
+        # the engine stamps the boundaries; the chain is written from them
+        # at the first fanout (tests/test_serve_trace.py drives a real engine)
+        t0 = stream.submitted_s
+        stream.req = _Request(0, [1, 2, 3], 4, slot=1, staged_s=t0 + 0.01, slot_s=t0 + 0.04)
+        assert [round(s, 6) for _, s in stream.stages(t0 + 0.05)] == [0.01, 0.03, 0.01]
         stream.begin_stage("serve.decode", ttft_s=0.05)
         stream.finish_trace("ok")
         tracer.close()
@@ -839,10 +847,13 @@ class TestRequestSpanAllocationFree:
                  for p in tmp_path.glob("*.jsonl")
                  for line in open(p).read().splitlines()]
         by_name = {s["name"]: s for s in spans}
-        assert {"serve.request", "serve.queue", "serve.prefill",
-                "serve.decode"} <= set(by_name)
-        for stage in ("serve.queue", "serve.prefill", "serve.decode"):
+        assert {"serve.request", "serve.queue", "serve.prefill", "serve.emit",
+                "serve.decode"} == set(by_name)
+        for stage in ("serve.queue", "serve.prefill", "serve.emit", "serve.decode"):
             assert by_name[stage]["parent_id"] == root_id
+        assert by_name["serve.queue"]["end_ms"] == by_name["serve.prefill"]["start_ms"]
+        assert by_name["serve.prefill"]["end_ms"] == by_name["serve.emit"]["start_ms"]
+        assert by_name["serve.emit"]["attrs"]["slot"] == 1
         assert by_name["serve.decode"]["attrs"]["ttft_s"] == 0.05
 
 

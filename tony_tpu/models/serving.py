@@ -32,6 +32,8 @@ dispatch as generate.py serves both.
 from __future__ import annotations
 
 import functools
+import itertools
+import time
 from dataclasses import dataclass, field
 from typing import NamedTuple
 
@@ -51,7 +53,54 @@ from tony_tpu.models.generate import (
     sample_logits,
 )
 from tony_tpu.models.llama import LlamaConfig
+from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.ops import layers as L
+
+# The engine's own account of its time (docs/observability.md "Where a
+# request's TTFT goes"): always on, like the server's instruments beside them.
+_ENGINE_SECONDS = obs_metrics.counter(
+    "tony_serve_engine_seconds_total",
+    "engine-thread seconds by phase of a pass (the phases tile the thread's time)",
+    labelnames=("phase",))
+_CHUNKS = obs_metrics.counter(
+    "tony_serve_engine_chunks_total", "decode chunks dispatched")
+_DECODE_SLOTS = obs_metrics.counter(
+    "tony_serve_decode_slots_total",
+    "running slots summed over dispatched decode chunks (over chunks = mean batch)")
+_PREFILL_TOKENS = obs_metrics.counter(
+    "tony_serve_prefill_tokens_total",
+    "prompt tokens dispatched to prefill, padded to their bucket (reused prefix tokens are not in it)")
+_ADMIT_BLOCKED = obs_metrics.counter(
+    "tony_serve_admit_blocked_total",
+    "engine passes in which a waiting request could not be admitted, by what was short",
+    labelnames=("reason",))
+
+
+PHASES = ("intake", "admit", "prefill_wait", "dispatch", "decode_wait", "emit", "idle")
+_ANNOTATION = {phase: "tony.serve." + phase for phase in PHASES}
+
+
+class _PhaseClock:
+    """Which phase of a pass the engine thread is in. ``to(name)`` closes the
+    open phase and opens the next, so consecutive phases tile the thread's
+    time with nothing between them. Each phase lands on two clocks at once:
+    its seconds in ``tony_serve_engine_seconds_total{phase}``, and a
+    ``tony.serve.<phase>`` annotation in the profiler's trace (a flag check
+    unless a capture runs), on the engine thread's line beside the device's
+    operations. ``to(None)`` closes without opening."""
+
+    def __init__(self):
+        self._name, self._t0, self._ann = None, 0.0, None
+
+    def to(self, name: str | None) -> None:
+        now = time.perf_counter()
+        if self._name is not None:
+            _ENGINE_SECONDS.inc(now - self._t0, phase=self._name)
+            self._ann.__exit__(None, None, None)
+        self._name, self._t0 = name, now
+        if name is not None:
+            self._ann = jax.profiler.TraceAnnotation(_ANNOTATION[name])
+            self._ann.__enter__()
 
 
 class SlotCache(NamedTuple):
@@ -384,6 +433,12 @@ class _Request:
     top_k: int | None = None
     top_p: float | None = None
     cancelled: bool = False           # client gone: retire at the next chunk
+    # where its time to first token went, on the clock the server's TTFT uses
+    # (time.time): left ``pending`` for ``_staged``, given a slot with its
+    # first token on the host; the server closes the third stage at its fanout
+    staged_s: float = 0.0
+    slot_s: float = 0.0
+    prefix_tokens: int = 0            # prompt tokens reused from the prefix cache
 
     def is_done(self, eos_id: int) -> bool:
         """THE termination predicate — budget spent, EOS emitted, or the
@@ -569,6 +624,9 @@ class ContinuousBatcher:
         # (overlap with the in-flight decode chunk)
         self._staged: list[_Staged] = []
         self._slot_len = [0] * num_slots  # host mirror of cache.lengths
+        #: the engine thread's phase clock; the server around the engine
+        #: switches it for its own parts of a pass (intake, fan-out, idle)
+        self.phase = _PhaseClock()
 
     def submit(
         self, prompt, max_new_tokens: int, *,
@@ -615,6 +673,13 @@ class ContinuousBatcher:
             temperature=temperature, top_k=top_k, top_p=top_p,
         ))
         return rid
+
+    def request(self, rid: int) -> _Request | None:
+        """The engine's record of a request it holds (waiting, staged or
+        running), newest first; None once it is done or was never there."""
+        held = itertools.chain(
+            reversed(self.pending), (e.req for e in self._staged), self.running.values())
+        return next((r for r in held if r.rid == rid), None)
 
     def cancel(self, rid: int) -> bool:
         """Drop a request wherever it is (same-thread as step(), like all
@@ -668,6 +733,7 @@ class ContinuousBatcher:
         one-chunk-per-step stall bound honest."""
         while self.pending and len(self._staged) < budget:
             req = self.pending.pop(0)
+            req.staged_s = time.time()
             entry = _Staged(req, init_cache(self.cfg, 1, self.max_len))
             if self.kv == "paged":
                 from tony_tpu.models.paged_cache import prefix_keys
@@ -720,6 +786,7 @@ class ContinuousBatcher:
         )
         entry.pos = len(matched) * self.page_len
         entry.matched = matched
+        entry.req.prefix_tokens = entry.pos
         self.prefix_hit_tokens += entry.pos
         return True
 
@@ -754,6 +821,7 @@ class ContinuousBatcher:
             # padded positions write garbage K/V past Tp; decode masks them
             # out via lengths[slot] = Tp, and causality protects the prefix
             logits, pre = _prefill_padded(self.params, toks, pre, self.cfg)
+            _PREFILL_TOKENS.inc(take + pad)
             pos += take
             if last:
                 last_logits = logits[:, take - 1].astype(jnp.float32)
@@ -799,6 +867,7 @@ class ContinuousBatcher:
             Tp = len(req.prompt)
             if self.kv == "paged":
                 if not self._admit_paged(req, pre, head.matched, head.keys, slot, Tp):
+                    _ADMIT_BLOCKED.inc(reason="pages")
                     break  # pages short: admission waits for retirements
             else:
                 self.cache = _insert_prefill(
@@ -815,9 +884,14 @@ class ContinuousBatcher:
             self._samp_dirty = True
             self._slot_len[slot] = Tp
             req.slot = slot
+            self.phase.to("prefill_wait")  # blocked until the prefill has run
             req.out.append(int(np.asarray(first)[0]))  # host copy (async-warmed)
+            self.phase.to("admit")
+            req.slot_s = time.time()
             self.running[slot] = req
             self._retire_if_done(req)  # 1-token requests finish at admission
+        if not free and (self.pending or self._staged):
+            _ADMIT_BLOCKED.inc(reason="slots")
 
     def _admit_paged(
         self, req, pre, matched: list[int], keys: list[tuple], slot: int, Tp: int
@@ -927,10 +1001,13 @@ class ContinuousBatcher:
 
     def step(self) -> bool:
         """Admit + one decode chunk. Returns True while work remains."""
+        self.phase.to("admit")
         self._admit()
         self._flush_retired()
         if not self.running:
+            self.phase.to(None)
             return bool(self.pending or self._staged)
+        self.phase.to("dispatch")
         # constant chunk height = ONE compiled decode variant; slots whose
         # request finishes mid-chunk simply discard the overshoot tokens
         # (their cache writes clamp at the view's end and the slot is fully
@@ -971,11 +1048,15 @@ class ContinuousBatcher:
                 bucket, self.temperature, self.top_k, samp,
             )
         self.tokens = toks
+        _CHUNKS.inc()
+        _DECODE_SLOTS.inc(len(self.running))
         # overlap: queue prefills for the next admissions while the chunk
         # (already dispatched, still in flight) computes; one speculative
         # stage beyond the currently-free slots covers mid-chunk retirement
         self._stage_prefills(max(len(self._free_slots()), 1))
+        self.phase.to("decode_wait")
         seq_host = np.asarray(seq)  # [h, S]: ONE device→host transfer
+        self.phase.to("emit")
         for slot in self.running:
             self._slot_len[slot] = min(self._slot_len[slot] + h, self.max_len)
         for slot, req in list(self.running.items()):
@@ -989,6 +1070,7 @@ class ContinuousBatcher:
             # drained: zero the final chunk's retirees now — cache.lengths is
             # externally observable and must agree with _slot_len between runs
             self._flush_retired()
+        self.phase.to(None)
         return more
 
     def drain_stream(self) -> dict[int, tuple[list[int], bool]]:

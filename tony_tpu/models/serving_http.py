@@ -69,6 +69,12 @@ _QUEUE_DEPTH = obs_metrics.gauge(
 _TTFT = obs_metrics.histogram(
     "tony_serve_ttft_seconds",
     "time from request submission to its first generated-token fanout")
+_STAGE = obs_metrics.histogram(
+    "tony_serve_stage_seconds",
+    "one request's TTFT by stage, stamped where the state changes: queue "
+    "(submit → staged), prefill (staged → in a slot), emit (in a slot → first "
+    "fanout); the three add up to its tony_serve_ttft_seconds sample",
+    labelnames=("stage",))
 _TOKEN_LATENCY = obs_metrics.histogram(
     "tony_serve_token_latency_seconds",
     "per-token decode latency (chunk interval / tokens in the chunk)")
@@ -98,7 +104,7 @@ class RequestStream:
     up within one decode chunk and frees the slot/pages."""
 
     __slots__ = ("q", "cancelled", "submitted_s", "last_fanout_s",
-                 "request_id", "span", "stage", "defer_finish")
+                 "request_id", "span", "stage", "defer_finish", "req")
 
     def __init__(self, maxsize: int = 0, request_id: str = ""):
         self.q: queue.Queue = queue.Queue(maxsize)
@@ -115,11 +121,14 @@ class RequestStream:
         #: finish_trace after the pages ship (safe: the engine thread never
         #: touches the stream again after its terminal event)
         self.defer_finish = False
-        # per-request span chain (queue → prefill → decode) under one
+        # per-request span chain (queue → prefill → emit → decode) under one
         # serve.request umbrella; both stay None with tracing disabled, so
         # every hot-path hook below is a single attribute check
         self.span = None
         self.stage = None
+        #: the engine's record of this request (its stage stamps), set by the
+        #: engine thread once ``engine.submit`` accepted it
+        self.req = None
 
     def get(self, timeout: float | None = None):
         return self.q.get(timeout=timeout)
@@ -132,12 +141,35 @@ class RequestStream:
 
     # ------------------------------------------------------ request spans
     def open_trace(self) -> None:
-        """Start the serve.request umbrella + its queue stage (no-op — and
-        allocation-free — when tracing is disabled)."""
+        """Start the serve.request umbrella (no-op — and allocation-free —
+        when tracing is disabled). Its TTFT stages are written by
+        :meth:`stages` once the stamps exist, not opened as they pass."""
         self.span = obs_trace.start_manual("serve.request", rid=self.request_id)
-        if self.span is not None:
-            self.stage = obs_trace.start_manual(
-                "serve.queue", parent_id=self.span.span_id)
+
+    def stages(self, end_s: float, status: str = "ok") -> list[tuple[str, float]]:
+        """The request's TTFT stages as (label, seconds), from the engine's
+        stamps: queue, prefill, emit, contiguous from ``submitted_s``; the one
+        it is in ends at ``end_s`` (the first fanout, or where it died). With
+        tracing on, each is also written as a ``serve.<label>`` span."""
+        req = self.req
+        marks = [self.submitted_s, 0.0, 0.0, 0.0]
+        attrs = {"rid": self.request_id}
+        if req is not None:  # None: it died in the inbox, before the engine saw it
+            marks[1:3] = req.staged_s, req.slot_s
+            attrs.update(prompt_tokens=len(req.prompt), prefix_tokens=req.prefix_tokens,
+                         slot=req.slot)
+        out = []
+        for i, label in enumerate(("queue", "prefill", "emit")):
+            current = not marks[i + 1]  # no later stamp: the stage it is in
+            end = end_s if current else marks[i + 1]
+            out.append((label, end - marks[i]))
+            if self.span is not None:
+                obs_trace.end_manual(obs_trace.start_manual(
+                    "serve." + label, parent_id=self.span.span_id, start_s=marks[i], **attrs,
+                ), status if current else "ok", end_s=end)
+            if current:
+                break
+        return out
 
     def begin_stage(self, name: str, **attrs: Any) -> None:
         """End the current stage span and open the next one in the chain."""
@@ -148,6 +180,8 @@ class RequestStream:
 
     def finish_trace(self, status: str = "ok") -> None:
         if self.span is not None:
+            if self.stage is None:  # died before its first token: say where
+                self.stages(time.time(), status)
             obs_trace.end_manual(self.stage, status)
             obs_trace.end_manual(self.span, status)
             self.span = self.stage = None
@@ -350,6 +384,7 @@ class EngineServer:
             if self._on_fatal is not None:
                 self._on_fatal()
         finally:
+            self.engine.phase.to(None)  # a pass that raised left its phase open
             # refuse anything still queued (or enqueued mid-teardown)
             with self._admit_lock:
                 self._draining.set()
@@ -413,6 +448,7 @@ class EngineServer:
         eng = self.engine
         carry = None  # item pulled by the idle wait — admitted FIRST (FIFO)
         while True:
+            eng.phase.to("intake")
             while True:
                 if carry is not None:
                     prompt, max_tokens, sampling, deadline, out = carry
@@ -440,13 +476,14 @@ class EngineServer:
                     out.finish_trace("error")
                     continue
                 self._streams[rid] = out
-                out.begin_stage("serve.prefill")
+                out.req = eng.request(rid)
                 if deadline:
                     self._deadlines[rid] = deadline
             self._sweep_cancellations()
             self._drain_control()
             _QUEUE_DEPTH.set(self._queue_depth())
-            had_work = eng.step()
+            had_work = eng.step()  # admit, prefill_wait, dispatch, decode_wait, emit
+            eng.phase.to("emit")
             # export the engine's prefix-reuse win as a REAL instrument, not
             # a /stats-payload-only field: the loadtest harness and the
             # portal read the registry, and "reuse happened" must be
@@ -469,6 +506,8 @@ class EngineServer:
                         # worst-offender exemplars: id-carrying requests link
                         # a burning TTFT SLO straight to their trace
                         _TTFT.observe(ttft, exemplar=out.request_id or None)
+                        for label, seconds in out.stages(now_s):
+                            _STAGE.observe(seconds, stage=label)
                         out.begin_stage("serve.decode", ttft_s=round(ttft, 6))
                     out.last_fanout_s = now_s
                 self.tokens_out += len(toks)
@@ -498,7 +537,9 @@ class EngineServer:
                         out.cancel()
             if not had_work:
                 if self._draining.is_set():
+                    eng.phase.to(None)
                     return
+                eng.phase.to("idle")
                 # idle: block until the next request (or drain) arrives; the
                 # pulled item is carried to the admission pass directly —
                 # re-queuing it would reorder it behind later arrivals

@@ -88,12 +88,14 @@ def maybe_span(name: str, kind: str = "internal", **attrs: Any):
 
 
 def start_manual(name: str, kind: str = "internal", parent_id: str | None = None,
-                 **attrs: Any) -> "Span | None":
+                 start_s: float | None = None, **attrs: Any) -> "Span | None":
     """A span NOT bound to the thread's context — for lifecycles that cross
     event-loop iterations (one serve request's queue → prefill → decode
     chain lives across many engine steps). Returns None when tracing is off:
     the disabled hot path stays one None check, no Span allocation (same
-    contract as :func:`maybe_span`). Pair with :func:`end_manual`."""
+    contract as :func:`maybe_span`). Pair with :func:`end_manual`.
+    ``start_s`` (and ``end_manual``'s ``end_s``), in ``time.time()`` seconds,
+    place a span whose boundaries were stamped before it was written."""
     tr = _tracer
     if tr is None:
         return None
@@ -101,19 +103,22 @@ def start_manual(name: str, kind: str = "internal", parent_id: str | None = None
         cur = _CURRENT.get()
         parent_id = cur.span_id if cur is not None else tr.root_parent
     span = Span(name, tr.trace_id, _new_span_id(), parent_id, kind, tr.identity)
+    if start_s is not None:
+        span.start_ms = start_s * 1000.0
     if attrs:
         span.attrs.update(attrs)
     return span
 
 
-def end_manual(span: "Span | None", status: str = "ok", **attrs: Any) -> None:
+def end_manual(span: "Span | None", status: str = "ok", end_s: float | None = None,
+               **attrs: Any) -> None:
     """Finish and sink a :func:`start_manual` span (no-op on None)."""
     tr = _tracer
     if tr is None or span is None:
         return
     if attrs:
         span.attrs.update(attrs)
-    span.end_ms = time.time() * 1000.0
+    span.end_ms = (time.time() if end_s is None else end_s) * 1000.0
     span.status = status
     tr._write(span)
 
