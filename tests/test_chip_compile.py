@@ -15,6 +15,7 @@ test skips from there where it cannot be described, and the compiles run in
 the test's own process.
 """
 
+import contextlib
 import functools
 
 import jax
@@ -44,16 +45,25 @@ def topo():
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
 
 
-@pytest.fixture()
-def chip(topo, monkeypatch):
+@contextlib.contextmanager
+def _compiling_for(topo):
     """One described chip to compile for; the kernels compile for real (the
     CPU suite's interpret switch is read when a kernel is traced) and nothing
     is written to a persistent compile cache that could not be read back."""
-    monkeypatch.delenv("TONY_PALLAS_INTERPRET", raising=False)
-    cache_was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
-    jax.config.update("jax_enable_compilation_cache", cache_was)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.delenv("TONY_PALLAS_INTERPRET", raising=False)
+        cache_was = jax.config.jax_enable_compilation_cache
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            yield SingleDeviceSharding(topo.devices[0])
+        finally:
+            jax.config.update("jax_enable_compilation_cache", cache_was)
+
+
+@pytest.fixture()
+def chip(topo):
+    with _compiling_for(topo) as one_chip:
+        yield one_chip
 
 
 def _s(shape, dtype, sharding):
@@ -119,20 +129,86 @@ class TestDecodeAttentionAtServeShapes:
 
     @pytest.mark.parametrize("page_len", [256, 128, 32])
     def test_paged_decode_with_chunk_staging(self, chip, page_len):
-        """The engine's decode step: paged pool + the chunk's staged columns."""
+        """The engine's decode step: the whole paged pool, a traced layer
+        index and the chunk's staged columns."""
         q, lengths, cur = self._common(chip)
         max_pages = MAX_LEN // page_len
-        pool = _s((SLOTS * max_pages + 1, HKV, page_len, DH), jnp.bfloat16, chip)
+        pool = _s((2, SLOTS * max_pages + 1, HKV, page_len, DH), jnp.bfloat16, chip)
         table = _s((SLOTS, max_pages), jnp.int32, chip)
         staged = _s((SLOTS, 8, HKV, DH), jnp.bfloat16, chip)
+        layer = _s((), jnp.int32, chip)
 
-        def fn(q, kp, vp, lengths, table, cur_k, cur_v, sk, sv, count):
+        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
             return DA.paged_decode_attention(
-                q, kp, vp, lengths, table, cur_k=cur_k, cur_v=cur_v,
+                q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v,
                 staged_k=sk, staged_v=sv, staged_count=count)
 
-        assert _kernel_calls(fn, q, pool, pool, lengths, table, cur, cur,
+        assert _kernel_calls(fn, q, pool, pool, lengths, table, layer, cur, cur,
                              staged, staged, lengths) == 1
+
+
+class TestPagedDecodeChunkTouchesThePoolOnlyByPage:
+    """The whole `serving.decode_steps` program of a paged engine at Mistral
+    widths (2 layers, 96 pages of 256, 64 slots, chunk 8: the serving cells'
+    shapes at a quarter of their pool). Inside the chunk the pool is an
+    operand that stays where it is: the kernel reads pages of it through a
+    layer index and the chunk's one write lands in place, so nothing in the
+    compiled program has the pool's shape, or one layer's pool's shape, but
+    the pool itself. A slice of a layer's pool handed to the kernel was 38-41%
+    of the serving chip and the scatter's transposes another 8-10% (PERF.md,
+    PR 27); this holds the compiler to their absence, at no chip time."""
+
+    LAYERS, PAGES, PAGE_LEN, CHUNK, MAX_PAGES = 2, 96, 256, 8, 16
+
+    @pytest.fixture(scope="class")
+    def compiled(self, topo):
+        with _compiling_for(topo) as chip:
+            return self._compile(chip)
+
+    def _compile(self, chip):
+        from tony_tpu.models import serving
+        from tony_tpu.models.paged_cache import PagedCache
+
+        cfg = llama.LlamaConfig(
+            vocab_size=32_000, d_model=4096, n_layers=self.LAYERS, n_heads=32, n_kv_heads=8,
+            d_ff=14_336, max_seq=4096, rope_theta=10_000.0, sliding_window=4096)
+        on_chip = functools.partial(jax.tree.map, lambda a: _s(a.shape, a.dtype, chip))
+        params = on_chip(jax.eval_shape(lambda: llama.init(jax.random.PRNGKey(0), cfg)))
+        pool = _s((self.LAYERS, self.PAGES, cfg.n_kv_heads, self.PAGE_LEN, cfg.head_dim),
+                  jnp.bfloat16, chip)
+        cache = PagedCache(pool, pool, _s((SLOTS,), jnp.int32, chip),
+                           _s((SLOTS, self.MAX_PAGES), jnp.int32, chip))
+        samp = (_s((SLOTS,), jnp.float32, chip), _s((SLOTS,), jnp.int32, chip),
+                _s((SLOTS,), jnp.float32, chip))
+        key = on_chip(jax.eval_shape(lambda: jax.random.PRNGKey(0)))
+        return serving.decode_steps.lower(
+            params, cache, _s((SLOTS,), jnp.int32, chip), key, cfg, self.CHUNK,
+            0.0, 0, "ragged", samp).compile()
+
+    def test_no_pool_shaped_value_but_the_pool(self, compiled):
+        import re
+
+        pool = f"bf16[{self.LAYERS},{self.PAGES},{HKV},{self.PAGE_LEN},{DH}]"
+        one_layer = f"bf16[{self.PAGES},{HKV},{self.PAGE_LEN},{DH}]"
+        # an instruction line: `[ROOT ]%name = <result shape> opcode(operands...)`
+        made = re.compile(r"^\s*(?:ROOT )?%?[\w.-]+ = (\(.*?\)|\S+) ([\w-]+)\(", re.M)
+        text = compiled.as_text()
+        # the pool passes through as it is: parameters, tuples, the loops that
+        # carry it, and the updates that alias it. A `fusion` of the pool's shape
+        # is the two pools' updates fused into one call; its body's lines are
+        # read here like any others, so a fused copy or select would still show
+        carries = {"parameter", "get-tuple-element", "tuple", "while", "dynamic-update-slice", "fusion"}
+        offenders = [
+            (name, shape) for shape, name in made.findall(text)
+            # by opcode: the text also carries source names, a test's among them
+            if (name not in carries and pool in shape) or one_layer in shape or "scatter" in name
+        ]
+        assert not offenders, offenders[:10]
+        assert text.count("tpu_custom_call") >= 1  # the Mosaic kernel is in there
+
+    def test_temporaries_stay_under_one_layers_pool(self, compiled):
+        one_layer = self.PAGES * HKV * self.PAGE_LEN * DH * 2  # 50 MB
+        assert compiled.memory_analysis().temp_size_in_bytes < one_layer
 
 
 class TestOtherKernels:

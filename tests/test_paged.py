@@ -64,8 +64,8 @@ class TestPagedKernel:
                 window=window, chunk=PLEN,
             )
             got = paged_decode_attention(
-                q, jnp.asarray(kp), jnp.asarray(vp), lengths, jnp.asarray(pt),
-                cur_k=cur_k, cur_v=cur_v, window=window,
+                q, jnp.asarray(kp)[None], jnp.asarray(vp)[None], lengths, jnp.asarray(pt),
+                jnp.int32(0), cur_k=cur_k, cur_v=cur_v, window=window,
             )
             np.testing.assert_allclose(
                 np.asarray(got), np.asarray(want), atol=2e-5, rtol=2e-5,
@@ -79,15 +79,182 @@ class TestPagedKernel:
 
         S, H, Hkv, Dh = 1, 2, 1, 128
         for plen in (4, 12):
-            kp = jnp.zeros((2, Hkv, plen, Dh), jnp.float32)
+            kp = jnp.zeros((1, 2, Hkv, plen, Dh), jnp.float32)
             with pytest.raises(ValueError, match="multiple of 8"):
                 paged_decode_attention(
                     jnp.zeros((S, H, Dh), jnp.float32), kp, kp,
                     jnp.zeros((S,), jnp.int32),
-                    jnp.zeros((S, 1), jnp.int32),
+                    jnp.zeros((S, 1), jnp.int32), jnp.int32(0),
                     cur_k=jnp.zeros((S, Hkv, Dh), jnp.float32),
                     cur_v=jnp.zeros((S, Hkv, Dh), jnp.float32),
                 )
+
+    @pytest.mark.parametrize("window", [0, 100])
+    @pytest.mark.parametrize("staged", [False, True])
+    def test_layer_index_reads_that_layers_pages(self, staged, window):
+        """The operand is the whole pool and a layer index: layer ``l`` of an
+        L=3 pool gives, bit for bit, what a one-layer pool holding only that
+        layer's pages gives at index 0 — so the index picks the layer and
+        nothing else about the read changed."""
+        from tony_tpu.ops.decode_attention import paged_decode_attention
+
+        L, S, H, Hkv, Dh, PLEN, max_pages, W = 3, 3, 4, 2, 128, 64, 4, 8
+        P = S * max_pages + 2
+        ks = jax.random.split(jax.random.PRNGKey(7), 7)
+        q = jax.random.normal(ks[0], (S, H, Dh), jnp.float32)
+        kp = jax.random.normal(ks[1], (L, P, Hkv, PLEN, Dh), jnp.float32)
+        vp = jax.random.normal(ks[2], (L, P, Hkv, PLEN, Dh), jnp.float32)
+        cur_k = jax.random.normal(ks[3], (S, Hkv, Dh), jnp.float32)
+        cur_v = jax.random.normal(ks[4], (S, Hkv, Dh), jnp.float32)
+        lengths = jnp.array([0, 129, 250], jnp.int32)
+        pt = jnp.asarray(np.random.default_rng(1).permutation(P)[: S * max_pages]
+                         .reshape(S, max_pages).astype(np.int32))
+        extra = {}
+        if staged:
+            extra = dict(
+                staged_k=jax.random.normal(ks[5], (S, W, Hkv, Dh), jnp.float32),
+                staged_v=jax.random.normal(ks[6], (S, W, Hkv, Dh), jnp.float32),
+                staged_count=jnp.full((S,), 5, jnp.int32),
+            )
+        outs = []
+        for l in range(L):
+            whole = paged_decode_attention(
+                q, kp, vp, lengths, pt, jnp.int32(l), cur_k=cur_k, cur_v=cur_v,
+                window=window, **extra)
+            alone = paged_decode_attention(
+                q, kp[l][None], vp[l][None], lengths, pt, jnp.int32(0),
+                cur_k=cur_k, cur_v=cur_v, window=window, **extra)
+            np.testing.assert_array_equal(np.asarray(whole), np.asarray(alone), err_msg=f"layer {l}")
+            outs.append(np.asarray(whole))
+        assert not np.array_equal(outs[0], outs[1])  # the layers do differ
+
+    def test_rejects_one_layers_slice(self):
+        """One signature: a [P, Hkv, page_len, Dh] slice is refused by name,
+        not read as a pool of P layers."""
+        from tony_tpu.ops.decode_attention import paged_decode_attention
+
+        kp = jnp.zeros((2, 1, 8, 128), jnp.float32)
+        with pytest.raises(ValueError, match="whole pool"):
+            paged_decode_attention(
+                jnp.zeros((1, 2, 128), jnp.float32), kp, kp, jnp.zeros((1,), jnp.int32),
+                jnp.zeros((1, 1), jnp.int32), jnp.int32(0),
+                cur_k=jnp.zeros((1, 1, 128), jnp.float32),
+                cur_v=jnp.zeros((1, 1, 128), jnp.float32),
+            )
+
+
+# ---------------------------------------------------------------------------
+# The chunk's one pool write: in place, by page, equal to the plain scatter
+# ---------------------------------------------------------------------------
+class TestChunkWrite:
+    L, P, HKV, DH = 3, 12, 2, 8
+    PLEN, N, MAX_PAGES = SHORT = (16, 8, 3)  # (page_len, n, max_pages): the engine's case, n <= page_len
+    MAX_T = MAX_PAGES * PLEN
+    LONG = (8, 12, 5)                        # a chunk longer than a page
+
+    @staticmethod
+    @jax.jit
+    def _plain_scatter(pk, stage, len0, page_table):
+        """The form ``decode_steps`` had before the in-place write, kept here
+        as the plain reference: two index arrays, one scatter."""
+        Lc, S, n, Hkv, Dh = stage.shape
+        page_len = pk.shape[3]
+        max_t = page_table.shape[1] * page_len
+        steps = jnp.arange(n, dtype=jnp.int32)[None, :]
+        pos = jnp.where(len0[:, None] > 0, jnp.minimum(len0[:, None] + steps, max_t - 1), 0)
+        pages = jnp.take_along_axis(page_table, pos // page_len, axis=1).reshape(-1)
+        offs = (pos % page_len).reshape(-1)
+        cols = stage.transpose(1, 2, 0, 3, 4).reshape(S * n, Lc, Hkv, Dh)
+        return pk.at[:, pages, :, offs, :].set(cols)
+
+    @pytest.mark.parametrize("geometry,len0_case", [
+        pytest.param(SHORT, PLEN, id="page_start"),              # offset 0 of its second page
+        pytest.param(SHORT, PLEN - N, id="fits_to_page_end"),    # offset page_len - n: the last that fits
+        pytest.param(SHORT, 2 * PLEN - 3, id="crossing"),        # 3 rows in one page, 5 in the next
+        pytest.param(SHORT, PLEN - 1, id="last_row"),            # 1 row, then 7 in the next
+        pytest.param(SHORT, 0, id="idle"),
+        pytest.param(SHORT, MAX_T - 1, id="clamped_at_end"),     # one live position, 7 steps past the end
+        pytest.param(SHORT, MAX_T - 4, id="overshoot"),          # 4 live positions, 4 steps past the end
+        pytest.param(LONG, 13, id="three_pages"),                # 3 + 8 + 1 rows in three pages
+    ])
+    def test_in_place_write_equals_plain_scatter(self, geometry, len0_case):
+        from tony_tpu.models.paged_cache import write_decode_chunk
+
+        L, P, Hkv, Dh = self.L, self.P, self.HKV, self.DH
+        PLEN, n, max_pages = geometry
+        MAX_T = max_pages * PLEN
+        # the slot under test between two bystanders: one mid-page, one idle
+        len0 = np.array([PLEN + 5, len0_case, 0], np.int32)
+        S = len(len0)
+        rng = np.random.default_rng(5)
+        own = rng.permutation(np.arange(1, P))      # page 0 is never a slot's own
+        pt = np.zeros((S, max_pages), np.int32)
+        pt[0], pt[1] = own[:max_pages], own[max_pages:2 * max_pages]
+        if len0_case == 0:
+            pt[1] = 0                                # a flushed slot's row is zeros
+        pk = rng.standard_normal((L, P, Hkv, PLEN, Dh)).astype(np.float32)
+        pv = rng.standard_normal((L, P, Hkv, PLEN, Dh)).astype(np.float32)
+        sk = rng.standard_normal((L, S, n, Hkv, Dh)).astype(np.float32)
+        sv = rng.standard_normal((L, S, n, Hkv, Dh)).astype(np.float32)
+
+        got_k, got_v = jax.jit(write_decode_chunk)(
+            jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(sk), jnp.asarray(sv),
+            jnp.asarray(len0), jnp.asarray(pt))
+        got_k, got_v = np.asarray(got_k), np.asarray(got_v)
+        ref_k = np.asarray(self._plain_scatter(
+            jnp.asarray(pk), jnp.asarray(sk), jnp.asarray(len0), jnp.asarray(pt)))
+
+        # every position a request can read: step j of a live slot, inside max_len
+        want_k, want_v = pk.copy(), pv.copy()
+        live = np.zeros((P, PLEN), bool)
+        for s in range(S):
+            for j in range(n):
+                q = int(len0[s]) + j
+                if len0[s] > 0 and q < MAX_T:
+                    page, off = pt[s, q // PLEN], q % PLEN
+                    want_k[:, page, :, off] = sk[:, s, j]
+                    want_v[:, page, :, off] = sv[:, s, j]
+                    # the plain scatter piles the steps past the end onto the last
+                    # position too; which of them it keeps there is not defined
+                    live[page, off] = q < MAX_T - 1 or j == n - 1
+        assert live.sum() == sum(
+            min(n, MAX_T - int(x)) - (int(x) + n > MAX_T) for x in len0 if x > 0)
+        np.testing.assert_array_equal(got_k.transpose(1, 3, 0, 2, 4)[live],
+                                      ref_k.transpose(1, 3, 0, 2, 4)[live])
+        # and nothing else moved: other slots' pages, the rest of its own pages
+        # and the sacrificial page are byte for byte what they were
+        np.testing.assert_array_equal(got_k, want_k)
+        np.testing.assert_array_equal(got_v, want_v)
+        assert not live[0].any() and np.array_equal(got_k[:, 0], pk[:, 0])
+
+
+
+    def test_single_step_and_chunk_of_one_write_the_same_pool(self):
+        """The two paged programs over `_decode_one`: `decode_step` writes its
+        one column at once (a chunk of one), `decode_steps` stages it and
+        writes after the scan. One step either way: same token, same pool,
+        through the same read (whole pool + layer index)."""
+        from tony_tpu.models import serving
+        from tony_tpu.models.paged_cache import PagedCache, init_paged_cache
+
+        params, cfg, S, plen = _params(), LLAMA_TINY, 3, 16
+        blank = init_paged_cache(cfg, S, 64, plen, 9)
+        ks = jax.random.split(jax.random.PRNGKey(11), 2)
+
+        def cache():  # fresh buffers each call: both programs donate theirs
+            return PagedCache(
+                jax.random.normal(ks[0], blank.k.shape, jnp.float32).astype(blank.k.dtype),
+                jax.random.normal(ks[1], blank.v.shape, jnp.float32).astype(blank.v.dtype),
+                jnp.array([plen - 1, 0, plen + 3], jnp.int32),      # a page's last row, idle, mid-page
+                jnp.array([[1, 2, 3, 4], [0, 0, 0, 0], [5, 6, 7, 8]], jnp.int32))
+
+        tokens, key = jnp.array([3, 4, 5], jnp.int32), jax.random.PRNGKey(0)
+        nxt1, one = serving.decode_step(params, cache(), tokens, key, cfg)
+        nxt8, _, chunk = serving.decode_steps(params, cache(), tokens, key, cfg, 1)
+        assert nxt1[0] == nxt8[0] and nxt1[2] == nxt8[2]      # live slots
+        np.testing.assert_array_equal(np.asarray(one.lengths), np.asarray(chunk.lengths))
+        np.testing.assert_array_equal(np.asarray(one.k, np.float32), np.asarray(chunk.k, np.float32))
+        np.testing.assert_array_equal(np.asarray(one.v, np.float32), np.asarray(chunk.v, np.float32))
 
 
 # ---------------------------------------------------------------------------

@@ -290,3 +290,78 @@ def insert_paged_prefill(
         lengths=cache.lengths.at[slot].set(true_len),
         page_table=cache.page_table.at[slot].set(pt_row),
     )
+
+
+def write_decode_chunk(
+    pk: jax.Array, pv: jax.Array,        # pools [L, P, Hkv, page_len, Dh] (donated by the caller's jit)
+    stage_k: jax.Array, stage_v: jax.Array,  # [L, S, n, Hkv, Dh] — the chunk's staged columns
+    len0: jax.Array,                     # [S] int32 — each slot's length when the chunk began
+    page_table: jax.Array,               # [S, max_pages]
+):
+    """A decode chunk's ONE pool write, in place: staged column (slot s, step
+    j) lands at position ``len0[s] + j`` of slot s. Called inside
+    ``serving.decode_steps``' jit, which donates the pools.
+
+    A slot's ``n`` positions lie in at most two pages (``n <= page_len``; a
+    longer chunk touches more, a window a page), so each slot is two
+    read-modify-write windows of ``n`` rows, one a page: slice the window
+    out of the page, take the staged row wherever the window's position is
+    one of the chunk's, and write it back. The window is shaped like the
+    pool (``[L, 1, Hkv, n, Dh]`` at ``(0, page, 0, offset, 0)``), so every
+    ``dynamic_update_slice`` aliases the donated pool with no transpose, and
+    nothing of the pool's size is made. (The one-shot form,
+    ``pool.at[:, pages, :, offs, :].set(cols)``, has index arrays on two
+    dimensions that are not adjacent: XLA transposes the whole pool, scatters
+    and transposes it back, four pool-sized copies a chunk. A loop of one-ROW
+    updates makes it relayout the pool around the loop instead.)
+
+    Edges. A window never runs off its page: the first is pulled back to
+    ``page_len - n`` where it would (``dynamic_update_slice`` clamps its
+    start and does not fail, so the rows are placed by position, not by
+    trust) and rewrites rows of its own page unchanged. An idle slot
+    (``len0 == 0``) and positions past ``maxT - 1`` take no staged row: both
+    windows write back what they read. A write therefore lands only in the
+    pages a live slot's own table gives for positions ``len0 ..
+    len0 + n - 1``: past the prompt, so never in a shared prefix page."""
+    L, _, Hkv, page_len, Dh = pk.shape
+    S, n = stage_k.shape[1:3]
+    max_pages = page_table.shape[1]
+    max_t = max_pages * page_len
+    w = min(n, page_len)                              # rows a window
+    windows = (n + page_len - 2) // page_len + 1      # pages n positions can touch
+    start = jnp.minimum(len0, max_t - 1)
+    first_page = start // page_len
+    rows = jnp.arange(w, dtype=jnp.int32)
+    window = (L, 1, Hkv, w, Dh)
+
+    def padded(stage):
+        # [L, S, Hkv, 3n, Dh]: the pool's axis order, n rows of padding on
+        # either side, so a window's staged rows are one slice at n + shift
+        return jnp.pad(stage.transpose(0, 1, 3, 2, 4), ((0, 0), (0, 0), (0, 0), (n, n), (0, 0)))
+
+    stage_k, stage_v = padded(stage_k), padded(stage_v)
+
+    def write_slot(s, kv):
+        for i in range(windows):
+            logical = first_page[s] + i
+            off = jnp.minimum(start[s] % page_len, page_len - w) if i == 0 else jnp.int32(0)
+            first = logical * page_len + off          # position of the window's row 0
+            shift = jnp.clip(first - len0[s], -n, n)  # staged step of the window's row 0
+            take = (len0[s] > 0) & (rows + shift >= 0) & (rows + shift < n) & (first + rows < max_t)
+            take = take[None, None, None, :, None]
+            at = (0, page_table[s, jnp.minimum(logical, max_pages - 1)], 0, off, 0)
+            kv = tuple(
+                jax.lax.dynamic_update_slice(
+                    pool,
+                    jnp.where(
+                        take,
+                        jax.lax.dynamic_slice(stage, (0, s, 0, n + shift, 0), window),
+                        jax.lax.dynamic_slice(pool, at, window),
+                    ),
+                    at,
+                )
+                for pool, stage in zip(kv, (stage_k, stage_v))
+            )
+        return kv
+
+    return jax.lax.fori_loop(0, S, write_slot, (pk, pv))

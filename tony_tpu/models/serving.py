@@ -141,7 +141,7 @@ def _decode_one(
     pool + per-slot page tables, models/paged_cache.py): the trace-time
     branch picks the attention read (per-slot slab DMA vs page-indirected
     DMA — same kernel body) and the write (per-slot column scatter vs
-    (page, offset) scatter). Everything else — projections, RoPE, FFN,
+    in-place page windows, paged_cache.write_decode_chunk). Everything else — projections, RoPE, FFN,
     sampling — is identical, so the two cache layouts cannot drift.
     """
     from tony_tpu.models.paged_cache import PagedCache
@@ -160,47 +160,50 @@ def _decode_one(
     pos = jnp.minimum(cache.lengths, maxT - 1)                      # write position
     x = _embed_lookup(params["embed"], tokens[:, None], cfg.jdtype)  # [S, 1, D]
 
-    # The big cache tensors are scan XS (read-only): attention sees the OLD
+    # The cache is READ-ONLY inside the layer scan: attention sees the OLD
     # cache plus the current token's K/V explicitly, and the scan emits only
     # the tiny [S, Hkv, Dh] new K/V per layer. Carrying the updated cache
     # through the scan instead (the first r3 design) stacked a full cache
     # copy as scan ys EVERY token — measured −32% decode tok/s at 64 slots.
+    # DENSE: the cache tensors are scan xs, one [S, Hkv, maxT, Dh] slab a
+    # layer. PAGED: the scan's xs is the layer INDEX and the kernel takes the
+    # whole pool with it — a layer's pool as xs is an operand of a Mosaic
+    # call, which XLA gives a buffer of its own: a copy of the layer's pool,
+    # K and V, every layer of every step (38-41% of the serving chip: PR 27).
     def layer(x, inputs):
-        if staged is not None:
-            lp, ck, cv, skl, svl = inputs  # + this layer's staged window
-        else:
-            lp, ck, cv = inputs  # dense: ck/cv [S, Hkv, maxT, Dh]; paged: [P, Hkv, page_len, Dh]
+        # rest — dense: this layer's (ck, cv); paged: (layer index[, its staged k, v])
+        lp, *rest = inputs
         h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         q = _mm(h, lp["wq"]).reshape(S, 1, H, Dh).transpose(0, 2, 1, 3)
         k = _mm(h, lp["wk"]).reshape(S, 1, Hkv, Dh).transpose(0, 2, 1, 3)
         v = _mm(h, lp["wv"]).reshape(S, 1, Hkv, Dh).transpose(0, 2, 1, 3)
         q = L.apply_rope(q, cos, sin, positions=pos[:, None])
         k = L.apply_rope(k, cos, sin, positions=pos[:, None])
-        k1 = k[:, :, 0].astype(ck.dtype)                             # [S, Hkv, Dh]
-        v1 = v[:, :, 0].astype(cv.dtype)
+        k1 = k[:, :, 0].astype(cache.k.dtype)                        # [S, Hkv, Dh]
+        v1 = v[:, :, 0].astype(cache.v.dtype)
         if paged:
             from tony_tpu.ops.decode_attention import paged_decode_attention
 
             extra = {}
             if staged is not None:
                 extra = dict(
-                    staged_k=skl, staged_v=svl,
+                    staged_k=rest[1], staged_v=rest[2],
                     staged_count=jnp.broadcast_to(staged[2], (S,)),
                 )
             o = paged_decode_attention(
-                q[:, :, 0], ck, cv, pos, cache.page_table, cur_k=k1, cur_v=v1,
-                window=cfg.sliding_window, **extra,
+                q[:, :, 0], cache.k, cache.v, pos, cache.page_table, rest[0],
+                cur_k=k1, cur_v=v1, window=cfg.sliding_window, **extra,
             )
         elif attn == "ragged":
             from tony_tpu.ops.decode_attention import ragged_decode_attention
 
             o = ragged_decode_attention(
-                q[:, :, 0], ck, cv, pos, cur_k=k1, cur_v=v1,
+                q[:, :, 0], *rest, pos, cur_k=k1, cur_v=v1,
                 window=cfg.sliding_window,
             )
         else:
             o = _masked_slot_attention(
-                q[:, :, 0], ck, cv, pos, H // Hkv, window=cfg.sliding_window,
+                q[:, :, 0], *rest, pos, H // Hkv, window=cfg.sliding_window,
                 cur_k=k1, cur_v=v1,
             )
         x = x + _mm(o.reshape(S, 1, H * Dh), lp["wo"])
@@ -208,9 +211,12 @@ def _decode_one(
         x = x + _ffn_with_cache(h, lp, cfg)
         return x, (k1, v1)
 
-    xs = (params["layers"], cache.k, cache.v)
-    if staged is not None:
-        xs = xs + (staged[0], staged[1])  # per-layer staged windows
+    if paged:
+        xs = (params["layers"], jnp.arange(cache.k.shape[0], dtype=jnp.int32))
+        if staged is not None:
+            xs = xs + (staged[0], staged[1])  # per-layer staged windows
+    else:
+        xs = (params["layers"], cache.k, cache.v)
     x, (ks_new, vs_new) = jax.lax.scan(layer, x, xs)
     x = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _mm(x[:, 0], params["lm_head"]).astype(jnp.float32)     # [S, V]
@@ -229,32 +235,16 @@ def _decode_one(
         # deferred-write mode (decode_steps' paged chunk): this step's
         # columns go to the chunk staging, the POOL is untouched — the
         # per-token page write measured −24%/chunk as 2·S serial dus
-        from tony_tpu.models.paged_cache import PagedCache as _PC
-
-        return nxt, _PC(cache.k, cache.v, new_len, cache.page_table), ks_new, vs_new
+        return nxt, cache._replace(lengths=new_len), ks_new, vs_new
     if paged:
-        # write each slot's [L, Hkv, Dh] column at its (physical page,
-        # in-page offset) via a fori chain of dynamic_update_slice — XLA
-        # keeps these in-place on the donated pool, where the equivalent
-        # two-index-array scatter measured +24% on the whole decode chunk
-        # (it materializes gather/scatter traffic instead of aliasing)
-        page_len = cache.k.shape[3]
-        pages = cache.page_table[jnp.arange(S), pos // page_len]     # [S]
-        offs = pos % page_len
+        # a single step is a chunk of one: the same in-place write
+        from tony_tpu.models.paged_cache import write_decode_chunk
 
-        def write_slot_page(s, kv):
-            ks, vs = kv
-            kcol = jax.lax.dynamic_slice_in_dim(ks_new, s, 1, axis=1)  # [L,1,Hkv,Dh]
-            vcol = jax.lax.dynamic_slice_in_dim(vs_new, s, 1, axis=1)
-            idx = (0, pages[s], 0, offs[s], 0)
-            ks = jax.lax.dynamic_update_slice(ks, kcol[:, 0][:, None, :, None, :], idx)
-            vs = jax.lax.dynamic_update_slice(vs, vcol[:, 0][:, None, :, None, :], idx)
-            return ks, vs
-
-        ks, vs = jax.lax.fori_loop(0, S, write_slot_page, (cache.k, cache.v))
-        from tony_tpu.models.paged_cache import PagedCache as _PC
-
-        return nxt, _PC(ks, vs, new_len, cache.page_table)
+        ks, vs = write_decode_chunk(
+            cache.k, cache.v, ks_new[:, :, None], vs_new[:, :, None],
+            cache.lengths, cache.page_table,
+        )
+        return nxt, cache._replace(k=ks, v=vs, lengths=new_len)
 
     # single write: scatter each slot's [L, Hkv, Dh] column at its position
     # (the donated cache updates in place — no full-cache copy per token)
@@ -293,8 +283,13 @@ def decode_steps(
     land in a chunk staging buffer (one contiguous write per step), the
     kernel folds the staged window from VMEM, and the page pool is written
     ONCE per chunk — the per-token page scatter (2·S serial updates into
-    dynamic (page, offset) targets) measured −24% on the whole chunk."""
-    from tony_tpu.models.paged_cache import PagedCache
+    dynamic (page, offset) targets) measured −24% on the whole chunk.
+    Inside the chunk the pool is only ever touched BY PAGE: the kernel reads
+    the whole pool through a layer index, and the one write a chunk lands in
+    place on the donated pool (paged_cache.write_decode_chunk). Nothing in
+    the program has the pool's shape or one layer's pool's shape but the
+    pool itself (tests/test_chip_compile.py holds the compiled HLO to it)."""
+    from tony_tpu.models.paged_cache import PagedCache, write_decode_chunk
 
     if not isinstance(cache, PagedCache):
 
@@ -308,9 +303,8 @@ def decode_steps(
         (cache, toks), seq = jax.lax.scan(body, (cache, tokens), jax.random.split(key, n))
         return toks, seq, cache
 
-    Lc, _, Hkv, page_len, Dh = cache.k.shape
+    Lc, _, Hkv, _, Dh = cache.k.shape
     S = tokens.shape[0]
-    maxT = cache.page_table.shape[1] * page_len
     len0 = cache.lengths
     stage_k = jnp.zeros((Lc, S, n, Hkv, Dh), cache.k.dtype)
     stage_v = jnp.zeros((Lc, S, n, Hkv, Dh), cache.v.dtype)
@@ -330,21 +324,10 @@ def decode_steps(
         body, (cache, tokens, stage_k, stage_v, jnp.int32(0)),
         jax.random.split(key, n),
     )
-    # ONE pool write for the whole chunk: position of (slot s, step j) is
-    # len0[s]+j (idle slots pin to the sacrificial page; overshoot clamps
-    # to maxT-1 — duplicate targets there hold garbage nothing reads)
-    steps = jnp.arange(n, dtype=jnp.int32)[None, :]
-    pos = jnp.where(
-        len0[:, None] > 0, jnp.minimum(len0[:, None] + steps, maxT - 1), 0
-    )                                                                # [S, n]
-    pages = jnp.take_along_axis(cache.page_table, pos // page_len, axis=1)
-    offs = (pos % page_len).reshape(-1)
-    pages = pages.reshape(-1)
-    cols_k = stage_k.transpose(1, 2, 0, 3, 4).reshape(S * n, Lc, Hkv, Dh)
-    cols_v = stage_v.transpose(1, 2, 0, 3, 4).reshape(S * n, Lc, Hkv, Dh)
-    k = cache.k.at[:, pages, :, offs, :].set(cols_k)
-    v = cache.v.at[:, pages, :, offs, :].set(cols_v)
-    return toks, seq, PagedCache(k, v, cache.lengths, cache.page_table)
+    # ONE pool write for the whole chunk, in place: (slot s, step j) goes to
+    # position len0[s]+j
+    k, v = write_decode_chunk(cache.k, cache.v, stage_k, stage_v, len0, cache.page_table)
+    return toks, seq, cache._replace(k=k, v=v)
 
 
 @functools.partial(

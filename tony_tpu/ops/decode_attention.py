@@ -48,8 +48,9 @@ def _kernel(len_ref, q_ref, ck_ref, cv_ref, k_hbm, v_hbm, o_ref, *, chunk, windo
             n_rep, pt_ref=None, staged_refs=None, count_ref=None):
     """Shared ragged-attention body. ``pt_ref=None``: dense per-slot cache —
     slab c reads ``k_hbm[0, :, c*chunk:(c+1)*chunk]``. ``pt_ref`` set: PAGED
-    cache — ``k_hbm`` is the whole [P, Hkv, page_len, Dh] page pool
-    (chunk == page_len) and slab c reads physical page ``pt_ref[slot, c]``;
+    cache — ``k_hbm`` is one layer's [P, Hkv, page_len, Dh] view of the
+    page pool (a ``.at[layer]`` of the whole-pool operand: nothing is copied;
+    chunk == page_len) and slab c reads physical page ``pt_ref[slot, c]``;
     the logical position math (lo/c0/c1, masking) is identical because a
     page holds exactly one slab's worth of positions."""
     from jax.experimental import pallas as pl
@@ -260,10 +261,11 @@ def ragged_decode_attention(
 @functools.partial(jax.jit, static_argnames=("window",))
 def paged_decode_attention(
     q: jax.Array,           # [S, H, Dh] — one new token per slot
-    kp: jax.Array,          # [P, Hkv, page_len, Dh] — page pool (read-only)
+    kp: jax.Array,          # [L, P, Hkv, page_len, Dh] — WHOLE page pool (read-only)
     vp: jax.Array,
     lengths: jax.Array,     # [S] int32 — CACHE positions (excluding current)
     page_table: jax.Array,  # [S, max_pages] int32 — logical page j → physical
+    layer: jax.Array,       # [] int32 — which layer's pages to read (may be traced)
     *,
     cur_k: jax.Array,       # [S, Hkv, Dh]
     cur_v: jax.Array,
@@ -278,9 +280,14 @@ def paged_decode_attention(
     (one grid instance per slot, double-buffered slab DMA, online softmax,
     current token folded as the final step) with one indirection: the DMA
     slab size is the PAGE size, and slab c of slot s reads physical page
-    ``page_table[s, c]`` of the pool. HBM traffic per step is still
-    Σ_s ceil(len_s/page_len)·page_len positions — the pool's total size P
-    is irrelevant to step cost, which is the whole point: HBM footprint
+    ``page_table[s, c]`` of layer ``layer`` of the pool. The operand is the
+    WHOLE pool plus a layer index (a scalar-prefetch operand, so the decode
+    step's layer scan can hand a traced one), never one layer's slice of it:
+    a Mosaic call's operand needs a buffer of its own, so a slice handed in
+    is a copy of a layer's pool a call, which once took two fifths of the
+    serving step (PERF.md, PR 27). HBM traffic per step is still
+    Σ_s ceil(len_s/page_len)·page_len positions — the pool's total size
+    L × P is irrelevant to step cost, which is the whole point: HBM footprint
     tracks allocated pages, not slots × max_len. Entries of ``page_table``
     beyond slot s's live pages are never read (loop bounds come from
     ``lengths``); SWA slots skip whole pages below the window exactly as
@@ -301,7 +308,12 @@ def paged_decode_attention(
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, Dh = q.shape
-    Hkv, page_len = kp.shape[1], kp.shape[2]
+    if kp.ndim != 5:
+        raise ValueError(
+            f"kp {kp.shape}: the operand is the whole pool [L, P, Hkv, page_len, "
+            "Dh] with a layer index, not one layer's slice of it"
+        )
+    P, Hkv, page_len = kp.shape[1:4]
     n_rep = H // Hkv
     if page_len < 8 or page_len % 8:
         raise ValueError(
@@ -312,9 +324,9 @@ def paged_decode_attention(
     has_staged = staged_k is not None
     if has_staged and (staged_v is None or staged_count is None):
         raise ValueError("staged_k needs staged_v and staged_count")
-    # two scalar-prefetch operands (lengths+counts, page_table). A packed
-    # single-operand variant was built and A/B'd on-chip: 342 vs 341
-    # ms/chunk — neutral, so the simpler form ships.
+    # three scalar-prefetch operands (lengths+counts, page_table, layer). A
+    # packed single-operand variant of the first two was built and A/B'd
+    # on-chip: 342 vs 341 ms/chunk — neutral, so the simpler form ships.
     meta = (
         jnp.stack([lengths, staged_count], axis=1).astype(jnp.int32)
         if has_staged else lengths[:, None]
@@ -322,23 +334,23 @@ def paged_decode_attention(
 
     staged_specs = (
         [
-            pl.BlockSpec((1,) + staged_k.shape[1:], lambda s, M, PT: (s, 0, 0, 0)),
-            pl.BlockSpec((1,) + staged_k.shape[1:], lambda s, M, PT: (s, 0, 0, 0)),
+            pl.BlockSpec((1,) + staged_k.shape[1:], lambda s, M, PT, LY: (s, 0, 0, 0)),
+            pl.BlockSpec((1,) + staged_k.shape[1:], lambda s, M, PT, LY: (s, 0, 0, 0)),
         ]
         if has_staged else []
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # meta [S, 1|2], page_table
+        num_scalar_prefetch=3,  # meta [S, 1|2], page_table, layer [1]
         grid=(S,),
         in_specs=[
-            pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, M, PT: (s, 0, 0, 0)),
-            pl.BlockSpec((1, Hkv, Dh), lambda s, M, PT: (s, 0, 0)),
-            pl.BlockSpec((1, Hkv, Dh), lambda s, M, PT: (s, 0, 0)),
+            pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, M, PT, LY: (s, 0, 0, 0)),
+            pl.BlockSpec((1, Hkv, Dh), lambda s, M, PT, LY: (s, 0, 0)),
+            pl.BlockSpec((1, Hkv, Dh), lambda s, M, PT, LY: (s, 0, 0)),
             *staged_specs,
-            pl.BlockSpec(memory_space=pl.ANY),   # kp stays in HBM
+            pl.BlockSpec(memory_space=pl.ANY),   # kp stays in HBM, all layers of it
             pl.BlockSpec(memory_space=pl.ANY),   # vp stays in HBM
         ],
-        out_specs=pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, M, PT: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, M, PT, LY: (s, 0, 0, 0)),
     )
 
     class _Col:
@@ -350,7 +362,7 @@ def paged_decode_attention(
         def __getitem__(self, s):
             return self.ref[s, self.col]
 
-    def kern(meta_ref, pt_ref, q_ref, ck_ref, cv_ref, *rest):
+    def kern(meta_ref, pt_ref, layer_ref, q_ref, ck_ref, cv_ref, *rest):
         if has_staged:
             sk_ref, sv_ref, k_hbm, v_hbm, o_ref = rest
             staged_refs = (sk_ref, sv_ref)
@@ -359,12 +371,13 @@ def paged_decode_attention(
             k_hbm, v_hbm, o_ref = rest
             staged_refs = count_ref = None
         _kernel(
-            _Col(meta_ref, 0), q_ref, ck_ref, cv_ref, k_hbm, v_hbm, o_ref,
+            _Col(meta_ref, 0), q_ref, ck_ref, cv_ref,
+            k_hbm.at[layer_ref[0]], v_hbm.at[layer_ref[0]], o_ref,
             chunk=page_len, window=window, n_rep=n_rep, pt_ref=pt_ref,
             staged_refs=staged_refs, count_ref=count_ref,
         )
 
-    operands = [meta, page_table, qg, cur_k, cur_v]
+    operands = [meta, page_table, jnp.reshape(layer, (1,)).astype(jnp.int32), qg, cur_k, cur_v]
     if has_staged:
         operands += [staged_k, staged_v]
     operands += [kp, vp]
@@ -378,7 +391,12 @@ def paged_decode_attention(
         interpret=interpret(),
         cost_estimate=pl.CostEstimate(
             flops=4 * S * H * page_table.shape[1] * page_len * Dh,
-            bytes_accessed=(kp.size + vp.size) * kp.dtype.itemsize // 4,
+            # one layer's K and V pages that the page table can address, a
+            # quarter of them live (the dense kernel's guess): the operand's
+            # other layers are never read, and must not weigh in the estimate
+            bytes_accessed=(
+                2 * min(P, page_table.size) * Hkv * page_len * Dh * kp.dtype.itemsize // 4
+            ),
             transcendentals=S * H * page_table.shape[1] * page_len,
         ),
     )(*operands)
