@@ -5,11 +5,13 @@ Training: the program's forward (its kernels, bf16) against the float32
 reference on weights and tokens made from the seed, and, where the cell's
 limits name `grad_rel_rms`, the gradient of the program's loss function (its
 backward kernels, its chunked cross-entropy, its remat) with respect to the
-attention projections against the reference's. Serving: the reference teacher-forced over what
-the engine returned, and how far below the reference's best logit the
-engine's choices lie. `control=True` also computes the control (the fp8
-reference in the program's place): used when a limit is set and by the test,
-never by the benchmark's own runs.
+leaves the reference names (`GRAD_LEAVES`) against the reference's. Serving:
+the reference teacher-forced over what the engine returned, and how far below
+the reference's best logit the engine's choices lie. `control=True` also
+computes the control (the reference in its `CONTROL` precision, in the
+program's place): used when a limit is set and by the test, never by the
+benchmark's own runs. Program and reference are the family's, found by the
+configuration's `module` (families/__init__.py).
 """
 
 from __future__ import annotations
@@ -21,7 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-import reference as R
+import compare
+import families
 from spec import CHECK_TAIL as TAIL
 
 
@@ -29,18 +32,26 @@ def _pad_to(tokens: np.ndarray, block: int) -> np.ndarray:
     return np.concatenate([tokens, np.zeros((-len(tokens)) % block, tokens.dtype)])
 
 
-#: the leaves whose gradient is compared: what flows into them has passed the
-#: flash backward of their own layer (dq, dk, dv) and every layer above it
-GRAD_LEAVES = ("wq", "wk", "wv")
-
-
 def _rel_rms_device(a, ref) -> jax.Array:
     a, ref = a.astype(jnp.float32), ref.astype(jnp.float32)
     return jnp.sqrt(jnp.sum((a - ref) ** 2) / jnp.sum(ref ** 2))
 
 
-def _with_leaves(p: dict, leaves: dict) -> dict:
-    return {**p, "layers": {**p["layers"], **leaves}}
+def _leaf(tree, path: tuple):
+    for k in path:
+        tree = tree[k]
+    return tree
+
+
+def _with_leaf(tree, path: tuple, leaf):
+    return leaf if not path else {**tree, path[0]: _with_leaf(tree[path[0]], path[1:], leaf)}
+
+
+def _with_leaves(params: dict, leaves: dict) -> dict:
+    """`leaves`: "a/b" -> array, put at the path a, b of the parameter tree."""
+    for name, leaf in leaves.items():
+        params = _with_leaf(params, tuple(name.split("/")), leaf)
+    return params
 
 
 class TrainComparison:
@@ -50,6 +61,8 @@ class TrainComparison:
 
     def __init__(self, module, cfg, mesh, sizes: dict, seq: int):
         self.sizes, self.seq, qb = sizes, seq, min(512, seq)
+        R = self.reference = families.reference(sizes)
+        grad_leaves = {"/".join(path): path for path in R.GRAD_LEAVES}
 
         @jax.jit
         def program(params, batch):
@@ -63,22 +76,22 @@ class TrainComparison:
             full = R.forward(params, row[:-1], sizes, prec, qb)
             return full[-TAIL:], R.nll(full, row[1:]).mean()
 
-        # d(mean loss of the step's batch)/d(wq, wk, wv of every layer): the
+        # d(mean loss of the step's batch)/d(the reference's GRAD_LEAVES): the
         # program's own loss function through jax.grad, as its step takes it
         @jax.jit
         def program_grad(params, batch):
-            leaves = {k: params["layers"][k] for k in GRAD_LEAVES}
+            leaves = {k: _leaf(params, path) for k, path in grad_leaves.items()}
             return jax.grad(lambda lv: module.loss_fn(_with_leaves(params, lv), {"tokens": batch}, cfg, mesh)[0])(leaves)
 
         @functools.partial(jax.jit, static_argnames=("prec",))
         def plain_grad_row(params, row, prec):
-            leaves = {k: params["layers"][k].astype(jnp.float32) for k in GRAD_LEAVES}
+            leaves = {k: _leaf(params, path).astype(jnp.float32) for k, path in grad_leaves.items()}
             return jax.grad(lambda lv: R.nll(R.forward(_with_leaves(params, lv), row[:-1], sizes, prec, qb),
                                              row[1:]).mean())(leaves)
 
         @jax.jit
         def grad_errors(got, ref):
-            return {k: _rel_rms_device(got[k], ref[k]) for k in GRAD_LEAVES}
+            return {k: _rel_rms_device(got[k], ref[k]) for k in grad_leaves}
 
         self.program, self.plain, self.program_grad = program, plain, program_grad
         self.plain_grad_row, self.grad_errors = plain_grad_row, grad_errors
@@ -99,7 +112,8 @@ class TrainComparison:
         through the program. The reference runs on one chip: the first row for
         the logits (16 layers x 8192 positions in float32 take seconds a row,
         and one row's logits decide), every row for the gradient."""
-        tokens = R.zipf_tokens(seed + 1, rows * (self.seq + 1), self.sizes["vocab"]).reshape(rows, self.seq + 1)
+        R = self.reference
+        tokens = compare.zipf_tokens(seed + 1, rows * (self.seq + 1), self.sizes["vocab"]).reshape(rows, self.seq + 1)
         batch = jnp.asarray(tokens)
         t0 = time.time()
         tail, prog_loss = jax.block_until_ready(self.program(params, batch))
@@ -109,14 +123,14 @@ class TrainComparison:
         ref_tail, ref_loss = self.plain(ref_params, ref_batch[0], "f32")
         ref_tail, ref_loss = np.asarray(ref_tail), float(ref_loss)
         result = {
-            "logit_rel_rms": R.rel_rms(tail, ref_tail),
+            "logit_rel_rms": compare.rel_rms(tail, ref_tail),
             "loss_gap": abs(float(prog_loss) - ref_loss),
             "program_loss": float(prog_loss), "reference_loss": ref_loss,
             "program_s": round(t_program, 2), "reference_s": round(time.time() - t0 - t_program, 2),
         }
         if control:
-            ctl_tail, ctl_loss = self.plain(ref_params, ref_batch[0], "fp8")
-            result["control_logit_rel_rms"] = R.rel_rms(ctl_tail, ref_tail)
+            ctl_tail, ctl_loss = self.plain(ref_params, ref_batch[0], R.CONTROL)
+            result["control_logit_rel_rms"] = compare.rel_rms(ctl_tail, ref_tail)
             result["control_loss_gap"] = abs(float(ctl_loss) - ref_loss)
         if not grad:
             return result
@@ -125,11 +139,12 @@ class TrainComparison:
         t_program = time.time() - t0
         ref = jax.block_until_ready(self.plain_grad(ref_params, ref_batch, "f32"))
         by_leaf = {k: float(v) for k, v in self.grad_errors(got, ref).items()}
-        # the number compared: the largest relative RMS error of the three
+        # the number compared: the largest relative RMS error of the leaves
         result.update(grad_rel_rms=max(by_leaf.values()), grad_rel_rms_by_leaf=by_leaf,
                       grad_program_s=round(t_program, 2), grad_reference_s=round(time.time() - t0 - t_program, 2))
         if control:
-            ctl = {k: float(v) for k, v in self.grad_errors(self.plain_grad(ref_params, ref_batch, "fp8"), ref).items()}
+            ctl_grad = self.plain_grad(ref_params, ref_batch, R.CONTROL)
+            ctl = {k: float(v) for k, v in self.grad_errors(ctl_grad, ref).items()}
             result.update(control_grad_rel_rms=max(ctl.values()), control_grad_rel_rms_by_leaf=ctl)
         return result
 
@@ -138,8 +153,10 @@ class TrainComparison:
 def _teacher_forced(params, seq, start, chosen, sizes_key, prec):
     """Reference rows for the answer's positions: (best logit - chosen token's
     logit, whether the chosen token is the argmax, the argmax), each [len(chosen)]."""
-    rows = jax.lax.dynamic_slice_in_dim(R.forward(params, seq, dict(sizes_key), prec), start, chosen.shape[0])
-    return R.chosen_gap(rows, chosen), rows.argmax(-1) == chosen, rows.argmax(-1), rows
+    sizes = dict(sizes_key)
+    logits = families.reference(sizes).forward(params, seq, sizes, prec)
+    rows = jax.lax.dynamic_slice_in_dim(logits, start, chosen.shape[0])
+    return compare.chosen_gap(rows, chosen), rows.argmax(-1) == chosen, rows.argmax(-1), rows
 
 
 def check_serve(params, sizes: dict, samples: list[dict], control: bool = False,
@@ -148,7 +165,7 @@ def check_serve(params, sizes: dict, samples: list[dict], control: bool = False,
     The reference reads prompt + answer; the row at the last prompt position
     predicts the first generated token, and so on. Sequences are padded at the
     end to a few fixed lengths so that few programs compile."""
-    key = tuple(sorted(sizes.items()))
+    key, control_prec = tuple(sorted(sizes.items())), families.reference(sizes).CONTROL
     gaps, agree, n, ctl_gaps = [], 0, 0, []
     for sm in samples:
         prompt, toks = sm["prompt"], sm["tokens"][:pad_answer]
@@ -161,8 +178,9 @@ def check_serve(params, sizes: dict, samples: list[dict], control: bool = False,
         agree += int(np.asarray(same)[:len(toks)].sum())
         n += len(toks)
         if control:
-            _, _, ctl_choice, _ = _teacher_forced(params, jnp.asarray(seq), len(prompt) - 1, jnp.asarray(chosen), key, "fp8")
-            ctl_gaps.append(float(np.asarray(R.chosen_gap(rows, ctl_choice))[:len(toks)].max()))
+            _, _, ctl_choice, _ = _teacher_forced(params, jnp.asarray(seq), len(prompt) - 1, jnp.asarray(chosen), key,
+                                                  control_prec)
+            ctl_gaps.append(float(np.asarray(compare.chosen_gap(rows, ctl_choice))[:len(toks)].max()))
     result = {"worst_gap": max(gaps), "argmax_agree": agree, "tokens": n}
     if control:
         result["control_worst_gap"] = max(ctl_gaps)
@@ -175,18 +193,16 @@ def train_setup(config: str, deployment: str, loop_argv: list[str]):
     batch shape: the kernels then compile as they do in the loop."""
     import spec
     from chipside import sharded_weights
-    from tony_tpu.models import llama, mixtral
     from tony_tpu.parallel import MeshSpec
     from tony_tpu.train.loop import parse_loop_args
 
     sizes = spec.model_sizes(spec.config(config), deployment)
     loop, _ = parse_loop_args(loop_argv)
-    module = {"llama": llama, "mixtral": mixtral}[sizes["module"]]
-    cfg = module.config_from_dict(spec.program_config_fields(sizes, loop.seq_len))
+    module, cfg = families.load(sizes["module"]).program(sizes, loop.seq_len)
     mesh = MeshSpec.auto(model=loop.model_axis, context=loop.context_axis,
                          expert=loop.expert_axis, stage=loop.stage_axis).build()
     return (TrainComparison(module, cfg, mesh, sizes, loop.seq_len),
-            lambda seed: sharded_weights(module, cfg, mesh, R.seed_key(seed), sizes), loop.batch_size)
+            lambda seed: sharded_weights(module, cfg, mesh, sizes, seed), loop.batch_size)
 
 
 def main(argv: list[str]) -> int:
@@ -197,7 +213,7 @@ def main(argv: list[str]) -> int:
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     import spec
-    from chipside import MemoryPeak
+    from chipside import MemoryPeak, seed_weights
     from tony_tpu.runtime import enable_compile_cache
 
     with open(argv[1]) as f:
@@ -209,7 +225,7 @@ def main(argv: list[str]) -> int:
                                 grad=bool(job.get("grad")))
     else:
         sizes = spec.model_sizes(spec.config(job["config"]), job["deployment"])
-        params = jax.jit(lambda k: R.init_weights(k, sizes))(R.seed_key(job["seed"]))
+        params = seed_weights(sizes, job["seed"])
         result = check_serve(params, sizes, job["samples"], control=bool(job.get("control")))
     # how near this child came to the chip's memory (shown by the harness, not compared)
     print(json.dumps({**result, "child_memory_peak_bytes": MemoryPeak().sample()}), flush=True)

@@ -33,14 +33,22 @@ class MemoryPeak:
         return self.peak
 
 
-def sharded_weights(module, cfg, mesh, key, sizes):
-    """The seed's weights laid out as the loop lays them out (the model's own
-    sharding rules), made on the device in one jitted call. The key is an
-    argument: one program for every seed, kept in the compile cache."""
+def seed_weights(sizes: dict, seed: int, shardings=None):
+    """The seed's weights from the family's reference, made on the device in
+    one jitted call. The key is an argument of it: one program for every seed,
+    so the compile cache holds it after the first run (22-29 s to compile at
+    7B width, 0.04 s to run). `shardings`: abstract tree -> its shardings."""
     import jax
 
-    import reference
+    import families
 
-    abstract = jax.eval_shape(lambda k: reference.init_weights(k, sizes), key)
-    shardings = module.sharding_rules(cfg).sharding_tree(abstract, mesh)
-    return jax.jit(lambda k: reference.init_weights(k, sizes), out_shardings=shardings)(key)
+    R = families.reference(sizes)
+    key = R.seed_key(seed)
+    draw = lambda k: R.init_weights(k, sizes)  # a lambda as before: its name is in the compile cache's key
+    return jax.jit(draw, out_shardings=shardings and shardings(jax.eval_shape(draw, key)))(key)
+
+
+def sharded_weights(module, cfg, mesh, sizes: dict, seed: int):
+    """The same weights laid out as the loop lays them out (the model's own
+    sharding rules)."""
+    return seed_weights(sizes, seed, lambda abstract: module.sharding_rules(cfg).sharding_tree(abstract, mesh))

@@ -1,10 +1,10 @@
 """The replica the benchmark's fleet runs: serving_http.main() with a
 configuration registered from benchmark/configs/ and weights from the seed.
 
-`serving_http` takes a model as a preset name and draws its weights with
-`llama.init`; this entry registers the cell's configuration under its name in
-`llama.PRESETS` (the dict serving_http imported), hands the engine the seed's
-weights (reference.init_weights, one jitted call on the device), and starts a
+The configuration's family (families/<module>.py: its `serve_install`) puts
+the cell's configuration where the program looks for it and hands the engine
+the seed's weights (its reference's, one jitted call on the device), or says
+that the program cannot serve that family yet; this entry then starts a
 control thread in the process that holds the chip, because only that process
 can trace it or read its memory:
 
@@ -13,7 +13,8 @@ can trace it or read its memory:
                                seconds into <out_dir>/trace, then write trace.done
   <out_dir>/ctl/snap.<id>.req  write the metrics registry to snap.<id>.json
 
-BENCH_SPEC names the JSON the harness wrote (config, deployment, seed, out_dir).
+BENCH_SPEC names the JSON the harness wrote (config, deployment, seed, out_dir,
+and the workload's `engine` block).
 """
 
 from __future__ import annotations
@@ -66,22 +67,14 @@ def control(out_dir: str) -> None:
 def main() -> int:
     with open(os.environ["BENCH_SPEC"]) as f:
         bench = json.load(f)
-    import jax
-
-    import reference
+    import families
     import spec
-    from tony_tpu.models import llama, serving_http
 
     sizes = spec.model_sizes(spec.config(bench["config"]), bench["deployment"])
-    if sizes["module"] != "llama":
-        raise SystemExit("serving_http builds llama-family weights only: a Mixtral replica waits for the program")
-    llama.PRESETS[bench["config"]] = llama.config_from_dict(
-        spec.program_config_fields(sizes, bench["max_len"]))
-    key = reference.seed_key(bench["seed"])
-    # the key is an argument of the jitted draw: one program for every seed, so
-    # the compile cache holds it after the first run (22-29 s to compile, 0.04 s to run)
-    serving_http.init = lambda _key, _cfg: jax.jit(lambda k: reference.init_weights(k, sizes))(key)
+    families.load(sizes["module"]).serve_install(sizes, bench)
     threading.Thread(target=control, args=(bench["out_dir"],), daemon=True).start()
+    from tony_tpu.models import serving_http
+
     return serving_http.main()
 
 

@@ -1,11 +1,12 @@
 """The training job the benchmark submits: `tony submit --executes "python
 benchmark/entry/train_lm.py <loop flags>"`.
 
-A training job is a user's script by design (examples/llama/pretrain.py is
+A training job is a user's script by design (the examples' pretrain.py is
 twenty lines around run_lm_training); this is the benchmark's. It differs from
 the examples in two ways, neither of them inside the program: the model's
 sizes come from benchmark/configs/<name>.json (the program takes presets by
-name only), and the weights come from --seed through reference.init_weights
+name only) through the configuration's family (families/<module>.py: its
+`program`), and the weights come from --seed through the family's reference
 (the loop's own are PRNGKey(0) whatever the seed: they are replaced once
 drawn, and <out_dir>/weights.json says that they were). The comparison with
 the reference is not here: the harness runs it in a child of its own once
@@ -46,17 +47,14 @@ def device_report(out_dir: str, stop: threading.Event) -> None:
 def main() -> int:
     with open(os.environ["BENCH_SPEC"]) as f:
         bench = json.load(f)
-    import reference
+    import families
     import spec
-    from tony_tpu.models import llama, mixtral
     from tony_tpu.runtime import enable_compile_cache
     from tony_tpu.train.loop import parse_loop_args, run_lm_training
 
     loop, _ = parse_loop_args()
     sizes = spec.model_sizes(spec.config(bench["config"]), bench["deployment"])
-    module = {"llama": llama, "mixtral": mixtral}[sizes["module"]]
-    cfg = module.config_from_dict(spec.program_config_fields(sizes, loop.seq_len))
-    key = reference.seed_key(bench["seed"])
+    module, cfg = families.load(sizes["module"]).program(sizes, loop.seq_len)
     enable_compile_cache()
     stop = threading.Event()
     threading.Thread(target=device_report, args=(bench["out_dir"], stop), daemon=True).start()
@@ -79,7 +77,7 @@ def main() -> int:
 
     def sharded_init_from_seed(init_fn, rules, mesh, optimizer):
         state = program_sharded_init(init_fn, rules, mesh, optimizer)
-        state = dataclasses.replace(state, params=sharded_weights(module, cfg, mesh, key, sizes))
+        state = dataclasses.replace(state, params=sharded_weights(module, cfg, mesh, sizes, bench["seed"]))
         write_json(os.path.join(bench["out_dir"], "weights.json"), {"from_seed": bench["seed"]})
         return state
 

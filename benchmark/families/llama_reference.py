@@ -1,4 +1,5 @@
-"""The plain reference: Mistral/Mixtral forward in jax.numpy and float32.
+"""The llama and mixtral families' plain reference: Mistral/Mixtral forward in
+jax.numpy and float32.
 
 No kernel, no cache, no batching, one sequence at a time: RMSNorm, rotary
 embedding (rotate-half, as the published code), grouped-query causal attention
@@ -7,7 +8,7 @@ gates are renormalised and a dense sum over the chosen experts. Attention is
 taken in blocks of queries against the whole context so that 8192 positions fit.
 
 Inputs come from the seed alone: `init_weights` draws the weights (the program
-is handed them; it makes none) and `zipf_tokens` the check's tokens.
+is handed them; it makes none); the check's tokens are compare.zipf_tokens.
 
 `forward` is differentiable (layers and attention blocks are checkpointed, so
 a gradient at 8192 positions fits beside the weights): the training comparison
@@ -27,7 +28,13 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
-import numpy as np
+
+#: the precision of the control (`prec` of `forward`)
+CONTROL = "fp8"
+#: the leaves whose gradient the training comparison reads, as paths into the
+#: parameter tree: what flows into them has passed the flash backward of their
+#: own layer (dq, dk, dv) and every layer above it
+GRAD_LEAVES = (("layers", "wq"), ("layers", "wk"), ("layers", "wv"))
 
 
 def seed_key(seed: int) -> jax.Array:
@@ -75,13 +82,6 @@ def init_weights(key: jax.Array, s: dict) -> dict:
         "lm_head": dense(ks[8], d, v, fan_in=d),
     }
 
-
-def zipf_tokens(seed: int, n: int, vocab: int, exponent: float = 1.2) -> np.ndarray:
-    """A Zipf unigram draw: structure a model can learn in a few steps (the
-    loss falls from ~ln V towards the distribution's entropy)."""
-    rng = np.random.default_rng(seed)
-    p = 1.0 / np.arange(1, vocab + 1) ** exponent
-    return rng.choice(vocab, size=n, p=p / p.sum()).astype(np.int32)
 
 
 def _round_fp8(a: jax.Array) -> jax.Array:
@@ -184,16 +184,3 @@ def forward(params: dict, tokens: jax.Array, s: dict, prec: str = "f32", q_block
 def nll(logits: jax.Array, targets: jax.Array) -> jax.Array:
     """Per-position negative log-likelihood, float32."""
     return jax.nn.logsumexp(logits, axis=-1) - jnp.take_along_axis(logits, targets[:, None], axis=-1)[:, 0]
-
-
-def rel_rms(a, ref) -> float:
-    """RMS of the difference over the RMS of the reference (on the host, in
-    float64): steady from seed to seed where a worst single entry is not."""
-    a, ref = np.asarray(a, np.float64), np.asarray(ref, np.float64)
-    return float(np.sqrt(np.mean((a - ref) ** 2) / np.mean(ref ** 2)))
-
-
-def chosen_gap(ref_rows: jax.Array, chosen: jax.Array) -> jax.Array:
-    """How far below the reference's best logit each chosen token's reference
-    logit lies, per position (0 where the choice is the reference's argmax)."""
-    return ref_rows.max(axis=-1) - jnp.take_along_axis(ref_rows, chosen[:, None], axis=-1)[:, 0]

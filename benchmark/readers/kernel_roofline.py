@@ -1,6 +1,7 @@
 import re
 
 import counts
+import families
 
 
 def read(ctx, kernel, match):
@@ -9,17 +10,21 @@ def read(ctx, kernel, match):
     the two bounds) over their device time. A Pallas kernel's trace event is
     its HLO line (`custom_call_target="tpu_custom_call"`, no kernel name), so
     `match` is a pattern over that line and the kernel's own operand shapes,
-    worked out from the cell's sizes, tell the kernels of one step apart."""
+    worked out from the cell's sizes, tell the kernels of one step apart. Both
+    the shapes and the count are the family's, found by the kernel's name:
+    `<kernel>_operands` and `<kernel>_layer_step` of its counts."""
     tr, run = ctx.get("trace"), ctx["run"]
     if not tr or ctx["device"].get("platform") != "tpu":
         return None
-    w = run.w
-    if kernel != "flash":
-        raise ValueError(f"no count for kernel {kernel!r}")
-    # flash reads and writes [rows x heads, positions, head size] in the model's type
-    own = re.compile(rf"\b\w+\[\d+,{w['seq_len']},{run.sizes['head_dim']}\]")
+    w, own = run.w, families.counts(run.sizes)
+    try:
+        operands, layer_step = getattr(own, kernel + "_operands"), getattr(own, kernel + "_layer_step")
+    except AttributeError:
+        raise ValueError(f"no count for kernel {kernel!r}: {own.__file__} has no {kernel}_operands "
+                         f"and {kernel}_layer_step") from None
+    operands = re.compile(operands(run.sizes, w["seq_len"]))
     pat = re.compile(match)
-    found = {n: t for n, t in tr["op_time_s"].items() if pat.search(n) and own.search(n)}
+    found = {n: t for n, t in tr["op_time_s"].items() if pat.search(n) and operands.search(n)}
     if not found:
         return None
     device_s = sum(found.values())
@@ -29,9 +34,6 @@ def read(ctx, kernel, match):
     # rows of the batch one chip's attention sees: the batch is split over
     # the chips unless the layout replicates it (`batch_shards` in the file)
     rows = w["batch_size"] // w.get("batch_shards", run.chips)
-    least = [counts.roofline_seconds(*counts.flash_call(run.sizes, rows, w["seq_len"], bwd), peak)
-             for bwd in (False, True)]
-    # full remat runs the forward once more inside the backward; that call's
-    # time is in the device time, so its least time is counted too
-    per_layer_step = 2 * least[0] + least[1]
+    per_layer_step = sum(counts.roofline_seconds(ops, nbytes, peak)
+                         for ops, nbytes in layer_step(run.sizes, rows, w["seq_len"]))
     return 100.0 * per_layer_step * calls / device_s

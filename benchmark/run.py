@@ -4,7 +4,8 @@
     python benchmark/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 A cell is a file under workloads/ (its kind, deployment and traffic), over a
-configuration under configs/. `train` cells submit benchmark/entry/train_lm.py
+configuration under configs/, whose `module` names its family under families/
+(sizes, program, serving hook, reference, counts). `train` cells submit benchmark/entry/train_lm.py
 through `tony submit`; `serve` cells bring a fleet up through the `tony serve`
 path (entry/serve_launch.py) and drive it from this process. This process
 never imports JAX: the job's child owns the chip, and what the last line says
@@ -35,6 +36,7 @@ HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 sys.path.insert(1, os.path.dirname(HERE))  # the program's launcher-side modules (no JAX)
 
+import families  # noqa: E402
 import jobs  # noqa: E402
 import spec  # noqa: E402
 
@@ -104,6 +106,7 @@ def finish(run: Run, result: dict) -> int:
         metrics[name] = {"value": v, "unit": unit}
     for line in result["compared"]:
         say(f"[correct] {line}")
+        print(f"[correct] {line}", file=sys.stderr)  # of a run that is not correct the driver keeps stderr's end
     last = {"correct": bool(result["correct"]), "attempted": result["attempted"],
             "failed": result["failed"], "metrics": metrics, "device": result["device"]}
     if run.trace and result.get("breakdown"):
@@ -119,13 +122,17 @@ def main() -> int:
     p.add_argument("--seconds", type=float, required=True)
     p.add_argument("--trace", type=int, choices=(0, 1), default=0)
     p.add_argument("--control", type=int, choices=(0, 1), default=0,
-                   help="1: also compute the control of the comparison (fp8 reference in the "
-                        "program's place) and print it; for setting limits, never in a check's runs")
+                   help="1: also compute the control of the comparison (the family's reference at its control's "
+                        "precision, in the program's place) and print it; for setting limits, never in a check's runs")
     args = p.parse_args()
     if not os.path.isdir(os.path.join(spec.ROOT, "tony_tpu")):
         print(f"benchmark: {spec.ROOT} holds no tony_tpu package: nothing to measure", file=sys.stderr)
         return 2
-    run = Run(args)
+    try:
+        run = Run(args)
+    except families.NoFamily as e:  # before any job is launched, not 20 s later in the job's child
+        print(f"benchmark: {args.workload}: {e}", file=sys.stderr)
+        return 2
     kind = importlib.import_module(run.w["kind"] + "_cell")
     try:
         result = kind.run(run)

@@ -60,7 +60,7 @@ class Fleet:
         spec_path = os.path.join(run.work, "bench_spec.json")
         with open(spec_path, "w") as f:
             json.dump({"config": self.w["config"], "deployment": self.w["deployment"], "seed": run.seed,
-                       "out_dir": run.out_dir, "max_len": eng["max_len"]}, f)
+                       "out_dir": run.out_dir, "engine": eng}, f)
         cmd = [sys.executable, os.path.join(spec.HERE, "entry", "serve_launch.py"),
                "--preset", self.w["config"], "--replicas", "1", "--slots", str(eng["slots"]),
                "--max_len", str(eng["max_len"]), "--page_len", str(eng["page_len"]),

@@ -1,12 +1,24 @@
 """What the data files say: BENCHMARK.json, configurations, workloads, metrics.
 
-Everything that belongs to one configuration, one traffic mix or one per-layer
-metric is a file found by its name, so a later PR adds files and entries and
-edits nothing here:
+Everything that belongs to one configuration, one traffic mix, one per-layer
+metric or one model family is a file found by its name, so a later PR adds
+files and entries and edits nothing here:
 
-  configs/<config>.json      the published sizes (HF key names), depth by deployment
+  configs/<config>.json      the published sizes (HF key names), depth by deployment,
+                             and `module`: the family that reads them
   workloads/<cell>.json      kind (train|serve), deployment, and the traffic parameters
   metrics/<metric>.json      the reader module under readers/ and its arguments
+  families/<module>.py       a family's sizes, program, serving hook, reference and counts
+                             (what each has to be: families/__init__.py)
+
+A new cell of a configuration that is here: workloads/<config>.<traffic>.json
+and its BENCHMARK.json entry; a per-layer metric that no file reads yet adds
+metrics/<metric>.json and, where no reader fits, readers/<reader>.py. A new
+configuration of a family that is here adds configs/<config>.json besides. A
+new family adds families/<module>.py, the reference and the counts it names
+(its own files, or a family's that are here), and a `tiny-*` configuration and
+workloads at a size the CPU holds, which its tests under tests/ rehearse. A
+workload of a new `kind` adds <kind>_cell.py.
 
 No JAX here: run.py imports this while a child holds the chip.
 """
@@ -16,6 +28,8 @@ from __future__ import annotations
 import json
 import os
 import re
+
+import families
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(HERE)
@@ -62,42 +76,9 @@ def metric(name: str) -> dict:
 
 
 def model_sizes(cfg: dict, deployment: str) -> dict:
-    """The sizes counts.py and reference.py work from, as plain numbers."""
-    depth = cfg["num_hidden_layers"]
-    if isinstance(depth, dict):
-        if deployment not in depth:
-            raise KeyError(f"configuration has no depth for deployment {deployment!r}: {sorted(depth)}")
-        depth = depth[deployment]
-    return {
-        "module": cfg["module"],
-        "vocab": cfg["vocab_size"],
-        "d_model": cfg["hidden_size"],
-        "layers": int(depth),
-        "heads": cfg["num_attention_heads"],
-        "kv_heads": cfg["num_key_value_heads"],
-        "head_dim": cfg["hidden_size"] // cfg["num_attention_heads"],
-        "d_ff": cfg["intermediate_size"],
-        "rope_theta": float(cfg["rope_theta"]),
-        "norm_eps": float(cfg["rms_norm_eps"]),
-        "window": int(cfg.get("sliding_window") or 0),
-        "experts": int(cfg.get("num_local_experts") or 0),
-        "top_k": int(cfg.get("num_experts_per_tok") or 0),
-        "dtype": cfg.get("torch_dtype", "bfloat16"),
-    }
-
-
-def program_config_fields(sizes: dict, max_seq: int) -> dict:
-    """The same sizes under the field names of the program's config dataclass
-    (models/llama.py LlamaConfig, models/mixtral.py MixtralConfig)."""
-    fields = {
-        "vocab_size": sizes["vocab"], "d_model": sizes["d_model"], "n_layers": sizes["layers"],
-        "n_heads": sizes["heads"], "n_kv_heads": sizes["kv_heads"], "d_ff": sizes["d_ff"],
-        "max_seq": max_seq, "rope_theta": sizes["rope_theta"], "norm_eps": sizes["norm_eps"],
-        "dtype": sizes["dtype"], "sliding_window": sizes["window"],
-    }
-    if sizes["module"] == "mixtral":
-        fields.update(num_experts=sizes["experts"], top_k=sizes["top_k"])
-    return fields
+    """The sizes the family's program, reference and counts work from, as plain
+    numbers: the configuration's `module` names the family that reads them."""
+    return families.load(cfg["module"]).sizes(cfg, deployment)
 
 
 def cell_metrics(bench: dict, cell: str, section: str) -> list[dict]:

@@ -1,14 +1,19 @@
 """Tests of the benchmark's own arithmetic and data files.
 
-Pure functions and hand-built fixtures: no job, server or subprocess is
-started, nothing compiles at real width, and no TPU library is touched at
+Pure functions and hand-built fixtures: no job or server is started (the only
+subprocesses are the harness's own files run from a temporary copy of
+benchmark/), nothing compiles at real width, and no TPU library is touched at
 import. Run with `JAX_PLATFORMS=cpu python -m pytest benchmark/tests -q`.
 """
 
 import importlib
+import json
 import math
 import os
+import shutil
+import subprocess
 import sys
+import types
 
 import numpy as np
 import pytest
@@ -19,10 +24,12 @@ sys.path.insert(0, BENCH)
 sys.path.insert(1, os.path.dirname(BENCH))
 
 import counts  # noqa: E402
+import families  # noqa: E402
 import reduce as R  # noqa: E402
 import serve_cell  # noqa: E402
 import spec  # noqa: E402
 import traffic as T  # noqa: E402
+from families import llama_counts  # noqa: E402
 
 B = spec.benchmark()
 CELLS = [w["name"] for w in B["workloads"]]
@@ -88,7 +95,7 @@ def test_configuration_names_source_and_cuts_no_width(name):
     assert cfg["source"] == entry["source"] and cfg["source"].startswith("https://")
     assert sorted(cfg["reduced"]) == sorted(entry["reduced"])
     assert not set(entry["reduced"]) & set(WIDTH) and not any(k.endswith(("_dim", "_rank")) for k in entry["reduced"])
-    assert cfg["module"] in ("llama", "mixtral") and "assumed" in cfg
+    assert callable(families.load(cfg["module"]).sizes) and "assumed" in cfg
     for family in ("llama", "gemma", "gpt-oss", "qwen3.5"):
         assert family not in cfg["source"].lower()
 
@@ -139,15 +146,15 @@ MIXTRAL = {**MISTRAL, "module": "mixtral", "layers": 2, "experts": 8, "top_k": 2
 
 
 def test_mistral_layer_is_218_112_000_parameters():
-    assert counts.layer_params(MISTRAL) == 218_112_000
-    assert counts.layer_matmul_params(MISTRAL) == 218_112_000 - 2 * 4096
-    assert counts.total_params(MISTRAL) == 4 * 218_112_000 + 2 * 32000 * 4096 + 4096
+    assert llama_counts.layer_params(MISTRAL) == 218_112_000
+    assert llama_counts.layer_matmul_params(MISTRAL) == 218_112_000 - 2 * 4096
+    assert llama_counts.total_params(MISTRAL) == 4 * 218_112_000 + 2 * 32000 * 4096 + 4096
 
 
 def test_mixtral_counts_active_experts_only():
     attn = 2 * 4096 * 4096 + 2 * 4096 * 1024
-    assert counts.layer_params(MIXTRAL) == attn + 8 * 3 * 4096 * 14336 + 4096 * 8 + 2 * 4096
-    assert counts.layer_matmul_params(MIXTRAL) == attn + 2 * 3 * 4096 * 14336 + 4096 * 8
+    assert llama_counts.layer_params(MIXTRAL) == attn + 8 * 3 * 4096 * 14336 + 4096 * 8 + 2 * 4096
+    assert llama_counts.layer_matmul_params(MIXTRAL) == attn + 2 * 3 * 4096 * 14336 + 4096 * 8
 
 
 @pytest.mark.parametrize("seq,window,pairs", [
@@ -162,8 +169,8 @@ def test_attention_pairs_inside_the_band(seq, window, pairs):
 def test_train_flops_per_token_by_hand():
     matmul = 2 * (4 * (218_112_000 - 8192) + 4096 * 32000)
     attn = 4 * 4 * 32 * 128 * counts.causal_pairs(8192, 4096) / 8192
-    assert counts.train_flops_per_token(MISTRAL, 8192) == pytest.approx(3 * (matmul + attn))
-    ops, nbytes = counts.flash_call(MISTRAL, 2, 8192, backward=False)
+    assert llama_counts.train_flops_per_token(MISTRAL, 8192) == pytest.approx(3 * (matmul + attn))
+    ops, nbytes = llama_counts.flash_call(MISTRAL, 2, 8192, backward=False)
     assert ops == 4 * 32 * 128 * 2 * counts.causal_pairs(8192, 4096)
     assert nbytes == 2 * (2 * 2 * 8192 * 32 * 128) + 2 * (2 * 2 * 8192 * 8 * 128)
     peak = counts.peak_for("TPU v5 lite", spec.load_json("peaks.json"))
@@ -378,26 +385,25 @@ def test_reference_agrees_with_the_program_in_float32_and_the_control_does_not(n
     import jax
     import jax.numpy as jnp
 
-    import reference
-    from tony_tpu.models import llama, mixtral
+    import compare
 
     sizes = spec.model_sizes(spec.config(name), deployment)
-    module = {"llama": llama, "mixtral": mixtral}[sizes["module"]]
-    cfg = module.config_from_dict(spec.program_config_fields(sizes, 128))
+    module, cfg = families.load(sizes["module"]).program(sizes, 128)
+    reference = families.reference(sizes)
     for seed in (3, 2 ** 31 + 11, 77):
         params = reference.init_weights(reference.seed_key(seed), sizes)
-        toks = jnp.asarray(reference.zipf_tokens(seed, 128, sizes["vocab"]))
+        toks = jnp.asarray(compare.zipf_tokens(seed, 128, sizes["vocab"]))
         ref = reference.forward(params, toks, sizes, "f32", 64)
         p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
         out = module.forward(p32, toks[None], dataclasses.replace(cfg, dtype="float32"))
-        exact = reference.rel_rms((out[0] if isinstance(out, tuple) else out)[0], ref)
+        exact = compare.rel_rms((out[0] if isinstance(out, tuple) else out)[0], ref)
         out = module.forward(params, toks[None], cfg)
-        sound = reference.rel_rms((out[0] if isinstance(out, tuple) else out)[0], ref)
-        control = reference.rel_rms(reference.forward(params, toks, sizes, "fp8", 64), ref)
+        sound = compare.rel_rms((out[0] if isinstance(out, tuple) else out)[0], ref)
+        control = compare.rel_rms(reference.forward(params, toks, sizes, reference.CONTROL, 64), ref)
         assert exact < 1e-5, "the reference is the program's mathematics in float32"
         assert control > 2.5 * sound and control > 0.03, (sound, control)
         rows = ref[-8:]
-        assert float(reference.chosen_gap(rows, rows.argmax(-1)).max()) == 0.0
+        assert float(compare.chosen_gap(rows, rows.argmax(-1)).max()) == 0.0
 
 
 def test_the_gradient_comparison_separates_the_program_from_its_control():
@@ -407,18 +413,17 @@ def test_the_gradient_comparison_separates_the_program_from_its_control():
     import jax.numpy as jnp
 
     import check
-    import reference
-    from tony_tpu.models import llama
 
     sizes = spec.model_sizes(spec.config("tiny-dense"), "train-1chip")
-    cfg = llama.config_from_dict(spec.program_config_fields(sizes, 128))
-    sound_cmp = check.TrainComparison(llama, cfg, None, sizes, 128)
-    exact_cmp = check.TrainComparison(llama, dataclasses.replace(cfg, dtype="float32"), None, sizes, 128)
+    module, cfg = families.load(sizes["module"]).program(sizes, 128)
+    reference = families.reference(sizes)
+    sound_cmp = check.TrainComparison(module, cfg, None, sizes, 128)
+    exact_cmp = check.TrainComparison(module, dataclasses.replace(cfg, dtype="float32"), None, sizes, 128)
     for seed in (5, 2 ** 31 + 12, 78):
         params = reference.init_weights(reference.seed_key(seed), sizes)
         r = sound_cmp.run(params, seed, rows=2, control=True, grad=True)
         assert r["control_grad_rel_rms"] > 3 * r["grad_rel_rms"] and r["control_grad_rel_rms"] > 0.03, r
-        assert set(r["grad_rel_rms_by_leaf"]) == set(check.GRAD_LEAVES)
+        assert set(r["grad_rel_rms_by_leaf"]) == {"/".join(path) for path in reference.GRAD_LEAVES}
         assert r["grad_rel_rms"] == max(r["grad_rel_rms_by_leaf"].values())
         # the reference's gradient is the program's in float32, over both rows of the batch
         p32 = jax.tree.map(lambda a: a.astype(jnp.float32), params)
@@ -452,3 +457,238 @@ def test_the_comparisons_child_is_started_again_after_a_failure(monkeypatch, tmp
     assert len(calls) == tries == 1 + len(jobs.CHILD_RETRY_WAITS[:tries - 1])
     assert {k: v for k, v in got.items() if k not in ("seconds", "tries")} == result
     assert got.get("tries") == (tries if result else None)
+
+
+# -- the family seam ------------------------------------------------------------
+FAMILY_SIZES = {
+    "llama": ("mistral-7b", "serve-1chip", {
+        "module": "llama", "vocab": 32000, "d_model": 4096, "layers": 8, "heads": 32, "kv_heads": 8, "head_dim": 128,
+        "d_ff": 14336, "rope_theta": 10000.0, "norm_eps": 1e-05, "window": 4096, "experts": 0, "top_k": 0,
+        "dtype": "bfloat16"}),
+    "mixtral": ("tiny-moe", "train-4chip", {
+        "module": "mixtral", "vocab": 256, "d_model": 64, "layers": 2, "heads": 4, "kv_heads": 2, "head_dim": 16,
+        "d_ff": 128, "rope_theta": 1000000.0, "norm_eps": 1e-05, "window": 0, "experts": 4, "top_k": 2,
+        "dtype": "bfloat16"}),
+}
+
+
+@pytest.mark.parametrize("family", sorted(FAMILY_SIZES))
+def test_a_family_answers_for_sizes_program_reference_and_counts(family):
+    config, deployment, expected = FAMILY_SIZES[family]
+    cfg = spec.config(config)
+    assert cfg["module"] == family
+    sizes = spec.model_sizes(cfg, deployment)
+    assert sizes == expected and list(sizes) == list(expected)
+    fam = families.load(family)
+    # a key the family does not know is an error, and so is a value it does not compute
+    with pytest.raises(KeyError, match="lightning_nh"):
+        fam.sizes({**cfg, "lightning_nh": 32}, deployment)
+    with pytest.raises(ValueError, match="hidden_act"):
+        fam.sizes({**cfg, "hidden_act": "gelu"}, deployment)
+    with pytest.raises(KeyError, match="no depth for deployment"):
+        fam.sizes(cfg, "serve-9chip")
+    module, pcfg = fam.program(sizes, 512)
+    assert module.__name__ == "tony_tpu.models." + family and callable(module.forward) and callable(module.loss_fn)
+    assert (pcfg.n_layers, pcfg.d_model, pcfg.max_seq, pcfg.sliding_window) == (
+        sizes["layers"], sizes["d_model"], 512, sizes["window"])
+    assert getattr(pcfg, "num_experts", 0) == sizes["experts"]
+    ref = families.reference(sizes)
+    assert all(callable(getattr(ref, f)) for f in ("seed_key", "init_weights", "forward", "nll"))
+    assert ref.CONTROL != "f32" and all(isinstance(path, tuple) for path in ref.GRAD_LEAVES)
+    own = families.counts(sizes)
+    assert own.train_flops_per_token(sizes, 8192) > 6 * sizes["layers"] * own.layer_matmul_params(sizes)
+    assert callable(fam.serve_install)
+
+
+def test_a_family_that_cannot_be_served_says_so_itself():
+    sizes = spec.model_sizes(spec.config("tiny-moe"), "train-4chip")
+    with pytest.raises(SystemExit, match="a Mixtral replica waits for the program"):
+        families.load("mixtral").serve_install(sizes, {"config": "tiny-moe", "seed": 1, "engine": {"max_len": 64}})
+
+
+def test_kernel_roofline_finds_its_count_by_the_kernel_argument():
+    read = importlib.import_module("readers.kernel_roofline").read
+    flash = ("%shard_map.1 = (bf16[64,8192,128]{2,1,0}, f32[64,8192,128]{2,1,0}) custom-call(bf16[64,8192,128]{2,1,0} %q), "
+             "custom_call_target=\"tpu_custom_call\"")
+    other = "%moe.2 = bf16[16384,14336]{1,0} custom-call(bf16[16384,4096]{1,0} %x), custom_call_target=\"tpu_custom_call\""
+    run = types.SimpleNamespace(sizes=MISTRAL, w={"seq_len": 8192, "batch_size": 2}, chips=1,
+                                peaks=spec.load_json("peaks.json"))
+    ctx = {"run": run, "device": {"platform": "tpu", "kind": "TPU v5 lite"},
+           "trace": {"op_time_s": {flash: 3.0, other: 5.0}, "op_count": {flash: 20, other: 20}}}
+    peak = counts.peak_for("TPU v5 lite", run.peaks)
+    fwd, bwd = (counts.roofline_seconds(*llama_counts.flash_call(MISTRAL, 2, 8192, b), peak) for b in (False, True))
+    assert read(ctx, kernel="flash", match="tpu_custom_call") == 100.0 * (2 * fwd + bwd) * 20 / 3.0
+    assert llama_counts.flash_layer_step(MISTRAL, 2, 8192) == [
+        llama_counts.flash_call(MISTRAL, 2, 8192, b) for b in (False, False, True)]
+    ctx["trace"] = {"op_time_s": {other: 5.0}, "op_count": {other: 20}}
+    assert read(ctx, kernel="flash", match="tpu_custom_call") is None  # no call with flash's shape: nothing, never 0
+    with pytest.raises(ValueError, match="llama_counts.py has no moe_gemm_operands"):
+        read(ctx, kernel="moe_gemm", match="tpu_custom_call")
+
+
+def _copy_of_the_benchmark(tmp_path) -> str:
+    """BENCHMARK.json and benchmark/ as git would commit them, in a directory
+    of their own beside a link to the program."""
+    top = str(tmp_path / "checkout")
+    shutil.copytree(BENCH, os.path.join(top, "benchmark"), ignore=shutil.ignore_patterns("__pycache__"))
+    shutil.copy(os.path.join(spec.ROOT, "BENCHMARK.json"), top)
+    os.symlink(os.path.join(spec.ROOT, "tony_tpu"), os.path.join(top, "tony_tpu"))
+    return top
+
+
+def _python(top: str, *argv: str, timeout: float = 600.0) -> subprocess.CompletedProcess:
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": top, "PYTHONDONTWRITEBYTECODE": "1"}
+    return subprocess.run([sys.executable, *argv], cwd=top, env=env, capture_output=True, text=True, timeout=timeout)
+
+
+def _write_json(top: str, rel: str, obj: dict) -> None:
+    with open(os.path.join(top, "benchmark", rel), "x") as f:
+        json.dump(obj, f)
+
+
+def _files(top: str) -> dict[str, bytes]:
+    out = {}
+    for r, dirs, fns in os.walk(top):
+        dirs[:] = [d for d in dirs if d != "__pycache__"]
+        for fn in fns:
+            with open(os.path.join(r, fn), "rb") as f:
+                out[os.path.relpath(os.path.join(r, fn), top)] = f.read()
+    return out
+
+
+ECHO_FAMILY = '''"""A family added by files alone: the llama family's program, reference and
+counts, and one key of its own that it reads from the configuration."""
+from families import llama
+
+REFERENCE, COUNTS = llama.REFERENCE, llama.COUNTS
+program, serve_install = llama.program, llama.serve_install
+
+
+def sizes(cfg, deployment):
+    own = {k: v for k, v in cfg.items() if k != "echo_width"}
+    return {**llama.sizes(own, deployment), "echo_width": int(cfg["echo_width"])}
+'''
+
+DRIVE_THE_ECHO_FAMILY = '''
+import json, sys
+sys.path.insert(0, "benchmark")
+import jax, jax.numpy as jnp, numpy as np
+import check, chipside, families, spec
+
+out = {}
+w = spec.workload("tiny-echo.train")
+sizes = spec.model_sizes(spec.config(w["config"]), w["deployment"])
+fam = families.load(sizes["module"])
+module, cfg = fam.program(sizes, w["seq_len"])
+out["sizes"], out["family"], out["program"] = sizes, fam.__file__, [module.__name__, cfg.n_layers, cfg.dtype]
+out["reference"], out["counts"] = families.reference(sizes).__name__, families.counts(sizes).__name__
+out["flops_per_token"] = families.counts(sizes).train_flops_per_token(sizes, w["seq_len"])
+comparison, weights, rows = check.train_setup(
+    w["config"], w["deployment"], ["--batch_size", str(w["batch_size"]), "--seq_len", str(w["seq_len"])])
+out["train"] = comparison.run(weights(2 ** 31 + 5), 2 ** 31 + 5, rows, control=True, grad=True)
+
+# serving: the reference's own greedy continuation is what a sound engine returns
+w = spec.workload("tiny-echo.serve")
+sizes = spec.model_sizes(spec.config(w["config"]), w["deployment"])
+R, params = families.reference(sizes), chipside.seed_weights(sizes, 7)
+step = jax.jit(lambda seq, n: R.forward(params, seq, sizes, "f32", 64)[n - 1].argmax())
+prompt = np.random.default_rng(7).integers(1, sizes["vocab"], 20).tolist()
+tokens = []
+for _ in range(6):
+    seq = np.zeros(64, np.int32)
+    seq[:len(prompt) + len(tokens)] = prompt + tokens
+    tokens.append(int(step(jnp.asarray(seq), len(prompt) + len(tokens))))
+out["serve"] = check.check_serve(params, sizes, [{"prompt": prompt, "tokens": tokens}], control=True, pad_seq=512, pad_answer=8)
+wrong = [(t + 1) % sizes["vocab"] for t in tokens]
+out["serve_wrong"] = check.check_serve(params, sizes, [{"prompt": prompt, "tokens": wrong}], pad_seq=512, pad_answer=8)
+print(json.dumps(out))
+'''
+
+
+def test_a_family_is_added_by_files_alone(tmp_path):
+    """A family file, a configuration and two workloads written into a copy of
+    benchmark/: sizes, program, reference and counts resolve through them, the
+    training and the serving comparison run against them in float32, and no
+    file that was there differs."""
+    top = _copy_of_the_benchmark(tmp_path)
+    before = _files(top)
+    with open(os.path.join(top, "benchmark", "families", "echo.py"), "x") as f:
+        f.write(ECHO_FAMILY)
+    _write_json(top, "configs/tiny-echo.json",
+                {**spec.config("tiny-dense"), "module": "echo", "echo_width": 7, "torch_dtype": "float32"})
+    for traffic in ("train", "serve"):
+        with open(os.path.join(BENCH, "workloads", f"tiny-dense.{traffic}.json")) as f:
+            _write_json(top, f"workloads/tiny-echo.{traffic}.json", {**json.load(f), "config": "tiny-echo"})
+    proc = _python(top, "-c", DRIVE_THE_ECHO_FAMILY)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["sizes"]["echo_width"] == 7 and out["sizes"]["module"] == "echo" and out["sizes"]["dtype"] == "float32"
+    assert out["family"] == os.path.join(top, "benchmark", "families", "echo.py")
+    assert out["program"] == ["tony_tpu.models.llama", 2, "float32"]
+    assert (out["reference"], out["counts"]) == ("families.llama_reference", "families.llama_counts")
+    assert out["flops_per_token"] == llama_counts.train_flops_per_token(spec.model_sizes(spec.config("tiny-dense"), "train-1chip"), 128)
+    train = out["train"]
+    assert train["logit_rel_rms"] < 1e-5 and train["grad_rel_rms"] < 1e-4, train
+    assert train["control_logit_rel_rms"] > 0.03 and train["control_grad_rel_rms"] > 0.03, train
+    assert set(train["grad_rel_rms_by_leaf"]) == {"layers/wq", "layers/wk", "layers/wv"}
+    assert out["serve"]["worst_gap"] < 1e-4 and out["serve"]["argmax_agree"] == out["serve"]["tokens"] == 6, out["serve"]
+    assert out["serve_wrong"]["worst_gap"] > 0.01 and out["serve_wrong"]["argmax_agree"] < 6, out["serve_wrong"]
+    after = _files(top)
+    assert {k: after[k] for k in before} == before, "a file that was there was edited"
+    assert sorted(set(after) - set(before)) == [
+        "benchmark/configs/tiny-echo.json", "benchmark/families/echo.py",
+        "benchmark/workloads/tiny-echo.serve.json", "benchmark/workloads/tiny-echo.train.json"]
+    repo = {os.path.join("benchmark", k): v for k, v in _files(BENCH).items()}
+    with open(os.path.join(spec.ROOT, "BENCHMARK.json"), "rb") as f:
+        assert before == {**repo, "BENCHMARK.json": f.read()}, "the copy is the repo's"
+
+
+def test_a_module_without_a_family_file_fails_before_any_job(tmp_path):
+    top = _copy_of_the_benchmark(tmp_path)
+    _write_json(top, "configs/tiny-sala.json", {**spec.config("tiny-dense"), "module": "sala"})
+    with open(os.path.join(BENCH, "workloads", "tiny-dense.serve.json")) as f:
+        _write_json(top, "workloads/tiny-sala.serve.json", {**json.load(f), "config": "tiny-sala"})
+    proc = _python(top, "benchmark/run.py", "--workload", "tiny-sala.serve", "--seed", "1", "--seconds", "1", timeout=60)
+    assert proc.returncode == 2 and not proc.stdout.strip()
+    assert os.path.join(top, "benchmark", "families", "sala.py") in proc.stderr and "add it" in proc.stderr
+    assert not os.path.exists(os.path.join(top, ".bench_work")), "nothing was launched"
+    with pytest.raises(families.NoFamily):
+        families.load("../spec")
+
+
+HARNESS_OFF_JAX = '''
+import argparse, sys
+sys.path.insert(0, "benchmark")
+import run
+r = run.Run(argparse.Namespace(workload=sys.argv[1], seed=2 ** 31 + 3, seconds=1.0, trace=1, control=0))
+assert r.sizes["layers"] >= 1
+print(sorted(m for m in sys.modules if m == "jax" or m.startswith(("jax.", "jaxlib", "tony_tpu.models"))))
+'''
+
+
+@pytest.mark.parametrize("workload", ["mistral-7b.train_8k", "mistral-7b.serve_chat", "tiny-dense.serve", "tiny-moe.train"])
+def test_the_harness_process_stays_off_jax(tmp_path, workload):
+    """run.py's process builds its Run (sizes through the family's file) with
+    neither JAX nor the program's models imported: a child holds the chip."""
+    top = _copy_of_the_benchmark(tmp_path)
+    proc = _python(top, "-c", HARNESS_OFF_JAX, workload, timeout=60)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert proc.stdout.strip().splitlines()[-1] == "[]"
+
+
+@pytest.mark.parametrize("correct", [True, False])
+def test_the_compared_numbers_end_both_streams(capsys, correct):
+    """Every run prints each number compared beside its limit, and they are the
+    last lines of standard error too: of a run that is not correct the driver's
+    record keeps the end of that, and of standard output the last line's keys."""
+    run = importlib.import_module("run")
+    r = types.SimpleNamespace(trace=False, listed=False, bench=B, cell="tiny-dense.serve", w={"kind": "serve"})
+    compared = ["requests failed: 0 of 3 []", f"worst_gap = {0.01 if correct else 0.2} over 6 tokens (limit 0.12)"]
+    result = {"ctx": {}, "end_to_end": {"setup_s": 1.5, "serve_out_tok_s": 2.5}, "compared": compared,
+              "correct": correct, "attempted": 3, "failed": 0, "device": {"platform": "cpu", "kind": "cpu", "count": 1}}
+    assert run.finish(r, result) == 0
+    out, err = capsys.readouterr()
+    assert err.splitlines() == [f"[correct] {line}" for line in compared]
+    assert out.splitlines()[:-1] == err.splitlines()
+    last = json.loads(out.splitlines()[-1])
+    assert last["correct"] is correct and last["metrics"]["serve_out_tok_s"] == {"value": 2.5, "unit": "tokens/s"}

@@ -165,7 +165,8 @@ def run(run) -> dict:
     chk = jobs.compare_in_child(run, {"kind": "train", "loop_argv": shlex.split(loop_flags),
                                       "grad": "grad_rel_rms" in limits}, "train")
     for name, what in (("logit_rel_rms", f"last {CHECK_TAIL} positions' logits of one sequence"),
-                       ("grad_rel_rms", "gradient of the step batch's mean loss by wq, wk, wv of every layer, the worst of the three")):
+                       ("grad_rel_rms", "gradient of the step batch's mean loss by "
+                                        f"{', '.join(chk.get('grad_rel_rms_by_leaf', {}))}, the worst of them")):
         if name not in limits:
             continue
         v = chk.get(name)
