@@ -147,6 +147,49 @@ class TestDecodeAttentionAtServeShapes:
                              staged, staged, lengths) == 1
 
 
+class TestSalaKernelsAtServedWidths:
+    """MiniCPM-SALA's three kernels at the widths `minicpm-sala.serve_longdoc`
+    serves: 32 query and 2 kv heads of 128, 16 slots of 50,688 positions, a
+    page a 64-token block, prefill chunks of 2048."""
+
+    S, H, HKV, D, PAGE, MAXLEN, CHUNK = 16, 32, 2, 128, 64, 50688, 2048
+
+    def test_sparse_paged_decode(self, chip):
+        c = self
+        n, pages = 128, c.S * (c.MAXLEN // c.PAGE) + 1
+        pool = _s((3, pages, c.HKV, c.PAGE, c.D), jnp.bfloat16, chip)
+        lists = _s((c.S, c.HKV, n), jnp.int32, chip)
+        slot = _s((c.S,), jnp.int32, chip)
+        cur, staged = _s((c.S, c.HKV, c.D), jnp.bfloat16, chip), _s((c.S, 8, c.HKV, c.D), jnp.bfloat16, chip)
+
+        def fn(q, kp, vp, layer, pages, logical, full, counts, lengths, win_lo, ck, cv, sk, sv, count):
+            return DA.sparse_paged_decode_attention(q, kp, vp, layer, pages, logical, full, counts, lengths, win_lo,
+                                                    cur_k=ck, cur_v=cv, staged_k=sk, staged_v=sv, staged_count=count)
+
+        assert _kernel_calls(fn, _s((c.S, c.H, c.D), jnp.bfloat16, chip), pool, pool, _s((), jnp.int32, chip), lists,
+                             lists, lists, _s((c.S, c.HKV), jnp.int32, chip), slot, slot, cur, cur, staged, staged,
+                             slot) == 1
+
+    def test_masked_prefill(self, chip):
+        from tony_tpu.ops import sparse_attention as SA
+
+        c = self
+        q = _s((c.HKV, c.H // c.HKV, c.CHUNK, c.D), jnp.bfloat16, chip)
+        kv = _s((c.HKV, c.MAXLEN, c.D), jnp.bfloat16, chip)
+        mask = _s((c.HKV, c.CHUNK, c.MAXLEN), jnp.int8, chip)
+        assert _kernel_calls(SA.masked_prefill_attention, q, kv, kv, mask, _s((), jnp.int32, chip)) == 1
+
+    def test_linear_chunk(self, chip):
+        """A prefill chunk's linear attention: one call for the 32 heads, the
+        one request's float32 state in and out."""
+        from tony_tpu.ops import linear_attention as LA
+
+        c = self
+        qkv = _s((1, c.H, c.CHUNK, c.D), jnp.bfloat16, chip)
+        state, slopes = _s((1, c.H, c.D, c.D), jnp.float32, chip), _s((c.H,), jnp.float32, chip)
+        assert _kernel_calls(LA.linear_attention_chunk, qkv, qkv, qkv, state, slopes, _s((), jnp.int32, chip)) == 1
+
+
 class TestPagedDecodeChunkTouchesThePoolOnlyByPage:
     """The whole `serving.decode_steps` program of a paged engine at Mistral
     widths (2 layers, 96 pages of 256, 64 slots, chunk 8: the serving cells'

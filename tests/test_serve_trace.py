@@ -192,7 +192,7 @@ def test_span_chain_has_the_four_stages_with_the_stamped_boundaries(params, tmp_
         assert span["parent_id"] == root["span_id"] and span["status"] == "ok"
         assert span["start_ms"] == pytest.approx(marks[i] * 1000, abs=2e-3)
         assert span["end_ms"] == pytest.approx(marks[i + 1] * 1000, abs=2e-3)
-        assert span["attrs"] == {"rid": "req-7", "prompt_tokens": 3, "prefix_tokens": 0, "slot": r.slot}
+        assert span["attrs"] == {"rid": "req-7", "prompt_tokens": 3, "prefix_tokens": 0, "chunks": 1, "slot": r.slot}
     decode = by_name["serve.decode"]
     assert decode["parent_id"] == root["span_id"] and decode["start_ms"] >= by_name["serve.emit"]["end_ms"]
     assert decode["attrs"]["ttft_s"] == pytest.approx(ttfts[0][1], abs=1e-6)

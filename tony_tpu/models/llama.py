@@ -94,6 +94,13 @@ LLAMA_TINY = LlamaConfig(
 PRESETS = {"llama3-8b": LLAMA3_8B, "llama-1b": LLAMA_1B, "tiny": LLAMA_TINY}
 
 
+def serving_programs(cfg: LlamaConfig, kv: str):
+    """What the serving engine asks a model module for (models/serving.py)."""
+    from tony_tpu.models.serving import llama_programs
+
+    return llama_programs(cfg, kv)
+
+
 def init(key: jax.Array, cfg: LlamaConfig) -> dict:
     """Initialize the parameter pytree (truncated-normal fan-in scaling)."""
     D, F, V = cfg.d_model, cfg.d_ff, cfg.vocab_size

@@ -330,3 +330,9 @@ def config_from_dict(d: dict | str) -> MixtralConfig:
         PRESETS.get(d.get("preset", ""), MixtralConfig()),
         **{k: v for k, v in d.items() if k in fields},
     )
+
+
+def serving_programs(cfg: MixtralConfig, kv: str):
+    """The serving engine's programs (models/serving.py): the llama family's,
+    whose decode step dispatches the FFN on the layer's keys."""
+    return llama_mod.serving_programs(cfg, kv)

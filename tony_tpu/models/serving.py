@@ -1,4 +1,6 @@
-"""Continuous-batching decode engine (Llama + Mixtral families).
+"""Continuous-batching decode engine (any model module that supplies its
+serving programs: `ServingPrograms`, below; the Llama and Mixtral families'
+are built here, MiniCPM-SALA's in models/minicpm_sala.py).
 
 The reference orchestrates training jobs only — serving is new capability
 (SURVEY.md §2.5 "absent" rows); this is the slot-based engine layer above
@@ -70,6 +72,16 @@ _DECODE_SLOTS = obs_metrics.counter(
 _PREFILL_TOKENS = obs_metrics.counter(
     "tony_serve_prefill_tokens_total",
     "prompt tokens dispatched to prefill, padded to their bucket (reused prefix tokens are not in it)")
+_CONTEXT_TOKENS = obs_metrics.counter(
+    "tony_serve_context_tokens_total",
+    "positions in context, summed over running slots and decode steps dispatched")
+_VISIBLE_TOKENS = obs_metrics.counter(
+    "tony_serve_visible_tokens_total",
+    "cache positions a decode step may read (a window, a chosen set of blocks), summed like the context")
+_PREFILL_CHUNKS = obs_metrics.counter(
+    "tony_serve_prefill_chunks_total",
+    "prefill chunks dispatched, by how their attention reads its keys",
+    labelnames=("path",))
 _ADMIT_BLOCKED = obs_metrics.counter(
     "tony_serve_admit_blocked_total",
     "engine passes in which a waiting request could not be admitted, by what was short",
@@ -404,6 +416,70 @@ def _insert_prefill(cache: SlotCache, pre: KVCache, slot: jax.Array, true_len: j
     return SlotCache(k, v, lengths)
 
 
+class ServingPrograms(NamedTuple):
+    """What the engine asks a model module for (`<module>.serving_programs(cfg,
+    kv)`, the module being the one that defines the config's class). The host
+    loop below (admission, pages, slots, streaming, phases) is one piece of
+    code for every model; everything that knows a layer is behind these."""
+
+    init_cache: object        # (num_slots, max_len, page_len, num_pages) -> the slots' device state
+    init_staging: object      # (max_len) -> one request's state while it prefills
+    prefill_chunk: object     # (params, tokens [1, T], staging, take) -> (logits of row take-1 [1, V], staging')
+    prefill_pad: object       # (take, prefill_chunk, room) -> padding rows of a prompt's last chunk
+    insert: object            # paged: (cache, staging, fresh_pages, pt_row, slot, true_len, j0, n) -> cache'
+    decode_chunk: object      # (params, cache, tokens, key, n=, temperature=, top_k=, samp=) -> (tokens, all, cache')
+    release: object           # (cache, mask [S]) -> cache' with the masked slots idle
+    visible_tokens: object    # (context lengths, numpy) -> cache positions a decode step may read at each
+    prefill_path: object      # (pos, take) -> "dense" | "sparse": how that chunk's attention reads its keys
+    insert_dense: object = None            # dense kv: (cache, staging, slot, true_len) -> cache'
+    decode_chunk_bucketed: object = None   # dense kv: decode_chunk with bucket=
+    # (staging, cache, pages, n) -> staging with an earlier request's full prompt pages in it; None where a
+    # page is not all a prefix leaves behind (state beside it), so no page is shared
+    gather_prefix: object = None
+
+
+def programs_for(cfg, kv: str) -> ServingPrograms:
+    """The serving programs of the module that defines `cfg`'s class."""
+    from tony_tpu.models.registry import module_of
+
+    return module_of(cfg).serving_programs(cfg, kv)
+
+
+def llama_programs(cfg: LlamaConfig, kv: str) -> ServingPrograms:
+    """The Llama and Mixtral families' programs: the jitted functions of this
+    file and of paged_cache.py, as the engine called them before it had a seam."""
+    from tony_tpu.models import paged_cache as pc
+
+    def prefill(params, tokens, staging, take):
+        logits, staging = _prefill_padded(params, tokens, staging, cfg)
+        return logits[:, take - 1].astype(jnp.float32), staging
+
+    def release_paged(cache, mask):
+        lengths, page_table = _mask_zero_paged(cache.lengths, cache.page_table, mask)
+        return pc.PagedCache(cache.k, cache.v, lengths, page_table)
+
+    paged = kv == "paged"
+    window = cfg.sliding_window
+    return ServingPrograms(
+        init_cache=(lambda S, max_len, page_len, num_pages: pc.init_paged_cache(cfg, S, max_len, page_len, num_pages))
+        if paged else (lambda S, max_len, page_len, num_pages: init_slot_cache(cfg, S, max_len)),
+        init_staging=lambda max_len: init_cache(cfg, 1, max_len),
+        prefill_chunk=prefill,
+        prefill_pad=lambda take, chunk, room: min(_bucket(take), room) - take,
+        insert=lambda cache, pre, fresh, pt_row, slot, true_len, j0, n: pc.insert_paged_prefill(
+            cache, pre.k, pre.v, fresh, pt_row, slot, true_len, j0, n=n),
+        decode_chunk=lambda params, cache, tokens, key, n, temperature, top_k, samp: decode_steps(
+            params, cache, tokens, key, cfg, n, temperature, top_k, "ragged", samp),
+        release=release_paged if paged else (lambda cache, mask: SlotCache(cache.k, cache.v, _mask_zero(cache.lengths, mask))),
+        visible_tokens=lambda n: np.minimum(n, window) if window > 0 else n,
+        prefill_path=lambda pos, take: "dense",
+        insert_dense=_insert_prefill,
+        decode_chunk_bucketed=lambda params, cache, tokens, key, n, bucket, temperature, top_k, samp: decode_steps_bucketed(
+            params, cache, tokens, key, cfg, n, bucket, temperature, top_k, samp),
+        gather_prefix=lambda pre, cache, pages, n: pc.gather_prefix_into_staging(pre, cache.k, cache.v, pages, n=n),
+    )
+
+
 @dataclass
 class _Request:
     rid: int
@@ -422,6 +498,7 @@ class _Request:
     staged_s: float = 0.0
     slot_s: float = 0.0
     prefix_tokens: int = 0            # prompt tokens reused from the prefix cache
+    prefill_chunks: int = 0           # prefill programs dispatched for it
 
     def is_done(self, eos_id: int) -> bool:
         """THE termination predicate — budget spent, EOS emitted, or the
@@ -457,15 +534,22 @@ class ContinuousBatcher:
     active slot fits a short bucket, the ragged Pallas kernel once the
     needed bucket crosses ``ragged_threshold`` — short regimes are
     XLA-batched-einsum-friendly, long/straggler regimes are where per-slot
-    reads pay), or force "ragged"/"bucketed". Works for Llama and Mixtral
-    param trees — the decode step dispatches the FFN on the layer keys.
+    reads pay), or force "ragged"/"bucketed" (the dense cache's knob).
+
+    ``cfg`` is the config object of any model module that has
+    ``serving_programs(cfg, kv)`` (``ServingPrograms``): its cache, its
+    prefill-chunk and decode-chunk programs, its admission commit. The host
+    loop here knows slots, pages, lengths and tokens, and no layer: a Llama or
+    Mixtral tree decodes through this file's programs (``llama_programs``), a
+    MiniCPM-SALA tree through models/minicpm_sala.py's, with recurrent state a
+    slot beside the page pool.
     """
 
     #: needed-bucket size above which "auto" switches to the ragged kernel
     RAGGED_THRESHOLD = 512
 
     def __init__(
-        self, params, cfg: LlamaConfig, *, num_slots: int = 8, max_len: int = 512,
+        self, params, cfg, *, num_slots: int = 8, max_len: int = 512,
         eos_id: int = -1, temperature: float = 0.0, top_k: int = 0,
         key: jax.Array | None = None, decode_chunk: int = 8, attn: str = "auto",
         prefill_chunk: int = 0, kv: str = "dense", page_len: int = 256,
@@ -522,6 +606,7 @@ class ContinuousBatcher:
         if kv == "dense" and attn in ("auto", "ragged") and max_len % 128:
             raise ValueError(f"attn={attn!r} needs max_len % 128 == 0, got {max_len}")
         self.params, self.cfg = params, cfg
+        self.programs = programs_for(cfg, kv)
         self.S, self.max_len, self.eos_id = num_slots, max_len, eos_id
         self.temperature, self.top_k = temperature, top_k
         self.attn = attn
@@ -547,7 +632,7 @@ class ContinuousBatcher:
         # the slot length, as in the unchunked path).
         self.prefill_chunk = prefill_chunk
         if kv == "paged":
-            from tony_tpu.models.paged_cache import PageAllocator, init_paged_cache
+            from tony_tpu.models.paged_cache import PageAllocator
 
             self.page_len = page_len
             self.max_pages = max_len // page_len
@@ -559,13 +644,13 @@ class ContinuousBatcher:
                 num_pages if num_pages is not None else num_slots * self.max_pages + 1
             )
             self.allocator = PageAllocator(self.num_pages)
-            self.cache = init_paged_cache(cfg, num_slots, max_len, page_len, self.num_pages)
+            self.cache = self.programs.init_cache(num_slots, max_len, page_len, self.num_pages)
             self._slot_pages: dict[int, list[int]] = {}  # slot → reserved pages
             #: cumulative count of prompt tokens whose prefill compute was
             #: skipped via prefix-cache hits (the sharing win, observable)
             self.prefix_hit_tokens = 0
         else:
-            self.cache = init_slot_cache(cfg, num_slots, max_len)
+            self.cache = self.programs.init_cache(num_slots, max_len, 0, 0)
         self.tokens = jnp.zeros((num_slots,), jnp.int32)  # last token per slot
         if self.tp > 1:
             from jax.sharding import NamedSharding
@@ -717,8 +802,8 @@ class ContinuousBatcher:
         while self.pending and len(self._staged) < budget:
             req = self.pending.pop(0)
             req.staged_s = time.time()
-            entry = _Staged(req, init_cache(self.cfg, 1, self.max_len))
-            if self.kv == "paged":
+            entry = _Staged(req, self.programs.init_staging(self.max_len))
+            if self.kv == "paged" and self.programs.gather_prefix is not None:
                 from tony_tpu.models.paged_cache import prefix_keys
 
                 entry.keys = prefix_keys(req.prompt, self.page_len)
@@ -757,16 +842,12 @@ class ContinuousBatcher:
         (Tp-1)//page_len: the LAST prompt token must always be prefilled
         (its logits sample the first output token). Only callable while the
         entry has no pins and no prefill progress."""
-        from tony_tpu.models.paged_cache import gather_prefix_into_staging
-
         cap = (len(entry.req.prompt) - 1) // self.page_len
         matched = self.allocator.match_prefix(entry.keys[:cap])
         if not matched:
             return False
-        entry.pre = gather_prefix_into_staging(
-            entry.pre, self.cache.k, self.cache.v,
-            jnp.asarray(matched, jnp.int32), n=len(matched),
-        )
+        entry.pre = self.programs.gather_prefix(
+            entry.pre, self.cache, jnp.asarray(matched, jnp.int32), len(matched))
         entry.pos = len(matched) * self.page_len
         entry.matched = matched
         entry.req.prefix_tokens = entry.pos
@@ -795,7 +876,7 @@ class ContinuousBatcher:
                 # dynamic_update_slice would clamp the start and silently
                 # shift real prompt K/V (caught by review repro: prompt 59,
                 # chunk 8, max_len 64 corrupted positions 48..59)
-                pad = min(_bucket(take), self.max_len - pos) - take
+                pad = self.programs.prefill_pad(take, self.prefill_chunk, self.max_len - pos)
             else:
                 pad = 0  # middle chunks are exact: cache positions stay true
             toks = jnp.array(
@@ -803,11 +884,12 @@ class ContinuousBatcher:
             )[None, :]
             # padded positions write garbage K/V past Tp; decode masks them
             # out via lengths[slot] = Tp, and causality protects the prefix
-            logits, pre = _prefill_padded(self.params, toks, pre, self.cfg)
+            last_logits, pre = self.programs.prefill_chunk(self.params, toks, pre, take)
             _PREFILL_TOKENS.inc(take + pad)
+            _PREFILL_CHUNKS.inc(path=self.programs.prefill_path(pos, take))
+            req.prefill_chunks += 1
             pos += take
             if last:
-                last_logits = logits[:, take - 1].astype(jnp.float32)
                 if (
                     req.temperature is not None or req.top_k is not None
                     or req.top_p is not None
@@ -853,7 +935,7 @@ class ContinuousBatcher:
                     _ADMIT_BLOCKED.inc(reason="pages")
                     break  # pages short: admission waits for retirements
             else:
-                self.cache = _insert_prefill(
+                self.cache = self.programs.insert_dense(
                     self.cache, pre, jnp.int32(slot), jnp.int32(Tp)
                 )
             self._staged.pop(0)
@@ -882,8 +964,6 @@ class ContinuousBatcher:
         """Reserve pages, attach the shared prefix, copy the prefilled span,
         install the page-table row. False → pool short, caller waits."""
         import numpy as np
-
-        from tony_tpu.models.paged_cache import insert_paged_prefill
 
         # a retired-but-unflushed slot being re-admitted still holds its old
         # reservation — release it BEFORE the availability check (the freed
@@ -920,16 +1000,15 @@ class ContinuousBatcher:
         # (a [nc]-shaped arg would re-compile per distinct nc)
         fp = np.zeros(self.max_pages, np.int32)
         fp[:nc] = fresh[:nc]
-        self.cache = insert_paged_prefill(
-            self.cache, pre.k, pre.v, fp, pt_row,
-            jnp.int32(slot), jnp.int32(Tp), jnp.int32(len(matched)),
-            n=jnp.int32(nc),
+        self.cache = self.programs.insert(
+            self.cache, pre, fp, pt_row,
+            jnp.int32(slot), jnp.int32(Tp), jnp.int32(len(matched)), jnp.int32(nc),
         )
         # content-address the request's FULL prompt pages so later
         # same-prefix requests reuse them (first writer wins)
-        for j in range(Tp // self.page_len):
-            if j >= len(matched):
-                self.allocator.register(row[j], keys[j])
+        # (no keys where the model shares no pages: nothing is registered)
+        for j in range(len(matched), min(Tp // self.page_len, len(keys))):
+            self.allocator.register(row[j], keys[j])
         self._slot_pages[slot] = row
         return True
 
@@ -961,8 +1040,6 @@ class ContinuousBatcher:
             mask[idle] = True
             mask = jnp.asarray(mask)  # [S] always — one compiled variant
             if self.kv == "paged":
-                from tony_tpu.models.paged_cache import PagedCache
-
                 # release the reservation (registered full-prompt pages park
                 # in the allocator's reuse pool for future prefix hits) and
                 # reset the page-table rows: an idle slot's garbage write
@@ -970,17 +1047,7 @@ class ContinuousBatcher:
                 for s in idle:
                     for p in self._slot_pages.pop(s, []):
                         self.allocator.release(p)
-                lengths, page_table = _mask_zero_paged(
-                    self.cache.lengths, self.cache.page_table, mask
-                )
-                self.cache = PagedCache(
-                    self.cache.k, self.cache.v, lengths, page_table
-                )
-            else:
-                self.cache = SlotCache(
-                    self.cache.k, self.cache.v,
-                    _mask_zero(self.cache.lengths, mask),
-                )
+            self.cache = self.programs.release(self.cache, mask)
 
     def step(self) -> bool:
         """Admit + one decode chunk. Returns True while work remains."""
@@ -1019,20 +1086,25 @@ class ContinuousBatcher:
                 self._samp_dirty = False
             samp = self._samp_dev
         if use_ragged:
-            toks, seq, self.cache = decode_steps(
-                self.params, self.cache, self.tokens, self._split(), self.cfg, h,
-                self.temperature, self.top_k, "ragged", samp,
+            toks, seq, self.cache = self.programs.decode_chunk(
+                self.params, self.cache, self.tokens, self._split(), n=h,
+                temperature=self.temperature, top_k=self.top_k, samp=samp,
             )
         else:
             # length bucket: attention reads only the shortest power-of-two
             # cache prefix covering every active slot through this chunk
-            toks, seq, self.cache = decode_steps_bucketed(
-                self.params, self.cache, self.tokens, self._split(), self.cfg, h,
-                bucket, self.temperature, self.top_k, samp,
+            toks, seq, self.cache = self.programs.decode_chunk_bucketed(
+                self.params, self.cache, self.tokens, self._split(), n=h, bucket=bucket,
+                temperature=self.temperature, top_k=self.top_k, samp=samp,
             )
         self.tokens = toks
         _CHUNKS.inc()
         _DECODE_SLOTS.inc(len(self.running))
+        # what the chunk's steps have in context and may read of it, from the
+        # host's own lengths: step j of slot s sees _slot_len[s] + j + 1 positions
+        context = np.array([self._slot_len[s] for s in self.running])[:, None] + np.arange(1, h + 1)
+        _CONTEXT_TOKENS.inc(int(context.sum()))
+        _VISIBLE_TOKENS.inc(int(np.sum(self.programs.visible_tokens(context))))
         # overlap: queue prefills for the next admissions while the chunk
         # (already dispatched, still in flight) computes; one speculative
         # stage beyond the currently-free slots covers mid-chunk retirement

@@ -52,7 +52,7 @@ from typing import Any
 import jax
 
 from tony_tpu import constants
-from tony_tpu.models.llama import PRESETS, init
+from tony_tpu.models import registry
 from tony_tpu.models.serving import ContinuousBatcher
 from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
@@ -157,7 +157,7 @@ class RequestStream:
         if req is not None:  # None: it died in the inbox, before the engine saw it
             marks[1:3] = req.staged_s, req.slot_s
             attrs.update(prompt_tokens=len(req.prompt), prefix_tokens=req.prefix_tokens,
-                         slot=req.slot)
+                         chunks=req.prefill_chunks, slot=req.slot)
         out = []
         for i, label in enumerate(("queue", "prefill", "emit")):
             current = not marks[i + 1]  # no later stamp: the stage it is in
@@ -955,9 +955,14 @@ def _resolve_kv(args) -> str:
     return "paged"
 
 
+def init(key, cfg) -> dict:
+    """Random weights for a preset: its own module's ``init``."""
+    return registry.module_of(cfg).init(key, cfg)
+
+
 def build_engine(args) -> ContinuousBatcher:
     args.kv = _resolve_kv(args)
-    cfg = PRESETS[args.preset]
+    cfg = registry.presets()[args.preset]
     if args.hf:
         from tony_tpu.models.convert import from_hf
 
@@ -1000,7 +1005,7 @@ def main(argv: list[str] | None = None) -> int:
     p = argparse.ArgumentParser(
         prog="tony-serve", description="continuous-batching HTTP inference server"
     )
-    p.add_argument("--preset", default="tiny", choices=sorted(PRESETS),
+    p.add_argument("--preset", default="tiny", choices=sorted(registry.presets()),
                    help="model preset (random init unless --hf)")
     p.add_argument("--hf", default="", help="HuggingFace checkpoint dir to load")
     p.add_argument("--tokenizer", default="", help="tokenizer dir for text prompts")

@@ -401,3 +401,189 @@ def paged_decode_attention(
         ),
     )(*operands)
     return o.reshape(S, H, Dh)
+
+
+#: pages fetched together by the sparse kernel: one wait covers 2 x this many
+#: copies in flight, so a page of 64 positions (16 KB a head) does not pay a
+#: DMA's latency each
+SPARSE_BATCH = 8
+
+
+@jax.jit
+def sparse_paged_decode_attention(
+    q: jax.Array,             # [S, H, Dh] — one new token per slot
+    kp: jax.Array,            # [L, P, Hkv, page_len, Dh] — WHOLE page pool (read-only)
+    vp: jax.Array,
+    layer: jax.Array,         # [] int32 — which layer's pages to read
+    pages: jax.Array,         # [S, Hkv, N] int32 — PHYSICAL pages to read, the first counts[s, h] live
+    logical: jax.Array,       # [S, Hkv, N] int32 — the logical page each stands for (its positions)
+    full: jax.Array,          # [S, Hkv, N] bool/int — every position of the page is visible (else: from win_lo on)
+    counts: jax.Array,        # [S, Hkv] int32
+    lengths: jax.Array,       # [S] int32 — CACHE positions (staged ones among them, current excluded)
+    win_lo: jax.Array,        # [S] int32 — first position the local window shows
+    *,
+    cur_k: jax.Array,         # [S, Hkv, Dh]
+    cur_v: jax.Array,
+    staged_k: jax.Array,      # [S, W, Hkv, Dh] — the decode chunk's staging
+    staged_v: jax.Array,
+    staged_count: jax.Array,  # [S] int32
+) -> jax.Array:
+    """Decode attention over a CHOSEN part of a paged cache; returns o [S, H, Dh].
+
+    ``paged_decode_attention`` reads every page of a slot's table up to its
+    length. Here the caller names the pages, a list for each slot and kv head
+    (a learned score chose them: ops/sparse_attention.py), and nothing else of
+    the pool is touched: page ``pages[s, h, c]`` holds the positions of logical
+    page ``logical[s, h, c]``, all of them visible where ``full`` says so and
+    otherwise from ``win_lo[s]`` on (the first page of the local window is cut
+    by position, not by page). Positions at or past the pool's part of the
+    slot (``lengths - staged_count``) are masked; the staged window and the
+    current token fold in as explicit online-softmax steps, as in the dense
+    kernel. One grid instance a (slot, kv head): the group's query heads share
+    one list, so they share one read. Pages arrive ``SPARSE_BATCH`` at a time,
+    double-buffered, each head's ``[page_len, Dh]`` slab a copy of its own.
+    HBM traffic a call is Σ counts x page_len positions, whatever the context.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, Dh = q.shape
+    if kp.ndim != 5:
+        raise ValueError(f"kp {kp.shape}: the operand is the whole pool [L, P, Hkv, page_len, Dh]")
+    Hkv, page_len = kp.shape[2:4]
+    n_rep, G = H // Hkv, SPARSE_BATCH
+    N = pages.shape[2]
+    pad = (-N) % G
+    rows = S * Hkv
+
+    def flat(a):  # [S, Hkv, N] -> [S*Hkv, N padded to whole batches]
+        return jnp.pad(a.astype(jnp.int32).reshape(rows, N), ((0, 0), (0, pad)))
+
+    meta = flat(logical) * 2 + flat(full)
+    info = jnp.stack([lengths, staged_count, win_lo], axis=1).astype(jnp.int32)   # [S, 3]
+    W = staged_k.shape[1]
+    scale = Dh ** -0.5
+
+    def kern(pages_ref, meta_ref, counts_ref, info_ref, layer_ref,
+             q_ref, ck_ref, cv_ref, sk_ref, sv_ref, k_hbm, v_hbm, o_ref):
+        s_i, h_i = pl.program_id(0), pl.program_id(1)
+        row = s_i * Hkv + h_i
+        length, staged, lo = info_ref[s_i, 0], info_ref[s_i, 1], info_ref[s_i, 2]
+        pool_len = jnp.maximum(length - staged, 0)
+        count = counts_ref[row, 0]
+        nb = pl.cdiv(count, G)
+        kl, vl = k_hbm.at[layer_ref[0]], v_hbm.at[layer_ref[0]]
+
+        def body(k_buf, v_buf, sem):
+            qf = q_ref[0, 0].astype(jnp.float32) * scale            # [n_rep, Dh]
+
+            def dma(slot, b):
+                out = []
+                for g in range(G):
+                    # past the list's end: fetch its last page again (masked below)
+                    page = pages_ref[row, jnp.minimum(b * G + g, count - 1)]
+                    out.append(pltpu.make_async_copy(kl.at[page, h_i], k_buf.at[slot, g], sem.at[slot, 0]))
+                    out.append(pltpu.make_async_copy(vl.at[page, h_i], v_buf.at[slot, g], sem.at[slot, 1]))
+                return out
+
+            @pl.when(nb > 0)
+            def _warmup():
+                for d in dma(0, 0):
+                    d.start()
+
+            col = jax.lax.broadcasted_iota(jnp.int32, (1, G * page_len), 1)
+            gidx, off = col // page_len, col % page_len
+
+            def step(b, carry):
+                m, l, acc = carry
+                cur, nxt = b % 2, (b + 1) % 2
+
+                @pl.when(b + 1 < nb)
+                def _():
+                    for d in dma(nxt, b + 1):
+                        d.start()
+
+                for d in dma(cur, b):
+                    d.wait()
+                k = k_buf[cur].reshape(G * page_len, Dh).astype(jnp.float32)
+                v = v_buf[cur].reshape(G * page_len, Dh).astype(jnp.float32)
+                s = jax.lax.dot_general(qf, k, (((1,), (1,)), ((), ())),
+                                        preferred_element_type=jnp.float32)     # [n_rep, G*page_len]
+                pos = jnp.zeros_like(col)
+                shown = jnp.zeros_like(col)
+                for g in range(G):
+                    mg = meta_ref[row, b * G + g]
+                    live = (b * G + g < count).astype(jnp.int32)
+                    pos = jnp.where(gidx == g, (mg >> 1) * page_len + off, pos)
+                    # 2: every position; 1: the window's; 0: not in the list
+                    shown = jnp.where(gidx == g, live * (1 + (mg & 1)), shown)
+                valid = (pos < pool_len) & ((shown == 2) | ((shown == 1) & (pos >= lo)))
+                s = jnp.where(valid, s, -1e30)
+                m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + p.sum(axis=1, keepdims=True)
+                acc = acc * alpha + jax.lax.dot_general(
+                    p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            m0 = jnp.full((n_rep, 1), -1e30, jnp.float32)
+            l0 = jnp.zeros((n_rep, 1), jnp.float32)
+            acc0 = jnp.zeros((n_rep, Dh), jnp.float32)
+            m, l, acc = jax.lax.fori_loop(0, nb, step, (m0, l0, acc0))
+
+            def fold_one(k1, v1, carry):
+                m, l, acc = carry
+                s1 = jnp.sum(qf * k1, axis=1, keepdims=True)         # [n_rep, 1]
+                m_new = jnp.maximum(m, s1)
+                alpha, p1 = jnp.exp(m - m_new), jnp.exp(s1 - m_new)
+                return m_new, l * alpha + p1, acc * alpha + p1 * v1
+
+            # the chunk's earlier tokens (positions pool_len .. length-1): always
+            # inside the local window
+            def staged_step(j, carry):
+                return fold_one(sk_ref[0, 0, j].astype(jnp.float32), sv_ref[0, 0, j].astype(jnp.float32), carry)
+
+            m, l, acc = jax.lax.fori_loop(0, staged, staged_step, (m, l, acc))
+            m, l, acc = fold_one(ck_ref[0, 0, 0].astype(jnp.float32), cv_ref[0, 0, 0].astype(jnp.float32), (m, l, acc))
+            o_ref[0, 0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+
+        pl.run_scoped(
+            body,
+            k_buf=pltpu.VMEM((2, G, page_len, Dh), kp.dtype),
+            v_buf=pltpu.VMEM((2, G, page_len, Dh), vp.dtype),
+            sem=pltpu.SemaphoreType.DMA((2, 2)),
+        )
+
+    def per_head(width):
+        return pl.BlockSpec((1, 1, width, Dh), lambda s, h, *_: (s, h, 0, 0))
+
+    def per_head_rows(width):  # a row an index of a leading dimension: [S, Hkv, width, 1, Dh]
+        return pl.BlockSpec((1, 1, width, 1, Dh), lambda s, h, *_: (s, h, 0, 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,  # pages, meta, counts, info, layer
+        grid=(S, Hkv),
+        in_specs=[
+            per_head(n_rep), per_head_rows(1), per_head_rows(1), per_head_rows(W), per_head_rows(W),
+            pl.BlockSpec(memory_space=pl.ANY), pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=per_head(n_rep),
+    )
+    read = S * Hkv * N * page_len
+    o = pl.pallas_call(
+        kern,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, n_rep, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret(),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * n_rep * read * Dh, bytes_accessed=2 * read * Dh * kp.dtype.itemsize,
+            transcendentals=n_rep * read),
+    )(
+        flat(pages), meta, counts.astype(jnp.int32).reshape(rows, 1), info,
+        jnp.reshape(layer, (1,)).astype(jnp.int32),
+        q.reshape(S, Hkv, n_rep, Dh), cur_k[:, :, None, None], cur_v[:, :, None, None],
+        staged_k.transpose(0, 2, 1, 3)[:, :, :, None], staged_v.transpose(0, 2, 1, 3)[:, :, :, None], kp, vp,
+    )
+    return o.reshape(S, H, Dh)

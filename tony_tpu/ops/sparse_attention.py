@@ -1,0 +1,208 @@
+"""Learned block-sparse attention (InfLLM-v2, as MiniCPM4's `sparse_config`).
+
+Past ``dense_len`` positions of context a query no longer reads every key. It
+scores COMPRESSED keys (the mean of ``kernel`` keys, one every ``stride``), a
+block of ``block`` tokens takes the largest softmax weight among the
+compressed keys that overlap it, summed over the query heads of one kv group,
+and the query reads: the first ``init_blocks`` blocks, the ``topk`` blocks of
+the highest score (the first ones counted among them), and the last ``window``
+tokens. One set a kv group and query position. At or below ``dense_len`` it is
+plain causal attention.
+
+Here: the compressed keys, the block scores (float32: a block is chosen or it
+is not, and the choice must not hang on bf16 rounding), the chosen blocks, and
+the two forms the chosen set takes: a token mask for a prefill chunk
+(``masked_prefill_attention``: a flash kernel that takes the mask as an
+operand; it computes every staged key under the mask and skips none yet) and a
+list of pages a (slot, kv head) for decode (``visible_pages`` ->
+ops/decode_attention.sparse_paged_decode_attention, which reads only those).
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+
+from tony_tpu.ops.interpret import interpret
+
+_HI = jax.lax.Precision.HIGHEST
+
+
+class SparseSpec(NamedTuple):
+    kernel: int       # keys a compressed key averages
+    stride: int       # distance between compressed keys
+    block: int        # tokens a selectable block
+    topk: int         # blocks a query reads (the first ones among them)
+    init_blocks: int  # blocks at the start always read
+    window: int       # last tokens always read
+    dense_len: int    # contexts up to this read everything
+
+    def check(self, max_len: int) -> None:
+        if self.kernel % self.stride or self.block % self.stride or max_len % self.block:
+            raise ValueError(f"sparse sizes {self} at max_len {max_len}: kernel and block must be whole strides "
+                             "and max_len whole blocks")
+
+    def list_len(self) -> int:
+        """The most pages one (slot, head) list can name, a page a block."""
+        return max(self.topk + -(-self.window // self.block) + 1, -(-self.dense_len // self.block))
+
+
+def stride_sums(k: jax.Array, spec: SparseSpec) -> jax.Array:
+    """k [T, Hkv, d] -> float32 [T // stride, Hkv, d]: the sum of each stride's keys."""
+    T, hkv, d = k.shape
+    return k.astype(jnp.float32).reshape(T // spec.stride, spec.stride, hkv, d).sum(1)
+
+
+def compress_keys(k: jax.Array, spec: SparseSpec) -> jax.Array:
+    """k [T, Hkv, d] -> float32 [T // stride, Hkv, d]: compressed key j is the
+    mean of k[stride*j : stride*j + kernel]; the last ``kernel/stride - 1``
+    entries run past T and stand for no kernel (never valid)."""
+    a = stride_sums(k, spec)
+    r = spec.kernel // spec.stride
+    pad = jnp.pad(a, ((0, r - 1), (0, 0), (0, 0)))
+    return sum(pad[i:i + a.shape[0]] for i in range(r)) / spec.kernel
+
+
+def block_scores(q: jax.Array, kc: jax.Array, n_ctx: jax.Array, spec: SparseSpec) -> jax.Array:
+    """q [T, Hkv, G, d] (G query heads a group), kc [nK, Hkv, d] compressed
+    keys, n_ctx [T] the context of each query (its position + 1). Returns
+    float32 [T, Hkv, nB]: the block's score; -1 for a block no finished kernel
+    overlaps; +inf for the first ``init_blocks``."""
+    nK, d = kc.shape[0], kc.shape[-1]
+    per, extra = spec.block // spec.stride, spec.kernel // spec.stride - 1
+    nB = nK // per
+    s = jnp.einsum("tgrd,kgd->tgrk", q.astype(jnp.float32), kc.astype(jnp.float32), precision=_HI) * d ** -0.5
+    n_valid = jnp.maximum((n_ctx - spec.kernel) // spec.stride + 1, 0)          # kernels that end inside the context
+    valid = (jnp.arange(nK)[None, :] < n_valid[:, None])[:, None, None, :]
+    m = jnp.max(jnp.where(valid, s, -1e30), axis=-1, keepdims=True)
+    e = jnp.where(valid, jnp.exp(s - m), 0.0)
+    p = e / jnp.maximum(e.sum(-1, keepdims=True), 1e-30)
+    # block b overlaps kernels b*per - extra .. b*per + per - 1
+    pp = jnp.pad(p, ((0, 0), (0, 0), (0, 0), (extra, 0)))
+    best = functools.reduce(jnp.maximum, [pp[..., o:o + nB * per:per] for o in range(per + extra)])
+    score = best.sum(axis=2)                                                    # over the group's heads
+    b = jnp.arange(nB)
+    has_kernel = jnp.maximum(b * per - extra, 0)[None, :] < n_valid[:, None]
+    score = jnp.where(has_kernel[:, None, :], score, -1.0)
+    return jnp.where((b < spec.init_blocks)[None, None, :], jnp.inf, score)
+
+
+def chosen_blocks(score: jax.Array, n_ctx: jax.Array, spec: SparseSpec) -> jax.Array:
+    """[T, Hkv, nB] bool: the blocks a query reads whole. All of them where the
+    context is dense; else the ``topk`` of the highest score, ties to the
+    earlier block."""
+    k = min(spec.topk, score.shape[-1])
+    kth = jax.lax.top_k(score, k)[0][..., -1:]
+    # neighbouring blocks share a compressed key, so equal scores are common:
+    # of those that tie for the last places the earlier blocks are taken
+    above, tied = score > kth, score == kth
+    room = k - above.sum(-1, keepdims=True)
+    top = (above | (tied & (jnp.cumsum(tied, axis=-1) <= room))) & (score >= 0)
+    return top | (n_ctx <= spec.dense_len)[:, None, None]
+
+
+def prefill_mask(chosen: jax.Array, q_pos: jax.Array, n_keys: int, spec: SparseSpec) -> jax.Array:
+    """chosen [T, Hkv, nB], q_pos [T] -> int8 [Hkv, T, n_keys]: 1 where the
+    query may read the key (causal; its block chosen or inside the window)."""
+    key = jnp.arange(n_keys)
+    by_block = jnp.repeat(chosen.transpose(1, 0, 2), spec.block, axis=2)[:, :, :n_keys]
+    near = key[None, :] > q_pos[:, None] - spec.window
+    return ((key[None, :] <= q_pos[:, None])[None] & (by_block | near[None])).astype(jnp.int8)
+
+
+def visible_pages(chosen: jax.Array, pool_len: jax.Array, length: jax.Array, spec: SparseSpec):
+    """The decode form of a chosen set. chosen [S, Hkv, nB]; pool_len [S]
+    positions that lie in the page pool; length [S] the current token's
+    position. A page is a block. Returns (logical [S, Hkv, N] ascending, full
+    [S, Hkv, N], counts [S, Hkv], win_lo [S]): the pages that hold a visible
+    pool position, whether all of the page is visible or only the window's
+    part of it, how many, and where the window starts."""
+    nB, N = chosen.shape[-1], min(spec.list_len(), chosen.shape[-1])
+    b = jnp.arange(nB)
+    win_lo = jnp.maximum(length + 1 - spec.window, 0)
+    in_pool = (b[None, :] * spec.block < pool_len[:, None])[:, None, :]
+    in_window = (b[None, :] >= (win_lo // spec.block)[:, None])[:, None, :]
+    shown = in_pool & (chosen | in_window)
+    order, logical = jax.lax.top_k(jnp.where(shown, nB - b, 0), N)             # shown pages first, ascending
+    full = jnp.take_along_axis(chosen, logical, axis=-1) & (order > 0)
+    return logical, full, shown.sum(-1).astype(jnp.int32), win_lo
+
+
+def _flash_masked_kernel(kmax_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, m_sc, l_sc, acc_sc, *, n_rep, scale):
+    from jax.experimental import pallas as pl
+
+    kb = pl.program_id(2)
+
+    @pl.when(kb == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, -1e30)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    @pl.when(kb <= kmax_ref[pl.program_id(1)])
+    def _tile():
+        q, k, v = q_ref[0, 0], k_ref[0], v_ref[0]                   # [n_rep*bq, d], [bk, d]
+        bq = q.shape[0] // n_rep
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        ok = mask_ref[0].astype(jnp.float32) > 0                    # [bq, bk], one mask for the group's heads
+        s = jnp.where(ok[None], s.reshape(n_rep, bq, -1), -1e30).reshape(n_rep * bq, -1)
+        m_new = jnp.maximum(m_sc[...], s.max(axis=1, keepdims=True))
+        p = jnp.where(s > -1e29, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_sc[...] - m_new)
+        l_sc[...] = l_sc[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    @pl.when(kb == pl.num_programs(2) - 1)
+    def _done():
+        o_ref[0, 0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k"))
+def masked_prefill_attention(q, k, v, mask, n_keys, *, block_q: int = 128, block_k: int = 512):
+    """q [Hkv, G, T, d]; k, v [Hkv, Tk, d] (a request's staged keys, the
+    chunk's own among them); mask int8 [Hkv, T, Tk]; n_keys [] int32, the keys
+    that exist (tiles wholly past them are neither fetched nor computed).
+    Returns [Hkv, G, T, d]. Softmax over the keys the mask lets through, in
+    float32; the group's G query heads share a mask row, so a tile's scores
+    are one [G x block_q, block_k] product."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    hkv, g, t, d = q.shape
+    tk = k.shape[1]
+    bq, bk = min(block_q, t), min(block_k, tk)
+    if t % bq or tk % bk:
+        raise ValueError(f"{t} queries / {tk} keys do not divide into tiles of {bq} / {bk}")
+    nq, nk = t // bq, tk // bk
+    # a q tile's rows: head r's bq queries, then head r+1's
+    qt = q.reshape(hkv, g, nq, bq, d).transpose(0, 2, 1, 3, 4).reshape(hkv, nq, g * bq, d)
+    last = jnp.broadcast_to(jnp.maximum(n_keys - 1, 0) // bk, (nq,)).astype(jnp.int32)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hkv, nq, nk),
+        in_specs=[
+            pl.BlockSpec((1, 1, g * bq, d), lambda h, i, j, last: (h, i, 0, 0)),
+            pl.BlockSpec((1, bk, d), lambda h, i, j, last: (h, jnp.minimum(j, last[i]), 0)),
+            pl.BlockSpec((1, bk, d), lambda h, i, j, last: (h, jnp.minimum(j, last[i]), 0)),
+            pl.BlockSpec((1, bq, bk), lambda h, i, j, last: (h, i, jnp.minimum(j, last[i]))),
+        ],
+        out_specs=pl.BlockSpec((1, 1, g * bq, d), lambda h, i, j, last: (h, i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((g * bq, 1), jnp.float32), pltpu.VMEM((g * bq, 1), jnp.float32),
+                        pltpu.VMEM((g * bq, d), jnp.float32)],
+    )
+    o = pl.pallas_call(
+        functools.partial(_flash_masked_kernel, n_rep=g, scale=d ** -0.5),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret(),
+        cost_estimate=pl.CostEstimate(flops=4 * hkv * g * t * tk * d, transcendentals=hkv * g * t * tk,
+                                      bytes_accessed=hkv * t * tk + 2 * hkv * nq * tk * d * k.dtype.itemsize),
+    )(last, qt, k, v, mask)
+    return o.reshape(hkv, nq, g, bq, d).transpose(0, 2, 1, 3, 4).reshape(hkv, g, t, d)
