@@ -149,21 +149,33 @@ def test_phases_tile_the_engine_threads_time(params, monkeypatch):
     assert srv.engine.phase._name is None  # the loop closed its last phase
 
 
-def test_chunk_slot_and_prefill_counters_match_a_hand_count(params):
-    """Two slots, chunks of 4. Pass 1 admits A and B (1 token each) and
-    decodes both to 5: B is done. Pass 2 admits C into B's slot and decodes
-    A to 9 and C to 5: both done. Two chunks, two slots each; three prompts
-    of 3 tokens, each padded to the smallest bucket (16)."""
+def admitted(under):
+    return serving._ADMISSIONS.value(under=under)
+
+
+def test_chunk_slot_prefill_and_admission_counters_match_a_hand_count(params):
+    """Two slots, chunks of 4. Pass 1 finds nothing running: it admits A and B
+    with the device idle and takes their first tokens at once. Pass 2
+    dispatches chunk 1 over A and B; B's budget (5) ends inside it, so its slot
+    counts as free and C is staged, prefilled and inserted behind the chunk;
+    the chunk's tokens take A to 5 and finish B. Pass 3 dispatches chunk 2 over
+    A and C (whose first token is read with the chunk's): A reaches 9, C 5,
+    both done. Two chunks, two slots each; three prompts of 3 tokens, each
+    padded to the smallest bucket (16); two admissions under nothing, one
+    under a chunk."""
     counters = (serving._CHUNKS, serving._DECODE_SLOTS, serving._PREFILL_TOKENS)
-    before = [c.value() for c in counters]
+    before = [c.value() for c in counters] + [admitted("idle"), admitted("chunk")]
     eng = engine(params)
     rids = [eng.submit([1, 2, 3], n) for n in (9, 5, 5)]
     assert [eng.request(r).max_new_tokens for r in rids] == [9, 5, 5] and eng.request(99) is None
-    passes = 0
+    lengths = []
     while eng.step():
-        passes += 1
-    assert [len(eng.done[r]) for r in rids] == [9, 5, 5] and passes == 1
-    assert [c.value() - b for c, b in zip(counters, before)] == [2, 4, 48]
+        lengths.append([len(r.out) for r in sorted(eng.running.values(), key=lambda r: r.rid)])
+    # after pass 1: A and B hold their first tokens; after pass 2: A at 5, C in B's slot with nothing on the host yet
+    assert lengths == [[1, 1], [5, 0]]
+    assert [len(eng.done[r]) for r in rids] == [9, 5, 5]
+    after = [c.value() for c in counters] + [admitted("idle"), admitted("chunk")]
+    assert [a - b for a, b in zip(after, before)] == [2, 4, 48, 2, 1]
     assert eng.phase._name is None  # a bare engine leaves no phase open between passes
     assert eng.request(rids[0]) is None  # done: the engine holds it no longer
 
@@ -256,6 +268,20 @@ def test_registry_delta_reads_the_change_between_two_snapshots():
     old = {"t": 0.0, "metrics": snap0["metrics"][:1]}
     for drive in ({"snap0": old, "snap1": old}, {"snap0": None, "snap1": snap1}, {"snap0": snap1, "snap1": snap1}):
         assert registry_delta.read({"drive": drive}, **metric_args("queue_wait_ms.serve")) is None
+
+
+def test_admit_overlap_is_the_share_of_the_windows_admissions_made_under_a_chunk():
+    def snap(chunk, idle):
+        return {"t": 0.0, "metrics": [{"name": "tony_serve_admissions_total", "type": "counter", "samples": [
+            {"labels": {"under": "chunk"}, "value": chunk}, {"labels": {"under": "idle"}, "value": idle}]}]}
+
+    args = metric_args("admit_overlap_pct.serve")
+    read = lambda snap0, snap1: registry_delta.read({"drive": {"snap0": snap0, "snap1": snap1}}, **args)
+    assert read(snap(10, 64), snap(490, 64)) == pytest.approx(100.0)  # the ramp's idle admissions lie before the window
+    assert read(snap(10, 4), snap(40, 14)) == pytest.approx(75.0)     # 30 of the window's 40
+    # a program from before the counter (the parent), or a window that admitted nobody: nothing, and no error
+    old = snapshot((1.0, 1), {}, {}, 1)
+    assert read(old, old) is None and read(snap(5, 5), snap(5, 5)) is None and read(None, snap(5, 5)) is None
 
 
 def test_gap_by_span_lays_the_gaps_under_the_phase_that_covers_them():

@@ -71,7 +71,7 @@ class TestContinuousBatching:
         # budget > decode_chunk so r1 is still RUNNING after one step (a
         # request finishing inside the step re-populates _retired_slots)
         r1 = eng.submit(list(np.asarray(_prompt(7, seed=31)[0])), max_new_tokens=20)
-        eng.step()  # admits r1 (maybe into slot0), then flushes retirements
+        eng.step()  # nothing running: flushes retirements, then admits r1 (maybe into slot0)
         assert not eng._retired_slots  # flushed
         lengths = np.asarray(eng.cache.lengths)
         for s in range(2):
@@ -143,6 +143,36 @@ class TestContinuousBatching:
         out = eng.run()[rid]
         assert len(out) == 4
         assert all(0 <= t < CFG.vocab_size for t in out)
+
+
+class TestIdleAdmission:
+    def test_start_up_and_drain_admit_with_nothing_running(self):
+        """No chunk to hide behind: a pass that finds nothing running admits at
+        once, takes the first token at once (a 1-token request is done there,
+        with no decode chunk at all), and dispatches no chunk; the next pass
+        does. After the engine has drained, the same again."""
+        from tony_tpu.models import serving
+
+        def counts():
+            return [serving._CHUNKS.value(), serving._ADMISSIONS.value(under="idle"),
+                    serving._ADMISSIONS.value(under="chunk")]
+
+        params = _params()
+        eng = ContinuousBatcher(params, CFG, num_slots=2, max_len=64, decode_chunk=4)
+        for prompt_seed in (60, 61):  # start-up, then after a drain
+            p = _prompt(5, seed=prompt_seed)
+            before = counts()
+            rid = eng.submit(list(np.asarray(p[0])), max_new_tokens=6)
+            one = eng.submit([9, 8, 7], max_new_tokens=1)
+            assert eng.step()
+            assert [a - b for a, b in zip(counts(), before)] == [0, 2, 0]
+            assert [len(r.out) for r in eng.running.values()] == [1] and len(eng.done[one]) == 1
+            assert next(iter(eng.running.values())).slot_s > 0
+            eng.run()
+            assert [a - b for a, b in zip(counts(), before)] == [2, 2, 0]
+            want = generate.generate(params, p, CFG, max_new_tokens=6)
+            np.testing.assert_array_equal(np.asarray(eng.done[rid]), np.asarray(want[0]))
+            assert not eng.running and not np.asarray(eng.cache.lengths).any()
 
 
 class TestLengthBucketing:
@@ -315,10 +345,10 @@ class TestChunkedPrefill:
             params, cfg, num_slots=1, max_len=64, prefill_chunk=4, decode_chunk=2,
         )
         r0 = eng.submit(list(np.asarray(_prompt(3, seed=30)[0])), max_new_tokens=8)
-        eng.step()  # admit r0
+        eng.step()  # nothing running: r0 prefilled and admitted, its first token taken
         r1 = eng.submit(list(np.asarray(_prompt(20, seed=31)[0])), max_new_tokens=3)
         produced_before = len(eng.running[0].out) if 0 in eng.running else 0
-        eng.step()  # r1 advances ONE prefill chunk; r0 decodes a chunk
+        eng.step()  # r0's chunk is dispatched; behind it r1 advances ONE prefill chunk
         produced_after = len(eng.running[0].out) if 0 in eng.running else 99
         assert produced_after > produced_before  # decode kept flowing
         results = eng.run()
@@ -410,10 +440,11 @@ class TestCancel:
         params = _params()
         eng = ContinuousBatcher(params, CFG, num_slots=1, max_len=64, decode_chunk=4)
         r = eng.submit([1, 2, 3], max_new_tokens=50)
-        eng.step()  # admit + first chunk
-        assert 0 in eng.running
+        eng.step()  # admitted with nothing running
+        eng.step()  # first chunk
+        assert 0 in eng.running and len(eng.running[0].out) == 5
         assert eng.cancel(r) is True
-        eng.step()  # the cancelled slot retires at this chunk boundary
+        eng.step()  # the cancelled slot is given up at this chunk's dispatch
         assert not eng.running
         assert r not in eng.done  # cancelled output is discarded, not surfaced
         # the slot is genuinely free: a new request admits and completes
@@ -441,7 +472,7 @@ class TestCancel:
         eng.run()
         avail0 = eng.allocator.available()
         rB = eng.submit(prompt, max_new_tokens=2)
-        eng._stage_prefills(1, advance=False)  # stage → prefix pages pinned
+        eng._stage_prefills(1)  # stage → prefix pages pinned, the rest prefilled
         assert eng._staged and eng._staged[0].matched, "test setup: no prefix hit"
         assert eng.cancel(rB) is True
         assert eng.allocator.available() == avail0  # pins released

@@ -24,9 +24,11 @@ two implementations:
   power-of-two cache prefix covering every active slot — the r2 scheme,
   kept as the CPU/test path and fallback.
 
-Host/device split: admission, queueing, EOS/termination bookkeeping run on
-the host between steps (microseconds, overlapped with the device step);
-everything per-token is one jitted call over all slots. Weights may be an
+Host/device split: a pass dispatches its decode chunk FIRST and does
+admission (staging, prefills, pages, inserts) while the chunk is in flight,
+queued on the device behind it (``ContinuousBatcher.step``); queueing and
+EOS/termination bookkeeping run on the host between chunks; everything
+per-token is one jitted call over all slots. Weights may be an
 int8-quantized tree (ops/quant.py) for the dense family — the same ``_mm``
 dispatch as generate.py serves both.
 """
@@ -86,9 +88,14 @@ _ADMIT_BLOCKED = obs_metrics.counter(
     "tony_serve_admit_blocked_total",
     "engine passes in which a waiting request could not be admitted, by what was short",
     labelnames=("reason",))
+_ADMISSIONS = obs_metrics.counter(
+    "tony_serve_admissions_total",
+    "requests given a slot, by what the device was doing at their insert: a decode chunk in flight, or nothing",
+    labelnames=("under",))
 
 
-PHASES = ("intake", "admit", "prefill_wait", "dispatch", "decode_wait", "emit", "idle")
+# in the order a pass runs them (``ContinuousBatcher.step``)
+PHASES = ("intake", "dispatch", "admit", "prefill_wait", "decode_wait", "emit", "idle")
 _ANNOTATION = {phase: "tony.serve." + phase for phase in PHASES}
 
 
@@ -499,6 +506,9 @@ class _Request:
     slot_s: float = 0.0
     prefix_tokens: int = 0            # prompt tokens reused from the prefix cache
     prefill_chunks: int = 0           # prefill programs dispatched for it
+    # its first token while only the device has it: a request admitted behind
+    # a decode chunk takes the host copy with the tokens of its own first chunk
+    first: object = None
 
     def is_done(self, eos_id: int) -> bool:
         """THE termination predicate — budget spent, EOS emitted, or the
@@ -506,6 +516,14 @@ class _Request:
         this one method, so a cancelled slot frees within one decode chunk."""
         return self.cancelled or len(self.out) >= self.max_new_tokens or (
             eos_id >= 0 and bool(self.out) and self.out[-1] == eos_id
+        )
+
+    def ends_within(self, h: int) -> bool:
+        """What the host knows BEFORE a chunk of ``h`` tokens has run: the
+        request is cancelled, or its budget ends inside the chunk. (An EOS is
+        known only once the chunk's tokens are on the host.)"""
+        return self.cancelled or (
+            len(self.out) + (self.first is not None) + h >= self.max_new_tokens
         )
 
 
@@ -522,7 +540,8 @@ class _Staged:
 
 
 class ContinuousBatcher:
-    """Slot-based continuous batching: admit → decode → retire, every step.
+    """Slot-based continuous batching: every pass dispatches a decode chunk,
+    admits in its shadow, then emits and retires (``step``).
 
     One engine instance owns S slots over a shared static KV cache. Requests
     are admitted into free slots as they arrive (prefill padded to a bucket
@@ -792,13 +811,13 @@ class ContinuousBatcher:
         hi = min(Tp + -(-max_new // h) * h, self.max_len)
         return -(-hi // self.page_len)
 
-    def _stage_prefills(self, budget: int, advance: bool = True):
-        """Stage up to ``budget`` pending requests and (when ``advance``)
-        run prefill work for every staged entry. The advancing call site is
-        AFTER the decode chunk is dispatched, so prefill compute queues
-        behind it instead of delaying it; admission-time staging passes
-        ``advance=False`` (unless nothing is decoding) to keep the
-        one-chunk-per-step stall bound honest."""
+    def _stage_prefills(self, budget: int):
+        """Stage pending requests until ``budget`` are staged, and run prefill
+        work for every staged entry: one chunk each (the one-chunk-per-pass
+        stall bound), or the whole prompt when unchunked. Called from
+        ``_admit``, after the pass's decode chunk is dispatched, so prefill
+        compute queues behind the chunk instead of delaying it; with nothing
+        decoding it runs at once."""
         while self.pending and len(self._staged) < budget:
             req = self.pending.pop(0)
             req.staged_s = time.time()
@@ -809,31 +828,30 @@ class ContinuousBatcher:
                 entry.keys = prefix_keys(req.prompt, self.page_len)
                 self._match_prefix_into(entry)
             self._staged.append(entry)
-        if advance:
-            # burst dedup: a staged entry whose FIRST full page matches ANY
-            # earlier still-staged entry defers its prefill — the earlier
-            # one admits and registers its pages, and this one re-matches
-            # them (_advance_prefill) instead of recomputing. The leader
-            # keeps claiming its key even after ITS prefill completes:
-            # while it is page-blocked at admission nothing is registered
-            # yet, and letting a follower through would burn a full
-            # redundant prefill per blocked round.
-            seen_first: set[tuple] = set()
-            for entry in self._staged:
-                fk = entry.keys[0] if entry.keys else None
-                defer = (
-                    fk is not None and fk in seen_first
-                    and entry.first is None and entry.pos == 0 and not entry.matched
-                    # once the leader REGISTERED the prefix, followers must
-                    # all proceed this round (they re-match, not recompute) —
-                    # deferring on the raw key would serialize the burst to
-                    # one follower per engine step
-                    and not self.allocator.has_key(fk)
-                )
-                if fk is not None:
-                    seen_first.add(fk)
-                if not defer:
-                    self._advance_prefill(entry)
+        # burst dedup: a staged entry whose FIRST full page matches ANY
+        # earlier still-staged entry defers its prefill — the earlier
+        # one admits and registers its pages, and this one re-matches
+        # them (_advance_prefill) instead of recomputing. The leader
+        # keeps claiming its key even after ITS prefill completes:
+        # while it is page-blocked at admission nothing is registered
+        # yet, and letting a follower through would burn a full
+        # redundant prefill per blocked round.
+        seen_first: set[tuple] = set()
+        for entry in self._staged:
+            fk = entry.keys[0] if entry.keys else None
+            defer = (
+                fk is not None and fk in seen_first
+                and entry.first is None and entry.pos == 0 and not entry.matched
+                # once the leader REGISTERED the prefix, followers must
+                # all proceed this round (they re-match, not recompute) —
+                # deferring on the raw key would serialize the burst to
+                # one follower per engine step
+                and not self.allocator.has_key(fk)
+            )
+            if fk is not None:
+                seen_first.add(fk)
+            if not defer:
+                self._advance_prefill(entry)
 
     def _match_prefix_into(self, entry: _Staged) -> bool:
         """Shared-prefix reuse (paged kv): pin the longest resident chain of
@@ -920,14 +938,23 @@ class ContinuousBatcher:
             if self.prefill_chunk > 0:
                 break  # one chunk per engine step — decode interleaves
 
-    def _admit(self):
+    def _admit(self, under: str):
+        """Everything a request needs between the queue and its first decode
+        chunk: stage, prefill, pages, ``insert``, its token, its sampling row.
+        ``under`` says what the device is doing meanwhile. "chunk": a decode
+        chunk is in flight and all of this queues behind it; the request
+        decodes from the next chunk, and its first token stays a device value
+        until that chunk's tokens are read (the host never waits for a prefill
+        with the next chunk undispatched). "idle": nothing is decoding (start-up,
+        drain, a replica that only prefills), there is nothing to hide behind,
+        and the first token is taken at once."""
         free = self._free_slots()
-        # only compute prefills here when nothing is decoding (startup /
-        # drain); otherwise they advance after the decode chunk dispatches
-        self._stage_prefills(len(free), advance=not self.running)
+        # one speculative stage beyond the free slots: its prefill is done by
+        # the time a slot frees that the host could not foresee (EOS)
+        self._stage_prefills(max(len(free), 1))
         while self._staged and free and self._staged[0].first is not None:
             head = self._staged[0]
-            req, pre, first = head.req, head.pre, head.first
+            req, pre = head.req, head.pre
             slot = free[0]
             Tp = len(req.prompt)
             if self.kv == "paged":
@@ -940,7 +967,7 @@ class ContinuousBatcher:
                 )
             self._staged.pop(0)
             free.pop(0)
-            self.tokens = _set_slot_token(self.tokens, jnp.int32(slot), first)
+            self.tokens = _set_slot_token(self.tokens, jnp.int32(slot), head.first)
             self._samp_temp[slot] = (
                 req.temperature if req.temperature is not None else self.temperature
             )
@@ -948,29 +975,31 @@ class ContinuousBatcher:
             self._samp_topp[slot] = req.top_p if req.top_p is not None else 0.0
             self._samp_dirty = True
             self._slot_len[slot] = Tp
-            req.slot = slot
-            self.phase.to("prefill_wait")  # blocked until the prefill has run
-            req.out.append(int(np.asarray(first)[0]))  # host copy (async-warmed)
-            self.phase.to("admit")
-            req.slot_s = time.time()
+            req.slot, req.first = slot, head.first
             self.running[slot] = req
-            self._retire_if_done(req)  # 1-token requests finish at admission
+            _ADMISSIONS.inc(under=under)
+            if under == "idle":
+                self.phase.to("prefill_wait")  # blocked until the prefill has run
+                self._take_first(req)
+                self.phase.to("admit")
+                if req.is_done(self.eos_id):  # 1-token requests finish at admission
+                    self._free_slot(slot)
+                    self._finish(req)
         if not free and (self.pending or self._staged):
             _ADMIT_BLOCKED.inc(reason="slots")
+        self._sampling_arrays()  # an admission's row is uploaded here, not before the next chunk
+
+    def _take_first(self, req: _Request) -> None:
+        """The host copy of the first token (async-warmed since its prefill)."""
+        req.out.append(int(np.asarray(req.first)[0]))
+        req.first = None
+        req.slot_s = time.time()
 
     def _admit_paged(
         self, req, pre, matched: list[int], keys: list[tuple], slot: int, Tp: int
     ) -> bool:
         """Reserve pages, attach the shared prefix, copy the prefilled span,
         install the page-table row. False → pool short, caller waits."""
-        import numpy as np
-
-        # a retired-but-unflushed slot being re-admitted still holds its old
-        # reservation — release it BEFORE the availability check (the freed
-        # pages may be exactly what covers this admission; checking first
-        # would stall the request one needless chunk)
-        for p in self._slot_pages.pop(slot, []):
-            self.allocator.release(p)
         n_covered = self._pages_needed(Tp, req.max_new_tokens)
         n_fresh = n_covered - len(matched)
         if n_fresh > self.allocator.available():
@@ -1018,23 +1047,29 @@ class ContinuousBatcher:
         self.key, sub = jax.random.split(self.key)
         return sub
 
-    def _retire_if_done(self, req: _Request):
-        if req.slot in self.running and req.is_done(self.eos_id):
-            del self.running[req.slot]
-            if req.cancelled:
-                self._stream_pos.pop(req.rid, None)  # nobody drains it again
-            else:
-                self.done[req.rid] = req.out
-            self._retired_slots.append(req.slot)
-            self._slot_len[req.slot] = 0
+    def _free_slot(self, slot: int) -> None:
+        """The slot's request ends with the chunk in flight (or has ended):
+        from here on the slot may be given away. Its device-side reset waits
+        for ``_flush_retired``, which every pass runs before it admits."""
+        del self.running[slot]
+        self._retired_slots.append(slot)
+        self._slot_len[slot] = 0
+
+    def _finish(self, req: _Request) -> None:
+        """Its last token is in ``out``: hand the answer over."""
+        if req.cancelled:
+            self._stream_pos.pop(req.rid, None)  # nobody drains it again
+        else:
+            self.done[req.rid] = req.out
 
     def _flush_retired(self):
-        """Zero retired slots' device-side lengths in ONE update (idle slots
+        """Zero freed slots' device-side lengths in ONE update (idle slots
         would otherwise keep advancing, clamped at maxT, and the ragged
-        kernel would stream their stale cache every step). Slots re-admitted
-        since retirement are skipped — their length is live again."""
-        idle = [s for s in self._retired_slots if s not in self.running]
-        self._retired_slots = []
+        kernel would stream their stale cache every step). Queued behind the
+        chunk in flight, which is the old owners' last use of their slots, and
+        before this pass's admissions, which may be handed the same slots and
+        the same pages."""
+        idle, self._retired_slots = self._retired_slots, []
         if idle:
             mask = np.zeros(self.S, bool)
             mask[idle] = True
@@ -1049,42 +1084,45 @@ class ContinuousBatcher:
                         self.allocator.release(p)
             self.cache = self.programs.release(self.cache, mask)
 
-    def step(self) -> bool:
-        """Admit + one decode chunk. Returns True while work remains."""
-        self.phase.to("admit")
-        self._admit()
-        self._flush_retired()
-        if not self.running:
-            self.phase.to(None)
-            return bool(self.pending or self._staged)
+    def _sampling_arrays(self):
+        """Per-slot sampling parameters on the device; None while no request
+        has overridden the engine's. Uploaded only when an admission changed a
+        slot's row — not per chunk forever after the first override."""
+        if not self._per_slot:
+            return None
+        if self._samp_dirty or self._samp_dev is None:
+            self._samp_dev = (
+                jnp.asarray(self._samp_temp),
+                jnp.asarray(self._samp_topk),
+                jnp.asarray(self._samp_topp),
+            )
+            self._samp_dirty = False
+        return self._samp_dev
+
+    def _dispatch_chunk(self):
+        """Dispatch one decode chunk over the running slots. Returns the
+        {slot: request} it was dispatched with and its tokens [h, S], still on
+        the device. A request the host already knows to end inside the chunk
+        (``ends_within``) gives its slot up HERE, so that this pass's admission
+        can refill it behind the chunk and no chunk is lost to the hand-over."""
         self.phase.to("dispatch")
         # constant chunk height = ONE compiled decode variant; slots whose
         # request finishes mid-chunk simply discard the overshoot tokens
         # (their cache writes clamp at the view's end and the slot is fully
         # overwritten at its next admission)
         h = self.decode_chunk
+        flying = dict(self.running)
+        samp = self._sampling_arrays()
         if self.kv == "paged":
             # paged decode has exactly one path: the page-indirected ragged
             # kernel ("ragged" below is ignored by _decode_one's paged branch)
             use_ragged, bucket = True, 0
         else:
-            needed = max(self._slot_len[s] for s in self.running) + h
+            needed = max(self._slot_len[s] for s in flying) + h
             bucket = min(_bucket(max(needed, 1)), self.max_len)
             use_ragged = self.attn == "ragged" or (
                 self.attn == "auto" and bucket > self.RAGGED_THRESHOLD
             )
-        samp = None
-        if self._per_slot:
-            # host→device upload only when an admission changed a slot's
-            # params — not per chunk forever after the first override
-            if self._samp_dirty or self._samp_dev is None:
-                self._samp_dev = (
-                    jnp.asarray(self._samp_temp),
-                    jnp.asarray(self._samp_topk),
-                    jnp.asarray(self._samp_topp),
-                )
-                self._samp_dirty = False
-            samp = self._samp_dev
         if use_ragged:
             toks, seq, self.cache = self.programs.decode_chunk(
                 self.params, self.cache, self.tokens, self._split(), n=h,
@@ -1099,27 +1137,54 @@ class ContinuousBatcher:
             )
         self.tokens = toks
         _CHUNKS.inc()
-        _DECODE_SLOTS.inc(len(self.running))
+        _DECODE_SLOTS.inc(len(flying))
         # what the chunk's steps have in context and may read of it, from the
         # host's own lengths: step j of slot s sees _slot_len[s] + j + 1 positions
-        context = np.array([self._slot_len[s] for s in self.running])[:, None] + np.arange(1, h + 1)
+        context = np.array([self._slot_len[s] for s in flying])[:, None] + np.arange(1, h + 1)
         _CONTEXT_TOKENS.inc(int(context.sum()))
         _VISIBLE_TOKENS.inc(int(np.sum(self.programs.visible_tokens(context))))
-        # overlap: queue prefills for the next admissions while the chunk
-        # (already dispatched, still in flight) computes; one speculative
-        # stage beyond the currently-free slots covers mid-chunk retirement
-        self._stage_prefills(max(len(self._free_slots()), 1))
-        self.phase.to("decode_wait")
-        seq_host = np.asarray(seq)  # [h, S]: ONE device→host transfer
-        self.phase.to("emit")
-        for slot in self.running:
-            self._slot_len[slot] = min(self._slot_len[slot] + h, self.max_len)
-        for slot, req in list(self.running.items()):
-            for i in range(h):
-                req.out.append(int(seq_host[i, slot]))
-                if req.is_done(self.eos_id):
-                    break  # post-budget/post-EOS chunk tokens are discarded
-            self._retire_if_done(req)
+        for slot, req in flying.items():
+            if req.ends_within(h):
+                self._free_slot(slot)
+            else:
+                self._slot_len[slot] = min(self._slot_len[slot] + h, self.max_len)
+        return flying, seq
+
+    def step(self) -> bool:
+        """One pass. Returns True while work remains.
+
+        (1) Dispatch the decode chunk for the slots that are running. (2) While
+        it is in flight, do everything the NEXT chunk needs: flush retirements,
+        stage, advance prefills, reserve pages, ``insert``, set the slot's
+        token. All of it queues on the device behind the chunk, and the device
+        executes in dispatch order: that order is the only synchronisation. A
+        request admitted here decodes from the next chunk. (3) Block on the
+        chunk's tokens, emit, retire. Between the tokens of one chunk reaching
+        the host and the dispatch of the next, no admission program is
+        dispatched and no admission scalar uploaded. With nothing running
+        there is no (1) and no (3): admission runs with the device idle."""
+        flying, seq = self._dispatch_chunk() if self.running else ({}, None)
+        self.phase.to("admit")
+        self._flush_retired()
+        self._admit("chunk" if flying else "idle")
+        if flying:
+            self.phase.to("decode_wait")
+            seq_host = np.asarray(seq)  # [h, S]: ONE device→host transfer
+            self.phase.to("emit")
+            # the slots the chunk was dispatched WITH: by now ``running`` may
+            # name a slot's next owner, whose tokens these are not
+            for slot, req in flying.items():
+                if req.first is not None:
+                    self._take_first(req)  # no wait: its prefill ran before this chunk did
+                for i in range(self.decode_chunk):
+                    if req.is_done(self.eos_id):
+                        break  # post-budget/post-EOS chunk tokens are discarded
+                    req.out.append(int(seq_host[i, slot]))
+                if not req.is_done(self.eos_id):
+                    continue
+                if self.running.get(slot) is req:
+                    self._free_slot(slot)  # an EOS: not known at dispatch, refilled a chunk later
+                self._finish(req)
         more = bool(self.running or self.pending or self._staged)
         if not more:
             # drained: zero the final chunk's retirees now — cache.lengths is

@@ -482,7 +482,13 @@ class EngineServer:
             self._sweep_cancellations()
             self._drain_control()
             _QUEUE_DEPTH.set(self._queue_depth())
-            had_work = eng.step()  # admit, prefill_wait, dispatch, decode_wait, emit
+            # one pass: dispatch the decode chunk, admit in its shadow (what
+            # intake just submitted is prefilled and inserted BEHIND the chunk
+            # and decodes from the next one), then decode_wait and emit (with
+            # the first tokens of the requests whose first chunk this was).
+            # From here to the next dispatch (fan-out below, intake above) the
+            # device has only what admission queued behind the chunk
+            had_work = eng.step()
             eng.phase.to("emit")
             # export the engine's prefix-reuse win as a REAL instrument, not
             # a /stats-payload-only field: the loadtest harness and the
