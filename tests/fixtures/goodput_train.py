@@ -10,7 +10,15 @@ step-counter "checkpoint" is persisted to the shared dir every
 ``ckpt_every`` steps and resumed after a gang restart, so the restart loses
 a provable amount of work (the rework the ledger must attribute).
 
-Usage: goodput_train.py <shared_dir> <steps> <base_ms> <slow_rank> <slow_mult> <ckpt_every>
+The ledger derives that rework from the AM's METRICS_SNAPSHOT events: the
+resumed epoch's first recorded step against the steps the lost epoch had
+recorded. With ``doomed_step`` (the N of the test's ``@step+N`` chaos gate)
+the fixture owns both ends instead of racing the AM's snapshot timer: the
+first attempt runs step N only once the AM has recorded step N-1, and stops
+there (the gate opens on it; nothing checkpoints past it), and a restarted
+attempt runs its second step only once the AM has recorded its first.
+
+Usage: goodput_train.py <shared_dir> <steps> <base_ms> <slow_rank> <slow_mult> <ckpt_every> [doomed_step]
 """
 
 import json
@@ -18,11 +26,15 @@ import os
 import sys
 import time
 
+from tony_tpu.obs import artifacts as obs_artifacts
+from tony_tpu.obs import goodput as obs_goodput
 from tony_tpu.obs import metrics as obs_metrics
 
+began_ms = int(time.time() * 1000)
 shared, steps, base_ms, slow_rank, slow_mult, ckpt_every = (
     sys.argv[1], int(sys.argv[2]), float(sys.argv[3]), int(sys.argv[4]),
     float(sys.argv[5]), int(sys.argv[6]))
+doomed_step = int(sys.argv[7]) if len(sys.argv) > 7 else 0
 rank = int(os.environ["TASK_INDEX"])
 metrics_path = os.environ["TONY_TRAIN_METRICS_FILE"]
 attempt = int(os.environ.get("TONY_RESTART_ATTEMPT", "0"))
@@ -48,6 +60,22 @@ def drop(path, obj):
     os.replace(tmp, path)
 
 
+def wait_recorded(step, since_ms):
+    """Block until the job's event stream holds a METRICS_SNAPSHOT, written at
+    or after ``since_ms``, in which some task is at ``step`` or past it (read
+    as the ledger reads it)."""
+    staging_root = os.path.dirname(os.environ["TONY_STAGING_DIR"].rstrip("/"))
+    while True:
+        events, _ = obs_artifacts.index(staging_root, os.environ["TONY_APP_ID"]).read_events()
+        if any(
+            ev.type.value == "METRICS_SNAPSHOT" and ev.timestamp_ms >= since_ms
+            and max(obs_goodput._snapshot_steps(ev).values(), default=0) >= step
+            for ev in events
+        ):
+            return
+        time.sleep(0.05)
+
+
 for s in range(start + 1, steps + 1):
     time.sleep(step_s)
     hist.observe(step_s)
@@ -61,5 +89,15 @@ for s in range(start + 1, steps + 1):
          [m for m in obs_metrics.REGISTRY.snapshot() if m["samples"]])
     if rank == 0 and s % ckpt_every == 0:
         drop(ckpt_path, {"step": s})
+    if not doomed_step:
+        continue
+    if attempt > 0 and s == start + 1:
+        wait_recorded(s, began_ms)
+    elif attempt == 0 and s == doomed_step - 1:
+        wait_recorded(s, 0)
+    elif attempt == 0 and s == doomed_step:
+        print(f"fixture: rank {rank} holds at step {s} for the chaos gate", flush=True)
+        while True:
+            time.sleep(1.0)
 
 print(f"fixture: rank {rank} attempt {attempt} finished at step {steps}")

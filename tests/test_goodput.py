@@ -612,6 +612,9 @@ class TestGoodputCLI:
 @pytest.mark.chaos
 class TestGoodputHeadlineE2E:
     STEPS = 26
+    # the chaos gate's step, which the fixture is told: one past a recorded
+    # step past the checkpoint at 4, and the last step the first attempt runs
+    DOOMED_STEP = 7
 
     def _wait(self, fn, timeout_s=90, interval=0.1):
         deadline = time.time() + timeout_s
@@ -671,12 +674,12 @@ class TestGoodputHeadlineE2E:
             keys.STAGING_ROOT: str(tmp_tony_root),
             # rank 2 runs 3x slow (the injected straggler); ckpt every 4 steps
             keys.EXECUTES: f"{fixture_cmd('goodput_train.py')} {shared} "
-                           f"{self.STEPS} 120 2 3.0 4",
+                           f"{self.STEPS} 120 2 3.0 4 {self.DOOMED_STEP}",
             "tony.worker.instances": "3",
             keys.TASK_METRICS_INTERVAL_MS: "150",
             keys.TASK_RESTART_ON_FAILURE: "true",
             # one gang restart: a node dies once the AM has seen step 7
-            keys.CHAOS_SPEC: "node-loss:worker:1@step+7",
+            keys.CHAOS_SPEC: f"node-loss:worker:1@step+{self.DOOMED_STEP}",
             keys.CHAOS_SEED: "7",
             keys.GOODPUT_INTERVAL_MS: "250",
             keys.GOODPUT_WINDOW_MS: "2500",
@@ -804,16 +807,20 @@ class TestGoodputHeadlineE2E:
             server.shutdown()
 
         # --- the optional bench goodput gate sees the same ledger
+        from tests.test_bench_gate import write_train_trajectory
         from tony_tpu.cli.history import main_bench
 
-        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        # the train family has no checked-in round (test_bench_gate.py)
+        trajectory = str(tmp_path / "trajectory")
+        os.mkdir(trajectory)
+        write_train_trajectory(trajectory)
         capsys.readouterr()
-        rc_hi = main_bench(["--gate", "--trajectory-dir", repo,
+        rc_hi = main_bench(["--gate", "--trajectory-dir", trajectory,
                             "--goodput-floor", "0.999", "--goodput-app", app_id,
                             "--staging", str(tmp_tony_root)])
         assert rc_hi == 1
         assert "GOODPUT REGRESSION" in capsys.readouterr().out
-        rc_lo = main_bench(["--gate", "--trajectory-dir", repo,
+        rc_lo = main_bench(["--gate", "--trajectory-dir", trajectory,
                             "--goodput-floor", "0.0", "--goodput-app", app_id,
                             "--staging", str(tmp_tony_root)])
         assert rc_lo == 0

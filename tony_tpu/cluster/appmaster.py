@@ -1008,9 +1008,13 @@ class ApplicationMaster:
             t for t in infos
             if t.get("status") in (TaskStatus.REGISTERED.value, TaskStatus.RUNNING.value)
         ]
-        for action, task, ratio, median in self._straggler.observe(
-            obs_introspect.step_stats_by_task(live, task_obs)
-        ):
+        # the tick that ends the job (every tracked task terminal) sees its
+        # ranks gone; a rank flagged until it finished was dragging the gang
+        # at the end (goodput.flagged_stragglers), and whether this throttled
+        # tick falls before the loop's exit must not decide that
+        transitions = [] if self.session.tracked_all_terminal() else self._straggler.observe(
+            obs_introspect.step_stats_by_task(live, task_obs))
+        for action, task, ratio, median in transitions:
             if action == "detected":
                 self.events.emit(
                     EventType.STRAGGLER_DETECTED,

@@ -46,7 +46,7 @@ class LlamaConfig:
     remat: bool = True
     remat_policy: str = "full"  # full | dots (save matmul outputs, recompute the rest)
     attn_impl: str = "auto"   # auto | flash | reference
-    cp_impl: str = "xla"      # context parallel: xla (ppermute ring) | pallas (remote-DMA ring) | ulysses (all-to-all)
+    cp_impl: str = "xla"      # context parallel: xla (ppermute ring) | ulysses (all-to-all)
     ce_chunk: int = 512       # fused lm-head+CE chunk length; 0 = materialize logits
     sliding_window: int = 0   # >0: Mistral/Mixtral-style sliding-window attention
     rope_scaling: tuple = ()  # () | ("linear", f) | ("llama3", f, lo, hi, orig) — see ops/layers.rope_frequencies
@@ -146,74 +146,25 @@ def sharding_rules(cfg: LlamaConfig) -> ShardingRules:
 
 
 def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
-    """Dispatch: context-parallel attention (cfg.cp_impl: XLA ring,
-    Pallas remote-DMA ring, or Ulysses all-to-all) when the context axis is
-    real, else fused single-device MHA.
+    """Dispatch: context-parallel attention (cfg.cp_impl: XLA ring or
+    Ulysses all-to-all) when the context axis is real, else fused
+    single-device MHA.
 
     q: [B, H, T, Dh]; k/v: [B, Hkv, T, Dh]; segment_ids [B, T] (packing).
     """
-    if cfg.cp_impl not in ("xla", "pallas", "ulysses"):
-        raise ValueError(
-            f"cp_impl must be 'xla', 'pallas', or 'ulysses', got {cfg.cp_impl!r}"
-        )
+    if cfg.cp_impl not in ("xla", "ulysses"):
+        raise ValueError(f"cp_impl must be 'xla' or 'ulysses', got {cfg.cp_impl!r}")
     if mesh is not None and mesh.shape.get("context", 1) > 1:
-        if cfg.cp_impl != "pallas":
-            if segment_ids is not None:
-                raise ValueError(
-                    "sequence packing (segment_ids) composes with a context "
-                    "axis only via cp_impl='pallas' (the ring kernel carries "
-                    "the global segment table); xla/ulysses do not"
-                )
-            if cfg.sliding_window > 0:
-                raise ValueError(
-                    "sliding_window composes with a context axis only via "
-                    "cp_impl='pallas' (in-kernel band skipping)"
-                )
-        if cfg.cp_impl == "pallas":
-            # remote-DMA ring kernel: GQA-native (KV stays at Hkv width on
-            # the wire); fully-manual shard_map because the kernel manages
-            # its own collectives (and interpret-mode emulation requires it)
-            from tony_tpu.ops.ring import (
-                ring_attention_pallas,
-                ring_attention_pallas_seg,
+        if segment_ids is not None:
+            raise ValueError(
+                "context parallelism does not compose with sequence packing "
+                "(segment_ids): neither the ring nor Ulysses carries a segment table"
             )
-
-            model_deg = mesh.shape.get("model", 1)
-            batch_deg = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
-            if cfg.n_kv_heads % model_deg or q.shape[0] % batch_deg:
-                raise ValueError(
-                    "cp_impl='pallas' shards kv heads over 'model' and batch "
-                    f"over data×fsdp explicitly: n_kv_heads {cfg.n_kv_heads} "
-                    f"must divide by model={model_deg} and batch {q.shape[0]} "
-                    f"by data×fsdp={batch_deg} (cp_impl='xla' has no such "
-                    "constraint)"
-                )
-            qspec = P(BATCH_AXES, "model", "context", None)
-            if segment_ids is not None:
-                ring = jax.shard_map(
-                    partial(
-                        ring_attention_pallas_seg, axis_name="context",
-                        causal=True, window=cfg.sliding_window,
-                    ),
-                    mesh=mesh,
-                    in_specs=(qspec, qspec, qspec, P(BATCH_AXES, "context")),
-                    out_specs=qspec,
-                    axis_names=set(mesh.axis_names),
-                    check_vma=False,
-                )
-                return ring(q, k, v, segment_ids)
-            ring = jax.shard_map(
-                partial(
-                    ring_attention_pallas, axis_name="context", causal=True,
-                    window=cfg.sliding_window,
-                ),
-                mesh=mesh,
-                in_specs=(qspec, qspec, qspec),
-                out_specs=qspec,
-                axis_names=set(mesh.axis_names),
-                check_vma=False,
+        if cfg.sliding_window > 0:
+            raise ValueError(
+                "context parallelism does not compose with sliding_window: "
+                "neither the ring nor Ulysses masks a band"
             )
-            return ring(q, k, v)
         n_rep = cfg.n_heads // cfg.n_kv_heads
         spec = P(None, None, "context", None)
         if cfg.cp_impl == "ulysses":
@@ -227,7 +178,7 @@ def _attention(q, k, v, cfg: LlamaConfig, mesh, segment_ids=None) -> jax.Array:
             if cfg.n_heads % cp:
                 raise ValueError(
                     f"cp_impl='ulysses' needs n_heads {cfg.n_heads} divisible "
-                    f"by the context degree {cp} (use 'xla'/'pallas' ring)"
+                    f"by the context degree {cp} (use the 'xla' ring)"
                 )
             if cfg.n_kv_heads % cp:
                 k = attn_ops.repeat_kv(k, n_rep)

@@ -52,8 +52,7 @@ def ring_all_reduce_sum(x: jax.Array, axis_name: str) -> jax.Array:
     """Explicit reduce-scatter + all-gather ring all-reduce.
 
     Functionally ``psum``; exists for schedule control when overlapping with
-    compute in shard_map bodies (and as the XLA-level analog of the Pallas
-    remote-DMA ring in ops/ring kernels).
+    compute in shard_map bodies.
     """
     n = jax.lax.axis_size(axis_name)
     if x.shape[0] % n:

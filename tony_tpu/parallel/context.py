@@ -7,10 +7,9 @@ axis ring (``ppermute`` → ICI neighbor exchange), merging partial results
 with the flash-attention log-sum-exp recurrence, so the full T×T score matrix
 never materializes on any chip and memory stays O(T/N) per device.
 
-This is the XLA-collectives implementation (compiler-scheduled overlap); the
-Pallas remote-DMA ring kernel (ops/) is the hand-overlapped variant of the
-same schedule. Ulysses-style all-to-all head sharding is provided as the
-alternative for models with many heads.
+The ring is built from XLA collectives (compiler-scheduled overlap).
+Ulysses-style all-to-all head sharding is provided as the alternative for
+models with many heads.
 """
 
 from __future__ import annotations
