@@ -148,7 +148,7 @@ class TestDecodeAttentionAtServeShapes:
 
 
 class TestSalaKernelsAtServedWidths:
-    """MiniCPM-SALA's three kernels at the widths `minicpm-sala.serve_longdoc`
+    """MiniCPM-SALA's four kernels at the widths `minicpm-sala.serve_longdoc`
     serves: 32 query and 2 kv heads of 128, 16 slots of 50,688 positions, a
     page a 64-token block, prefill chunks of 2048."""
 
@@ -178,6 +178,23 @@ class TestSalaKernelsAtServedWidths:
         kv = _s((c.HKV, c.MAXLEN, c.D), jnp.bfloat16, chip)
         mask = _s((c.HKV, c.CHUNK, c.MAXLEN), jnp.int8, chip)
         assert _kernel_calls(SA.masked_prefill_attention, q, kv, kv, mask, _s((), jnp.int32, chip)) == 1
+
+    def test_block_select(self, chip):
+        """A prefill chunk's block scores and their 64th largest: 2048 rows of
+        32 heads against 3168 float32 compressed keys, 792 blocks, one call
+        (products at full precision, a lane roll, the k-th search in VMEM)."""
+        from tony_tpu.models.minicpm_sala import SalaConfig
+        from tony_tpu.ops import sparse_attention as SA
+
+        c, sp = self, SalaConfig().sparse
+        q = _s((c.CHUNK, c.HKV, c.H // c.HKV, c.D), jnp.bfloat16, chip)
+        kc = _s((c.MAXLEN // sp.stride, c.HKV, c.D), jnp.float32, chip)
+        fn, n_ctx = functools.partial(SA.block_select, spec=sp), _s((c.CHUNK,), jnp.int32, chip)
+        text = jax.jit(fn).lower(q, kc, n_ctx).compile().as_text()
+        assert text.count("tpu_custom_call") == 1 and "block_select" in text      # the name the trace finds it by
+        score, kth = jax.eval_shape(fn, q, kc, n_ctx)
+        assert score.shape == (2048, 2, 792) and kth.shape == (2048, 2, 1)
+        assert score.dtype == kth.dtype == jnp.float32
 
     def test_linear_chunk(self, chip):
         """A prefill chunk's linear attention: one call for the 32 heads, the

@@ -196,6 +196,142 @@ def test_the_programs_chosen_blocks_are_the_references(tiny, seed):
     assert got[~sparse].all()
 
 
+# the fused selection at one shape (one trace a spec): 32 queries from `pos0` against 128 positions, tiles of 16
+# queries x 4 blocks, so 16 blocks are 4 tiles. (what the keys hold, pos0, dense_len or None for the config's 32)
+SELECT_CASES = {
+    "random": ("random", 40, None),
+    "ties-at-the-last-place": ("equal", 60, None),           # (i) every block scores the same: the earlier ones win
+    "fewer-valid-than-topk": ("random", 0, 0),               # (ii) contexts of 1..32: 0 to 4 blocks with a finished kernel
+    "straddles-the-dense-length": ("random", 16, None),      # (iii) contexts 17..48 around dense_len 32
+    "ends-inside-a-tile": ("nan-past", 36, None),            # (iv) 33 kernels end in context: tile 2 of 0..3 in part, 3 not at all
+    "ends-far-before-max-len": ("nan-past", 8, None),        # (iv) 19 kernels: tiles 2 and 3 are skipped, and hold NaN
+}
+
+
+@pytest.mark.parametrize("case", sorted(SELECT_CASES))
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_the_fused_selection_chooses_the_same_blocks(tiny, seed, case):
+    """`block_select` against `block_scores` (the XLA form: decode's, and the
+    oracle) and against the reference: the same bool set, block for block."""
+    from tony_tpu.ops import sparse_attention as SA
+
+    keys, pos0, dense_len = SELECT_CASES[case]
+    sizes, sp, R = dict(tiny["sizes"]), tiny["cfg"].sparse, tiny["reference"]
+    if dense_len is not None:
+        sp, sizes["sparse_dense_len"] = sp._replace(dense_len=dense_len), dense_len
+    rng = np.random.default_rng(seed)
+    t, hkv, g, d = 32, 2, 2, 16
+    q = jnp.asarray(rng.standard_normal((t, hkv, g, d)), jnp.float32)
+    k = rng.standard_normal((MAX_LEN, hkv, d)).astype(np.float32)
+    if keys == "equal":
+        k[:] = k[0]
+    if keys == "nan-past":
+        k[pos0 + t:] = np.nan                                 # no query's context holds them
+    k, pos = jnp.asarray(k), pos0 + jnp.arange(t)
+    kc = SA.compress_keys(k, sp)
+    fused, kth = SA.block_select(q, kc, pos + 1, sp, block_q=16, block_b=4)
+    got = np.asarray(SA.chosen_blocks(fused, pos + 1, sp, kth))
+    oracle = np.asarray(SA.chosen_blocks(SA.block_scores(q, kc, pos + 1, sp), pos + 1, sp))
+    want = np.asarray(R.chosen_blocks(q, R.compressed_keys(k, sizes), pos, sizes, "f32"))
+    sparse = np.asarray(pos) + 1 > sp.dense_len
+    assert not np.isnan(np.asarray(fused)).any() and (np.asarray(kth) == np.asarray(SA.kth_largest(fused, sp.topk))).all()
+    assert got.shape == oracle.shape == want.shape and (got == oracle).all() and (got[sparse] == want[sparse]).all()
+    assert got[~sparse].all() and sparse.any()
+    held = (np.asarray(fused) >= 0)[sparse].sum(-1)           # blocks a finished kernel overlaps, and the first
+    assert (got[sparse].sum(-1) == np.minimum(held, sp.topk)).all() and got[sparse][..., 0].all()
+    if keys == "equal":
+        assert got[sparse][..., :sp.topk].all()
+    if case == "fewer-valid-than-topk":
+        assert set(held.ravel()) == {1, 2, 3, 4}              # block 0 alone while no kernel has finished
+
+
+@pytest.mark.parametrize("k", [1, 3, 64])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_the_kth_largest_is_found_without_a_sort(seed, k):
+    """Against `np.partition`, on float32 with duplicates, +inf and -1 (a block
+    no kernel overlaps): the k-th largest, or 0 where fewer than k are >= 0."""
+    from tony_tpu.ops.sparse_attention import kth_largest
+
+    rng = np.random.default_rng(seed)
+    x = rng.choice(rng.random(40, np.float32) * np.float32(10.0) ** rng.integers(-30, 3, 40).astype(np.float32), (24, 2, 100))
+    x[rng.random(x.shape) < 0.2] = -1.0
+    x[rng.random(x.shape) < 0.05] = 0.0
+    x[:, :, 0] = np.inf
+    x[:6, :, 1:] = -1.0                                       # rows with one entry that is not negative
+    x[6:8] = np.float32(0.25)                                 # rows of one value
+    want = np.maximum(np.partition(x, -k, axis=-1)[..., -k], 0)[..., None]
+    got = np.asarray(kth_largest(jnp.asarray(x), k))
+    assert got.dtype == np.float32 and got.shape == want.shape and (got == want).all()
+    assert (want[:6] == (np.inf if k == 1 else 0)).all() and (k == 1 or len(np.unique(want)) > 4)
+
+
+@pytest.fixture(scope="module")
+def watched_prefill(tiny):
+    """The program's `prefill_chunk`, traced afresh with a `block_select` that
+    says when it RUNS (both branches of a `cond` are traced; one executes)."""
+    module, runs = tiny["module"], []
+    real = module.block_select
+
+    def watched(*args, **kw):
+        jax.debug.callback(lambda: runs.append(1))
+        return real(*args, **kw)
+
+    # a function of its own: a trace is kept by the function traced, and `prefill_chunk`'s may be an earlier test's
+    fn = jax.jit(lambda *args, cfg: module.prefill_chunk.__wrapped__(*args, cfg), static_argnames=("cfg",))
+    progs = module.serving_programs(tiny["cfg"], "paged")
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(module, "block_select", watched)
+        fn(tiny["params"], jnp.zeros((1, 16), jnp.int32), progs.init_staging(MAX_LEN), jnp.int32(16), cfg=tiny["cfg"])
+    jax.effects_barrier()
+    assert not runs                                           # a first chunk of 16 is dense
+    return fn, runs, progs
+
+
+@pytest.mark.parametrize("over", [0, 1], ids=["ends-at-the-dense-length", "ends-one-past-it"])
+def test_the_counter_and_the_program_take_the_same_path(tiny, watched_prefill, over):
+    """`prefill_path(pos, take)` feeds `tony_serve_prefill_chunks_total{path}`;
+    `_sparse_attend` branches on the device. A padded last chunk whose prompt
+    ends at the dense length, and one position past it: they must not part."""
+    fn, runs, progs = watched_prefill
+    sp = tiny["cfg"].sparse
+    prompt = _tokens(40 + over, sp.dense_len + over)
+    staging, paths = progs.init_staging(MAX_LEN), []
+    for pos in range(0, len(prompt), 16):
+        take = min(16, len(prompt) - pos)
+        toks = jnp.asarray(prompt[pos:pos + take] + [0] * (16 - take), jnp.int32)[None]
+        del runs[:]
+        logits, staging = fn(tiny["params"], toks, staging, jnp.int32(take), cfg=tiny["cfg"])
+        jax.effects_barrier()
+        paths.append(progs.prefill_path(pos, take))
+        assert (len(runs) > 0) == (paths[-1] == "sparse"), (pos, take)
+    assert paths == ["dense", "dense"] + ["sparse"] * over
+    assert np.abs(np.asarray(logits)[0] - tiny["ref_logits"](prompt)[-1]).max() < LOGIT_TOL
+
+
+@pytest.mark.parametrize("longest", [32, 48], ids=["dense", "sparse"])
+def test_a_chunks_attention_is_the_unfused_formulations_to_the_last_bit(tiny, longest):
+    """`_sparse_attend` against the parent's way (every row's scores in XLA,
+    `chosen_blocks` over them, all-true where the context is dense): on a
+    dense chunk the rows that count, on a sparse chunk every row."""
+    from tony_tpu.ops import sparse_attention as SA
+
+    module, cfg = tiny["module"], tiny["cfg"]
+    sp, t = cfg.sparse, 16
+    rng = np.random.default_rng(longest)
+    q = jnp.asarray(rng.standard_normal((t, cfg.n_heads, cfg.head_dim)), jnp.float32)
+    keys, values = (jnp.asarray(rng.standard_normal((cfg.n_kv_heads, MAX_LEN, cfg.head_dim)), jnp.float32) for _ in range(2))
+    pos0 = longest - t                                        # dense: a chunk of contexts 17..32; sparse: 33..48
+    positions = pos0 + jnp.arange(t, dtype=jnp.int32)
+    got = jax.jit(lambda *a: module._sparse_attend(*a, cfg))(q, keys, values, positions, jnp.int32(longest), jnp.int32(pos0 + t))
+    g = cfg.n_heads // cfg.n_kv_heads
+    qg = q.reshape(t, cfg.n_kv_heads, g, cfg.head_dim)
+    chosen = SA.chosen_blocks(SA.block_scores(qg, SA.compress_keys(keys.transpose(1, 0, 2), sp), positions + 1, sp), positions + 1, sp)
+    assert bool(chosen.all()) == (longest <= sp.dense_len)
+    mask = SA.prefill_mask(chosen, positions, MAX_LEN, sp)
+    want = SA.masked_prefill_attention(qg.transpose(1, 2, 0, 3), keys, values, mask, jnp.int32(pos0 + t))
+    assert np.array_equal(np.asarray(got), np.asarray(want.transpose(2, 0, 1, 3).reshape(t, cfg.n_heads, cfg.head_dim)))
+
+
 def test_the_decode_kernel_reads_only_the_pages_it_was_given(monkeypatch):
     """Every page that is not in a (slot, head)'s list is NaN: the output is
     finite and equals attention over the listed pages' visible positions."""

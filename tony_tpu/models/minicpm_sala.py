@@ -39,6 +39,7 @@ from tony_tpu.ops.linear_attention import linear_attention_chunk, linear_attenti
 from tony_tpu.ops.sparse_attention import (
     SparseSpec,
     block_scores,
+    block_select,
     chosen_blocks,
     compress_keys,
     masked_prefill_attention,
@@ -172,28 +173,22 @@ def _gate_out(o, h, lp):
     return _mm(o.reshape(o.shape[0], -1) * gate, lp["wo"])
 
 
-def _choose(q, kc, n_ctx, cfg, rows: int = 512):
-    """The blocks each query of a chunk reads: q [T, H, dh], kc [nK, Hkv, dh],
-    n_ctx [T] -> bool [T, Hkv, nB]. In runs of `rows` queries: a run's scores
-    against every compressed key are [rows, H, nK] float32."""
-    t = q.shape[0]
-    rows = math.gcd(rows, t)
-    qg = q.reshape(t // rows, rows, cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, cfg.head_dim)
-
-    def run(args):
-        qr, n = args
-        return chosen_blocks(block_scores(qr, kc, n, cfg.sparse), n, cfg.sparse)
-
-    return jax.lax.map(run, (qg, n_ctx.reshape(t // rows, rows))).reshape(t, cfg.n_kv_heads, -1)
-
-
-def _sparse_attend(q, keys, values, positions, n_keys, cfg):
+def _sparse_attend(q, keys, values, positions, longest, n_keys, cfg):
     """q [T, H, dh] at `positions` against keys/values [Hkv, Tk, dh] (the
-    queries' own among them): each query over its visible set."""
-    t, g = q.shape[0], cfg.n_heads // cfg.n_kv_heads
-    kc = compress_keys(keys.transpose(1, 0, 2), cfg.sparse)
-    chosen = _choose(q, kc, positions + 1, cfg)
-    mask = prefill_mask(chosen, positions, keys.shape[1], cfg.sparse)
+    queries' own among them): each query over its visible set. `longest` [] is
+    the longest context among the rows that count: at or below the dense
+    length every block is chosen by definition and nothing is scored (the
+    inequality of `serving_programs`' `prefill_path`, which counts the chunks)."""
+    t, g, sp = q.shape[0], cfg.n_heads // cfg.n_kv_heads, cfg.sparse
+    nB = keys.shape[1] // sp.block
+
+    def choose():
+        kc = compress_keys(keys.transpose(1, 0, 2), sp)
+        score, kth = block_select(q.reshape(t, cfg.n_kv_heads, g, cfg.head_dim), kc, positions + 1, sp)
+        return chosen_blocks(score, positions + 1, sp, kth)
+
+    chosen = jax.lax.cond(longest > sp.dense_len, choose, lambda: jnp.ones((t, cfg.n_kv_heads, nB), bool))
+    mask = prefill_mask(chosen, positions, keys.shape[1], sp)
     qh = q.reshape(t, cfg.n_kv_heads, g, cfg.head_dim).transpose(1, 2, 0, 3)
     o = masked_prefill_attention(qh, keys, values, mask, n_keys)
     return o.transpose(2, 0, 1, 3).reshape(t, cfg.n_heads, cfg.head_dim)
@@ -219,7 +214,7 @@ def _sequence(params, tokens, cfg: SalaConfig):
         h = L.rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         if kind == SPARSE:
             q, k, v = _sparse_qkv(h, lp, cfg)
-            o = _sparse_attend(q, k.transpose(1, 0, 2), v.transpose(1, 0, 2), positions, jnp.int32(t), cfg)
+            o = _sparse_attend(q, k.transpose(1, 0, 2), v.transpose(1, 0, 2), positions, jnp.int32(t), jnp.int32(t), cfg)
         else:
             q, k, v = (a.transpose(1, 0, 2)[None] for a in _linear_qkv(h, lp, cfg, cos, sin, positions))
             state = jnp.zeros((1, cfg.lin_heads, cfg.lin_head_dim, cfg.lin_head_dim), jnp.float32)
@@ -304,7 +299,7 @@ def prefill_chunk(params, tokens, staging: Staging, take, cfg: SalaConfig):
             q, k, v = _sparse_qkv(h, lp, cfg)
             ks = jax.lax.dynamic_update_slice(ks, k.transpose(1, 0, 2)[None, None].astype(ks.dtype), (i, 0, 0, pos0, 0))
             vs = jax.lax.dynamic_update_slice(vs, v.transpose(1, 0, 2)[None, None].astype(vs.dtype), (i, 0, 0, pos0, 0))
-            o = _sparse_attend(q, ks[i, 0], vs[i, 0], positions, pos0 + t, cfg)
+            o = _sparse_attend(q, ks[i, 0], vs[i, 0], positions, pos0 + take, pos0 + t, cfg)
         else:
             q, k, v = (a.transpose(1, 0, 2)[None] for a in _linear_qkv(h, lp, cfg, cos, sin, positions))
             o, new = linear_attention_chunk(q, k, v, state[i], slopes, valid=take, block=math.gcd(t, 256))

@@ -9,8 +9,17 @@ the highest score (the first ones counted among them), and the last ``window``
 tokens. One set a kv group and query position. At or below ``dense_len`` it is
 plain causal attention.
 
-Here: the compressed keys, the block scores (float32: a block is chosen or it
-is not, and the choice must not hang on bf16 rounding), the chosen blocks, and
+Here: the compressed keys; the block scores (float32: a block is chosen or it
+is not, and the choice must not hang on bf16 rounding) in two forms that agree
+but for the last bits of a row's softmax sum: ``block_scores``, plain XLA over
+a ``[rows, heads, compressed keys]`` array, for the one query a slot of a
+decode step and as the tests' oracle, and ``block_select``, one fused Pallas
+call for the rows of a prefill chunk, which keeps a running maximum and sum as
+flash does, pools the RAW scores a block (``exp(x - m) / l`` is monotone in
+``x``), never holds that array and neither fetches nor computes a tile of
+compressed keys past the chunk's context; the chosen blocks
+(``chosen_blocks``: the ``topk``-th largest score by an exact search over the
+bits of a float32, ``kth_largest``, no sort; ties to the earlier block); and
 the two forms the chosen set takes: a token mask for a prefill chunk
 (``masked_prefill_attention``: a flash kernel that takes the mask as an
 operand; it computes every staged key under the mask and skips none yet) and a
@@ -21,6 +30,7 @@ ops/decode_attention.sparse_paged_decode_attention, which reads only those).
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -66,6 +76,11 @@ def compress_keys(k: jax.Array, spec: SparseSpec) -> jax.Array:
     return sum(pad[i:i + a.shape[0]] for i in range(r)) / spec.kernel
 
 
+def _n_valid(n_ctx: jax.Array, spec: SparseSpec) -> jax.Array:
+    """The kernels that end inside a context of n_ctx positions: the first that many."""
+    return jnp.maximum((n_ctx - spec.kernel) // spec.stride + 1, 0)
+
+
 def block_scores(q: jax.Array, kc: jax.Array, n_ctx: jax.Array, spec: SparseSpec) -> jax.Array:
     """q [T, Hkv, G, d] (G query heads a group), kc [nK, Hkv, d] compressed
     keys, n_ctx [T] the context of each query (its position + 1). Returns
@@ -75,7 +90,7 @@ def block_scores(q: jax.Array, kc: jax.Array, n_ctx: jax.Array, spec: SparseSpec
     per, extra = spec.block // spec.stride, spec.kernel // spec.stride - 1
     nB = nK // per
     s = jnp.einsum("tgrd,kgd->tgrk", q.astype(jnp.float32), kc.astype(jnp.float32), precision=_HI) * d ** -0.5
-    n_valid = jnp.maximum((n_ctx - spec.kernel) // spec.stride + 1, 0)          # kernels that end inside the context
+    n_valid = _n_valid(n_ctx, spec)
     valid = (jnp.arange(nK)[None, :] < n_valid[:, None])[:, None, None, :]
     m = jnp.max(jnp.where(valid, s, -1e30), axis=-1, keepdims=True)
     e = jnp.where(valid, jnp.exp(s - m), 0.0)
@@ -90,12 +105,32 @@ def block_scores(q: jax.Array, kc: jax.Array, n_ctx: jax.Array, spec: SparseSpec
     return jnp.where((b < spec.init_blocks)[None, None, :], jnp.inf, score)
 
 
-def chosen_blocks(score: jax.Array, n_ctx: jax.Array, spec: SparseSpec) -> jax.Array:
+def kth_largest(score: jax.Array, k: int) -> jax.Array:
+    """[..., n] float32 -> [..., 1]: the k-th largest of the last axis, or 0
+    where fewer than k entries are >= 0. Exact, and no sort: a float32 that is
+    not negative orders as its bits do, so the answer is built bit by bit, the
+    largest t with k entries >= t (a negative entry is below every t; no NaN).
+    Plain array code: `block_select` runs it on a tile in VMEM."""
+    bits = jax.lax.bitcast_convert_type(score, jnp.int32)
+
+    def step(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        count = (bits >= cand).astype(jnp.float32).sum(axis=-1, keepdims=True)  # whole and under 2**24: exact
+        return jnp.where(count >= k, cand, t)
+
+    t = jax.lax.fori_loop(0, 31, step, jnp.zeros((*score.shape[:-1], 1), jnp.int32))
+    return jax.lax.bitcast_convert_type(t, jnp.float32)
+
+
+def chosen_blocks(score: jax.Array, n_ctx: jax.Array, spec: SparseSpec,
+                  kth: jax.Array | None = None) -> jax.Array:
     """[T, Hkv, nB] bool: the blocks a query reads whole. All of them where the
     context is dense; else the ``topk`` of the highest score, ties to the
-    earlier block."""
+    earlier block. `kth` [T, Hkv, 1]: the ``topk``-th largest score, where the
+    caller has it already."""
     k = min(spec.topk, score.shape[-1])
-    kth = jax.lax.top_k(score, k)[0][..., -1:]
+    if kth is None:
+        kth = kth_largest(score, k)
     # neighbouring blocks share a compressed key, so equal scores are common:
     # of those that tie for the last places the earlier blocks are taken
     above, tied = score > kth, score == kth
@@ -206,3 +241,125 @@ def masked_prefill_attention(q, k, v, mask, n_keys, *, block_q: int = 128, block
                                       bytes_accessed=hkv * t * tk + 2 * hkv * nq * tk * d * k.dtype.itemsize),
     )(last, qt, k, v, mask)
     return o.reshape(hkv, nq, g, bq, d).transpose(0, 2, 1, 3, 4).reshape(hkv, g, t, d)
+
+
+def _block_select_kernel(tiles_ref, q_ref, kc_ref, nv_ref, o_ref, kth_ref, m_sc, l_sc, tail_sc, pooled_sc, *,
+                         n_rep, extra, init_blocks, topk, scale):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    j, tiles = pl.program_id(2), tiles_ref[pl.program_id(1)]
+    n_tiles, rows, tb = pooled_sc.shape
+    per, bq = kc_ref.shape[2], rows // n_rep
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, -1e30)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        tail_sc[...] = jnp.full_like(tail_sc, -1e30)
+
+    @pl.when(j < tiles)
+    def _tile():
+        # column c of phase o is compressed key (j*tb + c)*per + o: a block's own `per` keys are a column
+        q, nv = q_ref[0, 0], nv_ref[0]                              # [rows, d], [rows, 1]
+        lane = jax.lax.broadcasted_iota(jnp.int32, (rows, tb), 1)
+        s = []
+        for o in range(per):
+            so = jax.lax.dot_general(q, kc_ref[0, 0, o], (((1,), (1,)), ((), ())), precision=_HI,
+                                     preferred_element_type=jnp.float32) * scale
+            s.append(jnp.where((j * tb + lane) * per + o < nv, so, -1e30))
+        best = functools.reduce(jnp.maximum, s)
+        # the block also overlaps the last `extra` keys of the block before it: the column to the left, the
+        # same numbers (not a second product: blocks that share a key must tie to the bit)
+        tail = functools.reduce(jnp.maximum, s[per - extra:])
+        left = jnp.where(lane == 0, pltpu.roll(tail_sc[...], 1, 1), pltpu.roll(tail, 1, 1))
+        pooled_sc[j] = jnp.maximum(best, left)
+        tail_sc[...] = tail
+        # the row's maximum and sum, a lane at a time: put together when the last tile is through
+        m_old = m_sc[...]
+        m_new = jnp.maximum(m_old, best)
+        seen = sum(jnp.where(so > -1e29, jnp.exp(so - m_new), 0.0) for so in s)
+        l_sc[...] = l_sc[...] * jnp.exp(m_old - m_new) + seen
+        m_sc[...] = m_new
+
+    @pl.when(j == n_tiles - 1)
+    def _done():
+        m = m_sc[...].max(axis=1, keepdims=True)
+        l = jnp.maximum((l_sc[...] * jnp.exp(m_sc[...] - m)).sum(axis=1, keepdims=True), 1e-30)
+        nv = nv_ref[0, :bq]
+        every = jax.lax.broadcasted_iota(jnp.int32, o_ref.shape[1:], 1)
+        o_ref[0] = jnp.where(every < init_blocks, jnp.inf, -1.0)     # what a tile past the context holds
+        for jj in range(n_tiles):
+            @pl.when(jj < tiles)
+            def _scores(jj=jj):
+                b = jj * tb + jax.lax.broadcasted_iota(jnp.int32, (bq, tb), 1)
+                pooled = pooled_sc[jj]
+                p = jnp.where(pooled > -1e29, jnp.exp(pooled - m), 0.0) / l
+                score = jnp.where(jnp.maximum(b * per - extra, 0) < nv, p.reshape(n_rep, bq, tb).sum(axis=0), -1.0)
+                o_ref[0, :, jj * tb:(jj + 1) * tb] = jnp.where(b < init_blocks, jnp.inf, score)
+
+        kth_ref[0] = kth_largest(o_ref[0], topk)
+
+
+@functools.partial(jax.jit, static_argnames=("spec", "block_q", "block_b"))
+def block_select(q, kc, n_ctx, spec: SparseSpec, *, block_q: int = 64, block_b: int = 128):
+    """``block_scores`` for the rows of a prefill chunk, fused: q [T, Hkv, G, d],
+    kc [nK, Hkv, d], n_ctx [T] -> (float32 [T, Hkv, nB], and its ``topk``-th
+    largest [T, Hkv, 1], found while the row is in VMEM: `chosen_blocks`
+    takes both). A tile is `block_b` blocks' compressed keys against `block_q`
+    queries' G heads; a row's scores exist a tile at a time. Tiles wholly past
+    the kernels that end inside the tile's largest context are neither fetched
+    nor computed."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, hkv, g, d = q.shape
+    nK = kc.shape[0]
+    per, extra = spec.block // spec.stride, spec.kernel // spec.stride - 1
+    if not 0 < extra <= per:
+        raise ValueError(f"a kernel of {spec.kernel} every {spec.stride} must overlap the block of {spec.block} before "
+                         "its own, and no other")
+    nB = nK // per
+    bq, tb = math.gcd(block_q, t), min(block_b, nB)
+    nq, nt = t // bq, -(-nB // tb)
+    # a q tile's rows: head r's bq queries, then head r+1's
+    qt = q.astype(jnp.float32).reshape(nq, bq, hkv, g, d).transpose(2, 0, 3, 1, 4).reshape(hkv, nq, g * bq, d)
+    # a kc tile: [per, tb, d], phase o of block b is compressed key b*per + o
+    kt = jnp.pad(kc.astype(jnp.float32)[:nB * per], ((0, (nt * tb - nB) * per), (0, 0), (0, 0)))
+    kt = kt.reshape(nt, tb, per, hkv, d).transpose(3, 0, 2, 1, 4)
+    n_valid = _n_valid(n_ctx, spec).astype(jnp.int32)
+    nv = jnp.broadcast_to(n_valid.reshape(nq, 1, bq, 1), (nq, g, bq, 1)).reshape(nq, g * bq, 1)
+    # the tiles that hold a block some finished kernel overlaps, a q tile
+    most = n_valid.reshape(nq, bq).max(axis=1)
+    tiles = jnp.where(most > 0, (most + extra - 1) // per // tb + 1, 0).astype(jnp.int32)
+
+    def key_tile(h, i, j, tiles):
+        return (h, jnp.minimum(j, jnp.maximum(tiles[i] - 1, 0)), 0, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(hkv, nq, nt),
+        in_specs=[
+            pl.BlockSpec((1, 1, g * bq, d), lambda h, i, j, tiles: (h, i, 0, 0)),
+            pl.BlockSpec((1, 1, per, tb, d), key_tile),
+            pl.BlockSpec((1, g * bq, 1), lambda h, i, j, tiles: (i, 0, 0)),
+        ],
+        out_specs=[pl.BlockSpec((1, bq, nt * tb), lambda h, i, j, tiles: (h, i, 0)),
+                   pl.BlockSpec((1, bq, 1), lambda h, i, j, tiles: (h, i, 0))],
+        scratch_shapes=[pltpu.VMEM((g * bq, tb), jnp.float32), pltpu.VMEM((g * bq, tb), jnp.float32),
+                        pltpu.VMEM((g * bq, tb), jnp.float32), pltpu.VMEM((nt, g * bq, tb), jnp.float32)],
+    )
+    score, kth = pl.pallas_call(
+        functools.partial(_block_select_kernel, n_rep=g, extra=extra, init_blocks=spec.init_blocks,
+                          topk=min(spec.topk, nB), scale=d ** -0.5),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((hkv, t, nt * tb), jnp.float32),
+                   jax.ShapeDtypeStruct((hkv, t, 1), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary"),
+                                             vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret(),
+        name="block_select",
+        cost_estimate=pl.CostEstimate(flops=2 * t * hkv * g * nB * per * d, transcendentals=2 * t * hkv * g * nB * per,
+                                      bytes_accessed=4 * (t * hkv * g * d + nq * hkv * nB * per * d + t * hkv * nB)),
+    )(tiles, qt, kt, nv)
+    return score.transpose(1, 0, 2)[:, :, :nB], kth.transpose(1, 0, 2)
