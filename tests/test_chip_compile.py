@@ -299,6 +299,64 @@ class TestOtherKernels:
         assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2, 3)), xs, wg, wg, wd, tg) >= 1
 
 
+class TestExaoneMoeKernelsAtServedWidths:
+    """K-EXAONE's served widths (hidden 6144, experts of 2048, 16 of 128 held, 64 q / 8 kv heads of 128,
+    window 128, 256 slots): the grouped product with its weight blocks cut along the expert width
+    (a whole slab is 75 MB, 151 double-buffered, over the chip's fast memory), the decode kernel on a
+    window layer's ring, and the two flash forms a prefill chunk runs."""
+
+    D, F, HELD, LAYERS, HQ, WINDOW, SLOTS = 6144, 2048, 16, 4, 64, 128, 256
+
+    @pytest.mark.parametrize("tile,rows", [(128, 4096), (256, 20480)], ids=["a-decode-step", "a-2048-row-chunk"])
+    def test_grouped_swiglu_over_stacked_banks(self, chip, tile, rows):
+        xs = _s((rows, self.D), jnp.bfloat16, chip)
+        up = _s((self.LAYERS, self.HELD, self.D, self.F), jnp.bfloat16, chip)
+        down = _s((self.LAYERS, self.HELD, self.F, self.D), jnp.bfloat16, chip)
+        tg, scalar = _s((rows // tile,), jnp.int32, chip), _s((), jnp.int32, chip)
+
+        def fn(xs, wg, wu, wd, tg, live, layer):
+            return MG.moe_swiglu_rows(xs, wg, wu, wd, tg, tile, live, layer, name="moe_swiglu_decode")
+
+        compiled = jax.jit(fn).lower(xs, up, up, down, tg, scalar, scalar).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1 and "moe_swiglu_decode" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20       # no copy of a layer's bank (403 MB)
+        assert MG.width_block(self.D, self.F, 2) == 512
+
+    def test_decode_attention_on_a_window_layers_ring(self, chip):
+        from tony_tpu.models.paged_cache import RING_SLACK
+
+        ring_len = self.WINDOW + RING_SLACK
+        q, cur = _s((self.SLOTS, self.HQ, DH), jnp.bfloat16, chip), _s((self.SLOTS, HKV, DH), jnp.bfloat16, chip)
+        ring = _s((self.LAYERS, self.SLOTS, HKV, ring_len, DH), jnp.bfloat16, chip)
+        table = _s((self.SLOTS, 6144 // ring_len + 2), jnp.int32, chip)
+        lengths, layer = _s((self.SLOTS,), jnp.int32, chip), _s((), jnp.int32, chip)
+        staged = _s((self.SLOTS, 8, HKV, DH), jnp.bfloat16, chip)
+
+        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
+            return DA.paged_decode_attention(q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v,
+                                             window=self.WINDOW, staged_k=sk, staged_v=sv, staged_count=count)
+
+        assert _kernel_calls(fn, q, ring, ring, lengths, table, layer, cur, cur, staged, staged, lengths) == 1
+
+    @pytest.mark.parametrize("chunk", [128, 2048])
+    def test_a_prefill_chunks_attention(self, chip, chunk):
+        """A window layer: flash with the band over [the last 128 positions ; the chunk], positions below 0
+        a segment of their own. The full layer: the masked flash kernel over the staged keys."""
+        from tony_tpu.ops.sparse_attention import masked_prefill_attention
+
+        ext, max_len = self.WINDOW + chunk, 6144
+        q, kv = _s((1, self.HQ, ext, DH), jnp.bfloat16, chip), _s((1, HKV, ext, DH), jnp.bfloat16, chip)
+        seg = _s((1, ext), jnp.int32, chip)
+
+        def band(q, k, v, seg):
+            return A.flash_attention(q, k, v, causal=True, window=self.WINDOW, segment_ids=seg)
+
+        assert _kernel_calls(band, q, kv, kv, seg) == 1
+        qh, keys = _s((HKV, self.HQ // HKV, chunk, DH), jnp.bfloat16, chip), _s((HKV, max_len, DH), jnp.bfloat16, chip)
+        mask, n = _s((HKV, chunk, max_len), jnp.int8, chip), _s((), jnp.int32, chip)
+        assert _kernel_calls(masked_prefill_attention, qh, keys, keys, mask, n) == 1
+
+
 class TestFlashOnAFourChipMesh:
     def test_flash_under_shard_map_lowers_where_the_bare_call_cannot(self, topo, chip):
         """GSPMD cannot partition a Mosaic kernel: under any data/fsdp/model

@@ -612,8 +612,8 @@ def test_the_cells_entries_and_files(bench):
     spec = bench["spec"]
     B, cell = spec.benchmark(), "minicpm-sala.serve_longdoc"
     entry = next(c for c in B["configs"] if c["name"] == "minicpm-sala")
-    assert entry["reduced"] == ["num_hidden_layers", "mixer_types"] and B["configs"][-1] is entry
-    assert B["workloads"][-1]["name"] == cell and B["workloads"][-1]["chips"] == 1
+    assert entry["reduced"] == ["num_hidden_layers", "mixer_types"] and B["configs"][1] is entry
+    assert B["workloads"][4]["name"] == cell and B["workloads"][4]["chips"] == 1
     # judged by tokens/s: the gaps of a request due in the window arrive mostly after it has closed (PERF.md section 2)
     assert {m["name"] for m in spec.cell_metrics(B, cell, "end_to_end")} == {"serve_out_tok_s", "setup_s"}
     per_layer = spec.cell_metrics(B, cell, "per_layer")
@@ -625,7 +625,7 @@ def test_the_cells_entries_and_files(bench):
     assert {m["moves"] for m in per_layer} == {"serve_out_tok_s", "setup_s"}
     assert all(spec.metric(m["name"])["moves"] == m["moves"] for m in B["per_layer"])
     launch = next(m for m in B["per_layer"] if m["name"] == "launch_s")
-    assert launch["workloads"] == [w["name"] for w in B["workloads"]] and len(B["workloads"]) == 5
+    assert launch["workloads"] == [w["name"] for w in B["workloads"]] and len(B["workloads"]) >= 5
     w = spec.workload(cell)
     assert (w["engine"]["slots"], w["engine"]["max_len"], w["engine"]["prefill_chunk"], w["engine"]["decode_chunk"]) == (
         16, 50688, 2048, 8)

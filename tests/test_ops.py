@@ -5,6 +5,7 @@ tests/test_chip_compile.py; their numerics on the chip are bench.py --smoke."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from tony_tpu.ops import attention as A
 from tony_tpu.ops import layers as L
@@ -442,3 +443,53 @@ class TestSlidingWindow:
             params, batch, dc.replace(base, attn_impl="flash", sliding_window=64)
         )
         np.testing.assert_allclose(float(l_ref), float(l_fl), rtol=2e-3)
+
+
+class TestGroupedSwiGLU:
+    """ops/moe_gemm's forward under the interpreter against jax.lax.ragged_dot on
+    the same sorted rows: the geometry it always had (an expert's whole slab one
+    block), and a wide one cut small, where the width is walked in blocks (what
+    6144 x 2048 needs to fit VMEM), the banks are every layer's with a layer
+    index, and the row tiles past the groups are skipped."""
+
+    @staticmethod
+    def _case(E, D, F, tile, sizes, layers=1, seed=0):
+        from tony_tpu.ops import moe_gemm as MG
+
+        ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+        padded = [max(-(-n // tile), 1) * tile for n in sizes]
+        rows = (sum(sizes) // tile + E + 3) * tile                   # slack past the groups, as a static bound leaves
+        xs = (jax.random.normal(ks[0], (rows, D)) * 0.5).astype(jnp.bfloat16)
+        wg = (jax.random.normal(ks[1], (layers, E, D, F)) / D ** 0.5).astype(jnp.bfloat16)
+        wu = (jax.random.normal(ks[2], (layers, E, D, F)) / D ** 0.5).astype(jnp.bfloat16)
+        wd = (jax.random.normal(ks[3], (layers, E, F, D)) / F ** 0.5).astype(jnp.bfloat16)
+        gs = jnp.asarray(padded, jnp.int32)
+        tg = MG.tile_group_map(gs, rows // tile, tile)
+        return MG, xs, wg, wu, wd, gs, tg, sum(padded)
+
+    @staticmethod
+    def _ragged(xs, wg, wu, wd, gs):
+        g = jax.nn.silu(jax.lax.ragged_dot(xs, wg, gs))
+        return jax.lax.ragged_dot((g * jax.lax.ragged_dot(xs, wu, gs)).astype(xs.dtype), wd, gs)
+
+    def test_the_geometry_it_always_had(self):
+        MG, xs, wg, wu, wd, gs, tg, live_rows = self._case(4, 128, 256, 16, [20, 0, 16, 7])
+        assert MG.width_block(128, 256, 2) == 256 and MG.width_block(1024, 2048, 2) == 2048      # one block: the slab resident
+        got = MG.moe_swiglu_grouped(xs, wg[0], wu[0], wd[0], tg, 16)
+        want = self._ragged(xs, wg[0], wu[0], wd[0], gs)
+        np.testing.assert_allclose(np.asarray(got[:live_rows], jnp.float32), np.asarray(want[:live_rows], jnp.float32),
+                                   atol=3e-2, rtol=3e-2)
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_a_wide_geometry_cut_small(self, monkeypatch, layer):
+        MG, xs, wg, wu, wd, gs, tg, live_rows = self._case(4, 256, 512, 16, [33, 5, 0, 16], layers=3, seed=layer)
+        assert MG.width_block(6144, 2048, 2) == 512                                              # the served width: four blocks
+        monkeypatch.setattr(MG, "_WEIGHT_VMEM", 3 * 256 * 128 * 2 * 2)                           # room for a block of 128
+        assert MG.width_block(256, 512, 2) == 128
+        got = MG.moe_swiglu_rows(xs, wg, wu, wd, tg, 16, jnp.int32(live_rows // 16), jnp.int32(layer), name="moe_swiglu_decode")
+        want = self._ragged(xs, wg[layer], wu[layer], wd[layer], gs)
+        np.testing.assert_allclose(np.asarray(got[:live_rows], jnp.float32), np.asarray(want[:live_rows], jnp.float32),
+                                   atol=3e-2, rtol=3e-2)
+        # another layer's bank gives another answer: the index is read
+        other = MG.moe_swiglu_rows(xs, wg, wu, wd, tg, 16, jnp.int32(live_rows // 16), jnp.int32(1))
+        assert np.abs(np.asarray(other[:live_rows], jnp.float32) - np.asarray(want[:live_rows], jnp.float32)).max() > 0.1

@@ -128,15 +128,18 @@ def _ffn_with_cache(h, lp, cfg: LlamaConfig):
     """Decode-side FFN: dense SwiGLU, or the MoE mixture when the layer
     params carry a router (Mixtral family).
 
-    The MoE DECODE path (short Tq) computes ALL experts and combines with
-    the top-k one-hot gates — at decode batch sizes (a handful of tokens)
-    the step is weight-bandwidth-bound and B·K distinct expert picks touch
-    most experts anyway, so dense-expert compute costs ~nothing extra
-    while avoiding per-token weight gathers; gates renormalize over top-k
-    exactly like training (parallel/expert._gating). PREFILL (long Tq)
-    routes through the training dispatch instead — all-expert compute
-    over a whole prompt would pay E/top_k× the FFN FLOPs and materialize
-    [B, T, E, F] banks."""
+    The MoE DECODE path (short Tq) computes ALL experts for every row and
+    combines with the top-k one-hot gates: E / top_k times the products a
+    routed dispatch makes, in exchange for no sort and no gather. At Mixtral's
+    8 experts and a handful of rows that is cheap beside the weight read,
+    which touches most experts anyway. It does not scale: at 128 experts and
+    256 rows it is sixteen times the products, as long again as the layer's
+    whole weight read, which is why models/exaone_moe.py decodes through
+    parallel/expert.held_expert_ffn (sorted rows, the chosen experts only).
+    Gates renormalize over top-k exactly like training
+    (parallel/expert._gating). PREFILL (long Tq) routes through the training
+    dispatch instead — all-expert compute over a whole prompt would pay
+    E/top_k× the FFN FLOPs and materialize [B, T, E, F] banks."""
     if "router" not in lp:
         g = jax.nn.silu(_mm(h, lp["w_gate"]))
         u = _mm(h, lp["w_up"])

@@ -292,6 +292,56 @@ def insert_paged_prefill(
     )
 
 
+# -- a window layer's cache: a ring a slot ---------------------------------------
+#
+# A layer whose queries see the last W positions needs W of them and the decode
+# chunk in flight, whatever the context's length. Its cache is a pool of its own
+# with ONE page a slot, `ring` positions long, used as a ring: position p of slot s
+# lives at row p % ring of page s, and every logical page of the slot's table is
+# that page (`ring_table`). paged_decode_attention then serves it unchanged: a step
+# reads positions [len + 1 - W, len - staged), at most two logical pages, which are
+# the same physical page read twice, and masks each row by the position its logical
+# page gives it. That is right as long as the ring holds every position a step may
+# read and the chunk's write lands on none of them: ring >= W + chunk - 1. A row
+# holds the newest position congruent to it; an older one (an earlier lap, or the
+# slot's last tenant) lies below the window's start and is masked by position.
+# write_decode_chunk writes the ring as it writes any pool.
+
+#: rows of a ring beyond the window: the decode chunk may be this long (+ 1)
+RING_SLACK = 16
+
+
+def init_window_rings(layers: int, num_slots: int, kv_heads: int, window: int, head_dim: int, dtype):
+    """(k, v) [layers, slots, Hkv, window + RING_SLACK, Dh]: memory that does not grow with max_len."""
+    shape = (layers, num_slots, kv_heads, window + RING_SLACK, head_dim)
+    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+
+def ring_table(num_slots: int, max_len: int, ring: int) -> jax.Array:
+    """[S, pages that max_len positions span + 1]: every logical page of slot s is physical page s."""
+    return jnp.broadcast_to(jnp.arange(num_slots, dtype=jnp.int32)[:, None], (num_slots, -(-max_len // ring) + 1))
+
+
+def insert_window_rings(rk: jax.Array, rv: jax.Array, tail_k: jax.Array, tail_v: jax.Array, slot, true_len):
+    """Admission: a request's last W prefilled positions into its slot's rings.
+    tail_k/v [Lw, Hkv, W, Dh] hold positions true_len - W .. true_len - 1 in
+    order (those below 0 do not exist). The slot's whole page is written, one
+    contiguous update of the donated rings: rows of positions the tail does
+    not hold are zeroed, and no step reads them (they lie below the window of
+    every later position)."""
+    ring, w = rk.shape[3], tail_k.shape[2]
+    row = jnp.arange(ring)
+    pos = true_len - 1 - (true_len - 1 - row) % ring          # the newest position below true_len that lives in the row
+    at = pos - (true_len - w)
+    held = ((pos >= 0) & (at >= 0))[None, None, :, None]
+
+    def page(tail):
+        return jnp.where(held, jnp.take(tail, jnp.clip(at, 0, w - 1), axis=2), 0)[:, None].astype(rk.dtype)
+
+    return (jax.lax.dynamic_update_slice(rk, page(tail_k), (0, slot, 0, 0, 0)),
+            jax.lax.dynamic_update_slice(rv, page(tail_v), (0, slot, 0, 0, 0)))
+
+
 def write_decode_chunk(
     pk: jax.Array, pv: jax.Array,        # pools [L, P, Hkv, page_len, Dh] (donated by the caller's jit)
     stage_k: jax.Array, stage_v: jax.Array,  # [L, S, n, Hkv, Dh] — the chunk's staged columns

@@ -12,9 +12,11 @@ from __future__ import annotations
 import importlib
 import sys
 
-#: modules a replica can serve. mixtral is not among them: its serving weights
-#: have no loader yet (PERF.md section 7)
-SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala")
+#: modules a replica can serve. mixtral is not among them: `serving_http` draws and loads no weights for
+#: it (the benchmark's families/mixtral.serve_install raises) and its decode computes every expert for every
+#: row (generate._ffn_with_cache); a routed FFN is served by exaone_moe, whose decode multiplies the chosen
+#: experts only
+SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala", "tony_tpu.models.exaone_moe")
 
 
 def presets() -> dict:

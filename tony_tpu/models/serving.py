@@ -1,6 +1,7 @@
 """Continuous-batching decode engine (any model module that supplies its
 serving programs: `ServingPrograms`, below; the Llama and Mixtral families'
-are built here, MiniCPM-SALA's in models/minicpm_sala.py).
+are built here, MiniCPM-SALA's in models/minicpm_sala.py, the exaone_moe
+family's in models/exaone_moe.py).
 
 The reference orchestrates training jobs only — serving is new capability
 (SURVEY.md §2.5 "absent" rows); this is the slot-based engine layer above
@@ -92,6 +93,14 @@ _ADMISSIONS = obs_metrics.counter(
     "tony_serve_admissions_total",
     "requests given a slot, by what the device was doing at their insert: a decode chunk in flight, or nothing",
     labelnames=("under",))
+# a model whose layers hold part of their experts returns these three with a chunk's tokens, summed on the
+# device over the chunk's steps and routed layers, from live slots' rows (ServingPrograms.decode_chunk)
+_EXPERT_COUNTS = (
+    obs_metrics.counter("tony_serve_expert_rows_total", "decode rows that landed on an expert this replica holds"),
+    obs_metrics.counter("tony_serve_expert_rows_max_total",
+                        "decode rows of the fullest held expert, a layer and step: the straggler a grouped product waits for"),
+    obs_metrics.counter("tony_serve_expert_choices_total", "expert choices decode rows made (rows x experts a token)"),
+)
 
 
 # in the order a pass runs them (``ContinuousBatcher.step``)
@@ -434,7 +443,9 @@ class ServingPrograms(NamedTuple):
     prefill_chunk: object     # (params, tokens [1, T], staging, take) -> (logits of row take-1 [1, V], staging')
     prefill_pad: object       # (take, prefill_chunk, room) -> padding rows of a prompt's last chunk
     insert: object            # paged: (cache, staging, fresh_pages, pt_row, slot, true_len, j0, n) -> cache'
-    decode_chunk: object      # (params, cache, tokens, key, n=, temperature=, top_k=, samp=) -> (tokens, all, cache')
+    # (params, cache, tokens, key, n=, temperature=, top_k=, samp=) -> (tokens, all, cache'[, expert counts [3]:
+    # held rows, the fullest held expert's rows, choices; where layers hold part of their experts])
+    decode_chunk: object
     release: object           # (cache, mask [S]) -> cache' with the masked slots idle
     visible_tokens: object    # (context lengths, numpy) -> cache positions a decode step may read at each
     prefill_path: object      # (pos, take) -> "dense" | "sparse": how that chunk's attention reads its keys
@@ -1101,8 +1112,8 @@ class ContinuousBatcher:
 
     def _dispatch_chunk(self):
         """Dispatch one decode chunk over the running slots. Returns the
-        {slot: request} it was dispatched with and its tokens [h, S], still on
-        the device. A request the host already knows to end inside the chunk
+        {slot: request} it was dispatched with, its tokens [h, S], still on
+        the device, and what else the model's chunk returns. A request the host already knows to end inside the chunk
         (``ends_within``) gives its slot up HERE, so that this pass's admission
         can refill it behind the chunk and no chunk is lost to the hand-over."""
         self.phase.to("dispatch")
@@ -1123,8 +1134,9 @@ class ContinuousBatcher:
             use_ragged = self.attn == "ragged" or (
                 self.attn == "auto" and bucket > self.RAGGED_THRESHOLD
             )
+        counts = ()
         if use_ragged:
-            toks, seq, self.cache = self.programs.decode_chunk(
+            toks, seq, self.cache, *counts = self.programs.decode_chunk(
                 self.params, self.cache, self.tokens, self._split(), n=h,
                 temperature=self.temperature, top_k=self.top_k, samp=samp,
             )
@@ -1148,7 +1160,7 @@ class ContinuousBatcher:
                 self._free_slot(slot)
             else:
                 self._slot_len[slot] = min(self._slot_len[slot] + h, self.max_len)
-        return flying, seq
+        return flying, seq, counts
 
     def step(self) -> bool:
         """One pass. Returns True while work remains.
@@ -1163,7 +1175,7 @@ class ContinuousBatcher:
         the host and the dispatch of the next, no admission program is
         dispatched and no admission scalar uploaded. With nothing running
         there is no (1) and no (3): admission runs with the device idle."""
-        flying, seq = self._dispatch_chunk() if self.running else ({}, None)
+        flying, seq, counts = self._dispatch_chunk() if self.running else ({}, None, ())
         self.phase.to("admit")
         self._flush_retired()
         self._admit("chunk" if flying else "idle")
@@ -1171,6 +1183,8 @@ class ContinuousBatcher:
             self.phase.to("decode_wait")
             seq_host = np.asarray(seq)  # [h, S]: ONE device→host transfer
             self.phase.to("emit")
+            for counter, value in zip(_EXPERT_COUNTS, np.asarray(counts[0]) if counts else ()):
+                counter.inc(int(value))
             # the slots the chunk was dispatched WITH: by now ``running`` may
             # name a slot's next owner, whose tokens these are not
             for slot, req in flying.items():

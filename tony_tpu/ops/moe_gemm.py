@@ -100,28 +100,39 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
-def _fwd_kernel(tg_ref, xs_ref, wg_ref, wu_ref, wd_ref, ys_ref):
-    x = xs_ref[...]
-    F = wg_ref.shape[2]
-    if F_CHUNK and F % F_CHUNK == 0 and F > F_CHUNK:
-        # F-chunked: overlap the next chunk's gate/up MXU work with the
-        # current chunk's VPU silu·mul tail (statically unrolled so Mosaic
-        # can software-pipeline the chunk sequence)
-        acc = jnp.zeros((x.shape[0], wd_ref.shape[2]), jnp.float32)
-        for c in range(F // F_CHUNK):
-            sl = slice(c * F_CHUNK, (c + 1) * F_CHUNK)
-            g = jnp.dot(x, wg_ref[0, :, sl], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, wu_ref[0, :, sl], preferred_element_type=jnp.float32)
+def _fwd_kernel(tg_ref, meta_ref, xs_ref, wg_ref, wu_ref, wd_ref, ys_ref, acc_ref):
+    """One row tile x one block of the expert width: the block's part of
+    ``silu(x Wg) * (x Wu) Wd`` added into the tile's float32 accumulator,
+    which leaves for HBM with the last block. ``meta_ref[0]`` is the number
+    of live row tiles: a tile past them (slack of the static row bound) is
+    skipped, its blocks mapped onto the last live tile's so that nothing is
+    fetched or written for it."""
+    from jax.experimental import pallas as pl
+
+    m, c = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(m < meta_ref[0])
+    def _tile():
+        @pl.when(c == 0)
+        def _init():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+
+        x = xs_ref[...]
+        fb = wg_ref.shape[1]
+        # within a block, F-chunked: overlap the next chunk's gate/up MXU work
+        # with the current chunk's VPU silu·mul tail (statically unrolled so
+        # Mosaic can software-pipeline the chunk sequence)
+        step = F_CHUNK if F_CHUNK and fb % F_CHUNK == 0 and fb > F_CHUNK else fb
+        for lo in range(0, fb, step):
+            sl = slice(lo, lo + step)
+            g = jnp.dot(x, wg_ref[:, sl], preferred_element_type=jnp.float32)
+            u = jnp.dot(x, wu_ref[:, sl], preferred_element_type=jnp.float32)
             h = (_silu(g) * u).astype(x.dtype)
-            acc += jnp.dot(h, wd_ref[0, sl, :], preferred_element_type=jnp.float32)
-        ys_ref[...] = acc.astype(ys_ref.dtype)
-        return
-    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-    h = (_silu(g) * u).astype(x.dtype)
-    ys_ref[...] = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32).astype(
-        ys_ref.dtype
-    )
+            acc_ref[...] += jnp.dot(h, wd_ref[sl, :], preferred_element_type=jnp.float32)
+
+        @pl.when(c == pl.num_programs(1) - 1)
+        def _flush():
+            ys_ref[...] = acc_ref[...].astype(ys_ref.dtype)
 
 
 def _bwd_kernel(
@@ -177,38 +188,86 @@ def _bwd_kernel(
     )
 
 
-def _fwd_call(xs, wg, wu, wd, tile_group, tile):
+#: VMEM the three weight blocks of a grid step may take, double-buffered (of the 100 MB the call asks for)
+_WEIGHT_VMEM = 48 * 1024 * 1024
+
+
+def width_block(D: int, F: int, itemsize: int) -> int:
+    """The block of the expert width a grid step holds: all of F where an
+    expert's three slabs fit VMEM double-buffered (D=1024, F=2048: one block,
+    the slab resident across an expert's tiles), else the largest multiple of
+    128 that divides F and fits (D=6144, F=2048 in bf16: 512)."""
+    for fb in range(F, 127, -128):
+        if F % fb == 0 and fb % 128 == 0 and 3 * D * fb * itemsize * 2 <= _WEIGHT_VMEM:
+            return fb
+    return F
+
+
+def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_swiglu_grouped"):
+    """wg/wu [L, E, D, F], wd [L, E, F, D] (or without the leading L): the
+    banks of every layer that shares them, with the layer's index a scalar
+    operand: a layer's slice handed in would be a copy of it a call (a Mosaic
+    operand needs a buffer of its own)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    if wg.ndim == 3:
+        wg, wu, wd = wg[None], wu[None], wd[None]
     PN, D = xs.shape
-    E, _, F = wg.shape
+    F = wg.shape[-1]
+    fb = width_block(D, F, xs.dtype.itemsize)
+    n_tiles, n_blocks = PN // tile, F // fb
+    meta = jnp.stack([jnp.asarray(n_tiles if live is None else live, jnp.int32), jnp.asarray(layer, jnp.int32)])
+
+    def rows(m, c, tg, meta):
+        return jnp.minimum(m, meta[0] - 1), 0
+
+    def block(m, c, meta):
+        return jnp.where(m < meta[0], c, n_blocks - 1)
+
+    def up(m, c, tg, meta):
+        return meta[1], tg[jnp.minimum(m, meta[0] - 1)], 0, block(m, c, meta)
+
+    def down(m, c, tg, meta):
+        return meta[1], tg[jnp.minimum(m, meta[0] - 1)], block(m, c, meta), 0
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(PN // tile,),
+        num_scalar_prefetch=2,
+        grid=(n_tiles, n_blocks),
         in_specs=[
-            pl.BlockSpec((tile, D), lambda m, tg: (m, 0)),
-            pl.BlockSpec((1, D, F), lambda m, tg: (tg[m], 0, 0)),
-            pl.BlockSpec((1, D, F), lambda m, tg: (tg[m], 0, 0)),
-            pl.BlockSpec((1, F, D), lambda m, tg: (tg[m], 0, 0)),
+            pl.BlockSpec((tile, D), rows),
+            pl.BlockSpec((None, None, D, fb), up),
+            pl.BlockSpec((None, None, D, fb), up),
+            pl.BlockSpec((None, None, fb, D), down),
         ],
-        out_specs=pl.BlockSpec((tile, D), lambda m, tg: (m, 0)),
+        out_specs=pl.BlockSpec((tile, D), rows),
+        scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32)],
     )
     return pl.pallas_call(
         _fwd_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((PN, D), xs.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",),  # revisit caching needs order
-            vmem_limit_bytes=100 * 1024 * 1024,  # weight slabs resident (v5e: 128M)
+            dimension_semantics=("arbitrary", "arbitrary"),  # revisit caching needs order
+            vmem_limit_bytes=100 * 1024 * 1024,  # weight blocks resident (v5e: 128M)
         ),
         interpret=interpret(),
+        name=name,
         cost_estimate=pl.CostEstimate(
             flops=2 * PN * D * F * 3,
-            bytes_accessed=(xs.size * 2 + (wg.size + wu.size + wd.size)) * xs.dtype.itemsize,
+            bytes_accessed=(xs.size * 2 + 3 * wg.shape[1] * D * F) * xs.dtype.itemsize,
             transcendentals=PN * F,
         ),
-    )(tile_group, xs, wg, wu, wd)
+    )(tile_group, meta, xs, wg, wu, wd)
+
+
+def moe_swiglu_rows(xs, wg, wu, wd, tile_group, tile, live, layer=0, name="moe_swiglu_grouped"):
+    """The forward alone, for serving: ``moe_swiglu_grouped``'s product over the
+    first ``live`` row tiles (a traced count); rows of later tiles are left
+    unwritten and the caller never reads them. ``wg``/``wu``/``wd`` may carry
+    a leading layer dimension, with ``layer`` the index into it. ``name`` is
+    the call's name in a trace."""
+    return _fwd_call(xs, wg, wu, wd, tile_group, tile, live, layer, name)
 
 
 def _bwd_call(xs, dy, wg, wu, wd, tile_group, tile):

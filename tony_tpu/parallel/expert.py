@@ -30,6 +30,18 @@ class MoEConfig:
     # | ragged_xla (force jax.lax.ragged_dot) | gather (indexed, capacity)
     # | dense (GShard einsum)
     dispatch: str = "ragged"
+    # how a token's router logits become gates: "softmax" (Mixtral: softmax over
+    # all experts, top-k of it, renormalised) | "sigmoid" (each expert scored by
+    # itself; the top-k of score + a bias that chooses and does not weigh; the
+    # chosen scores renormalised, then x routed_scale)
+    scoring: str = "softmax"
+    routed_scale: float = 1.0
+    # (first, count): the experts this layer holds of the num_experts it routes
+    # over. Routing and the gates' normalisation are over all of them; only
+    # assignments that land on a held expert are sorted, computed and combined,
+    # and what the absent experts would add is left out. None: all held. The
+    # ragged dispatch only, forward only (serving).
+    held: tuple | None = None
 
 
 def capacity(tokens_per_batch: int, cfg: MoEConfig) -> int:
@@ -38,10 +50,16 @@ def capacity(tokens_per_batch: int, cfg: MoEConfig) -> int:
 
 
 def _gating(
-    x: jax.Array, router_w: jax.Array, cfg: MoEConfig, token_mask: jax.Array | None = None
+    x: jax.Array, router_w: jax.Array, cfg: MoEConfig, token_mask: jax.Array | None = None,
+    bias: jax.Array | None = None,
 ):
     """Gating shared by every dispatch scheme: router softmax, top-k gates
-    (renormalized, Mixtral convention), aux losses.
+    (renormalized, Mixtral convention), aux losses. With
+    ``cfg.scoring == "sigmoid"``: float32 sigmoid scores, the top-k of
+    ``score + bias`` (``bias`` [E] chooses and does not weigh), the chosen
+    SCORES renormalised and scaled by ``cfg.routed_scale``; a float32 router
+    is multiplied in float32 at full precision (two scores that nearly tie
+    decide which expert a token takes).
 
     ``token_mask`` [B, T] (packed batches): masked-out tokens — padding —
     get zero gates and are excluded from the balance/z losses, so pads
@@ -53,14 +71,28 @@ def _gating(
 
     # bf16 inputs with f32 accumulation: an explicit x.astype(f32) would
     # materialize a full f32 activation copy just for this tiny projection
-    logits = jnp.einsum(
-        "btd,de->bte", x, router_w.astype(x.dtype),
-        preferred_element_type=jnp.float32,
-    )
-    probs = jax.nn.softmax(logits, axis=-1)
-
-    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)            # [B,T,K]
+    if cfg.scoring == "sigmoid":
+        logits = jnp.einsum(
+            "btd,de->bte", x.astype(router_w.dtype), router_w,
+            preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
+        )
+        probs = jax.nn.sigmoid(logits)
+        _, gate_idx = jax.lax.top_k(probs if bias is None else probs + bias, cfg.top_k)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        scale = cfg.routed_scale
+    elif cfg.scoring == "softmax":
+        logits = jnp.einsum(
+            "btd,de->bte", x, router_w.astype(x.dtype),
+            preferred_element_type=jnp.float32,
+        )
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)        # [B,T,K]
+        scale = 1.0
+    else:
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {cfg.scoring!r}")
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
+    if scale != 1.0:
+        gate_vals = gate_vals * scale
     choice_onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)   # [B,T,K,E]
     if token_mask is not None:
         m = token_mask.astype(jnp.float32)
@@ -186,7 +218,7 @@ def route_indices(x, router_w, cfg: MoEConfig, token_mask: jax.Array | None = No
 
 def route_ragged(
     x, router_w, cfg: MoEConfig, token_mask: jax.Array | None = None,
-    tile: int | None = None,
+    tile: int | None = None, bias: jax.Array | None = None,
 ):
     """Capacity-FREE routing for the grouped-GEMM (ragged) dispatch.
 
@@ -210,6 +242,12 @@ def route_ragged(
     read back by the combine. The row count becomes the STATIC
     ``PN = (ceil(N/tile) + E) · tile ≥ sum(padded group sizes)``.
 
+    With ``cfg.held = (first, count)`` only the choices that land on experts
+    ``first .. first + count - 1`` are sorted: the groups are the ``count``
+    held experts, a choice of an absent expert has no row (its ``dest`` is
+    the row count, past every row, and its gate is zero), and the row count
+    keeps its static bound (every choice could be held).
+
     Returns (sort_tok [N or PN] int32 — flat B·T token index in
     expert-major order, dest [N] int32 — each choice's position in that
     order, gate_vals [B,T,K] f32, gate_sorted [N or PN] f32 (zero on pad
@@ -219,7 +257,14 @@ def route_ragged(
     E, K = cfg.num_experts, cfg.top_k
     N = B * T * K
 
-    gate_vals, gate_idx, _, aux = _gating(x, router_w, cfg, token_mask)
+    gate_vals, gate_idx, _, aux = _gating(x, router_w, cfg, token_mask, bias)
+    on = None
+    if cfg.held is not None:
+        first, E = cfg.held
+        gate_idx = gate_idx - first
+        on = (gate_idx >= 0) & (gate_idx < E)
+        gate_idx = jnp.where(on, gate_idx, -1)          # one_hot(-1) is a row of zeros: in no group
+        gate_vals = jnp.where(on, gate_vals, 0.0)
     # rank-within-expert via per-batch-row cumsum ([B, T·K, E], depth
     # log(T·K) with B in parallel — the construction r2 measured as free)
     # + a tiny [B, E] prefix across rows; global order is b-major within
@@ -237,15 +282,17 @@ def route_ragged(
     dest = jnp.sum(
         (pos_b + (offsets[None, :] + prefix_b)[:, None, :]) * oh, axis=-1
     ).reshape(N)                                                         # [N], injective
+    if on is not None:
+        dest = jnp.where(on.reshape(N), dest, rows)                      # past every row: scattered nowhere
 
     # invert the permutation with two small typed scatters (token ids stay
     # int32 — a packed f32 payload would corrupt ids beyond 2^24 tokens).
     # gate_sorted keeps ZERO on pad rows, which is what makes the combine's
     # gather-form backward blank them out (see _combine_gather).
     tok = jnp.arange(N, dtype=jnp.int32) // K                            # flat B·T token id
-    sort_tok = jnp.zeros((rows,), jnp.int32).at[dest].set(tok)
+    sort_tok = jnp.zeros((rows,), jnp.int32).at[dest].set(tok, mode="drop")
     gate_sorted = jnp.zeros((rows,), jnp.float32).at[dest].set(
-        gate_vals.reshape(N).astype(jnp.float32)
+        gate_vals.reshape(N).astype(jnp.float32), mode="drop"
     )
 
     aux = dict(aux)
@@ -271,14 +318,22 @@ def _kernel_eligible(cfg: MoEConfig, D: int, F: int, dtype) -> bool:
     )
 
 
-def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, tile):
+def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, tile, layer=None, name="moe_swiglu_grouped"):
     """Grouped expert SwiGLU on sorted rows: the fused Pallas kernel when
-    ``tile`` is set, else three jax.lax.ragged_dot grouped GEMMs."""
+    ``tile`` is set, else three jax.lax.ragged_dot grouped GEMMs.
+    ``layer`` (forward only, serving): the banks are every layer's, stacked,
+    and this is the index of the layer's; the kernel takes the whole stack and
+    skips the row tiles past the groups' rows (the static row bound's slack),
+    leaving them unwritten."""
     from tony_tpu.ops import moe_gemm
 
     if tile is not None:
         tg = moe_gemm.tile_group_map(group_sizes, xs.shape[0] // tile, tile)
+        if layer is not None:
+            return moe_gemm.moe_swiglu_rows(xs, w_gate, w_up, w_down, tg, tile, group_sizes.sum() // tile, layer, name)
         return moe_gemm.moe_swiglu_grouped(xs, w_gate, w_up, w_down, tg, tile)
+    if layer is not None:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
     g = jax.nn.silu(jax.lax.ragged_dot(xs, w_gate, group_sizes))
     u = jax.lax.ragged_dot(xs, w_up, group_sizes)
     return jax.lax.ragged_dot((g * u).astype(xs.dtype), w_down, group_sizes)
@@ -506,6 +561,56 @@ def _ragged_expert_ffn_ep(
     return fn(x, router_w, w_gate, w_up, w_down, tm)
 
 
+def held_tile(cfg: MoEConfig, choices: int, tuned: int) -> int:
+    """The row tile of a layer that holds part of its experts. The row bound is
+    static (every choice could land here): ``ceil(choices / tile) + held``
+    tiles, of which the held experts' own are live and the rest are skipped at
+    about 3 us a grid step; a live tile costs its expert's whole slab. So the
+    tile is the tuned one while an expert expects fewer rows than it holds (a
+    decode step's 16 rows, a short prefill), and twice that beyond (a 2048-row
+    chunk: half the tiles, and an expert's slab read once, not twice). On the
+    chip at 6144 x 2048, 256 rows: 32 -> 2.18 ms a layer, 64 -> 1.77, 128 -> 1.74."""
+    return 2 * tuned if choices // cfg.num_experts >= tuned else tuned
+
+
+def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConfig, count_mask=None,
+                    name: str = "moe_swiglu_grouped"):
+    """The routed FFN of a layer that holds ``cfg.held`` of its experts, for
+    serving: x [T, D] -> (y [T, D], rows [count] int32). ``w_gate`` / ``w_up``
+    [L, count, D, F], ``w_down`` [L, count, F, D]: the held experts' banks of
+    every layer that has them, and ``layer`` [] the index of this one. Routing
+    is over all ``cfg.num_experts`` (``route_ragged``); the rows sorted,
+    multiplied and combined are the choices that landed on a held expert, so
+    a step touches the held-and-chosen experts' weights and nothing else of
+    size. ``rows`` counts each held expert's real rows (its load this call),
+    from the tokens ``count_mask`` [T] marks (a decode step's idle slots are
+    computed like any row and counted as none). ``name``: what the fused call
+    is called in a trace (a decode step's and a prefill chunk's are told apart
+    by it). No capacity: no choice is dropped. No backward."""
+    from tony_tpu.ops import moe_gemm
+
+    if cfg.held is None or cfg.dispatch != "ragged":
+        raise ValueError(f"held_expert_ffn wants cfg.held and dispatch 'ragged', got {cfg.held!r} / {cfg.dispatch!r}")
+    T, D = x.shape
+    K, F = cfg.top_k, w_gate.shape[-1]
+    tile = (
+        held_tile(cfg, T * K, moe_gemm.tuned_tile(cfg.num_experts, D, F, x.dtype))
+        if _kernel_eligible(cfg, D, F, x.dtype) else None
+    )
+    sort_tok, dest, gate_vals, _, group_sizes, _ = route_ragged(x[None], router_w, cfg, None, tile=tile, bias=bias)
+    rows = sort_tok.shape[0]
+    on = dest < rows                                                     # [T*K]: the choice has a row
+    counted = on if count_mask is None else on & jnp.repeat(count_mask, K)
+    real = jnp.zeros((cfg.held[1],), jnp.int32).at[
+        jnp.where(counted, jnp.searchsorted(jnp.cumsum(group_sizes), dest, side="right"), cfg.held[1])
+    ].add(1, mode="drop")
+    ys = _expert_swiglu(x[sort_tok], w_gate, w_up, w_down, group_sizes, tile, layer, name)
+    # a choice of an absent expert reads row 0 and is masked, never multiplied: rows past the groups are unwritten
+    yc = jnp.where(on[:, None], ys[jnp.where(on, dest, 0)], 0).reshape(T, K, D)
+    y = jnp.einsum("tkd,tk->td", yc, gate_vals.reshape(T, K).astype(ys.dtype))
+    return y.astype(x.dtype), real
+
+
 def _ragged_expert_ffn(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, token_mask):
     """Grouped-GEMM MoE: expert matmuls computed straight from gathered
     rows via ``jax.lax.ragged_dot`` (XLA's megablox-style grouped GEMM) —
@@ -605,6 +710,8 @@ def moe_ffn(
     computing only its own experts' span.
     """
     dtype = x.dtype
+    if cfg.held is not None:
+        raise ValueError("a layer that holds part of its experts runs through held_expert_ffn (serving, forward only)")
     if cfg.dispatch in ("ragged", "ragged_xla"):
         expert_sharded = (
             mesh is not None
