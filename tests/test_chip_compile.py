@@ -302,8 +302,8 @@ class TestOtherKernels:
 class TestExaoneMoeKernelsAtServedWidths:
     """K-EXAONE's served widths (hidden 6144, experts of 2048, 16 of 128 held, 64 q / 8 kv heads of 128,
     window 128, 256 slots): the grouped product with its weight blocks cut along the expert width
-    (a whole slab is 75 MB, 151 double-buffered, over the chip's fast memory), the decode kernel on a
-    window layer's ring, and the two flash forms a prefill chunk runs."""
+    (a whole slab is 75 MB, 151 double-buffered, over the chip's fast memory), the decode kernel for a
+    window layer's rings, and the two flash forms a prefill chunk runs."""
 
     D, F, HELD, LAYERS, HQ, WINDOW, SLOTS = 6144, 2048, 16, 4, 64, 128, 256
 
@@ -323,20 +323,24 @@ class TestExaoneMoeKernelsAtServedWidths:
         assert MG.width_block(self.D, self.F, 2) == 512
 
     def test_decode_attention_on_a_window_layers_ring(self, chip):
+        """Every window layer's rings are one operand with a layer index; a block of slots goes
+        through the call's own pipeline: one kernel, found by its name, and no copy of a layer's
+        rings (75 MB each of K and V) beside it."""
         from tony_tpu.models.paged_cache import RING_SLACK
 
-        ring_len = self.WINDOW + RING_SLACK
         q, cur = _s((self.SLOTS, self.HQ, DH), jnp.bfloat16, chip), _s((self.SLOTS, HKV, DH), jnp.bfloat16, chip)
-        ring = _s((self.LAYERS, self.SLOTS, HKV, ring_len, DH), jnp.bfloat16, chip)
-        table = _s((self.SLOTS, 6144 // ring_len + 2), jnp.int32, chip)
+        ring = _s((self.LAYERS, self.SLOTS, HKV, self.WINDOW + RING_SLACK, DH), jnp.bfloat16, chip)
         lengths, layer = _s((self.SLOTS,), jnp.int32, chip), _s((), jnp.int32, chip)
         staged = _s((self.SLOTS, 8, HKV, DH), jnp.bfloat16, chip)
 
-        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
-            return DA.paged_decode_attention(q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v,
-                                             window=self.WINDOW, staged_k=sk, staged_v=sv, staged_count=count)
+        def fn(q, rk, rv, lengths, layer, cur_k, cur_v, sk, sv, count):
+            return DA.ring_decode_attention(q, rk, rv, lengths, layer, cur_k=cur_k, cur_v=cur_v, window=self.WINDOW,
+                                            staged_k=sk, staged_v=sv, staged_count=count)
 
-        assert _kernel_calls(fn, q, ring, ring, lengths, table, layer, cur, cur, staged, staged, lengths) == 1
+        compiled = jax.jit(fn).lower(q, ring, ring, lengths, layer, cur, cur, staged, staged, lengths).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "ring_decode_attention" in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
 
     @pytest.mark.parametrize("chunk", [128, 2048])
     def test_a_prefill_chunks_attention(self, chip, chunk):

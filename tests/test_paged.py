@@ -15,6 +15,7 @@ reuse, reservation-based admission. Properties under test:
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -143,6 +144,106 @@ class TestPagedKernel:
                 cur_k=jnp.zeros((1, 1, 128), jnp.float32),
                 cur_v=jnp.zeros((1, 1, 128), jnp.float32),
             )
+
+
+# ---------------------------------------------------------------------------
+# A window layer's ring, read whole: against plain attention over the explicit
+# list of positions a step may see
+# ---------------------------------------------------------------------------
+RING, RING_WINDOW, RING_STAGED, RING_LAYERS, RING_MAX_LEN = 24, 16, 8, 3, 128
+
+#: a slot each: (positions its tenant has written to the ring, the step's length, staged rows)
+RING_SLOTS = {
+    "idle": (0, 0, 0),
+    "not-yet-full-over-the-last-tenants-rows": (10, 10, 0),
+    "staged-rows-only": (0, 3, 3),
+    "lapped-four-times-count-7": (93, 100, 7),
+    "window-wraps-the-rings-end-count-0": (50, 50, 0),
+    "count-3": (44, 47, 3),
+    "lo-lands-mid-ring-count-7": (33, 40, 7),
+    # the chunk began at 125 and is 5 steps in: the caller clips 130 to max_len - 1, so the
+    # ring's newest rows (122..124) lie past the pool's part of the slot and are not read
+    "length-clipped-at-max-len": (125, RING_MAX_LEN - 1, 5),
+    "exactly-one-lap": (24, 24, 0),
+    "window-starts-at-position-0": (15, 15, 0),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _ring_case(dtype):
+    """The ten slots' rings as their histories leave them, layer 1 of 3, and what plain
+    attention over each slot's visible positions gives. Every row starts as the last tenant's
+    (large values: a row read by mistake shows), then position p lands in row p % ring."""
+    from tony_tpu.ops.decode_attention import ring_decode_attention
+
+    S, H, Hkv, Dh = len(RING_SLOTS), 8, 2, 16
+    rng = np.random.default_rng(5)
+    draw = lambda *shape: np.asarray(jnp.asarray(rng.standard_normal(shape), dtype).astype(jnp.float32))
+    hist_k, hist_v = draw(S, RING_MAX_LEN, Hkv, Dh), draw(S, RING_MAX_LEN, Hkv, Dh)
+    rk, rv = 50.0 + draw(RING_LAYERS, S, Hkv, RING, Dh), 50.0 + draw(RING_LAYERS, S, Hkv, RING, Dh)
+    q, cur_k, cur_v = draw(S, H, Dh), draw(S, Hkv, Dh), draw(S, Hkv, Dh)
+    sk, sv = draw(S, RING_STAGED, Hkv, Dh), draw(S, RING_STAGED, Hkv, Dh)
+    want = np.zeros((S, H, Dh), np.float32)
+    for s, (written, length, count) in enumerate(RING_SLOTS.values()):
+        for p in range(written):
+            rk[1, s, :, p % RING], rv[1, s, :, p % RING] = hist_k[s, p], hist_v[s, p]
+        pool_len, lo = max(length - count, 0), max(length + 1 - RING_WINDOW, 0)
+        seen = [(hist_k[s, p], hist_v[s, p]) for p in range(lo, pool_len)]
+        seen += [(sk[s, j], sv[s, j]) for j in range(count) if pool_len + j >= lo]
+        seen.append((cur_k[s], cur_v[s]))
+        keys, vals = (np.stack(a, axis=1) for a in zip(*seen))                      # [Hkv, n, Dh]
+        scores = np.einsum("hrd,hnd->hrn", q[s].reshape(Hkv, H // Hkv, Dh) * Dh ** -0.5, keys)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        want[s] = np.einsum("hrn,hnd->hrd", p / p.sum(-1, keepdims=True), vals).reshape(H, Dh)
+    operands = (jnp.asarray(q, dtype), jnp.asarray(rk, dtype), jnp.asarray(rv, dtype),
+                jnp.asarray([c[1] for c in RING_SLOTS.values()], jnp.int32))
+    kw = dict(cur_k=jnp.asarray(cur_k, dtype), cur_v=jnp.asarray(cur_v, dtype), window=RING_WINDOW,
+              staged_k=jnp.asarray(sk, dtype), staged_v=jnp.asarray(sv, dtype),
+              staged_count=jnp.asarray([c[2] for c in RING_SLOTS.values()], jnp.int32))
+    return operands, kw, np.asarray(ring_decode_attention(*operands, jnp.int32(1), **kw).astype(jnp.float32)), want
+
+
+class TestRingKernel:
+    @pytest.mark.parametrize("case,dtype", [(c, d) for d in ("float32", "bfloat16") for c in RING_SLOTS]
+                             + [("layer-index-traced-under-cond-in-a-scan", "float32"),
+                                ("a-slot-count-the-block-does-not-divide", "float32"),
+                                ("agrees-with-the-page-walk-over-ring-table", "float32")])
+    def test_ring_decode_attention(self, case, dtype):
+        from tony_tpu.models.paged_cache import ring_table
+        from tony_tpu.ops.decode_attention import RING_BLOCK, paged_decode_attention, ring_decode_attention
+
+        operands, kw, got, want = _ring_case(dtype)
+        if case in RING_SLOTS:
+            s = list(RING_SLOTS).index(case)
+            # bfloat16: the same rows, so what is left is the output's own rounding (2 ** -9 of values under 4)
+            np.testing.assert_allclose(got[s], want[s], atol=1e-5 if dtype == "float32" else 1e-2, rtol=0)
+        elif case.startswith("layer-index"):
+            # as exaone_moe._decode_one hands it: a scan's slice, the call in one branch of a cond
+            call = lambda layer: ring_decode_attention(*operands, layer, **kw)
+
+            def body(_, xs):
+                layer, is_window = xs
+                return None, jax.lax.cond(is_window == 1, call, lambda layer: jnp.zeros(got.shape, dtype), layer)
+
+            layers, kinds = jnp.asarray([0, 0, 1, 2], jnp.int32), jnp.asarray([1, 0, 1, 1], jnp.int32)
+            outs = np.asarray(jax.lax.scan(body, None, (layers, kinds))[1])
+            np.testing.assert_array_equal(outs[2], got)
+            np.testing.assert_array_equal(outs[1], 0)
+            np.testing.assert_array_equal(outs[3], np.asarray(call(jnp.int32(2))))
+            assert not np.array_equal(outs[0], got) and not np.array_equal(outs[3], got)   # the layers do differ
+        elif case.startswith("a-slot-count"):
+            # the first n slots alone, n the first count above the block that it does not divide: smaller blocks
+            n = next(n for n in range(RING_BLOCK + 1, len(RING_SLOTS)) if n % RING_BLOCK)
+            q, rk, rv, lengths = operands
+            alone = ring_decode_attention(q[:n], rk[:, :n], rv[:, :n], lengths[:n], jnp.int32(1),
+                                          **{k: v if k == "window" else v[:n] for k, v in kw.items()})
+            np.testing.assert_array_equal(np.asarray(alone), got[:n])
+        else:
+            # the call it replaced: the same rings as pages of `ring` rows, a logical page of slot s page s
+            q, rk, rv, lengths = operands
+            paged = paged_decode_attention(q, rk, rv, lengths, ring_table(len(RING_SLOTS), RING_MAX_LEN, RING),
+                                           jnp.int32(1), **kw)
+            np.testing.assert_allclose(got, np.asarray(paged), atol=1e-5, rtol=0)
 
 
 # ---------------------------------------------------------------------------

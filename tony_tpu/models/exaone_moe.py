@@ -27,10 +27,14 @@ experts' banks are not among the scan's slices: the grouped kernel takes every
 layer's bank and a layer index (a slice handed to a Mosaic call is a copy).
 
 Serving (``serving_programs``, models/serving.py): a cache for each kind. Full
-layers keep pages in a pool over the full layers only; a window layer keeps a
+layers keep pages in a pool over the full layers only, read through the page
+table (ops/decode_attention.paged_decode_attention); a window layer keeps a
 ring of ``window + RING_SLACK`` positions a slot (models/paged_cache.py), so a
-slot's window layers cost the same at any ``max_len``. No train step and no
-sharding rules: served only.
+slot's window layers cost the same at any ``max_len``. A decode step reads a
+ring whole, once, and masks a row by the position it holds
+(``ring_decode_attention``: no page table); ``ring_table`` serves the chunk's
+one write only (``write_decode_chunk``). No train step and no sharding rules:
+served only.
 """
 
 from __future__ import annotations
@@ -364,7 +368,7 @@ class ExaoneCache(NamedTuple):
     page_table: jax.Array   # [S, max_pages]
     wk: jax.Array           # [Lw, S, Hkv, window + RING_SLACK, dh]
     wv: jax.Array
-    ring_table: jax.Array   # [S, ring pages]: every logical page of slot s is page s of wk / wv
+    ring_table: jax.Array   # [S, ring pages]: every logical page of slot s is page s of wk / wv (the chunk's write)
 
 
 def _init_cache(cfg: ExaoneMoeConfig, num_slots: int, max_len: int, page_len: int, num_pages: int) -> ExaoneCache:
@@ -404,7 +408,7 @@ def _decode_one(params, cache: ExaoneCache, tokens, cfg: ExaoneMoeConfig, staged
     """One token a slot, pool and rings read-only: (logits [S, V], lengths',
     this step's keys and values [L, S, Hkv, dh] x 2, the routed layers' held
     rows [Lr, count])."""
-    from tony_tpu.ops.decode_attention import paged_decode_attention
+    from tony_tpu.ops.decode_attention import paged_decode_attention, ring_decode_attention
 
     sk, sv, step = staged                                     # [L, S, n, Hkv, dh] x 2: the chunk's earlier steps
     S = tokens.shape[0]
@@ -417,8 +421,8 @@ def _decode_one(params, cache: ExaoneCache, tokens, cfg: ExaoneMoeConfig, staged
     def window_layer(q, k, v, i, sk_l, sv_l):
         q, k = _rope(q, cos, sin, pos), _rope(k, cos, sin, pos)
         k1, v1 = k.astype(cache.wk.dtype), v.astype(cache.wv.dtype)
-        o = paged_decode_attention(q, cache.wk, cache.wv, pos, cache.ring_table, i, cur_k=k1, cur_v=v1,
-                                   window=cfg.window, staged_k=sk_l, staged_v=sv_l, staged_count=count)
+        o = ring_decode_attention(q, cache.wk, cache.wv, pos, i, cur_k=k1, cur_v=v1, window=cfg.window,
+                                  staged_k=sk_l, staged_v=sv_l, staged_count=count)
         return o, (k1, v1)
 
     def full_layer(q, k, v, i, sk_l, sv_l):

@@ -297,15 +297,16 @@ def insert_paged_prefill(
 # A layer whose queries see the last W positions needs W of them and the decode
 # chunk in flight, whatever the context's length. Its cache is a pool of its own
 # with ONE page a slot, `ring` positions long, used as a ring: position p of slot s
-# lives at row p % ring of page s, and every logical page of the slot's table is
-# that page (`ring_table`). paged_decode_attention then serves it unchanged: a step
-# reads positions [len + 1 - W, len - staged), at most two logical pages, which are
-# the same physical page read twice, and masks each row by the position its logical
-# page gives it. That is right as long as the ring holds every position a step may
-# read and the chunk's write lands on none of them: ring >= W + chunk - 1. A row
-# holds the newest position congruent to it; an older one (an earlier lap, or the
-# slot's last tenant) lies below the window's start and is masked by position.
-# write_decode_chunk writes the ring as it writes any pool.
+# lives at row p % ring of page s. A decode step reads a slot's ring whole, once
+# (ops/decode_attention.ring_decode_attention: a block of slots is one rectangular
+# block, so no table is needed to find it), and masks a row by the position it
+# holds: the newest one below the pool's part of the slot that is congruent to the
+# row, read iff it lies in [len + 1 - W, len - staged). That is right as long as
+# the ring holds every position a step may read and the chunk's write lands on none
+# of them: ring >= W + chunk - 1. An older position (an earlier lap, or the slot's
+# last tenant) lies below the window's start and is masked by position.
+# `ring_table`, in which every logical page of slot s is page s, serves the chunk's
+# write only: write_decode_chunk writes the ring as it writes any pool.
 
 #: rows of a ring beyond the window: the decode chunk may be this long (+ 1)
 RING_SLACK = 16

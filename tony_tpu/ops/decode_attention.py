@@ -403,6 +403,141 @@ def paged_decode_attention(
     return o.reshape(S, H, Dh)
 
 
+#: slots of a ring a grid step of ``ring_decode_attention`` reads as one block. Chosen on the
+#: chip by the call's device time in a trace at 256 slots x 8 kv heads x 144 rows x 128: blocks
+#: of 2 / 4 / 8 / 16 / 32 slots took 203.8 / 204.7 / 206.5 / 210.3 / 217.6 us (PERF.md, PR 35).
+#: The HBM bounds the call at any of them (151 MB a call: 184 us at the peak); what a larger
+#: block adds is the first block's fetch, which nothing hides.
+RING_BLOCK = 2
+
+
+@functools.partial(jax.jit, static_argnames=("window",))
+def ring_decode_attention(
+    q: jax.Array,             # [S, H, Dh] — one new token per slot
+    rk: jax.Array,            # [Lw, S, Hkv, ring, Dh] — EVERY window layer's rings (read-only)
+    rv: jax.Array,
+    lengths: jax.Array,       # [S] int32 — CACHE positions (staged ones among them, current excluded)
+    layer: jax.Array,         # [] int32 — which layer's rings to read (may be traced)
+    *,
+    cur_k: jax.Array,         # [S, Hkv, Dh]
+    cur_v: jax.Array,
+    window: int,
+    staged_k: jax.Array,      # [S, W, Hkv, Dh] — the decode chunk's staging
+    staged_v: jax.Array,
+    staged_count: jax.Array,  # [S] int32
+) -> jax.Array:
+    """Decode attention of a WINDOW layer over its rings; returns o [S, H, Dh].
+
+    A ring (models/paged_cache.py) keeps position p of slot s at row
+    ``p % ring`` of ``rk[layer, s]``: a slot's whole cache is one contiguous
+    ``[Hkv, ring, Dh]`` block and the slots' blocks lie one after another, so
+    there is nothing to look up. A grid step takes a block of ``RING_BLOCK``
+    slots through a plain ``BlockSpec`` whose index map reads the layer from a
+    scalar-prefetch operand (the operand is every layer's rings: a layer's
+    slice handed to a Mosaic call would be a copy), and Pallas' pipeline
+    fetches the next block while this one is computed. Every ring is read
+    once, whole, and a row is masked by the position it holds: with
+    ``pool_len = max(length - staged_count, 0)`` row r holds the newest
+    position below ``pool_len`` that is congruent to r, and is read iff that
+    position is at least ``lo = max(length + 1 - window, 0)``. A row of an
+    earlier lap or of the slot's last tenant holds a position below ``lo``
+    (the ring has ``window + chunk - 1`` rows or more), so it is never read.
+
+    The arithmetic is ``paged_decode_attention``'s: float32 scores, softmax
+    and accumulator from the cache's rows, q scaled by ``Dh ** -0.5``, ONE
+    softmax over the ring, the staged rows (positions ``pool_len .. length -
+    1``, kept iff ``>= lo``) and the current token. The ring is one slab, so
+    nothing is rescaled between slabs; the order of summation differs from the
+    page walk's, the precision does not. An idle slot (length 0) normalises
+    over its current token alone.
+    """
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, Dh = q.shape
+    if rk.ndim != 5 or rk.shape[1] != S:
+        raise ValueError(f"rk {rk.shape}: the operand is every window layer's rings [Lw, S={S}, Hkv, ring, Dh]")
+    Hkv, ring = rk.shape[2:4]
+    n_rep, W = H // Hkv, staged_k.shape[1]
+    block = max(b for b in range(1, min(RING_BLOCK, S) + 1) if S % b == 0)
+    scale = Dh ** -0.5
+    meta = jnp.stack([lengths, staged_count], axis=1).astype(jnp.int32)       # [S, 2]
+
+    def kern(meta_ref, layer_ref, q_ref, ck_ref, cv_ref, sk_ref, sv_ref, k_ref, v_ref, o_ref):
+        first = pl.program_id(0) * block
+        # the staged rows and the current token of ALL kv heads are one small matrix a slot, rows (j, h'):
+        # a query row (h, r) takes the columns of its own head and a mask hides the others, so the fold
+        # is two matmuls and no loop (a step a row, each over [Hkv, n_rep, 1] arrays, cost a ring's read)
+        row_head = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 0) // n_rep
+        col = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 1)
+        own_head, step = row_head == col % Hkv, col // Hkv                   # step W is the current token
+
+        def one_slot(b, _):
+            length, count = meta_ref[first + b, 0], meta_ref[first + b, 1]
+            pool_len = jnp.maximum(length - count, 0)
+            lo = jnp.maximum(length + 1 - window, 0)
+            # rows 0 .. newest hold the lap of position pool_len - 1, the rows after it the lap before
+            last = jnp.maximum(pool_len - 1, 0)
+            newest = last % ring
+            lap = last - newest
+            qf = q_ref[b].astype(jnp.float32) * scale                        # [Hkv, n_rep, Dh]
+            k = k_ref[b].astype(jnp.float32)                                 # [Hkv, ring, Dh]
+            v = v_ref[b].astype(jnp.float32)
+            s = jax.lax.dot_general(qf, k, (((2,), (2,)), ((0,), (0,))),
+                                    preferred_element_type=jnp.float32)      # [Hkv, n_rep, ring]
+            row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+            pos = lap + row - jnp.where(row > newest, ring, 0)
+            s = jnp.where((pos >= lo) & (pool_len > 0), s, -1e30)
+
+            def with_current(staged_ref, cur_ref):                           # [(W + 1) * Hkv, Dh], rows (j, h')
+                staged = staged_ref[b].astype(jnp.float32).reshape(W * Hkv, Dh)
+                return jnp.concatenate([staged, cur_ref[b].astype(jnp.float32)], axis=0)
+
+            s2 = jax.lax.dot_general(qf.reshape(H, Dh), with_current(sk_ref, ck_ref), (((1,), (1,)), ((), ())),
+                                     preferred_element_type=jnp.float32)     # [H, (W + 1) * Hkv]
+            # staged row j is position pool_len + j; the current token is always shown, so every maximum
+            # is a real score and a masked column's weight is exactly 0
+            shown = own_head & ((step == W) | ((step < count) & (pool_len + step >= lo)))
+            s2 = jnp.where(shown, s2, -1e30)
+            m = jnp.maximum(s.max(axis=2, keepdims=True), s2.max(axis=1, keepdims=True).reshape(Hkv, n_rep, 1))
+            p, p2 = jnp.exp(s - m), jnp.exp(s2 - m.reshape(H, 1))
+            l = p.sum(axis=2, keepdims=True) + p2.sum(axis=1, keepdims=True).reshape(Hkv, n_rep, 1)
+            acc = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
+                                      preferred_element_type=jnp.float32)    # [Hkv, n_rep, Dh]
+            acc = acc + jax.lax.dot_general(p2, with_current(sv_ref, cv_ref), (((1,), (0,)), ((), ())),
+                                            preferred_element_type=jnp.float32).reshape(Hkv, n_rep, Dh)
+            o_ref[b] = (acc / l).astype(o_ref.dtype)
+            return 0
+
+        jax.lax.fori_loop(0, block, one_slot, 0)
+
+    def slots(*rest):  # a block of slots of an operand [S, *rest]
+        return pl.BlockSpec((block, *rest), lambda i, M, LY: (i,) + (0,) * len(rest))
+
+    rings = pl.BlockSpec((None, block, Hkv, ring, Dh), lambda i, M, LY: (LY[0], i, 0, 0, 0))
+    o = pl.pallas_call(
+        kern,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,  # meta [S, 2], layer [1]
+            grid=(S // block,),
+            in_specs=[slots(Hkv, n_rep, Dh), slots(Hkv, Dh), slots(Hkv, Dh), slots(W, Hkv, Dh), slots(W, Hkv, Dh),
+                      rings, rings],
+            out_specs=slots(Hkv, n_rep, Dh),
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, Hkv, n_rep, Dh), q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",)),
+        interpret=interpret(),
+        name="ring_decode_attention",
+        cost_estimate=pl.CostEstimate(
+            flops=4 * S * H * (ring + W + 1) * Dh,
+            bytes_accessed=2 * S * Hkv * ring * Dh * rk.dtype.itemsize,      # one layer's rings, K and V, once
+            transcendentals=S * H * (ring + W + 1),
+        ),
+    )(meta, jnp.reshape(layer, (1,)).astype(jnp.int32), q.reshape(S, Hkv, n_rep, Dh), cur_k, cur_v,
+      staged_k, staged_v, rk, rv)
+    return o.reshape(S, H, Dh)
+
+
 #: pages fetched together by the sparse kernel: one wait covers 2 x this many
 #: copies in flight, so a page of 64 positions (16 KB a head) does not pay a
 #: DMA's latency each
