@@ -17,6 +17,7 @@ the test's own process.
 
 import contextlib
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,6 +112,21 @@ class TestFlashAtLlama1bShapes:
     def test_sliding_window(self, chip):
         q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
         assert _kernel_calls(_flash_grad(window=512), q, kv, kv) == 3
+
+
+class TestFlashAtTheTrainingCellsShape:
+    def test_the_three_named_calls(self, chip):
+        """`mistral-7b.train_8k` / `train_fsdp4`, a chip's share: 2 x 32 query
+        and 8 kv heads of 128 over 8192 positions, band 4096, bfloat16 straight
+        into the MXU; n_rep x Tq is far past the resident dkv's rows, so this
+        is the streaming dkv with its own q block. The trace finds the calls
+        by these instruction names."""
+        q, kv = _s((2, 32, 8192, DH), jnp.bfloat16, chip), _s((2, 8, 8192, DH), jnp.bfloat16, chip)
+        text = jax.jit(_flash_grad(window=4096)).lower(q, kv, kv).compile().as_text()
+        assert text.count("tpu_custom_call") == 3
+        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            # jax wraps the name in its transforms' (jvp_..., transpose_jvp_...)
+            assert len(re.findall(rf"%\w*{name}[\w.]* = ", text)) == 1, name
 
 
 class TestDecodeAttentionAtServeShapes:

@@ -291,6 +291,59 @@ class TestFlashAttentionInterpret:
             assert float(jnp.max(jnp.abs(a - b))) / scale < 2e-4
 
 
+def _brute_force_classes(Tq, Tk, bq, bk, causal, window):
+    """[Tq//bq, Tk//bk] of 0 hidden / 1 edge / 2 interior from the [Tq, Tk]
+    mask itself (absolute positions, as the kernels count them)."""
+    qp, kp = np.arange(Tq)[:, None], np.arange(Tk)[None, :]
+    mask = np.ones((Tq, Tk), bool)
+    if causal:
+        mask &= qp >= kp
+    if window > 0:
+        mask &= qp - kp < window
+    blocks = mask.reshape(Tq // bq, bq, Tk // bk, bk)
+    return blocks.any(axis=(1, 3)).astype(int) + blocks.all(axis=(1, 3))
+
+
+class TestFlashBlockClasses:
+    @pytest.mark.parametrize("Tq,Tk", [(1024, 1024), (512, 1024)])
+    @pytest.mark.parametrize("bq,bk", [(128, 256), (256, 128)])
+    @pytest.mark.parametrize("window", ["none", "one_block", "three_blocks", "past_Tk"])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_counts_and_loop_bounds_match_the_mask(self, causal, window, bq, bk, Tq, Tk):
+        """Every pair of an interior block visible, none of a hidden block,
+        every visible pair in a visited block: `flash_block_classes` and the
+        bounds the kernels loop over (`_k_runs` from a q block's side, forward
+        and dq; `_q_runs` from a k block's, both dkv forms) against the mask."""
+        window = {"none": 0, "one_block": bk, "three_blocks": 3 * bk, "past_Tk": Tk + 5}[window]
+        want = _brute_force_classes(Tq, Tk, bq, bk, causal, window)
+        nq, nk = Tq // bq, Tk // bk
+        by_q, by_k = np.zeros_like(want), np.zeros_like(want)
+        for qb in range(nq):
+            s, e1, e2, e = A._k_runs(qb, bq, bk, nk, causal, window)
+            by_q[qb, s:e] = 1
+            by_q[qb, e1:e2] = 2
+        for kb in range(nk):
+            s, e1, e2, e = A._q_runs(kb, bq, bk, nq, causal, window)
+            by_k[s:e, kb] = 1
+            by_k[e1:e2, kb] = 2
+        np.testing.assert_array_equal(by_q, want)
+        np.testing.assert_array_equal(by_k, want)
+        assert A.flash_block_classes(Tq, Tk, bq, bk, causal, window) == {
+            "interior": int((want == 2).sum()), "edge": int((want == 1).sum()),
+            "hidden": int((want == 0).sum())}
+        # where the band is wide, no block is crossed by both of its edges
+        if causal and window and A._wide_band(bq, bk, window):
+            qp, kp = np.arange(Tq)[:, None], np.arange(Tk)[None, :]
+            above = (qp < kp).reshape(nq, bq, nk, bk).any(axis=(1, 3))
+            below = (qp - kp >= window).reshape(nq, bq, nk, bk).any(axis=(1, 3))
+            assert not (above & below & (want > 0)).any()
+
+    def test_the_training_cells_shape(self):
+        # 8192 positions, band 4096, the module's blocks: what PERF.md section 5 quotes
+        assert A.flash_block_classes(8192, 8192, 256, 512, True, 4096) == {
+            "interior": 168, "edge": 48, "hidden": 296}
+
+
 class TestSegmentIds:
     """Packed-sequence (segment-id) masking: reference semantics + the flash
     kernels (fwd, dq, resident dkv, streaming dkv) in interpret mode."""
@@ -411,6 +464,43 @@ class TestSlidingWindow:
     def test_flash_bwd_streaming_variant(self, monkeypatch):
         monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
         self.test_flash_bwd_matches_reference()
+
+    @pytest.mark.parametrize("dkv", ["resident", "streaming", "streaming_own_q_block"])
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("blocks", [(128, 256), (256, 128)])
+    def test_every_block_class_through_all_four_kernels(self, monkeypatch, blocks, dtype, dkv):
+        """T 1024, window 512, GQA 4:2: every q block's k loop (and every k
+        block's q loop) has hidden blocks, blocks an edge crosses and interior
+        runs, two blocks long where the k block is the smaller. float32 at the
+        file's tolerances; bfloat16 (operands straight into the MXU, p and ds
+        cast to it) against the float32 reference at a bfloat16 rounding."""
+        bq, bk = blocks
+        T, window = 1024, 512
+        classes = A.flash_block_classes(T, T, bq, bk, True, window)
+        assert min(classes.values()) > 0, classes
+        if dkv != "resident":
+            monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
+        if dkv == "streaming":  # the geometry above in the streaming grid too
+            monkeypatch.setattr(A, "_DKV_STREAM_BLOCK_Q", bq)
+        q, k, v = self._qkv(T=T)
+        do = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32)
+
+        def ref(q, k, v):
+            return A.attention_reference(
+                q, A.repeat_kv(k, 2), A.repeat_kv(v, 2), causal=True, window=window)
+
+        want, vjp = jax.vjp(ref, q, k, v)
+        want = (want, *vjp(do))
+        dt = jnp.dtype(dtype)
+        q, k, v, do = (x.astype(dt) for x in (q, k, v, do))
+        o, lse = A._flash_fwd_lanes(q, k, v, True, bq, bk, None, window)
+        got = (o, *A._flash_bwd_impl(q, k, v, o, lse, do, True, bq, bk, None, window))
+        tol = 2e-4 if dtype == "float32" else 2e-2
+        for name, a, b in zip("o dq dk dv".split(), got, want):
+            assert a.dtype == dt, name
+            scale = float(jnp.max(jnp.abs(b)))
+            err = float(jnp.max(jnp.abs(a.astype(jnp.float32) - b))) / scale
+            assert err < tol, f"{name} rel err {err}"
 
     def test_window_with_segments(self):
         q, k, v = self._qkv(T=512)

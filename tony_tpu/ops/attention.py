@@ -89,18 +89,202 @@ def _seg_arrays(segment_ids: jax.Array, B: int, T: int) -> tuple[jax.Array, jax.
 # Pallas flash attention (TPU)
 # ---------------------------------------------------------------------------
 
+def _imin(a, b):
+    """min over Python ints (the block counts, the streaming pair list) and
+    traced scalars (a kernel's loop bounds) alike."""
+    return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
+
+
+def _imax(a, b):
+    return max(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.maximum(a, b)
+
+
+def _k_runs(qb, block_q: int, block_k: int, nk: int, causal: bool, window: int):
+    """The k blocks q block ``qb`` visits, as bounds s <= e1 <= e2 <= e over
+    ABSOLUTE positions (query i sees keys <= i, and > i - window): [s, e1) is
+    crossed by the window's lower edge, [e1, e2) is interior (every pair
+    visible: min q_pos >= max k_pos and max q_pos - min k_pos < window),
+    [e2, e) is crossed by the diagonal. Blocks outside [s, e) are hidden: no
+    pair visible, not visited. An empty visit has e <= s and e1 == e2 == e."""
+    q_lo, q_hi = qb * block_q, qb * block_q + block_q - 1
+    end = _imin(nk, q_hi // block_k + 1) if causal else nk
+    start = _imax(0, (q_lo - window + 1) // block_k) if window > 0 else 0
+    int_end = (q_lo + 1) // block_k if causal else nk
+    int_start = (q_hi - window) // block_k + 1 if window > 0 else 0
+    e1 = _imin(_imax(int_start, start), end)
+    e2 = _imin(_imax(int_end, e1), end)
+    return start, e1, e2, end
+
+
+def _q_runs(kb, block_q: int, block_k: int, nq: int, causal: bool, window: int):
+    """``_k_runs`` from the k block's side (the dkv kernels): the q blocks k
+    block ``kb`` is visible to, [s, e1) crossed by the diagonal, [e1, e2)
+    interior, [e2, e) crossed by the window's edge."""
+    k_lo, k_hi = kb * block_k, kb * block_k + block_k - 1
+    start = _imin(nq, k_lo // block_q) if causal else 0
+    end = _imin(nq, (k_hi + window - 1) // block_q + 1) if window > 0 else nq
+    int_start = (k_hi + block_q - 1) // block_q if causal else 0
+    int_end = (k_lo + window) // block_q if window > 0 else nq
+    e1 = _imin(_imax(int_start, start), end)
+    e2 = _imin(_imax(int_end, e1), end)
+    return start, e1, e2, end
+
+
+def _wide_band(block_q: int, block_k: int, window: int) -> bool:
+    """A band at least block_q + block_k - 2 wide keeps its two edges in
+    different blocks: an edge block then owes the mask of one edge only."""
+    return window >= block_q + block_k - 2
+
+
+def _edge_masks(block_q: int, block_k: int, causal: bool, window: int):
+    """(causal, window) masks each of the three runs of ``_k_runs`` /
+    ``_q_runs`` owes, as ((diagonal run), (interior run), (window-edge run))."""
+    wide = _wide_band(block_q, block_k, window)
+    return (causal, window > 0 and not wide), (False, False), (causal and not wide, window > 0)
+
+
+def flash_block_classes(
+    Tq: int, Tk: int, block_q: int, block_k: int, causal: bool, window: int,
+) -> dict[str, int]:
+    """How many of one head's (q block, k block) pairs are ``interior`` (every
+    pair visible: the kernels run them with no iota, compare or select),
+    ``edge`` (crossed by the diagonal or the window's edge: masked) and
+    ``hidden`` (no visible pair: never visited). Static, so a function and not
+    a counter; from the same bounds the kernels loop over."""
+    nq, nk = Tq // block_q, Tk // block_k
+    interior = edge = 0
+    for qb in range(nq):
+        s, e1, e2, e = _k_runs(qb, block_q, block_k, nk, causal, window)
+        interior += max(e2 - e1, 0)
+        edge += max(e1 - s, 0) + max(e - e2, 0)
+    return {"interior": interior, "edge": edge, "hidden": nq * nk - interior - edge}
+
+
+def _visible(s, q_pos, k_pos, mask_causal: bool, window: int, sq=None, sk=None):
+    """Scores with the pairs a block's class says may be hidden set to
+    NEG_INF: one select over the tile, none where nothing is owed. ``window``
+    is 0 where the window's edge cannot cross the block."""
+    ok = None
+    if mask_causal:
+        ok = q_pos >= k_pos
+    if window > 0:
+        w = k_pos > q_pos - window
+        ok = w if ok is None else ok & w
+    if sq is not None:
+        same = sq == sk
+        ok = same if ok is None else ok & same
+    return s if ok is None else jnp.where(ok, s, NEG_INF)
+
+
+def _k_pos(kb, block_k: int):
+    return kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+
+
+def _q_pos(qb, block_q: int):
+    return qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+
+
+# tiles a step of the interior loop takes, longest first: a `fori_loop` of
+# dynamic trip count is one tile a basic block, so nothing of tile i + 1 runs
+# under tile i and each pays the MXU's fill and drain alone. Two tiles a step,
+# then the odd one: at the training cells' shape the forward 7.19 -> 7.00 ms
+# and dq 8.51 -> 8.36 a call, four a step no better (7.01, 8.35: PERF.md
+# section 6, PR 40); it is all the unrolling a shape with no band gets
+_INTERIOR_UNROLL = (2, 1)
+
+
+def _tiles(lo, hi, tile, carry, unroll=(1,)):
+    """``carry = tile(i, carry)`` for i in [lo, hi), ``unroll`` tiles a loop
+    step (a cascade: each loop takes what the one before left over)."""
+    for u in unroll:
+        steps = jnp.maximum(hi - lo, 0) // u
+
+        def step(i, c, lo=lo, u=u):
+            for t in range(u):
+                c = tile(lo + i * u + t, c)
+            return c
+
+        carry = jax.lax.fori_loop(0, steps, step, carry)
+        lo = lo + steps * u
+    return carry
+
+
+# the longest run of tiles a q block deep in the band gets as one basic block
+_STEADY_MAX_TILES = 12
+
+
+def _steady_runs(Tq: int, Tk: int, block_q: int, block_k: int, causal: bool, window: int):
+    """(window-edge, interior, diagonal) tile counts of a q block deep in the
+    band — the last one's, where the band's lower edge lies inside the
+    sequence — or None where the shape has no such block. Under a band every
+    q block past the first ``window`` rows visits that same short sequence of
+    blocks, so the kernels run it as one basic block of static length."""
+    start, e1, e2, end = _k_runs(Tq // block_q - 1, block_q, block_k, Tk // block_k, causal, window)
+    if not (causal and window > 0 and 0 < start < end <= start + _STEADY_MAX_TILES):
+        return None
+    return e1 - start, e2 - e1, end - e2
+
+
+def _band_loop(qb, block_q: int, block_k: int, nk: int, causal: bool, window: int, steady,
+               make_tile, carry):
+    """``carry`` through every k block q block ``qb`` visits (``_k_runs``),
+    each by the tile of its class: ``make_tile(mask_causal, mask_window)``
+    gives ``tile(kb, carry)``. A q block whose runs have the ``steady``
+    lengths (``_steady_runs``) takes them as one unrolled basic block, in
+    which the scheduler runs one tile's vector work under the next tile's
+    matmuls; every other q block loops a run at a time."""
+    start, e1, e2, end = _k_runs(qb, block_q, block_k, nk, causal, window)
+    diagonal, interior, window_edge = _edge_masks(block_q, block_k, causal, window)
+
+    def by_runs(carry):
+        if window > 0:
+            carry = _tiles(start, e1, make_tile(*window_edge), carry)
+        carry = _tiles(e1, e2, make_tile(*interior), carry, _INTERIOR_UNROLL)
+        if causal:
+            carry = _tiles(e2, end, make_tile(*diagonal), carry)
+        return carry
+
+    if steady is None:
+        return by_runs(carry)
+    n_edge, n_interior, n_diagonal = steady
+
+    def unrolled(carry):
+        classes = [window_edge] * n_edge + [interior] * n_interior + [diagonal] * n_diagonal
+        for t, masks in enumerate(classes):
+            carry = make_tile(*masks)(start + t, carry)
+        return carry
+
+    is_steady = (e1 - start == n_edge) & (e2 - e1 == n_interior) & (end - e2 == n_diagonal)
+    return jax.lax.cond(is_steady, unrolled, by_runs, carry)
+
+
+_NT = (((1,), (1,)), ((), ()))   # a @ b.T
+_NN = (((1,), (0,)), ((), ()))   # a @ b
+_TN = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _scaled(q_blk, scale: float):
+    """A q block times the softmax scale, once for every tile it meets and in
+    the type it arrived in (the MXU takes bfloat16 as it is; float32 stays
+    float32)."""
+    return (q_blk.astype(jnp.float32) * scale).astype(q_blk.dtype)
+
+
 def _flash_kernel(
     q_ref, k_ref, v_ref, *rest,
-    block_k: int, causal: bool, has_seg: bool, window: int, scale: float,
+    block_k: int, causal: bool, has_seg: bool, window: int, scale: float, steady,
 ):
     """Grid: (B*H, Tq//block_q). Online softmax over KV blocks in VMEM.
 
     Also emits the per-row logsumexp (scaled-score space) so the Pallas
     backward can recompute probabilities blockwise without the T×T matrix.
-    With ``has_seg``, two extra refs carry packed-sequence segment ids
-    (q-side rows, k-side cols) and scores cross segments are masked.
-    ``window`` > 0 adds the sliding-window band: k blocks wholly before the
-    window are skipped (no DMA, no flops), partial blocks are masked.
+    The key loop is cut by block class (``_k_runs``): blocks the band hides
+    are not visited (no DMA, no flops), blocks it covers whole run with no
+    iota, compare or select, and only the blocks the window's edge or the
+    diagonal crosses pay for that one mask. With ``has_seg``, two extra refs
+    carry packed-sequence segment ids (q-side rows, k-side cols) and every
+    visited block also pays the segment compare (segment ids are data).
+    q, k, v reach the MXU in their own type; m, l, o stay float32.
     """
     from jax.experimental import pallas as pl
 
@@ -111,48 +295,37 @@ def _flash_kernel(
     block_q, D = q_ref.shape
     Tk = k_ref.shape[0]
     q_blk_idx = pl.program_id(1)
-    q = q_ref[:] .astype(jnp.float32) * scale
-    q_pos = q_blk_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    q = _scaled(q_ref[:], scale)
+    q_pos = _q_pos(q_blk_idx, block_q)
     sq = segq_ref[:][:, :1] if has_seg else None  # [block_q, 1]
 
-    num_k_blocks = pl.cdiv(Tk, block_k)
-    kb_start = 0
-    if causal:
-        # only blocks at or below the diagonal contribute
-        num_k_blocks = jnp.minimum(num_k_blocks, (q_blk_idx + 1) * block_q // block_k + 1)
-    if window > 0:
-        # first k position any row of this q block can see: q_first−window+1
-        kb_start = jnp.maximum(0, (q_blk_idx * block_q - window + 1) // block_k)
+    def make_tile(mask_causal: bool, mask_window: bool):
+        def tile(kb, carry):
+            o, m, l = carry
+            ks = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+            k_blk, v_blk = k_ref[ks, :], v_ref[ks, :]
+            s = jax.lax.dot_general(q, k_blk, _NT, preferred_element_type=jnp.float32)
+            s = _visible(s, q_pos, _k_pos(kb, block_k), mask_causal, window if mask_window else 0,
+                         sq, segk_ref[:1, ks] if has_seg else None)
+            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new)
+            l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
+            o_new = o * alpha + jax.lax.dot_general(
+                p.astype(v_blk.dtype), v_blk, _NN, preferred_element_type=jnp.float32
+            )
+            return o_new, m_new, l_new
 
-    def body(kb, carry):
-        o, m, l = carry
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )  # [block_q, block_k]
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window > 0:
-            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-        if has_seg:
-            sk = segk_ref[:1, pl.ds(kb * block_k, block_k)]  # [1, block_k]
-            s = jnp.where(sq == sk, s, NEG_INF)
-        m_b = jnp.max(s, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m, m_b)
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(s - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        o_new = o * alpha + jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return o_new, m_new, l_new
+        return tile
 
-    o0 = jnp.zeros((block_q, D), jnp.float32)
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o, m, l = jax.lax.fori_loop(kb_start, num_k_blocks, body, (o0, m0, l0))
+    carry = (
+        jnp.zeros((block_q, D), jnp.float32),
+        jnp.full((block_q, 1), NEG_INF, jnp.float32),
+        jnp.zeros((block_q, 1), jnp.float32),
+    )
+    carry = _band_loop(q_blk_idx, block_q, block_k, pl.cdiv(Tk, block_k), causal, window, steady,
+                       make_tile, carry)
+    o, m, l = carry
     l = jnp.maximum(l, 1e-20)
     o_ref[:] = (o / l).astype(o_ref.dtype)
     lse_ref[:] = jnp.broadcast_to(m + jnp.log(l), (block_q, _STAT_LANES))
@@ -194,7 +367,7 @@ def _flash_fwd_lanes(
     has_seg = segment_ids is not None
     kernel = functools.partial(
         _flash_kernel, block_k=block_k, causal=causal, has_seg=has_seg,
-        window=window, scale=scale,
+        window=window, scale=scale, steady=_steady_runs(Tq, Tk, block_q, block_k, causal, window),
     )
     in_specs = [
         pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0)),
@@ -225,6 +398,7 @@ def _flash_fwd_lanes(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret(),
+        name="flash_fwd",
         cost_estimate=pl.CostEstimate(
             flops=4 * B * H * Tq * Tk * D,
             bytes_accessed=2 * (qf.size + kf.size + vf.size) * q.dtype.itemsize,
@@ -283,9 +457,11 @@ def flash_attention(
 
 def _flash_bwd_dq_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_k: int, causal: bool, has_seg: bool, window: int, scale: float,
+    block_k: int, causal: bool, has_seg: bool, window: int, scale: float, steady,
 ):
-    """Grid: (B*H, Tq//block_q). dq[i] = scale · Σ_kb ds[i,kb] @ k[kb]."""
+    """Grid: (B*H, Tq//block_q). dq[i] = scale · Σ_kb ds[i,kb] @ k[kb], over
+    the forward's three runs of k blocks (``_k_runs``): hidden blocks are not
+    visited, interior blocks are not masked, an edge block pays its one edge."""
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -295,74 +471,54 @@ def _flash_bwd_dq_kernel(
     block_q, D = q_ref.shape
     Tk = k_ref.shape[0]
     q_blk_idx = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32)
-    do = do_ref[:].astype(jnp.float32)
+    q = _scaled(q_ref[:], scale)
+    do = do_ref[:]
     lse = lse_ref[:][:, :1]            # [block_q, 1] (lanes identical)
     delta = delta_ref[:][:, :1]        # [block_q, 1]
-    q_pos = q_blk_idx * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+    q_pos = _q_pos(q_blk_idx, block_q)
     sq = segq_ref[:][:, :1] if has_seg else None
 
-    num_k_blocks = pl.cdiv(Tk, block_k)
-    kb_start = 0
-    if causal:
-        num_k_blocks = jnp.minimum(num_k_blocks, (q_blk_idx + 1) * block_q // block_k + 1)
-    if window > 0:
-        kb_start = jnp.maximum(0, (q_blk_idx * block_q - window + 1) // block_k)
+    def make_tile(mask_causal: bool, mask_window: bool):
+        def tile(kb, dq):
+            ks = pl.ds(pl.multiple_of(kb * block_k, block_k), block_k)
+            k_blk, v_blk = k_ref[ks, :], v_ref[ks, :]
+            s = jax.lax.dot_general(q, k_blk, _NT, preferred_element_type=jnp.float32)
+            s = _visible(s, q_pos, _k_pos(kb, block_k), mask_causal, window if mask_window else 0,
+                         sq, segk_ref[:1, ks] if has_seg else None)
+            p = jnp.exp(s - lse)                                   # [block_q, block_k]
+            dp = jax.lax.dot_general(do, v_blk, _NT, preferred_element_type=jnp.float32)
+            ds = p * (dp - delta)
+            return dq + jax.lax.dot_general(
+                ds.astype(k_blk.dtype), k_blk, _NN, preferred_element_type=jnp.float32
+            )
 
-    def body(kb, dq):
-        k_blk = k_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        v_blk = v_ref[pl.ds(kb * block_k, block_k), :].astype(jnp.float32)
-        s = scale * jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        k_pos = kb * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-        if causal:
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        if window > 0:
-            s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-        if has_seg:
-            sk = segk_ref[:1, pl.ds(kb * block_k, block_k)]
-            s = jnp.where(sq == sk, s, NEG_INF)
-        p = jnp.exp(s - lse)                                   # [block_q, block_k]
-        dp = jax.lax.dot_general(                              # do @ v^T
-            do, v_blk, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        ds = p * (dp - delta)
-        dq = dq + jax.lax.dot_general(                         # ds @ k
-            ds, k_blk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        return dq
+        return tile
 
-    dq = jax.lax.fori_loop(kb_start, num_k_blocks, body, jnp.zeros((block_q, D), jnp.float32))
+    dq = _band_loop(q_blk_idx, block_q, block_k, pl.cdiv(Tk, block_k), causal, window, steady,
+                    make_tile, jnp.zeros((block_q, D), jnp.float32))
     dq_ref[:] = (scale * dq).astype(dq_ref.dtype)
 
 
 def _dkv_block_contrib(
-    q_blk, do_blk, lse_blk, delta_blk, k, v, q_pos, k_pos, causal, scale,
-    sq=None, sk=None, window: int = 0,
+    q_blk, do_blk, lse_blk, delta_blk, k, v, q_pos, k_pos, mask_causal: bool, window: int,
+    sq=None, sk=None,
 ):
     """One q-block's contribution to (dk, dv) for one k block — the shared
     gradient math of both dkv variants (they differ only in data staging).
-    Returns dk WITHOUT the final `scale` factor (callers apply it)."""
-    s = scale * jax.lax.dot_general(
-        q_blk, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )  # [block_q, block_k]
-    if causal:
-        s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-    if window > 0:
-        s = jnp.where(q_pos - k_pos < window, s, NEG_INF)
-    if sq is not None:
-        s = jnp.where(sq == sk, s, NEG_INF)
+    ``q_blk`` arrives scaled (``_scaled``), so the scores and dk both carry
+    the softmax scale; ``mask_causal`` and ``window`` are what the block's
+    class owes (False and 0 on an interior block). Operands go to the MXU in
+    their own type, p and ds in the type of the operand they meet."""
+    s = jax.lax.dot_general(q_blk, k, _NT, preferred_element_type=jnp.float32)  # [block_q, block_k]
+    s = _visible(s, q_pos, k_pos, mask_causal, window, sq, sk)
     p = jnp.exp(s - lse_blk)
     dv_c = jax.lax.dot_general(                    # p^T @ do
-        p, do_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        p.astype(do_blk.dtype), do_blk, _TN, preferred_element_type=jnp.float32
     )
-    dp = jax.lax.dot_general(                      # do @ v^T
-        do_blk, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-    )
+    dp = jax.lax.dot_general(do_blk, v, _NT, preferred_element_type=jnp.float32)  # do @ v^T
     ds = p * (dp - delta_blk)
-    dk_c = jax.lax.dot_general(                    # ds^T @ q
-        ds, q_blk, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+    dk_c = jax.lax.dot_general(                    # ds^T @ (scale · q)
+        ds.astype(q_blk.dtype), q_blk, _TN, preferred_element_type=jnp.float32
     )
     return dk_c, dv_c
 
@@ -372,9 +528,11 @@ def _flash_bwd_dkv_kernel_resident(
     block_q: int, n_rep: int, causal: bool, has_seg: bool, window: int, scale: float,
 ):
     """Grid: (B*Hkv, Tk//block_k) with the whole [n_rep·Tq, D] q/do staged in
-    VMEM — the fast variant for moderate sequence lengths: causally-skipped
-    q blocks cost neither DMA nor flops (the fori_loop starts at the
-    diagonal). Selected when the staged operands fit the VMEM budget."""
+    VMEM — the fast variant for moderate sequence lengths. The q loop is cut
+    by block class (``_q_runs``): q blocks the band hides from this k block
+    cost neither DMA nor flops, interior blocks no mask, and the blocks the
+    diagonal or the window's edge crosses that one mask. Selected when the
+    staged operands fit the VMEM budget."""
     from jax.experimental import pallas as pl
 
     if has_seg:
@@ -384,66 +542,71 @@ def _flash_bwd_dkv_kernel_resident(
     block_k, D = k_ref.shape
     Tq = q_ref.shape[0] // n_rep
     k_blk_idx = pl.program_id(1)
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    k_pos = k_blk_idx * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
+    k, v = k_ref[:], v_ref[:]
+    k_pos = _k_pos(k_blk_idx, block_k)
     sk = segk_ref[:1, :] if has_seg else None  # [1, block_k] (this k block)
 
-    num_q_blocks = pl.cdiv(Tq, block_q)
-    qb_start = (k_blk_idx * block_k) // block_q if causal else 0
-    qb_end = num_q_blocks
-    if window > 0:
-        # rows beyond the window of this k block's LAST position contribute 0
-        last_k = k_blk_idx * block_k + block_k - 1
-        qb_end = jnp.minimum(num_q_blocks, (last_k + window - 1) // block_q + 1)
-
-    def make_body(g_off: int):
-        def body(qb, carry):
+    def run(g_off: int, lo, hi, carry, mask_causal: bool, mask_window: bool):
+        def tile(qb, carry):
             dk, dv = carry
-            q_blk = q_ref[pl.ds(g_off + qb * block_q, block_q), :].astype(jnp.float32)
-            do_blk = do_ref[pl.ds(g_off + qb * block_q, block_q), :].astype(jnp.float32)
-            lse_blk = lse_ref[pl.ds(g_off + qb * block_q, block_q), :][:, :1]
-            delta_blk = delta_ref[pl.ds(g_off + qb * block_q, block_q), :][:, :1]
-            q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
+            rows = pl.ds(pl.multiple_of(g_off + qb * block_q, block_q), block_q)
+            q_blk = _scaled(q_ref[rows, :], scale)
+            lse_blk = lse_ref[rows, :][:, :1]
+            delta_blk = delta_ref[rows, :][:, :1]
             # seg rows are PER HEAD (not group-folded): index by qb directly
             sq = segq_ref[pl.ds(qb * block_q, block_q), :][:, :1] if has_seg else None
             dk_c, dv_c = _dkv_block_contrib(
-                q_blk, do_blk, lse_blk, delta_blk, k, v, q_pos, k_pos, causal, scale,
-                sq, sk, window,
+                q_blk, do_ref[rows, :], lse_blk, delta_blk, k, v, _q_pos(qb, block_q), k_pos,
+                mask_causal, window if mask_window else 0, sq, sk,
             )
             return dk + dk_c, dv + dv_c
 
-        return body
+        # a tile a step: the staged rows leave no VMEM for an unrolled step's values
+        return jax.lax.fori_loop(lo, hi, tile, carry)
 
+    start, e1, e2, end = _q_runs(k_blk_idx, block_q, block_k, pl.cdiv(Tq, block_q), causal, window)
+    diagonal, interior, window_edge = _edge_masks(block_q, block_k, causal, window)
     zeros = jnp.zeros((block_k, D), jnp.float32)
-    dk, dv = zeros, zeros
+    carry = (zeros, zeros)
     for g in range(n_rep):  # static group unroll
-        dk, dv = jax.lax.fori_loop(qb_start, qb_end, make_body(g * Tq), (dk, dv))
-    dk_ref[:] = (scale * dk).astype(dk_ref.dtype)
-    dv_ref[:] = dv.astype(dv_ref.dtype)
+        if causal:
+            carry = run(g * Tq, start, e1, carry, *diagonal)
+        carry = run(g * Tq, e1, e2, carry, *interior)
+        if window > 0:
+            carry = run(g * Tq, e2, end, carry, *window_edge)
+    dk_ref[:] = carry[0].astype(dk_ref.dtype)
+    dv_ref[:] = carry[1].astype(dv_ref.dtype)
 
 
 # staged q/do bytes (bf16, double-buffered) beyond which the resident dkv
 # variant would exceed the ~16M scoped-VMEM budget → use the streaming grid
 _DKV_RESIDENT_MAX_QROWS = 4096
+# rows of q a streaming dkv grid step takes (the largest doubling of the
+# backward's block_q that divides Tq, up to this)
+_DKV_STREAM_BLOCK_Q = 1024
 
 
 def _flash_bwd_dkv_kernel(
     kb_ref, qrow_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
     num_q_blocks: int, causal: bool, has_seg: bool, window: int, scale: float,
 ):
-    """Grid: (B*Hkv, n_pairs) — one causally-contributing (k block, q block)
-    pair per step, streamed via scalar-prefetched index arrays.
+    """Grid: (B*Hkv, n_pairs) — one (k block, q block) pair that holds a
+    visible pair per step, streamed via scalar-prefetched index arrays.
 
     Only one q block is staged in VMEM per step (long sequences would blow
     the VMEM budget if the whole [n_rep·Tq, D] q were staged, as an earlier
-    design did), and — unlike a dense (k block × q block) grid — pairs above
-    the causal diagonal are never enumerated, so they cost neither DMA nor a
-    grid step. dk/dv output blocks are revisited across consecutive pairs of
+    design did), and — unlike a dense (k block × q block) grid — pairs the
+    band hides are never enumerated, so they cost neither DMA nor a grid
+    step. dk/dv output blocks are revisited across consecutive pairs of
     the same k block (pairs are sorted by k block), accumulating in f32 in
     VMEM; GQA group members are folded into the q dim (layout
     [B*Hkv, n_rep*Tq, …]), so each pair's q-block index within its own head
-    (for position masking) is ``qrow % num_q_blocks``.
+    (for position masking) is ``qrow % num_q_blocks``. The step's pair takes
+    the body of its class, worked out from the two indices: no mask on an
+    interior pair, the one mask of the edge that crosses it otherwise. What a
+    step costs outside its matmuls (the pipeline's step, the float32
+    read-modify-write of dk / dv) is by the step, so this call's q block is
+    its own and larger (``_DKV_STREAM_BLOCK_Q``).
     """
     from jax.experimental import pallas as pl
 
@@ -463,21 +626,30 @@ def _flash_bwd_dkv_kernel(
         dk_ref[:] = jnp.zeros_like(dk_ref)
         dv_ref[:] = jnp.zeros_like(dv_ref)
 
-    k = k_ref[:].astype(jnp.float32)
-    v = v_ref[:].astype(jnp.float32)
-    k_pos = k_blk_idx * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
-    q_blk = q_ref[:].astype(jnp.float32)
-    do_blk = do_ref[:].astype(jnp.float32)
-    lse_blk = lse_ref[:][:, :1]
-    delta_blk = delta_ref[:][:, :1]
-    q_pos = qb * block_q + jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
-    sq = segq_ref[:][:, :1] if has_seg else None
-    sk = segk_ref[:1, :] if has_seg else None
-    dk_c, dv_c = _dkv_block_contrib(
-        q_blk, do_blk, lse_blk, delta_blk, k, v, q_pos, k_pos, causal, scale, sq, sk, window
-    )
-    dk_ref[:] += scale * dk_c
-    dv_ref[:] += dv_c
+    # the pair's class from its indices (the rule of `_q_runs`): the diagonal
+    # crosses it unless min q_pos >= max k_pos, the window's edge unless
+    # max q_pos - min k_pos < window. The one visit a wholly hidden k block
+    # keeps (Tk > Tq) lies above the diagonal: masked to exact zeros.
+    q_lo, k_lo = qb * block_q, k_blk_idx * block_k
+    on_diagonal = q_lo < k_lo + block_k - 1 if causal else False
+    on_window_edge = q_lo + block_q - 1 - k_lo >= window if window > 0 else False
+
+    def contribute(mask_causal: bool, mask_window: bool):
+        dk_c, dv_c = _dkv_block_contrib(
+            _scaled(q_ref[:], scale), do_ref[:], lse_ref[:][:, :1], delta_ref[:][:, :1],
+            k_ref[:], v_ref[:], _q_pos(qb, block_q), _k_pos(k_blk_idx, block_k),
+            mask_causal, window if mask_window else 0,
+            segq_ref[:][:, :1] if has_seg else None, segk_ref[:1, :] if has_seg else None,
+        )
+        dk_ref[:] += dk_c
+        dv_ref[:] += dv_c
+
+    for mask_causal in (False, True) if causal else (False,):
+        for mask_window in (False, True) if window > 0 else (False,):
+            if mask_causal and mask_window and _wide_band(block_q, block_k, window):
+                continue  # the band's two edges never share a block
+            pl.when(jnp.logical_and(on_diagonal == mask_causal, on_window_edge == mask_window))(
+                functools.partial(contribute, mask_causal, mask_window))
 
 
 def _flash_bwd_impl(
@@ -523,7 +695,7 @@ def _flash_bwd_impl(
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, block_k=block_k, causal=causal, has_seg=has_seg,
-            window=window, scale=scale,
+            window=window, scale=scale, steady=_steady_runs(Tq, Tk, block_q, block_k, causal, window),
         ),
         grid=(B * H, Tq // block_q),
         in_specs=dq_specs,
@@ -531,6 +703,7 @@ def _flash_bwd_impl(
         out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret(),
+        name="flash_bwd_dq",
         cost_estimate=pl.CostEstimate(
             flops=6 * B * H * Tq * Tk * D,
             bytes_accessed=3 * (qf.size + kf.size) * q.dtype.itemsize,
@@ -542,6 +715,11 @@ def _flash_bwd_impl(
     # group is folded into the q dim (layout [B*Hkv, n_rep*Tq, …]) and the
     # innermost grid dim walks one q block at a time — O(block) VMEM at any
     # sequence length, with dk/dv blocks revisited and accumulated in f32.
+    resident = n_rep * Tq <= _DKV_RESIDENT_MAX_QROWS
+    if not resident:
+        # the streaming grid pays by the step, so its q block is its own
+        while block_q * 2 <= _DKV_STREAM_BLOCK_Q and Tq % (block_q * 2) == 0:
+            block_q *= 2
     num_q_blocks = Tq // block_q
     qg = qf.reshape(B * Hkv, n_rep * Tq, D)
     dog = dof.reshape(B * Hkv, n_rep * Tq, D)
@@ -554,7 +732,7 @@ def _flash_bwd_impl(
         transcendentals=B * H * Tq * Tk,
     )
 
-    if n_rep * Tq <= _DKV_RESIDENT_MAX_QROWS:
+    if resident:
         full_qg = pl.BlockSpec((None, n_rep * Tq, D), lambda b, i: (b, 0, 0))
         row_full_g = pl.BlockSpec((None, n_rep * Tq, _STAT_LANES), lambda b, i: (b, 0, 0))
         dkv_specs = [full_qg, blk_kv2, blk_kv2, full_qg, row_full_g, row_full_g]
@@ -583,28 +761,25 @@ def _flash_bwd_impl(
                 dimension_semantics=("parallel", "arbitrary")
             ),
             interpret=interpret(),
+            name="flash_bwd_dkv",
             cost_estimate=cost,
         )(*dkv_operands)
     else:
-        # streaming grid: enumerate only the causally-contributing
-        # (k block, group member, q block) pairs, sorted by k block, and
+        # streaming grid: enumerate only the (k block, group member, q block)
+        # pairs that hold a visible pair (`_q_runs`), sorted by k block, and
         # scalar-prefetch the index arrays so BlockSpec index maps (and the
-        # DMA pipeline) follow the sparse walk — q blocks above the diagonal
-        # are never fetched, halving DMA traffic and grid steps for causal.
+        # DMA pipeline) follow the sparse walk — q blocks the band hides are
+        # never fetched, halving DMA traffic and grid steps for causal.
         kb_l, qrow_l = [], []
         for i in range(Tk // block_k):
-            # fully-masked k blocks (possible when Tk > Tq) still emit ONE
+            qb0, _, _, qb1 = _q_runs(i, block_q, block_k, num_q_blocks, causal, window)
+            # a wholly hidden k block (possible when Tk > Tq) still emits ONE
             # q block per group member: its contribution is exactly zero
             # through the mask, but the visit zero-initializes the output
             # block, which would otherwise be returned uninitialized
-            qb0 = min((i * block_k) // block_q, num_q_blocks - 1) if causal else 0
-            qb1 = num_q_blocks
-            if window > 0:
-                # q rows past this k block's window band contribute nothing
-                last_k = i * block_k + block_k - 1
-                qb1 = max(min(num_q_blocks, (last_k + window - 1) // block_q + 1), qb0 + 1)
+            qb0 = min(qb0, num_q_blocks - 1)
             for g in range(n_rep):
-                for qb in range(qb0, qb1):
+                for qb in range(qb0, max(qb1, qb0 + 1)):
                     kb_l.append(i)
                     qrow_l.append(g * num_q_blocks + qb)
         kb = jnp.array(kb_l, dtype=jnp.int32)
@@ -671,6 +846,7 @@ def _flash_bwd_impl(
                 dimension_semantics=("parallel", "arbitrary")
             ),
             interpret=interpret(),
+            name="flash_bwd_dkv",
             cost_estimate=cost,
         )(kb, qrow, *stream_operands)
 
@@ -687,12 +863,17 @@ def _flash_bwd_impl(
 # per-row logsumexp; backward recomputes probabilities blockwise in VMEM (two
 # kernels: dq over q blocks, dk/dv over k blocks) — no T×T materialization.
 
-# bq 256 / bk 512: the r3 measured optimum on v5e — halving k-block count
-# beats 256/256 on EVERY bench preset, same-session A/Bs: llama-0.87B
-# 46.5→49.0% MFU, llama 2×8192 38.4→46.1%, moe 35.3→37.0%, BERT 34.5→37.7%.
-# (512/512 and bk 1024 fail to compile — VMEM; bq 128 is neutral.)
-# (The builders' r3 ladder, older than this code.) Env-overridable for
-# per-hardware tuning.
+# bq 256 / bk 512, chosen on a v5e at the training cells' shape ([2·32, 8192,
+# 128] / 8 kv heads, bfloat16, band 4096; device time of a call from a trace,
+# PERF.md section 6, PR 40). Forward: 7.01 ms at 256 x 512, 7.57 at 256 x 1024,
+# 11.52 at 256 x 256; 512 x 512 no longer fits VMEM once a steady q block's
+# nine tiles are one basic block. dq: 8.36 ms at 256 x 512, 8.08 at 512 x 512,
+# 9.13 at 256 x 1024, 9.93 at 256 x 256. Both grids compute the same 28.3 M
+# pairs a head (1.12 x the band's); what a smaller k block saves in pairs it
+# loses twice over in loop steps. The streaming dkv pays by the grid step, so
+# it takes more q rows a step (`_DKV_STREAM_BLOCK_Q`): 19.09 ms at 256 q rows
+# x 512, 13.77 at 512, 12.14 at 1024, 14.36 at 2048 (1.5 x the band's pairs).
+# Env-overridable for per-hardware tuning.
 _BLOCK_Q = int(os.environ.get("TONY_FLASH_BQ", "256"))
 _BLOCK_K = int(os.environ.get("TONY_FLASH_BK", "512"))
 if _BLOCK_Q < 8 or _BLOCK_Q % 8:
