@@ -136,11 +136,13 @@ try:
                     lambda: all(_read_step(_progress(r), start) >= t + 1 for r in range(1, world)),
                     f"the gang to finish step {t + 1}",
                 )
-                mgr.save(t + 1, state, force=True)
+                # the cursor first (a file a step): a rank killed between the
+                # two must not leave a checkpoint whose cursor is missing
                 ConsumptionCursor(
                     global_batch_index=t + 1, global_batch_size=GLOBAL_BATCH,
                     seed=SEED, world_size=world,
                 ).save(ckpt_dir)
+                mgr.save(t + 1, state, force=True)
         _publish(_progress(rank), t + 1)
         if metrics_file:
             # the executor piggybacks this on its heartbeat — the AM's chaos

@@ -519,11 +519,18 @@ def test_the_cells_entries_and_files(bench):
     assert {m["name"] for m in per_layer} == {
         "moe_decode_roofline_pct.serve", "moe_prefill_roofline_pct.serve", "attn_decode_roofline_pct.serve",
         "expert_rows_max_over_mean.serve", "held_share_pct.serve", "launch_s", "slots_active_mean.serve", "host_share_pct.serve",
-        "decode_batch_mean.serve", "visible_share_pct.serve", "decode_step_ms.serve_tput", "chunk_period_ms.serve_tput"}
+        "decode_batch_mean.serve", "visible_share_pct.serve", "decode_step_ms.serve_tput", "chunk_period_ms.serve_tput",
+        # PR 41: the host side of a pass
+        "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput",
+        "write_gap_pct.serve_tput"}
     assert {m["moves"] for m in per_layer} == {"serve_out_tok_s", "setup_s"}
     assert all(spec.metric(m["name"])["moves"] == m["moves"] for m in B["per_layer"])
     new = [m for m in B["per_layer"] if m["workloads"] == [cell]]
-    assert len(new) == 5 and B["per_layer"][-5:] == new                          # put at the end of their list
+    at = B["per_layer"].index(new[0])
+    assert len(new) == 5 and B["per_layer"][at:at + 5] == new                    # put at the end of their list, together,
+    assert [m["name"] for m in B["per_layer"][at + 5:]] == [                     # and PR 41's five after them
+        "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput",
+        "write_gap_pct.serve_tput"]
     w = spec.workload(cell)
     assert (w["engine"]["slots"], w["engine"]["max_len"], w["engine"]["prefill_chunk"], w["engine"]["decode_chunk"]) == (
         256, 6144, 2048, 8)
@@ -550,7 +557,7 @@ def test_the_cells_entries_and_files(bench):
     assert all(set(v) == {"value", "why"} and v["why"] for k, v in cfg["assumed"].items() if isinstance(v, dict))
 
 
-def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
+def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
     """`tiny-exaone-moe.serve` through run.py: the `tony serve` path, the
     router, the replica registered through the family's hook, bucketed prefill
     and decode through pages and rings under the interpreter, and the harness's
@@ -563,3 +570,12 @@ def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] >= 2, proc.stdout[-3000:]
     assert last["device"]["platform"] == "cpu" and "serve_out_tok_s" in last["metrics"]
+    # the host side's account of a pass (docs/observability.md "Where a pass's host time goes"): the window's
+    # two registry snapshots, which the run left behind, give each of its three metrics something to read
+    from readers import registry_delta  # benchmark/ is on the path while `bench` lives
+
+    ctl = os.path.join(ROOT, ".bench_work", TINY + ".serve", "out", "ctl")
+    drive = {tag: json.load(open(os.path.join(ctl, f"snap.{name}.json"))) for tag, name in (("snap0", "open"), ("snap1", "close"))}
+    for name in ("host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput"):
+        value = registry_delta.read({"drive": drive}, **bench["spec"].metric(name)["args"])
+        assert value is not None and value >= 0.0, name

@@ -620,7 +620,10 @@ def test_the_cells_entries_and_files(bench):
     assert {m["name"] for m in per_layer} == {
         "sparse_decode_roofline_pct.serve", "linear_decode_roofline_pct.serve", "linear_prefill_roofline_pct.serve",
         "sparse_prefill_roofline_pct.serve", "visible_share_pct.serve", "decode_step_ms.serve_tput", "chunk_period_ms.serve_tput",
-        "launch_s", "slots_active_mean.serve", "host_share_pct.serve", "decode_batch_mean.serve"}
+        "launch_s", "slots_active_mean.serve", "host_share_pct.serve", "decode_batch_mean.serve",
+        # PR 41: the host side of a pass
+        "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput",
+        "write_gap_pct.serve_tput"}
     # a per-layer metric moves an end-to-end metric its cell reports, and its file says what BENCHMARK.json says
     assert {m["moves"] for m in per_layer} == {"serve_out_tok_s", "setup_s"}
     assert all(spec.metric(m["name"])["moves"] == m["moves"] for m in B["per_layer"])
@@ -640,7 +643,7 @@ def test_the_cells_entries_and_files(bench):
     assert {k: cfg[k] for k in published} == published and cfg["num_hidden_layers"]["source"] == 32
 
 
-def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
+def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
     """`tiny-minicpm-sala.serve` through run.py: the `tony serve` path, the
     router, the replica registered through the family's hook, chunked prefill
     and paged decode under the interpreter, and the harness's own comparison
@@ -653,3 +656,12 @@ def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
     last = json.loads(proc.stdout.strip().splitlines()[-1])
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] >= 2, proc.stdout[-3000:]
     assert last["device"]["platform"] == "cpu" and "serve_out_tok_s" in last["metrics"]
+    # the host side's account of a pass (docs/observability.md "Where a pass's host time goes"): the window's
+    # two registry snapshots, which the run left behind, give each of its three metrics something to read
+    from readers import registry_delta  # benchmark/ is on the path while `bench` lives
+
+    ctl = os.path.join(ROOT, ".bench_work", TINY + ".serve", "out", "ctl")
+    drive = {tag: json.load(open(os.path.join(ctl, f"snap.{name}.json"))) for tag, name in (("snap0", "open"), ("snap1", "close"))}
+    for name in ("host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput"):
+        value = registry_delta.read({"drive": drive}, **bench["spec"].metric(name)["args"])
+        assert value is not None and value >= 0.0, name

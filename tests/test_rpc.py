@@ -69,6 +69,30 @@ class TestRpc:
         c._sock.close()  # simulate a dropped connection
         assert c.call("echo", a=2) == {"a": 2}  # transparent reconnect
 
+    @pytest.mark.parametrize("fault", [KeyboardInterrupt, ValueError])
+    def test_a_call_cut_before_its_answer_is_read_leaves_none_for_the_next_call(self, server, monkeypatch, fault):
+        """`tony serve`, interrupted while its monitor polled the AM, sent the
+        kill on the same connection, read the poll's answer as the kill's and
+        the kill's as task infos, and died with exit 1 after a clean drain
+        (ROADMAP D8). A call that does not read its answer drops the connection."""
+        from tony_tpu.cluster import rpc
+
+        c, recv, cut = client_for(server), rpc._recv_frame, []
+
+        def recv_once_cut(sock):
+            if not cut and sock is c._sock:  # the client's end: the server reads through the same function
+                cut.append(sock)
+                raise fault("between request and response")  # the request is sent, its answer on the way
+            return recv(sock)
+
+        assert c.call("echo", poll=0) == {"poll": 0}  # the persistent connection
+        monkeypatch.setattr(rpc, "_recv_frame", recv_once_cut)
+        with pytest.raises(fault):
+            c.call("echo", poll=1)
+        assert c._sock is None and cut[0].fileno() == -1
+        assert c.call("echo", kill=True) == {"kill": True}  # not {"poll": 1}
+        assert c.call("echo", poll=2) == {"poll": 2}
+
     def test_call_with_retry_eventually_connects(self):
         srv = RpcServer(secret="")
         srv.register("ping", lambda: "pong")

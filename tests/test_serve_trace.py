@@ -1,6 +1,8 @@
 """The serving engine's account of its own time (docs/observability.md "Where
-a request's TTFT goes"): request stages stamped in the engine, engine phases
-on two clocks, and the two benchmark readers that turn them into metrics.
+a request's TTFT goes", "Where a pass's host time goes"): request stages
+stamped in the engine, engine phases on two clocks and their time off the CPU,
+the stream writers' own instruments, and the benchmark readers that turn them
+into metrics.
 
 Tiny config, ``ContinuousBatcher`` and ``EngineServer`` in-process: no fleet,
 no subprocess, no sleep over 0.2 s.
@@ -11,6 +13,9 @@ import os
 import sys
 import threading
 import time
+import types
+import urllib.request
+from http.server import ThreadingHTTPServer
 
 import jax
 import pytest
@@ -27,7 +32,7 @@ BENCH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if BENCH not in sys.path:
     sys.path.append(BENCH)
 
-from readers import gap_by_span, registry_delta  # noqa: E402
+from readers import gap_by_span, gap_under_writes, registry_delta  # noqa: E402
 
 STAGES = ("queue", "prefill", "emit")
 
@@ -147,6 +152,93 @@ def test_phases_tile_the_engine_threads_time(params, monkeypatch):
     assert all(v >= 0 for v in by_phase.values())
     assert sum(by_phase.values()) == pytest.approx(wall[0], rel=0.01)
     assert srv.engine.phase._name is None  # the loop closed its last phase
+
+
+class Recorded:
+    """A counter's place: what was added, by phase, in order."""
+
+    def __init__(self):
+        self.seen = []
+
+    def inc(self, amount=1.0, **labels):
+        self.seen.append((labels["phase"], amount))
+
+
+def test_the_phase_clock_counts_wall_less_cpu_as_time_off_the_cpu_and_never_less_than_nothing(monkeypatch):
+    wall, off = Recorded(), Recorded()
+    monkeypatch.setattr(serving, "_ENGINE_SECONDS", wall)
+    monkeypatch.setattr(serving, "_ENGINE_OFFCPU", off)
+    # (wall, cpu) at each change of phase: dispatch runs 1 s of which 0.25 on the CPU; decode_wait 2 s with none;
+    # emit is all CPU, and the CPU clock's coarser tick reads past the wall clock's: that is no negative wait
+    stamps = iter([(10.0, 5.0), (11.0, 5.25), (13.0, 5.25), (13.5, 5.875), (14.0, 5.875)])
+    now = {}
+
+    def tick():
+        now["wall"], now["cpu"] = next(stamps)
+        return now["wall"]
+
+    clock = serving._PhaseClock(wall=tick, cpu=lambda: now["cpu"])
+    for name in ("dispatch", "decode_wait", "emit", "idle", None):
+        clock.to(name)
+    assert wall.seen == [("dispatch", 1.0), ("decode_wait", 2.0), ("emit", 0.5), ("idle", 0.5)]
+    assert off.seen == [("dispatch", 0.75), ("decode_wait", 2.0), ("emit", 0.0), ("idle", 0.5)]
+    assert sum(v for _, v in wall.seen) == 14.0 - 10.0  # the phases still tile the thread's time
+    assert all(0.0 <= o <= w for (_, w), (_, o) in zip(wall.seen, off.seen)) and clock._name is None
+    # the real clocks: a thread asleep is off the CPU, one spinning is on it
+    monkeypatch.setattr(serving, "_ENGINE_OFFCPU", real := Recorded())
+    clock = serving._PhaseClock()
+    clock.to("idle")
+    time.sleep(0.05)
+    clock.to("emit")
+    t0 = time.perf_counter()
+    while time.perf_counter() - t0 < 0.05:
+        pass
+    clock.to(None)
+    (_, asleep), (_, spinning) = real.seen
+    assert asleep >= 0.04 and spinning <= 0.03
+
+
+def sum_count(histogram):
+    _, children = histogram._snapshot_children()
+    return next(((c["sum"], c["count"]) for key, c in children if key == ()), (0.0, 0))
+
+
+def test_a_streamed_request_leaves_one_write_an_event_and_a_delay_no_shorter_than_the_write(params):
+    """Over HTTP, as a client sees it: every SSE event is one observation of
+    the writer's time and one of the delay since the engine handed it over."""
+    srv = EngineServer(engine(params)).start()
+    handler = type("Handler", (serving_http._Handler,), {"server_ref": srv, "tokenizer": None})
+    httpd = ThreadingHTTPServer(("127.0.0.1", 0), handler)
+    threading.Thread(target=httpd.serve_forever, daemon=True).start()
+    write0, delay0 = sum_count(serving_http._STREAM_WRITE), sum_count(serving_http._FANOUT_DELAY)
+    try:
+        req = urllib.request.Request(f"http://127.0.0.1:{httpd.server_address[1]}/v1/completions", json.dumps(
+            {"prompt_tokens": [4, 5, 6], "max_tokens": 13, "stream": True}).encode(), {"Content-Type": "application/json"})
+        with urllib.request.urlopen(req, timeout=120) as resp:
+            events = [json.loads(line[6:]) for line in resp.read().decode().splitlines() if line.startswith("data: ")]
+    finally:
+        httpd.shutdown()
+        httpd.server_close()
+        assert srv.stop()
+    assert events[-1]["finished"] and len(events[-1]["tokens"]) == 13
+    assert len(events) >= 4 and sum(len(e["tokens"]) for e in events[:-1]) <= 13  # chunks of 4, then the whole answer
+    write1, delay1 = sum_count(serving_http._STREAM_WRITE), sum_count(serving_http._FANOUT_DELAY)
+    assert write1[1] - write0[1] == delay1[1] - delay0[1] == len(events)
+    assert delay1[0] - delay0[0] >= write1[0] - write0[0] > 0.0
+
+
+def test_an_events_stamp_rides_with_it_and_a_later_event_does_not_overwrite_it():
+    stream = RequestStream(4)
+    stream.put(("tokens", [1]))
+    between = time.perf_counter()
+    EngineServer._finish_stream(stream, ("done", [1, 2]))
+    assert stream.get(timeout=1) == ("tokens", [1]) and 0.0 < stream.handed_s <= between
+    assert stream.get(timeout=1) == ("done", [1, 2]) and stream.handed_s >= between
+    # a full queue: the terminal event takes a buffered chunk's place, with its own stamp
+    full = RequestStream(1)
+    full.put(("tokens", [1]))
+    EngineServer._finish_stream(full, ("error", "cancelled"))
+    assert full.get(timeout=1) == ("error", "cancelled") and full.handed_s >= between
 
 
 def admitted(under):
@@ -301,3 +393,137 @@ def test_gap_by_span_lays_the_gaps_under_the_phase_that_covers_them():
     assert got["window_s"] == pytest.approx(12.0) and got["gap_s"] == pytest.approx(by)
     assert gap_by_span.summarise(ops, []) is None and gap_by_span.summarise({}, phases) is None
     assert gap_by_span.read({"trace": None}) is None
+
+
+# phases as the engine's clock writes them, a device busy except for four gaps, and three handler threads whose
+# writes overlap each other, the gaps and the phases
+PHASES = [("dispatch", 4.0, 5.0), ("admit", 0.0, 1.0), ("prefill_wait", 1.0, 3.0), ("admit", 3.0, 4.0),
+          ("decode_wait", 5.0, 9.0), ("emit", 9.0, 10.0)]
+GAPS = [(0.5, 1.5), (3.5, 4.5), (6.0, 6.25), (9.5, 11.0)]
+BUSY = [(0.0, 0.5), (1.5, 3.5), (4.5, 6.0), (6.25, 9.5), (11.0, 12.0)]
+WRITES = [[(0.25, 0.75), (3.75, 4.25), (10.5, 10.75)], [(0.5, 1.25), (6.0, 6.5)], [(0.625, 0.875), (9.0, 9.75)]]
+
+
+def fake_profile(writes):
+    """What `ProfileData.from_file` returns, as far as the readers look: planes, lines, events."""
+    def event(name, s, e):
+        return types.SimpleNamespace(name=name, start_ns=round(s * 1e9), duration_ns=round((e - s) * 1e9))
+
+    def line(name, events):
+        return types.SimpleNamespace(name=name, events=events)
+
+    device = types.SimpleNamespace(name="/device:TPU:0", lines=[
+        line("XLA Ops", [event("%fusion.1 = f32[8]{0} fusion(%p)", s, e) for s, e in BUSY]
+             + [event("%while.2 = (s32[]) while(%t), body=%b", 0.0, 12.0)]),
+        line("XLA Modules", [event("jit_decode_steps", 0.0, 12.0)])])
+    host = types.SimpleNamespace(name="/host:CPU", lines=[
+        line("engine", [event("tony.serve." + n, s, e) for n, s, e in PHASES] + [event("_value", 5.0, 9.0)])]
+        + [line(f"handler-{i}", [event("tony.stream.write", s, e) for s, e in ws] + [event("sendall", 0.0, 12.0)])
+           for i, ws in enumerate(writes)])
+    return types.SimpleNamespace(planes=[device, host])
+
+
+def test_the_writers_annotations_leave_gap_by_span_exactly_as_it_was(monkeypatch):
+    """`gap_by_span` sweeps every `tony.serve.*` event as ONE thread's phases;
+    256 overlapping events under that prefix would corrupt it. The writers'
+    are `tony.stream.write`: with or without them it reads the same."""
+    assert not gap_under_writes.WRITE.startswith(gap_by_span.PREFIX)
+    got = {}
+    for key, writes in (("without", []), ("with", WRITES)):
+        monkeypatch.setattr(jax.profiler.ProfileData, "from_file", staticmethod(lambda path, w=writes: fake_profile(w)))
+        ops, phases = gap_by_span.read_xplane("a.xplane.pb")
+        assert sorted(phases) == sorted(PHASES)
+        got[key] = gap_by_span.summarise(ops, phases)
+    assert got["with"] == got["without"]
+    by = got["with"]["gap_s"]
+    assert by == pytest.approx({"admit": 1.0, "prefill_wait": 0.5, "dispatch": 0.5, "decode_wait": 0.25, "emit": 0.5,
+                                gap_by_span.NONE: 1.0})
+    host_gap_pct = 100.0 * sum(v for k, v in by.items() if k not in (gap_by_span.NONE, "decode_wait", "prefill_wait")) / 12.0
+    assert host_gap_pct == pytest.approx(100.0 * 2.0 / 12.0)
+    # and the writers' reader finds them on every handler thread's line, beside the same phases
+    ops, writes, phases = gap_under_writes.read_xplane("a.xplane.pb")
+    assert sorted(writes) == sorted(w for ws in WRITES for w in ws) and sorted(phases) == sorted(PHASES)
+    assert gap_under_writes.summarise(ops, writes, phases)["gap_s"] == pytest.approx(
+        gap_under_writes.split(GAPS, writes, PHASES))
+
+
+def test_gap_under_writes_splits_the_idle_time_by_who_was_at_work():
+    assert gap_under_writes.intersect([(0.0, 2.0), (3.0, 5.0), (1.0, 2.5)], [(2.25, 3.5), (0.5, 1.0), (4.0, 9.0)]) == [
+        (0.5, 1.0), (2.25, 2.5), (3.0, 3.5), (4.0, 5.0)]
+    writes = [w for ws in WRITES for w in ws]  # their union: 0.25-1.25, 3.75-4.25, 6.0-6.5, 9.0-9.75, 10.5-10.75
+    by = gap_under_writes.split(GAPS, writes, PHASES)
+    # gap 0.5-1.5: admit to 1.0, under a write all the way (both 0.5), then prefill_wait (waiting 0.5, write or no write)
+    # gap 3.5-4.5: admit and dispatch; a write over 3.75-4.25 (both 0.5, host alone 0.5)
+    # gap 6.0-6.25: decode_wait, under a write: the device's own all the same (waiting 0.25)
+    # gap 9.5-11.0: emit to 10.0, a write to 9.75 (both 0.25, host alone 0.25); then no phase: a write over
+    #               10.5-10.75 (writes alone 0.25), the rest under neither (0.75)
+    assert by == pytest.approx({"both": 1.25, "writes": 0.25, "host": 0.75, "neither": 0.75, "waiting": 0.75})
+    assert sum(by.values()) == pytest.approx(sum(e - s for s, e in GAPS))  # the parts are the idle time, once
+    # beside gap_by_span on the same lists: its host phases are `both` + `host`, its waiting phases `waiting`
+    spans = gap_by_span.gap_seconds_by_phase(GAPS, PHASES)
+    assert by["both"] + by["host"] == pytest.approx(spans["admit"] + spans["dispatch"] + spans["emit"])
+    assert by["waiting"] == pytest.approx(spans["prefill_wait"] + spans["decode_wait"])
+    assert by["writes"] + by["neither"] == pytest.approx(spans[gap_by_span.NONE])
+    ops = {"/device:TPU:0": [("%fusion.1 = f32[8]{0} fusion(%p)", s, e) for s, e in BUSY]}
+    got = gap_under_writes.summarise(ops, writes, PHASES)
+    assert got["window_s"] == pytest.approx(12.0) and got["writes"] == 7 and got["write_s"] == pytest.approx(3.0)
+    assert 100.0 * (got["gap_s"]["both"] + got["gap_s"]["writes"]) / got["window_s"] == pytest.approx(12.5)
+    # no writers in the trace (a program from before them), no phase, no device plane, no traced run: nothing
+    assert gap_under_writes.summarise(ops, [], PHASES) is None and gap_under_writes.summarise(ops, writes, []) is None
+    assert gap_under_writes.summarise({}, writes, PHASES) is None and gap_under_writes.read({"trace": None}) is None
+
+
+NEW_METRICS = {  # metric -> (reader, the cells that list it)
+    "host_gap_pct.serve_tput": ("gap_by_span", ["minicpm-sala.serve_longdoc", "k-exaone-236b.serve_reason"]),
+    "host_offcpu_ms.serve_tput": ("registry_delta", ["mistral-7b.serve_batch", "minicpm-sala.serve_longdoc",
+                                                     "k-exaone-236b.serve_reason"]),
+    "stream_write_ms.serve_tput": ("registry_delta", ["mistral-7b.serve_batch", "minicpm-sala.serve_longdoc",
+                                                      "k-exaone-236b.serve_reason"]),
+    "fanout_delay_ms.serve_tput": ("registry_delta", ["mistral-7b.serve_batch", "minicpm-sala.serve_longdoc",
+                                                      "k-exaone-236b.serve_reason"]),
+    "write_gap_pct.serve_tput": ("gap_under_writes", ["minicpm-sala.serve_longdoc", "k-exaone-236b.serve_reason"]),
+}
+
+
+@pytest.mark.parametrize("name", sorted(NEW_METRICS))
+def test_a_new_metric_names_a_reader_that_is_there_and_cells_that_report_what_it_moves(name):
+    reader, cells = NEW_METRICS[name]
+    with open(os.path.join(BENCH, "metrics", name + ".json")) as f:
+        m = json.load(f)
+    with open(os.path.join(os.path.dirname(BENCH), "BENCHMARK.json")) as f:
+        bench = json.load(f)
+    assert (m["reader"], m["layer"], m["moves"], m["kinds"], m["better"]) == (
+        reader, "engine", "serve_out_tok_s", ["serve"], "lower")
+    assert os.path.exists(os.path.join(BENCH, "readers", reader + ".py"))
+    (entry,) = [x for x in bench["per_layer"] if x["name"] == name]
+    assert entry["workloads"] == cells and (entry["unit"], entry["moves"]) == (m["unit"], m["moves"])
+    reports = next(x["workloads"] for x in bench["end_to_end"] if x["name"] == "serve_out_tok_s")
+    assert set(cells) <= set(reports)
+    if reader == "registry_delta":  # it reads an instrument the program registers, and nothing before the window moved it
+        registered = {x["name"] for x in serving_http.obs_metrics.REGISTRY.snapshot()}
+        assert {m["args"]["num"]["name"], m["args"]["den"]["name"]} <= registered
+        assert registry_delta.read({"drive": {"snap0": None, "snap1": None}}, **m["args"]) is None
+
+
+def test_the_three_registry_metrics_read_the_change_of_their_instruments():
+    def snap(offcpu, chunks, write, delay):
+        return {"t": 0.0, "metrics": [
+            {"name": "tony_serve_engine_offcpu_seconds_total", "type": "counter",
+             "samples": [{"labels": {"phase": k}, "value": v} for k, v in offcpu.items()]},
+            {"name": "tony_serve_engine_chunks_total", "type": "counter", "samples": [{"labels": {}, "value": chunks}]},
+            {"name": "tony_serve_stream_write_seconds", "type": "histogram",
+             "samples": [{"labels": {}, "sum": write[0], "count": write[1]}]},
+            {"name": "tony_serve_fanout_delay_seconds", "type": "histogram",
+             "samples": [{"labels": {}, "sum": delay[0], "count": delay[1]}]}]}
+
+    snap0 = snap({"intake": 0.5, "dispatch": 1.0, "admit": 1.0, "emit": 2.0, "decode_wait": 40.0, "idle": 9.0},
+                 100, (1.0, 1000), (3.0, 1000))
+    snap1 = snap({"intake": 0.5, "dispatch": 2.0, "admit": 1.5, "emit": 4.5, "decode_wait": 70.0, "idle": 9.0},
+                 300, (3.0, 5000), (11.0, 5000))
+    ctx = {"drive": {"snap0": snap0, "snap1": snap1}}
+    assert registry_delta.read(ctx, **metric_args("host_offcpu_ms.serve_tput")) == pytest.approx(20.0)  # 4 s over 200 passes
+    assert registry_delta.read(ctx, **metric_args("stream_write_ms.serve_tput")) == pytest.approx(0.5)  # 2 s over 4000 events
+    assert registry_delta.read(ctx, **metric_args("fanout_delay_ms.serve_tput")) == pytest.approx(2.0)
+    old = {"t": 0.0, "metrics": snap0["metrics"][1:2]}  # the parent: chunks, and none of the new instruments
+    for name in ("host_offcpu_ms", "stream_write_ms", "fanout_delay_ms"):
+        assert registry_delta.read({"drive": {"snap0": old, "snap1": old}}, **metric_args(name + ".serve_tput")) is None

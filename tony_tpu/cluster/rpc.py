@@ -272,6 +272,18 @@ class RpcClient:
                         if attempt:
                             raise
                         reconnecting = had_cached
+                    except BaseException:
+                        # a user's interrupt (or a frame that does not parse)
+                        # between request and response leaves the answer
+                        # unread on the persistent connection, where the NEXT
+                        # call would take it for its own: `tony serve`'s
+                        # monitor, interrupted mid-poll, read the kill's
+                        # answer as task infos and died with exit 1 after a
+                        # clean drain (ROADMAP D8)
+                        if self._sock is not None:
+                            self._sock.close()
+                            self._sock = None
+                        raise
                 if not resp.get("ok"):
                     raise RpcError(resp.get("error", "unknown remote error"))
                 result = resp.get("result")

@@ -67,6 +67,12 @@ _ENGINE_SECONDS = obs_metrics.counter(
     "tony_serve_engine_seconds_total",
     "engine-thread seconds by phase of a pass (the phases tile the thread's time)",
     labelnames=("phase",))
+_ENGINE_OFFCPU = obs_metrics.counter(
+    "tony_serve_engine_offcpu_seconds_total",
+    "engine-thread seconds by phase in which the thread was on no CPU (a phase's wall time less its thread CPU "
+    "time): the device in decode_wait and prefill_wait; in the other phases the interpreter held by another "
+    "thread, a lock, or a blocking call into the runtime",
+    labelnames=("phase",))
 _CHUNKS = obs_metrics.counter(
     "tony_serve_engine_chunks_total", "decode chunks dispatched")
 _DECODE_SLOTS = obs_metrics.counter(
@@ -115,17 +121,22 @@ class _PhaseClock:
     its seconds in ``tony_serve_engine_seconds_total{phase}``, and a
     ``tony.serve.<phase>`` annotation in the profiler's trace (a flag check
     unless a capture runs), on the engine thread's line beside the device's
-    operations. ``to(None)`` closes without opening."""
+    operations. Beside the wall clock it reads the thread's CPU clock, and
+    what a phase's wall time has over its CPU time goes to
+    ``tony_serve_engine_offcpu_seconds_total{phase}``: the time the thread
+    waited, whatever for. ``to(None)`` closes without opening."""
 
-    def __init__(self):
-        self._name, self._t0, self._ann = None, 0.0, None
+    def __init__(self, wall=time.perf_counter, cpu=time.thread_time):
+        self._wall, self._cpu = wall, cpu
+        self._name, self._t0, self._cpu0, self._ann = None, 0.0, 0.0, None
 
     def to(self, name: str | None) -> None:
-        now = time.perf_counter()
+        now, cpu = self._wall(), self._cpu()
         if self._name is not None:
             _ENGINE_SECONDS.inc(now - self._t0, phase=self._name)
+            _ENGINE_OFFCPU.inc(max(0.0, now - self._t0 - (cpu - self._cpu0)), phase=self._name)
             self._ann.__exit__(None, None, None)
-        self._name, self._t0 = name, now
+        self._name, self._t0, self._cpu0 = name, now, cpu
         if name is not None:
             self._ann = jax.profiler.TraceAnnotation(_ANNOTATION[name])
             self._ann.__enter__()

@@ -95,6 +95,23 @@ _HANDOFF_LATENCY = obs_metrics.histogram(
     "tony_serve_kv_handoff_seconds",
     "disaggregated handoff wall time on the prefill replica: prompt done → "
     "pages exported, shipped, and acked by the decode replica")
+# The stream writers' account (docs/observability.md "Where a pass's host time
+# goes"): one observation of each an SSE event, on the handler thread that
+# wrote it, both under one lock.
+# Their annotation in a profiler capture is NOT under ``tony.serve.``: those are
+# the engine thread's phases, which tile one thread's time, and the writers'
+# overlap across as many threads as there are streams.
+_WRITE_ANNOTATION = "tony.stream.write"  # lint: disable=config-keys — an annotation's name, not a config key
+# an event written alone takes about 50 us
+_EVENT_BUCKETS = (5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
+_STREAM_WRITE = obs_metrics.histogram(
+    "tony_serve_stream_write_seconds",
+    "a stream writer's wall time for one SSE event: encode, write, flush", buckets=_EVENT_BUCKETS)
+_FANOUT_DELAY = obs_metrics.histogram(
+    "tony_serve_fanout_delay_seconds",
+    "from the engine thread's hand-over of a stream event to the return of its flush: how long a chunk's "
+    "tokens lie between the engine and the socket (the writer's wake-up, its turn, the write)",
+    buckets=_EVENT_BUCKETS, lock_of=_STREAM_WRITE)
 
 
 class RequestStream:
@@ -103,7 +120,7 @@ class RequestStream:
     the client-disconnect/deadline path: the engine thread picks the flag
     up within one decode chunk and frees the slot/pages."""
 
-    __slots__ = ("q", "cancelled", "submitted_s", "last_fanout_s",
+    __slots__ = ("q", "cancelled", "submitted_s", "last_fanout_s", "handed_s",
                  "request_id", "span", "stage", "defer_finish", "req")
 
     def __init__(self, maxsize: int = 0, request_id: str = ""):
@@ -114,6 +131,11 @@ class RequestStream:
         # client actually experiences
         self.submitted_s = time.time()
         self.last_fanout_s = 0.0
+        #: when the event ``get`` last returned was handed over
+        #: (``time.perf_counter``). The stamp rides in the queue with its
+        #: event, so a later chunk cannot overwrite it; only the consumer's
+        #: thread reads or writes this field
+        self.handed_s = 0.0
         #: router-propagated id (X-Tony-Request-Id) — exemplar + span key
         self.request_id = request_id
         #: disagg handoff: True → on "done" the engine opens a serve.handoff
@@ -131,10 +153,11 @@ class RequestStream:
         self.req = None
 
     def get(self, timeout: float | None = None):
-        return self.q.get(timeout=timeout)
+        kind, payload, self.handed_s = self.q.get(timeout=timeout)
+        return kind, payload
 
     def put(self, item) -> None:
-        self.q.put(item)
+        self.q.put((*item, time.perf_counter()))
 
     def cancel(self) -> None:
         self.cancelled.set()
@@ -407,6 +430,7 @@ class EngineServer:
         if the stream's bounded queue is full (slow consumer), evict one
         buffered chunk to make room — the handler always sees an end-of-
         stream event instead of blocking forever on a silently-dead queue."""
+        event = (*event, time.perf_counter())  # the hand-over's stamp (RequestStream.handed_s)
         try:
             stream.q.put_nowait(event)
         except queue.Full:
@@ -536,7 +560,7 @@ class EngineServer:
                     self._deadlines.pop(rid, None)
                 else:
                     try:
-                        out.q.put_nowait(("tokens", toks))
+                        out.q.put_nowait(("tokens", toks, time.perf_counter()))
                     except queue.Full:
                         # dead-slow consumer: cap host memory by treating it
                         # as a disconnect (picked up by the next sweep)
@@ -773,8 +797,12 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
 
         def emit(obj: Any) -> None:
-            self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
-            self.wfile.flush()
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(_WRITE_ANNOTATION):
+                self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
+                self.wfile.flush()
+            t1 = time.perf_counter()
+            obs_metrics.observe_pair(_STREAM_WRITE, t1 - t0, _FANOUT_DELAY, t1 - out.handed_s)
 
         delivered = 0
         kind, payload = first_kind, first_payload

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import math
 import threading
+from bisect import bisect_left
 from typing import Any, Iterable, Mapping, Sequence
 
 _INF = float("inf")
@@ -130,12 +131,14 @@ class Histogram(_Metric):
     kind = "histogram"
 
     def __init__(self, name: str, help_: str, labelnames: Sequence[str] = (),
-                 buckets: Sequence[float] = DEFAULT_BUCKETS):
+                 buckets: Sequence[float] = DEFAULT_BUCKETS, lock_of: "Histogram | None" = None):
         super().__init__(name, help_, labelnames)
         bs = sorted(float(b) for b in buckets)
         if not bs or any(not math.isfinite(b) for b in bs):
             raise ValueError(f"{name}: buckets must be finite and non-empty")
         self.buckets = tuple(bs)
+        if lock_of is not None:  # two timings of one event: see observe_pair
+            self._lock = lock_of._lock
 
     def ensure_bucket(self, bound: float) -> None:
         """Insert a bucket boundary (idempotent) — e.g. the configured SLO
@@ -161,28 +164,26 @@ class Histogram(_Metric):
             return
         key = self._key(labels)
         with self._lock:
-            child = self._children.get(key)
-            if child is None:
-                # [per-bucket counts..., overflow], sum, count
-                child = self._children[key] = {
-                    "counts": [0] * (len(self.buckets) + 1), "sum": 0.0, "count": 0,
-                    "exemplars": [],
-                }
-            for i, ub in enumerate(self.buckets):
-                if value <= ub:
-                    child["counts"][i] += 1
-                    break
-            else:
-                child["counts"][-1] += 1
-            child["sum"] += value
-            child["count"] += 1
-            if exemplar is not None:
-                # worst-K by value: lets an operator jump from a burning
-                # latency SLO straight to the offending request ids
-                ex = child["exemplars"]
-                ex.append((float(value), str(exemplar)))
-                ex.sort(key=lambda t: -t[0])
-                del ex[EXEMPLAR_K:]
+            self._observe_locked(key, value, exemplar)
+
+    def _observe_locked(self, key: tuple[str, ...], value: float, exemplar: Any = None) -> None:
+        child = self._children.get(key)
+        if child is None:
+            # [per-bucket counts..., overflow], sum, count
+            child = self._children[key] = {
+                "counts": [0] * (len(self.buckets) + 1), "sum": 0.0, "count": 0,
+                "exemplars": [],
+            }
+        child["counts"][bisect_left(self.buckets, value)] += 1  # the first bound not under it, else the overflow
+        child["sum"] += value
+        child["count"] += 1
+        if exemplar is not None:
+            # worst-K by value: lets an operator jump from a burning
+            # latency SLO straight to the offending request ids
+            ex = child["exemplars"]
+            ex.append((float(value), str(exemplar)))
+            ex.sort(key=lambda t: -t[0])
+            del ex[EXEMPLAR_K:]
 
     def _snapshot_children(self) -> "tuple[list[float], list[tuple[tuple[str, ...], dict]]]":
         # buckets + children under ONE lock: ensure_bucket resizes counts in
@@ -222,8 +223,8 @@ class MetricsRegistry:
         return self._register(Gauge, name, help_, labelnames)
 
     def histogram(self, name: str, help_: str = "", labelnames: Sequence[str] = (),
-                  buckets: Sequence[float] = DEFAULT_BUCKETS) -> Histogram:
-        return self._register(Histogram, name, help_, labelnames, buckets=buckets)
+                  buckets: Sequence[float] = DEFAULT_BUCKETS, lock_of: Histogram | None = None) -> Histogram:
+        return self._register(Histogram, name, help_, labelnames, buckets=buckets, lock_of=lock_of)
 
     def reset(self) -> None:
         """Drop all recorded values AND registrations (tests only)."""
@@ -278,8 +279,23 @@ def gauge(name: str, help_: str = "", labelnames: Sequence[str] = ()) -> Gauge:
 
 
 def histogram(name: str, help_: str = "", labelnames: Sequence[str] = (),
-              buckets: Sequence[float] = DEFAULT_BUCKETS) -> Histogram:
-    return REGISTRY.histogram(name, help_, labelnames, buckets=buckets)
+              buckets: Sequence[float] = DEFAULT_BUCKETS, lock_of: Histogram | None = None) -> Histogram:
+    return REGISTRY.histogram(name, help_, labelnames, buckets=buckets, lock_of=lock_of)
+
+
+def observe_pair(first: Histogram, a: float, second: Histogram, b: float) -> None:
+    """One unlabelled observation in each of two histograms under ONE lock
+    acquisition, for a hot path that takes two timings of one event (a stream
+    writer's own time and the event's delay). ``second`` was made with
+    ``lock_of=first``, so each histogram's own ``observe`` and snapshot hold
+    the same lock."""
+    if not _enabled:
+        return
+    if second._lock is not first._lock:
+        raise ValueError(f"{second.name} does not share {first.name}'s lock (lock_of)")
+    with first._lock:
+        first._observe_locked((), a)
+        second._observe_locked((), b)
 
 
 # ------------------------------------------------------- Prometheus text
