@@ -9,6 +9,7 @@ and kill drains gracefully.
 
 import json
 import os
+import socket
 import sys
 import threading
 import time
@@ -36,14 +37,21 @@ def tiny_engine(**kw):
     return ContinuousBatcher(params, LLAMA_TINY, **defaults)
 
 
-def http_server(srv):
+def http_server(srv, sndbuf=None):
     """A bare ThreadingHTTPServer around an EngineServer — the HTTP layer
-    without the tony job spine (for handler-level tests)."""
+    without the tony job spine (for handler-level tests). ``sndbuf``: shrink
+    every connection's send buffer, so that a client that stops reading
+    fills it within a few KB."""
     from http.server import ThreadingHTTPServer
 
     from tony_tpu.models.serving_http import _Handler
 
-    handler = type("Handler", (_Handler,), {"server_ref": srv, "tokenizer": None})
+    def setup(self):
+        if sndbuf:
+            self.request.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, sndbuf)
+        _Handler.setup(self)
+
+    handler = type("Handler", (_Handler,), {"server_ref": srv, "tokenizer": None, "setup": setup})
     httpd = ThreadingHTTPServer(("127.0.0.1", 0), handler)
     threading.Thread(target=httpd.serve_forever, daemon=True).start()
     return httpd, f"http://127.0.0.1:{httpd.server_address[1]}"
@@ -357,6 +365,256 @@ class TestEngineServerDrain:
             if proc.poll() is None:
                 proc.kill()
                 proc.wait(timeout=10)
+
+
+# ---------------------------------------------------------------------------
+# The stream writer: one hand-over a pass, one thread on every SSE socket
+# ---------------------------------------------------------------------------
+def open_stream(port, obj, rcvbuf=None, timeout=90):
+    """POST a streamed completion on a raw socket: what the client reads back
+    is the server's bytes, nothing parsed or buffered in between. ``rcvbuf``:
+    a receive buffer so small that a client that does not read stalls the
+    server's sends after a few KB."""
+    sock = socket.socket()
+    if rcvbuf:
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    sock.settimeout(timeout)
+    sock.connect(("127.0.0.1", port))
+    body = json.dumps({**obj, "stream": True}).encode()
+    sock.sendall(b"POST /v1/completions HTTP/1.1\r\nHost: x\r\nContent-Type: application/json\r\n"
+                 + f"Content-Length: {len(body)}\r\n\r\n".encode() + body)
+    return sock
+
+
+def read_to_end(sock, raw=b""):
+    """(status line, body) of a response the server ends by closing (``raw``:
+    what was read of it already)."""
+    while chunk := sock.recv(65536):
+        raw += chunk
+    sock.close()
+    head, _, body = raw.partition(b"\r\n\r\n")
+    return head.split(b"\r\n")[0], body
+
+
+def sse_events(body):
+    assert body.endswith(b"\n\n")
+    frames = body[:-2].split(b"\n\n")
+    assert all(f.startswith(b"data: ") for f in frames)
+    return [json.loads(f[6:]) for f in frames]
+
+
+def wait_for(cond, seconds=60):
+    deadline = time.time() + seconds
+    while not cond():
+        assert time.time() < deadline, "condition not met in time"
+        time.sleep(0.01)
+
+
+def counter_value(name):
+    from tony_tpu.obs import metrics as obs_metrics
+
+    for m in obs_metrics.REGISTRY.snapshot():
+        if m["name"] == name:
+            return sum(x.get("value", x.get("count", 0)) for x in m["samples"])
+    raise AssertionError(f"{name} is not registered")
+
+
+class TestStreamWriter:
+    def streamed_bodies(self, n, max_tokens):
+        """``n`` identical greedy streamed requests, all in the inbox before
+        the engine's first pass (so all are admitted in it and decode in
+        lockstep): each one's body, and the counters' change."""
+        srv = EngineServer(tiny_engine(num_slots=4))
+        httpd, url = http_server(srv)
+        before = [counter_value("tony_serve_stream_handovers_total"),
+                  counter_value("tony_serve_stream_write_seconds")]
+        try:
+            socks = [open_stream(httpd.server_address[1], {"prompt_tokens": [4, 5, 6], "max_tokens": max_tokens})
+                     for _ in range(n)]
+            wait_for(lambda: srv._inbox.qsize() == n)
+            srv.start()
+            got = [read_to_end(sock) for sock in socks]
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+            assert srv.stop()
+        assert all(status == b"HTTP/1.0 200 OK" for status, _ in got)
+        assert srv.stats()["stream_handovers"] == counter_value("tony_serve_stream_handovers_total") - before[0]
+        return [body for _, body in got], srv.stats()["stream_handovers"], (
+            counter_value("tony_serve_stream_write_seconds") - before[1])
+
+    def test_concurrent_streams_read_a_lone_streams_bytes_and_a_pass_is_one_handover(self):
+        (alone,), handovers_alone, writes_alone = self.streamed_bodies(1, 13)
+        events = sse_events(alone)
+        # one event a chunk in the engine's order (the first token, then chunks of 4), then the whole answer
+        assert [len(e["tokens"]) for e in events] == [1, 4, 4, 13] and events[-1]["finished"] is True
+        assert [list(e) for e in events[:-1]] == [["tokens"]] * 3
+        assert sum((e["tokens"] for e in events[:-1]), []) == events[-1]["tokens"][:9]
+        assert alone == b"".join(b"data: " + json.dumps(e).encode() + b"\n\n" for e in events)
+        # the first event goes through the request's own queue, every later one through a hand-over
+        assert (handovers_alone, writes_alone) == (3, 4)
+        bodies, handovers, writes = self.streamed_bodies(4, 13)
+        assert bodies == [alone] * 4  # byte for byte
+        assert (handovers, writes) == (3, 16)  # one a pass, not one a stream
+
+    def test_a_client_that_stops_reading_is_cancelled_at_the_bound_and_delays_nobody(self, monkeypatch):
+        """Over HTTP: one client never reads, with buffers so small that the
+        server's sends soon stop being taken; a second one reads its whole
+        answer meanwhile. The stalled one is cancelled when ``STREAM_QUEUE_CHUNKS``
+        events wait for its socket, and its slot frees."""
+        monkeypatch.setattr(EngineServer, "STREAM_QUEUE_CHUNKS", 8)
+        deferred0 = counter_value("tony_serve_stream_writes_deferred_total")
+        srv = EngineServer(tiny_engine(num_slots=2, max_len=1024)).start()
+        httpd, url = http_server(srv, sndbuf=1)
+        port = httpd.server_address[1]
+        try:
+            stalled = open_stream(port, {"prompt_tokens": [1, 2, 3], "max_tokens": 1000}, rcvbuf=1)
+            wait_for(lambda: srv.stats()["slots_active"] == 1)
+            status, body = read_to_end(open_stream(port, {"prompt_tokens": [7, 8], "max_tokens": 200}))
+            events = sse_events(body)
+            assert status == b"HTTP/1.0 200 OK" and events[-1]["finished"] and len(events[-1]["tokens"]) == 200
+            wait_for(lambda: srv.stats()["requests_cancelled"] == 1 and not srv.engine.running, 120)
+            st = srv.stats()
+            assert st["tokens_out"] < 1200, st  # far short of the stalled request's 1000 tokens
+            assert st["stream_writes_deferred"] >= 1
+            assert counter_value("tony_serve_stream_writes_deferred_total") - deferred0 == st["stream_writes_deferred"]
+            # its connection is closed on it: what it reads now is what the socket had taken, and the end
+            status, body = read_to_end(stalled)
+            assert status == b"HTTP/1.0 200 OK" and b'"finished"' not in body
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+            assert srv.stop()
+
+    def test_a_stalled_socket_holds_up_no_other_stream_for_even_one_handover(self):
+        """The writer alone, on socket pairs, one step at a time: a peer that
+        never reads takes part of a large event and no more; every event of the
+        other stream is on its socket before the next list is handed, and the
+        stalled stream ends when ``bound`` events wait for it, not before."""
+        from tony_tpu.models.serving_http import RequestStream, StreamWriter, _Response
+
+        delivered = []
+        writer = StreamWriter(6, delivered.append)
+        writer.start()
+        pairs = [socket.socketpair() for _ in range(2)]
+        for ours, peer in pairs:
+            ours.setsockopt(socket.SOL_SOCKET, socket.SO_SNDBUF, 1)
+            peer.settimeout(30)
+        (slow_sock, _), (fast_sock, fast_peer) = pairs
+        slow, fast = _Response(RequestStream()), _Response(RequestStream())
+        big = list(range(20000))  # some 130 KB as an event: more than the pair's buffers hold
+        for resp, sock, first in ((slow, slow_sock, big), (fast, fast_sock, [0])):
+            writer.open(resp)
+            writer.attach(resp, sock, ("tokens", first, time.perf_counter()))
+        buf = b""
+
+        def fast_reads(event):
+            nonlocal buf
+            want = b"data: " + json.dumps(event).encode() + b"\n\n"
+            while len(buf) < len(want):
+                buf += fast_peer.recv(65536)
+            assert buf[:len(want)] == want
+            buf = buf[len(want):]
+
+        fast_reads({"tokens": [0]})
+        for i in range(1, 5):  # the stalled stream holds its first event and these four: 5 of a bound of 6
+            writer.hand([(slow, "tokens", [i], time.perf_counter()), (fast, "tokens", [i], time.perf_counter())])
+            fast_reads({"tokens": [i]})
+            assert not slow.ended.is_set() and not slow.out.cancelled.is_set()
+        assert writer.deferred >= 1 and writer.open_count() == 2
+        writer.hand([(slow, "tokens", [5], time.perf_counter()), (fast, "done", [0, 1, 2, 3, 4, 5], time.perf_counter())])
+        fast_reads({"finished": True, "tokens": [0, 1, 2, 3, 4, 5]})
+        assert slow.ended.wait(30) and slow.out.cancelled.is_set()  # like a disconnect
+        assert fast.ended.wait(30) and not fast.out.cancelled.is_set()
+        assert delivered == [1, 1, 1, 1, 1, 1]  # the fast stream's tokens: five events and the answer's remainder
+        writer.close()
+        assert writer.flushed.wait(30) and writer.open_count() == 0
+        for ours, peer in pairs:
+            ours.close()
+            peer.close()
+
+    def test_an_error_before_the_first_event_is_a_plain_reply_with_its_status(self):
+        """A streamed request that fails before its first byte gets what any
+        request gets: 400, 504, 429, 503, a JSON body, no SSE."""
+        srv = EngineServer(tiny_engine(max_len=16)).start()
+        httpd, url = http_server(srv)
+        port = httpd.server_address[1]
+
+        def answer(obj):
+            status, body = read_to_end(open_stream(port, obj))
+            return int(status.split()[1]), json.loads(body)["error"]
+
+        try:
+            code, err = answer({"prompt_tokens": [1] * 20, "max_tokens": 10})  # longer than max_len
+            assert code == 400 and "max_len" in err
+            code, err = answer({"prompt_tokens": [1, 2], "max_tokens": 4, "timeout_s": 1e-6})
+            assert code == 504 and "deadline" in err
+            # and a stream on the same server still works afterwards
+            status, body = read_to_end(open_stream(port, {"prompt_tokens": [1, 2], "max_tokens": 3}))
+            assert sse_events(body)[-1]["finished"]
+            assert srv.stop()
+            code, err = answer({"prompt_tokens": [1, 2], "max_tokens": 4})
+            assert code == 503 and "draining" in err
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+        full = EngineServer(tiny_engine(), max_queue=1)  # loop NOT started: the inbox stays full
+        full.submit([1, 2], max_tokens=2)
+        httpd, url = http_server(full)
+        try:
+            status, body = read_to_end(open_stream(httpd.server_address[1], {"prompt_tokens": [5], "max_tokens": 1}))
+            assert status == b"HTTP/1.0 429 Too Many Requests" and "overloaded" in json.loads(body)["error"]
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+
+    def test_an_engine_failure_mid_stream_ends_every_open_stream_with_an_error_event(self):
+        srv = EngineServer(tiny_engine(num_slots=2, max_len=512))
+        fired = threading.Event()
+        srv._on_fatal = fired.set
+        srv.start()
+        httpd, url = http_server(srv)
+        try:
+            socks = [open_stream(httpd.server_address[1], {"prompt_tokens": [1 + i, 2], "max_tokens": 400})
+                     for i in range(2)]
+            heads = [sock.recv(4096) for sock in socks]  # headers (and first bytes): both are streaming
+            assert all(h.startswith(b"HTTP/1.0 200 OK") for h in heads)
+            srv.engine.step = lambda: (_ for _ in ()).throw(RuntimeError("device lost"))
+            for head, sock in zip(heads, socks):
+                events = sse_events(read_to_end(sock, head)[1])
+                assert "device lost" in events[-1]["error"] and all(list(e) == ["tokens"] for e in events[:-1])
+            assert fired.wait(10) and srv.error is not None
+            assert srv.stop()  # the loop is over and the writer has nothing left
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
+
+    def test_the_drain_waits_for_a_terminal_event_still_with_the_writer(self):
+        """A client that reads nothing until its request is finished: the
+        answer's last events cannot all be on its socket, so the drain is not
+        over when the engine is; it is once the client has read them."""
+        srv = EngineServer(tiny_engine(num_slots=1, max_len=1024)).start()
+        httpd, url = http_server(srv, sndbuf=1)
+        try:
+            sock = open_stream(httpd.server_address[1], {"prompt_tokens": [1, 2, 3], "max_tokens": 900}, rcvbuf=1)
+            wait_for(lambda: srv.stats()["requests_done"] == 1, 150)
+            done = {}
+            stopper = threading.Thread(target=lambda: done.update(clean=srv.stop(timeout_s=120)), daemon=True)
+            stopper.start()
+            assert srv._stopped.wait(30)  # the engine's part of the drain is over ...
+            stopper.join(0.3)
+            assert stopper.is_alive() and srv.writer.open_count() == 1  # ... the writer's is not
+            assert srv.stats()["stream_writes_deferred"] >= 1 and srv.stats()["requests_cancelled"] == 0
+            status, body = read_to_end(sock)
+            events = sse_events(body)
+            assert events[-1]["finished"] and len(events[-1]["tokens"]) == 900
+            chunks = sum((e["tokens"] for e in events[:-1]), [])
+            assert chunks == events[-1]["tokens"][:len(chunks)] and len(chunks) >= 896  # nothing dropped
+            stopper.join(60)
+            assert done.get("clean") is True and srv.writer.open_count() == 0
+        finally:
+            httpd.shutdown()
+            httpd.server_close()
 
 
 class TestServingInstruments:

@@ -30,19 +30,37 @@ long-running, AM-supervised task that:
   clean inside the pool's deadline — serving survives preemption as
   gracefully as training does.
 
-Threading model: HTTP handler threads only ever touch thread-safe queues;
-ONE engine thread owns the batcher (submit → step → drain_stream), so the
-engine itself needs no locks — the same host/device split the engine's
-docstring promises stays intact.
+Threading model: three kinds of thread, each with one job.
+
+- HTTP handler threads (``ThreadingHTTPServer``, one a connection) parse the
+  request, ``submit`` it and park. One that does not stream waits on its
+  request's queue and writes the one reply. One that streams waits there for
+  the FIRST event only (an error before the first byte is still a plain
+  429 / 504 / 503 / 400), sends the headers, hands its connection to the
+  stream writer and sleeps until the writer says the stream has ended: it
+  wakes once a request, not once a chunk.
+- ONE engine thread owns the batcher (submit → step → drain_stream), so the
+  engine itself needs no locks — the same host/device split the engine's
+  docstring promises stays intact. It ends a pass by handing the stream
+  writer ONE list of that pass's stream events, each stamped where it was
+  appended: one ``put`` and one wake-up, whatever the number of live streams.
+- ONE stream-writer thread (:class:`StreamWriter`) owns every streaming
+  response's socket from the headers on. It encodes each event to its SSE
+  bytes and sends it without blocking; what a socket will not take stays as
+  that stream's pending bytes while the writer goes on to the next stream, so
+  one stalled reader holds up nobody else. The drain waits for it.
 """
 
 from __future__ import annotations
 
 import argparse
+import collections
 import json
 import os
 import queue
+import selectors
 import signal
+import socket
 import sys
 import threading
 import time
@@ -95,23 +113,34 @@ _HANDOFF_LATENCY = obs_metrics.histogram(
     "tony_serve_kv_handoff_seconds",
     "disaggregated handoff wall time on the prefill replica: prompt done → "
     "pages exported, shipped, and acked by the decode replica")
-# The stream writers' account (docs/observability.md "Where a pass's host time
-# goes"): one observation of each an SSE event, on the handler thread that
-# wrote it, both under one lock.
-# Their annotation in a profiler capture is NOT under ``tony.serve.``: those are
-# the engine thread's phases, which tile one thread's time, and the writers'
-# overlap across as many threads as there are streams.
+# The stream writer's account (docs/observability.md "Where a pass's host time
+# goes"): one observation of each an SSE event, on the ONE writer thread that
+# sends every stream's events (StreamWriter._send), both under one lock (a
+# snapshot on another thread reads them).
+# Its annotation in a profiler capture is NOT under ``tony.serve.``: those are
+# the engine thread's phases, which tile that thread's time, and the writer's
+# events lie on another thread's line, over and between them.
 _WRITE_ANNOTATION = "tony.stream.write"  # lint: disable=config-keys — an annotation's name, not a config key
 # an event written alone takes about 50 us
 _EVENT_BUCKETS = (5e-5, 1e-4, 2.5e-4, 5e-4, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 1.0)
 _STREAM_WRITE = obs_metrics.histogram(
     "tony_serve_stream_write_seconds",
-    "a stream writer's wall time for one SSE event: encode, write, flush", buckets=_EVENT_BUCKETS)
+    "the stream writer's wall time for one SSE event: encode and send (of an event its socket did not take "
+    "whole at once, the attempt that sent its last byte)", buckets=_EVENT_BUCKETS)
 _FANOUT_DELAY = obs_metrics.histogram(
     "tony_serve_fanout_delay_seconds",
-    "from the engine thread's hand-over of a stream event to the return of its flush: how long a chunk's "
-    "tokens lie between the engine and the socket (the writer's wake-up, its turn, the write)",
+    "from the engine thread's stamp on a stream event (where it joins the pass's hand-over) to the return of "
+    "the send that put its last byte on the socket: how long a chunk's tokens lie between the engine and the "
+    "socket (the rest of the fan-out, the writer's wake-up, its place in the pass's line, the send)",
     buckets=_EVENT_BUCKETS, lock_of=_STREAM_WRITE)
+_HANDOVERS = obs_metrics.counter(
+    "tony_serve_stream_handovers_total",
+    "lists of stream events the engine thread handed the stream writer: one a pass that had any, whatever the "
+    "number of live streams (tony_serve_stream_write_seconds' count over this: the events a hand-over)")
+_DEFERRED = obs_metrics.counter(
+    "tony_serve_stream_writes_deferred_total",
+    "sends a streaming client's socket did not take whole at once: the rest waits as that stream's pending "
+    "bytes while the writer goes on to the next stream")
 
 
 class RequestStream:
@@ -121,7 +150,7 @@ class RequestStream:
     up within one decode chunk and frees the slot/pages."""
 
     __slots__ = ("q", "cancelled", "submitted_s", "last_fanout_s", "handed_s",
-                 "request_id", "span", "stage", "defer_finish", "req")
+                 "request_id", "span", "stage", "defer_finish", "req", "response", "started")
 
     def __init__(self, maxsize: int = 0, request_id: str = ""):
         self.q: queue.Queue = queue.Queue(maxsize)
@@ -151,6 +180,12 @@ class RequestStream:
         #: the engine's record of this request (its stage stamps), set by the
         #: engine thread once ``engine.submit`` accepted it
         self.req = None
+        #: a STREAMED request's record with the stream writer (``submit`` makes
+        #: it). Only its first event comes through ``q``; the engine thread
+        #: hands every later one to the writer (``EngineServer._deliver``)
+        self.response: _Response | None = None
+        #: the engine thread has delivered an event (engine thread only)
+        self.started = False
 
     def get(self, timeout: float | None = None):
         kind, payload, self.handed_s = self.q.get(timeout=timeout)
@@ -210,6 +245,203 @@ class RequestStream:
             self.span = self.stage = None
 
 
+def _sse(kind: str, payload: Any) -> bytes:
+    """One engine event as the one SSE event a streaming client reads."""
+    if kind == "tokens":
+        obj = {"tokens": payload}
+    elif kind == "done":
+        obj = {"finished": True, "tokens": list(payload)}
+    else:
+        obj = {"error": payload}
+    return b"data: " + json.dumps(obj).encode() + b"\n\n"
+
+
+class _Response:
+    """The stream writer's record of one streaming response. The handler
+    thread parks on ``ended``; every other field is the writer thread's."""
+
+    __slots__ = ("out", "sock", "unsent", "rest", "delivered", "waiting", "ended")
+
+    def __init__(self, out: RequestStream):
+        self.out = out
+        self.sock: socket.socket | None = None  # the client's, once its handler attached it
+        #: (kind, payload, stamp) not yet whole on the socket, in the engine's order
+        self.unsent: collections.deque = collections.deque()
+        self.rest: bytes | None = None  # what the socket has not taken of ``unsent[0]``
+        self.delivered = 0  # tokens its "tokens" events carried to the socket
+        self.waiting = False  # with the selector, until the socket takes bytes again
+        self.ended = threading.Event()
+
+
+class StreamWriter:
+    """The ONE thread that writes every streaming response (module docstring,
+    "Threading model").
+
+    Other threads only enqueue a call for it (``open``, ``attach``, ``drop``,
+    ``hand``, ``close``) and wake it; it runs them in the order they came, so
+    no event passes another of its stream: a response is ``open`` from the
+    moment its request is admitted, its handler ``attach``es the connection
+    with the first event (or ``drop``s it: an error reply, a failed header),
+    and what the engine ``hand``ed before that waits behind the first event.
+
+    It never waits on one client. Sockets are non-blocking; what a send leaves
+    over is that stream's pending bytes, the selector says when the socket
+    takes more, and meanwhile the stream's later events queue behind them. A
+    stream with ``bound`` events not yet on its socket is cancelled like a
+    disconnect (the slow-consumer bound, ``EngineServer.STREAM_QUEUE_CHUNKS``),
+    and so is one whose send fails: the engine frees the slot within a chunk.
+
+    After ``close`` it runs until every open response has ended (its terminal
+    event written, or failed), then sets ``flushed`` and exits."""
+
+    def __init__(self, bound: int, delivered):
+        self._bound = bound
+        self._delivered = delivered  # tokens written to a socket -> the server's count
+        self._calls: collections.deque = collections.deque()
+        self._open: set[_Response] = set()
+        self._closing = False
+        self._sel: selectors.BaseSelector | None = None
+        self._wake_r = self._wake_w = None
+        self.deferred = 0  # sends not taken whole (/stats; writer thread only)
+        self.flushed = threading.Event()
+        self._thread = threading.Thread(target=self._run, name="stream-writer", daemon=True)
+
+    def start(self) -> None:
+        self._sel = selectors.DefaultSelector()
+        self._wake_r, self._wake_w = socket.socketpair()
+        self._wake_r.setblocking(False)
+        self._wake_w.setblocking(False)
+        self._sel.register(self._wake_r, selectors.EVENT_READ)
+        self._thread.start()
+
+    # ------------------------------------------------- any thread: enqueue
+    def _call(self, fn, *args, wake: bool = True) -> None:
+        self._calls.append((fn, args))
+        if wake:
+            try:
+                self._wake_w.send(b"\0")
+            except OSError:
+                pass  # full: a wake-up is already on its way; closed: the writer has exited
+
+    def open(self, resp: _Response) -> None:
+        """A streamed request was admitted: the drain waits for its response
+        from here on. Nothing to do yet, so nobody is woken."""
+        self._call(self._open.add, resp, wake=False)
+
+    def attach(self, resp: _Response, sock: socket.socket, first: tuple) -> None:
+        """The headers are out: the connection is the writer's from here, and
+        ``first`` (kind, payload, stamp) goes out before anything handed since."""
+        self._call(self._attach, resp, sock, first)
+
+    def drop(self, resp: _Response) -> None:
+        """Its handler answered without a stream (an error before the first byte)."""
+        self._call(self._end, resp)
+
+    def hand(self, events: list[tuple]) -> None:
+        """A pass's events, ``(response, kind, payload, stamp)`` each, in one list."""
+        self._call(self._take, events)
+
+    def close(self) -> None:
+        """No request will be admitted any more: flush and exit."""
+        self._call(self._close)
+
+    def open_count(self) -> int:
+        return len(self._open)
+
+    # ------------------------------------------------- the writer thread
+    def _run(self) -> None:
+        while not (self._closing and not self._open):
+            for key, _ in self._sel.select():
+                if key.data is None:
+                    try:
+                        self._wake_r.recv(4096)
+                    except BlockingIOError:
+                        pass
+                else:
+                    self._send(key.data)  # a socket that takes bytes again
+            while self._calls:
+                fn, args = self._calls.popleft()
+                fn(*args)
+        self._sel.close()
+        self._wake_r.close()
+        self._wake_w.close()
+        self.flushed.set()
+
+    def _close(self) -> None:
+        self._closing = True
+
+    def _attach(self, resp: _Response, sock: socket.socket, first: tuple) -> None:
+        if resp.ended.is_set():
+            return  # over the bound before its handler came back
+        sock.setblocking(False)
+        resp.sock = sock
+        resp.unsent.appendleft(first)
+        self._send(resp)
+
+    def _take(self, events: list[tuple]) -> None:
+        for resp, *event in events:
+            if resp.ended.is_set():
+                continue  # failed or cancelled: what the engine still had for it goes nowhere
+            resp.unsent.append(event)
+            if resp.sock is not None and not resp.waiting:
+                self._send(resp)
+            if len(resp.unsent) >= self._bound:
+                # dead-slow consumer: cap host memory by treating it as a
+                # disconnect (the engine's next sweep frees the slot)
+                resp.out.cancel()
+                self._end(resp)
+
+    def _send(self, resp: _Response) -> None:
+        """Put the stream's events on its socket, in order, as far as it takes them."""
+        while resp.unsent:
+            kind, payload, handed_s = resp.unsent[0]
+            t0 = time.perf_counter()
+            with jax.profiler.TraceAnnotation(_WRITE_ANNOTATION):
+                if resp.rest is None:
+                    resp.rest = _sse(kind, payload)
+                try:
+                    sent = resp.sock.send(resp.rest)
+                except BlockingIOError:
+                    sent = 0
+                except OSError:
+                    resp.out.cancel()  # dropped client: free the slot mid-decode
+                    self._end(resp)
+                    return
+            t1 = time.perf_counter()
+            if sent < len(resp.rest):
+                resp.rest = resp.rest[sent:]
+                self.deferred += 1
+                _DEFERRED.inc()
+                if not resp.waiting:
+                    self._sel.register(resp.sock, selectors.EVENT_WRITE, resp)
+                    resp.waiting = True
+                return
+            resp.rest = None
+            resp.unsent.popleft()
+            obs_metrics.observe_pair(_STREAM_WRITE, t1 - t0, _FANOUT_DELAY, t1 - handed_s)
+            if kind == "tokens":
+                resp.delivered += len(payload)
+                self._delivered(len(payload))
+            else:
+                if kind == "done":  # chunks already written; only the remainder is new bytes
+                    self._delivered(max(len(payload) - resp.delivered, 0))
+                self._end(resp)
+                return
+        self._unwatch(resp)
+
+    def _unwatch(self, resp: _Response) -> None:
+        if resp.waiting:
+            self._sel.unregister(resp.sock)
+            resp.waiting = False
+
+    def _end(self, resp: _Response) -> None:
+        self._unwatch(resp)
+        resp.unsent.clear()
+        resp.rest = None
+        self._open.discard(resp)
+        resp.ended.set()  # its handler thread returns, and the server closes the connection
+
+
 class EngineServer:
     """Thread-safe facade over one ContinuousBatcher.
 
@@ -221,11 +453,12 @@ class EngineServer:
     Load shedding: the admission inbox is BOUNDED (``max_queue``) — when
     it is full, submit() refuses with an "overloaded" error the HTTP layer
     maps to 429, so overload surfaces as fast rejection, not unbounded
-    latency. Per-stream queues are bounded too: a consumer that stops
-    draining (dead-slow SSE client) trips the bound and is cancelled like
-    a disconnect instead of growing host memory without limit."""
+    latency. What waits for one client is bounded too, on a request's queue
+    and with the stream writer alike: a consumer that stops draining
+    (dead-slow SSE client) trips the bound and is cancelled like a
+    disconnect instead of growing host memory without limit."""
 
-    STREAM_QUEUE_CHUNKS = 1024  # per-request event bound (chunks, not tokens)
+    STREAM_QUEUE_CHUNKS = 1024  # per-request bound on events not yet with the client (chunks, not tokens)
 
     def __init__(self, engine: ContinuousBatcher, on_fatal=None,
                  max_queue: int = 256, request_timeout_s: float = 0.0,
@@ -241,6 +474,9 @@ class EngineServer:
         #: top of every loop iteration, answered (ok, value) on a per-op box.
         self._control: "queue.Queue[tuple]" = queue.Queue()
         self._streams: dict[int, RequestStream] = {}
+        #: the stream writer's share of this pass's events, handed over once (engine thread only)
+        self._events: list[tuple] = []
+        self.writer = StreamWriter(self.STREAM_QUEUE_CHUNKS, self.add_delivered)
         self._deadlines: dict[int, float] = {}
         self.request_timeout_s = request_timeout_s
         self._draining = threading.Event()
@@ -258,13 +494,15 @@ class EngineServer:
         self.tokens_delivered = 0   # actually written to a client socket
         self.requests_done = 0
         self.requests_cancelled = 0
+        self.stream_handovers = 0   # lists of events handed to the stream writer
         self._prefix_hits_exported = 0  # engine-thread watermark → registry delta
         # disagg handoff accounting (engine-thread only: export/adopt both
         # run as control ops, so plain ints need no lock)
         self.kv_handoff_exported = 0    # pages shipped toward decode replicas
         self.kv_handoff_adopted = 0     # pages adopted into this pool
-        # delivered is the ONE counter with multiple writers (every HTTP
-        # handler thread); unsynchronized += would lose updates
+        # delivered is the ONE counter with multiple writers (the stream
+        # writer, and every handler thread that answers a request whole);
+        # unsynchronized += would lose updates
         self._delivered_lock = threading.Lock()
 
     def add_delivered(self, n: int) -> None:
@@ -305,21 +543,27 @@ class EngineServer:
                 box.put((False, e))
 
     def start(self) -> "EngineServer":
+        self.writer.start()
         self._thread.start()
         return self
 
     def submit(
         self, prompt_tokens: list[int], max_tokens: int,
         sampling: dict | None = None, timeout_s: float | None = None,
-        request_id: str = "",
+        request_id: str = "", streamed: bool = False,
     ) -> RequestStream:
         """Enqueue a request; returns the stream its events arrive on:
         ("tokens", [..]) zero or more times, then ("done", all_tokens) —
         or ("error", message). ``sampling``: per-request temperature /
         top_k / top_p overrides. ``timeout_s`` overrides the server's
         default per-request deadline (0/None → no deadline).
-        ``request_id``: router-propagated id for spans/exemplars."""
+        ``request_id``: router-propagated id for spans/exemplars.
+        ``streamed``: only the FIRST event arrives on the stream; the caller
+        then owes the stream writer an ``attach`` of the client's connection
+        or a ``drop`` (``out.response``), and the rest go out there."""
         out = RequestStream(self.STREAM_QUEUE_CHUNKS, request_id=request_id)
+        if streamed:
+            out.response = _Response(out)
         # span chain opens BEFORE the inbox put: once the engine thread can
         # see the stream, only it touches the spans
         out.open_trace()
@@ -340,6 +584,9 @@ class EngineServer:
             except queue.Full:
                 out.put(("error", "overloaded: admission queue full"))
                 out.finish_trace("error")
+            else:
+                if streamed:  # under the lock: before the loop's last sweep closes the writer
+                    self.writer.open(out.response)
         return out
 
     def _queue_depth(self) -> int:
@@ -361,6 +608,8 @@ class EngineServer:
             "requests_cancelled": self.requests_cancelled,
             "tokens_out": self.tokens_out,
             "tokens_delivered": self.tokens_delivered,
+            "stream_handovers": self.stream_handovers,
+            "stream_writes_deferred": self.writer.deferred,
             "tokens_per_s": round(self.tokens_out / up, 2),
             "uptime_s": round(up, 1),
             "draining": self._draining.is_set(),
@@ -382,10 +631,14 @@ class EngineServer:
         }
 
     def stop(self, timeout_s: float = 10.0) -> bool:
-        """Drain: no new admissions; in-flight requests finish. Returns True
-        if the drain completed inside ``timeout_s`` (False → truncated)."""
+        """Drain: no new admissions; in-flight requests finish, and the stream
+        writer puts every terminal event handed to it on its socket (or sees
+        that stream fail). Returns True if both completed inside ``timeout_s``
+        (False → truncated)."""
         self._draining.set()
-        return self._stopped.wait(timeout_s)
+        deadline = time.monotonic() + timeout_s
+        return (self._stopped.wait(timeout_s)
+                and self.writer.flushed.wait(max(deadline - time.monotonic(), 0.0)))
 
     def _loop(self) -> None:
         try:
@@ -401,9 +654,10 @@ class EngineServer:
             if self._streams:
                 _REQUESTS_DONE.inc(len(self._streams), outcome="error")
             for out in self._streams.values():
-                self._finish_stream(out, ("error", f"engine failed: {e}"))
+                self._deliver(out, ("error", f"engine failed: {e}"))
                 out.finish_trace("error")
             self._streams.clear()
+            self._hand_over()
             if self._on_fatal is not None:
                 self._on_fatal()
         finally:
@@ -422,6 +676,7 @@ class EngineServer:
                         box.put((False, RuntimeError("engine stopped")))
                     except queue.Empty:
                         break
+                self.writer.close()  # after the last hand-over and the last admission
                 self._stopped.set()
 
     @staticmethod
@@ -443,6 +698,34 @@ class EngineServer:
             except queue.Full:
                 pass  # racing consumer refilled it: it is draining, fine
 
+    def _deliver(self, out: RequestStream, event: tuple) -> None:
+        """One event of an admitted request toward its client, without ever
+        blocking the engine thread. A streamed request's FIRST event goes on
+        its queue like anyone's, where its handler thread waits to choose
+        between an error reply and the headers; every later one joins this
+        pass's hand-over to the stream writer, stamped here."""
+        if out.response is not None and out.started:
+            self._events.append((out.response, *event, time.perf_counter()))
+        elif event[0] != "tokens":
+            self._finish_stream(out, event)
+        else:
+            try:
+                out.q.put_nowait((*event, time.perf_counter()))
+            except queue.Full:
+                # dead-slow consumer: cap host memory by treating it
+                # as a disconnect (picked up by the next sweep)
+                out.cancel()
+        out.started = True
+
+    def _hand_over(self) -> None:
+        """The stream writer gets what ``_deliver`` gathered: one list, one
+        wake-up, whatever the number of live streams."""
+        if self._events:
+            self.writer.hand(self._events)
+            self._events = []
+            self.stream_handovers += 1
+            _HANDOVERS.inc()
+
     def _sweep_cancellations(self) -> None:
         """Between chunks: propagate client cancellations (disconnect, slow
         consumer) and expired deadlines into the engine — the slot/pages
@@ -457,7 +740,7 @@ class EngineServer:
                 eng.cancel(rid)
                 # ALWAYS terminate the stream (the handler may still be
                 # attached — slow-consumer cancels have a live socket)
-                self._finish_stream(
+                self._deliver(
                     stream,
                     ("error", "deadline exceeded" if expired
                      else "cancelled: consumer stopped draining"),
@@ -467,6 +750,7 @@ class EngineServer:
                 stream.finish_trace("error")
                 del self._streams[rid]
                 self._deadlines.pop(rid, None)
+        self._hand_over()  # now, not a pass later (nothing to hand unless something was cancelled)
 
     def _loop_inner(self) -> None:
         eng = self.engine
@@ -544,12 +828,7 @@ class EngineServer:
                 if done:
                     self.requests_done += 1
                     _REQUESTS_DONE.inc(outcome="done")
-                    # terminal event via the non-blocking evict-then-put: a
-                    # full queue (consumer stalled since the last chunk) must
-                    # not block the ONE engine thread on out.put()
-                    self._finish_stream(
-                        out, ("done", final if final is not None else toks)
-                    )
+                    self._deliver(out, ("done", final if final is not None else toks))
                     if out.defer_finish:
                         # disagg: the span stays open through the KV handoff;
                         # the /v1/prefill handler closes it after the ship
@@ -559,12 +838,8 @@ class EngineServer:
                     del self._streams[rid]
                     self._deadlines.pop(rid, None)
                 else:
-                    try:
-                        out.q.put_nowait(("tokens", toks, time.perf_counter()))
-                    except queue.Full:
-                        # dead-slow consumer: cap host memory by treating it
-                        # as a disconnect (picked up by the next sweep)
-                        out.cancel()
+                    self._deliver(out, ("tokens", toks))
+            self._hand_over()
             if not had_work:
                 if self._draining.is_set():
                     eng.phase.to(None)
@@ -649,8 +924,8 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply(400, {"error": str(e)})
             return
         request_id = (self.headers.get("X-Tony-Request-Id") or "").strip()
-        out = self.server_ref.submit(prompt, max_tokens, sampling,
-                                     timeout_s=timeout_s, request_id=request_id)
+        out = self.server_ref.submit(prompt, max_tokens, sampling, timeout_s=timeout_s,
+                                     request_id=request_id, streamed=stream)
         if stream:
             self._stream_response(out)
         else:
@@ -784,45 +1059,30 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _stream_response(self, out) -> None:
         """SSE: one ``data: {"tokens": [...]}`` event per decode chunk, then
-        ``data: {"finished": true, ...}``. A write failure (client went
-        away) CANCELS the engine request — the slot frees within one decode
-        chunk instead of decoding to max_tokens for nobody."""
-        first_kind, first_payload = out.get()
-        if first_kind == "error":
-            self._error_reply(first_payload)
-            return
-        self.send_response(200)
-        self.send_header("Content-Type", "text/event-stream")
-        self.send_header("Cache-Control", "no-cache")
-        self.end_headers()
-
-        def emit(obj: Any) -> None:
-            t0 = time.perf_counter()
-            with jax.profiler.TraceAnnotation(_WRITE_ANNOTATION):
-                self.wfile.write(b"data: " + json.dumps(obj).encode() + b"\n\n")
-                self.wfile.flush()
-            t1 = time.perf_counter()
-            obs_metrics.observe_pair(_STREAM_WRITE, t1 - t0, _FANOUT_DELAY, t1 - out.handed_s)
-
-        delivered = 0
-        kind, payload = first_kind, first_payload
+        ``data: {"finished": true, ...}``. This thread waits for the first
+        event, answers an error that comes before the first byte as any
+        request's, else sends the headers, hands the connection to the stream
+        writer with that event and parks until the stream has ended: the
+        writer sends every event. A send that fails there (client went away)
+        CANCELS the engine request — the slot frees within one decode chunk
+        instead of decoding to max_tokens for nobody."""
+        writer, attached = self.server_ref.writer, False
         try:
-            while True:
-                if kind == "tokens":
-                    emit({"tokens": payload})
-                    delivered += len(payload)
-                    self.server_ref.add_delivered(len(payload))
-                elif kind == "done":
-                    emit({"finished": True, "tokens": list(payload)})
-                    # chunks already emitted; only the remainder is new bytes
-                    self.server_ref.add_delivered(max(len(payload) - delivered, 0))
-                    return
-                else:
-                    emit({"error": payload})
-                    return
-                kind, payload = out.get()
-        except OSError:
-            out.cancel()  # dropped client: free the slot mid-decode
+            kind, payload = out.get()
+            if kind == "error":
+                self._error_reply(payload)
+                return
+            self.send_response(200)
+            self.send_header("Content-Type", "text/event-stream")
+            self.send_header("Cache-Control", "no-cache")
+            self.end_headers()
+            writer.attach(out.response, self.connection, (kind, payload, out.handed_s))
+            attached = True
+        finally:
+            if not attached:
+                out.cancel()  # nobody to decode for (a no-op after an error: that request is over)
+                writer.drop(out.response)
+        out.response.ended.wait()
 
 
 def _register_with_am(url: str) -> None:
@@ -935,7 +1195,8 @@ def _drain_watch(srv: EngineServer, stop: threading.Event,
             if not srv.stop(timeout_s=budget_s):
                 obs_logging.warning(
                     f"[tony-serve] drain {req_id} timed out with "
-                    f"{len(srv._streams)} request(s) in flight — truncating")
+                    f"{len(srv._streams)} request(s) in flight, "
+                    f"{srv.writer.open_count()} stream(s) unwritten — truncating")
         # later requests against an already-drained server (a gang-wide
         # preemption following a scale-down drain) ack instantly — stop()
         # is idempotent and the AM must not burn its margin waiting
@@ -1158,13 +1419,15 @@ def main(argv: list[str] | None = None) -> int:
         pass
     if srv.error is not None:
         obs_logging.error(f"[tony-serve] engine failed: {srv.error}")
+        srv.stop(timeout_s=budget_s)  # the loop is over: only the open streams' error events are waited for
         httpd.shutdown()
         return 1
     # graceful drain: refuse new work, finish in-flight, then exit 0.
     obs_logging.info(f"[tony-serve] draining (budget {budget_s:.0f}s)")
     if not srv.stop(timeout_s=budget_s):
         obs_logging.warning(f"[tony-serve] drain timed out with {len(srv._streams)} "
-                            f"request(s) in flight — truncating")
+                            f"request(s) in flight, {srv.writer.open_count()} stream(s) "
+                            f"unwritten — truncating")
     stop_drain_watch.set()
     stop_metrics.set()
     httpd.shutdown()
