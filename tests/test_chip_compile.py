@@ -401,3 +401,49 @@ class TestFlashOnAFourChipMesh:
             jax.jit(bare).lower(q, kv, kv)
         assert _kernel_calls(on_mesh, q, kv, kv) == 1
         assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+
+
+class TestLatentAttentionAtTheNotesCellsShapes:
+    """`dots3-note-prev.serve_notes` (one chip's share): the five Pallas calls of
+    the latent-attention path at the published widths, found in a trace by these
+    names: a 2048-row prefill chunk over 67,584 staged positions, a decode step
+    of 32 slots over 2048 chosen rows and over the rings."""
+
+    S, T, MAX, PAGE, TOPK = 32, 2048, 67584, 1024, 2048
+
+    def _named(self, fn, name, *args):
+        text = jax.jit(fn).lower(*args).compile().as_text()
+        assert text.count("tpu_custom_call") == 1 and len(re.findall(rf"%\w*{name}[\w.]* = ", text)) == 1, name
+
+    @pytest.mark.parametrize("heads,r,dn,rows,row", [(128, 512, 128, 67584, 640), (64, 1024, 192, 2560, 1152)],
+                             ids=["full-layer-under-the-indexers-mask", "window-layer-under-the-band"])
+    def test_the_expanded_prefill(self, chip, heads, r, dn, rows, row):
+        from tony_tpu.ops import latent_attention as LA
+
+        bf, i32 = jnp.bfloat16, jnp.int32
+        args = (_s((heads, self.T, dn), bf, chip), _s((heads, self.T, 64), bf, chip), _s((rows, row), bf, chip),
+                _s((heads, r, dn), bf, chip), _s((heads, r, 128), bf, chip), _s((rows // 512, self.T, 512), jnp.int8, chip),
+                _s((2,), i32, chip), _s((2,), i32, chip))
+        self._named(functools.partial(LA.latent_prefill_attention, scale=0.07), "latent_prefill", *args)
+
+    @pytest.mark.parametrize("name,heads,r,rows,row", [("latent_decode", 128, 512, (1, 32, 2048, 640), 640),
+                                                         ("latent_ring_decode", 64, 1024, (3, 32, 640, 1152), 1152)])
+    def test_the_absorbed_decode(self, chip, name, heads, r, rows, row):
+        from tony_tpu.ops import latent_attention as LA
+
+        bf = jnp.bfloat16
+        args = (_s((self.S, heads, row), bf, chip), _s(rows, bf, chip), _s((), jnp.int32, chip), _s((self.S, rows[2]), jnp.bool_, chip),
+                _s((self.S, 128, row), bf, chip), _s((self.S, 128), jnp.bool_, chip))
+        self._named(functools.partial(LA.latent_rows_attention, r=r, scale=0.07, name=name), name, *args)
+
+    def test_the_indexers_scores_and_choice(self, chip):
+        from tony_tpu.ops import sparse_attention as SA
+
+        bf, i32 = jnp.bfloat16, jnp.int32
+        self._named(SA.index_scores_prefill, "index_scores_prefill", _s((self.T, 64, 128), bf, chip), _s((self.T, 64), jnp.float32, chip),
+                    _s((self.MAX, 128), bf, chip), _s((), i32, chip))
+        self._named(functools.partial(SA.index_select, topk=self.TOPK), "index_select",
+                    _s((self.MAX // 512, self.T, 512), i32, chip), _s((), i32, chip))
+        self._named(SA.index_scores_decode, "index_scores_decode", _s((self.S, 64, 128), bf, chip), _s((self.S, 64), jnp.float32, chip),
+                    _s((2, 1201, self.PAGE, 128), bf, chip), _s((), i32, chip), _s((self.S, self.MAX // self.PAGE), i32, chip),
+                    _s((self.S,), i32, chip))

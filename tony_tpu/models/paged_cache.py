@@ -416,3 +416,104 @@ def write_decode_chunk(
         return kv
 
     return jax.lax.fori_loop(0, S, write_slot, (pk, pv))
+
+
+# -- a latent cache: one row a position for all heads -------------------------------------------
+#
+# A latent-attention layer keeps no keys and values a head: it keeps ONE row a position (the normed latent and
+# the rope key the heads share), and a layer with an indexer a second, narrower row beside it (the index key).
+# So its pools are [L, P, page_len, width], one a kind of row, filled and written together: `pools` below is a
+# tuple of them, `staged` / `stages` the matching tuple of what goes in. The host side (PageAllocator, page
+# tables, reservation) is the one above. A window layer's rows live in a ring a slot, as a key-value window
+# layer's do: [L, slots, ring, width], one page a slot, written by the same chunk write through `ring_table`
+# and read whole by position (`latent_ring_valid`).
+
+
+def init_latent_pools(layers: int, num_pages: int, page_len: int, widths: tuple, dtype) -> tuple:
+    """A pool a width: [layers, num_pages, page_len, width]."""
+    return tuple(jnp.zeros((layers, num_pages, page_len, w), dtype) for w in widths)
+
+
+def latent_ring_len(window: int) -> int:
+    """Rows of a latent ring: the window, the decode chunk in flight, up to whole tiles of 128."""
+    return -(-(window + RING_SLACK) // 128) * 128
+
+
+def insert_latent_prefill(pools: tuple, staged: tuple, fresh_pages: jax.Array, j0: jax.Array, n: jax.Array) -> tuple:
+    """Admission: logical pages j0 .. j0 + n of a request's staged rows (each
+    [L, max_len, width], at their true positions) into its fresh physical pages,
+    in place on the donated pools: one dynamic_update_slice a page and pool, the
+    trip count traced (one compiled variant for every page count)."""
+    page_len = pools[0].shape[2]
+
+    def body(j, pools):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                pool, jax.lax.dynamic_slice_in_dim(rows, (j0 + j) * page_len, page_len, axis=1)[:, None],
+                (0, fresh_pages[j], 0, 0))
+            for pool, rows in zip(pools, staged))
+
+    return jax.lax.fori_loop(0, n, body, tuple(pools))
+
+
+def insert_latent_rings(ring: jax.Array, tail: jax.Array, slot, true_len) -> jax.Array:
+    """Admission: a request's last prefilled positions into its slot's rings.
+    ring [Lw, S, R, width]; tail [Lw, W, width] holds positions true_len - W ..
+    true_len - 1 in order (those below 0 do not exist). The slot's whole page is
+    written: rows of positions the tail does not hold are zeroed, and no step
+    reads them (`latent_ring_valid` masks by position)."""
+    R, w = ring.shape[2], tail.shape[1]
+    row = jnp.arange(R)
+    pos = true_len - 1 - (true_len - 1 - row) % R             # the newest position below true_len that lives in the row
+    at = pos - (true_len - w)
+    held = ((pos >= 0) & (at >= 0))[None, :, None]
+    page = jnp.where(held, jnp.take(tail, jnp.clip(at, 0, w - 1), axis=1), 0)[:, None].astype(ring.dtype)
+    return jax.lax.dynamic_update_slice(ring, page, (0, slot, 0, 0))
+
+
+def latent_ring_valid(ring_len: int, pool_len: jax.Array, pos: jax.Array, window: int) -> jax.Array:
+    """bool [S, ring_len]: whether a decode step at position `pos` [S] reads a
+    ring's row. Row rho holds the newest position below `pool_len` [S] (what was
+    written before the chunk in flight) congruent to it; it is read iff that
+    position exists and lies inside the window, which counts `pos` itself."""
+    row = jnp.arange(ring_len)[None, :]
+    held = pool_len[:, None] - 1 - (pool_len[:, None] - 1 - row) % ring_len
+    return (held >= 0) & (held > pos[:, None] - window)
+
+
+def write_latent_chunk(pools: tuple, stages: tuple, len0: jax.Array, page_table: jax.Array) -> tuple:
+    """A decode chunk's one write into latent pools (or rings, through
+    `ring_table`), in place: staged row (slot s, step j) of stages[i] [L, S, n,
+    width] lands at position len0[s] + j of slot s in pools[i]. `write_decode_chunk`
+    for rows without a head axis: the same two read-modify-write windows a slot,
+    placed by position, an idle slot (len0 == 0) and positions past the table's
+    end writing back what they read."""
+    page_len = pools[0].shape[2]
+    S, n = stages[0].shape[1:3]
+    max_pages = page_table.shape[1]
+    max_t = max_pages * page_len
+    w = min(n, page_len)
+    windows = (n + page_len - 2) // page_len + 1
+    start = jnp.minimum(len0, max_t - 1)
+    first_page = start // page_len
+    rows = jnp.arange(w, dtype=jnp.int32)
+    padded = tuple(jnp.pad(st, ((0, 0), (0, 0), (n, n), (0, 0))) for st in stages)        # [L, S, 3n, width]
+
+    def write_slot(s, pools):
+        for i in range(windows):
+            logical = first_page[s] + i
+            off = jnp.minimum(start[s] % page_len, page_len - w) if i == 0 else jnp.int32(0)
+            first = logical * page_len + off
+            shift = jnp.clip(first - len0[s], -n, n)
+            take = ((len0[s] > 0) & (rows + shift >= 0) & (rows + shift < n) & (first + rows < max_t))[None, None, :, None]
+            page = page_table[s, jnp.minimum(logical, max_pages - 1)]
+            out = []
+            for pool, stage in zip(pools, padded):
+                window = (pool.shape[0], 1, w, pool.shape[3])
+                at = (0, page, off, 0)
+                new = jax.lax.dynamic_slice(stage, (0, s, n + shift, 0), window)
+                out.append(jax.lax.dynamic_update_slice(pool, jnp.where(take, new, jax.lax.dynamic_slice(pool, at, window)), at))
+            pools = tuple(out)
+        return pools
+
+    return jax.lax.fori_loop(0, S, write_slot, tuple(pools))

@@ -16,7 +16,8 @@ import sys
 #: it (the benchmark's families/mixtral.serve_install raises) and its decode computes every expert for every
 #: row (generate._ffn_with_cache); a routed FFN is served by exaone_moe, whose decode multiplies the chosen
 #: experts only
-SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala", "tony_tpu.models.exaone_moe")
+SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala", "tony_tpu.models.exaone_moe",
+            "tony_tpu.models.dots3_note")
 
 
 def presets() -> dict:

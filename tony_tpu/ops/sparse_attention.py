@@ -363,3 +363,237 @@ def block_select(q, kc, n_ctx, spec: SparseSpec, *, block_q: int = 64, block_b: 
                                       bytes_accessed=4 * (t * hkv * g * d + nq * hkv * nB * per * d + t * hkv * nB)),
     )(tiles, qt, kt, nv)
     return score.transpose(1, 0, 2)[:, :, :nB], kth.transpose(1, 0, 2)
+
+
+# -- a learned indexer that chooses single positions (DSA) ----------------------------------------
+#
+# A full layer of a model with an indexer reads, for the query at position t, the `topk` positions s <= t of
+# largest I[t, s] = sum_h w[t, h] relu(qI[t, h] . kI[s]): Hi index heads, ONE di-wide index key a position.
+# Scores are float32 (a position is read or it is not). The choice is a threshold, found with no sort: a
+# float32's bits, made to order as a signed integer orders (`order_keys`), and the `topk`-th largest of a row
+# by the bit-search `kth_largest` runs on block scores, here over all 32 bits of a key (`kth_largest_key`,
+# and inside `index_select` for the rows of a prefill chunk). A score equal to the threshold is read too
+# (sums of 64 float32 products: a tie is an accident). Two forms of the chosen set: a tile-major int8 mask
+# for a prefill chunk (ops/latent_attention.latent_prefill_attention), and for a decode step a list of
+# positions a slot (`compact_chosen`: sort-free too), whose rows the caller gathers.
+
+KEY_MIN = -2 ** 31   # the key of a position a query may not read: below every score's
+
+
+def order_keys(score: jax.Array) -> jax.Array:
+    """float32 -> int32 that orders as the floats do (negative floats' bits are flipped)."""
+    bits = jax.lax.bitcast_convert_type(score.astype(jnp.float32), jnp.int32)
+    return bits ^ (jnp.right_shift(bits, 31) & jnp.int32(0x7FFFFFFF))
+
+
+def key_scores(keys: jax.Array) -> jax.Array:
+    """`order_keys` undone (the map is its own inverse): for tests that read scores."""
+    return jax.lax.bitcast_convert_type(keys ^ (jnp.right_shift(keys, 31) & jnp.int32(0x7FFFFFFF)), jnp.float32)
+
+
+def _kth_key(count_ge, shape, k: int) -> jax.Array:
+    """The largest int32 t with `count_ge(t) >= k` entries at or above it, or KEY_MIN + 1 where fewer than k
+    entries are readable at all (then every readable entry is at or above it, and no unreadable one):
+    built from the sign bit down, in offset binary. `count_ge(t)` -> float32 counts shaped `shape`."""
+    t = jnp.where(count_ge(jnp.zeros(shape, jnp.int32)) >= k, 0, KEY_MIN).astype(jnp.int32)
+
+    def bit(i, t):
+        cand = t | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(count_ge(cand) >= k, cand, t)
+
+    return jnp.maximum(jax.lax.fori_loop(0, 31, bit, t), KEY_MIN + 1)
+
+
+def kth_largest_key(keys: jax.Array, k: int) -> jax.Array:
+    """int32 [..., n] (`order_keys`; KEY_MIN where unreadable) -> [..., 1]: the threshold a row's `k`
+    largest keys lie at or above. Exact, no sort; plain array code (a decode step's rows)."""
+    return _kth_key(lambda t: (keys >= t).astype(jnp.float32).sum(axis=-1, keepdims=True), (*keys.shape[:-1], 1), k)
+
+
+def _index_prefill_kernel(pos_ref, q_ref, w_ref, k_ref, o_ref, *, heads):
+    from jax.experimental import pallas as pl
+
+    i, j = pl.program_id(0), pl.program_id(1)
+    bq, bk = o_ref.shape[1], o_ref.shape[2]
+    pos0 = pos_ref[0]
+    last = (pos0 + (i + 1) * bq - 1) // bk
+
+    @pl.when(j <= last)
+    def _tile():
+        s = jax.lax.dot_general(q_ref[...], k_ref[...], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        score = (jnp.maximum(s, 0.0) * w_ref[...]).reshape(bq, heads, bk).sum(axis=1)           # [bq, bk] float32
+        col = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+        qpos = pos0 + i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
+        o_ref[0] = jnp.where(col <= qpos, order_keys(score), KEY_MIN)
+
+    @pl.when(j > last)
+    def _past():
+        o_ref[0] = jnp.full((bq, bk), KEY_MIN, jnp.int32)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k"))
+def index_scores_prefill(qi, w, ki, pos0, *, block_q: int = 32, block_k: int = 512):
+    """The index scores of a prefill chunk's queries against a request's staged
+    index keys, as ordered keys. qi [T, Hi, di], w [T, Hi] float32, ki [Tk, di]
+    (the chunk's own keys among them, at positions pos0 ..), pos0 [] int32.
+    Returns int32 [Tk // bk, T, bk], tile-major: `order_keys(I[t, s])` where s
+    <= pos0 + t, KEY_MIN elsewhere. A tile of keys wholly past its queries is
+    neither fetched nor multiplied."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    t, hi, di = qi.shape
+    tk = ki.shape[0]
+    bq, bk = math.gcd(block_q, t), math.gcd(block_k, tk)
+    nq, nk = t // bq, tk // bk
+
+    def key_tile(i, j, pos):
+        return (jnp.minimum(j, (pos[0] + (i + 1) * bq - 1) // bk), 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(nq, nk),
+        in_specs=[
+            pl.BlockSpec((bq * hi, di), lambda i, j, pos: (i, 0)),
+            pl.BlockSpec((bq * hi, 1), lambda i, j, pos: (i, 0)),
+            pl.BlockSpec((bk, di), key_tile),
+        ],
+        out_specs=pl.BlockSpec((1, bq, bk), lambda i, j, pos: (j, i, 0)),
+    )
+    return pl.pallas_call(
+        functools.partial(_index_prefill_kernel, heads=hi),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nk, t, bk), jnp.int32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary"),
+                                             vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret(),
+        name="index_scores_prefill",
+        cost_estimate=pl.CostEstimate(flops=2 * t * hi * tk * di, transcendentals=0,
+                                      bytes_accessed=nq * tk * di * ki.dtype.itemsize + 4 * t * tk),
+    )(jnp.reshape(pos0, (1,)).astype(jnp.int32), qi.reshape(t * hi, di), w.astype(jnp.float32).reshape(t * hi, 1), ki)
+
+
+def _index_select_kernel(n_ref, keys_ref, mask_ref, *, topk):
+    n = n_ref[0]
+    rows = keys_ref.shape[1]
+
+    def count_ge(t):
+        return jax.lax.fori_loop(
+            0, n, lambda j, c: c + (keys_ref[j] >= t).astype(jnp.float32).sum(axis=-1, keepdims=True),
+            jnp.zeros((rows, 1), jnp.float32))
+
+    kth = _kth_key(count_ge, (rows, 1), topk)
+
+    def write(j, carry):
+        mask_ref[j] = jnp.where(keys_ref[j] >= kth, 1, 0).astype(jnp.int8)
+        return carry
+
+    jax.lax.fori_loop(0, n, write, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("topk", "block_q"))
+def index_select(keys, n_keys, *, topk: int, block_q: int = 32):
+    """keys int32 [nk, T, bk] (`index_scores_prefill`), n_keys [] int32 (the
+    positions that exist: the chunk's end) -> int8 [nk, T, bk]: 1 at a query's
+    `topk` largest keys (every readable position where there are fewer). Tiles at
+    or past ceil(n_keys / bk) are neither counted nor written: the attention that
+    reads the mask does not fetch them either."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    nk, t, bk = keys.shape
+    bq = math.gcd(block_q, t)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(t // bq,),
+        in_specs=[pl.BlockSpec((nk, bq, bk), lambda i, n: (0, i, 0))],
+        out_specs=pl.BlockSpec((nk, bq, bk), lambda i, n: (0, i, 0)),
+    )
+    tiles = jnp.minimum((n_keys + bk - 1) // bk, nk)
+    return pl.pallas_call(
+        functools.partial(_index_select_kernel, topk=topk),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((nk, t, bk), jnp.int8),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel",), vmem_limit_bytes=96 * 1024 * 1024),
+        interpret=interpret(),
+        name="index_select",
+        cost_estimate=pl.CostEstimate(flops=3 * 32 * nk * t * bk, transcendentals=0, bytes_accessed=5 * nk * t * bk),
+    )(jnp.reshape(tiles, (1,)).astype(jnp.int32), keys)
+
+
+def _index_decode_kernel(layer_ref, pages_ref, table_ref, q_ref, w_ref, k_ref, o_ref):
+    from jax.experimental import pallas as pl
+
+    s, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(j < pages_ref[s])
+    def _page():
+        sc = jax.lax.dot_general(q_ref[0], k_ref[0, 0], (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+        o_ref[0, 0] = (jnp.maximum(sc, 0.0) * w_ref[0]).sum(axis=0, keepdims=True)             # [1, page_len]
+
+
+@jax.jit
+def index_scores_decode(qi, w, pool, layer, page_table, pool_len):
+    """One query a slot against the slot's index keys in the page pool. qi [S,
+    Hi, di], w [S, Hi] float32, pool [L, P, page_len, di], layer [] int32,
+    page_table [S, max_pages], pool_len [S] (positions of the slot that lie in the
+    pool). Returns float32 [S, max_pages * page_len]: I[s, position]; what lies at
+    or past pool_len[s] is not computed and holds anything (the caller masks it).
+    A slot's pages are read once, its live ones only."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, hi, di = qi.shape
+    page_len, max_pages = pool.shape[2], page_table.shape[1]
+    pages = ((pool_len + page_len - 1) // page_len).astype(jnp.int32)
+
+    def page(s, j, layer, pages, table):
+        return (layer[0], table[s, jnp.minimum(j, jnp.maximum(pages[s] - 1, 0))], 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, max_pages),
+        in_specs=[
+            pl.BlockSpec((1, hi, di), lambda s, j, *_: (s, 0, 0)),
+            pl.BlockSpec((1, hi, 1), lambda s, j, *_: (s, 0, 0)),
+            pl.BlockSpec((1, 1, page_len, di), page),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, page_len), lambda s, j, *_: (s, j, 0, 0)),
+    )
+    out = pl.pallas_call(
+        _index_decode_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, max_pages, 1, page_len), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret(),
+        name="index_scores_decode",
+        cost_estimate=pl.CostEstimate(flops=2 * S * hi * max_pages * page_len * di, transcendentals=0,
+                                      bytes_accessed=S * max_pages * page_len * (di * pool.dtype.itemsize + 4)),
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), pages, page_table.astype(jnp.int32), qi,
+      w.astype(jnp.float32)[:, :, None], pool)
+    return out.reshape(S, max_pages * page_len)
+
+
+def compact_chosen(chosen: jax.Array, k: int, lanes: int = 128):
+    """chosen bool [S, N] with at most k set a row -> (idx int32 [S, k]: the set
+    positions ascending, then anything; count [S]). No sort and no scatter: the
+    j-th set position lies in the first block of `lanes` whose running count
+    passes j (a comparison against the blocks' running counts), that block's
+    bits are fetched by a one-hot product, and the lane is the first whose
+    running count inside the block passes what is left of j."""
+    S, N = chosen.shape
+    if N % lanes:
+        raise ValueError(f"{N} positions are not whole blocks of {lanes}")
+    B = N // lanes
+    bits = chosen.reshape(S, B, lanes)
+    per_block = bits.sum(axis=-1, dtype=jnp.int32)
+    running = jnp.cumsum(per_block, axis=-1)                                      # [S, B] inclusive
+    j = jnp.arange(k, dtype=jnp.int32)
+    before = running[:, None, :] <= j[None, :, None]                              # [S, k, B]: blocks wholly before the j-th
+    block = jnp.minimum(before.sum(axis=-1, dtype=jnp.int32), B - 1)
+    left = j[None, :] - jnp.where(before, per_block[:, None, :], 0).sum(axis=-1)  # the j-th is the `left`-th of its block
+    one_hot = (jnp.arange(B, dtype=jnp.int32)[None, None, :] == block[:, :, None]).astype(jnp.bfloat16)
+    row = jnp.einsum("skb,sbl->skl", one_hot, bits.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
+    inside = jnp.cumsum(row, axis=-1)                                             # small whole numbers: exact
+    lane = (inside <= left[:, :, None].astype(jnp.float32)).sum(axis=-1, dtype=jnp.int32)
+    return block * lanes + jnp.minimum(lane, lanes - 1), running[:, -1]
