@@ -162,6 +162,33 @@ class TestDecodeAttentionAtServeShapes:
         assert _kernel_calls(fn, q, pool, pool, lengths, table, layer, cur, cur,
                              staged, staged, lengths) == 1
 
+    @pytest.mark.parametrize("pool,slots,heads,max_pages,window", [
+        ((8, 384, HKV, 256, DH), 64, 32, 16, 4096), ((1, 4096, HKV, 256, DH), 256, 64, 24, 0),
+    ], ids=["mistral-7b-serve-batch", "k-exaone-236b-serve-reason"])
+    def test_paged_decode_at_the_cells_shapes(self, chip, pool, slots, heads, max_pages, window):
+        """The call as the serving cells make it: ONE Mosaic kernel whose operand is the whole
+        pool (the slab buffers and semaphores a fetch is handed through are the call's scratch,
+        not operands), nothing else of the pool's or a layer's shape, and next to no temporaries."""
+        q, cur = _s((slots, heads, DH), jnp.bfloat16, chip), _s((slots, HKV, DH), jnp.bfloat16, chip)
+        lengths, layer = _s((slots,), jnp.int32, chip), _s((), jnp.int32, chip)
+        staged = _s((slots, 8, HKV, DH), jnp.bfloat16, chip)
+
+        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
+            return DA.paged_decode_attention(
+                q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v, window=window,
+                staged_k=sk, staged_v=sv, staged_count=count)
+
+        kp = _s(pool, jnp.bfloat16, chip)
+        compiled = jax.jit(fn).lower(q, kp, kp, lengths, _s((slots, max_pages), jnp.int32, chip), layer, cur, cur,
+                                     staged, staged, lengths).compile()
+        text = compiled.as_text()
+        whole, one_layer = (f"bf16[{','.join(map(str, shape))}]" for shape in (pool, pool[1:]))
+        calls = [line for line in text.splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+        assert len(calls) == 1 and calls[0].count(whole) == 2, calls          # K's pool and V's, whole
+        made = re.findall(r"^\s*(?:ROOT )?%?[\w.-]+ = (\(.*?\)|\S+) ([\w-]+)\(", text, re.M)
+        assert not [(op, shape) for shape, op in made if op != "parameter" and (whole in shape or one_layer in shape)]
+        assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
 
 class TestSalaKernelsAtServedWidths:
     """MiniCPM-SALA's four kernels at the widths `minicpm-sala.serve_longdoc`

@@ -147,6 +147,153 @@ class TestPagedKernel:
 
 
 # ---------------------------------------------------------------------------
+# The page walk: a fetch handed from slot to slot, the chunk's staged rows and
+# the current token in one fold; against plain attention over the explicit list
+# of positions a step may see
+# ---------------------------------------------------------------------------
+WALK_PAGE, WALK_PAGES, WALK_WINDOW, WALK_STAGED, WALK_LAYERS = 16, 8, 40, 8, 3
+WALK_MAX_LEN = WALK_PAGE * WALK_PAGES
+
+#: the slots IN ORDER (a slot's first fetch is its predecessor's to start): (positions its tenant
+#: has written to its pages, the step's length, staged rows). Slabs read: a page each from the
+#: window's first to the pool part's last
+WALK_SLOTS = {
+    "the-first-slot-idle": (0, 0, 0),
+    "one-slab-after-an-idle-slot-count-0": (10, 10, 0),               # warms up itself, hands on
+    "two-slabs-behind-one-count-3": (27, 30, 3),                      # handed into buffer 1
+    "idle-between-two-live-slots": (0, 0, 0),                         # the chain breaks
+    "three-slabs-resuming-after-the-idle-slot-count-7": (40, 47, 7),  # and resumes: a warm-up
+    "every-position-staged-count-5": (0, 5, 5),                       # neither receives nor hands on
+    "window-skips-three-pages-four-slabs": (100, 100, 0),             # c0 = 3, warms up again
+    "window-skips-three-pages-three-slabs-count-7": (83, 90, 7),      # odd after even
+    # the chunk began at 125 and is 5 steps in: the caller clips 130 to max_len - 1, so the
+    # newest cached rows (122..124) lie past the pool's part of the slot and are not read
+    "length-clipped-at-max-len-minus-1": (125, WALK_MAX_LEN - 1, 5),  # odd after odd
+    "every-position-staged-count-1": (0, 1, 1),
+    "exactly-one-page-count-0": (16, 16, 0),
+    "every-position-staged-count-7": (0, 7, 7),
+    "the-last-slot-live-two-slabs-count-1": (32, 33, 1),
+}
+
+
+def _plain_rows(q, seen):
+    """Plain attention of each slot's query heads over its own list of (key, value) rows."""
+    S, H, Dh = q.shape
+    want = np.zeros((S, H, Dh), np.float32)
+    for s, rows in enumerate(seen):
+        keys, vals = (np.stack(a, axis=1) for a in zip(*rows))                          # [Hkv, n, Dh]
+        Hkv = keys.shape[0]
+        scores = np.einsum("hrd,hnd->hrn", q[s].reshape(Hkv, H // Hkv, Dh) * Dh ** -0.5, keys)
+        p = np.exp(scores - scores.max(-1, keepdims=True))
+        want[s] = np.einsum("hrn,hnd->hrd", p / p.sum(-1, keepdims=True), vals).reshape(H, Dh)
+    return want
+
+
+@functools.lru_cache(maxsize=None)
+def _walk_case(dtype):
+    """The slots' pages as their histories leave them (layer 1 of 3, a shuffled table; every other
+    row of the pool large, so a row read by mistake shows) and what plain attention gives."""
+    from tony_tpu.ops.decode_attention import paged_decode_attention
+
+    S, H, Hkv, Dh = len(WALK_SLOTS), 8, 2, 16
+    P = S * WALK_PAGES + 1
+    rng = np.random.default_rng(11)
+    draw = lambda *shape: np.asarray(jnp.asarray(rng.standard_normal(shape), dtype).astype(jnp.float32))
+    hist_k, hist_v = draw(S, WALK_MAX_LEN, Hkv, Dh), draw(S, WALK_MAX_LEN, Hkv, Dh)
+    kp, vp = 50.0 + draw(WALK_LAYERS, P, Hkv, WALK_PAGE, Dh), 50.0 + draw(WALK_LAYERS, P, Hkv, WALK_PAGE, Dh)
+    table = (1 + rng.permutation(P - 1)).reshape(S, WALK_PAGES).astype(np.int32)
+    q, cur_k, cur_v = draw(S, H, Dh), draw(S, Hkv, Dh), draw(S, Hkv, Dh)
+    sk, sv = draw(S, WALK_STAGED, Hkv, Dh), draw(S, WALK_STAGED, Hkv, Dh)
+    seen, seen_unstaged = [], []
+    for s, (written, length, count) in enumerate(WALK_SLOTS.values()):
+        for p in range(written):
+            kp[1, table[s, p // WALK_PAGE], :, p % WALK_PAGE] = hist_k[s, p]
+            vp[1, table[s, p // WALK_PAGE], :, p % WALK_PAGE] = hist_v[s, p]
+        pool_len, lo = max(length - count, 0), max(length + 1 - WALK_WINDOW, 0)
+        cached = lambda lo, hi: [(hist_k[s, p], hist_v[s, p]) for p in range(lo, hi)]
+        staged = [(sk[s, j], sv[s, j]) for j in range(count) if pool_len + j >= lo]
+        seen.append(cached(lo, pool_len) + staged + [(cur_k[s], cur_v[s])])
+        # the same pages with no staging: the step's length is what the tenant has written
+        seen_unstaged.append(cached(max(written + 1 - WALK_WINDOW, 0), written) + [(cur_k[s], cur_v[s])])
+    lengths, counts = (jnp.asarray([c[i] for c in WALK_SLOTS.values()], jnp.int32) for i in (1, 2))
+    operands = dict(q=jnp.asarray(q, dtype), kp=jnp.asarray(kp, dtype), vp=jnp.asarray(vp, dtype), lengths=lengths,
+                    page_table=jnp.asarray(table), cur_k=jnp.asarray(cur_k, dtype), cur_v=jnp.asarray(cur_v, dtype),
+                    staged_k=jnp.asarray(sk, dtype), staged_v=jnp.asarray(sv, dtype), staged_count=counts)
+    got = paged_decode_attention(layer=jnp.int32(1), window=WALK_WINDOW, **operands).astype(jnp.float32)
+    return operands, np.asarray(got), _plain_rows(q, seen), _plain_rows(q, seen_unstaged), (hist_k, hist_v)
+
+
+class TestPageWalk:
+    @pytest.mark.parametrize("case,dtype", [(c, d) for d in ("float32", "bfloat16") for c in WALK_SLOTS]
+                             + [(c, "float32") for c in (
+                                 "the-same-slots-in-another-order-give-the-same-rows",
+                                 "one-slot-alone",
+                                 "layer-index-traced-under-cond-in-a-scan",
+                                 "no-staging",
+                                 "no-window",
+                                 "a-dense-cache-through-the-same-walk")])
+    def test_paged_decode_attention(self, case, dtype):
+        from tony_tpu.ops.decode_attention import paged_decode_attention, ragged_decode_attention
+
+        operands, got, want, want_unstaged, (hist_k, hist_v) = _walk_case(dtype)
+        names = list(WALK_SLOTS)
+        call = functools.partial(paged_decode_attention, window=WALK_WINDOW)
+        per_slot = lambda rows: {k: v if k in ("kp", "vp") else v[rows] for k, v in operands.items()}
+        written = jnp.asarray([c[0] for c in WALK_SLOTS.values()], jnp.int32)
+        if case in WALK_SLOTS:
+            s = names.index(case)
+            # bfloat16: the same rows, so what is left is the output's own rounding (2 ** -9 of values under 4)
+            np.testing.assert_allclose(got[s], want[s], atol=1e-5 if dtype == "float32" else 1e-2, rtol=0)
+        elif case.startswith("the-same-slots"):
+            # other neighbours, other buffers, other hand-overs (the idle slots now lie elsewhere): a slot's row
+            # is its own arithmetic whatever was fetched around it
+            for seed in (0, 1):
+                order = np.random.default_rng(seed).permutation(len(names))
+                again = call(layer=jnp.int32(1), **per_slot(order))
+                np.testing.assert_array_equal(np.asarray(again), got[order], err_msg=str(order))
+        elif case == "one-slot-alone":
+            for s in (names.index("window-skips-three-pages-four-slabs"), names.index("every-position-staged-count-5")):
+                alone = call(layer=jnp.int32(1), **per_slot(np.asarray([s])))
+                np.testing.assert_array_equal(np.asarray(alone)[0], got[s])
+        elif case.startswith("layer-index"):
+            # as exaone_moe._decode_one hands it: a scan's slice, the call in one branch of a cond
+            at = lambda layer: call(layer=layer, **operands)
+
+            def body(_, xs):
+                layer, is_full = xs
+                return None, jax.lax.cond(is_full == 1, at, lambda layer: jnp.zeros(got.shape, dtype), layer)
+
+            layers, kinds = jnp.asarray([0, 0, 1, 2], jnp.int32), jnp.asarray([1, 0, 1, 1], jnp.int32)
+            outs = np.asarray(jax.lax.scan(body, None, (layers, kinds))[1])
+            np.testing.assert_array_equal(outs[2], got)
+            np.testing.assert_array_equal(outs[1], 0)
+            np.testing.assert_array_equal(outs[3], np.asarray(at(jnp.int32(2))))
+            assert not np.array_equal(outs[0], got) and not np.array_equal(outs[3], got)   # the layers do differ
+        elif case == "no-staging":
+            # no staged operands at all: the fold is the current token's alone
+            unstaged = {k: v for k, v in operands.items() if not k.startswith("staged")} | {"lengths": written}
+            np.testing.assert_allclose(np.asarray(call(layer=jnp.int32(1), **unstaged)), want_unstaged, atol=1e-5, rtol=0)
+        elif case == "no-window":
+            # every cached page from the first: up to 8 slabs a slot; against the windowed rows where the window
+            # hides nothing, and not equal to them where it does
+            whole = np.asarray(paged_decode_attention(layer=jnp.int32(1), window=0, **operands))
+            for s, (_, length, _) in enumerate(WALK_SLOTS.values()):
+                if length + 1 <= WALK_WINDOW:
+                    np.testing.assert_allclose(whole[s], want[s], atol=1e-5, rtol=0, err_msg=names[s])
+                else:
+                    assert np.abs(whole[s] - want[s]).max() > 1e-3, names[s]
+        else:
+            # ragged_decode_attention runs the same body over a cache a slot: slab c is positions c * chunk ..
+            # of the slot's own rows, and the fetch handed on is the next slot's
+            cache = lambda hist: jnp.asarray(np.where(
+                np.arange(WALK_MAX_LEN)[None, None, :, None] < np.asarray(written)[:, None, None, None],
+                hist.transpose(0, 2, 1, 3), 50.0))
+            dense = ragged_decode_attention(operands["q"], cache(hist_k), cache(hist_v), written, cur_k=operands["cur_k"],
+                                            cur_v=operands["cur_v"], window=WALK_WINDOW, chunk=WALK_PAGE)
+            np.testing.assert_allclose(np.asarray(dense), want_unstaged, atol=1e-5, rtol=0)
+
+
+# ---------------------------------------------------------------------------
 # A window layer's ring, read whole: against plain attention over the explicit
 # list of positions a step may see
 # ---------------------------------------------------------------------------

@@ -1,28 +1,45 @@
-"""Ragged per-slot decode attention (Pallas TPU) for the serving engine.
+"""Decode attention for the serving engine (Pallas TPU): one new token a slot over that slot's cache.
 
-The XLA decode path reads a GLOBAL length bucket of every slot's KV cache:
-one long-lived request drags every slot's per-token read back to the
-longest bucket (VERDICT r2 weak #3 — serving is KV-bandwidth-bound at long
-context). This kernel reads each slot's cache RAGGED: slot s streams only
-``ceil(lengths[s]/chunk)`` chunks from HBM through a double-buffered VMEM
-pipeline, so the step's KV traffic is Σ_s len_s instead of S·max(len).
-``lengths`` counts CACHE positions only — the current token's K/V arrive
-via ``cur_k``/``cur_v`` and fold in as a final online-softmax step (the
-r3-cont read-only-cache contract). Sliding-window models read cache from
-``max(0, len + 1 - window)`` — window-sized reads, closing the r2 gap
-where windowed models still read the full bucket.
+Four calls. ``paged_decode_attention`` walks a slot's pages of a page pool and
+``ragged_decode_attention`` the slabs of a dense cache a slot (the same body,
+``_kernel``); ``ring_decode_attention`` reads a window layer's ring whole;
+``sparse_paged_decode_attention`` reads the pages a learned score chose. All
+read each slot's cache RAGGED: slot s costs its own ``lengths[s]`` positions
+of HBM traffic, not the longest slot's bucket, so a step's KV traffic is
+Σ_s len_s. ``lengths`` counts CACHE positions only: the current token's K/V
+arrive via ``cur_k``/``cur_v`` (the cache is read-only here and the engine
+writes it once a chunk), and the positions a decode chunk has produced but not
+yet written arrive in a staged block. Sliding-window models read the cache
+from ``max(0, len + 1 - window)``: whole slabs below the window are skipped.
 
-Grid is (S,): one instance per slot streams [Hkv, chunk, Dh] K/V SLABS
-(all kv heads per DMA — 8× bigger transfers than a per-head grid, which
-measured ~2× slower end-to-end at short lengths from per-instance + DMA
-overhead) and computes all heads with Hkv-batched dots, flash-style online
-softmax in f32. GQA is native: q arrives grouped [Hkv, n_rep, Dh]. The
-cache stays in HBM (``memory_space=ANY``); lengths arrive via scalar
-prefetch so chunk counts are per-slot dynamic loop bounds, not padding.
+The page walk (``_kernel``). The grid is (S,), one instance a slot, run in
+order. An instance streams its slot's ``[Hkv, chunk, Dh]`` K and V SLABS (all
+kv heads a DMA; a page is one slab) through two VMEM buffers, one computed
+from while the other fills, with a flash-style online softmax in float32 over
+Hkv-batched dots; GQA is native, q arrives grouped ``[Hkv, n_rep, Dh]``. The
+cache stays in HBM (``memory_space=ANY``); lengths, counts, the page table
+and the layer index arrive by scalar prefetch, so slab counts are per-slot
+loop bounds, not padding. Two things keep the HBM queue and the vector units
+busy (PERF.md, PR 44):
 
-No reference counterpart (the reference does not serve); the engine-level
-contract is tested against the XLA masked-attention decode path, and the
-engine picks ragged-vs-bucketed by live length (serving.ContinuousBatcher).
+* **A fetch is handed from slot to slot.** The buffers and their DMA
+  semaphores are the call's scratch, alive over the whole grid. While a slot
+  computes its last slab it starts the fetch of the NEXT slot's first slab
+  (from the same prefetched scalars) into the other buffer, and that slot
+  waits for it and starts nothing of its own; the buffer a slot starts in is
+  where its predecessor put the slab (an SMEM scalar), so the parity follows
+  the running count of slabs. A slot that reads nothing from the pool (idle,
+  or every position still staged) neither receives nor hands on: the slot
+  after it starts its own first fetch. Both sides decide by one predicate
+  over the same scalars, so no DMA is left in flight or waited for twice.
+* **One fold.** The chunk's staged rows and the current token are one small
+  matrix a slot and fold in as ONE softmax step joined to the slabs' running
+  m, l, acc: a score matmul under an own-head and a shown mask, a value
+  matmul, no loop (``_chunk_fold``, which the ring's call shares).
+
+No reference counterpart (the reference does not serve); the calls are tested
+against plain attention over the explicit positions (tests/test_paged.py) and
+the engines against the XLA masked-attention decode path.
 """
 
 from __future__ import annotations
@@ -35,149 +52,172 @@ import jax.numpy as jnp
 
 from tony_tpu.ops.interpret import interpret
 
-# cache positions streamed per DMA slab; 256 measured best on v5e (r3-cont
-# ladder at 8×2048-cache slots: 128→533, 256→554, 512→531 tok/s) — bigger
-# slabs amortize per-DMA overhead until VMEM pressure bites. Env-tunable;
-# shrunk by halving to divide the cache length.
+# cache positions a DMA slab of the DENSE cache holds (a paged cache's slab is its page); shrunk by
+# halving to divide the cache length. Env-tunable.
 CHUNK = int(os.environ.get("TONY_DECODE_CHUNK", "256"))
 if CHUNK < 8:  # fail at import, not inside a jit trace
     raise ValueError(f"TONY_DECODE_CHUNK={CHUNK}: DMA slab must be >= 8 positions")
 
 
-def _kernel(len_ref, q_ref, ck_ref, cv_ref, k_hbm, v_hbm, o_ref, *, chunk, window,
-            n_rep, pt_ref=None, staged_refs=None, count_ref=None):
-    """Shared ragged-attention body. ``pt_ref=None``: dense per-slot cache —
-    slab c reads ``k_hbm[0, :, c*chunk:(c+1)*chunk]``. ``pt_ref`` set: PAGED
-    cache — ``k_hbm`` is one layer's [P, Hkv, page_len, Dh] view of the
-    page pool (a ``.at[layer]`` of the whole-pool operand: nothing is copied;
-    chunk == page_len) and slab c reads physical page ``pt_ref[slot, c]``;
-    the logical position math (lo/c0/c1, masking) is identical because a
-    page holds exactly one slab's worth of positions."""
+def _chunk_fold(H, Hkv, W, Dh):
+    """The fold of a decode chunk's staged rows and the current token, which the page walk and
+    the ring share: ``(scores, values)``, built inside a kernel's body.
+
+    The staged rows and the current token of ALL kv heads are one small matrix a slot, rows
+    ``(j, h')``: a query row ``(h, r)`` takes the columns of its own head and a mask hides the
+    others, so the fold is two matmuls and no loop (a step a row, each over ``[Hkv, n_rep, 1]``
+    arrays, cost a ring's read: PERF.md, PR 35). Staged row ``j`` is position ``pool_len + j``,
+    shown iff ``j < count`` and the position is at least ``lo``; the current token (step ``W``)
+    is always shown, so every maximum is a real score and a masked column's weight is exactly 0.
+    The refs are a grid step's blocks, ``b`` the slot among them; ``W == 0`` (no staging: the
+    staged ref is None) folds the current token alone.
+    """
+    n_rep = H // Hkv
+    row_head = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 0) // n_rep
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 1)
+    own_head, step = row_head == col % Hkv, col // Hkv                       # step W is the current token
+
+    def with_current(staged_ref, cur_ref, b):                                # [(W + 1) * Hkv, Dh], rows (j, h')
+        if staged_ref is None:
+            return cur_ref[b].astype(jnp.float32)
+        staged = staged_ref[b].astype(jnp.float32).reshape(W * Hkv, Dh)
+        return jnp.concatenate([staged, cur_ref[b].astype(jnp.float32)], axis=0)
+
+    def scores(qf, sk_ref, ck_ref, b, count, pool_len, lo):                  # qf [Hkv, n_rep, Dh], scaled
+        s2 = jax.lax.dot_general(qf.reshape(H, Dh), with_current(sk_ref, ck_ref, b), (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)         # [H, (W + 1) * Hkv]
+        shown = own_head & ((step == W) | ((step < count) & (pool_len + step >= lo)))
+        return jnp.where(shown, s2, -1e30)
+
+    def values(p2, sv_ref, cv_ref, b):                                       # p2 [H, (W + 1) * Hkv]
+        return jax.lax.dot_general(p2, with_current(sv_ref, cv_ref, b), (((1,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32).reshape(Hkv, n_rep, Dh)
+
+    return scores, values
+
+
+def _kernel(len_ref, count_ref, q_ref, ck_ref, cv_ref, staged_refs, k_hbm, v_hbm, o_ref,
+            k_buf, v_buf, sem, start_ref, *, slab, chunk, window):
+    """The page walk's body, one grid instance a slot, the slots in order. ``slab(ref, s, c)`` is
+    slab ``c`` of slot ``s`` in the HBM operand: ``[Hkv, chunk, Dh]`` positions ``c * chunk ..``
+    of a dense cache, or the physical page a table names (a page holds exactly one slab's
+    positions, so the position arithmetic is one). The two slab buffers, their DMA semaphores and
+    ``start_ref`` are the CALL's scratch, alive over the whole grid, so a fetch started by one
+    slot can be waited for by the next."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    s_i = pl.program_id(0)
-    length = len_ref[s_i]  # CACHE positions (current token arrives via ck/cv refs)
-    # staged window (paged chunked-decode): the most recent ``count`` of the
-    # ``length`` positions live in the staged VMEM block, NOT the pool —
-    # the pool read stops short of them and they fold in explicitly after
-    count = count_ref[s_i] if count_ref is not None else jnp.int32(0)
-    # clamp at 0: idle slots (length 0) carry staged garbage the caller
-    # discards; a negative pool span must not start a negative-offset DMA
-    pool_len = jnp.maximum(length - count, 0)
-    # the current token sits at position `length`; cache band is
-    # (length - window, length) — the self term is always in-window
-    lo = jnp.maximum(length + 1 - window, 0) if window > 0 else jnp.int32(0)
-    c0 = jnp.minimum(lo, pool_len) // chunk
-    c1 = pl.cdiv(pool_len, chunk)
-    Dh = q_ref.shape[-1]
-    Hkv = q_ref.shape[1]
-    scale = Dh ** -0.5
+    s_i, S = pl.program_id(0), pl.num_programs(0)
+    Hkv, n_rep, Dh = q_ref.shape[1:]
+    W = staged_refs[0].shape[1] if staged_refs is not None else 0
+    sk_ref, sv_ref = staged_refs if staged_refs is not None else (None, None)
 
-    def body(k_buf, v_buf, sem):
-        q = q_ref[0].astype(jnp.float32) * scale  # [Hkv, n_rep, Dh]
+    def span(s):
+        length = len_ref[s]  # CACHE positions (the current token arrives via ck/cv refs)
+        # the most recent ``count`` of them live in the staged block, NOT the pool: the pool's
+        # read stops short of them. Clamped at 0: an idle slot (length 0) carries staged garbage
+        # the caller discards, and a negative span must not start a negative-offset DMA
+        count = count_ref[s] if count_ref is not None else jnp.int32(0)
+        pool_len = jnp.maximum(length - count, 0)
+        # the current token sits at position `length`; the band is (length - window, length]
+        lo = jnp.maximum(length + 1 - window, 0) if window > 0 else jnp.int32(0)
+        return count, pool_len, lo, jnp.minimum(lo, pool_len) // chunk, pl.cdiv(pool_len, chunk)
 
-        def dma(slot, c):
-            # one DMA per buffer: the whole [Hkv, chunk, Dh] slab
-            if pt_ref is None:
-                k_src = k_hbm.at[0, :, pl.ds(c * chunk, chunk)]
-                v_src = v_hbm.at[0, :, pl.ds(c * chunk, chunk)]
-            else:
-                page = pt_ref[s_i, c]
-                k_src = k_hbm.at[page]
-                v_src = v_hbm.at[page]
-            return (
-                pltpu.make_async_copy(k_src, k_buf.at[slot], sem.at[slot, 0]),
-                pltpu.make_async_copy(v_src, v_buf.at[slot], sem.at[slot, 1]),
-            )
+    count, pool_len, lo, c0, c1 = span(s_i)
+    before, after = jnp.maximum(s_i - 1, 0), jnp.minimum(s_i + 1, S - 1)
+    *_, b0, b1 = span(before)
+    *_, a0, a1 = span(after)
+    # a fetch passes from a slot to the next iff BOTH read the pool: one predicate, evaluated on
+    # both sides from the same scalars, so nothing is left in flight or waited for twice. A slot
+    # with nothing to read (idle, or every position still staged) breaks the chain, and the slot
+    # after it warms up by itself
+    reads = c0 < c1
+    handed = (s_i > 0) & (b0 < b1) & reads
+    hands_on = (s_i + 1 < S) & (a0 < a1) & reads
+    # the buffer this slot's first slab is in: where its predecessor put it, so the parity
+    # follows the running count of slabs and not the slot's own
+    first = jnp.where(handed, start_ref[0], 0)
 
-        @pl.when(c0 < c1)  # a zero-length slot must not leave a DMA in flight
-        def _warmup():
-            for d in dma(0, c0):
+    def fetch(buf, s, c):  # one DMA a buffer: the whole [Hkv, chunk, Dh] slab
+        return (
+            pltpu.make_async_copy(slab(k_hbm, s, c), k_buf.at[buf], sem.at[buf, 0]),
+            pltpu.make_async_copy(slab(v_hbm, s, c), v_buf.at[buf], sem.at[buf, 1]),
+        )
+
+    @pl.when(reads & jnp.logical_not(handed))
+    def _warm_up():
+        for d in fetch(first, s_i, c0):
+            d.start()
+
+    q = q_ref[0].astype(jnp.float32) * Dh ** -0.5  # [Hkv, n_rep, Dh]
+
+    def step(c, carry):
+        m, l, acc = carry
+        cur = (first + c - c0) % 2
+        more = c + 1 < c1
+
+        # behind the slot's last slab goes the next slot's first, into the buffer this step
+        # does not compute from: no slot starts with an empty HBM queue
+        @pl.when(more | hands_on)
+        def _():
+            for d in fetch(1 - cur, jnp.where(more, s_i, after), jnp.where(more, c + 1, a0)):
                 d.start()
 
-        def step(c, carry):
-            m, l, acc = carry
-            i = c - c0
-            cur, nxt = i % 2, (i + 1) % 2
+        for d in fetch(cur, s_i, c):
+            d.wait()
 
-            @pl.when(c + 1 < c1)
-            def _():
-                for d in dma(nxt, c + 1):
-                    d.start()
-
-            for d in dma(cur, c):
-                d.wait()
-
-            k = k_buf[cur].astype(jnp.float32)            # [Hkv, chunk, Dh]
-            v = v_buf[cur].astype(jnp.float32)
-            # batched over kv heads: s [Hkv, n_rep, chunk]
-            s = jax.lax.dot_general(
-                q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
-            )
-            pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
-            valid = jnp.logical_and(pos >= lo, pos < pool_len)
-            s = jnp.where(valid, s, -1e30)
-            m_new = jnp.maximum(m, s.max(axis=2, keepdims=True))
-            p = jnp.exp(s - m_new)
-            alpha = jnp.exp(m - m_new)
-            l = l * alpha + p.sum(axis=2, keepdims=True)
-            pv = jax.lax.dot_general(                      # [Hkv, n_rep, Dh]
-                p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
-            )
-            acc = acc * alpha + pv
-            return m_new, l, acc
-
-        m0 = jnp.full((Hkv, n_rep, 1), -1e30, jnp.float32)
-        l0 = jnp.zeros((Hkv, n_rep, 1), jnp.float32)
-        acc0 = jnp.zeros((Hkv, n_rep, Dh), jnp.float32)
-        m, l, acc = jax.lax.fori_loop(c0, c1, step, (m0, l0, acc0))
-
-        def fold_one(kv, pos_valid, carry):
-            """One explicit (k, v) pair as an online-softmax step."""
-            m, l, acc = carry
-            k1, v1 = kv
-            s1 = jax.lax.dot_general(   # [Hkv, n_rep] (q pre-scaled)
-                q, k1, (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32,
-            )[..., None]
-            s1 = jnp.where(pos_valid, s1, -1e30)
-            m_new = jnp.maximum(m, s1)
-            alpha = jnp.exp(m - m_new)
-            p1 = jnp.exp(s1 - m_new)
-            return m_new, l * alpha + p1, acc * alpha + p1 * v1[:, None, :]
-
-        if staged_refs is not None:
-            # staged window: positions pool_len .. length-1 (this chunk's
-            # earlier tokens, not yet flushed to the pool), VMEM-resident.
-            # Dynamic trip count: step i has only i live entries — looping
-            # the full static window would double the serial fold chain
-            sk_ref, sv_ref = staged_refs
-
-            def staged_step(j, carry):
-                p = pool_len + j
-                return fold_one(
-                    (sk_ref[0, j].astype(jnp.float32),
-                     sv_ref[0, j].astype(jnp.float32)),
-                    p >= lo, carry,
-                )
-
-            m, l, acc = jax.lax.fori_loop(0, count, staged_step, (m, l, acc))
-
-        # fold the current token (position `length`) as a final online step:
-        # the cache stays read-only and a zero-length slot still normalizes
-        m, l, acc = fold_one(
-            (ck_ref[0].astype(jnp.float32), cv_ref[0].astype(jnp.float32)),
-            jnp.bool_(True), (m, l, acc),
+        k = k_buf[cur].astype(jnp.float32)            # [Hkv, chunk, Dh]
+        v = v_buf[cur].astype(jnp.float32)
+        # batched over kv heads: s [Hkv, n_rep, chunk]
+        s = jax.lax.dot_general(
+            q, k, (((2,), (2,)), ((0,), (0,))), preferred_element_type=jnp.float32
         )
-        o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        pos = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 2)
+        valid = jnp.logical_and(pos >= lo, pos < pool_len)
+        s = jnp.where(valid, s, -1e30)
+        m_new = jnp.maximum(m, s.max(axis=2, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(axis=2, keepdims=True)
+        pv = jax.lax.dot_general(                      # [Hkv, n_rep, Dh]
+            p, v, (((2,), (1,)), ((0,), (0,))), preferred_element_type=jnp.float32
+        )
+        acc = acc * alpha + pv
+        return m_new, l, acc
 
-    pl.run_scoped(
-        body,
-        k_buf=pltpu.VMEM((2, Hkv, chunk, Dh), k_hbm.dtype),
-        v_buf=pltpu.VMEM((2, Hkv, chunk, Dh), v_hbm.dtype),
-        sem=pltpu.SemaphoreType.DMA((2, 2)),
-    )
+    m0 = jnp.full((Hkv, n_rep, 1), -1e30, jnp.float32)
+    l0 = jnp.zeros((Hkv, n_rep, 1), jnp.float32)
+    acc0 = jnp.zeros((Hkv, n_rep, Dh), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(c0, c1, step, (m0, l0, acc0))
+
+    @pl.when(hands_on)
+    def _():
+        start_ref[0] = (first + c1 - c0) % 2
+
+    # the chunk's staged rows (positions pool_len .. length-1, VMEM-resident) and the current
+    # token (position `length`), one softmax step joined to the slabs' running m, l, acc: the
+    # cache stays read-only, and a slot with nothing cached normalises over what is shown here
+    H = Hkv * n_rep
+    scores, values = _chunk_fold(H, Hkv, W, Dh)
+    s2 = scores(q, sk_ref, ck_ref, 0, count, pool_len, lo)
+    m_new = jnp.maximum(m, s2.max(axis=1, keepdims=True).reshape(Hkv, n_rep, 1))
+    alpha, p2 = jnp.exp(m - m_new), jnp.exp(s2 - m_new.reshape(H, 1))
+    l = l * alpha + p2.sum(axis=1, keepdims=True).reshape(Hkv, n_rep, 1)
+    acc = acc * alpha + values(p2, sv_ref, cv_ref, 0)
+    o_ref[0] = (acc / l).astype(o_ref.dtype)
+
+
+def _slab_scratch(Hkv, chunk, Dh, dtype):
+    """``_kernel``'s scratch: two slab buffers each of K and V, a DMA semaphore each, and the
+    buffer a handed-on fetch went to."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [
+        pltpu.VMEM((2, Hkv, chunk, Dh), dtype),
+        pltpu.VMEM((2, Hkv, chunk, Dh), dtype),
+        pltpu.SemaphoreType.DMA((2, 2)),
+        pltpu.SMEM((1,), jnp.int32),
+    ]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "chunk"))
@@ -195,10 +235,11 @@ def ragged_decode_attention(
     """Per-slot ragged cache attention; returns o [S, H, Dh].
 
     Slot s attends cache positions [max(0, len_s + 1 - window), len_s) plus
-    the current token (its K/V arrive via ``cur_k``/``cur_v``, folded as a
-    final online-softmax step) — the cache is never written here, so the
-    engine can defer the cache write to one small scatter per step.
-    HBM traffic per step is Σ_s ceil(len_s/chunk)·chunk positions.
+    the current token (its K/V arrive via ``cur_k``/``cur_v``, folded as the
+    last softmax step) — the cache is never written here, so the engine can
+    defer the cache write to one small scatter per step. HBM traffic per step
+    is Σ_s ceil(len_s/chunk)·chunk positions; slab c of slot s is positions
+    ``c * chunk ..`` of the slot's own rows (``_kernel``: the module docstring).
 
     PRECONDITION: ``lengths[s] < maxT`` for every slot whose output is
     consumed. At ``lengths == maxT`` (only reachable via the engine's
@@ -230,15 +271,13 @@ def ragged_decode_attention(
             pl.BlockSpec(memory_space=pl.ANY),   # cv stays in HBM
         ],
         out_specs=pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, L: (s, 0, 0, 0)),
+        scratch_shapes=_slab_scratch(Hkv, chunk, Dh, ck.dtype),
     )
 
-    def kern(len_ref, q_ref, ck_ref, cv_ref, k_hbm, v_hbm, o_ref):
-        s_i = pl.program_id(0)
+    def kern(len_ref, q_ref, ck_ref, cv_ref, k_hbm, v_hbm, o_ref, *scratch):
         _kernel(
-            len_ref, q_ref, ck_ref, cv_ref,
-            k_hbm.at[pl.ds(s_i, 1)],
-            v_hbm.at[pl.ds(s_i, 1)],
-            o_ref, chunk=chunk, window=window, n_rep=n_rep,
+            len_ref, None, q_ref, ck_ref, cv_ref, None, k_hbm, v_hbm, o_ref, *scratch,
+            slab=lambda ref, s, c: ref.at[s, :, pl.ds(c * chunk, chunk)], chunk=chunk, window=window,
         )
 
     o = pl.pallas_call(
@@ -276,30 +315,31 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Ragged decode attention over a PAGED cache; returns o [S, H, Dh].
 
-    Identical math and streaming structure to ``ragged_decode_attention``
-    (one grid instance per slot, double-buffered slab DMA, online softmax,
-    current token folded as the final step) with one indirection: the DMA
-    slab size is the PAGE size, and slab c of slot s reads physical page
+    ``ragged_decode_attention``'s walk (``_kernel``: an instance a slot, two
+    slab buffers, a fetch handed from each slot to the next, online softmax,
+    one fold of the staged rows and the current token) with one indirection:
+    the DMA slab is a PAGE, and slab c of slot s reads physical page
     ``page_table[s, c]`` of layer ``layer`` of the pool. The operand is the
     WHOLE pool plus a layer index (a scalar-prefetch operand, so the decode
     step's layer scan can hand a traced one), never one layer's slice of it:
     a Mosaic call's operand needs a buffer of its own, so a slice handed in
     is a copy of a layer's pool a call, which once took two fifths of the
-    serving step (PERF.md, PR 27). HBM traffic per step is still
+    serving step (PERF.md, PR 27). HBM traffic per step is
     Σ_s ceil(len_s/page_len)·page_len positions — the pool's total size
-    L × P is irrelevant to step cost, which is the whole point: HBM footprint
-    tracks allocated pages, not slots × max_len. Entries of ``page_table``
-    beyond slot s's live pages are never read (loop bounds come from
-    ``lengths``); SWA slots skip whole pages below the window exactly as
-    the dense kernel skips slabs.
+    L × P is irrelevant to step cost: HBM footprint tracks allocated pages,
+    not slots × max_len. Entries of ``page_table`` beyond slot s's live pages
+    are never read (loop bounds come from ``lengths``; the fetch handed on
+    reads the next slot's first LIVE page); SWA slots skip whole pages below
+    the window exactly as the dense kernel skips slabs.
 
     CHUNKED DECODE STAGING: with ``staged_k/v/count``, the most recent
     ``staged_count[s]`` of the ``lengths[s]`` positions live in the staged
     buffer (this decode chunk's not-yet-flushed columns), NOT the pool —
-    the pool read stops short of them and they fold in as explicit
-    online-softmax steps from VMEM. This is what lets the engine write the
-    pool ONCE per chunk instead of once per token (the per-token scatter
-    measured −24%/chunk on v5e).
+    the pool read stops short of them and they fold in from VMEM with the
+    current token, as one softmax step whatever the count (staged row j is
+    position ``lengths - staged_count + j``, kept iff inside the window).
+    This is what lets the engine write the pool ONCE per chunk and not once
+    per token.
 
     Same PRECONDITION as the dense kernel: consumed slots have
     ``lengths[s] < max_pages * page_len`` and their pages allocated.
@@ -324,9 +364,7 @@ def paged_decode_attention(
     has_staged = staged_k is not None
     if has_staged and (staged_v is None or staged_count is None):
         raise ValueError("staged_k needs staged_v and staged_count")
-    # three scalar-prefetch operands (lengths+counts, page_table, layer). A
-    # packed single-operand variant of the first two was built and A/B'd
-    # on-chip: 342 vs 341 ms/chunk — neutral, so the simpler form ships.
+    # three scalar-prefetch operands: lengths (and counts), page_table, layer
     meta = (
         jnp.stack([lengths, staged_count], axis=1).astype(jnp.int32)
         if has_staged else lengths[:, None]
@@ -351,6 +389,7 @@ def paged_decode_attention(
             pl.BlockSpec(memory_space=pl.ANY),   # vp stays in HBM
         ],
         out_specs=pl.BlockSpec((1, Hkv, n_rep, Dh), lambda s, M, PT, LY: (s, 0, 0, 0)),
+        scratch_shapes=_slab_scratch(Hkv, page_len, Dh, kp.dtype),
     )
 
     class _Col:
@@ -363,18 +402,14 @@ def paged_decode_attention(
             return self.ref[s, self.col]
 
     def kern(meta_ref, pt_ref, layer_ref, q_ref, ck_ref, cv_ref, *rest):
-        if has_staged:
-            sk_ref, sv_ref, k_hbm, v_hbm, o_ref = rest
-            staged_refs = (sk_ref, sv_ref)
-            count_ref = _Col(meta_ref, 1)
-        else:
-            k_hbm, v_hbm, o_ref = rest
-            staged_refs = count_ref = None
+        staged_refs, rest = (rest[:2], rest[2:]) if has_staged else (None, rest)
+        k_hbm, v_hbm, o_ref, *scratch = rest
         _kernel(
-            _Col(meta_ref, 0), q_ref, ck_ref, cv_ref,
-            k_hbm.at[layer_ref[0]], v_hbm.at[layer_ref[0]], o_ref,
-            chunk=page_len, window=window, n_rep=n_rep, pt_ref=pt_ref,
-            staged_refs=staged_refs, count_ref=count_ref,
+            _Col(meta_ref, 0), _Col(meta_ref, 1) if has_staged else None, q_ref, ck_ref, cv_ref, staged_refs,
+            k_hbm, v_hbm, o_ref, *scratch,
+            # slab c of slot s is physical page page_table[s, c] of the layer: a view of the whole-pool
+            # operand, nothing copied
+            slab=lambda ref, s, c: ref.at[layer_ref[0], pt_ref[s, c]], chunk=page_len, window=window,
         )
 
     operands = [meta, page_table, jnp.reshape(layer, (1,)).astype(jnp.int32), qg, cur_k, cur_v]
@@ -465,12 +500,7 @@ def ring_decode_attention(
 
     def kern(meta_ref, layer_ref, q_ref, ck_ref, cv_ref, sk_ref, sv_ref, k_ref, v_ref, o_ref):
         first = pl.program_id(0) * block
-        # the staged rows and the current token of ALL kv heads are one small matrix a slot, rows (j, h'):
-        # a query row (h, r) takes the columns of its own head and a mask hides the others, so the fold
-        # is two matmuls and no loop (a step a row, each over [Hkv, n_rep, 1] arrays, cost a ring's read)
-        row_head = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 0) // n_rep
-        col = jax.lax.broadcasted_iota(jnp.int32, (H, (W + 1) * Hkv), 1)
-        own_head, step = row_head == col % Hkv, col // Hkv                   # step W is the current token
+        scores, values = _chunk_fold(H, Hkv, W, Dh)
 
         def one_slot(b, _):
             length, count = meta_ref[first + b, 0], meta_ref[first + b, 1]
@@ -489,23 +519,13 @@ def ring_decode_attention(
             pos = lap + row - jnp.where(row > newest, ring, 0)
             s = jnp.where((pos >= lo) & (pool_len > 0), s, -1e30)
 
-            def with_current(staged_ref, cur_ref):                           # [(W + 1) * Hkv, Dh], rows (j, h')
-                staged = staged_ref[b].astype(jnp.float32).reshape(W * Hkv, Dh)
-                return jnp.concatenate([staged, cur_ref[b].astype(jnp.float32)], axis=0)
-
-            s2 = jax.lax.dot_general(qf.reshape(H, Dh), with_current(sk_ref, ck_ref), (((1,), (1,)), ((), ())),
-                                     preferred_element_type=jnp.float32)     # [H, (W + 1) * Hkv]
-            # staged row j is position pool_len + j; the current token is always shown, so every maximum
-            # is a real score and a masked column's weight is exactly 0
-            shown = own_head & ((step == W) | ((step < count) & (pool_len + step >= lo)))
-            s2 = jnp.where(shown, s2, -1e30)
+            s2 = scores(qf, sk_ref, ck_ref, b, count, pool_len, lo)              # [H, (W + 1) * Hkv]
             m = jnp.maximum(s.max(axis=2, keepdims=True), s2.max(axis=1, keepdims=True).reshape(Hkv, n_rep, 1))
             p, p2 = jnp.exp(s - m), jnp.exp(s2 - m.reshape(H, 1))
             l = p.sum(axis=2, keepdims=True) + p2.sum(axis=1, keepdims=True).reshape(Hkv, n_rep, 1)
             acc = jax.lax.dot_general(p, v, (((2,), (1,)), ((0,), (0,))),
                                       preferred_element_type=jnp.float32)    # [Hkv, n_rep, Dh]
-            acc = acc + jax.lax.dot_general(p2, with_current(sv_ref, cv_ref), (((1,), (0,)), ((), ())),
-                                            preferred_element_type=jnp.float32).reshape(Hkv, n_rep, Dh)
+            acc = acc + values(p2, sv_ref, cv_ref, b)
             o_ref[b] = (acc / l).astype(o_ref.dtype)
             return 0
 
@@ -573,8 +593,8 @@ def sparse_paged_decode_attention(
     otherwise from ``win_lo[s]`` on (the first page of the local window is cut
     by position, not by page). Positions at or past the pool's part of the
     slot (``lengths - staged_count``) are masked; the staged window and the
-    current token fold in as explicit online-softmax steps, as in the dense
-    kernel. One grid instance a (slot, kv head): the group's query heads share
+    current token fold in as explicit online-softmax steps, a row at a time
+    (this call's own loop). One grid instance a (slot, kv head): the group's query heads share
     one list, so they share one read. Pages arrive ``SPARSE_BATCH`` at a time,
     double-buffered, each head's ``[page_len, Dh]`` slab a copy of its own.
     HBM traffic a call is Σ counts x page_len positions, whatever the context.
