@@ -474,3 +474,34 @@ class TestLatentAttentionAtTheNotesCellsShapes:
         self._named(SA.index_scores_decode, "index_scores_decode", _s((self.S, 64, 128), bf, chip), _s((self.S, 64), jnp.float32, chip),
                     _s((2, 1201, self.PAGE, 128), bf, chip), _s((), i32, chip), _s((self.S, self.MAX // self.PAGE), i32, chip),
                     _s((self.S,), i32, chip))
+
+
+class TestLatentAttentionAtTheDocqaCellsShapes:
+    """`mistral-small-4-119b.serve_docqa` (one chip's share): the two Pallas
+    calls of the dense latent path at the published widths (32 heads, a latent of
+    256 + 64 laid out in 384), found in a trace by these names: a prefill chunk
+    (a whole one of 2048 rows, and a question's 64) over 35,840 staged positions,
+    and a decode step of 64 slots that walks each slot's pages of the pool."""
+
+    S, MAX, PAGE, PAGES, ROW = 64, 35840, 1024, 577, 384
+    _named = TestLatentAttentionAtTheNotesCellsShapes._named
+
+    @pytest.mark.parametrize("rows", [2048, 64], ids=["a-whole-chunk", "a-questions-bucket"])
+    def test_the_expanded_prefill(self, chip, rows):
+        from tony_tpu.ops import latent_attention as LA
+
+        bf, i32 = jnp.bfloat16, jnp.int32
+        tiles = rows // LA.divisor(rows, 1024)
+        args = (_s((32, rows, 64), bf, chip), _s((32, rows, 64), bf, chip), _s((self.MAX, self.ROW), bf, chip),
+                _s((32, 256, 64), bf, chip), _s((32, 256, 128), bf, chip), _s((self.MAX // 512, rows, 512), jnp.int8, chip),
+                _s((tiles,), i32, chip), _s((tiles,), i32, chip))
+        self._named(functools.partial(LA.latent_prefill_attention, scale=0.195, block_q=LA.divisor(rows, 1024)), "latent_prefill", *args)
+
+    def test_the_paged_absorbed_decode(self, chip):
+        from tony_tpu.ops import latent_attention as LA
+
+        bf, i32 = jnp.bfloat16, jnp.int32
+        args = (_s((self.S, 32, self.ROW), bf, chip), _s((5, self.PAGES, self.PAGE, self.ROW), bf, chip), _s((), i32, chip),
+                _s((self.S,), i32, chip), _s((self.S, self.MAX // self.PAGE), i32, chip), _s((self.S, 128, self.ROW), bf, chip),
+                _s((), i32, chip))
+        self._named(functools.partial(LA.latent_paged_decode, r=256, scale=0.195), "latent_paged_decode", *args)

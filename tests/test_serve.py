@@ -617,6 +617,29 @@ class TestStreamWriter:
             httpd.server_close()
 
 
+def test_a_caller_that_hung_up_before_its_reply_leaves_no_traceback(capsys):
+    """A poller whose timeout passed while the process was stalled (a profiler's
+    export holds the interpreter for seconds) has closed its socket when the
+    reply is written: the handler ends that connection and prints nothing (a
+    harness that reads the replica's log for "Traceback" must not find one)."""
+    from tony_tpu.models.serving_http import _Handler
+
+    class Gone:
+        def write(self, _):
+            raise BrokenPipeError(32, "Broken pipe")
+
+        def flush(self):
+            pass
+
+    handler = _Handler.__new__(_Handler)
+    handler.request_version, handler.requestline, handler.client_address = "HTTP/1.1", "GET /stats HTTP/1.1", ("127.0.0.1", 1)
+    handler.wfile, handler.close_connection = Gone(), False
+    handler._reply(200, {"slots_active": 3})
+    assert handler.close_connection is True
+    out, err = capsys.readouterr()
+    assert "Traceback" not in out + err
+
+
 class TestServingInstruments:
     """Satellite of PR 3's obs wiring: EngineServer records queue depth,
     TTFT, per-token latency, and delivered tokens into the process metrics

@@ -456,6 +456,26 @@ def insert_latent_prefill(pools: tuple, staged: tuple, fresh_pages: jax.Array, j
     return jax.lax.fori_loop(0, n, body, tuple(pools))
 
 
+def gather_latent_prefix(staged: tuple, pools: tuple, pages: jax.Array, n: jax.Array) -> tuple:
+    """A prefix hit on latent pools: `insert_latent_prefill` the other way. The
+    matched physical pages `pages[:n]` ([max_pages], the rest unread) are copied
+    into a request's staged rows (each [L, max_len, width]) at logical pages
+    0 .. n, in place on the donated staging, the trip count traced: one compiled
+    variant for every length of prefix. `gather_prefix_into_staging` for rows
+    without a head axis; where pages are all a prefix leaves behind (no ring, no
+    index key, no recurrent state beside them) this is the whole of a hit."""
+    page_len = pools[0].shape[2]
+
+    def body(j, staged):
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                rows, jax.lax.dynamic_slice(pool, (0, pages[j], 0, 0), (pool.shape[0], 1, page_len, pool.shape[3]))[:, 0],
+                (0, j * page_len, 0))
+            for rows, pool in zip(staged, pools))
+
+    return jax.lax.fori_loop(0, n, body, tuple(staged))
+
+
 def insert_latent_rings(ring: jax.Array, tail: jax.Array, slot, true_len) -> jax.Array:
     """Admission: a request's last prefilled positions into its slot's rings.
     ring [Lw, S, R, width]; tail [Lw, W, width] holds positions true_len - W ..

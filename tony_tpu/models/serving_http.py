@@ -868,11 +868,16 @@ class _Handler(BaseHTTPRequestHandler):
 
     def _reply(self, code: int, obj: Any) -> None:
         body = json.dumps(obj).encode()
-        self.send_response(code)
-        self.send_header("Content-Type", "application/json")
-        self.send_header("Content-Length", str(len(body)))
-        self.end_headers()
-        self.wfile.write(body)
+        try:
+            self.send_response(code)
+            self.send_header("Content-Type", "application/json")
+            self.send_header("Content-Length", str(len(body)))
+            self.end_headers()
+            self.wfile.write(body)
+        except (BrokenPipeError, ConnectionResetError):
+            # the caller gave up before the answer was ready (a poller's timeout while the process was
+            # stalled): its loss, not a fault of the server's, and no traceback for the log
+            self.close_connection = True
 
     def do_GET(self) -> None:  # noqa: N802
         if self.path == "/healthz":

@@ -17,6 +17,10 @@ compute the same attention:
   heads x (2r + dr) x 2 operations. The rows are a block a slot: the positions
   an indexer chose, gathered (models/dots3_note.py), or a window layer's ring
   whole, with a validity a row; and the decode chunk's own rows beside them.
+  A DENSE layer reads every row of a slot's context (``latent_paged_decode``):
+  the same products over the slot's pages of the latent pool, walked through
+  the page table with a running softmax, a page's fetch in flight while the
+  page before it is computed from.
 
 Softmax statistics are float32 in both. `tests/test_dots3_note.py` holds the two
 forms equal.
@@ -195,6 +199,128 @@ def latent_rows_attention(q, rows, layer, valid, extra, extra_valid, *, r: int, 
                                       bytes_accessed=S * (R + E) * W * rows.dtype.itemsize),
     )(jnp.reshape(layer, (1,)).astype(jnp.int32), q, rows, valid.astype(jnp.float32)[:, None, :], extra,
       extra_valid.astype(jnp.float32)[:, None, :])
+
+
+def _latent_paged_kernel(len_ref, step_ref, pt_ref, layer_ref, q_ref, extra_ref, pool_hbm, o_ref, buf, sem, start_ref,
+                         *, r, scale, page_len):
+    """One grid instance a slot, the slots in order: ops/decode_attention._kernel's page walk over
+    rows that have no head axis. The two page buffers, their DMA semaphores and `start_ref` are the
+    call's scratch, alive over the whole grid: while a slot computes from its last page it starts the
+    fetch of the next slot's first, and that slot waits for it and starts nothing of its own (both
+    sides decide by one predicate over the same prefetched scalars). A slot with nothing in the pool
+    neither receives nor hands on."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_i, S = pl.program_id(0), pl.num_programs(0)
+    n = len_ref[s_i]
+    c1 = pl.cdiv(n, page_len)
+    before, after = jnp.maximum(s_i - 1, 0), jnp.minimum(s_i + 1, S - 1)
+    reads = c1 > 0
+    handed = (s_i > 0) & (len_ref[before] > 0) & reads
+    hands_on = (s_i + 1 < S) & (len_ref[after] > 0) & reads
+    first = jnp.where(handed, start_ref[0], 0)           # the buffer the predecessor put this slot's first page in
+
+    def fetch(b, s, c):
+        return pltpu.make_async_copy(pool_hbm.at[layer_ref[0], pt_ref[s, c]], buf.at[b], sem.at[b])
+
+    @pl.when(reads & jnp.logical_not(handed))
+    def _warm_up():
+        fetch(first, s_i, 0).start()
+
+    q = q_ref[0]                                          # [H, row], the pool's dtype
+    contract = (((1,), (1,)), ((), ()))
+
+    def page(c, carry):
+        m, l, acc = carry
+        cur = (first + c) % 2
+        more = c + 1 < c1
+
+        @pl.when(more | hands_on)
+        def _():
+            fetch(1 - cur, jnp.where(more, s_i, after), jnp.where(more, c + 1, 0)).start()
+
+        fetch(cur, s_i, c).wait()
+        rows = buf[cur]                                   # [page_len, row]: read once for every head
+        s = jax.lax.dot_general(q, rows, contract, preferred_element_type=jnp.float32) * scale
+        pos = c * page_len + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < n, s, -1e30)                  # a last page's rows past the slot's length
+        m_new = jnp.maximum(m, s.max(axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + p.sum(axis=1, keepdims=True)
+        acc = acc * alpha + jnp.dot(p.astype(rows.dtype), rows[:, :r], preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    H = q.shape[0]
+    m, l, acc = jax.lax.fori_loop(0, c1, page, (jnp.full((H, 1), -1e30, jnp.float32), jnp.zeros((H, 1), jnp.float32),
+                                                jnp.zeros((H, r), jnp.float32)))
+
+    @pl.when(hands_on)
+    def _():
+        start_ref[0] = (first + c1) % 2
+
+    # the chunk's own rows (its earlier steps' and the current token's, at index `step`): one softmax
+    # step joined to the pages' running m, l, acc, as `_chunk_fold` joins a key-value chunk's
+    extra = extra_ref[0]                                  # [E, row]
+    s2 = jax.lax.dot_general(q, extra, contract, preferred_element_type=jnp.float32) * scale
+    shown = jax.lax.broadcasted_iota(jnp.int32, s2.shape, 1) <= step_ref[0]
+    s2 = jnp.where(shown, s2, -1e30)
+    m_new = jnp.maximum(m, s2.max(axis=1, keepdims=True))
+    p2 = jnp.where(shown, jnp.exp(s2 - m_new), 0.0)
+    alpha = jnp.exp(m - m_new)
+    l = l * alpha + p2.sum(axis=1, keepdims=True)
+    acc = acc * alpha + jnp.dot(p2.astype(extra.dtype), extra[:, :r], preferred_element_type=jnp.float32)
+    o_ref[0] = acc / l
+
+
+@functools.partial(jax.jit, static_argnames=("r", "scale"))
+def latent_paged_decode(q, pool, layer, lengths, page_table, extra, step, *, r: int, scale: float):
+    """The absorbed form over EVERY row of a slot's context, through the page
+    table. q [S, H, row] (folded through W_uk, then the rotated rope part, zeros
+    where a row holds filling; any scale a position asks of the query folded
+    in); pool [L, P, page_len, row]: the WHOLE latent pool with `layer` [] the
+    index of this layer's (a slice handed to a Mosaic call would be a copy of
+    it); lengths [S]: the rows of slot s that lie in the pool, logical page j of
+    them in physical page page_table[s, j]; extra [S, E, row]: the decode
+    chunk's own rows, of which those at index <= `step` [] are read (the
+    current token's is at `step`). Returns float32 [S, H, r]: each head's
+    weighted sum of LATENTS over pool rows [0, lengths[s]) and the shown extra
+    rows (W_uv is the caller's, after the sum). HBM traffic is the slots' live
+    pages, each read once for all heads: ceil(len / page_len) * page_len * row
+    * itemsize a slot. A slot always reads a row (its current one)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    S, H, W = q.shape
+    if pool.ndim != 4 or pool.shape[3] != W or extra.shape[2] != W:
+        raise ValueError(f"pool {pool.shape} / extra {extra.shape}: the whole pool [L, P, page_len, row] with a layer "
+                         f"index and rows [S, E, row], row = the query's {W}")
+    page_len, E = pool.shape[2], extra.shape[1]
+    live_pages = -(-lengths.shape[0] * page_table.shape[1] // 2)           # the estimate's guess: half the table live
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,  # lengths [S], step [1], page_table [S, max_pages], layer [1]
+        grid=(S,),
+        in_specs=[
+            pl.BlockSpec((1, H, W), lambda s, LN, ST, PT, LY: (s, 0, 0)),
+            pl.BlockSpec((1, E, W), lambda s, LN, ST, PT, LY: (s, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),            # the pool stays in HBM, all layers of it
+        ],
+        out_specs=pl.BlockSpec((1, H, r), lambda s, LN, ST, PT, LY: (s, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, page_len, W), pool.dtype), pltpu.SemaphoreType.DMA((2,)), pltpu.SMEM((1,), jnp.int32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_paged_kernel, r=r, scale=scale, page_len=page_len),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, r), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",), vmem_limit_bytes=64 * 1024 * 1024),
+        interpret=interpret(),
+        name="latent_paged_decode",
+        cost_estimate=pl.CostEstimate(flops=2 * H * (live_pages * page_len + S * E) * (W + r),
+                                      transcendentals=H * (live_pages * page_len + S * E),
+                                      bytes_accessed=(live_pages * page_len + S * E) * W * pool.dtype.itemsize),
+    )(lengths.astype(jnp.int32), jnp.reshape(step, (1,)).astype(jnp.int32), page_table.astype(jnp.int32),
+      jnp.reshape(layer, (1,)).astype(jnp.int32), q, extra, pool)
 
 
 def expanded_attention(qn, qr, ckr, w_uk, w_uv, seen, *, scale: float):

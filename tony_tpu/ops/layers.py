@@ -8,6 +8,8 @@ collectives, quantization; see ops/attention.py, ops/quant.py).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -37,10 +39,16 @@ def rope_frequencies(
       ("llama3", factor, low_freq_factor, high_freq_factor, original_max)
         → Llama-3.1 frequency-band scaling (matches the HF implementation:
         low-frequency bands divided by factor, high-frequency bands kept,
-        the middle band smoothly interpolated).
+        the middle band smoothly interpolated),
+      ("yarn", factor, beta_fast, beta_slow, original_max, mscale, mscale_all_dim)
+        → YaRN (HF's ``_compute_yarn_parameters``, truncated correction
+        range): pair i below ``low`` keeps its frequency, above ``high`` has
+        it divided by factor, a linear ramp between; cos and sin are scaled
+        by ``yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all_dim)``.
     """
     inv_freq = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
     t = jnp.arange(max_seq, dtype=jnp.float32)
+    attention_factor = 1.0
     if scaling:
         kind = scaling[0]
         if kind == "linear":
@@ -59,10 +67,32 @@ def rope_frequencies(
                 ),
             )
             inv_freq = scaled
+        elif kind == "yarn":
+            factor, fast, slow, orig, mscale, mscale_all = (float(s) for s in scaling[1:])
+            low, high = yarn_correction_range(dim, theta, fast, slow, orig)
+            ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low) / max(high - low, 1e-3), 0.0, 1.0)
+            inv_freq = inv_freq * (1.0 - ramp) + inv_freq / factor * ramp
+            attention_factor = yarn_mscale(factor, mscale) / yarn_mscale(factor, mscale_all)
         else:
-            raise ValueError(f"unknown rope scaling kind {kind!r} (linear|llama3)")
+            raise ValueError(f"unknown rope scaling kind {kind!r} (linear|llama3|yarn)")
     freqs = jnp.outer(t, inv_freq)
+    if attention_factor != 1.0:
+        return jnp.cos(freqs) * attention_factor, jnp.sin(freqs) * attention_factor
     return jnp.cos(freqs), jnp.sin(freqs)
+
+
+def yarn_mscale(scale: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude correction: 0.1 mscale ln(scale) + 1 (1 for no stretch)."""
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_correction_range(dim: int, theta: float, beta_fast: float, beta_slow: float, original_max: float) -> tuple[int, int]:
+    """(low, high): the rotary pairs between which YaRN blends. Pair i turns
+    ``original_max theta^(-2i/dim) / 2 pi`` times over the original context;
+    ``low`` is the last pair that turns ``beta_fast`` times or more (floored),
+    ``high`` the first that turns ``beta_slow`` times or fewer (ceiled)."""
+    at = lambda turns: dim * math.log(original_max / (turns * 2.0 * math.pi)) / (2.0 * math.log(theta))
+    return max(math.floor(at(beta_fast)), 0), min(math.ceil(at(beta_slow)), dim - 1)
 
 
 def apply_rope(

@@ -71,25 +71,30 @@ def _gating(
 
     # bf16 inputs with f32 accumulation: an explicit x.astype(f32) would
     # materialize a full f32 activation copy just for this tiny projection
-    if cfg.scoring == "sigmoid":
+    if cfg.scoring not in ("softmax", "sigmoid"):
+        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {cfg.scoring!r}")
+    if cfg.scoring == "sigmoid" or (cfg.held is not None and router_w.dtype == jnp.float32):
+        # sigmoid scores, and a softmax router of a layer that holds a share of its experts (serving): the
+        # router in its own dtype at full precision: which side of a near tie a token falls decides a whole
+        # expert's part
         logits = jnp.einsum(
             "btd,de->bte", x.astype(router_w.dtype), router_w,
             preferred_element_type=jnp.float32, precision=jax.lax.Precision.HIGHEST,
         )
-        probs = jax.nn.sigmoid(logits)
-        _, gate_idx = jax.lax.top_k(probs if bias is None else probs + bias, cfg.top_k)
-        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
-        scale = cfg.routed_scale
-    elif cfg.scoring == "softmax":
+    else:
         logits = jnp.einsum(
             "btd,de->bte", x, router_w.astype(x.dtype),
             preferred_element_type=jnp.float32,
         )
+    if cfg.scoring == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+        _, gate_idx = jax.lax.top_k(probs if bias is None else probs + bias, cfg.top_k)
+        gate_vals = jnp.take_along_axis(probs, gate_idx, axis=-1)
+        scale = cfg.routed_scale
+    else:
         probs = jax.nn.softmax(logits, axis=-1)
         gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)        # [B,T,K]
         scale = 1.0
-    else:
-        raise ValueError(f"scoring must be 'softmax' or 'sigmoid', got {cfg.scoring!r}")
     gate_vals = gate_vals / jnp.maximum(gate_vals.sum(-1, keepdims=True), 1e-9)
     if scale != 1.0:
         gate_vals = gate_vals * scale
