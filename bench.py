@@ -27,9 +27,12 @@ def _build_presets():
     from tony_tpu.models import llama, mixtral
 
     # ~0.9B params: fits one 16G v5e chip with Adam + remat at seq 2048.
-    # Best measured single-chip recipe: batch 12, remat_policy="flash" (pin
-    # only the flash-kernel outputs; replay the cheap matmuls), CE fused per
-    # 1024-token chunk (the builders' rounds-1-5 ladder, older than this code).
+    # Best measured single-chip recipe: batch 12, remat_policy="flash" (rung 1
+    # of ops/attention.REMAT_LADDER: pin only the flash-kernel outputs; replay
+    # the cheap matmuls), CE fused per 1024-token chunk (the builders'
+    # rounds-1-5 ladder, older than this code and than the rungs above 1; the
+    # train loop's "auto" would choose from the device's memory, bench.py
+    # builds its own step and so names its rung).
     bench_1chip = dataclasses.replace(
         llama.LLAMA_1B, max_seq=2048, remat=True, remat_policy="flash",
         attn_impl="auto", ce_chunk=1024,

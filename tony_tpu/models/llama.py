@@ -21,6 +21,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
 from tony_tpu.ops import attention as attn_ops
@@ -44,7 +45,11 @@ class LlamaConfig:
     norm_eps: float = 1e-5
     dtype: str = "bfloat16"
     remat: bool = True
-    remat_policy: str = "full"  # full | dots (save matmul outputs, recompute the rest)
+    #: what the scanned block saves for its backward: a rung of
+    #: ops/attention.REMAT_LADDER (a tuple of names) or auto | full | flash |
+    #: dots. "auto": the train loop chooses the rung from the device's memory
+    #: (train/trainer.py); anywhere else it is "full"
+    remat_policy: str | tuple = "auto"
     attn_impl: str = "auto"   # auto | flash | reference
     cp_impl: str = "xla"      # context parallel: xla (ppermute ring) | ulysses (all-to-all)
     ce_chunk: int = 512       # fused lm-head+CE chunk length; 0 = materialize logits
@@ -269,11 +274,15 @@ def _block(
     v = jnp.einsum("btd,dh->bth", h, lp["wv"]).reshape(B, T, Hkv, Dh).transpose(0, 2, 1, 3)
     q = L.apply_rope(q, cos, sin, positions=positions)
     k = L.apply_rope(k, cos, sin, positions=positions)
+    # named for the remat ladder (ops/attention.REMAT_LADDER; so are swiglu's
+    # two products): a name is an identity that a policy can pin
+    q, k, v = (checkpoint_name(a, "attn_qkv") for a in (q, k, v))
     o = _attention(q, k, v, cfg, mesh, segment_ids=segment_ids)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, H * Dh)
     x = x + jnp.einsum("bth,hd->btd", o, lp["wo"])
     if mesh is not None:
         x = constrain(x, mesh, act_spec)
+    x = checkpoint_name(x, "attn_res")
     h = L.rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     x = x + L.swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"])
     if mesh is not None:

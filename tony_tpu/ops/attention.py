@@ -940,16 +940,24 @@ def _flash_fwd(q, k, v, causal, window):
     # Named so a remat policy can pin JUST the kernel outputs
     # (save_only_these_names("flash_o", "flash_lse")): the backward then
     # recomputes the cheap qkv matmuls but not the O(T²) flash forward.
+    # The lse kept for the backward is one lane of the kernel's: a minor
+    # dimension of _STAT_LANES floats is padded to 128 in HBM (268 MB a layer
+    # at 2 x 32 x 8192 rows for 2 MB of numbers: compile-only, PR 47).
     o = checkpoint_name(o, "flash_o")
-    lse = checkpoint_name(lse, "flash_lse")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return o, (q, k, v, o, lse)
+
+
+def _lse_lanes(lse: jax.Array) -> jax.Array:
+    """[B, H, Tq] back to the lane-replicated form the backward kernels read."""
+    return jnp.broadcast_to(lse[..., None], (*lse.shape, _STAT_LANES))
 
 
 def _flash_bwd(causal, window, res, g):
     q, k, v, o, lse = res
     Tq, Tk = q.shape[2], k.shape[2]
     bq, bk = _tuned_blocks("flash_bwd", q, k.shape[1], Tk)
-    return _flash_bwd_impl(q, k, v, o, lse, g, causal, bq, bk, None, window)
+    return _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, None, window)
 
 
 _flash_trainable.defvjp(_flash_fwd, _flash_bwd)
@@ -969,7 +977,7 @@ def _flash_seg_fwd(q, k, v, seg, causal, window):
     bq, bk = _tuned_blocks("flash_fwd", q, k.shape[1], Tk)
     o, lse = _flash_fwd_lanes(q, k, v, causal, bq, bk, seg, window)
     o = checkpoint_name(o, "flash_o")
-    lse = checkpoint_name(lse, "flash_lse")
+    lse = checkpoint_name(lse[..., 0], "flash_lse")
     return o, (q, k, v, seg, o, lse)
 
 
@@ -979,21 +987,46 @@ def _flash_seg_bwd(causal, window, res, g):
     q, k, v, seg, o, lse = res
     Tq, Tk = q.shape[2], k.shape[2]
     bq, bk = _tuned_blocks("flash_bwd", q, k.shape[1], Tk)
-    dq, dk, dv = _flash_bwd_impl(q, k, v, o, lse, g, causal, bq, bk, seg, window)
+    dq, dk, dv = _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, seg, window)
     return dq, dk, dv, np.zeros(seg.shape, jax.dtypes.float0)
 
 
 _flash_trainable_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 
 
-def remat_block(block_fn, remat: bool, policy: str = "full"):
+#: What a scanned decoder block may save for its backward: a ladder of named
+#: activations, cumulative, in order of time bought a byte. Rung 0 saves
+#: nothing (every layer's forward runs again inside its backward); rung 1 is
+#: this module's kernel outputs, with the MoE routing outputs ("moe_route",
+#: parallel/expert.py: tiny tensors whose replay re-runs the whole vector-bound
+#: gating pipeline) and the fused expert-MLP output ("moe_gemm",
+#: ops/moe_gemm.py: the one activation whose replay runs three grouped GEMMs);
+#: then the residual after attention, q/k/v after rope (models/llama.py:_block)
+#: and the two [B, T, F] products of the FFN (ops/layers.py:swiglu). A name
+#: the traced block does not hold saves nothing. train/trainer.py chooses the
+#: rung where the policy is "auto" and the loop runs on a device that reports
+#: its memory.
+_LADDER_STEPS = (
+    ("flash_o", "flash_lse", "moe_route", "moe_gemm"),
+    ("attn_res",), ("attn_qkv",), ("ffn_gate",), ("ffn_up",),
+)
+REMAT_LADDER = tuple(
+    sum(_LADDER_STEPS[:i], ()) for i in range(len(_LADDER_STEPS) + 1))
+#: the policies that are rungs by another name ("auto" outside the train loop
+#: IS "full": only the loop knows a device's memory)
+_NAMED_RUNGS = {"full": REMAT_LADDER[0], "auto": REMAT_LADDER[0], "flash": REMAT_LADDER[1]}
+
+
+def remat_block(block_fn, remat: bool, policy: str | tuple[str, ...] = "full"):
     """Wrap a scanned decoder block in the configured remat policy.
 
-    Lives here because the "flash" policy pins THIS module's checkpoint
-    names (flash_o / flash_lse from _flash_fwd) — models must not hardcode
-    them. Policies: "full" (recompute everything), "dots" (save matmul
-    outputs), "flash" (save only the flash-kernel outputs so the backward
-    never replays the O(T²) forward kernel).
+    Lives here because the ladder starts at THIS module's checkpoint names
+    (flash_o / flash_lse from _flash_fwd) — models must not hardcode them.
+    ``policy`` is a rung (a tuple of names: those values are saved, the rest
+    of the block is recomputed), one of the rungs by name ("full": nothing
+    saved; "flash": the kernel outputs, so the backward never replays the
+    O(T²) forward kernel; "auto": see REMAT_LADDER), or "dots" (XLA's own
+    policy: save matmul outputs).
     """
     if not remat:
         return block_fn
@@ -1001,27 +1034,39 @@ def remat_block(block_fn, remat: bool, policy: str = "full"):
         return jax.checkpoint(
             block_fn, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable
         )
-    if policy == "flash":
-        # also pins MoE routing outputs (parallel/expert.py names them
-        # "moe_route": tiny tensors whose recompute would re-run the whole
-        # vector-bound gating pipeline) and the fused expert-MLP kernel
-        # output ("moe_gemm", ops/moe_gemm.py): [N_rows, D] bf16 per layer
-        # — the one activation whose replay would re-run three grouped
-        # GEMMs (A/B'd +0.8 MFU pt on the moe bench preset: builders' r3
-        # run, older than this code).
-        # TONY_REMAT_EXTRA_NAMES ("a,b") appends further named activations
-        # (e.g. moe_disp / moe_combine) — the measurement ladder's knob for
-        # per-shape save-vs-replay tradeoffs without code edits.
-        names = ["flash_o", "flash_lse", "moe_route", "moe_gemm"]
-        extra = os.environ.get("TONY_REMAT_EXTRA_NAMES", "")
-        names += [n.strip() for n in extra.split(",") if n.strip()]
-        return jax.checkpoint(
-            block_fn,
-            policy=jax.checkpoint_policies.save_only_these_names(*names),
-        )
-    if policy != "full":
-        raise ValueError(f"remat_policy must be full|dots|flash, got {policy!r}")
-    return jax.checkpoint(block_fn)
+    if isinstance(policy, str):
+        if policy not in _NAMED_RUNGS:
+            raise ValueError(
+                f"remat_policy must be auto|full|dots|flash or a tuple of names, got {policy!r}")
+        policy = _NAMED_RUNGS[policy]
+    if not policy:
+        return jax.checkpoint(block_fn)
+    return jax.checkpoint(
+        block_fn, policy=jax.checkpoint_policies.save_only_these_names(*policy))
+
+
+def named_bytes(fn, *args, has_aux: bool = False) -> dict[str, int]:
+    """Bytes of the values ``fn`` names with ``checkpoint_name``, by name, at
+    the shapes of ``args`` (arrays or ShapeDtypeStructs): what a rung of
+    REMAT_LADDER keeps alive from the forward to the backward. One abstract
+    trace of the forward as differentiation runs it (a custom_vjp names its
+    outputs in its fwd rule, which a plain forward never traces); a scan's
+    body counts once an iteration, so a scanned block counts once a layer."""
+    jaxpr = jax.make_jaxpr(lambda *a: jax.vjp(fn, *a, has_aux=has_aux)[0])(*args)
+    out: dict[str, int] = {}
+
+    def walk(jaxpr, times):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "name":
+                aval = eqn.outvars[0].aval
+                out[eqn.params["name"]] = (
+                    out.get(eqn.params["name"], 0) + times * aval.size * aval.dtype.itemsize)
+            inner = times * eqn.params["length"] if eqn.primitive.name == "scan" else times
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, inner)
+
+    walk(jaxpr.jaxpr, 1)
+    return out
 
 
 def _flash_selected(impl: str, Tq: int, Tk: int) -> bool:

@@ -12,6 +12,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -115,9 +116,10 @@ def apply_rope(
 
 
 def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array, w_down: jax.Array) -> jax.Array:
-    """SwiGLU MLP: (silu(x@Wg) * (x@Wu)) @ Wd, bf16-friendly."""
-    g = jax.nn.silu(jnp.einsum("...d,df->...f", x, w_gate))
-    u = jnp.einsum("...d,df->...f", x, w_up)
+    """SwiGLU MLP: (silu(x@Wg) * (x@Wu)) @ Wd, bf16-friendly. The two
+    products carry names for the remat ladder (ops/attention.REMAT_LADDER)."""
+    g = jax.nn.silu(checkpoint_name(jnp.einsum("...d,df->...f", x, w_gate), "ffn_gate"))
+    u = checkpoint_name(jnp.einsum("...d,df->...f", x, w_up), "ffn_up")
     return jnp.einsum("...f,fd->...d", g * u, w_down)
 
 

@@ -660,8 +660,9 @@ def _ragged_expert_ffn(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, token_
     # NAMED but not saved by the default flash policy: saving xs would skip
     # the gather replay in the backward, but the PN·D/layer it costs forces
     # a smaller batch — measured net NEGATIVE (b24 32.6% / b28 33.2% pinned
-    # vs b32 33.8% unpinned). The name lets the remat ladder
-    # (TONY_REMAT_EXTRA_NAMES=moe_disp) re-test the tradeoff per shape.
+    # vs b32 33.8% unpinned). The name lets a rung of its own (a tuple of
+    # names as remat_policy: ops/attention.remat_block) re-test the tradeoff
+    # per shape.
     xs = checkpoint_name(xs, "moe_disp")
     ys = _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, tile)
     # combine in choice order: gather each (token, k) choice's row and

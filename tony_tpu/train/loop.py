@@ -9,6 +9,7 @@ restart.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import json
 import os
@@ -22,6 +23,7 @@ from tony_tpu import constants
 from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.obs import trace as obs_trace
+from tony_tpu.ops.attention import REMAT_LADDER, named_bytes
 from tony_tpu.parallel import MeshSpec
 from tony_tpu.runtime import device_facts, enable_compile_cache, init_distributed
 from tony_tpu.train.checkpoint import UrgentSaveSignal, restore_or_init
@@ -30,7 +32,9 @@ from tony_tpu.train.metrics import detect_peak_flops, flops_per_token_for_batch
 from tony_tpu.train.profiling import StepProfiler
 from tony_tpu.train.trainer import (
     OptimizerConfig,
+    REMAT_MARGIN,
     Throughput,
+    choose_remat_rung,
     make_pp_train_step,
     make_train_step,
     sharded_init,
@@ -39,6 +43,10 @@ from tony_tpu.train.trainer import (
 _FIRST_STEP_SECONDS = obs_metrics.gauge(
     "tony_train_first_step_seconds",
     "wall time of the first executed step (XLA compile + first run)")
+_REMAT_SAVED_BYTES = obs_metrics.gauge(
+    "tony_train_remat_saved_bytes",
+    "what the decoder blocks keep of the forward for the backward, bytes a "
+    "device and step (0: every layer's forward runs again in its backward)")
 _STEP_SECONDS = obs_metrics.histogram(
     "tony_train_step_seconds",
     "mean per-step wall time, sampled once per logging window")
@@ -116,6 +124,108 @@ def _drop_obs_metrics() -> None:
         os.replace(tmp, path + ".obs")
     except OSError:
         pass
+
+
+def _step_memory(executable) -> int | None:
+    """A compiled step's peak bytes on one device by the compiler's own
+    report, arguments included. Not arguments + outputs + temporaries -
+    aliased where the peak is given: the TPU compiler's temporaries are its
+    whole heap, fragmentation and all (19.1 GB for a step that runs in 14.5:
+    PERF.md section 6, PR 47)."""
+    m = executable.memory_analysis()
+    if m is None:
+        return None
+    return getattr(m, "peak_memory_in_bytes", 0) or (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes)
+
+
+def _auto_remat_step(model_module, model_cfg, mesh, opt):
+    """The train step of ``remat_policy="auto"``: the blocks save for their
+    backward the highest rung of ops/attention.REMAT_LADDER that the device's
+    memory holds (train/trainer.choose_remat_rung), chosen at the first call,
+    when the state and a batch are there to lower the step with. What runs
+    from then on is the executable the choice compiled, not a second trace."""
+
+    def step_at(policy):
+        cfg = dataclasses.replace(model_cfg, remat_policy=policy)
+        return make_train_step(functools.partial(model_module.loss_fn, cfg=cfg, mesh=mesh), opt)
+
+    def say(names, saved, free, rung, n, why):
+        _REMAT_SAVED_BYTES.set(saved)
+        obs_logging.info(
+            f"[train] remat: saves {', '.join(names) or 'nothing'} ({saved / 1e9:.2f} GB a device, "
+            f"{free / 1e9:.2f} GB free before, rung {rung} of {n}; {why})")
+
+    limits = [(d.memory_stats() or {}).get("bytes_limit") for d in jax.local_devices()]
+    if not all(limits):
+        say((), 0, 0, 0, len(REMAT_LADDER) - 1, "the device reports no bytes_limit")
+        return step_at(REMAT_LADDER[0])
+    limit = min(limits)
+
+    def choose(state, batch):
+        # one device's share of the batch, through the model with no mesh:
+        # the named values at the shapes a device holds them in
+        rows = mesh.shape.get("data", 1) * mesh.shape.get("fsdp", 1)
+        context = mesh.shape.get("context", 1)
+        shape = jax.ShapeDtypeStruct
+        share = {k: shape((max(v.shape[0] // rows, 1), (v.shape[1] - 1) // context + 1), v.dtype)
+                 for k, v in batch.items()}
+        named = named_bytes(
+            functools.partial(model_module.loss_fn, mesh=None,
+                              cfg=dataclasses.replace(model_cfg, remat_policy=REMAT_LADDER[-1])),
+            jax.tree.map(lambda p: shape(p.shape, p.dtype), state.params), share, has_aux=True)
+        reckoned = [sum(named.get(name, 0) for name in rung) for rung in REMAT_LADDER]
+        # the rungs that save something the one below does not (a family's
+        # block holds the names it holds)
+        rungs = [i for i, b in enumerate(reckoned) if i == 0 or b > reckoned[i - 1]]
+        saved = [reckoned[i] for i in rungs]
+
+        def on_fullest_device(tree) -> int:
+            held: dict[int, int] = {}
+            for leaf in jax.tree.leaves(tree):
+                for shard in leaf.addressable_shards:
+                    held[shard.device.id] = held.get(shard.device.id, 0) + shard.data.nbytes
+            return max(held.values(), default=0)
+
+        # rung 0 before any compile: the state, gradients the size of the
+        # parameters, the batch, and one block's backward, which holds the
+        # block's activations and their cotangents, some of them in float32:
+        # about three times a layer's named bytes (12.77 GB compiled at 4
+        # layers of 7B widths, of which state and gradients 9.08 and a layer's
+        # names 1.41). Where the parameters are sharded a layer's are gathered
+        # whole for its forward and for its backward, the next layer's behind
+        # each, and its gradient is whole before it is scattered: about six
+        # layers' parameters (13.44 GB compiled at 16 layers over four chips,
+        # 5.7 layers' more than this reckons without them; both compile-only,
+        # PR 47). It leans high: a report with room sends the chooser up at
+        # the price of a cache load, a compile that runs out of memory is
+        # kept by no cache and is paid at every start.
+        layers = max(getattr(model_cfg, "n_layers", 1), 1)
+        params_held = on_fullest_device(state.params)
+        params_whole = sum(leaf.nbytes for leaf in jax.tree.leaves(state.params))
+        held = (on_fullest_device(state) + params_held + on_fullest_device(batch)
+                + 3 * saved[-1] // layers
+                + (6 * params_whole // layers if params_whole > params_held else 0))
+
+        def compile_rung(i):
+            executable = step_at(REMAT_LADDER[rungs[i]]).lower(state, batch).compile()
+            return executable, _step_memory(executable)
+
+        i, executable, why = choose_remat_rung(saved, limit, held, compile_rung)
+        used = _step_memory(executable)
+        free = int(limit * (1 - REMAT_MARGIN)) - (used - saved[i] if used is not None else held)
+        say(REMAT_LADDER[rungs[i]], saved[i], free, i, len(rungs) - 1, why)
+        return executable
+
+    chosen = []
+
+    def step(state, batch):
+        if not chosen:
+            chosen.append(choose(state, batch))
+        return chosen[0](state, batch)
+
+    return step
 
 
 def run_lm_training(model_module, model_cfg, loop: LoopConfig) -> dict:
@@ -214,6 +324,8 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
             ),
             opt,
         )
+    elif getattr(model_cfg, "remat_policy", None) == "auto" and model_cfg.remat:
+        step_fn = _auto_remat_step(model_module, model_cfg, mesh, opt)
     else:
         step_fn = make_train_step(
             functools.partial(model_module.loss_fn, cfg=model_cfg, mesh=mesh), opt

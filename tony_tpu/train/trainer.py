@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Callable
+from typing import Any, Callable, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -128,6 +128,78 @@ def make_pp_train_step(
         return TrainState(params, opt_state, state.step + 1), metrics
 
     return jax.jit(train_step, donate_argnums=0)
+
+
+#: What the remat chooser leaves free of a device's ``bytes_limit``: the
+#: compiled step's report counts one program, not the batches the input
+#: pipeline holds ahead, the allocator's fragmentation or a checkpoint's
+#: copies. A constant reckoned on the chip (PERF.md section 6, PR 47), not a
+#: setting.
+REMAT_MARGIN = 0.06
+
+
+def choose_remat_rung(
+    saved: Sequence[int], limit: int | None, held: int,
+    compile_rung: Callable[[int], tuple[Any, int | None]],
+) -> tuple[int, Any, str]:
+    """Which rung of a remat ladder a train step runs: ``(rung, executable, why)``.
+
+    ``saved[i]`` is what rung i keeps of the forward for the backward, in
+    bytes a device and step, rising with i (``saved[0] == 0``: everything is
+    recomputed); ``limit`` the device's ``bytes_limit``; ``held`` a first
+    reckoning of what the step holds at rung 0 (state, gradients, batch, one
+    block's working set); ``compile_rung(i)`` compiles the step at rung i and
+    returns it with its ``memory_analysis()`` peak (None where the backend
+    gives none), raising the compiler's out-of-memory error where the step
+    does not fit at all.
+
+    The first rung tried is the highest the reckoning puts under the limit
+    less the margin. From then on the compiler's own report decides: it says
+    what rung 0 holds (the report less that rung's saved bytes), so the step
+    goes down while it is over or the compile ran out of memory, and up where
+    the report leaves room for a higher rung's bytes. Shapes, the device's
+    limit and the compile's report are equal in every process of a gang, so
+    every process reaches the same rung; that is why no host-side state (the
+    free memory at this moment, which differs between them) is read. No limit
+    (the CPU): rung 0 and no executable, the caller's jitted step runs as ever.
+    """
+    top = len(saved) - 1
+    if limit is None:
+        return 0, None, "the device reports no bytes_limit"
+    budget = int(limit * (1 - REMAT_MARGIN))
+
+    def highest(base: int) -> int:
+        return max((i for i in range(top + 1) if base + saved[i] <= budget), default=0)
+
+    done: dict[int, tuple[Any, int | None]] = {}
+    over: dict[int, str] = {}  # rung -> why it does not fit
+    rung = highest(held)
+    while True:
+        if rung not in done:
+            try:
+                done[rung] = compile_rung(rung)
+            except jax.errors.JaxRuntimeError as e:
+                if rung == 0 or "RESOURCE_EXHAUSTED" not in str(e):
+                    raise
+                over[rung] = f"rung {rung}'s compile ran out of memory"
+                rung -= 1
+                continue
+        executable, used = done[rung]
+        if used is None:
+            return rung, executable, "the compiled step reports no memory"
+        base = used - saved[rung]
+        if used > budget and rung > 0:
+            over[rung] = f"rung {rung} compiled to {used / 1e9:.2f} GB of {budget / 1e9:.2f}"
+            rung = min(rung - 1, highest(base))
+            continue
+        higher = highest(base)
+        while higher in over:
+            higher -= 1
+        if higher <= rung:
+            why = "the top rung fits" if rung == top else over.get(rung + 1) or (
+                f"rung {rung + 1} would hold {(base + saved[rung + 1]) / 1e9:.2f} GB of {budget / 1e9:.2f}")
+            return rung, executable, why
+        rung = higher
 
 
 def sharded_init(
