@@ -159,7 +159,8 @@ def test_chunked_prefill_then_decode_agree_with_the_reference(tiny, prompt_len, 
             want = tiny["ref_logits"](seq)[-1]
             assert want.max() - want[int(chunk_toks[j, slot])] < LOGIT_TOL
         # one live slot, 4 steps, 4 routed layers, top-2: the choices; the rows are those that landed on a held expert
-        rows, rows_max, choices = np.asarray(counts)
+        rows, rows_max, choices, touched = np.asarray(counts)
+        assert touched == rows                    # a token's choices are distinct experts: one slot, one row an expert it touched
         assert choices == 4 * 4 * 2 and 0 < rows_max <= rows <= choices
     assert np.asarray(cache.lengths).tolist() == [0, prompt_len + 12]
 
@@ -414,6 +415,7 @@ def test_the_engine_counts_index_positions_expert_rows_and_visible_positions(tin
     assert delta["tony_serve_index_positions_total{prefill}"] == 2 * (32 * 33 // 2 + 8 * 32 + 8 * 9 // 2)
     assert delta["tony_serve_expert_choices_total"] == 8 * 4 * 2
     assert 0 < delta["tony_serve_expert_rows_max_total"] <= delta["tony_serve_expert_rows_total"] <= 8 * 4 * 2
+    assert delta["tony_serve_experts_touched_total"] == delta["tony_serve_expert_rows_total"]      # one slot: a row an expert
     assert len(eng.done[rid]) == 9
 
 
@@ -422,11 +424,13 @@ def test_the_engine_counts_index_positions_expert_rows_and_visible_positions(tin
 #: parent commit (22d7abf) by the code of `_lowered` below: this PR adds functions to models/paged_cache.py and
 #: ops/sparse_attention.py and changes nothing those programs lower to. The two `decode_chunk`s that hold
 #: `paged_decode_attention` (llama's, exaone_moe's) were taken again at PR 44, which changed that kernel's body; their
-#: `prefill_chunk` and `insert`, and all three of minicpm_sala's (its decode reads listed pages: another call), did not move
+#: `prefill_chunk` and `insert`, and all three of minicpm_sala's (its decode reads listed pages: another call), did not move.
+#: exaone_moe's `decode_chunk` was taken again at PR 48, whose chunk returns a fourth count (the held experts a row chose:
+#: a3ffab437d62f6ac until then); its `prefill_chunk` did not move (float32 rows take `ragged_dot`, where no group is padded)
 PARENT_LOWERED = {
     "tiny-dense": {"prefill_chunk": "59bbb8e8694afa8f", "insert": "210285f3c6b88e0d", "decode_chunk": "dc951271c6e8cd2c"},
     "tiny-minicpm-sala": {"prefill_chunk": "06929982745c9738", "insert": "f871ff2b11f9c16d", "decode_chunk": "92bb0b1ff9f0801a"},
-    "tiny-exaone-moe": {"prefill_chunk": "8fb0f36a0c7df860", "insert": "91cac576a663f3e7", "decode_chunk": "a3ffab437d62f6ac"},
+    "tiny-exaone-moe": {"prefill_chunk": "8fb0f36a0c7df860", "insert": "91cac576a663f3e7", "decode_chunk": "2839be267de17b5d"},
 }
 
 

@@ -148,7 +148,8 @@ def test_chunked_prefill_then_paged_decode_agree_with_the_reference(tiny, prompt
             want = tiny["ref_logits"](seq)[-1]
             assert want.max() - want[int(chunk_toks[j, slot])] < LOGIT_TOL
         # one live slot, 4 steps, 4 routed layers, top-2: the choices; the rows are those that landed on a held expert
-        rows, rows_max, choices = np.asarray(counts)
+        rows, rows_max, choices, touched = np.asarray(counts)
+        assert touched == rows                    # a token's choices are distinct experts: one slot, one row an expert it touched
         assert choices == 4 * 4 * 2 and 0 < rows_max <= rows <= choices
     assert np.asarray(cache.lengths).tolist() == [0, prompt_len + 12]
 
@@ -352,6 +353,7 @@ def test_the_engine_counts_expert_rows_and_visible_positions(tiny):
     assert delta["tony_serve_visible_tokens_total"] == sum(int(((4 * 8 + c) / 5).sum()) for c in (contexts[:4], contexts[4:]))
     assert delta["tony_serve_expert_choices_total"] == 8 * 4 * 2
     assert 0 < delta["tony_serve_expert_rows_max_total"] <= delta["tony_serve_expert_rows_total"] <= 8 * 4 * 2
+    assert delta["tony_serve_experts_touched_total"] == delta["tony_serve_expert_rows_total"]      # one slot: a row an expert
     assert len(eng.done[rid]) == 9
 
 

@@ -148,7 +148,8 @@ def test_chunked_prefill_then_decode_agree_with_the_reference(tiny, prompt_len, 
             want = tiny["ref_logits"](seq)[-1]
             assert want.max() - want[int(chunk_toks[j, slot])] < LOGIT_TOL
         # one live slot, 4 steps, 3 layers, top-2: the choices; the rows are those that landed on a held expert
-        rows, rows_max, choices = np.asarray(counts)
+        rows, rows_max, choices, touched = np.asarray(counts)
+        assert touched == rows                    # a token's choices are distinct experts: one slot, one row an expert it touched
         assert choices == 4 * 3 * 2 and 0 < rows_max <= rows <= choices
     assert np.asarray(cache.lengths).tolist() == [0, prompt_len + 12]
 
@@ -360,6 +361,7 @@ def test_the_engine_counts_context_and_expert_rows(tiny):
     assert delta["tony_serve_context_tokens_total"] == delta["tony_serve_visible_tokens_total"] == contexts.sum()
     assert delta["tony_serve_expert_choices_total"] == 8 * 3 * 2
     assert 0 < delta["tony_serve_expert_rows_max_total"] <= delta["tony_serve_expert_rows_total"] <= 8 * 3 * 2
+    assert delta["tony_serve_experts_touched_total"] == delta["tony_serve_expert_rows_total"]      # one slot: a row an expert
     assert len(eng.done[rid]) == 9 and eng.cache.c.shape == (3, 2 * 8 + 1, PAGE, 128)
 
 
@@ -373,8 +375,9 @@ def test_the_engine_counts_context_and_expert_rows(tiny):
 #: this family's dense and shared FFNs call in prefill: a name lowers to nothing, but the lowering's count of private
 #: functions moves by one behind it (`@silu_320` -> `@silu_321`; 80 such lines in prefill, 132 in decode), so until then
 #: `prefill_chunk` read c2d5483b10cb7019 and `decode_chunk` 343d101c9f873ea1; with the `_<n>` of every `@name_<n>` taken
-#: off, both texts are the parent's (aad441a), line for line
-PARENT_LOWERED_DOTS3 = {"prefill_chunk": "87fd180e4f475ab0", "insert": "9a9d6ec16fc97060", "decode_chunk": "2ee4b1e328fd61e1"}
+#: off, both texts are the parent's (aad441a), line for line. PR 48 adds a fourth count to what `decode_chunk` returns (the
+#: held experts a row chose): 2ee4b1e328fd61e1 until then; `prefill_chunk` and `insert` did not move
+PARENT_LOWERED_DOTS3 = {"prefill_chunk": "87fd180e4f475ab0", "insert": "9a9d6ec16fc97060", "decode_chunk": "f1633cc74368550d"}
 
 
 def test_the_latent_family_before_this_one_lowers_to_the_parents_text(bench, monkeypatch):

@@ -583,3 +583,134 @@ class TestGroupedSwiGLU:
         # another layer's bank gives another answer: the index is read
         other = MG.moe_swiglu_rows(xs, wg, wu, wd, tg, 16, jnp.int32(live_rows // 16), jnp.int32(1))
         assert np.abs(np.asarray(other[:live_rows], jnp.float32) - np.asarray(want[:live_rows], jnp.float32)).max() > 0.1
+
+
+class TestAGroupWithNoRows:
+    """A held layer is forward only, and a held expert that no row chose has no
+    row tile there (parallel/expert.route_ragged): the grouped product fetches
+    the slabs of the held-and-chosen experts alone. With a backward, every
+    group keeps a tile: the expert's weight-gradient blocks are initialised at
+    its first. Choices are set by hand: router logits are 4 x the first E
+    columns of a row, which hold 2 at the first choice and 1 at the second."""
+
+    E, D, F, T, K, TILE = 8, 128, 128, 24, 2, 16
+    HELD = (1, 5)                                                        # experts 1 .. 5 of 8
+    CASES = {
+        # experts 1, 3 and 5 (the first, a middle and the last held) get no row; 2 gets two tiles, 4 one
+        "some-held-experts-unchosen": ([2] * 20 + [4] * 4, [7] * 20 + [0] * 4),
+        "no-held-expert-chosen": ([0] * 24, [7] * 24),
+        # the serve_reason shape: every held expert has rows in every step
+        "every-held-expert-chosen": ([1 + t % 5 for t in range(24)], [1 + (t + 2) % 5 for t in range(24)]),
+    }
+
+    @classmethod
+    def _rows(cls, first, second, seed=0):
+        x = np.array(jax.random.normal(jax.random.PRNGKey(seed), (cls.T, cls.D)) * 0.5)
+        x[:, :cls.E] = 0
+        x[np.arange(cls.T), first] = 2
+        x[np.arange(cls.T), second] = 1
+        return jnp.asarray(x, jnp.bfloat16), jnp.zeros((cls.D, cls.E), jnp.float32).at[:cls.E].set(4 * jnp.eye(cls.E))
+
+    @classmethod
+    def _banks(cls, experts, seed=1):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        up = lambda k: (jax.random.normal(k, (experts, cls.D, cls.F)) / cls.D ** 0.5).astype(jnp.bfloat16)
+        return up(ks[0]), up(ks[1]), (jax.random.normal(ks[2], (experts, cls.F, cls.D)) / cls.F ** 0.5).astype(jnp.bfloat16)
+
+    @staticmethod
+    def _blocks_in_range(call):
+        """Every block index of the recorded pallas_call, at every grid step, names a block its operand has."""
+        spec, operands, scalars = call
+        for m in range(spec.grid[0]):
+            for c in range(spec.grid[1]):
+                for bs, operand in zip((*spec.in_specs, spec.out_specs), (*operands, operands[0])):   # ys has xs' shape
+                    index = [int(i) for i in bs.index_map(jnp.int32(m), jnp.int32(c), *scalars)]
+                    blocks = [-(-dim // (b or 1)) for dim, b in zip(operand.shape, bs.block_shape)]
+                    assert all(0 <= i < n for i, n in zip(index, blocks)), (m, c, index, blocks)
+
+    def _held(self, monkeypatch, case):
+        """(y, rows) of the kernel's path and of ragged_dot's on the same values, and what the kernel was handed."""
+        from jax.experimental import pallas as pl
+
+        from tony_tpu.ops import moe_gemm as MG
+        from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
+
+        monkeypatch.setattr(MG, "tuned_tile", lambda *a: self.TILE)
+        seen = {}
+        rows_call, pallas_call = MG.moe_swiglu_rows, pl.pallas_call
+
+        def recorded_rows(xs, wg, wu, wd, tile_group, tile, live, *rest):
+            seen.update(tile_group=np.asarray(tile_group), live=int(live), tile=tile, rows=xs.shape[0])
+            return rows_call(xs, wg, wu, wd, tile_group, tile, live, *rest)
+
+        def recorded_pallas(kernel, *, grid_spec, **kw):
+            inner = pallas_call(kernel, grid_spec=grid_spec, **kw)
+
+            def run(tile_group, meta, *operands):
+                seen["call"] = (grid_spec, operands, (tile_group, meta))
+                return inner(tile_group, meta, *operands)
+            return run
+
+        monkeypatch.setattr(MG, "moe_swiglu_rows", recorded_rows)
+        monkeypatch.setattr(pl, "pallas_call", recorded_pallas)
+        cfg = MoEConfig(num_experts=self.E, top_k=self.K, held=self.HELD)
+        x, router = self._rows(*self.CASES[case])
+        banks = tuple(b[None] for b in self._banks(self.HELD[1]))
+        got = held_expert_ffn(x, router, None, *banks, jnp.int32(0), cfg)
+        plain = held_expert_ffn(x.astype(jnp.float32), router, None, *(b.astype(jnp.float32) for b in banks), jnp.int32(0), cfg)
+        assert seen["call"][1][0].dtype == jnp.bfloat16                  # the kernel ran once: float32 rows take ragged_dot
+        return got, plain, seen
+
+    @pytest.mark.parametrize("case", [*CASES, "training-keeps-a-tile"])
+    def test_a_group_with_no_rows(self, monkeypatch, case):
+        if case == "training-keeps-a-tile":
+            return self._training(monkeypatch)
+        (y, rows), (y_plain, rows_plain), seen = self._held(monkeypatch, case)
+        first, count = self.HELD
+        chosen = np.array([c for pair in zip(*self.CASES[case]) for c in pair])
+        want_rows = np.bincount(chosen[(chosen >= first) & (chosen < first + count)] - first, minlength=count)
+        assert np.array_equal(np.asarray(rows), want_rows) and np.array_equal(np.asarray(rows_plain), want_rows)
+        np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_plain), atol=3e-2, rtol=3e-2)
+        # the static bound stays; the live tiles are the groups' own, an expert's ceil(rows / tile), none for none
+        tiles = -(-want_rows // self.TILE)
+        assert seen["rows"] == (-(-self.T * self.K // self.TILE) + count) * self.TILE and seen["tile"] == self.TILE
+        assert seen["live"] == tiles.sum()
+        assert seen["tile_group"][:seen["live"]].tolist() == np.repeat(np.arange(count), tiles).tolist()
+        self._blocks_in_range(seen["call"])
+        if case == "some-held-experts-unchosen":
+            assert seen["live"] == 3 and (want_rows > 0).sum() == 2 and np.abs(np.asarray(y_plain)).max() > 0.1
+        elif case == "no-held-expert-chosen":
+            assert seen["live"] == 0 and np.isfinite(np.asarray(y, np.float32)).all() and not np.asarray(y, np.float32).any()
+        else:
+            # what they were when every group had a tile at least: nothing of this traffic changes
+            old = np.maximum(tiles, 1)
+            assert np.array_equal(tiles, old) and seen["live"] == old.sum() and (want_rows > 0).all()
+
+    def _training(self, monkeypatch):
+        """moe_ffn with a backward (held is None), expert 2 of 4 chosen by no token: its group is one tile
+        of padding, and the fused backward's gradients are ragged_dot's, that expert's zero."""
+        from tony_tpu.ops import moe_gemm as MG
+        from tony_tpu.parallel.expert import MoEConfig, moe_ffn, route_ragged
+
+        monkeypatch.setattr(MG, "tuned_tile", lambda *a: self.TILE)
+        E = 4
+        x, router = self._rows([0] * 20 + [3] * 4, [1] * 12 + [3] * 8 + [0] * 4)
+        router = router[:, :E].astype(jnp.bfloat16)
+        banks = self._banks(E)
+        cot = jax.random.normal(jax.random.PRNGKey(3), (1, self.T, self.D), jnp.float32)
+        sizes = np.asarray(route_ragged(x[None], router, MoEConfig(num_experts=E, top_k=self.K), tile=self.TILE)[4])
+        assert sizes.tolist() == [32, 16, 16, 16]                        # 24, 12, 0 and 12 rows: no group under a tile
+
+        def loss(dispatch, x, wg, wu, wd):
+            y, _ = moe_ffn(x[None], router, wg, wu, wd, MoEConfig(num_experts=E, top_k=self.K, dispatch=dispatch))
+            return (y.astype(jnp.float32) * cot).sum(), y
+
+        (_, y), got = jax.value_and_grad(lambda *a: loss("ragged", *a), argnums=(0, 1, 2, 3), has_aux=True)(x, *banks)
+        (_, y_plain), want = jax.value_and_grad(lambda *a: loss("ragged_xla", *a), argnums=(0, 1, 2, 3), has_aux=True)(x, *banks)
+        np.testing.assert_allclose(np.asarray(y, np.float32), np.asarray(y_plain, np.float32), atol=3e-2, rtol=3e-2)
+        for g, w in zip(got, want):
+            g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+            assert np.isfinite(g).all()
+            np.testing.assert_allclose(g, w, atol=4e-2 * np.abs(w).max(), rtol=4e-2)
+        for g in got[1:]:
+            assert not np.asarray(g[2], np.float32).any() and np.abs(np.asarray(g[0], np.float32)).max() > 0

@@ -53,7 +53,7 @@ import numpy as np
 
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 BANKS = ("we_gate", "we_up", "we_down")
@@ -516,10 +516,11 @@ def _decode_one(params, cache: LatentCache, tokens, cfg: Dots3NoteConfig, staged
 def decode_steps(params, cache: LatentCache, tokens, key, cfg: Dots3NoteConfig, n: int, temperature: float = 0.0,
                  top_k: int = 0, samp=None):
     """`n` decode steps in one compiled call: (tokens [S], all tokens [n, S],
-    cache', counts [3] int32). The pools and the rings are written once, when
+    cache', counts [4] int32). The pools and the rings are written once, when
     the chunk is over; a step reads the chunk's earlier rows from the staged
     ones. `counts` as models/exaone_moe.decode_steps: rows that landed on a held
-    expert, the fullest held expert's rows, the choices made."""
+    expert, the fullest held expert's rows, the choices made, the held experts
+    a row chose."""
     from tony_tpu.models.generate import _sample, sample_logits
     from tony_tpu.models.paged_cache import RING_SLACK, write_latent_chunk
 
@@ -536,11 +537,11 @@ def decode_steps(params, cache: LatentCache, tokens, key, cfg: Dots3NoteConfig, 
         logits, cols, rows, _ = _decode_one(params, cache, toks, cfg, (*stage, i))
         nxt = sample_logits(logits, k_step, *samp) if samp is not None else _sample(logits, k_step, temperature, top_k)
         stage = tuple(jax.lax.dynamic_update_slice(st, col[:, :, None], (0, 0, i, 0)) for st, col in zip(stage, cols))
-        counts = counts + jnp.stack([rows.sum(), rows.max(axis=1).sum(), live.sum() * cfg.top_k * rows.shape[0]])
+        counts = counts + held_step_counts(rows, live, cfg.top_k)
         return (nxt, stage, i + 1, counts), nxt
 
     (toks, (sc, ski, sring), _, counts), seq = jax.lax.scan(
-        body, (tokens, stage, jnp.int32(0), jnp.zeros((3,), jnp.int32)), jax.random.split(key, n))
+        body, (tokens, stage, jnp.int32(0), jnp.zeros((4,), jnp.int32)), jax.random.split(key, n))
     c, ki = write_latent_chunk((cache.c, cache.ki), (sc, ski), cache.lengths, cache.page_table)
     (ring,) = write_latent_chunk((cache.ring,), (sring,), cache.lengths, cache.ring_table)
     # idle slots (length 0) stay at 0, as in the dense family's step

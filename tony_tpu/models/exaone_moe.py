@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -458,11 +458,12 @@ def _decode_one(params, cache: ExaoneCache, tokens, cfg: ExaoneMoeConfig, staged
 def decode_steps(params, cache: ExaoneCache, tokens, key, cfg: ExaoneMoeConfig, n: int, temperature: float = 0.0,
                  top_k: int = 0, samp=None):
     """`n` decode steps in one compiled call: (tokens [S], all tokens [n, S],
-    cache', counts [3] int32). The pool and the rings are written once, when
+    cache', counts [4] int32). The pool and the rings are written once, when
     the chunk is over (the dense family's deferred write). `counts`, summed
-    over the chunk's steps and the routed layers, from live slots' tokens: the
-    rows that landed on a held expert, the rows of the fullest held expert (the
-    straggler a grouped product waits for), and the choices made (rows x top_k)."""
+    over the chunk's steps and the routed layers, from live slots' tokens
+    (parallel/expert.held_step_counts): the rows that landed on a held expert,
+    the rows of the fullest held expert, the choices made (rows x top_k), and
+    the held experts a row chose."""
     from tony_tpu.models.generate import _sample, sample_logits
     from tony_tpu.models.paged_cache import RING_SLACK, write_decode_chunk
 
@@ -477,11 +478,11 @@ def decode_steps(params, cache: ExaoneCache, tokens, key, cfg: ExaoneMoeConfig, 
         nxt = sample_logits(logits, k_step, *samp) if samp is not None else _sample(logits, k_step, temperature, top_k)
         sk = jax.lax.dynamic_update_slice(sk, cols_k[:, :, None], (0, 0, i, 0, 0))
         sv = jax.lax.dynamic_update_slice(sv, cols_v[:, :, None], (0, 0, i, 0, 0))
-        counts = counts + jnp.stack([rows.sum(), rows.max(axis=1).sum(), live.sum() * cfg.top_k * rows.shape[0]])
+        counts = counts + held_step_counts(rows, live, cfg.top_k)
         return (lengths, nxt, sk, sv, i + 1, counts), nxt
 
     (lengths, toks, sk, sv, _, counts), seq = jax.lax.scan(
-        body, (cache.lengths, tokens, stage, stage, jnp.int32(0), jnp.zeros((3,), jnp.int32)), jax.random.split(key, n))
+        body, (cache.lengths, tokens, stage, stage, jnp.int32(0), jnp.zeros((4,), jnp.int32)), jax.random.split(key, n))
     is_w = np.array([w > 0 for w in cfg.windows])
     k, v = write_decode_chunk(cache.k, cache.v, sk[~is_w], sv[~is_w], cache.lengths, cache.page_table)
     wk, wv = write_decode_chunk(cache.wk, cache.wv, sk[is_w], sv[is_w], cache.lengths, cache.ring_table)

@@ -49,7 +49,7 @@ import numpy as np
 
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
 
 BANKS = ("we_gate", "we_up", "we_down")
 
@@ -378,10 +378,10 @@ def _decode_one(params, cache: LatentCache, tokens, cfg: Mistral4Config, staged,
 def decode_steps(params, cache: LatentCache, tokens, key, cfg: Mistral4Config, n: int, temperature: float = 0.0,
                  top_k: int = 0, samp=None):
     """`n` decode steps in one compiled call: (tokens [S], all tokens [n, S],
-    cache', counts [3] int32). The pool is written once, when the chunk is over;
+    cache', counts [4] int32). The pool is written once, when the chunk is over;
     a step reads the chunk's earlier rows from the staged ones. `counts` as
     models/exaone_moe.decode_steps: rows that landed on a held expert, the
-    fullest held expert's rows, the choices made."""
+    fullest held expert's rows, the choices made, the held experts a row chose."""
     from tony_tpu.models.generate import _sample, sample_logits
     from tony_tpu.models.paged_cache import write_latent_chunk
 
@@ -395,11 +395,11 @@ def decode_steps(params, cache: LatentCache, tokens, key, cfg: Mistral4Config, n
         logits, col, rows = _decode_one(params, cache, toks, cfg, stage, i)
         nxt = sample_logits(logits, k_step, *samp) if samp is not None else _sample(logits, k_step, temperature, top_k)
         stage = jax.lax.dynamic_update_slice(stage, col[:, :, None], (0, 0, i, 0))
-        counts = counts + jnp.stack([rows.sum(), rows.max(axis=1).sum(), live.sum() * cfg.top_k * rows.shape[0]])
+        counts = counts + held_step_counts(rows, live, cfg.top_k)
         return (nxt, stage, i + 1, counts), nxt
 
     (toks, stage, _, counts), seq = jax.lax.scan(
-        body, (tokens, stage, jnp.int32(0), jnp.zeros((3,), jnp.int32)), jax.random.split(key, n))
+        body, (tokens, stage, jnp.int32(0), jnp.zeros((4,), jnp.int32)), jax.random.split(key, n))
     (c,) = write_latent_chunk((cache.c,), (stage,), cache.lengths, cache.page_table)
     # idle slots (length 0) stay at 0, as in the dense family's step
     lengths = jnp.where(live, jnp.minimum(cache.lengths + n, max_len), 0)

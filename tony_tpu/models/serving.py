@@ -99,13 +99,15 @@ _ADMISSIONS = obs_metrics.counter(
     "tony_serve_admissions_total",
     "requests given a slot, by what the device was doing at their insert: a decode chunk in flight, or nothing",
     labelnames=("under",))
-# a model whose layers hold part of their experts returns these three with a chunk's tokens, summed on the
+# a model whose layers hold part of their experts returns these four with a chunk's tokens, summed on the
 # device over the chunk's steps and routed layers, from live slots' rows (ServingPrograms.decode_chunk)
 _EXPERT_COUNTS = (
     obs_metrics.counter("tony_serve_expert_rows_total", "decode rows that landed on an expert this replica holds"),
     obs_metrics.counter("tony_serve_expert_rows_max_total",
                         "decode rows of the fullest held expert, a layer and step: the straggler a grouped product waits for"),
     obs_metrics.counter("tony_serve_expert_choices_total", "expert choices decode rows made (rows x experts a token)"),
+    obs_metrics.counter("tony_serve_experts_touched_total",
+                        "held experts that a live slot's decode row chose, a layer and step: the slabs the grouped product reads"),
 )
 
 
@@ -454,8 +456,8 @@ class ServingPrograms(NamedTuple):
     prefill_chunk: object     # (params, tokens [1, T], staging, take) -> (logits of row take-1 [1, V], staging')
     prefill_pad: object       # (take, prefill_chunk, room) -> padding rows of a prompt's last chunk
     insert: object            # paged: (cache, staging, fresh_pages, pt_row, slot, true_len, j0, n) -> cache'
-    # (params, cache, tokens, key, n=, temperature=, top_k=, samp=) -> (tokens, all, cache'[, expert counts [3]:
-    # held rows, the fullest held expert's rows, choices; where layers hold part of their experts])
+    # (params, cache, tokens, key, n=, temperature=, top_k=, samp=) -> (tokens, all, cache'[, expert counts [4]:
+    # held rows, the fullest held expert's rows, choices, held experts chosen; where layers hold part of their experts])
     decode_chunk: object
     release: object           # (cache, mask [S]) -> cache' with the masked slots idle
     visible_tokens: object    # (context lengths, numpy) -> cache positions a decode step may read at each

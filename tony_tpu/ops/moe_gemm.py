@@ -104,9 +104,11 @@ def _fwd_kernel(tg_ref, meta_ref, xs_ref, wg_ref, wu_ref, wd_ref, ys_ref, acc_re
     """One row tile x one block of the expert width: the block's part of
     ``silu(x Wg) * (x Wu) Wd`` added into the tile's float32 accumulator,
     which leaves for HBM with the last block. ``meta_ref[0]`` is the number
-    of live row tiles: a tile past them (slack of the static row bound) is
-    skipped, its blocks mapped onto the last live tile's so that nothing is
-    fetched or written for it."""
+    of live row tiles, the groups' own: a tile past them (the rest of the
+    static row bound) is skipped, its blocks mapped onto the last live tile's
+    so that nothing is fetched or written for it. It may be 0 (no row landed
+    on any group): every tile is skipped, all map onto tile 0's last block,
+    fetched once, and tile 0's rows come back as unspecified as the rest."""
     from jax.experimental import pallas as pl
 
     m, c = pl.program_id(0), pl.program_id(1)
@@ -219,17 +221,20 @@ def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_sw
     n_tiles, n_blocks = PN // tile, F // fb
     meta = jnp.stack([jnp.asarray(n_tiles if live is None else live, jnp.int32), jnp.asarray(layer, jnp.int32)])
 
+    def tile_of(m, meta):
+        return jnp.minimum(m, jnp.maximum(meta[0] - 1, 0))       # a skipped tile: the last live one, or tile 0 with none live
+
     def rows(m, c, tg, meta):
-        return jnp.minimum(m, meta[0] - 1), 0
+        return tile_of(m, meta), 0
 
     def block(m, c, meta):
         return jnp.where(m < meta[0], c, n_blocks - 1)
 
     def up(m, c, tg, meta):
-        return meta[1], tg[jnp.minimum(m, meta[0] - 1)], 0, block(m, c, meta)
+        return meta[1], tg[tile_of(m, meta)], 0, block(m, c, meta)
 
     def down(m, c, tg, meta):
-        return meta[1], tg[jnp.minimum(m, meta[0] - 1)], block(m, c, meta), 0
+        return meta[1], tg[tile_of(m, meta)], block(m, c, meta), 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -263,10 +268,10 @@ def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_sw
 
 def moe_swiglu_rows(xs, wg, wu, wd, tile_group, tile, live, layer=0, name="moe_swiglu_grouped"):
     """The forward alone, for serving: ``moe_swiglu_grouped``'s product over the
-    first ``live`` row tiles (a traced count); rows of later tiles are left
-    unwritten and the caller never reads them. ``wg``/``wu``/``wd`` may carry
-    a leading layer dimension, with ``layer`` the index into it. ``name`` is
-    the call's name in a trace."""
+    first ``live`` row tiles (a traced count, which may be 0); rows of later
+    tiles are left unwritten and the caller never reads them. ``wg``/``wu``/
+    ``wd`` may carry a leading layer dimension, with ``layer`` the index into
+    it. ``name`` is the call's name in a trace."""
     return _fwd_call(xs, wg, wu, wd, tile_group, tile, live, layer, name)
 
 
@@ -375,9 +380,12 @@ moe_swiglu_grouped.defvjp(_vjp_fwd, _vjp_bwd)
 def tile_group_map(group_sizes_padded: jax.Array, num_tiles: int, tile: int) -> jax.Array:
     """[E] padded group sizes → [num_tiles] expert id per row tile.
 
-    Tiles beyond ``sum(group_sizes_padded)`` clamp to the last expert —
-    they compute garbage on pad rows that nothing reads, and contribute
-    zero to every gradient (their upstream cotangent rows are zero).
+    A group of size zero (a held expert no row chose, forward only) has no
+    tile: ``side="right"`` steps over it to the next group that has rows.
+    Tiles beyond ``sum(group_sizes_padded)`` clamp to the last expert: with
+    a backward they compute garbage on pad rows that nothing reads, and
+    contribute zero to every gradient (their upstream cotangent rows are
+    zero); the forward-only call skips them (``moe_swiglu_rows``'s ``live``).
     """
     bounds = jnp.cumsum(group_sizes_padded)                       # [E]
     starts = jnp.arange(num_tiles, dtype=jnp.int32) * tile

@@ -241,17 +241,22 @@ def route_ragged(
     output or the router losses.
 
     With ``tile`` set (the Pallas fused-kernel path, ops/moe_gemm.py), each
-    group's span is padded up to a multiple of ``tile`` (and at least one
-    tile, so every expert's weight-grad block gets initialized) — pad rows
-    scatter nothing, so they keep the zero-init token index 0 and are never
-    read back by the combine. The row count becomes the STATIC
-    ``PN = (ceil(N/tile) + E) · tile ≥ sum(padded group sizes)``.
+    group's span is padded up to a multiple of ``tile`` — pad rows scatter
+    nothing, so they keep the zero-init token index 0 and are never read
+    back by the combine. Where a backward exists (``cfg.held`` is None) a
+    group with no rows still gets one tile: the backward kernel initialises
+    an expert's weight-grad block at the expert's first tile. The row count
+    becomes the STATIC ``PN = (ceil(N/tile) + E) · tile ≥ sum(padded group
+    sizes)``.
 
     With ``cfg.held = (first, count)`` only the choices that land on experts
     ``first .. first + count - 1`` are sorted: the groups are the ``count``
     held experts, a choice of an absent expert has no row (its ``dest`` is
     the row count, past every row, and its gate is zero), and the row count
-    keeps its static bound (every choice could be held).
+    keeps its static bound (every choice could be held, and every group
+    could end in a partial tile). A held layer is forward only, so a held
+    expert that no row chose has a group of size zero and no tile: the
+    kernel never fetches its slab, and the padded sizes may sum to zero.
 
     Returns (sort_tok [N or PN] int32 — flat B·T token index in
     expert-major order, dest [N] int32 — each choice's position in that
@@ -281,7 +286,8 @@ def route_ragged(
     group_sizes = counts_b.sum(axis=0)                                   # [E], sums to N
     rows = N
     if tile is not None:
-        group_sizes = jnp.maximum(-(-group_sizes // tile), 1) * tile     # ceil, >= 1 tile
+        # whole tiles; at least one where a backward has a weight-grad block to initialise
+        group_sizes = jnp.maximum(-(-group_sizes // tile), 0 if cfg.held is not None else 1) * tile
         rows = (-(-N // tile) + E) * tile                                # static upper bound
     offsets = jnp.cumsum(group_sizes) - group_sizes                      # exclusive prefix
     dest = jnp.sum(
@@ -328,8 +334,9 @@ def _expert_swiglu(xs, w_gate, w_up, w_down, group_sizes, tile, layer=None, name
     ``tile`` is set, else three jax.lax.ragged_dot grouped GEMMs.
     ``layer`` (forward only, serving): the banks are every layer's, stacked,
     and this is the index of the layer's; the kernel takes the whole stack and
-    skips the row tiles past the groups' rows (the static row bound's slack),
-    leaving them unwritten."""
+    runs the groups' own row tiles, ``sum(group_sizes) // tile`` of them (none
+    for a group of size zero, so possibly none at all), and skips the rest of
+    the static row bound, leaving those rows unwritten."""
     from tony_tpu.ops import moe_gemm
 
     if tile is not None:
@@ -569,12 +576,13 @@ def _ragged_expert_ffn_ep(
 def held_tile(cfg: MoEConfig, choices: int, tuned: int) -> int:
     """The row tile of a layer that holds part of its experts. The row bound is
     static (every choice could land here): ``ceil(choices / tile) + held``
-    tiles, of which the held experts' own are live and the rest are skipped at
-    about 3 us a grid step; a live tile costs its expert's whole slab. So the
-    tile is the tuned one while an expert expects fewer rows than it holds (a
-    decode step's 16 rows, a short prefill), and twice that beyond (a 2048-row
-    chunk: half the tiles, and an expert's slab read once, not twice). On the
-    chip at 6144 x 2048, 256 rows: 32 -> 2.18 ms a layer, 64 -> 1.77, 128 -> 1.74."""
+    tiles, of which those that hold a row are live (none for a held expert no
+    row chose) and the rest are skipped at about 3 us a grid step; a live tile
+    costs its expert's whole slab. So the tile is the tuned one while an expert
+    expects fewer rows than it holds (a decode step's 16 rows, a short prefill),
+    and twice that beyond (a 2048-row chunk: half the tiles, and an expert's
+    slab read once, not twice). On the chip at 6144 x 2048, 256 rows: 32 -> 2.18
+    ms a layer, 64 -> 1.77, 128 -> 1.74."""
     return 2 * tuned if choices // cfg.num_experts >= tuned else tuned
 
 
@@ -585,9 +593,11 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     [L, count, D, F], ``w_down`` [L, count, F, D]: the held experts' banks of
     every layer that has them, and ``layer`` [] the index of this one. Routing
     is over all ``cfg.num_experts`` (``route_ragged``); the rows sorted,
-    multiplied and combined are the choices that landed on a held expert, so
-    a step touches the held-and-chosen experts' weights and nothing else of
-    size. ``rows`` counts each held expert's real rows (its load this call),
+    multiplied and combined are the choices that landed on a held expert, and
+    a held expert that no row chose has no row tile (``route_ragged``), so a
+    step reads the held-and-chosen experts' weights and nothing else of size:
+    with no choice on any held expert the kernel runs no tile and ``y`` is
+    zero. ``rows`` counts each held expert's real rows (its load this call),
     from the tokens ``count_mask`` [T] marks (a decode step's idle slots are
     computed like any row and counted as none). ``name``: what the fused call
     is called in a trace (a decode step's and a prefill chunk's are told apart
@@ -614,6 +624,17 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     yc = jnp.where(on[:, None], ys[jnp.where(on, dest, 0)], 0).reshape(T, K, D)
     y = jnp.einsum("tkd,tk->td", yc, gate_vals.reshape(T, K).astype(ys.dtype))
     return y.astype(x.dtype), real
+
+
+def held_step_counts(rows, live, top_k: int):
+    """What a decode step adds to a chunk's counts, [4] int32: from ``rows`` [Lr,
+    count] (``held_expert_ffn``'s, of each routed layer) and the ``live`` slots
+    [S]: the rows that landed on a held expert, the rows of the fullest held
+    expert a layer (the straggler a grouped product waits for), the choices
+    made (rows x top_k), and the held experts that a live slot's row chose, a
+    layer: those whose slabs the grouped product read (an idle slot's row is
+    computed like any and may add one that is not counted)."""
+    return jnp.stack([rows.sum(), rows.max(axis=1).sum(), live.sum() * top_k * rows.shape[0], (rows > 0).sum()])
 
 
 def _ragged_expert_ffn(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, token_mask):
