@@ -61,16 +61,13 @@ class TestLayering:
         flipped = TonyConfig({keys.POOL_SCHEDULER_INDEXED: "false"})
         assert flipped.get_bool(keys.POOL_SCHEDULER_INDEXED) is False
 
-    def test_train_and_tune_keys_registered_with_defaults(self):
+    def test_train_keys_registered_with_defaults(self):
         """The r11 step-path knobs (docs/performance.md): registered,
         defaulted, and typed the way the executor reads them."""
         cfg = TonyConfig()
         assert cfg.get_int(keys.TRAIN_PREFETCH_DEPTH) == 2
         assert cfg.get_time_ms(keys.TRAIN_INPUT_WAIT_SPAN_MS) == 25
-        assert cfg.get(keys.TUNE_CACHE_FILE) == ""     # → env/per-user default
-        assert cfg.get_bool(keys.TUNE_ENABLED) is True
-        for k in (keys.TRAIN_PREFETCH_DEPTH, keys.TRAIN_INPUT_WAIT_SPAN_MS,
-                  keys.TUNE_CACHE_FILE, keys.TUNE_ENABLED):
+        for k in (keys.TRAIN_PREFETCH_DEPTH, keys.TRAIN_INPUT_WAIT_SPAN_MS):
             assert k in keys.DEFAULTS
 
     def test_layer_order_later_wins(self, tmp_path):

@@ -1,4 +1,4 @@
-"""The r11 step-path overhaul: overlapped input pipeline, kernel autotuner,
+"""The r11 step-path overhaul: overlapped input pipeline,
 goodput input_wait attribution, bench-gate movement/provenance warnings, and
 the size-1-axis collective guard.
 
@@ -8,15 +8,11 @@ Headline contracts:
   and synthetic sources);
 - a producer failure propagates to the step loop's thread and teardown is
   clean mid-run;
-- the autotuner cache round-trips to disk and the kernel entry points pick
-  winners up (with stale entries degrading to the shipped defaults);
 - `tony bench --gate` warns on a gate round whose headline metric didn't
   move vs the prior round, and on perf records without profile provenance.
 """
 
 import functools
-import json
-import os
 import threading
 import time
 
@@ -26,7 +22,6 @@ import numpy as np
 import pytest
 
 from tony_tpu.obs import goodput as obs_goodput
-from tony_tpu.ops import tune
 from tony_tpu.train.input_pipeline import InputPipeline, InputPipelineError
 
 
@@ -231,226 +226,6 @@ class TestLoopParity:
                 break
             time.sleep(0.05)
         assert not any("input-pipeline" in n for n in leaked), leaked
-
-
-# ---------------------------------------------------------------------------
-# autotuner: cache round-trip + kernel consult
-# ---------------------------------------------------------------------------
-class TestTuneCache:
-    def test_miss_then_hit_and_persistence_roundtrip(self, tmp_path):
-        path = str(tmp_path / "tune.json")
-        c = tune.TuneCache(path)
-        assert c.get("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16", kind="v5e") is None
-        c.put("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16",
-              {"block_q": 128, "block_k": 256}, ms=3.5, kind="v5e")
-        c.save()
-        # a FRESH object (new process analog) reads the same winner back
-        c2 = tune.TuneCache(path)
-        assert c2.get("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16",
-                      kind="v5e") == {"block_q": 128, "block_k": 256}
-        # different device kind / shape / dtype are misses
-        assert c2.get("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16", kind="v4") is None
-        assert c2.get("flash_fwd", (1, 2, 1, 512, 512, 64), "bfloat16", kind="v5e") is None
-        assert c2.get("flash_fwd", (1, 2, 1, 256, 256, 64), "float32", kind="v5e") is None
-
-    def test_save_merges_with_concurrent_writers(self, tmp_path):
-        path = str(tmp_path / "tune.json")
-        a, b = tune.TuneCache(path), tune.TuneCache(path)
-        a.put("moe_gemm", (8, 64, 128), "bfloat16", {"tile": 64}, kind="v5e")
-        a.save()
-        b.put("int8_matmul", (128, 256, 256), "bfloat16",
-              {"block_m": 128, "block_n": 128, "block_k": 256}, kind="v5e")
-        b.save()
-        c = tune.TuneCache(path)
-        assert c.get("moe_gemm", (8, 64, 128), "bfloat16", kind="v5e")
-        assert c.get("int8_matmul", (128, 256, 256), "bfloat16", kind="v5e")
-
-    def test_corrupt_cache_is_cold_not_fatal(self, tmp_path):
-        path = tmp_path / "tune.json"
-        path.write_text("{torn")
-        c = tune.TuneCache(str(path))
-        assert c.get("flash_fwd", (1,), "bfloat16", kind="x") is None
-        c.put("flash_fwd", (1,), "bfloat16", {"block_q": 8, "block_k": 128}, kind="x")
-        c.save()
-        assert tune.TuneCache(str(path)).get("flash_fwd", (1,), "bfloat16", kind="x")
-
-    def test_lookup_honors_disable_env(self, tmp_path, monkeypatch):
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        c = tune.TuneCache(path)
-        c.put("flash_fwd", (9,), "bfloat16", {"block_q": 8, "block_k": 128})
-        c.save()
-        assert tune.lookup("flash_fwd", (9,), "bfloat16") is not None
-        monkeypatch.setenv(tune.ENV_DISABLE, "1")
-        assert tune.lookup("flash_fwd", (9,), "bfloat16") is None
-
-    def test_persist_winners_takes_lowest_ms_per_key(self, tmp_path):
-        cache = tune.TuneCache(str(tmp_path / "t.json"))
-        rows = [
-            {"op": "flash_fwd", "shape": (1, 2, 1, 256, 256, 64),
-             "dtype": "bfloat16", "params": {"block_q": 256, "block_k": 256}, "ms": 9.0},
-            {"op": "flash_fwd", "shape": (1, 2, 1, 256, 256, 64),
-             "dtype": "bfloat16", "params": {"block_q": 128, "block_k": 128}, "ms": 4.0},
-            {"op": "flash_fwd", "shape": (1, 2, 1, 256, 256, 64),
-             "dtype": "bfloat16", "params": {"block_q": 512, "block_k": 512},
-             "ms": None, "error": "OOM"},
-        ]
-        tune.persist_winners(rows, cache)
-        got = cache.get("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16")
-        assert got == {"block_q": 128, "block_k": 128}
-
-
-class TestKernelConsult:
-    def test_flash_entry_points_pick_the_tuned_blocks_up(self, tmp_path, monkeypatch):
-        from tony_tpu.ops import attention as A
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        q = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
-        shape = (1, 2, 1, 256, 256, 64)
-        # cold cache → module defaults
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == A._block_sizes(256, 256)
-        c = tune.TuneCache(path)
-        c.put("flash_fwd", shape, "bfloat16", {"block_q": 128, "block_k": 128})
-        c.put("flash_bwd", shape, "bfloat16", {"block_q": 64, "block_k": 256})
-        c.save()
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == (128, 128)
-        # fwd and bwd are tuned independently
-        assert A._tuned_blocks("flash_bwd", q, 1, 256) == (64, 256)
-
-    def test_explicit_env_override_beats_the_cache(self, tmp_path, monkeypatch):
-        """Review-caught precedence: TONY_FLASH_BQ/BK (and TONY_MOE_TILE)
-        are the operator's explicit debugging lever — a tune-cache hit must
-        not silently win over them."""
-        from tony_tpu.ops import attention as A
-        from tony_tpu.ops import moe_gemm
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        c = tune.TuneCache(path)
-        c.put("flash_fwd", (1, 2, 1, 256, 256, 64), "bfloat16",
-              {"block_q": 128, "block_k": 128})
-        c.put("moe_gemm", (8, 64, 128), "bfloat16", {"tile": 64})
-        c.save()
-        q = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == (128, 128)
-        monkeypatch.setenv("TONY_FLASH_BQ", "256")
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == A._block_sizes(256, 256)
-        assert moe_gemm.tuned_tile(8, 64, 128, "bfloat16") == 64
-        monkeypatch.setenv("TONY_MOE_TILE", str(moe_gemm.TILE_M))
-        assert moe_gemm.tuned_tile(8, 64, 128, "bfloat16") == moe_gemm.TILE_M
-
-    def test_stale_entry_degrades_to_default_not_lowering_failure(
-            self, tmp_path, monkeypatch):
-        from tony_tpu.ops import attention as A
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        q = jnp.zeros((1, 2, 256, 64), jnp.bfloat16)
-        shape = (1, 2, 1, 256, 256, 64)
-        c = tune.TuneCache(path)
-        # 192 does not divide 256; 100 is not lane-aligned — both invalid
-        c.put("flash_fwd", shape, "bfloat16", {"block_q": 192, "block_k": 100})
-        c.save()
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == A._block_sizes(256, 256)
-
-    def test_tuned_flash_matches_reference_numerics(self, tmp_path, monkeypatch):
-        """A cache winner actually changes the kernel grid AND the math
-        stays right (interpret mode on CPU)."""
-        from tony_tpu.ops import attention as A
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        ks = jax.random.split(jax.random.PRNGKey(0), 3)
-        q = jax.random.normal(ks[0], (1, 2, 256, 64), jnp.float32) * 0.5
-        k = jax.random.normal(ks[1], (1, 1, 256, 64), jnp.float32) * 0.5
-        v = jax.random.normal(ks[2], (1, 1, 256, 64), jnp.float32) * 0.5
-        c = tune.TuneCache(path)
-        c.put("flash_fwd", (1, 2, 1, 256, 256, 64), "float32",
-              {"block_q": 128, "block_k": 128})
-        c.save()
-        assert A._tuned_blocks("flash_fwd", q, 1, 256) == (128, 128)
-        got = A.flash_attention(q, k, v, causal=True)
-        want = A.attention_reference(
-            q, A.repeat_kv(k, 2), A.repeat_kv(v, 2), causal=True)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   atol=2e-3, rtol=2e-3)
-
-    def test_int8_corrupt_cache_entry_degrades_not_crashes(self, tmp_path, monkeypatch):
-        """Review-caught: a zero/misaligned tuned block must fall back to
-        the shipped defaults, not ZeroDivisionError at trace time."""
-        from tony_tpu.ops import quant
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        x = jnp.ones((128, 256), jnp.float32)
-        qt = quant.quantize_int8(np.ones((256, 256), np.float32))
-        c = tune.TuneCache(path)
-        c.put("int8_matmul", (128, 256, 256), "float32",
-              {"block_m": 0, "block_n": -128, "block_k": 100})
-        c.save()
-        out = quant.int8_matmul(x, qt)          # must not raise
-        want = quant.int8_matmul_ref(x, qt)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
-                                   atol=1e-2, rtol=1e-2)
-
-    def test_moe_tuned_tile_validates_entries(self, tmp_path, monkeypatch):
-        from tony_tpu.ops import moe_gemm
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        assert moe_gemm.tuned_tile(8, 64, 128, "bfloat16") == moe_gemm.TILE_M
-        c = tune.TuneCache(path)
-        c.put("moe_gemm", (8, 64, 128), "bfloat16", {"tile": 64})
-        c.save()
-        assert moe_gemm.tuned_tile(8, 64, 128, "bfloat16") == 64
-        c.put("moe_gemm", (8, 64, 128), "bfloat16", {"tile": 60})  # not 8-aligned
-        c.save()
-        assert moe_gemm.tuned_tile(8, 64, 128, "bfloat16") == moe_gemm.TILE_M
-
-    def test_sweep_flash_measures_and_persists_on_this_backend(self, tmp_path, monkeypatch):
-        """The whole tony tune flow, CPU interpret mode: sweep a tiny
-        geometry, persist, and see the kernel entry point consult it."""
-        from tony_tpu.ops import attention as A
-
-        path = str(tmp_path / "tune.json")
-        monkeypatch.setenv(tune.ENV_CACHE, path)
-        rows = tune.sweep_flash(1, 2, 1, 256, 64, dtype="float32", steps=1)
-        measured = [r for r in rows if r.get("ms") is not None]
-        assert {r["op"] for r in measured} == {"flash_fwd", "flash_bwd"}
-        tune.persist_winners(rows)
-        q = jnp.zeros((1, 2, 256, 64), jnp.float32)
-        bq, bk = A._tuned_blocks("flash_fwd", q, 1, 256)
-        best = min((r for r in measured if r["op"] == "flash_fwd"),
-                   key=lambda r: r["ms"])
-        assert (bq, bk) == (best["params"]["block_q"], best["params"]["block_k"])
-
-    @pytest.mark.slow
-    def test_tune_cli_dry_run_and_persist(self, tmp_path, capsys):
-        from tony_tpu.cli.tune import main as tune_main
-
-        cache = str(tmp_path / "tune.json")
-        rc = tune_main(["--flash", "1,2,1,256,64", "--dtype", "float32",
-                        "--steps", "1", "--dry-run"])
-        assert rc == 0
-        assert not os.path.exists(cache)
-        rc = tune_main(["--flash", "1,2,1,256,64", "--dtype", "float32",
-                        "--steps", "1", "--cache", cache])
-        assert rc == 0
-        data = json.loads(open(cache).read())
-        assert any("flash_fwd" in k for k in data["entries"])
-
-    def test_tune_cli_usage_errors(self, capsys):
-        from tony_tpu.cli.tune import main as tune_main
-
-        assert tune_main([]) == 2                       # nothing to sweep
-        assert tune_main(["--flash", "1,2"]) == 2       # bad dims
-
-    def test_tune_cli_registered_in_tony_main(self, capsys):
-        from tony_tpu.cli.main import main as tony_main
-
-        assert tony_main([]) == 0
-        assert "tune" in capsys.readouterr().out
 
 
 # ---------------------------------------------------------------------------

@@ -158,20 +158,21 @@ class TestFlashAttentionInterpret:
             err = float(jnp.max(jnp.abs(a - b))) / scale
             assert err < 2e-4, f"{name} rel err {err}"
 
-    def test_block_sizes_shrink_to_divide(self):
-        # invariants hold under any TONY_FLASH_BQ/BK retuning
-        for t in (768, 2048, 512, 640):
-            bq, bk = A._block_sizes(t, t)
-            assert t % bq == 0 and t % bk == 0
-            assert bq <= min(A._BLOCK_Q, t) and bk <= min(A._BLOCK_K, t)
-        if (A._BLOCK_Q, A._BLOCK_K) == (256, 512):  # stock defaults
-            # a 768-long sequence divides 256 but not 512 — bk must halve
-            assert A._block_sizes(768, 768) == (256, 256)
-            assert A._block_sizes(512, 512) == (256, 512)
-        # awkward lengths bottom out small — flash_attention must then take
-        # the reference path, not launch a degenerate laneless grid
-        bq, bk = A._block_sizes(257, 257)
-        assert bq < 8  # degenerate → flash_attention takes the reference path
+    @pytest.mark.parametrize("t,want", [
+        (512, (256, 512)),      # the module's blocks, bq != bk
+        (768, (256, 256)),      # divides 256 but not 512: bk halves
+        (2048, (256, 512)),
+        (640, (128, 128)),
+        (8192, (256, 512)),     # the training cells' length
+        (257, (1, 1)),          # halves to nothing
+        (132, (1, 1)),          # divides itself but is no multiple of 8
+    ])
+    def test_block_sizes_shrink_to_divide(self, t, want):
+        """The largest blocks under the module's that divide the length; a
+        block under 8 rows sends every entry point to the XLA reference."""
+        bq, bk = A._block_sizes(t, t)
+        assert (bq, bk) == want
+        assert t % bq == 0 and t % bk == 0
 
     def test_awkward_length_falls_back_to_reference(self):
         # T=257: _block_sizes degenerates; flash_attention must return the
@@ -193,8 +194,7 @@ class TestFlashAttentionInterpret:
         def loss_ref(q, k, v):
             return (A.attention_reference(q, k, v, causal=True) * w).sum()
 
-        if (A._BLOCK_Q, A._BLOCK_K) == (256, 512):  # stock defaults
-            assert A._block_sizes(512, 512) == (256, 512)  # exercising bq != bk
+        assert A._block_sizes(512, 512) == (256, 512)  # exercising bq != bk
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
         for name, a, b in zip("dq dk dv".split(), gf, gr):
@@ -635,7 +635,7 @@ class TestAGroupWithNoRows:
         from tony_tpu.ops import moe_gemm as MG
         from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
 
-        monkeypatch.setattr(MG, "tuned_tile", lambda *a: self.TILE)
+        monkeypatch.setattr(MG, "TILE_M", self.TILE)
         seen = {}
         rows_call, pallas_call = MG.moe_swiglu_rows, pl.pallas_call
 
@@ -692,7 +692,7 @@ class TestAGroupWithNoRows:
         from tony_tpu.ops import moe_gemm as MG
         from tony_tpu.parallel.expert import MoEConfig, moe_ffn, route_ragged
 
-        monkeypatch.setattr(MG, "tuned_tile", lambda *a: self.TILE)
+        monkeypatch.setattr(MG, "TILE_M", self.TILE)
         E = 4
         x, router = self._rows([0] * 20 + [3] * 4, [1] * 12 + [3] * 8 + [0] * 4)
         router = router[:, :E].astype(jnp.bfloat16)
@@ -714,3 +714,105 @@ class TestAGroupWithNoRows:
             np.testing.assert_allclose(g, w, atol=4e-2 * np.abs(w).max(), rtol=4e-2)
         for g in got[1:]:
             assert not np.asarray(g[2], np.float32).any() and np.abs(np.asarray(g[0], np.float32)).max() > 0
+
+
+# What nothing outside a kernel's module can change: its block sizes. One fresh interpreter with every
+# lever that used to be read at import set to another legal value, and a tuner cache file that holds an
+# entry for each consult there was; each case reads its own line of the child's one answer.
+_LEVERS = {  # the variable: its module under tony_tpu.ops, the constant it set, another legal value
+    "TONY_FLASH_BQ": ("attention", "_BLOCK_Q", 128), "TONY_FLASH_BK": ("attention", "_BLOCK_K", 256),
+    "TONY_MOE_TILE": ("moe_gemm", "TILE_M", 64), "TONY_MOE_TILE_BWD": ("moe_gemm", "TILE_M_BWD", 64),
+    "TONY_MOE_FCHUNK": ("moe_gemm", "F_CHUNK", 256), "TONY_DECODE_CHUNK": ("decode_attention", "CHUNK", 128),
+}
+_CACHE_ENTRIES = {
+    "flash_fwd|cpu|1x1x1x256x256x64|float32": {"block_q": 128, "block_k": 128},
+    "moe_gemm|cpu|8x128x128|bfloat16": {"tile": 64},
+    "int8_matmul|cpu|256x512x256|float32": {"block_m": 128, "block_n": 128, "block_k": 256},
+}
+
+
+def _constants():
+    import importlib
+
+    return {lever: getattr(importlib.import_module(f"tony_tpu.ops.{module}"), name) for lever, (module, name, _) in _LEVERS.items()}
+
+
+_CHILD = f"""
+import importlib, json
+import jax, jax.numpy as jnp
+from jax.experimental import pallas as pl
+from tony_tpu.ops import attention as A, quant as Q
+from tony_tpu.parallel import expert as EX
+
+out = {{lever: getattr(importlib.import_module("tony_tpu.ops." + module), name) for lever, (module, name, _) in {_LEVERS!r}.items()}}
+
+def recording(fn, note):
+    def wrapped(*a, **kw):
+        note(*a, **kw)
+        return fn(*a, **kw)
+    return wrapped
+
+# each choice read where it is handed on: the forward's blocks, the routing's tile, the matmul's grid
+A._flash_fwd_impl = recording(A._flash_fwd_impl, lambda q, k, v, causal, bq, bk, *rest: out.update(flash=[bq, bk]))
+q = jax.ShapeDtypeStruct((1, 1, 256, 64), jnp.float32)
+jax.eval_shape(lambda q, k, v: A.flash_attention(q, k, v, causal=True), q, q, q)
+
+EX.route_ragged = recording(EX.route_ragged, lambda *a, tile=None, **kw: out.update(moe=tile))
+bf = jnp.bfloat16
+bank = jax.ShapeDtypeStruct((8, 128, 128), bf)
+jax.eval_shape(lambda x, r, wg, wu, wd: EX.moe_ffn(x, r, wg, wu, wd, EX.MoEConfig(num_experts=8, top_k=2)),
+               jax.ShapeDtypeStruct((1, 16, 128), bf), jax.ShapeDtypeStruct((128, 8), bf), bank, bank, bank)
+
+pl.pallas_call = recording(pl.pallas_call, lambda *a, **kw: out.update(int8=list(kw["grid"])))
+jax.eval_shape(Q.int8_matmul, jax.ShapeDtypeStruct((256, 512), jnp.float32),
+               Q.QTensor(jax.ShapeDtypeStruct((512, 256), jnp.int8), jax.ShapeDtypeStruct((256,), jnp.float32)))
+print(json.dumps(out))
+"""
+
+
+@pytest.fixture(scope="module")
+def levered_child(tmp_path_factory):
+    import json
+    import os
+    import subprocess
+    import sys
+
+    cache = tmp_path_factory.mktemp("tune") / "tune.json"
+    cache.write_text(json.dumps({"version": 1, "entries": {k: {"params": v} for k, v in _CACHE_ENTRIES.items()}}))
+    env = {**os.environ, **{lever: str(other) for lever, (_, _, other) in _LEVERS.items()},
+           "TONY_TUNE_CACHE": str(cache), "JAX_PLATFORMS": "cpu", "TONY_PALLAS_INTERPRET": "1"}
+    env.pop("TONY_TUNE_DISABLE", None)
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    done = subprocess.run([sys.executable, "-c", _CHILD], env=env, cwd=root, capture_output=True, text=True, timeout=150)
+    assert done.returncode == 0, done.stderr[-3000:]
+    return json.loads(done.stdout.splitlines()[-1])
+
+
+class TestNothingOutsideTheModuleChangesItsBlocks:
+    @pytest.mark.parametrize("lever", sorted(_LEVERS))
+    def test_a_set_lever_is_not_read(self, levered_child, lever):
+        assert levered_child[lever] == _constants()[lever] != _LEVERS[lever][2]
+
+    @pytest.mark.parametrize("choice", ["flash", "moe", "int8"])
+    def test_a_cache_entry_changes_no_choice(self, levered_child, choice):
+        from tony_tpu.ops import moe_gemm as MG
+        from tony_tpu.ops import quant as Q
+
+        source = {"flash": list(A._block_sizes(256, 256)), "moe": MG.TILE_M,
+                  "int8": [256 // Q._BLOCK_M, 256 // Q._BLOCK_N, 512 // Q._BLOCK_K]}
+        assert levered_child[choice] == source[choice]
+
+    @pytest.mark.parametrize("verb", ["tune", "nosuch"])
+    def test_no_verb_retunes_them(self, capsys, verb):
+        from tony_tpu.cli.main import main as tony_main
+
+        assert tony_main([verb]) == 2
+        assert f"unknown command {verb!r}" in capsys.readouterr().err
+
+    @pytest.mark.parametrize("key", ["tony.tune.enabled", "tony.tune.cache-file"])
+    def test_no_key_names_them(self, key):
+        """Held like any name the program never declared, and read by nothing."""
+        from tony_tpu.config import TonyConfig, keys
+
+        assert key not in keys.all_known_keys() and key not in TonyConfig()
+        assert TonyConfig({key: "false"}).get(key) == "false"

@@ -162,3 +162,29 @@ class TestExecutorEnvWiring:
         ex = TaskExecutor(env=env)
         child_env = ex.build_child_env({"worker": ["h:1"]}, {})
         assert constants.ENV_LOG_DIR not in child_env
+
+    def test_no_kernel_lever_rides_the_env_channel(self, monkeypatch, tmp_path):
+        """A kernel's block sizes are its module's: a config that still names
+        the tuner's keys exports nothing to the task."""
+        from tony_tpu.cluster.executor import TaskExecutor
+
+        staging = tmp_path / "stage"
+        staging.mkdir()
+        cfg = TonyConfig({
+            "tony.worker.instances": "1",
+            "tony.tune.cache-file": str(tmp_path / "tune.json"),
+            "tony.tune.enabled": "false",
+        })
+        cfg.freeze()
+        cfg.write_final(str(staging))
+        env = {
+            constants.ENV_APP_ID: "app",
+            constants.ENV_STAGING_DIR: str(staging),
+            constants.ENV_JOB_NAME: "worker",
+            constants.ENV_TASK_INDEX: "0",
+            constants.ENV_AM_PORT: "1",
+        }
+        for name in [n for n in os.environ if n.startswith("TONY_TUNE")]:
+            monkeypatch.delenv(name)
+        child_env = TaskExecutor(env=env).build_child_env({"worker": ["h:1"]}, {})
+        assert not [n for n in child_env if n.startswith("TONY_TUNE")]

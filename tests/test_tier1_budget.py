@@ -42,7 +42,6 @@ SLOW_SOAKS = [
     ("test_models.py", "test_loss_decreases"),
     ("test_models.py", "test_mlm_loss_and_convergence"),
     ("test_serving.py", "test_more_requests_than_slots"),
-    ("test_input_pipeline.py", "test_tune_cli_dry_run_and_persist"),
     ("test_generate.py", "test_incremental_decode_matches_full_forward"),
     ("test_cbench.py", "test_probe_at_100k_apps_names_the_next_wall"),
 ]

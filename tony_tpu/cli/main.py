@@ -70,12 +70,6 @@ def _cmd_lint(argv: list[str]) -> int:
     return lint_main(argv)
 
 
-def _cmd_tune(argv: list[str]) -> int:
-    from tony_tpu.cli.tune import main as tune_main
-
-    return tune_main(argv)
-
-
 def _cmd_chaos(argv: list[str]) -> int:
     from tony_tpu.cli.chaos import main as chaos_main
 
@@ -360,7 +354,6 @@ _COMMANDS = {
     "slo": _cmd_slo,
     "sim": _cmd_sim,
     "explain": _cmd_explain,
-    "tune": _cmd_tune,
     "loadtest": _cmd_loadtest,
     "cbench": _cmd_cbench,
 }
@@ -369,7 +362,7 @@ _COMMANDS = {
 def main(argv: list[str] | None = None) -> int:
     argv = list(sys.argv[1:] if argv is None else argv)
     if not argv or argv[0] in ("-h", "--help"):
-        print("usage: tony {submit|pool|history|history-server|bench|cbench|portal|notebook|serve|loadtest|mini|data-prep|lint|chaos|trace|profile|logs|top|resize|goodput|slo|sim|explain|tune} [options]\n")
+        print("usage: tony {submit|pool|history|history-server|bench|cbench|portal|notebook|serve|loadtest|mini|data-prep|lint|chaos|trace|profile|logs|top|resize|goodput|slo|sim|explain} [options]\n")
         print("  submit     submit and monitor a job (tony submit --help)")
         print("  pool       run a pool service + host agents on this machine (RM/NM analog)")
         print("  history    query the persistent history tier (list|show|compare|ingest|gc)")
@@ -394,7 +387,6 @@ def main(argv: list[str] | None = None) -> int:
         print("  sim        replay seeded synthetic arrivals against the live scheduler policy (invariant check),")
         print("             or recorded history with --from-history (fidelity gate + what-if counterfactuals)")
         print("  explain    render the pool scheduler's decision provenance for an app or queue (flight recorder)")
-        print("  tune       autotune Pallas kernel block sizes on this backend into the on-disk cache")
         return 0
     cmd = _COMMANDS.get(argv[0])
     if cmd is None:

@@ -376,13 +376,6 @@ class TaskExecutor:
         env[constants.ENV_INPUT_WAIT_SPAN_MS] = str(
             self.config.get_time_ms(keys.TRAIN_INPUT_WAIT_SPAN_MS, 25)
         )
-        # kernel-autotuner contract (tony.tune.*): where the tuned
-        # block-size cache lives, and the per-job kill switch
-        tune_cache = self.config.get(keys.TUNE_CACHE_FILE)
-        if tune_cache:
-            env[constants.ENV_TUNE_CACHE] = tune_cache
-        if not self.config.get_bool(keys.TUNE_ENABLED, True):
-            env[constants.ENV_TUNE_DISABLE] = "1"
         if self.config.get_bool(keys.TASK_PROFILE):
             env[constants.ENV_PROFILE_DIR] = os.path.join(
                 self.staging_dir, "profile", f"{self.job_name}_{self.index}"

@@ -512,17 +512,6 @@ TRAIN_PREFETCH_DEPTH = "tony.train.prefetch-depth"
 TRAIN_INPUT_WAIT_SPAN_MS = "tony.train.input-wait-span-ms"
 
 # ---------------------------------------------------------------------------
-# tony.tune.* — Pallas kernel autotuner (ops/tune.py, docs/performance.md)
-# ---------------------------------------------------------------------------
-# Cache of measured block-size winners keyed by (op, device kind, shape,
-# dtype); `tony tune` writes it, every kernel entry point consults it at
-# trace time. Empty → $TONY_TUNE_CACHE; neither → source constants only.
-TUNE_CACHE_FILE = "tony.tune.cache-file"
-# false → kernels ignore the cache (module-constant defaults only); the
-# per-job kill switch when a tuning looks implicated in a regression.
-TUNE_ENABLED = "tony.tune.enabled"
-
-# ---------------------------------------------------------------------------
 # tony.checkpoint.* — gang-restart-from-checkpoint (rebuild-only; SURVEY §5.3/5.4)
 # ---------------------------------------------------------------------------
 CHECKPOINT_DIR = "tony.checkpoint.dir"
@@ -724,9 +713,6 @@ DEFAULTS: dict[str, str] = {
 
     TRAIN_PREFETCH_DEPTH: "2",
     TRAIN_INPUT_WAIT_SPAN_MS: "25",
-
-    TUNE_CACHE_FILE: "",
-    TUNE_ENABLED: "true",
 
     CHECKPOINT_DIR: "",
     CHECKPOINT_INTERVAL_STEPS: "0",

@@ -92,11 +92,6 @@ ENV_PROFILE_POLL_MS = "TONY_PROFILE_POLL_MS"
 # ledger's input_wait phase.
 ENV_PREFETCH_DEPTH = "TONY_PREFETCH_DEPTH"            # from tony.train.prefetch-depth
 ENV_INPUT_WAIT_SPAN_MS = "TONY_INPUT_WAIT_SPAN_MS"    # from tony.train.input-wait-span-ms
-# Kernel-autotuner contract (tony.tune.*, docs/performance.md): the tuned
-# block-size cache file every kernel entry point consults at trace time
-# (ops/tune.py), and the kill switch that ignores it.
-ENV_TUNE_CACHE = "TONY_TUNE_CACHE"                    # from tony.tune.cache-file
-ENV_TUNE_DISABLE = "TONY_TUNE_DISABLE"                # "1" → ignore the cache
 ENV_NOTEBOOK_PORT = "NOTEBOOK_PORT"     # notebook task port (proxied by submitter)
 # Hot-spare contract (tony.elastic.spares): set → this executor parks after
 # register_spare and polls for a gang-slot assignment instead of joining as

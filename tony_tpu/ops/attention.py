@@ -17,7 +17,6 @@ Only the XLA reference path broadcasts (``repeat_kv``).
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -425,7 +424,7 @@ def flash_attention(
     ``segment_ids`` [B, T] confines attention within packed segments
     (training-shape only: Tq == Tk). ``window`` > 0: sliding-window band —
     out-of-band k blocks are skipped entirely (no DMA, no flops).
-    ``block_q``/``block_k`` default to the tuned module constants, shrunk
+    ``block_q``/``block_k`` default to the module constants, shrunk
     to divide the sequence lengths (``_block_sizes``)."""
     B, H, Tq, D = q.shape
     Hkv, Tk = k.shape[1], k.shape[2]
@@ -433,7 +432,7 @@ def flash_attention(
         raise ValueError(f"n_heads {H} must be divisible by n_kv_heads {Hkv}")
     if segment_ids is not None and Tq != Tk:
         raise ValueError(f"segment_ids requires Tq == Tk, got {Tq} vs {Tk}")
-    auto_bq, auto_bk = _tuned_blocks("flash_fwd", q, Hkv, Tk)
+    auto_bq, auto_bk = _block_sizes(Tq, Tk)
     block_q = auto_bq if block_q is None else min(block_q, Tq)
     block_k = auto_bk if block_k is None else min(block_k, Tk)
     # awkward lengths (e.g. 257) make _block_sizes halve to degenerate
@@ -873,17 +872,12 @@ def _flash_bwd_impl(
 # loses twice over in loop steps. The streaming dkv pays by the grid step, so
 # it takes more q rows a step (`_DKV_STREAM_BLOCK_Q`): 19.09 ms at 256 q rows
 # x 512, 13.77 at 512, 12.14 at 1024, 14.36 at 2048 (1.5 x the band's pairs).
-# Env-overridable for per-hardware tuning.
-_BLOCK_Q = int(os.environ.get("TONY_FLASH_BQ", "256"))
-_BLOCK_K = int(os.environ.get("TONY_FLASH_BK", "512"))
-if _BLOCK_Q < 8 or _BLOCK_Q % 8:
-    raise ValueError(f"TONY_FLASH_BQ must be a multiple of 8 >= 8, got {_BLOCK_Q}")
-if _BLOCK_K < 128 or _BLOCK_K % 128:
-    raise ValueError(f"TONY_FLASH_BK must be a multiple of 128 >= 128, got {_BLOCK_K}")
+_BLOCK_Q = 256
+_BLOCK_K = 512
 
 
 def _block_sizes(Tq: int, Tk: int) -> tuple[int, int]:
-    """Largest blocks ≤ the configured defaults that DIVIDE the sequence
+    """Largest blocks ≤ `_BLOCK_Q` / `_BLOCK_K` that DIVIDE the sequence
     lengths (halving until they do). With bq ≠ bk defaults, a length like
     768 divides 256 but not 512 — every kernel entry point must agree on
     this rule or the grid reads padded garbage past the last block."""
@@ -904,28 +898,6 @@ def _block_sizes(Tq: int, Tk: int) -> tuple[int, int]:
     return bq, bk
 
 
-def _tuned_blocks(op: str, q: jax.Array, kv_heads: int, Tk: int) -> tuple[int, int]:
-    """Autotuner-aware block sizes: an ops/tune.py cache hit for this exact
-    (device, geometry, dtype) — validated against the kernels' lowering
-    preconditions, so a stale entry degrades to the default instead of a
-    Mosaic failure — else the tuned module constants via ``_block_sizes``.
-    Trace-time only (the blocks are static kernel parameters)."""
-    B, H, Tq, D = (int(d) for d in q.shape)
-    if "TONY_FLASH_BQ" in os.environ or "TONY_FLASH_BK" in os.environ:
-        # an EXPLICIT env override is the operator's debugging lever — it
-        # must beat the tune cache (which otherwise wins silently)
-        return _block_sizes(Tq, Tk)
-    from tony_tpu.ops import tune
-
-    params = tune.lookup(op, (B, H, int(kv_heads), Tq, int(Tk), D), str(q.dtype))
-    if params:
-        bq, bk = int(params.get("block_q", 0)), int(params.get("block_k", 0))
-        if (bq >= 8 and bk >= 128 and not (bq % 8 or bk % 128)
-                and not (Tq % bq or Tk % bk)):
-            return bq, bk
-    return _block_sizes(Tq, Tk)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_trainable(q, k, v, causal, window=0):
     return flash_attention(q, k, v, causal=causal, window=window)
@@ -935,7 +907,7 @@ def _flash_fwd(q, k, v, causal, window):
     from jax.ad_checkpoint import checkpoint_name
 
     Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _tuned_blocks("flash_fwd", q, k.shape[1], Tk)
+    bq, bk = _block_sizes(Tq, Tk)
     o, lse = _flash_fwd_lanes(q, k, v, causal, bq, bk, None, window)
     # Named so a remat policy can pin JUST the kernel outputs
     # (save_only_these_names("flash_o", "flash_lse")): the backward then
@@ -956,7 +928,7 @@ def _lse_lanes(lse: jax.Array) -> jax.Array:
 def _flash_bwd(causal, window, res, g):
     q, k, v, o, lse = res
     Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _tuned_blocks("flash_bwd", q, k.shape[1], Tk)
+    bq, bk = _block_sizes(Tq, Tk)
     return _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, None, window)
 
 
@@ -966,7 +938,7 @@ _flash_trainable.defvjp(_flash_fwd, _flash_bwd)
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
 def _flash_trainable_seg(q, k, v, seg, causal, window=0):
     """Packed-sequence variant: seg [B, T] int; cotangent for seg is float0."""
-    bq, bk = _tuned_blocks("flash_fwd", q, k.shape[1], k.shape[2])
+    bq, bk = _block_sizes(q.shape[2], k.shape[2])
     return _flash_fwd_impl(q, k, v, causal, bq, bk, seg, window)[0]
 
 
@@ -974,7 +946,7 @@ def _flash_seg_fwd(q, k, v, seg, causal, window):
     from jax.ad_checkpoint import checkpoint_name
 
     Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _tuned_blocks("flash_fwd", q, k.shape[1], Tk)
+    bq, bk = _block_sizes(Tq, Tk)
     o, lse = _flash_fwd_lanes(q, k, v, causal, bq, bk, seg, window)
     o = checkpoint_name(o, "flash_o")
     lse = checkpoint_name(lse[..., 0], "flash_lse")
@@ -986,7 +958,7 @@ def _flash_seg_bwd(causal, window, res, g):
 
     q, k, v, seg, o, lse = res
     Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _tuned_blocks("flash_bwd", q, k.shape[1], Tk)
+    bq, bk = _block_sizes(Tq, Tk)
     dq, dk, dv = _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, seg, window)
     return dq, dk, dv, np.zeros(seg.shape, jax.dtypes.float0)
 

@@ -45,7 +45,6 @@ the engines against the XLA masked-attention decode path.
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -53,10 +52,8 @@ import jax.numpy as jnp
 from tony_tpu.ops.interpret import interpret
 
 # cache positions a DMA slab of the DENSE cache holds (a paged cache's slab is its page); shrunk by
-# halving to divide the cache length. Env-tunable.
-CHUNK = int(os.environ.get("TONY_DECODE_CHUNK", "256"))
-if CHUNK < 8:  # fail at import, not inside a jit trace
-    raise ValueError(f"TONY_DECODE_CHUNK={CHUNK}: DMA slab must be >= 8 positions")
+# halving to divide the cache length.
+CHUNK = 256
 
 
 def _chunk_fold(H, Hkv, W, Dh):

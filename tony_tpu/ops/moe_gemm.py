@@ -30,7 +30,6 @@ accumulators (3·D·F·4 ≈ 25 MB); the row-tile buffers scale with TILE_M
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -42,12 +41,11 @@ from tony_tpu.ops.interpret import interpret
 # measured optimum on v5e at the bench geometry (same-session ladder:
 # 64→36.2%, 96→38.1%, 128→38.4%, 256→36.9%, 512→36.8% active MFU — less
 # group-padding waste and tighter pipelining beat bigger GEMM tiles).
-# (The builders' r3 ladder, older than this code.) Env-overridable for
-# per-hardware tuning.
-TILE_M = int(os.environ.get("TONY_MOE_TILE", "128"))
-# bwd row-tile (more VMEM-hungry: f32 dW accumulators); must divide TILE_M
-# when smaller (the backward splits fwd tiles into bwd tiles)
-TILE_M_BWD = int(os.environ.get("TONY_MOE_TILE_BWD", "128"))
+# (The builders' r3 ladder, older than this code.)
+TILE_M = 128
+# bwd row-tile (more VMEM-hungry: f32 dW accumulators); a fwd tile larger than
+# this must be a multiple of it (the backward splits fwd tiles into bwd tiles)
+TILE_M_BWD = 128
 # fwd F-chunking: >0 splits the expert MLP's hidden dim into chunks of this
 # size — per chunk: gate/up GEMMs, the silu·mul on the VPU, and a chunked
 # down-GEMM accumulating [tile, D] in f32. The monolithic kernel serializes
@@ -56,44 +54,7 @@ TILE_M_BWD = int(os.environ.get("TONY_MOE_TILE_BWD", "128"))
 # tail. r4 same-session ladder (active MFU, 2 reps): 0 → 38.18/37.92,
 # 512 → 38.25/38.25, 1024 → 38.13/38.09 — 512 never loses, ships as
 # default; shapes where F % F_CHUNK != 0 fall back to monolithic.
-F_CHUNK = int(os.environ.get("TONY_MOE_FCHUNK", "512"))
-if F_CHUNK and (F_CHUNK < 128 or F_CHUNK % 128):
-    raise ValueError(f"TONY_MOE_FCHUNK={F_CHUNK}: must be 0 or a multiple of 128 >= 128")
-
-# fail at import, not deep inside Mosaic lowering or the first backward
-for _name, _t in (("TONY_MOE_TILE", TILE_M), ("TONY_MOE_TILE_BWD", TILE_M_BWD)):
-    if _t < 8 or _t % 8:
-        raise ValueError(f"{_name}={_t}: row tiles must be positive multiples of 8")
-if TILE_M > TILE_M_BWD and TILE_M % TILE_M_BWD:
-    raise ValueError(
-        f"TONY_MOE_TILE={TILE_M} is larger than but not a multiple of "
-        f"TONY_MOE_TILE_BWD={TILE_M_BWD}: the backward cannot split the "
-        "padded group spans — pick a multiple (or set them equal)"
-    )
-# NOTE: TILE_M_BWD > TILE_M is legal — it simply never applies for calls at
-# the default fwd tile (the backward only SPLITS fwd tiles), but a caller
-# passing an explicitly larger ``tile=`` still gets the coarser bwd split.
-
-
-def tuned_tile(E: int, D: int, F: int, dtype) -> int:
-    """``TILE_M``, overridden by an ops/tune.py cache hit for this expert
-    geometry on this device. Validated against the row-tile preconditions
-    (positive multiple of 8; splittable by TILE_M_BWD when larger) so a
-    stale cache entry degrades to the default instead of failing lowering.
-    Callers pick the tile ONCE per MoE layer call (parallel/expert.py) —
-    it also sets the routing's group padding, so it must be chosen before
-    route_ragged, not inside the kernel."""
-    if "TONY_MOE_TILE" in os.environ:
-        # an EXPLICIT env override is the operator's debugging lever — it
-        # must beat the tune cache (which otherwise wins silently)
-        return TILE_M
-    from tony_tpu.ops import tune
-
-    params = tune.lookup("moe_gemm", (E, D, F), str(dtype))
-    t = int(params.get("tile", 0)) if params else 0
-    if t < 8 or t % 8 or (t > TILE_M_BWD and t % TILE_M_BWD):
-        return TILE_M
-    return t
+F_CHUNK = 512
 
 
 def _silu(x):
@@ -352,10 +313,10 @@ def _vjp_bwd(tile, res, dy):
     xs, wg, wu, wd, tile_group = res
     bwd_tile = tile
     if tile > TILE_M_BWD:
-        if tile % TILE_M_BWD:  # import checks cover defaults; tile is a call arg
+        if tile % TILE_M_BWD:  # tile is a call arg
             raise ValueError(
                 f"tile={tile} is larger than but not a multiple of "
-                f"TONY_MOE_TILE_BWD={TILE_M_BWD}: the backward cannot split "
+                f"TILE_M_BWD={TILE_M_BWD}: the backward cannot split "
                 "the padded group spans — pick a multiple (or set them equal)"
             )
         # finer backward tiling: same group spans (TILE_M_BWD divides the

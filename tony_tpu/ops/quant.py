@@ -70,21 +70,22 @@ def _quant_matmul_kernel(x_ref, q_ref, s_ref, o_ref, acc_ref, *, n_k: int):
         o_ref[:] = (acc_ref[:] * s_ref[:][0]).astype(o_ref.dtype)
 
 
+# the blocks a caller gets who names none (the builders' r3 ladder on a v5e, older than this code)
+_BLOCK_M, _BLOCK_N, _BLOCK_K = 256, 256, 512
+
+
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k"))
 def int8_matmul(
     x: jax.Array,
     qt: QTensor,
     *,
-    block_m: int | None = None,
-    block_n: int | None = None,
-    block_k: int | None = None,
+    block_m: int = _BLOCK_M,
+    block_n: int = _BLOCK_N,
+    block_k: int = _BLOCK_K,
 ) -> jax.Array:
     """Fused dequant matmul: x [M, K] (or [..., K]) @ QTensor[K, N] → [..., N].
 
-    Falls back to the XLA reference when shapes don't tile evenly. Blocks
-    left unset resolve to an ops/tune.py cache hit for this (M, K, N) on
-    this device, else the measured defaults (256, 256, 512) — resolution is
-    trace-time (the blocks are static kernel parameters).
+    Falls back to the XLA reference when shapes don't tile evenly.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -94,24 +95,6 @@ def int8_matmul(
     N = qt.q.shape[1]
     xm = x.reshape(-1, K)
     M = xm.shape[0]
-    if block_m is None or block_n is None or block_k is None:
-        from tony_tpu.ops import tune
-
-        tuned = tune.lookup("int8_matmul", (M, K, N), str(x.dtype)) or {}
-
-        def _pick(given, key, default, align):
-            # explicit caller blocks pass through; TUNED values must satisfy
-            # the kernel's alignment preconditions or they degrade to the
-            # shipped default (a corrupt cache entry — 0, negative, odd —
-            # must never turn into a trace-time ZeroDivisionError)
-            if given is not None:
-                return given
-            t = int(tuned.get(key, 0) or 0)
-            return t if t >= align and t % align == 0 else default
-
-        block_m = _pick(block_m, "block_m", 256, 8)
-        block_n = _pick(block_n, "block_n", 256, 128)
-        block_k = _pick(block_k, "block_k", 512, 128)
     bm, bn, bk = min(block_m, M), min(block_n, N), min(block_k, K)
     # TPU minimum-tile alignment (8 sublanes × 128 lanes for f32 blocks) in
     # addition to even tiling — sub-tile blocks would fail Mosaic lowering

@@ -498,11 +498,7 @@ def _ragged_expert_ffn_ep(
     K = cfg.top_k
     from tony_tpu.ops import moe_gemm
 
-    tile = (
-        moe_gemm.tuned_tile(cfg.num_experts, D, w_gate.shape[-1], x.dtype)
-        if _kernel_eligible(cfg, D, w_gate.shape[-1], x.dtype)
-        else None
-    )
+    tile = moe_gemm.TILE_M if _kernel_eligible(cfg, D, w_gate.shape[-1], x.dtype) else None
     batch_axes = tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
 
     def body(x_l, router_l, wg_l, wu_l, wd_l, tm_l):
@@ -573,17 +569,17 @@ def _ragged_expert_ffn_ep(
     return fn(x, router_w, w_gate, w_up, w_down, tm)
 
 
-def held_tile(cfg: MoEConfig, choices: int, tuned: int) -> int:
+def held_tile(cfg: MoEConfig, choices: int, tile: int) -> int:
     """The row tile of a layer that holds part of its experts. The row bound is
     static (every choice could land here): ``ceil(choices / tile) + held``
     tiles, of which those that hold a row are live (none for a held expert no
     row chose) and the rest are skipped at about 3 us a grid step; a live tile
-    costs its expert's whole slab. So the tile is the tuned one while an expert
+    costs its expert's whole slab. So the tile is the given one while an expert
     expects fewer rows than it holds (a decode step's 16 rows, a short prefill),
     and twice that beyond (a 2048-row chunk: half the tiles, and an expert's
     slab read once, not twice). On the chip at 6144 x 2048, 256 rows: 32 -> 2.18
     ms a layer, 64 -> 1.77, 128 -> 1.74."""
-    return 2 * tuned if choices // cfg.num_experts >= tuned else tuned
+    return 2 * tile if choices // cfg.num_experts >= tile else tile
 
 
 def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConfig, count_mask=None,
@@ -608,10 +604,7 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
         raise ValueError(f"held_expert_ffn wants cfg.held and dispatch 'ragged', got {cfg.held!r} / {cfg.dispatch!r}")
     T, D = x.shape
     K, F = cfg.top_k, w_gate.shape[-1]
-    tile = (
-        held_tile(cfg, T * K, moe_gemm.tuned_tile(cfg.num_experts, D, F, x.dtype))
-        if _kernel_eligible(cfg, D, F, x.dtype) else None
-    )
+    tile = held_tile(cfg, T * K, moe_gemm.TILE_M) if _kernel_eligible(cfg, D, F, x.dtype) else None
     sort_tok, dest, gate_vals, _, group_sizes, _ = route_ragged(x[None], router_w, cfg, None, tile=tile, bias=bias)
     rows = sort_tok.shape[0]
     on = dest < rows                                                     # [T*K]: the choice has a row
@@ -662,11 +655,7 @@ def _ragged_expert_ffn(x, router_w, w_gate, w_up, w_down, cfg: MoEConfig, token_
     # fused Pallas kernel (one VMEM pass for the whole expert MLP) when the
     # geometry is MXU-aligned and we're on a TPU backend (or the interpret
     # harness); otherwise three jax.lax.ragged_dot grouped GEMMs
-    tile = (
-        moe_gemm.tuned_tile(cfg.num_experts, D, w_gate.shape[-1], dtype)
-        if _kernel_eligible(cfg, D, w_gate.shape[-1], dtype)
-        else None
-    )
+    tile = moe_gemm.TILE_M if _kernel_eligible(cfg, D, w_gate.shape[-1], dtype) else None
     sort_tok, dest, gate_vals, gate_sorted, group_sizes, aux = route_ragged(
         x, router_w, cfg, token_mask, tile=tile
     )
