@@ -540,7 +540,9 @@ def test_the_cell_is_the_issues(bench):
     assert [m["name"] for m in spec.cell_metrics(bench_json, CELL, "end_to_end")] == ["serve_out_tok_s", "setup_s"]
     for name in ("latent_paged_decode_roofline_pct.serve", "prefix_hit_pct.serve"):
         m = next(m for m in bench_json["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and spec.metric(name)["reader"] in ("family_roofline", "registry_share")
+        # this cell first; a later cell whose requests share pages joins `prefix_hit_pct.serve` behind it (PR 50)
+        assert m["workloads"][0] == CELL and spec.metric(name)["reader"] in ("family_roofline", "registry_share")
+        assert m["workloads"] == [CELL] or name == "prefix_hit_pct.serve"
     # every last prefill chunk the window can see has a warmed shape: a question of 64-1024 rows, in powers of two
     lo, hi = t["prompt_len"]["min"] - t["prefix"]["tokens"], t["prompt_len"]["max"] - t["prefix"]["tokens"]
     assert (lo, hi) == (64, 1024) and {1 << (n - 1).bit_length() for n in range(lo, hi + 1)} <= set(w["engine"]["warm_prefill"])

@@ -39,6 +39,13 @@ import jax
 import jax.numpy as jnp
 
 from tony_tpu.models.llama import LlamaConfig
+from tony_tpu.obs import metrics as obs_metrics
+
+STATE_SNAPSHOTS = obs_metrics.counter(
+    "tony_serve_state_snapshots_total",
+    "snapshots of recurrent state at a prompt page's edge: taken at an insert, restored into a request that matched "
+    "up to the page, dropped with the page's eviction or when their place in the store was given to a newer one",
+    labelnames=("event",))
 
 
 class PagedCache(NamedTuple):
@@ -95,6 +102,10 @@ class PageAllocator:
         self._chain: dict[tuple, int] = {}       # prefix key → page
         self._key_of: dict[int, tuple] = {}      # page → its chain key
         self._reusable: "OrderedDict[int, None]" = OrderedDict()  # ref==0, keyed
+        # a family whose prefix leaves recurrent state beside its pages (models/olmo_hybrid.py) keeps that
+        # state at some pages' edges in a store on the device: page -> its place there, oldest first
+        self._state_at: "OrderedDict[int, int]" = OrderedDict()
+        self._state_free: list[int] | None = None                 # places nothing is kept in (None: no store yet)
 
     # -- capacity ----------------------------------------------------------
     def available(self) -> int:
@@ -119,6 +130,7 @@ class PageAllocator:
             else:
                 p, _ = self._reusable.popitem(last=False)  # LRU eviction
                 del self._chain[self._key_of.pop(p)]
+                self._drop_state(p)
             self._ref[p] = 1
             out.append(p)
         return out
@@ -132,6 +144,7 @@ class PageAllocator:
             self._reusable.move_to_end(page)
         else:
             self._free.append(page)
+            self._drop_state(page)  # no key: nothing can match up to it
 
     # -- prefix chain ------------------------------------------------------
     def match_prefix(self, keys: list[tuple]) -> list[int]:
@@ -157,9 +170,51 @@ class PageAllocator:
     def register(self, page: int, key: tuple) -> None:
         """Content-address a LIVE full prompt page. First writer wins — a
         concurrent duplicate simply stays unregistered and frees normally."""
-        if key not in self._chain and page not in self._key_of:
+        owner = self._chain.get(key)
+        if owner is None and page not in self._key_of:
             self._chain[key] = page
             self._key_of[page] = key
+        elif owner is not None and page in self._state_at and owner not in self._state_at:
+            # a duplicate of a resident page whose edge had lost its state (its place in the store given away):
+            # the state kept at the duplicate's edge is the same prefix's, and the resident page is the one a
+            # match finds
+            self._state_at[owner] = self._state_at.pop(page)
+
+    # -- state at a page's edge ----------------------------------------------
+    # The state beside a chain of pages lives and dies with the chain's last
+    # page: one manager and one eviction order. An entry goes when its page
+    # is evicted from the reuse pool (or freed without a key) or when its
+    # place in the store is given to a newer snapshot, the oldest first.
+    def keep_state(self, page: int, store: int) -> int:
+        """The state at live ``page``'s edge is about to be kept: its place in
+        a store of ``store`` places (a free one, else the oldest entry's, which
+        goes), or -1 where the page's edge has its state kept already."""
+        if page in self._state_at:
+            return -1
+        if self._state_free is None:
+            self._state_free = list(range(store - 1, -1, -1))
+        if self._state_free:
+            where = self._state_free.pop()
+        else:
+            _, where = self._state_at.popitem(last=False)
+            STATE_SNAPSHOTS.inc(event="dropped")
+        self._state_at[page] = where
+        STATE_SNAPSHOTS.inc(event="taken")
+        return where
+
+    def state_at(self, page: int) -> int | None:
+        return self._state_at.get(page)
+
+    def deepest_state(self, pages: list[int]) -> int:
+        """How many of a matched chain's pages a request may start from where
+        state lies beside them: up to the deepest one whose edge has it kept."""
+        return max((i + 1 for i, p in enumerate(pages) if p in self._state_at), default=0)
+
+    def _drop_state(self, page: int) -> None:
+        where = self._state_at.pop(page, None)
+        if where is not None:
+            self._state_free.append(where)
+            STATE_SNAPSHOTS.inc(event="dropped")
 
 
 def prefix_keys(prompt: list[int], page_len: int) -> list[tuple]:

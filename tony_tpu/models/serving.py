@@ -467,6 +467,9 @@ class ServingPrograms(NamedTuple):
     # (staging, cache, pages, n) -> staging with an earlier request's full prompt pages in it; None where a
     # page is not all a prefix leaves behind (state beside it), so no page is shared
     gather_prefix: object = None
+    # (the page allocator, the matched pages) -> how many of them a request may start from; None: all. Where state
+    # lies beside a prefix's pages, a match ends at the deepest page whose edge has that state kept
+    prefix_usable: object = None
 
 
 def programs_for(cfg, kv: str) -> ServingPrograms:
@@ -886,10 +889,17 @@ class ContinuousBatcher:
         entry has no pins and no prefill progress."""
         cap = (len(entry.req.prompt) - 1) // self.page_len
         matched = self.allocator.match_prefix(entry.keys[:cap])
+        if self.programs.prefix_usable is not None:
+            keep = self.programs.prefix_usable(self.allocator, matched)
+            for p in matched[keep:]:
+                self.allocator.release(p)  # matched past what is usable: unpinned again
+            matched = matched[:keep]
         if not matched:
             return False
+        # a host array: `jnp.asarray` of a list compiles a conversion a distinct LENGTH, and where matches differ
+        # in length (a session's turns: 16 to 76 pages) that is a compile a new length inside a serving window
         entry.pre = self.programs.gather_prefix(
-            entry.pre, self.cache, jnp.asarray(matched, jnp.int32), len(matched))
+            entry.pre, self.cache, np.asarray(matched, np.int32), len(matched))
         entry.pos = len(matched) * self.page_len
         entry.matched = matched
         entry.req.prefix_tokens = entry.pos

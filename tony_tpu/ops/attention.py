@@ -1126,3 +1126,101 @@ def mha(
         q, repeat_kv(k, n_rep), repeat_kv(v, n_rep),
         causal=causal, segment_ids=segment_ids, window=window,
     )
+
+
+# -- a prefill chunk's queries against a request's staged keys (serving) -----------------------------------
+
+def _chunk_prefill_kernel(meta_ref, q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc, *, scale, block_q, block_k):
+    """One (head, q block, k tile): the running max, sum and accumulator stay in
+    scratch over the k tiles, as ``sparse_attention._flash_masked_kernel`` keeps
+    them. meta: [pos0, last tile of q block 0, of q block 1, ..., the layer]."""
+    from jax.experimental import pallas as pl
+
+    i, j = pl.program_id(1), pl.program_id(2)
+    first_row = meta_ref[0] + i * block_q                                    # the q block's first position
+
+    @pl.when(j == 0)
+    def _init():
+        m_sc[...] = jnp.full_like(m_sc, NEG_INF)
+        l_sc[...] = jnp.zeros_like(l_sc)
+        acc_sc[...] = jnp.zeros_like(acc_sc)
+
+    def tile(masked: bool):
+        q, k, v = q_ref[0], k_ref[0], v_ref[0]                               # [bq, d], [bk, d]
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32) * scale
+        if masked:
+            q_pos = first_row + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = j * block_k + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(k_pos <= q_pos, s, NEG_INF)
+        m_new = jnp.maximum(m_sc[...], s.max(axis=1, keepdims=True))
+        p = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m_sc[...] - m_new)
+        l_sc[...] = l_sc[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        m_sc[...] = m_new
+
+    live = j <= meta_ref[1 + i]
+    diagonal = (j + 1) * block_k - 1 > first_row                             # a key of the tile lies past the block's first row
+    pl.when(live & diagonal)(lambda: tile(True))
+    pl.when(live & jnp.logical_not(diagonal))(lambda: tile(False))
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        o_ref[0] = (acc_sc[...] / jnp.maximum(l_sc[...], 1e-30)).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_q", "block_k"))
+def chunk_prefill_attention(q, k, v, pos0, n_keys, layer=None, *, block_q: int = 256, block_k: int = 512):
+    """Causal attention of a prefill chunk over a request's staged keys. q [H, T,
+    d]: the chunk's queries, at positions pos0 .. pos0 + T; k, v [Hkv, Tk, d]: the
+    request's keys and values at their true positions, the chunk's own among them
+    (Tk the staging's length, whatever has been written of it); pos0, n_keys []
+    int32: the chunk's first position and the keys that exist. Returns [H, T, d].
+    With ``layer`` ([] int32), k and v are a request's WHOLE staging [L, 1, Hkv, Tk,
+    d] and the call reads that layer's tiles of it: one layer's slice handed in is
+    a copy of it a call (a Mosaic call's operand needs a buffer of its own).
+
+    Flash attention with the KEY TILES ON THE GRID (head, q block, k tile), where
+    ``flash_attention`` holds a head's keys and values whole in VMEM: a staging of
+    20k positions and more is read a tile at a time. The causal mask comes from the
+    positions (an iota on the tiles the diagonal crosses, none on the tiles wholly
+    before it); a tile past a q block's last row, or past ``n_keys``, is neither
+    fetched (its index map repeats the last live tile) nor computed. Softmax in
+    float32, scale ``d ** -0.5``; q head h reads kv head ``h // (H // Hkv)``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    H, T, d = q.shape
+    Hkv, Tk = k.shape[-3:-1]
+    if (k.ndim == 5) != (layer is not None):
+        raise ValueError(f"k {k.shape}: a staging [L, 1, Hkv, Tk, d] comes with its layer, keys [Hkv, Tk, d] without one")
+    bq, bk = min(block_q, T), min(block_k, Tk)
+    if T % bq or Tk % bk or H % Hkv:
+        raise ValueError(f"{T} queries / {Tk} keys do not divide into tiles of {bq} / {bk}, or {H} heads into {Hkv}")
+    nq, nk, n_rep = T // bq, Tk // bk, H // Hkv
+    last_row = pos0 + (jnp.arange(nq, dtype=jnp.int32) + 1) * bq - 1
+    last = jnp.minimum(last_row, jnp.maximum(n_keys - 1, 0)) // bk
+    meta = jnp.concatenate([jnp.reshape(pos0, (1,)).astype(jnp.int32), last.astype(jnp.int32),
+                            jnp.reshape(0 if layer is None else layer, (1,)).astype(jnp.int32)])
+    if layer is None:
+        kv_spec = pl.BlockSpec((1, bk, d), lambda h, i, j, meta: (h // n_rep, jnp.minimum(j, meta[1 + i]), 0))
+    else:
+        kv_spec = pl.BlockSpec((None, None, 1, bk, d), lambda h, i, j, meta: (meta[1 + nq], 0, h // n_rep, jnp.minimum(j, meta[1 + i]), 0))
+    return pl.pallas_call(
+        functools.partial(_chunk_prefill_kernel, scale=d ** -0.5, block_q=bq, block_k=bk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(H, nq, nk),
+            in_specs=[pl.BlockSpec((1, bq, d), lambda h, i, j, meta: (h, i, 0)), kv_spec, kv_spec],
+            out_specs=pl.BlockSpec((1, bq, d), lambda h, i, j, meta: (h, i, 0)),
+            scratch_shapes=[pltpu.VMEM((bq, 1), jnp.float32), pltpu.VMEM((bq, 1), jnp.float32),
+                            pltpu.VMEM((bq, d), jnp.float32)],
+        ),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret(),
+        name="chunk_prefill_attention",
+        cost_estimate=pl.CostEstimate(flops=4 * H * T * Tk * d, transcendentals=H * T * Tk,
+                                      bytes_accessed=(2 * H * T * d + 2 * Hkv * nq * Tk * d) * q.dtype.itemsize),
+    )(meta, q, k, v)
