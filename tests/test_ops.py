@@ -47,37 +47,94 @@ class TestLayers:
         loss, _ = L.cross_entropy_loss(logits, targets)
         assert float(loss) < 1e-3
 
-    def test_chunked_cross_entropy_matches_plain(self):
-        key = jax.random.PRNGKey(3)
-        B, T, D, V = 2, 16, 8, 32
-        x = jax.random.normal(key, (B, T, D), jnp.float32)
-        w = jax.random.normal(jax.random.PRNGKey(4), (D, V), jnp.float32) * 0.1
-        targets = jax.random.randint(jax.random.PRNGKey(5), (B, T), 0, V)
-        targets = targets.at[0, :3].set(-100)  # masked prefix
+    # chunk: the whole sequence, a divisor of it, a non-divisor (padded with
+    # ignored targets); what is ignored; the cotangent the loss is handed
+    @pytest.mark.parametrize("cotangent", [1.0, 3.0])
+    @pytest.mark.parametrize("ignored", ["some", "a_row", "all"])
+    @pytest.mark.parametrize("chunk", [16, 4, 5])
+    def test_chunked_cross_entropy_matches_plain(self, chunk, ignored, cotangent):
+        """Value, dx and dW of the chunked loss against cross_entropy_loss on
+        whole logits, in float32: the chunked loss takes its gradient in its
+        forward pass (a custom_vjp), the plain one by autodiff."""
+        x, w, targets = _head_case(ignored)
 
-        plain_loss, plain_n = L.cross_entropy_loss(jnp.einsum("btd,dv->btv", x, w), targets)
-        for chunk in (4, 16, 5):  # 5: non-divisible → padded with ignored targets
+        def plain(x, w):
+            loss, n = L.cross_entropy_loss(jnp.einsum("btd,dv->btv", x, w), targets)
+            return cotangent * loss, n
+
+        def chunked(x, w):
             loss, n = L.chunked_cross_entropy_loss(x, w, targets, chunk=chunk)
-            np.testing.assert_allclose(float(loss), float(plain_loss), rtol=1e-5)
-            assert int(n) == int(plain_n)
+            return cotangent * loss, n
 
-    def test_chunked_cross_entropy_grads_match(self):
-        key = jax.random.PRNGKey(6)
-        B, T, D, V = 2, 8, 4, 16
-        x = jax.random.normal(key, (B, T, D), jnp.float32)
-        w = jax.random.normal(jax.random.PRNGKey(7), (D, V), jnp.float32) * 0.1
-        targets = jax.random.randint(jax.random.PRNGKey(8), (B, T), 0, V)
+        (want, want_n), (wx, ww) = jax.value_and_grad(plain, argnums=(0, 1), has_aux=True)(x, w)
+        (got, got_n), (gx, gw) = jax.value_and_grad(chunked, argnums=(0, 1), has_aux=True)(x, w)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        assert int(got_n) == int(want_n)
+        np.testing.assert_allclose(np.asarray(gx), np.asarray(wx), rtol=2e-4, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(gw), np.asarray(ww), rtol=2e-4, atol=1e-6)
+        # the primal alone (no gradient asked) is the same number
+        np.testing.assert_allclose(float(chunked(x, w)[0]), float(want), rtol=1e-5)
+
+    @pytest.mark.parametrize("chunk", [16, 5])
+    def test_chunked_cross_entropy_bfloat16_inside_the_benchmarks_band(self, chunk):
+        """bfloat16 activations and head against the float32 plain loss on the
+        same values: inside benchmark/check.py's bands at train_8k (relative
+        RMS 0.02 on the value's side, 0.015 on the gradients')."""
+        x, w, targets = _head_case("some", D=64, V=256)
+        xb, wb = x.astype(jnp.bfloat16), w.astype(jnp.bfloat16)
 
         def plain(x, w):
             return L.cross_entropy_loss(jnp.einsum("btd,dv->btv", x, w), targets)[0]
 
         def chunked(x, w):
+            return L.chunked_cross_entropy_loss(x, w, targets, chunk=chunk)[0]
+
+        want, (wx, ww) = jax.value_and_grad(plain, argnums=(0, 1))(
+            xb.astype(jnp.float32), wb.astype(jnp.float32))
+        got, (gx, gw) = jax.value_and_grad(chunked, argnums=(0, 1))(xb, wb)
+        assert gx.dtype == jnp.bfloat16 and gw.dtype == jnp.bfloat16
+        assert abs(float(got) - float(want)) < 0.02 * abs(float(want))
+        assert _rel_rms(gx, wx) < 0.015
+        assert _rel_rms(gw, ww) < 0.015
+
+    def test_chunked_cross_entropy_takes_three_vocabulary_products(self):
+        """Differentiated, a chunk holds three products with the vocabulary
+        among their dimensions (logits, dx, dW; four when the backward formed
+        the logits again), and the primal alone holds one."""
+        x, w, targets = _head_case("some", V=48)  # no other dimension is 48
+
+        def chunked(x, w):
             return L.chunked_cross_entropy_loss(x, w, targets, chunk=4)[0]
 
-        gx1, gw1 = jax.grad(plain, argnums=(0, 1))(x, w)
-        gx2, gw2 = jax.grad(chunked, argnums=(0, 1))(x, w)
-        np.testing.assert_allclose(np.asarray(gx1), np.asarray(gx2), rtol=2e-4, atol=1e-6)
-        np.testing.assert_allclose(np.asarray(gw1), np.asarray(gw2), rtol=2e-4, atol=1e-6)
+        def vocabulary_products(jaxpr) -> int:
+            n = 0
+            for eqn in jaxpr.eqns:
+                shapes = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+                n += eqn.primitive.name == "dot_general" and any(48 in shape for shape in shapes)
+                n += sum(vocabulary_products(sub) for sub in jax.core.jaxprs_in_params(eqn.params))
+            return n
+
+        assert vocabulary_products(jax.make_jaxpr(jax.grad(chunked, argnums=(0, 1)))(x, w).jaxpr) == 3
+        assert vocabulary_products(jax.make_jaxpr(chunked)(x, w).jaxpr) == 1
+
+
+def _head_case(ignored: str, B=2, T=16, D=8, V=32):
+    """Final hidden states, a head and targets of which `ignored` are -100."""
+    x = jax.random.normal(jax.random.PRNGKey(3), (B, T, D), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(4), (D, V), jnp.float32) * 0.1
+    targets = jax.random.randint(jax.random.PRNGKey(5), (B, T), 0, V)
+    if ignored == "some":
+        targets = targets.at[0, :3].set(-100).at[1, 7].set(-100)
+    elif ignored == "a_row":
+        targets = targets.at[1].set(-100)
+    else:
+        targets = jnp.full_like(targets, -100)
+    return x, w, targets
+
+
+def _rel_rms(a, ref) -> float:
+    a, ref = np.asarray(a, np.float64), np.asarray(ref, np.float64)
+    return float(np.sqrt(np.mean((a - ref) ** 2) / np.mean(ref ** 2)))
 
 
 class TestAttentionReference:

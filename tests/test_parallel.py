@@ -491,6 +491,108 @@ class TestMoE:
         assert float(aux["moe_dropped_frac"]) > 0.5
 
 
+class TestHeadLossOnAMesh:
+    """ops/layers.chunked_cross_entropy_loss handed the mesh its batch is
+    sharded over: a chip sums the head's gradient over its own rows of the
+    batch through the whole scan, and the one reduction comes after it."""
+
+    B, T, D, V, CHUNK = 8, 64, 32, 512, 16
+    MESHES = {"fsdp4": dict(fsdp=4), "fsdp2_model2": dict(fsdp=2, model=2)}
+
+    def _case(self):
+        x = jax.random.normal(jax.random.PRNGKey(0), (self.B, self.T, self.D), jnp.float32)
+        w = jax.random.normal(jax.random.PRNGKey(1), (self.D, self.V), jnp.float32) * 0.1
+        targets = jax.random.randint(jax.random.PRNGKey(2), (self.B, self.T), 0, self.V)
+        return x, w, targets.at[0, :5].set(-100)
+
+    def _on(self, axes):
+        from jax.sharding import NamedSharding
+
+        from tony_tpu.ops import layers as L
+
+        mesh = MeshSpec(**axes).build(jax.devices()[:int(np.prod(list(axes.values())))])
+        rows, head = NamedSharding(mesh, P(("data", "fsdp"))), NamedSharding(mesh, P("fsdp", "model"))
+        return jax.jit(
+            jax.value_and_grad(
+                lambda x, w, t: L.chunked_cross_entropy_loss(x, w, t, chunk=self.CHUNK, mesh=mesh)[0],
+                argnums=(0, 1)),
+            in_shardings=(rows, head, rows),
+            out_shardings=(NamedSharding(mesh, P()), (rows, head)),
+        )
+
+    @pytest.mark.parametrize("axes", MESHES.values(), ids=MESHES.keys())
+    def test_gradients_equal_the_single_device_ones(self, axes):
+        from tony_tpu.ops import layers as L
+
+        x, w, targets = self._case()
+        want, (wx, ww) = jax.value_and_grad(
+            lambda x, w: L.chunked_cross_entropy_loss(x, w, targets, chunk=self.CHUNK)[0],
+            argnums=(0, 1))(x, w)
+        got, (gx, gw) = self._on(axes)(x, w, targets)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(gx), np.asarray(wx), rtol=2e-4, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(gw), np.asarray(ww), rtol=2e-4, atol=1e-7)
+
+    def test_a_batch_the_mesh_does_not_divide_takes_the_bare_scan(self):
+        """Six rows over fsdp=4, as the loop hands a batch smaller than its
+        mesh: nothing to give each chip whole rows of, so the scan runs bare
+        under the partitioner, and the gradients are the single-device ones."""
+        from jax.sharding import NamedSharding
+
+        from tony_tpu.ops import layers as L
+
+        x, w, targets = (a[:6] if a.shape[0] == self.B else a for a in self._case())
+        want, (wx, ww) = jax.value_and_grad(
+            lambda x, w: L.chunked_cross_entropy_loss(x, w, targets, chunk=self.CHUNK)[0],
+            argnums=(0, 1))(x, w)
+        mesh = MeshSpec(fsdp=4).build(jax.devices()[:4])
+        head = NamedSharding(mesh, P("fsdp", "model"))
+        got, (gx, gw) = jax.jit(
+            jax.value_and_grad(
+                lambda x, w: L.chunked_cross_entropy_loss(x, w, targets, chunk=self.CHUNK, mesh=mesh)[0],
+                argnums=(0, 1)),
+            in_shardings=(NamedSharding(mesh, P()), head),
+        )(x, w)
+        assert gw.sharding.is_equivalent_to(head, 2)
+        np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+        np.testing.assert_allclose(np.asarray(gx), np.asarray(wx), rtol=2e-4, atol=1e-7)
+        np.testing.assert_allclose(np.asarray(gw), np.asarray(ww), rtol=2e-4, atol=1e-7)
+
+    @pytest.mark.parametrize("axes", MESHES.values(), ids=MESHES.keys())
+    def test_nothing_head_sized_is_exchanged_inside_the_scan(self, axes):
+        """The compiled loss: inside the scan's while body no collective moves
+        anything as large as the head or a chunk's logits as that body holds
+        them (none at all where only the batch is sharded; with the vocabulary
+        split over `model` the row statistics and dx's partial sums cross it,
+        a D-th and a V-th of that size), and the head's gradient is reduced
+        by ONE reduce-scatter, after the loop."""
+        import re
+
+        text = self._on(axes).lower(*self._case()).compile().as_text()
+        computations = dict(re.findall(r"^(?:ENTRY )?%?([\w.-]+) \([^\n]*\) -> [^\n]*\{\n(.*?)^\}", text, re.M | re.S))
+        body, = set(re.findall(r" while\([^\n]*body=%?([\w.-]+)", text))
+        inside, todo = set(), [body]
+        while todo:  # the body and whatever it calls
+            name = todo.pop()
+            if name not in inside:
+                inside.add(name)
+                todo += re.findall(r"(?:calls|to_apply|body|condition)=%?([\w.-]+)", computations[name])
+        collective = re.compile(
+            r"= (\w+)\[([\d,]*)\]\S* (all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)(?:-start)?\(")
+        sizes = lambda lines: [  # noqa: E731
+            (op, int(np.prod([int(d) for d in dims.split(",") if d]))) for _, dims, op in collective.findall(lines)]
+        in_the_loop = sizes("\n".join(computations[name] for name in inside))
+        shards, model = axes.get("fsdp", 1), axes.get("model", 1)
+        head_there = self.D * self.V // model
+        logits_there = self.B // shards * self.CHUNK * self.V // model
+        assert all(n < min(head_there, logits_there) // 2 for _, n in in_the_loop), in_the_loop
+        if model == 1:
+            assert not in_the_loop, in_the_loop
+        everywhere = sizes(text)
+        assert [op for op, _ in everywhere].count("reduce-scatter") == 1, everywhere
+        assert not [(op, n) for op, n in everywhere if op == "all-reduce" and n >= head_there // 2], everywhere
+
+
 @pytest.mark.slow  # ~6 min of multi-device XLA compiles on the CPU mesh:
 # each 1F1B case builds a full shard_map pipeline fwd+bwd; tier-1 budgets
 # its 870 s for breadth, so this class runs in the unfiltered suite only

@@ -514,7 +514,7 @@ def loss_fn(params: dict, batch: dict, cfg: LlamaConfig, mesh=None) -> tuple[jax
     if cfg.ce_chunk > 0:
         x = hidden_states(params, tokens[:, :-1], cfg, mesh, segment_ids=seg_in)
         loss, n = L.chunked_cross_entropy_loss(
-            x, params["lm_head"], targets, chunk=cfg.ce_chunk
+            x, params["lm_head"], targets, chunk=cfg.ce_chunk, mesh=mesh
         )
     else:
         logits = forward(params, tokens[:, :-1], cfg, mesh, segment_ids=seg_in)

@@ -203,7 +203,7 @@ def loss_fn(params: dict, batch: dict, cfg: MixtralConfig, mesh=None) -> tuple[j
     if cfg.ce_chunk > 0:
         x, aux = hidden_states(params, tokens[:, :-1], cfg, mesh, segment_ids=seg_in)
         ce, n = L.chunked_cross_entropy_loss(
-            x, params["lm_head"], targets, chunk=cfg.ce_chunk
+            x, params["lm_head"], targets, chunk=cfg.ce_chunk, mesh=mesh
         )
     else:
         logits, aux = forward(params, tokens[:, :-1], cfg, mesh, segment_ids=seg_in)

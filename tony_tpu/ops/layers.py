@@ -8,11 +8,13 @@ collectives, quantization; see ops/attention.py, ops/quant.py).
 
 from __future__ import annotations
 
+import functools
 import math
 
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.sharding import PartitionSpec as P
 
 
 def rms_norm(x: jax.Array, weight: jax.Array, eps: float = 1e-6) -> jax.Array:
@@ -148,17 +150,26 @@ def chunked_cross_entropy_loss(
     targets: jax.Array,
     ignore_index: int = -100,
     chunk: int = 512,
+    mesh=None,
 ) -> tuple[jax.Array, jax.Array]:
     """Fused lm-head + CE that never materializes the [B, T, V] logits.
 
     A ``lax.scan`` over sequence chunks computes each chunk's logits, its
-    logsumexp, and the gold logit, keeping only O(B·chunk·V) live; the
-    chunk body is checkpointed so the backward recomputes per-chunk logits
-    instead of saving them. At Llama-scale vocab this removes the largest
-    activation in the train step (the bf16 logits + f32 softmax temps),
-    which is what bounds the per-chip batch size.
+    logsumexp, and the gold logit, keeping only O(B·chunk·V) live. Under
+    differentiation the same scan takes the gradient while it has the logits
+    in hand (``softmax - onehot``, and the two products that carry it to ``x``
+    and ``lm_head``): three products with a vocabulary dimension a chunk, none
+    replayed, and the backward only scales what the forward kept. At
+    Llama-scale vocab this removes the largest activation in the train step
+    (the bf16 logits + f32 softmax temps), which is what bounds the per-chip
+    batch size.
 
     x: [B, T, D] final hidden states; lm_head: [D, V]; targets: [B, T].
+    ``mesh``: the mesh ``x``'s batch is sharded over (data x fsdp), if any: a
+    chip then sums the head's gradient over its own rows of the batch through
+    the whole scan, and one reduce-scatter after it leaves each chip its rows
+    of the sum. None, a batch axis of one device, or axes that do not divide
+    B and D (a batch the partitioner cannot shard either) is the same scan bare.
     """
     B, T, D = x.shape
     chunk = T if chunk <= 0 else min(chunk, T)
@@ -169,26 +180,107 @@ def chunked_cross_entropy_loss(
         # (a divisor-based fallback would degenerate to tiny chunks)
         x = jnp.pad(x, ((0, 0), (0, pad), (0, 0)))
         targets = jnp.pad(targets, ((0, 0), (0, pad)), constant_values=ignore_index)
-        T += pad
-    n_chunks = T // chunk
-    mask_all = targets != ignore_index
-    xs = x.reshape(B, n_chunks, chunk, D).transpose(1, 0, 2, 3)
-    ts = targets.reshape(B, n_chunks, chunk).transpose(1, 0, 2)
+    n = jnp.maximum((targets != ignore_index).sum(), 1)
+    return _head_loss(x, lm_head, targets, n, ignore_index, chunk, mesh), n
 
-    def chunk_nll(carry, xt):
-        xc, tc = xt
-        logits = jnp.einsum(
-            "bcd,dv->bcv", xc, lm_head, preferred_element_type=jnp.float32
+
+def _head_scan(x, lm_head, targets, n, ignore_index, chunk, mesh, with_grads):
+    """The scan over chunks behind ``chunked_cross_entropy_loss``: ``(loss,)``,
+    the mean nll over ``n`` targets, or ``with_grads`` ``(loss, dx, dw)`` with
+    its gradients against ``x`` (in x's type) and ``lm_head`` (summed over the
+    chunks in float32, then in lm_head's type)."""
+    B, T, D = x.shape
+    inv_n = 1.0 / n.astype(jnp.float32)
+    # the batch's axes of the mesh, where each chip can be handed whole rows of
+    # the batch and whole rows of the reduced gradient
+    axes = () if mesh is None else tuple(a for a in ("data", "fsdp") if mesh.shape.get(a, 1) > 1)
+    shards = math.prod(mesh.shape[a] for a in axes)
+    if B % shards or D % shards:
+        # a batch with fewer rows than the axes have chips (the loop hands one
+        # over as it is, and the partitioner replicates it): the bare scan
+        axes = ()
+
+    def local(x, lm_head, targets, inv_n):
+        def chunk_nll(carry, at):
+            total, *grads = carry
+            xc = jax.lax.dynamic_slice_in_dim(x, at, chunk, axis=1)
+            tc = jax.lax.dynamic_slice_in_dim(targets, at, chunk, axis=1)
+            logits = jnp.einsum(
+                "bcd,dv->bcv", xc, lm_head, preferred_element_type=jnp.float32
+            )
+            mask = tc != ignore_index
+            safe = jnp.where(mask, tc, 0)
+            logz = jax.nn.logsumexp(logits, axis=-1)
+            # gold logit via masked reduce (fuses; no gather, so vocab-parallel
+            # TP shards reduce locally and psum instead of rematerializing)
+            iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
+            gold_at = iota == safe[..., None]
+            gold = jnp.sum(jnp.where(gold_at, logits, 0.0), axis=-1)
+            total = total + jnp.sum((logz - gold) * mask)
+            if not with_grads:
+                return (total,), None
+            dw, dx = grads
+            # d(mean nll)/dlogits, handed to its two products in the activations'
+            # type, and as an array: fused into the products as the compiler
+            # would, dW's forms it again for every tile of its output (42.6 ms a
+            # step at [2, 8192, 4096] x [4096, 32000] where the product of an
+            # array takes 28.5, and dx's 27.0 for 22.4: my chip run, PR 51)
+            dlogits = jax.lax.optimization_barrier((
+                (jnp.exp(logits - logz[..., None]) - gold_at) * (mask * inv_n)[..., None]
+            ).astype(x.dtype))
+            dxc = jnp.einsum(
+                "bcv,dv->bcd", dlogits, lm_head, preferred_element_type=jnp.float32
+            ).astype(x.dtype)
+            dw = dw + jnp.einsum(
+                "bcd,bcv->dv", xc, dlogits, preferred_element_type=jnp.float32
+            )
+            return (total, dw, jax.lax.dynamic_update_slice_in_dim(dx, dxc, at, axis=1)), None
+
+        init = (jnp.float32(0.0),)
+        if with_grads:
+            init += (jnp.zeros(lm_head.shape, jnp.float32), jnp.zeros_like(x))
+        with jax.named_scope("head_loss"):
+            (total, *grads), _ = jax.lax.scan(chunk_nll, init, jnp.arange(0, T, chunk))
+        if axes:
+            total = jax.lax.psum(total, axes)
+        if not with_grads:
+            return (total,)
+        dw, dx = grads
+        if axes:  # the one exchange of anything head-sized: each chip keeps its rows
+            dw = jax.lax.psum_scatter(dw, axes, scatter_dimension=0, tiled=True)
+        # The float32 sum ends HERE: left to itself the compiler folds the cast
+        # into the optimizer's update and keeps the float32 array through every
+        # layer's backward (+0.26 GB at the step's reported peak on one chip),
+        # and runs the reduction behind the last layer's backward with the whole
+        # unreduced sum still alive (+0.39 GB allocated a chip over four). dx,
+        # which that backward starts from, waits for both (compile-only, PR 51).
+        dx, dw = jax.lax.optimization_barrier((dx, dw.astype(lm_head.dtype)))
+        return total, dx, dw
+
+    if axes:
+        rows = P(axes)
+        local = jax.shard_map(
+            local, mesh=mesh, in_specs=(rows, P(), rows, P()),
+            out_specs=(P(), rows, rows) if with_grads else (P(),),
+            axis_names=set(axes), check_vma=False,
         )
-        mask = tc != ignore_index
-        safe = jnp.where(mask, tc, 0)
-        logz = jax.nn.logsumexp(logits, axis=-1)
-        # gold logit via masked reduce (fuses; no gather, so vocab-parallel
-        # TP shards reduce locally and psum instead of rematerializing)
-        iota = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 2)
-        gold = jnp.sum(jnp.where(iota == safe[..., None], logits, 0.0), axis=-1)
-        return carry + jnp.sum((logz - gold) * mask), None
+    total, *grads = local(x, lm_head, targets, inv_n)
+    return (total / n, *grads)
 
-    total, _ = jax.lax.scan(jax.checkpoint(chunk_nll), jnp.float32(0.0), (xs, ts))
-    n = jnp.maximum(mask_all.sum(), 1)
-    return total / n, n
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _head_loss(x, lm_head, targets, n, ignore_index, chunk, mesh):
+    return _head_scan(x, lm_head, targets, n, ignore_index, chunk, mesh, with_grads=False)[0]
+
+
+def _head_loss_fwd(x, lm_head, targets, n, ignore_index, chunk, mesh):
+    loss, dx, dw = _head_scan(x, lm_head, targets, n, ignore_index, chunk, mesh, with_grads=True)
+    return loss, (dx, dw)
+
+
+def _head_loss_bwd(ignore_index, chunk, mesh, kept, g):
+    dx, dw = kept
+    return (g * dx).astype(dx.dtype), (g * dw).astype(dw.dtype), None, None
+
+
+_head_loss.defvjp(_head_loss_fwd, _head_loss_bwd)
