@@ -95,38 +95,59 @@ class TestFlashAttentionTPU:
 
 
 class TestFlashAtLlama1bShapes:
-    def test_fwd_bwd_resident_dkv(self, chip):
+    def test_fwd_bwd(self, chip):
         q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
-        assert _kernel_calls(_flash_grad(), q, kv, kv) == 3  # fwd, dq, dkv
+        assert _kernel_calls(_flash_grad(), q, kv, kv) == 2  # fwd, the one bwd
 
-    def test_fwd_bwd_streaming_dkv(self, chip):
-        # n_rep * Tq past _DKV_RESIDENT_MAX_QROWS takes the streaming dkv grid
-        assert 2 * 4096 > A._DKV_RESIDENT_MAX_QROWS
+    def test_fwd_bwd_at_twice_the_length(self, chip):
+        # 2 x 4096 rows of q a kv head, 4096 keys resident with their float32 dk and dv
         q, kv = _s((2, H, 4096, DH), jnp.bfloat16, chip), _s((2, HKV, 4096, DH), jnp.bfloat16, chip)
-        assert _kernel_calls(_flash_grad(), q, kv, kv) == 3
+        assert _kernel_calls(_flash_grad(), q, kv, kv) == 2
 
     def test_packed_segments(self, chip):
         q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
-        assert _kernel_calls(_flash_grad(), q, kv, kv, _s((B, T), jnp.int32, chip)) == 3
+        assert _kernel_calls(_flash_grad(), q, kv, kv, _s((B, T), jnp.int32, chip)) == 2
 
     def test_sliding_window(self, chip):
         q, kv = _s((B, H, T, DH), jnp.bfloat16, chip), _s((B, HKV, T, DH), jnp.bfloat16, chip)
-        assert _kernel_calls(_flash_grad(window=512), q, kv, kv) == 3
+        assert _kernel_calls(_flash_grad(window=512), q, kv, kv) == 2
 
 
 class TestFlashAtTheTrainingCellsShape:
-    def test_the_three_named_calls(self, chip):
-        """`mistral-7b.train_8k` / `train_fsdp4`, a chip's share: 2 x 32 query
-        and 8 kv heads of 128 over 8192 positions, band 4096, bfloat16 straight
-        into the MXU; n_rep x Tq is far past the resident dkv's rows, so this
-        is the streaming dkv with its own q block. The trace finds the calls
-        by these instruction names."""
-        q, kv = _s((2, 32, 8192, DH), jnp.bfloat16, chip), _s((2, 8, 8192, DH), jnp.bfloat16, chip)
-        text = jax.jit(_flash_grad(window=4096)).lower(q, kv, kv).compile().as_text()
-        assert text.count("tpu_custom_call") == 3
-        for name in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
-            # jax wraps the name in its transforms' (jvp_..., transpose_jvp_...)
-            assert len(re.findall(rf"%\w*{name}[\w.]* = ", text)) == 1, name
+    """`mistral-7b.train_8k` / `train_fsdp4`, a chip's share: 2 x 32 query and
+    8 kv heads of 128 over 8192 positions, band 4096, bfloat16 straight into
+    the MXU. The backward is ONE call that holds a kv head's k, v and float32
+    dk, dv whole in VMEM (25 MB double-buffered), so it lowers only with the
+    VMEM it asks for. The trace finds the calls by these instruction names."""
+
+    Q, KV = (2, 32, 8192, DH), (2, 8, 8192, DH)
+
+    def _check(self, text):
+        assert text.count("tpu_custom_call") == 2
+        for name, calls in (("flash_fwd", 1), ("flash_bwd", 1), ("flash_bwd_dq", 0), ("flash_bwd_dkv", 0)):
+            # jax wraps the name in its transforms' (%jvp_flash_fwd_.1, %transpose_jvp_flash_bwd__.1)
+            assert len(re.findall(rf"%\w*{name}[_.\d]* = ", text)) == calls, name
+        limits = re.findall(r"scoped_memory_configs.{0,80}?size.{0,4}?(\d+)", text)
+        assert str(A._BWD_VMEM_LIMIT) in limits, limits
+
+    def test_the_two_named_calls(self, chip):
+        q, kv = _s(self.Q, jnp.bfloat16, chip), _s(self.KV, jnp.bfloat16, chip)
+        self._check(jax.jit(_flash_grad(window=4096)).lower(q, kv, kv).compile().as_text())
+
+    def test_the_two_named_calls_under_shard_map(self, topo, chip):
+        """`train_fsdp4`'s form: four chips' batch (8 x 8192) over fsdp, each
+        chip's share the bare call's shape, the kernel per shard under
+        `mha_on_mesh`'s shard_map."""
+        mesh = Mesh(list(topo.devices), ("fsdp",))
+        sharded = NamedSharding(mesh, P("fsdp", None, None, None))
+        q = _s((8, *self.Q[1:]), jnp.bfloat16, sharded)
+        kv = _s((8, *self.KV[1:]), jnp.bfloat16, sharded)
+
+        def loss(q, k, v):
+            return A.mha_on_mesh(q, k, v, mesh=mesh, causal=True, impl="flash",
+                                 window=4096).astype(jnp.float32).sum()
+
+        self._check(jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(q, kv, kv).compile().as_text())
 
 
 class TestDecodeAttentionAtServeShapes:
@@ -427,7 +448,7 @@ class TestFlashOnAFourChipMesh:
         with pytest.raises(NotImplementedError, match="shard_map"):
             jax.jit(bare).lower(q, kv, kv)
         assert _kernel_calls(on_mesh, q, kv, kv) == 1
-        assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 3
+        assert _kernel_calls(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv) == 2
 
 
 class TestLatentAttentionAtTheNotesCellsShapes:

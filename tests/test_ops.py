@@ -175,6 +175,19 @@ class TestAttentionReference:
         assert all(np.isfinite(np.asarray(g)).all() for g in grads)
 
 
+def _absolute_reference(q, k, v, causal=True, window=0):
+    """`attention_reference` with the kernels' ABSOLUTE positions (query i sees
+    keys <= i), which differ from its bottom-aligned ones where Tk > Tq."""
+    Tq, Tk, D = q.shape[2], k.shape[2], q.shape[3]
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * D ** -0.5
+    qp, kp = jnp.arange(Tq)[:, None], jnp.arange(Tk)[None, :]
+    if causal:
+        s = jnp.where(qp >= kp, s, A.NEG_INF)
+    if window > 0:
+        s = jnp.where(qp - kp < window, s, A.NEG_INF)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
 class TestFlashAttentionInterpret:
     """Kernel numerics on CPU via the Pallas interpreter (conftest sets
     TONY_PALLAS_INTERPRET=1): forward + the FlashAttention-2 backward."""
@@ -294,17 +307,10 @@ class TestFlashAttentionInterpret:
             err = float(jnp.max(jnp.abs(a - b))) / scale
             assert err < 2e-4, f"{name} rel err {err}"
 
-    def test_gqa_backward_streaming_variant(self, monkeypatch):
-        # force the pair-enumeration (long-sequence) dkv kernel and check parity
-        monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
-        self.test_gqa_backward_matches_reference()
-        self.test_backward_matches_reference()
-
-    def test_streaming_dkv_causal_tk_gt_tq(self, monkeypatch):
+    def test_backward_causal_tk_gt_tq(self):
         # Tk > Tq + causal: k blocks wholly past the causal horizon must come
-        # back as exact ZERO dk/dv (the sparse pair walk still has to visit
-        # them once to zero-init the output block)
-        monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
+        # back as exact ZERO dk/dv (no q block visits them: the zeros are the
+        # resident blocks' first write)
         B, H, Tq, Tk, D = 1, 2, 256, 1024, 64
         ks = [jax.random.fold_in(jax.random.PRNGKey(17), i) for i in range(3)]
         q = jax.random.normal(ks[0], (B, H, Tq, D), jnp.float32) * 0.5
@@ -315,12 +321,7 @@ class TestFlashAttentionInterpret:
             return A._flash_trainable(q, k, v, True).sum()
 
         def loss_ref(q, k, v):
-            # flash-kernel causal semantics: ABSOLUTE positions (query i sees
-            # keys <= i), unlike attention_reference's bottom-aligned tril
-            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) * (D ** -0.5)
-            mask = jnp.arange(Tq)[:, None] >= jnp.arange(Tk)[None, :]
-            p = jax.nn.softmax(jnp.where(mask, s, A.NEG_INF), axis=-1)
-            return jnp.einsum("bhqk,bhkd->bhqd", p, v).sum()
+            return _absolute_reference(q, k, v).sum()
 
         gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
         gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
@@ -369,22 +370,17 @@ class TestFlashBlockClasses:
     def test_counts_and_loop_bounds_match_the_mask(self, causal, window, bq, bk, Tq, Tk):
         """Every pair of an interior block visible, none of a hidden block,
         every visible pair in a visited block: `flash_block_classes` and the
-        bounds the kernels loop over (`_k_runs` from a q block's side, forward
-        and dq; `_q_runs` from a k block's, both dkv forms) against the mask."""
+        bounds the kernels loop over (`_k_runs`, from a q block's side: the
+        forward and the backward walk the same runs) against the mask."""
         window = {"none": 0, "one_block": bk, "three_blocks": 3 * bk, "past_Tk": Tk + 5}[window]
         want = _brute_force_classes(Tq, Tk, bq, bk, causal, window)
         nq, nk = Tq // bq, Tk // bk
-        by_q, by_k = np.zeros_like(want), np.zeros_like(want)
+        by_q = np.zeros_like(want)
         for qb in range(nq):
             s, e1, e2, e = A._k_runs(qb, bq, bk, nk, causal, window)
             by_q[qb, s:e] = 1
             by_q[qb, e1:e2] = 2
-        for kb in range(nk):
-            s, e1, e2, e = A._q_runs(kb, bq, bk, nq, causal, window)
-            by_k[s:e, kb] = 1
-            by_k[e1:e2, kb] = 2
         np.testing.assert_array_equal(by_q, want)
-        np.testing.assert_array_equal(by_k, want)
         assert A.flash_block_classes(Tq, Tk, bq, bk, causal, window) == {
             "interior": int((want == 2).sum()), "edge": int((want == 1).sum()),
             "hidden": int((want == 0).sum())}
@@ -401,9 +397,73 @@ class TestFlashBlockClasses:
             "interior": 168, "edge": 48, "hidden": 296}
 
 
+def _fused_bwd_cases():
+    """float32 over the whole cross; bfloat16 where the benchmark trains
+    (causal, GQA 4:1) over every band and shape."""
+    for shape in ("square", "tk_gt_tq", "segments"):
+        for causal in (True, False):
+            for window in ("none", "inside_a_block", "several_blocks"):
+                for n_rep in (1, 4):
+                    yield pytest.param(causal, window, n_rep, shape, "float32",
+                                       id=f"{shape}-{'causal' if causal else 'full'}-{window}-rep{n_rep}-float32")
+    for shape in ("square", "tk_gt_tq", "segments"):
+        for window in ("none", "inside_a_block", "several_blocks"):
+            yield pytest.param(True, window, 4, shape, "bfloat16", id=f"{shape}-causal-{window}-rep4-bfloat16")
+
+
+class TestFusedFlashBackward:
+    """The one backward call (`flash_bwd`: a tile's s, p, dp and ds formed once
+    and fed to dv, dk and dq) against `jax.grad` of the plain reference."""
+
+    BQ, BK, TQ, D = 128, 128, 512, 64
+
+    @pytest.mark.parametrize("causal,window,n_rep,shape,dtype", _fused_bwd_cases())
+    def test_gradients_match_the_reference(self, causal, window, n_rep, shape, dtype):
+        """4 x 4 (or 4 x 6) blocks of 128: with a band of 300 one run holds
+        interior, diagonal and window-edge pairs and hidden ones on both sides;
+        a band of 64 lies inside a block, where one tile owes both edges."""
+        bq, bk, Tq, D = self.BQ, self.BK, self.TQ, self.D
+        Tk = Tq + 2 * bk if shape == "tk_gt_tq" else Tq
+        window = {"none": 0, "inside_a_block": 64, "several_blocks": 300}[window]
+        if causal and window == 300 and shape != "tk_gt_tq":
+            classes = A.flash_block_classes(Tq, Tk, bq, bk, causal, window)
+            assert min(classes.values()) > 0, classes
+        H, Hkv = 4, 4 // n_rep
+        ks = [jax.random.fold_in(jax.random.PRNGKey(52), i) for i in range(4)]
+        q = jax.random.normal(ks[0], (1, H, Tq, D), jnp.float32) * 0.5
+        k = jax.random.normal(ks[1], (1, Hkv, Tk, D), jnp.float32) * 0.5
+        v = jax.random.normal(ks[2], (1, Hkv, Tk, D), jnp.float32) * 0.5
+        do = jax.random.normal(ks[3], q.shape, jnp.float32)
+        seg = None
+        if shape == "segments":  # three segments, their edges inside blocks
+            seg = jnp.searchsorted(jnp.array([90, 301]), jnp.arange(Tq), side="right")[None, :].astype(jnp.int32)
+
+        def ref(q, k, v):
+            k, v = A.repeat_kv(k, n_rep), A.repeat_kv(v, n_rep)
+            if Tk > Tq:
+                return _absolute_reference(q, k, v, causal, window)
+            return A.attention_reference(q, k, v, causal=causal, segment_ids=seg, window=window)
+
+        want = jax.vjp(ref, q, k, v)[1](do)
+        dt = jnp.dtype(dtype)
+        q, k, v, do = (x.astype(dt) for x in (q, k, v, do))
+        o, lse = A._flash_fwd_lanes(q, k, v, causal, bq, bk, seg, window)
+        got = A._flash_bwd_impl(q, k, v, o, lse, do, causal, bq, bk, seg, window)
+        for name, a, b in zip("dq dk dv".split(), got, want):
+            assert a.dtype == dt and a.shape == b.shape, name
+            a = np.asarray(a.astype(jnp.float32))
+            if dtype == "float32":
+                err, tol = np.max(np.abs(a - b)) / np.max(np.abs(b)), 2e-4
+            else:  # the benchmark's band for a gradient (`grad_rel_rms`, benchmark/check.py)
+                err, tol = np.sqrt(np.mean((a - b) ** 2) / np.mean(np.asarray(b) ** 2)), 0.015
+            assert err < tol, f"{name} rel err {err}"
+        if causal and Tk > Tq:  # keys no query reaches: exact zeros
+            assert not np.asarray(got[1][:, :, Tq:]).any() and not np.asarray(got[2][:, :, Tq:]).any()
+
+
 class TestSegmentIds:
     """Packed-sequence (segment-id) masking: reference semantics + the flash
-    kernels (fwd, dq, resident dkv, streaming dkv) in interpret mode."""
+    kernels (the forward, the one backward) in interpret mode."""
 
     def _packed(self, B=1, H=2, T=512, D=64, n_seg=3, seed=23):
         ks = [jax.random.fold_in(jax.random.PRNGKey(seed), i) for i in range(4)]
@@ -456,14 +516,10 @@ class TestSegmentIds:
             err = float(jnp.max(jnp.abs(a - b))) / scale
             assert err < 2e-4, f"{name} rel err {err}"
 
-    def test_flash_bwd_streaming_variant(self, monkeypatch):
-        monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
-        self.test_flash_bwd_matches_reference()
-
 
 class TestSlidingWindow:
     """Mistral/Mixtral-style sliding-window attention: reference semantics +
-    all four flash kernels (fwd, dq, resident dkv, streaming dkv)."""
+    both flash kernels (the forward, the one backward)."""
 
     def _qkv(self, B=1, H=4, Hkv=2, T=768, D=64, seed=31):
         ks = [jax.random.fold_in(jax.random.PRNGKey(seed), i) for i in range(3)]
@@ -518,27 +574,18 @@ class TestSlidingWindow:
                 err = float(jnp.max(jnp.abs(a - b))) / scale
                 assert err < 2e-4, f"window={window} {name} rel err {err}"
 
-    def test_flash_bwd_streaming_variant(self, monkeypatch):
-        monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
-        self.test_flash_bwd_matches_reference()
-
-    @pytest.mark.parametrize("dkv", ["resident", "streaming", "streaming_own_q_block"])
     @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
     @pytest.mark.parametrize("blocks", [(128, 256), (256, 128)])
-    def test_every_block_class_through_all_four_kernels(self, monkeypatch, blocks, dtype, dkv):
-        """T 1024, window 512, GQA 4:2: every q block's k loop (and every k
-        block's q loop) has hidden blocks, blocks an edge crosses and interior
-        runs, two blocks long where the k block is the smaller. float32 at the
-        file's tolerances; bfloat16 (operands straight into the MXU, p and ds
-        cast to it) against the float32 reference at a bfloat16 rounding."""
+    def test_every_block_class_through_both_kernels(self, blocks, dtype):
+        """T 1024, window 512, GQA 4:2: every q block's k loop has hidden
+        blocks, blocks an edge crosses and interior runs, two blocks long
+        where the k block is the smaller. float32 at the file's tolerances;
+        bfloat16 (operands straight into the MXU, p and ds cast to it)
+        against the float32 reference at a bfloat16 rounding."""
         bq, bk = blocks
         T, window = 1024, 512
         classes = A.flash_block_classes(T, T, bq, bk, True, window)
         assert min(classes.values()) > 0, classes
-        if dkv != "resident":
-            monkeypatch.setattr(A, "_DKV_RESIDENT_MAX_QROWS", 0)
-        if dkv == "streaming":  # the geometry above in the streaming grid too
-            monkeypatch.setattr(A, "_DKV_STREAM_BLOCK_Q", bq)
         q, k, v = self._qkv(T=T)
         do = jax.random.normal(jax.random.PRNGKey(5), q.shape, jnp.float32)
 

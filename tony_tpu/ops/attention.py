@@ -89,8 +89,8 @@ def _seg_arrays(segment_ids: jax.Array, B: int, T: int) -> tuple[jax.Array, jax.
 # ---------------------------------------------------------------------------
 
 def _imin(a, b):
-    """min over Python ints (the block counts, the streaming pair list) and
-    traced scalars (a kernel's loop bounds) alike."""
+    """min over Python ints (the block counts) and traced scalars (a kernel's
+    loop bounds) alike."""
     return min(a, b) if isinstance(a, int) and isinstance(b, int) else jnp.minimum(a, b)
 
 
@@ -115,20 +115,6 @@ def _k_runs(qb, block_q: int, block_k: int, nk: int, causal: bool, window: int):
     return start, e1, e2, end
 
 
-def _q_runs(kb, block_q: int, block_k: int, nq: int, causal: bool, window: int):
-    """``_k_runs`` from the k block's side (the dkv kernels): the q blocks k
-    block ``kb`` is visible to, [s, e1) crossed by the diagonal, [e1, e2)
-    interior, [e2, e) crossed by the window's edge."""
-    k_lo, k_hi = kb * block_k, kb * block_k + block_k - 1
-    start = _imin(nq, k_lo // block_q) if causal else 0
-    end = _imin(nq, (k_hi + window - 1) // block_q + 1) if window > 0 else nq
-    int_start = (k_hi + block_q - 1) // block_q if causal else 0
-    int_end = (k_lo + window) // block_q if window > 0 else nq
-    e1 = _imin(_imax(int_start, start), end)
-    e2 = _imin(_imax(int_end, e1), end)
-    return start, e1, e2, end
-
-
 def _wide_band(block_q: int, block_k: int, window: int) -> bool:
     """A band at least block_q + block_k - 2 wide keeps its two edges in
     different blocks: an edge block then owes the mask of one edge only."""
@@ -136,8 +122,8 @@ def _wide_band(block_q: int, block_k: int, window: int) -> bool:
 
 
 def _edge_masks(block_q: int, block_k: int, causal: bool, window: int):
-    """(causal, window) masks each of the three runs of ``_k_runs`` /
-    ``_q_runs`` owes, as ((diagonal run), (interior run), (window-edge run))."""
+    """(causal, window) masks each of the three runs of ``_k_runs`` owes, as
+    ((diagonal run), (interior run), (window-edge run))."""
     wide = _wide_band(block_q, block_k, window)
     return (causal, window > 0 and not wide), (False, False), (causal and not wide, window > 0)
 
@@ -454,22 +440,37 @@ def flash_attention(
     return _flash_fwd_impl(q, k, v, causal, block_q, block_k, segment_ids, window)[0]
 
 
-def _flash_bwd_dq_kernel(
+def _flash_bwd_kernel(
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_k: int, causal: bool, has_seg: bool, window: int, scale: float, steady,
+    block_k: int, num_q_blocks: int, causal: bool, has_seg: bool, window: int, scale: float, steady,
 ):
-    """Grid: (B*H, Tq//block_q). dq[i] = scale · Σ_kb ds[i,kb] @ k[kb], over
-    the forward's three runs of k blocks (``_k_runs``): hidden blocks are not
-    visited, interior blocks are not masked, an edge block pays its one edge."""
+    """Grid: (B*Hkv, n_rep * Tq//block_q): one q block of one query head of a
+    kv head's group a step, that kv head's k and v whole in VMEM (as the
+    forward holds them) and its dk and dv beside them as float32 output blocks
+    that stay resident until the group is done. A (q block, k block) pair forms
+    s, p = exp(s - lse), dp and ds ONCE and feeds all three gradients from them:
+    dv[kb] += p^T do, dk[kb] += ds^T (scale q), dq += ds k[kb]: five products
+    and one pass of exponentials a pair. The k loop is the forward's
+    (``_band_loop``): hidden blocks are not visited, interior blocks are not
+    masked, an edge block pays its one edge. The group's query heads are folded
+    into the q rows (layout [B*Hkv, n_rep*Tq, ...]), so a kv head's dk and dv
+    sum over them in place; a k block no q block sees keeps its zeros."""
     from jax.experimental import pallas as pl
 
     if has_seg:
-        segq_ref, segk_ref, dq_ref = rest
+        segq_ref, segk_ref, dq_ref, dk_ref, dv_ref = rest
     else:
-        (dq_ref,) = rest
+        dq_ref, dk_ref, dv_ref = rest
     block_q, D = q_ref.shape
     Tk = k_ref.shape[0]
-    q_blk_idx = pl.program_id(1)
+    step = pl.program_id(1)
+    q_blk_idx = step % num_q_blocks  # the q block within its own head (positions)
+
+    @pl.when(step == 0)
+    def _init():
+        dk_ref[:] = jnp.zeros_like(dk_ref)
+        dv_ref[:] = jnp.zeros_like(dv_ref)
+
     q = _scaled(q_ref[:], scale)
     do = do_ref[:]
     lse = lse_ref[:][:, :1]            # [block_q, 1] (lanes identical)
@@ -486,10 +487,13 @@ def _flash_bwd_dq_kernel(
                          sq, segk_ref[:1, ks] if has_seg else None)
             p = jnp.exp(s - lse)                                   # [block_q, block_k]
             dp = jax.lax.dot_general(do, v_blk, _NT, preferred_element_type=jnp.float32)
-            ds = p * (dp - delta)
-            return dq + jax.lax.dot_general(
-                ds.astype(k_blk.dtype), k_blk, _NN, preferred_element_type=jnp.float32
-            )
+            # p and ds meet the MXU in the type of the operand they meet
+            ds = (p * (dp - delta)).astype(q.dtype)
+            dv_ref[ks, :] += jax.lax.dot_general(                  # p^T @ do
+                p.astype(do.dtype), do, _TN, preferred_element_type=jnp.float32)
+            dk_ref[ks, :] += jax.lax.dot_general(                  # ds^T @ (scale · q)
+                ds, q, _TN, preferred_element_type=jnp.float32)
+            return dq + jax.lax.dot_general(ds, k_blk, _NN, preferred_element_type=jnp.float32)
 
         return tile
 
@@ -498,164 +502,18 @@ def _flash_bwd_dq_kernel(
     dq_ref[:] = (scale * dq).astype(dq_ref.dtype)
 
 
-def _dkv_block_contrib(
-    q_blk, do_blk, lse_blk, delta_blk, k, v, q_pos, k_pos, mask_causal: bool, window: int,
-    sq=None, sk=None,
-):
-    """One q-block's contribution to (dk, dv) for one k block — the shared
-    gradient math of both dkv variants (they differ only in data staging).
-    ``q_blk`` arrives scaled (``_scaled``), so the scores and dk both carry
-    the softmax scale; ``mask_causal`` and ``window`` are what the block's
-    class owes (False and 0 on an interior block). Operands go to the MXU in
-    their own type, p and ds in the type of the operand they meet."""
-    s = jax.lax.dot_general(q_blk, k, _NT, preferred_element_type=jnp.float32)  # [block_q, block_k]
-    s = _visible(s, q_pos, k_pos, mask_causal, window, sq, sk)
-    p = jnp.exp(s - lse_blk)
-    dv_c = jax.lax.dot_general(                    # p^T @ do
-        p.astype(do_blk.dtype), do_blk, _TN, preferred_element_type=jnp.float32
-    )
-    dp = jax.lax.dot_general(do_blk, v, _NT, preferred_element_type=jnp.float32)  # do @ v^T
-    ds = p * (dp - delta_blk)
-    dk_c = jax.lax.dot_general(                    # ds^T @ (scale · q)
-        ds.astype(q_blk.dtype), q_blk, _TN, preferred_element_type=jnp.float32
-    )
-    return dk_c, dv_c
-
-
-def _flash_bwd_dkv_kernel_resident(
-    q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    block_q: int, n_rep: int, causal: bool, has_seg: bool, window: int, scale: float,
-):
-    """Grid: (B*Hkv, Tk//block_k) with the whole [n_rep·Tq, D] q/do staged in
-    VMEM — the fast variant for moderate sequence lengths. The q loop is cut
-    by block class (``_q_runs``): q blocks the band hides from this k block
-    cost neither DMA nor flops, interior blocks no mask, and the blocks the
-    diagonal or the window's edge crosses that one mask. Selected when the
-    staged operands fit the VMEM budget."""
-    from jax.experimental import pallas as pl
-
-    if has_seg:
-        segq_ref, segk_ref, dk_ref, dv_ref = rest
-    else:
-        dk_ref, dv_ref = rest
-    block_k, D = k_ref.shape
-    Tq = q_ref.shape[0] // n_rep
-    k_blk_idx = pl.program_id(1)
-    k, v = k_ref[:], v_ref[:]
-    k_pos = _k_pos(k_blk_idx, block_k)
-    sk = segk_ref[:1, :] if has_seg else None  # [1, block_k] (this k block)
-
-    def run(g_off: int, lo, hi, carry, mask_causal: bool, mask_window: bool):
-        def tile(qb, carry):
-            dk, dv = carry
-            rows = pl.ds(pl.multiple_of(g_off + qb * block_q, block_q), block_q)
-            q_blk = _scaled(q_ref[rows, :], scale)
-            lse_blk = lse_ref[rows, :][:, :1]
-            delta_blk = delta_ref[rows, :][:, :1]
-            # seg rows are PER HEAD (not group-folded): index by qb directly
-            sq = segq_ref[pl.ds(qb * block_q, block_q), :][:, :1] if has_seg else None
-            dk_c, dv_c = _dkv_block_contrib(
-                q_blk, do_ref[rows, :], lse_blk, delta_blk, k, v, _q_pos(qb, block_q), k_pos,
-                mask_causal, window if mask_window else 0, sq, sk,
-            )
-            return dk + dk_c, dv + dv_c
-
-        # a tile a step: the staged rows leave no VMEM for an unrolled step's values
-        return jax.lax.fori_loop(lo, hi, tile, carry)
-
-    start, e1, e2, end = _q_runs(k_blk_idx, block_q, block_k, pl.cdiv(Tq, block_q), causal, window)
-    diagonal, interior, window_edge = _edge_masks(block_q, block_k, causal, window)
-    zeros = jnp.zeros((block_k, D), jnp.float32)
-    carry = (zeros, zeros)
-    for g in range(n_rep):  # static group unroll
-        if causal:
-            carry = run(g * Tq, start, e1, carry, *diagonal)
-        carry = run(g * Tq, e1, e2, carry, *interior)
-        if window > 0:
-            carry = run(g * Tq, e2, end, carry, *window_edge)
-    dk_ref[:] = carry[0].astype(dk_ref.dtype)
-    dv_ref[:] = carry[1].astype(dv_ref.dtype)
-
-
-# staged q/do bytes (bf16, double-buffered) beyond which the resident dkv
-# variant would exceed the ~16M scoped-VMEM budget → use the streaming grid
-_DKV_RESIDENT_MAX_QROWS = 4096
-# rows of q a streaming dkv grid step takes (the largest doubling of the
-# backward's block_q that divides Tq, up to this)
-_DKV_STREAM_BLOCK_Q = 1024
-
-
-def _flash_bwd_dkv_kernel(
-    kb_ref, qrow_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, *rest,
-    num_q_blocks: int, causal: bool, has_seg: bool, window: int, scale: float,
-):
-    """Grid: (B*Hkv, n_pairs) — one (k block, q block) pair that holds a
-    visible pair per step, streamed via scalar-prefetched index arrays.
-
-    Only one q block is staged in VMEM per step (long sequences would blow
-    the VMEM budget if the whole [n_rep·Tq, D] q were staged, as an earlier
-    design did), and — unlike a dense (k block × q block) grid — pairs the
-    band hides are never enumerated, so they cost neither DMA nor a grid
-    step. dk/dv output blocks are revisited across consecutive pairs of
-    the same k block (pairs are sorted by k block), accumulating in f32 in
-    VMEM; GQA group members are folded into the q dim (layout
-    [B*Hkv, n_rep*Tq, …]), so each pair's q-block index within its own head
-    (for position masking) is ``qrow % num_q_blocks``. The step's pair takes
-    the body of its class, worked out from the two indices: no mask on an
-    interior pair, the one mask of the edge that crosses it otherwise. What a
-    step costs outside its matmuls (the pipeline's step, the float32
-    read-modify-write of dk / dv) is by the step, so this call's q block is
-    its own and larger (``_DKV_STREAM_BLOCK_Q``).
-    """
-    from jax.experimental import pallas as pl
-
-    if has_seg:
-        segq_ref, segk_ref, dk_ref, dv_ref = rest
-    else:
-        dk_ref, dv_ref = rest
-    block_q = q_ref.shape[0]
-    block_k = k_ref.shape[0]
-    j = pl.program_id(1)
-    k_blk_idx = kb_ref[j]
-    qb = qrow_ref[j] % num_q_blocks  # q-block index within this member's head
-    first = jnp.logical_or(j == 0, k_blk_idx != kb_ref[jnp.maximum(j - 1, 0)])
-
-    @pl.when(first)
-    def _init():
-        dk_ref[:] = jnp.zeros_like(dk_ref)
-        dv_ref[:] = jnp.zeros_like(dv_ref)
-
-    # the pair's class from its indices (the rule of `_q_runs`): the diagonal
-    # crosses it unless min q_pos >= max k_pos, the window's edge unless
-    # max q_pos - min k_pos < window. The one visit a wholly hidden k block
-    # keeps (Tk > Tq) lies above the diagonal: masked to exact zeros.
-    q_lo, k_lo = qb * block_q, k_blk_idx * block_k
-    on_diagonal = q_lo < k_lo + block_k - 1 if causal else False
-    on_window_edge = q_lo + block_q - 1 - k_lo >= window if window > 0 else False
-
-    def contribute(mask_causal: bool, mask_window: bool):
-        dk_c, dv_c = _dkv_block_contrib(
-            _scaled(q_ref[:], scale), do_ref[:], lse_ref[:][:, :1], delta_ref[:][:, :1],
-            k_ref[:], v_ref[:], _q_pos(qb, block_q), _k_pos(k_blk_idx, block_k),
-            mask_causal, window if mask_window else 0,
-            segq_ref[:][:, :1] if has_seg else None, segk_ref[:1, :] if has_seg else None,
-        )
-        dk_ref[:] += dk_c
-        dv_ref[:] += dv_c
-
-    for mask_causal in (False, True) if causal else (False,):
-        for mask_window in (False, True) if window > 0 else (False,):
-            if mask_causal and mask_window and _wide_band(block_q, block_k, window):
-                continue  # the band's two edges never share a block
-            pl.when(jnp.logical_and(on_diagonal == mask_causal, on_window_edge == mask_window))(
-                functools.partial(contribute, mask_causal, mask_window))
+# what the backward asks of the chip's 128 MiB of VMEM: a kv head's k and v and
+# its float32 dk and dv stay resident (double-buffered, 25 MB at 8192 x 128 in
+# bfloat16) beside a steady q block's tiles, past the default scoped 16 MiB
+_BWD_VMEM_LIMIT = 100 * 1024 * 1024
 
 
 def _flash_bwd_impl(
     q, k, v, o, lse, do, causal: bool, block_q: int, block_k: int,
     segment_ids: jax.Array | None = None, window: int = 0,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
-    """Pallas flash backward: recompute p blockwise from (q, k, lse)."""
+    """Pallas flash backward, one call: p recomputed blockwise from (q, k, lse),
+    once a (q block, k block) pair, for dq, dk and dv together."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -663,192 +521,59 @@ def _flash_bwd_impl(
     Hkv, Tk = k.shape[1], k.shape[2]
     n_rep = H // Hkv
     scale = D ** -0.5
-    qf = q.reshape(B * H, Tq, D)
+    num_q_blocks = Tq // block_q
+    # the GQA group folded into the q rows: [B*Hkv, n_rep*Tq, ...]
+    qg = q.reshape(B * Hkv, n_rep * Tq, D)
+    dog = do.reshape(B * Hkv, n_rep * Tq, D)
     kf = k.reshape(B * Hkv, Tk, D)
     vf = v.reshape(B * Hkv, Tk, D)
-    dof = do.reshape(B * H, Tq, D)
-    lsef = lse.reshape(B * H, Tq, _STAT_LANES)  # lane-replicated from the fwd
+    lseg = lse.reshape(B * Hkv, n_rep * Tq, _STAT_LANES)  # lane-replicated from the fwd
     # delta[i] = rowsum(do ⊙ o): the softmax-normalization term of ds
-    delta = jnp.sum(
-        dof.astype(jnp.float32) * o.reshape(B * H, Tq, D).astype(jnp.float32), axis=-1
-    )
-    delta = jnp.broadcast_to(delta[:, :, None], (B * H, Tq, _STAT_LANES))
+    delta = jnp.sum(dog.astype(jnp.float32) * o.reshape(qg.shape).astype(jnp.float32), axis=-1)
+    deltag = jnp.broadcast_to(delta[:, :, None], lseg.shape)
 
-    full_k = pl.BlockSpec((None, Tk, D), lambda b, i: (b // n_rep, 0, 0))
     blk_q = pl.BlockSpec((None, block_q, D), lambda b, i: (b, i, 0))
-    blk_k = pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0))
     row_q = pl.BlockSpec((None, block_q, _STAT_LANES), lambda b, i: (b, i, 0))
-
+    full_k = pl.BlockSpec((None, Tk, D), lambda b, i: (b, 0, 0))
+    in_specs = [blk_q, full_k, full_k, blk_q, row_q, row_q]
+    operands = [qg, kf, vf, dog, lseg, deltag]
     has_seg = segment_ids is not None
     if has_seg:
-        segq, segk = _seg_arrays(segment_ids, B, Tq)  # Tq == Tk (validated)
-
-    dq_specs = [blk_q, full_k, full_k, blk_q, row_q, row_q]
-    dq_operands = [qf, kf, vf, dof, lsef, delta]
-    if has_seg:
-        dq_specs += [
-            pl.BlockSpec((None, block_q, _STAT_LANES), lambda b, i: (b // H, i, 0)),
-            pl.BlockSpec((None, _STAT_LANES, Tk), lambda b, i: (b // H, 0, 0)),
+        # seg arrays are [B, ...] per head (not group-folded): batch = b // Hkv,
+        # q block within its head = i % num_q_blocks
+        in_specs += [
+            pl.BlockSpec((None, block_q, _STAT_LANES), lambda b, i: (b // Hkv, i % num_q_blocks, 0)),
+            pl.BlockSpec((None, _STAT_LANES, Tk), lambda b, i: (b // Hkv, 0, 0)),
         ]
-        dq_operands += [segq, segk]
-    dq = pl.pallas_call(
+        operands += list(_seg_arrays(segment_ids, B, Tq))  # Tq == Tk (validated)
+    # the pairs the walk visits, of the dense grid's: what the five products cost
+    classes = flash_block_classes(Tq, Tk, block_q, block_k, causal, window)
+    visited = B * H * (classes["interior"] + classes["edge"]) * block_q * block_k
+    itemsize = q.dtype.itemsize
+    dq, dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_bwd_dq_kernel, block_k=block_k, causal=causal, has_seg=has_seg,
-            window=window, scale=scale, steady=_steady_runs(Tq, Tk, block_q, block_k, causal, window),
+            _flash_bwd_kernel, block_k=block_k, num_q_blocks=num_q_blocks, causal=causal,
+            has_seg=has_seg, window=window, scale=scale,
+            steady=_steady_runs(Tq, Tk, block_q, block_k, causal, window),
         ),
-        grid=(B * H, Tq // block_q),
-        in_specs=dq_specs,
-        out_specs=blk_q,
-        out_shape=jax.ShapeDtypeStruct((B * H, Tq, D), q.dtype),
-        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        grid=(B * Hkv, n_rep * num_q_blocks),
+        in_specs=in_specs,
+        out_specs=[blk_q, full_k, full_k],
+        out_shape=[
+            jax.ShapeDtypeStruct(qg.shape, q.dtype),
+            jax.ShapeDtypeStruct(kf.shape, jnp.float32),
+            jax.ShapeDtypeStruct(vf.shape, jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_BWD_VMEM_LIMIT),
         interpret=interpret(),
-        name="flash_bwd_dq",
+        name="flash_bwd",
         cost_estimate=pl.CostEstimate(
-            flops=6 * B * H * Tq * Tk * D,
-            bytes_accessed=3 * (qf.size + kf.size) * q.dtype.itemsize,
-            transcendentals=B * H * Tq * Tk,
+            flops=5 * 2 * visited * D,
+            bytes_accessed=(3 * qg.size + 2 * kf.size) * itemsize + 2 * kf.size * 4,
+            transcendentals=visited,
         ),
-    )(*dq_operands)
-
-    # dk/dv: grid over (kv head, k block, group-member × q block); the GQA
-    # group is folded into the q dim (layout [B*Hkv, n_rep*Tq, …]) and the
-    # innermost grid dim walks one q block at a time — O(block) VMEM at any
-    # sequence length, with dk/dv blocks revisited and accumulated in f32.
-    resident = n_rep * Tq <= _DKV_RESIDENT_MAX_QROWS
-    if not resident:
-        # the streaming grid pays by the step, so its q block is its own
-        while block_q * 2 <= _DKV_STREAM_BLOCK_Q and Tq % (block_q * 2) == 0:
-            block_q *= 2
-    num_q_blocks = Tq // block_q
-    qg = qf.reshape(B * Hkv, n_rep * Tq, D)
-    dog = dof.reshape(B * Hkv, n_rep * Tq, D)
-    lseg = lsef.reshape(B * Hkv, n_rep * Tq, _STAT_LANES)
-    deltag = delta.reshape(B * Hkv, n_rep * Tq, _STAT_LANES)
-    blk_kv2 = pl.BlockSpec((None, block_k, D), lambda b, i: (b, i, 0))
-    cost = pl.CostEstimate(
-        flops=8 * B * H * Tq * Tk * D,
-        bytes_accessed=3 * (qf.size + kf.size) * q.dtype.itemsize,
-        transcendentals=B * H * Tq * Tk,
-    )
-
-    if resident:
-        full_qg = pl.BlockSpec((None, n_rep * Tq, D), lambda b, i: (b, 0, 0))
-        row_full_g = pl.BlockSpec((None, n_rep * Tq, _STAT_LANES), lambda b, i: (b, 0, 0))
-        dkv_specs = [full_qg, blk_kv2, blk_kv2, full_qg, row_full_g, row_full_g]
-        dkv_operands = [qg, kf, vf, dog, lseg, deltag]
-        if has_seg:
-            dkv_specs += [
-                # per-head q rows (NOT group-folded; kernel indexes by qb)
-                pl.BlockSpec((None, Tq, _STAT_LANES), lambda b, i: (b // Hkv, 0, 0)),
-                pl.BlockSpec((None, _STAT_LANES, block_k), lambda b, i: (b // Hkv, 0, i)),
-            ]
-            dkv_operands += [segq, segk]
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _flash_bwd_dkv_kernel_resident,
-                block_q=block_q, n_rep=n_rep, causal=causal, has_seg=has_seg,
-                window=window, scale=scale,
-            ),
-            grid=(B * Hkv, Tk // block_k),
-            in_specs=dkv_specs,
-            out_specs=[blk_kv2, blk_kv2],
-            out_shape=[
-                jax.ShapeDtypeStruct((B * Hkv, Tk, D), k.dtype),
-                jax.ShapeDtypeStruct((B * Hkv, Tk, D), v.dtype),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
-            ),
-            interpret=interpret(),
-            name="flash_bwd_dkv",
-            cost_estimate=cost,
-        )(*dkv_operands)
-    else:
-        # streaming grid: enumerate only the (k block, group member, q block)
-        # pairs that hold a visible pair (`_q_runs`), sorted by k block, and
-        # scalar-prefetch the index arrays so BlockSpec index maps (and the
-        # DMA pipeline) follow the sparse walk — q blocks the band hides are
-        # never fetched, halving DMA traffic and grid steps for causal.
-        kb_l, qrow_l = [], []
-        for i in range(Tk // block_k):
-            qb0, _, _, qb1 = _q_runs(i, block_q, block_k, num_q_blocks, causal, window)
-            # a wholly hidden k block (possible when Tk > Tq) still emits ONE
-            # q block per group member: its contribution is exactly zero
-            # through the mask, but the visit zero-initializes the output
-            # block, which would otherwise be returned uninitialized
-            qb0 = min(qb0, num_q_blocks - 1)
-            for g in range(n_rep):
-                for qb in range(qb0, max(qb1, qb0 + 1)):
-                    kb_l.append(i)
-                    qrow_l.append(g * num_q_blocks + qb)
-        kb = jnp.array(kb_l, dtype=jnp.int32)
-        qrow = jnp.array(qrow_l, dtype=jnp.int32)
-        n_pairs = len(kb_l)
-        # the sparse walk does `frac` of the dense grid's work (~1/2 causal)
-        frac = n_pairs / ((Tk // block_k) * n_rep * num_q_blocks)
-        cost = pl.CostEstimate(
-            flops=int(cost.flops * frac),
-            bytes_accessed=int(cost.bytes_accessed * frac),
-            transcendentals=int(cost.transcendentals * frac),
-        )
-
-        def q_map(b, j, kb_r, qrow_r):
-            return (b, qrow_r[j], 0)
-
-        def kv_map(b, j, kb_r, qrow_r):
-            return (b, kb_r[j], 0)
-
-        stream_specs = [
-            pl.BlockSpec((None, block_q, D), q_map),
-            pl.BlockSpec((None, block_k, D), kv_map),
-            pl.BlockSpec((None, block_k, D), kv_map),
-            pl.BlockSpec((None, block_q, D), q_map),
-            pl.BlockSpec((None, block_q, _STAT_LANES), q_map),
-            pl.BlockSpec((None, block_q, _STAT_LANES), q_map),
-        ]
-        stream_operands = [qg, kf, vf, dog, lseg, deltag]
-        if has_seg:
-            # seg arrays are [B, ...] per-head (not group-folded): batch =
-            # b // Hkv, q block within head = qrow % num_q_blocks
-            stream_specs += [
-                pl.BlockSpec(
-                    (None, block_q, _STAT_LANES),
-                    lambda b, j, kb_r, qrow_r: (b // Hkv, qrow_r[j] % num_q_blocks, 0),
-                ),
-                pl.BlockSpec(
-                    (None, _STAT_LANES, block_k),
-                    lambda b, j, kb_r, qrow_r: (b // Hkv, 0, kb_r[j]),
-                ),
-            ]
-            stream_operands += [segq, segk]
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(B * Hkv, n_pairs),
-            in_specs=stream_specs,
-            out_specs=[
-                pl.BlockSpec((None, block_k, D), kv_map),
-                pl.BlockSpec((None, block_k, D), kv_map),
-            ],
-        )
-        dk, dv = pl.pallas_call(
-            functools.partial(
-                _flash_bwd_dkv_kernel,
-                num_q_blocks=num_q_blocks, causal=causal, has_seg=has_seg,
-                window=window, scale=scale,
-            ),
-            grid_spec=grid_spec,
-            out_shape=[
-                jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32),
-                jax.ShapeDtypeStruct((B * Hkv, Tk, D), jnp.float32),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")
-            ),
-            interpret=interpret(),
-            name="flash_bwd_dkv",
-            cost_estimate=cost,
-        )(kb, qrow, *stream_operands)
-
+    )(*operands)
     return (
         dq.reshape(B, H, Tq, D),
         dk.reshape(B, Hkv, Tk, D).astype(k.dtype),
@@ -859,29 +584,37 @@ def _flash_bwd_impl(
 # -- trainable flash attention: pallas forward + pallas backward -------------
 # pallas_call has no JVP rule (pallas guide §20: production kernels define a
 # custom VJP). The backward is the FlashAttention-2 scheme: forward saves the
-# per-row logsumexp; backward recomputes probabilities blockwise in VMEM (two
-# kernels: dq over q blocks, dk/dv over k blocks) — no T×T materialization.
+# per-row logsumexp; backward recomputes probabilities blockwise in VMEM (one
+# kernel over q blocks: a tile's p and ds feed dq, dk and dv) — no T×T
+# materialization.
 
 # bq 256 / bk 512, chosen on a v5e at the training cells' shape ([2·32, 8192,
 # 128] / 8 kv heads, bfloat16, band 4096; device time of a call from a trace,
 # PERF.md section 6, PR 40). Forward: 7.01 ms at 256 x 512, 7.57 at 256 x 1024,
 # 11.52 at 256 x 256; 512 x 512 no longer fits VMEM once a steady q block's
-# nine tiles are one basic block. dq: 8.36 ms at 256 x 512, 8.08 at 512 x 512,
-# 9.13 at 256 x 1024, 9.93 at 256 x 256. Both grids compute the same 28.3 M
-# pairs a head (1.12 x the band's); what a smaller k block saves in pairs it
-# loses twice over in loop steps. The streaming dkv pays by the grid step, so
-# it takes more q rows a step (`_DKV_STREAM_BLOCK_Q`): 19.09 ms at 256 q rows
-# x 512, 13.77 at 512, 12.14 at 1024, 14.36 at 2048 (1.5 x the band's pairs).
+# nine tiles are one basic block. The grid computes 28.3 M pairs a head (1.12 x
+# the band's); what a smaller k block saves in pairs it loses twice over in
+# loop steps.
 _BLOCK_Q = 256
 _BLOCK_K = 512
+# the one backward call's own, at the same shape (PERF.md section 6, PR 52):
+# 12.97 ms at 512 x 512, 13.97 at 256 x 512, 15.27 at 512 x 256, 14.43 at
+# 256 x 1024, 14.31 at 512 x 1024, 16.27 at 1024 x 256; a q block of 1024 rows
+# spills (24.0 ms at 1024 x 512, 28.3 at 1024 x 1024). A tile's float32
+# read-modify-write of dk and dv is by the tile, so the larger q block wins
+# where the forward's does not fit; 512 x 512 computes the forward's 28.3 M
+# pairs a head, its five products at 91% of the MXU's peak.
+_BWD_BLOCK_Q = 512
+_BWD_BLOCK_K = 512
 
 
-def _block_sizes(Tq: int, Tk: int) -> tuple[int, int]:
-    """Largest blocks ≤ `_BLOCK_Q` / `_BLOCK_K` that DIVIDE the sequence
-    lengths (halving until they do). With bq ≠ bk defaults, a length like
-    768 divides 256 but not 512 — every kernel entry point must agree on
-    this rule or the grid reads padded garbage past the last block."""
-    bq, bk = min(_BLOCK_Q, Tq), min(_BLOCK_K, Tk)
+def _block_sizes(Tq: int, Tk: int, block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K) -> tuple[int, int]:
+    """Largest blocks ≤ ``block_q`` / ``block_k`` (the forward's, by default)
+    that DIVIDE the sequence lengths (halving until they do). With bq ≠ bk
+    defaults, a length like 768 divides 256 but not 512 — every kernel entry
+    point must agree on this rule or the grid reads padded garbage past the
+    last block."""
+    bq, bk = min(block_q, Tq), min(block_k, Tk)
     while bq > 1 and Tq % bq:
         bq //= 2
     while bk > 1 and Tk % bk:
@@ -927,8 +660,7 @@ def _lse_lanes(lse: jax.Array) -> jax.Array:
 
 def _flash_bwd(causal, window, res, g):
     q, k, v, o, lse = res
-    Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _block_sizes(Tq, Tk)
+    bq, bk = _block_sizes(q.shape[2], k.shape[2], _BWD_BLOCK_Q, _BWD_BLOCK_K)
     return _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, None, window)
 
 
@@ -957,8 +689,7 @@ def _flash_seg_bwd(causal, window, res, g):
     import numpy as np
 
     q, k, v, seg, o, lse = res
-    Tq, Tk = q.shape[2], k.shape[2]
-    bq, bk = _block_sizes(Tq, Tk)
+    bq, bk = _block_sizes(q.shape[2], k.shape[2], _BWD_BLOCK_Q, _BWD_BLOCK_K)
     dq, dk, dv = _flash_bwd_impl(q, k, v, o, _lse_lanes(lse), g, causal, bq, bk, seg, window)
     return dq, dk, dv, np.zeros(seg.shape, jax.dtypes.float0)
 
