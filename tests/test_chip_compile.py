@@ -526,3 +526,48 @@ class TestLatentAttentionAtTheDocqaCellsShapes:
                 _s((self.S,), i32, chip), _s((self.S, self.MAX // self.PAGE), i32, chip), _s((self.S, 128, self.ROW), bf, chip),
                 _s((), i32, chip))
         self._named(functools.partial(LA.latent_paged_decode, r=256, scale=0.195), "latent_paged_decode", *args)
+
+
+class TestStateSpaceAtTheAssistCellsShapes:
+    """`granite-4.0-h-small.serve_assist` (one chip's share of a pair): the Pallas
+    calls of a `mamba` layer at the published widths (128 heads of 64 over a state
+    of 128, 8448 convolution channels), found in a trace by these names: a
+    prefill chunk's blocked recurrence (a whole chunk of 2048 rows and the
+    smallest bucket's 256) from a request's float32 state `[128, 8192]`, a decode
+    step of 64 slots that reads and writes each slot's state IN PLACE, and the
+    convolution with its bias."""
+
+    S, H, P, N = 64, 128, 64, 128
+    _named = TestLatentAttentionAtTheNotesCellsShapes._named
+
+    @pytest.mark.parametrize("rows", [2048, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_the_blocked_recurrence(self, chip, rows):
+        from tony_tpu.ops import ssd
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        args = (_s((rows, self.H, self.P), bf, chip), _s((rows, self.H), f32, chip), _s((rows, self.H), f32, chip),
+                _s((rows, self.N), bf, chip), _s((rows, self.N), bf, chip), _s((self.H,), f32, chip),
+                _s((self.N, self.H * self.P), f32, chip), _s((), jnp.int32, chip))
+        self._named(ssd.ssd_chunk, "ssd_chunk", *args)
+
+    def test_the_decode_step_updates_the_state_in_place(self, chip):
+        from tony_tpu.ops import ssd
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        state = _s((self.S, self.N, self.H * self.P), f32, chip)
+        args = (_s((self.S, self.H, self.P), bf, chip), _s((self.S, self.H), f32, chip), _s((self.S, self.H), f32, chip),
+                _s((self.S, self.N), bf, chip), _s((self.S, self.N), bf, chip), _s((self.H,), f32, chip), state)
+        compiled = jax.jit(ssd.ssd_step, donate_argnums=(6,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and len(re.findall(r"%\w*ssd_step[\w.]* = ", text)) == 1
+        # the state goes out in the buffer it came in: no second 268 MB, no copy of it among the temporaries
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= 4 * self.S * self.N * self.H * self.P and memory.temp_size_in_bytes < 16 << 20
+
+    def test_the_convolution_with_a_bias(self, chip):
+        from tony_tpu.ops import delta_rule
+
+        bf = jnp.bfloat16
+        c = self.H * self.P + 2 * self.N
+        self._named(delta_rule.short_conv_chunk, "short_conv", _s((2048, c), bf, chip), _s((3, c), bf, chip), _s((4, c), bf, chip),
+                    _s((), jnp.int32, chip), _s((c,), bf, chip))

@@ -1,0 +1,469 @@
+"""The granite_hybrid family (Granite-4.0-H): a decoder whose layers take turns
+between two mixers, nine state-space ones to an attention one, with a routed
+FFN in EVERY layer, the recurrent ones too.
+
+``layer_types`` gives each layer its mixer:
+
+- ``mamba`` — a Mamba-2 mixer (ops/ssd.py). One projection to ``[z | x B C | dt]``
+  (the gate, the convolution's channels, a step a head); a causal depthwise
+  convolution of ``conv_taps`` inputs over time with a bias, then SiLU, over ``x B
+  C``; ``x`` as ``ssm_heads`` heads of ``ssm_head_dim``, ``B`` and ``C`` of
+  ``ssm_state`` shared by every head (one group); ``dt = softplus(dt + dt_bias)``
+  and the log-decay ``g = -exp(A_log) dt`` a head, no clamp; the recurrence over a
+  float32 state ``[ssm_head_dim, ssm_state]`` a head (kept as ``[ssm_state, heads x
+  ssm_head_dim]``: ops/ssd.lanes), plus ``D x``; gate THEN
+  norm: ``y silu(z)``, RMSNorm over all of the inner width times a weight;
+  ``W_out``.
+- ``attention`` — grouped-query softmax attention with NO rotary embedding (the
+  state-space layers carry position), no q/k norm, no bias, scores times
+  ``attention_multiplier``.
+
+Every layer is pre-norm with a scaled branch: ``h = x + r Mixer(Norm(x))``, ``y = h
++ r (Routed(n) + Shared(n))``, ``n = Norm(h)``, ``r = residual_multiplier``. The
+routed FFN is a float32 router over ``num_experts``, the ``top_k`` largest logits
+chosen, gates the softmax over those (the same numbers as a softmax over all of
+them renormalised over the chosen), each expert a SwiGLU of ``d_expert``; the
+shared expert a SwiGLU of ``d_shared``. The replica holds ``held`` of the experts
+(parallel/expert.held_expert_ffn): what the absent experts would add is left out,
+and no code stands in for them. The embedding is multiplied by
+``embedding_multiplier``; the head is the embedding, tied, its logits divided by
+``logits_scaling``. Each of these choices is one function here and one in the
+benchmark's reference (benchmark/families/granite_hybrid_reference.py; the
+configuration's ``assumed``).
+
+``params["layers"]`` is a list with one dict of leaves a layer, in order, shaped
+by the layer's mixer (two shapes: unrolled, not scanned); the held experts'
+banks are every layer's, STACKED (``we_gate``, ``we_up``, ``we_down``: the grouped
+kernel takes the stack and a layer index; a slice handed to a Mosaic call is a
+copy). ``forward`` is the whole-sequence program; ``serving_programs`` is what
+the serving engine asks for (models/serving.py): the llama family's paged K/V
+pool over the ATTENTION layers only (one layer in ten), beside it a state-space
+state (a buffer a layer, updated in place) and a convolution tail a slot. No
+prefix reuse: a page is not all a prefix leaves behind, and this family keeps no
+snapshot of its state (37.7 MB a slot at the published sizes). Served only: no
+train step (``ssd_chunk`` has no backward) and no sharding rules; one chip's
+share of the chips that share a layer.
+"""
+
+from __future__ import annotations
+
+import functools
+from dataclasses import dataclass
+from typing import NamedTuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from tony_tpu.ops import layers as L
+from tony_tpu.ops.delta_rule import short_conv_chunk, short_conv_step
+from tony_tpu.ops.ssd import ssd_chunk, ssd_step
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
+
+MAMBA, ATTENTION = "mamba", "attention"
+BANKS = ("we_gate", "we_up", "we_down")
+
+
+@dataclass(frozen=True)
+class GraniteHybridConfig:
+    vocab_size: int = 100_352
+    d_model: int = 4096
+    layer_types: tuple = ((MAMBA,) * 5 + (ATTENTION,) + (MAMBA,) * 4) * 4
+    n_heads: int = 32                 # attention layers: query heads
+    n_kv_heads: int = 8
+    head_dim: int = 128
+    ssm_heads: int = 128              # state-space layers
+    ssm_head_dim: int = 64
+    ssm_state: int = 128
+    conv_taps: int = 4
+    d_expert: int = 768
+    num_experts: int = 72
+    held: tuple = (0, 72)             # (first, count) of the experts this replica holds
+    top_k: int = 10
+    d_shared: int = 1536
+    embedding_multiplier: float = 12.0
+    residual_multiplier: float = 0.22
+    attention_multiplier: float = 1.0 / 128
+    logits_scaling: float = 16.0
+    max_seq: int = 131_072
+    norm_eps: float = 1e-5
+    page_len: int = 256               # serving: a prompt's last chunk is padded to a page times a power of two
+    dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        first, count = self.held
+        if first < 0 or count < 1 or first + count > self.num_experts:
+            raise ValueError(f"held {self.held} is not a range of the {self.num_experts} experts")
+        if set(self.layer_types) - {MAMBA, ATTENTION}:
+            raise ValueError(f"layer types {sorted(set(self.layer_types))}: each is {MAMBA!r} or {ATTENTION!r}")
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layer_types)
+
+    @property
+    def jdtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        """What the convolution runs over: x of every head, then B and C."""
+        return self.d_inner + 2 * self.ssm_state
+
+    @property
+    def moe(self) -> MoEConfig:
+        return MoEConfig(num_experts=self.num_experts, top_k=self.top_k, scoring="softmax", held=self.held)
+
+    def count(self, kind: str) -> int:
+        return sum(1 for m in self.layer_types if m == kind)
+
+
+GRANITE_HYBRID_TINY = GraniteHybridConfig(
+    vocab_size=256, d_model=64, layer_types=(MAMBA, MAMBA, ATTENTION, MAMBA) * 2, n_heads=4, n_kv_heads=2, head_dim=16,
+    ssm_heads=4, ssm_head_dim=32, ssm_state=16, d_expert=32, num_experts=8, held=(0, 4), top_k=3, d_shared=48,
+    attention_multiplier=1.0 / 16, max_seq=256, page_len=16, dtype="float32",
+)
+
+PRESETS = {"granite-hybrid-tiny": GRANITE_HYBRID_TINY}
+
+
+def init(key: jax.Array, cfg: GraniteHybridConfig) -> dict:
+    """The parameter tree (truncated normal, fan-in scaled; norms at one; the
+    router float32; the convolution's taps and bias fan-in scaled; ``A_log = log
+    U(1, 16)``, ``dt_bias`` the inverse softplus of ``exp U(log 0.001, log 0.1)`` and
+    ``D = 1``, all float32; the tied embedding fan-in scaled by ``embedding_multiplier^2
+    d_model``: a head's draw over the multiplier, or every position would predict
+    its own token by a margin no rounding moves). ``layers`` is a list with one dict of leaves a layer,
+    shaped by its mixer; the held experts' banks are stacked over the layers and
+    drawn a layer at a time (one draw of every layer's is a float32 temporary of
+    their whole size). ``w_in`` is the state-space projection's ``z | x B C``; its
+    ``dt`` columns are a leaf of their own (``w_dt``) whose product is float32."""
+    D, V, dt, n = cfg.d_model, cfg.vocab_size, cfg.jdtype, cfg.n_layers
+    Fe, Fs, held, E = cfg.d_expert, cfg.d_shared, cfg.held[1], cfg.num_experts
+    ks = iter(jax.random.split(key, 8 + 16 * n))
+
+    def draw(k, shape, fan_in, dtype=dt):
+        return (jax.random.truncated_normal(k, -2, 2, shape, jnp.float32) * fan_in ** -0.5).astype(dtype)
+
+    def dense(*shape, fan_in, dtype=dt):
+        return draw(next(ks), shape, fan_in, dtype)
+
+    def stack(*shape, fan_in):
+        return jax.lax.map(lambda k: draw(k, shape, fan_in), jax.random.split(next(ks), n))
+
+    def layer(kind):
+        lp = {"mixer_norm": jnp.ones((D,), dt), "ffn_norm": jnp.ones((D,), dt), "router": dense(D, E, fan_in=D, dtype=jnp.float32),
+              "ws_gate": dense(D, Fs, fan_in=D), "ws_up": dense(D, Fs, fan_in=D), "ws_down": dense(Fs, D, fan_in=Fs)}
+        if kind == ATTENTION:
+            q, kv = cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+            return {**lp, "w_qkv": dense(D, q + 2 * kv, fan_in=D), "wo": dense(q, D, fan_in=q)}
+        H, I, C = cfg.ssm_heads, cfg.d_inner, cfg.conv_channels
+        step = jnp.exp(jax.random.uniform(next(ks), (H,), jnp.float32, np.log(0.001), np.log(0.1)))
+        return {**lp, "w_in": dense(D, I + C, fan_in=D), "w_dt": dense(D, H, fan_in=D),
+                "conv": dense(cfg.conv_taps, C, fan_in=cfg.conv_taps), "conv_bias": dense(C, fan_in=cfg.conv_taps),
+                "A_log": jnp.log(jax.random.uniform(next(ks), (H,), jnp.float32, 1.0, 16.0)),
+                "dt_bias": step + jnp.log(-jnp.expm1(-step)), "D": jnp.ones((H,), jnp.float32),
+                "y_norm": jnp.ones((I,), dt), "w_out": dense(I, D, fan_in=I)}
+
+    # the tied matrix is drawn as a head is (fan-in d_model) over the embedding's multiplier: see the configuration's `assumed`
+    return {"embed": dense(V, D, fan_in=cfg.embedding_multiplier ** 2 * D), "layers": [layer(kind) for kind, _ in _layers(cfg)],
+            "we_gate": stack(held, D, Fe, fan_in=D), "we_up": stack(held, D, Fe, fan_in=D), "we_down": stack(held, Fe, D, fan_in=Fe),
+            "final_norm": jnp.ones((D,), dt)}
+
+
+# -- the layers, over [T, D] rows (a sequence's positions, or the slots' tokens) --------------------
+
+def _layers(cfg: GraniteHybridConfig):
+    """(kind, index among the layers of its kind) of every layer, in order: the
+    index is the layer's place in the cache of its kind (pages, or state)."""
+    seen = {MAMBA: 0, ATTENTION: 0}
+    for kind in cfg.layer_types:
+        yield kind, seen[kind]
+        seen[kind] += 1
+
+
+def _mm(x, w):
+    return jnp.einsum("...d,dh->...h", x, w)
+
+
+def _embed(params, tokens, cfg):
+    return (jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32) * cfg.embedding_multiplier).astype(cfg.jdtype)
+
+
+def _branch(x, y, cfg):
+    """The family's scaled residual: a branch is added times ``residual_multiplier``."""
+    return x + (cfg.residual_multiplier * y.astype(jnp.float32)).astype(x.dtype)
+
+
+def _ffn(x, lp, banks, li, cfg, live, name):
+    """x [T, D] -> (x + r (Routed + Shared)(Norm(x)), rows [count]: each held
+    expert's rows from the tokens `live` marks)."""
+    n = L.rms_norm(x, lp["ffn_norm"], cfg.norm_eps)
+    y, rows = held_expert_ffn(n, lp["router"], None, *banks, li, cfg.moe, count_mask=live, name=name)
+    return _branch(x, y + L.swiglu(n, lp["ws_gate"], lp["ws_up"], lp["ws_down"]), cfg), rows
+
+
+def _qkv(u, lp, cfg):
+    """u [T, D] -> q [T, H, dh] times what makes the kernels' ``dh^-0.5`` the
+    family's ``attention_multiplier``, k, v [T, Hkv, dh]; no rotary embedding."""
+    t, q_w, kv_w = u.shape[0], cfg.n_heads * cfg.head_dim, cfg.n_kv_heads * cfg.head_dim
+    qkv = _mm(u, lp["w_qkv"])
+    q = (qkv[:, :q_w].astype(jnp.float32) * (cfg.attention_multiplier * cfg.head_dim ** 0.5)).astype(qkv.dtype)
+    return (q.reshape(t, cfg.n_heads, cfg.head_dim), qkv[:, q_w:q_w + kv_w].reshape(t, cfg.n_kv_heads, cfg.head_dim),
+            qkv[:, q_w + kv_w:].reshape(t, cfg.n_kv_heads, cfg.head_dim))
+
+
+def _ssm_inputs(u, lp, cfg):
+    """u [T, D] -> (z [T, I] the gate, xBC [T, C] before the convolution, dt, g [T,
+    H] float32: the step softplus(dt + dt_bias) and the log-decay -exp(A_log) dt)."""
+    zx = _mm(u, lp["w_in"])
+    dt = jax.nn.softplus(jnp.einsum("td,dh->th", u, lp["w_dt"], preferred_element_type=jnp.float32) + lp["dt_bias"].astype(jnp.float32))
+    return zx[:, :cfg.d_inner], zx[:, cfg.d_inner:], dt, -jnp.exp(lp["A_log"].astype(jnp.float32)) * dt
+
+
+def _split(xbc, cfg):
+    """The convolution's output [T, C] -> x [T, H, P], B, C [T, N]."""
+    I, N = cfg.d_inner, cfg.ssm_state
+    return xbc[:, :I].reshape(-1, cfg.ssm_heads, cfg.ssm_head_dim), xbc[:, I:I + N], xbc[:, I + N:]
+
+
+def _ssm_out(y, z, u, lp, cfg):
+    """y [T, H, P] -> gate, THEN RMSNorm over the whole inner width, then W_out."""
+    gated = y.astype(jnp.float32).reshape(y.shape[0], -1) * jax.nn.silu(z.astype(jnp.float32))
+    return _mm(L.rms_norm(gated, lp["y_norm"].astype(jnp.float32), cfg.norm_eps).astype(u.dtype), lp["w_out"])
+
+
+def _finish(x, params, cfg):
+    """Rows of the trunk -> float32 logits over the held rows of the tied embedding."""
+    h = L.rms_norm(x, params["final_norm"], cfg.norm_eps)
+    return jnp.einsum("td,vd->tv", h, params["embed"], preferred_element_type=jnp.float32) / cfg.logits_scaling
+
+
+# -- a chunk of one sequence: prefill, and the whole-sequence forward --------------------------------
+
+class Staging(NamedTuple):
+    """A request mid-prefill: its attention layers' keys and values at their true
+    positions, its state-space layers' state and convolution tail after `length`
+    positions."""
+
+    k: jax.Array           # [La, 1, Hkv, max_len, dh]
+    v: jax.Array
+    state: jax.Array       # [Lm, N, H x P] float32 (ops/ssd.lanes: the state's index down the rows)
+    tail: jax.Array        # [Lm, taps - 1, C]
+    length: jax.Array      # [] int32
+
+
+def _init_staging(cfg: GraniteHybridConfig, max_len: int) -> Staging:
+    kv = (cfg.count(ATTENTION), 1, cfg.n_kv_heads, max_len, cfg.head_dim)
+    return Staging(jnp.zeros(kv, cfg.jdtype), jnp.zeros(kv, cfg.jdtype),
+                   jnp.zeros((cfg.count(MAMBA), cfg.ssm_state, cfg.d_inner), jnp.float32),
+                   jnp.zeros((cfg.count(MAMBA), cfg.conv_taps - 1, cfg.conv_channels), cfg.jdtype), jnp.zeros((), jnp.int32))
+
+
+def _chunk(params, tokens, st: Staging, take, cfg: GraniteHybridConfig):
+    """tokens [T] at positions st.length .. + T, the first `take` of them real.
+    Returns (the trunk's rows [T, D], the staging with the chunk in it)."""
+    from tony_tpu.ops.attention import chunk_prefill_attention
+
+    t = tokens.shape[0]
+    pos0 = st.length
+    x = _embed(params, tokens, cfg)
+    banks = tuple(params[k] for k in BANKS)
+    ks, vs, state, tail = st.k, st.v, st.state, st.tail
+    for li, ((kind, i), lp) in enumerate(zip(_layers(cfg), params["layers"], strict=True)):
+        u = L.rms_norm(x, lp["mixer_norm"], cfg.norm_eps)
+        if kind == ATTENTION:
+            q, k, v = _qkv(u, lp, cfg)
+            ks = jax.lax.dynamic_update_slice(ks, k.transpose(1, 0, 2)[None, None].astype(ks.dtype), (i, 0, 0, pos0, 0))
+            vs = jax.lax.dynamic_update_slice(vs, v.transpose(1, 0, 2)[None, None].astype(vs.dtype), (i, 0, 0, pos0, 0))
+            o = chunk_prefill_attention(q.transpose(1, 0, 2), ks, vs, pos0, pos0 + t, jnp.int32(i))
+            branch = _mm(o.transpose(1, 0, 2).reshape(t, -1), lp["wo"])
+        else:
+            z, xbc, dt, g = _ssm_inputs(u, lp, cfg)
+            xbc, new_tail = short_conv_chunk(xbc, tail[i], lp["conv"], take, lp["conv_bias"])
+            xs, B, C = _split(xbc, cfg)
+            y, new = ssd_chunk(xs, dt, g, B, C, lp["D"], state[i], take)
+            state, tail = state.at[i].set(new), tail.at[i].set(new_tail.astype(tail.dtype))
+            branch = _ssm_out(y, z, u, lp, cfg)
+        x, _ = _ffn(_branch(x, branch, cfg), lp, banks, jnp.int32(li), cfg, None, "moe_swiglu_prefill")
+    return x, Staging(ks, vs, state, tail, pos0 + take)
+
+
+def forward(params, tokens, cfg: GraniteHybridConfig, mesh=None):
+    """tokens [B, T] -> logits [B, T, V] float32 (one device; T in whole blocks of the scan's and the convolution's)."""
+    t = tokens.shape[1]
+    return jax.lax.map(lambda row: _finish(_chunk(params, row, _init_staging(cfg, t), jnp.int32(t), cfg)[0], params, cfg), tokens)
+
+
+# -- serving: what models/serving.ContinuousBatcher asks a model module for -------------------------
+
+class HybridCache(NamedTuple):
+    """The engine's device state for S slots: a page pool over the ATTENTION
+    layers only, the state-space layers' state and convolution tail a slot."""
+
+    k: jax.Array           # [La, P, Hkv, page_len, dh]
+    v: jax.Array
+    lengths: jax.Array     # [S]
+    page_table: jax.Array  # [S, max_pages]
+    state: tuple           # Lm arrays [S, N, H x P] float32: a layer's is a buffer of its own, updated in place
+    tail: jax.Array        # [Lm, S, taps - 1, C]
+
+
+def _init_cache(cfg: GraniteHybridConfig, num_slots: int, max_len: int, page_len: int, num_pages: int) -> HybridCache:
+    if max_len % page_len:
+        raise ValueError(f"max_len {max_len} must be a multiple of page_len {page_len}")
+    pool = (cfg.count(ATTENTION), num_pages, cfg.n_kv_heads, page_len, cfg.head_dim)
+    return HybridCache(
+        k=jnp.zeros(pool, cfg.jdtype), v=jnp.zeros(pool, cfg.jdtype),
+        lengths=jnp.zeros((num_slots,), jnp.int32),
+        page_table=jnp.zeros((num_slots, max_len // page_len), jnp.int32),
+        state=tuple(jnp.zeros((num_slots, cfg.ssm_state, cfg.d_inner), jnp.float32) for _ in range(cfg.count(MAMBA))),
+        tail=jnp.zeros((cfg.count(MAMBA), num_slots, cfg.conv_taps - 1, cfg.conv_channels), cfg.jdtype),
+    )
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(2,))
+def prefill_chunk(params, tokens, staging: Staging, take, cfg: GraniteHybridConfig):
+    """tokens [1, T] at positions staging.length .. + T, of which the first
+    `take` are the prompt's. Returns (logits of row take-1 [1, V], staging')."""
+    x, staging = _chunk(params, tokens[0], staging, take, cfg)
+    return _finish(jax.lax.dynamic_slice_in_dim(x, take - 1, 1, axis=0), params, cfg), staging
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def insert_prefill(cache: HybridCache, staging: Staging, fresh_pages, pt_row, slot, true_len, j0, n):
+    """Admission: the staged keys and values into the slot's fresh pages (the
+    llama family's insert), the slot's state and tail from the staging."""
+    from tony_tpu.models.paged_cache import PagedCache, insert_paged_prefill
+
+    paged = insert_paged_prefill(PagedCache(cache.k, cache.v, cache.lengths, cache.page_table),
+                                 staging.k, staging.v, fresh_pages, pt_row, slot, true_len, j0, n=n)
+    return HybridCache(
+        paged.k, paged.v, paged.lengths, paged.page_table,
+        tuple(jax.lax.dynamic_update_slice_in_dim(s, staging.state[i][None], slot, axis=0) for i, s in enumerate(cache.state)),
+        jax.lax.dynamic_update_slice_in_dim(cache.tail, staging.tail[:, None], slot, axis=1),
+    )
+
+
+def _decode_one(params, cache: HybridCache, tokens, cfg: GraniteHybridConfig, staged):
+    """One token a slot, the pool read-only: (logits [S, V], lengths', state',
+    tail', this step's keys and values [La, S, Hkv, dh] x 2, the layers' held rows [L, count])."""
+    from tony_tpu.ops.decode_attention import paged_decode_attention
+
+    sk, sv, step = staged
+    S = tokens.shape[0]
+    max_len = cache.page_table.shape[1] * cache.k.shape[3]
+    pos = jnp.minimum(cache.lengths, max_len - 1)
+    live = cache.lengths > 0
+    x = _embed(params, tokens, cfg)
+    banks = tuple(params[k] for k in BANKS)
+    state, tail = list(cache.state), cache.tail
+    new_k, new_v, rows = [], [], []
+    for li, ((kind, i), lp) in enumerate(zip(_layers(cfg), params["layers"], strict=True)):
+        u = L.rms_norm(x, lp["mixer_norm"], cfg.norm_eps)
+        if kind == ATTENTION:
+            q, k, v = _qkv(u, lp, cfg)
+            k1, v1 = k.astype(cache.k.dtype), v.astype(cache.v.dtype)
+            o = paged_decode_attention(q, cache.k, cache.v, pos, cache.page_table, jnp.int32(i), cur_k=k1, cur_v=v1,
+                                       staged_k=sk[i], staged_v=sv[i], staged_count=jnp.broadcast_to(step, (S,)))
+            new_k.append(k1)
+            new_v.append(v1)
+            branch = _mm(o.reshape(S, -1), lp["wo"])
+        else:
+            z, xbc, dt, g = _ssm_inputs(u, lp, cfg)
+            xbc, new_tail = short_conv_step(xbc, tail[i], lp["conv"], lp["conv_bias"])
+            xs, B, C = _split(xbc, cfg)
+            y, state[i] = ssd_step(xs, dt, g, B, C, lp["D"], state[i])
+            tail = tail.at[i].set(new_tail)
+            branch = _ssm_out(y, z, u, lp, cfg)
+        x, held_rows = _ffn(_branch(x, branch, cfg), lp, banks, jnp.int32(li), cfg, live, "moe_swiglu_decode")
+        rows.append(held_rows)
+    # idle slots (length 0) stay at 0, as in the dense family's step
+    lengths = jnp.where(live, jnp.minimum(cache.lengths + 1, max_len), 0)
+    return _finish(x, params, cfg), lengths, tuple(state), tail, jnp.stack(new_k), jnp.stack(new_v), jnp.stack(rows)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "n", "temperature", "top_k"), donate_argnums=(1,))
+def decode_steps(params, cache: HybridCache, tokens, key, cfg: GraniteHybridConfig, n: int, temperature: float = 0.0,
+                 top_k: int = 0, samp=None):
+    """`n` decode steps in one compiled call: (tokens [S], all tokens [n, S],
+    cache', counts [4] int32). The page pool is written once, when the chunk is
+    over (the dense family's deferred write); the state-space layers' state and
+    tail are carried from step to step, a layer's state updated in place.
+    `counts` as models/exaone_moe.decode_steps: rows that landed on a held
+    expert, the fullest held expert's rows, the choices made, the held experts a
+    row chose, summed over the chunk's steps and the layers."""
+    from tony_tpu.models.generate import _sample, sample_logits
+    from tony_tpu.models.paged_cache import write_decode_chunk
+
+    na, S = cache.k.shape[0], tokens.shape[0]
+    stage = jnp.zeros((na, S, n, cfg.n_kv_heads, cfg.head_dim), cache.k.dtype)
+    live = cache.lengths > 0
+
+    def body(carry, k_step):
+        lengths, toks, state, tail, sk, sv, i, counts = carry
+        view = cache._replace(lengths=lengths, state=state, tail=tail)
+        logits, lengths, state, tail, cols_k, cols_v, rows = _decode_one(params, view, toks, cfg, (sk, sv, i))
+        nxt = sample_logits(logits, k_step, *samp) if samp is not None else _sample(logits, k_step, temperature, top_k)
+        sk = jax.lax.dynamic_update_slice(sk, cols_k[:, :, None], (0, 0, i, 0, 0))
+        sv = jax.lax.dynamic_update_slice(sv, cols_v[:, :, None], (0, 0, i, 0, 0))
+        return (lengths, nxt, state, tail, sk, sv, i + 1, counts + held_step_counts(rows, live, cfg.top_k)), nxt
+
+    (lengths, toks, state, tail, sk, sv, _, counts), seq = jax.lax.scan(
+        body, (cache.lengths, tokens, cache.state, cache.tail, stage, stage, jnp.int32(0), jnp.zeros((4,), jnp.int32)),
+        jax.random.split(key, n))
+    k, v = write_decode_chunk(cache.k, cache.v, sk, sv, cache.lengths, cache.page_table)
+    return toks, seq, cache._replace(k=k, v=v, lengths=lengths, state=state, tail=tail), counts
+
+
+@functools.partial(jax.jit, static_argnames=("cfg",), donate_argnums=(1,))
+def decode_logits(params, cache: HybridCache, tokens, cfg: GraniteHybridConfig):
+    """A chunk of one step that hands back what it computed: (logits [S, V],
+    cache' with the step's keys and values in the pool)."""
+    from tony_tpu.models.paged_cache import write_decode_chunk
+
+    stage = jnp.zeros((cache.k.shape[0], tokens.shape[0], 1, cfg.n_kv_heads, cfg.head_dim), cache.k.dtype)
+    logits, lengths, state, tail, cols_k, cols_v, _ = _decode_one(params, cache, tokens, cfg, (stage, stage, jnp.int32(0)))
+    k, v = write_decode_chunk(cache.k, cache.v, cols_k[:, :, None], cols_v[:, :, None], cache.lengths, cache.page_table)
+    return logits, cache._replace(k=k, v=v, lengths=lengths, state=state, tail=tail)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _release(cache: HybridCache, mask):
+    """Retired slots: length and page-table row to zero. Their state and tail stay
+    as they are: the next admission overwrites all of a slot's."""
+    return cache._replace(lengths=jnp.where(mask, 0, cache.lengths), page_table=jnp.where(mask[:, None], 0, cache.page_table))
+
+
+def serving_programs(cfg: GraniteHybridConfig, kv: str):
+    from tony_tpu.models.serving import ServingPrograms, _bucket
+
+    if kv != "paged":
+        raise ValueError("this model is served from the page pool only (kv='paged'): its decode reads by page")
+    page = cfg.page_len
+
+    def prefill(params, tokens, staging, take):
+        return prefill_chunk(params, tokens, staging, jnp.int32(take), cfg)
+
+    def pad(take, chunk, room):
+        # a last chunk is padded to a page times a power of two (a compiled program a bucket, in whole blocks of
+        # the scan's and tiles of the convolution's), never past the chunk or the room
+        if chunk % page:
+            raise ValueError(f"prefill_chunk {chunk}: this model's chunks are whole pages of {page}")
+        return min(max(_bucket(take), page), chunk or room, room) - take
+
+    return ServingPrograms(
+        init_cache=functools.partial(_init_cache, cfg),
+        init_staging=functools.partial(_init_staging, cfg),
+        prefill_chunk=prefill,
+        prefill_pad=pad,
+        insert=insert_prefill,
+        decode_chunk=functools.partial(decode_steps, cfg=cfg),
+        release=_release,
+        visible_tokens=lambda n: n,            # the attention layers read the whole context
+        prefill_path=lambda pos, take: "dense",
+    )

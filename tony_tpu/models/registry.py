@@ -17,7 +17,8 @@ import sys
 #: row (generate._ffn_with_cache); a routed FFN is served by exaone_moe, whose decode multiplies the chosen
 #: experts only
 SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala", "tony_tpu.models.exaone_moe",
-            "tony_tpu.models.dots3_note", "tony_tpu.models.mistral4", "tony_tpu.models.olmo_hybrid")
+            "tony_tpu.models.dots3_note", "tony_tpu.models.mistral4", "tony_tpu.models.olmo_hybrid",
+            "tony_tpu.models.granite_hybrid")
 
 
 def presets() -> dict:

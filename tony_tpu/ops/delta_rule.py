@@ -33,8 +33,9 @@ Three forms of the one recurrence:
   (``delta_step``) that reads and writes the state of every slot once, in place.
 
 ``short_conv_chunk`` / ``short_conv_step``: the causal depthwise convolution
-over time that precedes the rule (``taps`` inputs a channel, then SiLU), with the
-last ``taps - 1`` inputs carried from chunk to chunk and from step to step.
+over time that precedes the rule (``taps`` inputs a channel, an optional bias,
+then SiLU), with the last ``taps - 1`` inputs carried from chunk to chunk and from
+step to step. The state-space layers (ops/ssd.py) run the same one, with a bias.
 
 The state, ``g``, ``beta`` and the triangular solve are float32; the block's
 products take their operands in the activations' type with float32
@@ -209,12 +210,14 @@ def gated_delta_step(q, k, v, g, beta, state):
 
 # -- the convolution before the rule -----------------------------------------------------------------
 
-def _conv_kernel(u_ref, prev_ref, tail_ref, w_ref, o_ref, *, taps):
+def _conv_kernel(u_ref, prev_ref, tail_ref, w_ref, *rest, taps):
     """A tile of rows x channels: u [tb, cb]; prev [8, cb], the 8 rows of u before
     the tile (its last taps - 1 are read); tail [8, cb], the same for the chunk's
-    first tile; w [taps, cb]."""
+    first tile; w [taps, cb]; then, where the convolution has one, its bias [1, cb]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    *bias_ref, o_ref = rest
 
     before = jnp.where(pl.program_id(0) == 0, tail_ref[...], prev_ref[...]).astype(jnp.float32)
     x = jnp.concatenate([before, u_ref[...].astype(jnp.float32)], axis=0)    # [8 + tb, cb]
@@ -224,6 +227,8 @@ def _conv_kernel(u_ref, prev_ref, tail_ref, w_ref, o_ref, *, taps):
     for j in range(taps):                                                    # y_t = sum_j w_j x_{t - (taps-1) + j}
         lag = taps - 1 - j
         acc = acc + w[j:j + 1] * pltpu.roll(x, (n - (8 - lag)) % n, 0)[:tb]
+    if bias_ref:
+        acc = acc + bias_ref[0][...].astype(jnp.float32)
     o_ref[...] = (acc * jax.nn.sigmoid(acc)).astype(o_ref.dtype)
 
 
@@ -233,11 +238,12 @@ def _conv_channels(C: int) -> int:
 
 
 @jax.jit
-def short_conv_chunk(u, tail, w, valid=None):
+def short_conv_chunk(u, tail, w, valid=None, bias=None):
     """u [T, C]: the chunk's inputs; tail [taps - 1, C]: the inputs before it,
     oldest first (zeros at a sequence's start); w [taps, C], the newest input's
-    weight last. Returns (silu(conv) [T, C] in u's type, the last taps - 1 inputs
-    before position ``valid`` of the chunk (default its end))."""
+    weight last; bias [C] or None, added before the SiLU. Returns (silu(conv + bias)
+    [T, C] in u's type, the last taps - 1 inputs before position ``valid`` of the
+    chunk (default its end))."""
     from jax.experimental import pallas as pl
 
     T, C = u.shape
@@ -248,26 +254,29 @@ def short_conv_chunk(u, tail, w, valid=None):
     if T % tb or tb % 8:
         raise ValueError(f"chunk of {T} rows does not divide into tiles of {tb} rows, a multiple of 8")
     tail8 = jnp.pad(tail.astype(u.dtype), ((8 - (taps - 1), 0), (0, 0)))
+    with_bias = ([pl.BlockSpec((1, cb), lambda t, c: (0, c))], [bias.reshape(1, C)]) if bias is not None else ([], [])
     y = pl.pallas_call(
         functools.partial(_conv_kernel, taps=taps),
         grid=(T // tb, C // cb),
         in_specs=[pl.BlockSpec((tb, cb), lambda t, c: (t, c)),
                   pl.BlockSpec((8, cb), lambda t, c: (jnp.maximum(t * (tb // 8) - 1, 0), c)),
                   pl.BlockSpec((8, cb), lambda t, c: (0, c)),
-                  pl.BlockSpec((taps, cb), lambda t, c: (0, c))],
+                  pl.BlockSpec((taps, cb), lambda t, c: (0, c)), *with_bias[0]],
         out_specs=pl.BlockSpec((tb, cb), lambda t, c: (t, c)),
         out_shape=jax.ShapeDtypeStruct((T, C), u.dtype),
         interpret=interpret(),
         name="short_conv",
-    )(u, u, tail8, w)
+    )(u, u, tail8, w, *with_bias[1])
     at = (T if valid is None else valid) - (taps - 1) + jnp.arange(taps - 1)   # rows of u; below 0: rows of the tail
     kept = jnp.where((at >= 0)[:, None], u[jnp.clip(at, 0, T - 1)], tail.astype(u.dtype)[jnp.clip(at + taps - 1, 0, taps - 2)])
     return y, kept
 
 
-def short_conv_step(u, tail, w):
-    """One position a slot: u [S, C]; tail [S, taps - 1, C]. Returns (silu(conv)
-    [S, C] in u's type, the tail with this input in it)."""
+def short_conv_step(u, tail, w, bias=None):
+    """One position a slot: u [S, C]; tail [S, taps - 1, C]; bias [C] or None.
+    Returns (silu(conv + bias) [S, C] in u's type, the tail with this input in it)."""
     x = jnp.concatenate([tail.astype(jnp.float32), u.astype(jnp.float32)[:, None]], axis=1)   # [S, taps, C]
     acc = jnp.sum(x * w.astype(jnp.float32)[None], axis=1)
+    if bias is not None:
+        acc = acc + bias.astype(jnp.float32)
     return (acc * jax.nn.sigmoid(acc)).astype(u.dtype), x[:, 1:].astype(tail.dtype)
