@@ -386,6 +386,34 @@ class TestExaoneMoeKernelsAtServedWidths:
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20       # no copy of a layer's bank (403 MB)
         assert MG.width_block(self.D, self.F, 2) == 512
 
+    @pytest.mark.parametrize("cell,tokens,d,f,held,layers,top_k", [
+        ("k-exaone-236b", 256, 6144, 2048, 16, 4, 8), ("granite-4.0-h-small", 64, 4096, 768, 36, 10, 10),
+        ("dots3-note-prev", 24, 5120, 1536, 32, 4, 8), ("k-exaone-236b-a-512-row-bucket", 512, 6144, 2048, 16, 4, 8)])
+    def test_grouped_swiglu_that_gathers_and_sums(self, chip, cell, tokens, d, f, held, layers, top_k):
+        """A decode step's form (`moe_swiglu_tokens`): the call takes the step's tokens whole, a tile's tokens and
+        gates beside the weight blocks, and returns `y [T, D]`: one kernel under the decode step's name with the
+        banks as operands, no buffer at the static row bound (43 MB at granite, 50 at K-EXAONE) and no copy of a bank
+        beside it. K-EXAONE's 512 x 6144 (a prefill bucket) is the most it holds in VMEM: 37.7 MB of tokens, sum and
+        result beside 38 MB of weight blocks, of the 100 MB the call asks for; 24 tokens are padded to 32."""
+        from tony_tpu.parallel.expert import held_form
+
+        tile = 128
+        bound = (-(-tokens * top_k // tile) + held) * tile
+        x = _s((tokens, d), jnp.bfloat16, chip)
+        up, down = _s((layers, held, d, f), jnp.bfloat16, chip), _s((layers, held, f, d), jnp.bfloat16, chip)
+        tok, gate = _s((bound,), jnp.int32, chip), _s((bound,), jnp.float32, chip)
+        tg, scalar = _s((bound // tile,), jnp.int32, chip), _s((), jnp.int32, chip)
+
+        def fn(x, tok, gate, wg, wu, wd, tg, live, layer):
+            return MG.moe_swiglu_tokens(x, tok, gate, wg, wu, wd, tg, tile, live, layer, name="moe_swiglu_decode")
+
+        assert held_form(tokens, d, 2) == "in_kernel"
+        compiled = jax.jit(fn).lower(x, tok, gate, up, up, down, tg, scalar, scalar).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and "moe_swiglu_decode" in text
+        assert f"bf16[{layers},{held},{d},{f}]" in text and f"bf16[{bound},{d}]" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 20
+
     def test_decode_attention_on_a_window_layers_ring(self, chip):
         """Every window layer's rings are one operand with a layer index; a block of slots goes
         through the call's own pipeline: one kernel, found by its name, and no copy of a layer's

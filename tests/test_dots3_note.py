@@ -426,11 +426,14 @@ def test_the_engine_counts_index_positions_expert_rows_and_visible_positions(tin
 #: `paged_decode_attention` (llama's, exaone_moe's) were taken again at PR 44, which changed that kernel's body; their
 #: `prefill_chunk` and `insert`, and all three of minicpm_sala's (its decode reads listed pages: another call), did not move.
 #: exaone_moe's `decode_chunk` was taken again at PR 48, whose chunk returns a fourth count (the held experts a row chose:
-#: a3ffab437d62f6ac until then); its `prefill_chunk` did not move (float32 rows take `ragged_dot`, where no group is padded)
+#: a3ffab437d62f6ac until then); its `prefill_chunk` did not move (float32 rows take `ragged_dot`, where no group is padded).
+#: PR 54 counts a held expert's rows by one compare over the spans (`held_expert_ffn`: a binary search and a scatter until
+#: then), so every `decode_chunk` of a family that holds part of its experts was taken again (exaone_moe's here:
+#: 2839be267de17b5d until then); a prefill chunk drops the count, so no `prefill_chunk` moved, and llama's and minicpm_sala's three did not
 PARENT_LOWERED = {
     "tiny-dense": {"prefill_chunk": "59bbb8e8694afa8f", "insert": "210285f3c6b88e0d", "decode_chunk": "dc951271c6e8cd2c"},
     "tiny-minicpm-sala": {"prefill_chunk": "06929982745c9738", "insert": "f871ff2b11f9c16d", "decode_chunk": "92bb0b1ff9f0801a"},
-    "tiny-exaone-moe": {"prefill_chunk": "8fb0f36a0c7df860", "insert": "91cac576a663f3e7", "decode_chunk": "2839be267de17b5d"},
+    "tiny-exaone-moe": {"prefill_chunk": "8fb0f36a0c7df860", "insert": "91cac576a663f3e7", "decode_chunk": "4e3acef181bcd9ab"},
 }
 
 

@@ -357,6 +357,38 @@ def test_the_engine_decodes_the_references_greedy_tokens_and_counts_its_experts(
     assert eng.prefix_hit_tokens == 0 and eng.programs.gather_prefix is None and eng.programs.prefix_usable is None
 
 
+@pytest.mark.parametrize("case", ["the-tiny-engine-is-staged", "a-chunk-by-its-slots-a-prefill-by-its-rows", "the-served-widths"])
+def test_the_engine_counts_its_routed_ffn_programs_by_form(tiny, case):
+    """`tony_serve_routed_ffn_programs_total{form}`: a decode chunk is counted under the form its slots give,
+    a prefill chunk under the form its padded rows give (parallel/expert.held_ffn_form through
+    `ServingPrograms.routed_ffn_form`): float32 rows take `ragged_dot`, so the tiny engine is staged
+    throughout; at the served widths in bfloat16 the decode batch is in the kernel and a 2048-row chunk staged."""
+    import dataclasses
+
+    from tony_tpu.models import granite_hybrid as GH
+
+    name = "tony_serve_routed_ffn_programs_total"
+    if case == "the-served-widths":
+        cfg = dataclasses.replace(tiny["cfg"], d_model=4096, d_expert=768, dtype="bfloat16")
+        form = GH.serving_programs(cfg, "paged").routed_ffn_form
+        assert [form(rows) for rows in (64, 512, 1024, 2048)] == ["in_kernel", "in_kernel", "staged", "staged"]
+        assert GH.serving_programs(tiny["cfg"], "paged").routed_ffn_form(2) == "staged"
+        return
+    eng = _engine(tiny)
+    if case == "a-chunk-by-its-slots-a-prefill-by-its-rows":
+        eng.programs = eng.programs._replace(routed_ffn_form=lambda rows: "in_kernel" if rows == 2 else "staged")
+    before = _counters()
+    eng.submit(_tokens(7, 40), 9)                                       # two prefill chunks (32 + 8 padded to 16), then chunks of 4
+    eng.run()
+    moved = {k: v - before.get(k, 0) for k, v in _counters().items()}
+    chunks, prefills = moved["tony_serve_engine_chunks_total"], moved["tony_serve_prefill_chunks_total{dense}"]
+    assert chunks >= 2 and prefills == 2
+    if case == "the-tiny-engine-is-staged":
+        assert moved[name + "{staged}"] == chunks + prefills and moved.get(name + "{in_kernel}", 0) == 0
+    else:
+        assert moved[name + "{in_kernel}"] == chunks and moved[name + "{staged}"] == prefills
+
+
 def test_a_slot_used_again_reads_nothing_of_its_last_tenant(tiny):
     """State and tail stay in a released slot; the next admission overwrites all
     of a slot's: the same prompt twice, with the cache poisoned in between."""

@@ -376,8 +376,9 @@ def test_the_engine_counts_context_and_expert_rows(tiny):
 #: functions moves by one behind it (`@silu_320` -> `@silu_321`; 80 such lines in prefill, 132 in decode), so until then
 #: `prefill_chunk` read c2d5483b10cb7019 and `decode_chunk` 343d101c9f873ea1; with the `_<n>` of every `@name_<n>` taken
 #: off, both texts are the parent's (aad441a), line for line. PR 48 adds a fourth count to what `decode_chunk` returns (the
-#: held experts a row chose): 2ee4b1e328fd61e1 until then; `prefill_chunk` and `insert` did not move
-PARENT_LOWERED_DOTS3 = {"prefill_chunk": "87fd180e4f475ab0", "insert": "9a9d6ec16fc97060", "decode_chunk": "f1633cc74368550d"}
+#: held experts a row chose): 2ee4b1e328fd61e1 until then; `prefill_chunk` and `insert` did not move. PR 54 counts a held
+#: expert's rows by one compare (tests/test_dots3_note.py's note): `decode_chunk` f1633cc74368550d until then
+PARENT_LOWERED_DOTS3 = {"prefill_chunk": "87fd180e4f475ab0", "insert": "9a9d6ec16fc97060", "decode_chunk": "5e5855861908355f"}
 
 
 def test_the_latent_family_before_this_one_lowers_to_the_parents_text(bench, monkeypatch):

@@ -732,20 +732,29 @@ class TestAGroupWithNoRows:
                     blocks = [-(-dim // (b or 1)) for dim, b in zip(operand.shape, bs.block_shape)]
                     assert all(0 <= i < n for i, n in zip(index, blocks)), (m, c, index, blocks)
 
-    def _held(self, monkeypatch, case):
-        """(y, rows) of the kernel's path and of ragged_dot's on the same values, and what the kernel was handed."""
+    def _held(self, monkeypatch, case, form="in_kernel"):
+        """(y, rows) of the kernel's path and of ragged_dot's on the same values, and what the kernel was handed,
+        in the form ``held_form`` chooses at 24 tokens (the kernel gathers and sums) or staged through HBM."""
         from jax.experimental import pallas as pl
 
         from tony_tpu.ops import moe_gemm as MG
+        from tony_tpu.parallel import expert as EX
         from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn
 
         monkeypatch.setattr(MG, "TILE_M", self.TILE)
+        if form == "staged":
+            monkeypatch.setattr(EX, "HELD_IN_KERNEL_TOKENS", 0)
+        assert EX.held_form(self.T, self.D, 2) == form
         seen = {}
-        rows_call, pallas_call = MG.moe_swiglu_rows, pl.pallas_call
+        rows_call, tokens_call, pallas_call = MG.moe_swiglu_rows, MG.moe_swiglu_tokens, pl.pallas_call
 
         def recorded_rows(xs, wg, wu, wd, tile_group, tile, live, *rest):
-            seen.update(tile_group=np.asarray(tile_group), live=int(live), tile=tile, rows=xs.shape[0])
+            seen.update(tile_group=np.asarray(tile_group), live=int(live), tile=tile, rows=xs.shape[0], form="staged")
             return rows_call(xs, wg, wu, wd, tile_group, tile, live, *rest)
+
+        def recorded_tokens(x, sort_tok, gate_sorted, wg, wu, wd, tile_group, tile, live, *rest):
+            seen.update(tile_group=np.asarray(tile_group), live=int(live), tile=tile, rows=sort_tok.shape[0], form="in_kernel")
+            return tokens_call(x, sort_tok, gate_sorted, wg, wu, wd, tile_group, tile, live, *rest)
 
         def recorded_pallas(kernel, *, grid_spec, **kw):
             inner = pallas_call(kernel, grid_spec=grid_spec, **kw)
@@ -756,6 +765,7 @@ class TestAGroupWithNoRows:
             return run
 
         monkeypatch.setattr(MG, "moe_swiglu_rows", recorded_rows)
+        monkeypatch.setattr(MG, "moe_swiglu_tokens", recorded_tokens)
         monkeypatch.setattr(pl, "pallas_call", recorded_pallas)
         cfg = MoEConfig(num_experts=self.E, top_k=self.K, held=self.HELD)
         x, router = self._rows(*self.CASES[case])
@@ -763,13 +773,15 @@ class TestAGroupWithNoRows:
         got = held_expert_ffn(x, router, None, *banks, jnp.int32(0), cfg)
         plain = held_expert_ffn(x.astype(jnp.float32), router, None, *(b.astype(jnp.float32) for b in banks), jnp.int32(0), cfg)
         assert seen["call"][1][0].dtype == jnp.bfloat16                  # the kernel ran once: float32 rows take ragged_dot
+        assert seen["form"] == form
         return got, plain, seen
 
-    @pytest.mark.parametrize("case", [*CASES, "training-keeps-a-tile"])
-    def test_a_group_with_no_rows(self, monkeypatch, case):
+    @pytest.mark.parametrize("case,form", [*((c, f) for c in CASES for f in ("in_kernel", "staged")),
+                                           ("training-keeps-a-tile", "staged")])
+    def test_a_group_with_no_rows(self, monkeypatch, case, form):
         if case == "training-keeps-a-tile":
             return self._training(monkeypatch)
-        (y, rows), (y_plain, rows_plain), seen = self._held(monkeypatch, case)
+        (y, rows), (y_plain, rows_plain), seen = self._held(monkeypatch, case, form)
         first, count = self.HELD
         chosen = np.array([c for pair in zip(*self.CASES[case]) for c in pair])
         want_rows = np.bincount(chosen[(chosen >= first) & (chosen < first + count)] - first, minlength=count)
@@ -818,6 +830,110 @@ class TestAGroupWithNoRows:
             np.testing.assert_allclose(g, w, atol=4e-2 * np.abs(w).max(), rtol=4e-2)
         for g in got[1:]:
             assert not np.asarray(g[2], np.float32).any() and np.abs(np.asarray(g[0], np.float32)).max() > 0
+
+
+class TestTheKernelGathersAndSums:
+    """``held_expert_ffn`` in the kernel's form (``moe_gemm.moe_swiglu_tokens``: the grouped product takes
+    ``x [T, D]`` and returns ``y [T, D]``) against the staged form (``x[sort_tok]`` at the static bound, the
+    product over rows, the choices gathered back and summed in XLA) on the same values, under the
+    interpreter: the four routed cells' decode shapes cut small (their slots, their top-k, a part of the
+    experts held, an expert's width in one block and in several), and the edges: no choice on any held
+    expert, a held expert no row chose, an idle slot under ``count_mask``, T in no whole sublane group."""
+
+    D, F, TILE = 128, 256, 16
+    SHAPES = {  # T, experts, top_k, held (first, count), width blocks, scoring
+        "serve_notes-like": (24, 32, 8, (4, 8), 1, "sigmoid"),
+        "serve_docqa-like": (48, 16, 4, (8, 4), 2, "softmax"),
+        "serve_assist-like": (64, 12, 10, (0, 6), 1, "softmax"),
+        "serve_reason-like": (256, 16, 8, (2, 4), 2, "sigmoid"),
+        "t-in-no-whole-sublane-group": (20, 8, 3, (0, 4), 1, "softmax"),
+    }
+    EDGES = ("no-held-expert-chosen", "a-held-expert-unchosen", "an-idle-slot-is-not-counted")
+
+    def _both(self, monkeypatch, x, router, bias, banks, cfg, blocks=1, count_mask=None):
+        from tony_tpu.ops import moe_gemm as MG
+        from tony_tpu.parallel import expert as EX
+
+        monkeypatch.setattr(MG, "TILE_M", self.TILE)
+        if blocks > 1:
+            monkeypatch.setattr(MG, "_WEIGHT_VMEM", 3 * self.D * (self.F // blocks) * 2 * 2)
+        assert MG.width_block(self.D, self.F, 2) == self.F // blocks
+        ran = []
+        tokens_call, rows_call = MG.moe_swiglu_tokens, MG.moe_swiglu_rows
+        monkeypatch.setattr(MG, "moe_swiglu_tokens", lambda *a, **kw: (ran.append("in_kernel"), tokens_call(*a, **kw))[1])
+        monkeypatch.setattr(MG, "moe_swiglu_rows", lambda *a, **kw: (ran.append("staged"), rows_call(*a, **kw))[1])
+        call = lambda: EX.held_expert_ffn(x, router, bias, *banks, jnp.int32(1), cfg, count_mask=count_mask, name="moe_swiglu_decode")
+        assert EX.held_form(x.shape[0], self.D, 2) == "in_kernel"
+        got = call()
+        monkeypatch.setattr(EX, "HELD_IN_KERNEL_TOKENS", 0)
+        want = call()
+        assert ran == ["in_kernel", "staged"]
+        return got, want
+
+    def _banks(self, count, seed=1):
+        ks = jax.random.split(jax.random.PRNGKey(seed), 3)
+        up = lambda k: (jax.random.normal(k, (2, count, self.D, self.F)) / self.D ** 0.5).astype(jnp.bfloat16)
+        return up(ks[0]), up(ks[1]), (jax.random.normal(ks[2], (2, count, self.F, self.D)) / self.F ** 0.5).astype(jnp.bfloat16)
+
+    @staticmethod
+    def _close(y, y_staged):
+        """Within bfloat16 rounding: both forms round a tile's output and the gates alike and sum a
+        token's choices wide, in another order, then round once (an ulp of bfloat16 is 2 ** -8 of the value)."""
+        y, y_staged = np.asarray(y, np.float32), np.asarray(y_staged, np.float32)
+        assert y.shape == y_staged.shape and np.isfinite(y).all()
+        np.testing.assert_allclose(y, y_staged, rtol=2 ** -7, atol=2 ** -7 * max(np.abs(y_staged).max(), 1e-3))
+
+    @pytest.mark.parametrize("case", [*SHAPES, *EDGES])
+    def test_the_two_forms_agree(self, monkeypatch, case):
+        from tony_tpu.parallel.expert import MoEConfig
+
+        if case in self.SHAPES:
+            T, E, K, held, blocks, scoring = self.SHAPES[case]
+            ks = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+            x = (jax.random.normal(ks[0], (T, self.D)) * 0.5).astype(jnp.bfloat16)
+            router = jax.random.normal(ks[1], (self.D, E), jnp.float32) / self.D ** 0.5
+            bias = 0.1 * jax.random.normal(ks[2], (E,)) if scoring == "sigmoid" else None
+            cfg = MoEConfig(num_experts=E, top_k=K, held=held, scoring=scoring, routed_scale=2.5 if scoring == "sigmoid" else 1.0)
+            (y, rows), (y_staged, rows_staged) = self._both(monkeypatch, x, router, bias, self._banks(held[1]), cfg, blocks)
+            assert np.array_equal(np.asarray(rows), np.asarray(rows_staged)) and int(rows.sum()) > 0
+            assert np.abs(np.asarray(y_staged, np.float32)).max() > 0.05
+            return self._close(y, y_staged)
+        # the edges, choices set by hand as TestAGroupWithNoRows sets them: top-2 of 8, experts 1 .. 5 held
+        first, second = {
+            "no-held-expert-chosen": ([0] * 24, [7] * 24),
+            "a-held-expert-unchosen": ([2] * 20 + [4] * 4, [7] * 20 + [5] * 4),
+            "an-idle-slot-is-not-counted": ([1 + t % 5 for t in range(24)], [1 + (t + 2) % 5 for t in range(24)]),
+        }[case]
+        x, router = TestAGroupWithNoRows._rows(first, second)
+        cfg = MoEConfig(num_experts=8, top_k=2, held=(1, 5))
+        live = jnp.arange(24) % 3 != 1 if case == "an-idle-slot-is-not-counted" else None
+        (y, rows), (y_staged, rows_staged) = self._both(monkeypatch, x, router, None, self._banks(5), cfg, count_mask=live)
+        assert np.array_equal(np.asarray(rows), np.asarray(rows_staged))
+        self._close(y, y_staged)
+        chosen = np.array([first, second]).T                               # [T, 2]
+        counted = chosen if live is None else chosen[np.asarray(live)]
+        assert np.asarray(rows).tolist() == np.bincount(counted[(counted >= 1) & (counted < 6)] - 1, minlength=5).tolist()
+        if case == "no-held-expert-chosen":
+            assert not np.asarray(y, np.float32).any() and not np.asarray(rows).any()      # live == 0: y is zero
+        elif case == "a-held-expert-unchosen":
+            assert np.asarray(rows).tolist() == [0, 20, 0, 4, 4] and np.abs(np.asarray(y, np.float32)).max() > 0.1
+        else:
+            # an idle slot's row is computed like any (y is the unmasked call's) and counted as none
+            assert int(rows.sum()) == 2 * int(live.sum()) and np.abs(np.asarray(y, np.float32)[1]).max() > 0.05
+
+    def test_the_form_follows_the_shapes(self):
+        """In the kernel at the four cells' decode batches at their widths and at a 512-row bucket, staged at
+        a 1024- and a 2048-row chunk, and once staged never in the kernel again as T grows."""
+        from tony_tpu.parallel.expert import held_form
+
+        for T, D in ((24, 5120), (48, 4096), (64, 4096), (256, 6144), (512, 6144)):
+            assert held_form(T, D, 2) == "in_kernel", (T, D)
+        for D in (4096, 5120, 6144):
+            assert held_form(2048, D, 2) == "staged" and held_form(1024, D, 2) == "staged"
+            forms = [held_form(T, D, 2) for T in range(8, 4097, 8)]
+            switch = forms.index("staged")
+            assert switch > 0 and set(forms[:switch]) == {"in_kernel"} and set(forms[switch:]) == {"staged"}
+        assert held_form(64, 16384, 2) == "in_kernel" and held_form(512, 16384, 2) == "staged"      # what VMEM holds
 
 
 # What nothing outside a kernel's module can change: its block sizes. One fresh interpreter with every

@@ -53,7 +53,7 @@ import numpy as np
 
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_ffn_form, held_step_counts
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 BANKS = ("we_gate", "we_up", "we_down")
@@ -590,4 +590,5 @@ def serving_programs(cfg: Dots3NoteConfig, kv: str):
         # the page's edge are the rest, and nothing keeps them). No page is shared.
         visible_tokens=visible_tokens,
         prefill_path=prefill_path,
+        routed_ffn_form=lambda rows: held_ffn_form(cfg.moe, rows, cfg.d_model, cfg.d_expert, cfg.jdtype),
     )

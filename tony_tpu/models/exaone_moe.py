@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_ffn_form, held_step_counts
 
 WINDOW, FULL = "sliding_attention", "full_attention"
 
@@ -520,4 +520,5 @@ def serving_programs(cfg: ExaoneMoeConfig, kv: str):
         # at the page's edge are the rest, and nothing keeps them). No page is shared.
         visible_tokens=lambda n: (n_window * np.minimum(n, cfg.window) + n_full * n) / cfg.n_layers,
         prefill_path=lambda pos, take: "dense",
+        routed_ffn_form=lambda rows: held_ffn_form(cfg.moe, rows, cfg.d_model, cfg.d_expert, cfg.jdtype),
     )

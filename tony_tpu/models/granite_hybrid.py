@@ -58,7 +58,7 @@ import numpy as np
 from tony_tpu.ops import layers as L
 from tony_tpu.ops.delta_rule import short_conv_chunk, short_conv_step
 from tony_tpu.ops.ssd import ssd_chunk, ssd_step
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_ffn_form, held_step_counts
 
 MAMBA, ATTENTION = "mamba", "attention"
 BANKS = ("we_gate", "we_up", "we_down")
@@ -466,4 +466,5 @@ def serving_programs(cfg: GraniteHybridConfig, kv: str):
         release=_release,
         visible_tokens=lambda n: n,            # the attention layers read the whole context
         prefill_path=lambda pos, take: "dense",
+        routed_ffn_form=lambda rows: held_ffn_form(cfg.moe, rows, cfg.d_model, cfg.d_expert, cfg.jdtype),
     )

@@ -49,7 +49,7 @@ import numpy as np
 
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.ops import layers as L
-from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_step_counts
+from tony_tpu.parallel.expert import MoEConfig, held_expert_ffn, held_ffn_form, held_step_counts
 
 BANKS = ("we_gate", "we_up", "we_down")
 
@@ -451,4 +451,5 @@ def serving_programs(cfg: Mistral4Config, kv: str):
         visible_tokens=lambda n: n,            # every layer reads the whole context
         prefill_path=prefill_path,
         gather_prefix=gather,
+        routed_ffn_form=lambda rows: held_ffn_form(cfg.moe, rows, cfg.d_model, cfg.d_expert, cfg.jdtype),
     )

@@ -99,6 +99,12 @@ _ADMISSIONS = obs_metrics.counter(
     "tony_serve_admissions_total",
     "requests given a slot, by what the device was doing at their insert: a decode chunk in flight, or nothing",
     labelnames=("under",))
+_ROUTED_FFN = obs_metrics.counter(
+    "tony_serve_routed_ffn_programs_total",
+    "decode chunks and prefill chunks dispatched by a model whose layers hold part of their experts, by where the "
+    "routed FFN gathers its rows and sums its choices at that program's row count: in the grouped product's "
+    "kernel, or staged through HBM at the static row bound (parallel/expert.held_form)",
+    labelnames=("form",))
 # a model whose layers hold part of their experts returns these four with a chunk's tokens, summed on the
 # device over the chunk's steps and routed layers, from live slots' rows (ServingPrograms.decode_chunk)
 _EXPERT_COUNTS = (
@@ -470,6 +476,9 @@ class ServingPrograms(NamedTuple):
     # (the page allocator, the matched pages) -> how many of them a request may start from; None: all. Where state
     # lies beside a prefix's pages, a match ends at the deepest page whose edge has that state kept
     prefix_usable: object = None
+    # (rows of a program: the slots of a decode chunk, a prefill chunk's padded length) -> "in_kernel" | "staged":
+    # the form its routed FFN runs in (parallel/expert.held_ffn_form); None where no layer holds part of its experts
+    routed_ffn_form: object = None
 
 
 def programs_for(cfg, kv: str) -> ServingPrograms:
@@ -939,6 +948,8 @@ class ContinuousBatcher:
             last_logits, pre = self.programs.prefill_chunk(self.params, toks, pre, take)
             _PREFILL_TOKENS.inc(take + pad)
             _PREFILL_CHUNKS.inc(path=self.programs.prefill_path(pos, take))
+            if self.programs.routed_ffn_form is not None:
+                _ROUTED_FFN.inc(form=self.programs.routed_ffn_form(take + pad))
             req.prefill_chunks += 1
             pos += take
             if last:
@@ -1172,6 +1183,8 @@ class ContinuousBatcher:
             )
         self.tokens = toks
         _CHUNKS.inc()
+        if self.programs.routed_ffn_form is not None:
+            _ROUTED_FFN.inc(form=self.programs.routed_ffn_form(self.S))
         _DECODE_SLOTS.inc(len(flying))
         # what the chunk's steps have in context and may read of it, from the
         # host's own lengths: step j of slot s sees _slot_len[s] + j + 1 positions

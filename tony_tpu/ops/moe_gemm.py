@@ -61,6 +61,22 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
+def _swiglu_block(x, wg_ref, wu_ref, wd_ref, acc_ref):
+    """A block of the expert width for one row tile ``x``: its part of
+    ``silu(x Wg) * (x Wu) Wd`` added into ``acc_ref``."""
+    fb = wg_ref.shape[1]
+    # within a block, F-chunked: overlap the next chunk's gate/up MXU work
+    # with the current chunk's VPU silu·mul tail (statically unrolled so
+    # Mosaic can software-pipeline the chunk sequence)
+    step = F_CHUNK if F_CHUNK and fb % F_CHUNK == 0 and fb > F_CHUNK else fb
+    for lo in range(0, fb, step):
+        sl = slice(lo, lo + step)
+        g = jnp.dot(x, wg_ref[:, sl], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[:, sl], preferred_element_type=jnp.float32)
+        h = (_silu(g) * u).astype(x.dtype)
+        acc_ref[...] += jnp.dot(h, wd_ref[sl, :], preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(tg_ref, meta_ref, xs_ref, wg_ref, wu_ref, wd_ref, ys_ref, acc_ref):
     """One row tile x one block of the expert width: the block's part of
     ``silu(x Wg) * (x Wu) Wd`` added into the tile's float32 accumulator,
@@ -80,22 +96,57 @@ def _fwd_kernel(tg_ref, meta_ref, xs_ref, wg_ref, wu_ref, wd_ref, ys_ref, acc_re
         def _init():
             acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
 
-        x = xs_ref[...]
-        fb = wg_ref.shape[1]
-        # within a block, F-chunked: overlap the next chunk's gate/up MXU work
-        # with the current chunk's VPU silu·mul tail (statically unrolled so
-        # Mosaic can software-pipeline the chunk sequence)
-        step = F_CHUNK if F_CHUNK and fb % F_CHUNK == 0 and fb > F_CHUNK else fb
-        for lo in range(0, fb, step):
-            sl = slice(lo, lo + step)
-            g = jnp.dot(x, wg_ref[:, sl], preferred_element_type=jnp.float32)
-            u = jnp.dot(x, wu_ref[:, sl], preferred_element_type=jnp.float32)
-            h = (_silu(g) * u).astype(x.dtype)
-            acc_ref[...] += jnp.dot(h, wd_ref[sl, :], preferred_element_type=jnp.float32)
+        _swiglu_block(xs_ref[...], wg_ref, wu_ref, wd_ref, acc_ref)
 
         @pl.when(c == pl.num_programs(1) - 1)
         def _flush():
             ys_ref[...] = acc_ref[...].astype(ys_ref.dtype)
+
+
+def _tokens_kernel(tg_ref, meta_ref, x_ref, tok_ref, gate_ref, wg_ref, wu_ref, wd_ref, y_ref, xs_ref, acc_ref, yacc_ref):
+    """``_fwd_kernel`` with the rows gathered and the choices summed in VMEM:
+    ``x_ref`` [T, D] is the call's tokens whole, ``tok_ref`` / ``gate_ref``
+    [1, tile] the tile's token of each row and its gate (zero on a pad row,
+    whose token is 0), ``y_ref`` [T, D] the gated sum over every token's held
+    choices. A tile's rows are ``onehot [T, tile]^T x`` (one non-zero term a
+    row: exact) at its first block; at its last, the tile's output in the
+    activations' type goes into ``yacc_ref`` [T, D] float32 through ``(onehot
+    * gate) [T, tile]``: the roundings of the staged form (the output cast,
+    the gate cast, products summed wide), a token's choices in tile order.
+    ``y`` is zero before the first tile and written after the last grid step,
+    live or skipped."""
+    from jax.experimental import pallas as pl
+
+    m, c = pl.program_id(0), pl.program_id(1)
+    last_block = c == pl.num_programs(1) - 1
+
+    @pl.when((m == 0) & (c == 0))
+    def _zero():
+        yacc_ref[...] = jnp.zeros(yacc_ref.shape, yacc_ref.dtype)
+
+    @pl.when(m < meta_ref[0])
+    def _tile():
+        T, tile = x_ref.shape[0], tok_ref.shape[1]
+        # [T, tile] float32 (a 32-bit mask selects 32-bit values; the casts to the activations' type follow)
+        onehot = jnp.where(jax.lax.broadcasted_iota(jnp.int32, (T, tile), 0) == tok_ref[...], 1.0, 0.0)
+
+        @pl.when(c == 0)
+        def _gather():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+            xs_ref[...] = jax.lax.dot_general(
+                onehot.astype(x_ref.dtype), x_ref[...], (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            ).astype(xs_ref.dtype)
+
+        _swiglu_block(xs_ref[...], wg_ref, wu_ref, wd_ref, acc_ref)
+
+        @pl.when(last_block)
+        def _combine():
+            gates = (onehot * gate_ref[...].astype(y_ref.dtype).astype(jnp.float32)).astype(y_ref.dtype)
+            yacc_ref[...] += jnp.dot(gates, acc_ref[...].astype(y_ref.dtype), preferred_element_type=jnp.float32)
+
+    @pl.when((m == pl.num_programs(0) - 1) & last_block)
+    def _write():
+        y_ref[...] = yacc_ref[...].astype(y_ref.dtype)
 
 
 def _bwd_kernel(
@@ -166,17 +217,21 @@ def width_block(D: int, F: int, itemsize: int) -> int:
     return F
 
 
-def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_swiglu_grouped"):
+def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_swiglu_grouped", route=None):
     """wg/wu [L, E, D, F], wd [L, E, F, D] (or without the leading L): the
     banks of every layer that shares them, with the layer's index a scalar
     operand: a layer's slice handed in would be a copy of it a call (a Mosaic
-    operand needs a buffer of its own)."""
+    operand needs a buffer of its own). ``route`` = (sort_tok, gate_sorted),
+    each [PN]: ``xs`` is then the tokens ``x`` [T, D] and the result ``y``
+    [T, D] (``_tokens_kernel``); the grid, the scalars and the weight blocks
+    are the same."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     if wg.ndim == 3:
         wg, wu, wd = wg[None], wu[None], wd[None]
-    PN, D = xs.shape
+    D = xs.shape[1]
+    PN = xs.shape[0] if route is None else route[0].shape[0]
     F = wg.shape[-1]
     fb = width_block(D, F, xs.dtype.itemsize)
     n_tiles, n_blocks = PN // tile, F // fb
@@ -197,22 +252,34 @@ def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_sw
     def down(m, c, tg, meta):
         return meta[1], tg[tile_of(m, meta)], block(m, c, meta), 0
 
+    weights = [
+        pl.BlockSpec((None, None, D, fb), up),
+        pl.BlockSpec((None, None, D, fb), up),
+        pl.BlockSpec((None, None, fb, D), down),
+    ]
+    acc = pltpu.VMEM((tile, D), jnp.float32)
+    if route is None:
+        kernel, operands = _fwd_kernel, (xs,)
+        ins, out, scratch = [pl.BlockSpec((tile, D), rows)], pl.BlockSpec((tile, D), rows), [acc]
+    else:
+        T = xs.shape[0]
+        whole = pl.BlockSpec((T, D), lambda m, c, tg, meta: (0, 0))                  # one block, fetched once
+        of_tile = pl.BlockSpec((None, 1, tile), lambda m, c, tg, meta: (tile_of(m, meta), 0, 0))
+        kernel = _tokens_kernel
+        operands = (xs, route[0].reshape(n_tiles, 1, tile), route[1].reshape(n_tiles, 1, tile))
+        ins, out = [whole, of_tile, of_tile], whole
+        scratch = [pltpu.VMEM((tile, D), xs.dtype), acc, pltpu.VMEM((T, D), jnp.float32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_tiles, n_blocks),
-        in_specs=[
-            pl.BlockSpec((tile, D), rows),
-            pl.BlockSpec((None, None, D, fb), up),
-            pl.BlockSpec((None, None, D, fb), up),
-            pl.BlockSpec((None, None, fb, D), down),
-        ],
-        out_specs=pl.BlockSpec((tile, D), rows),
-        scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32)],
+        in_specs=ins + weights,
+        out_specs=out,
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
-        _fwd_kernel,
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((PN, D), xs.dtype),
+        out_shape=jax.ShapeDtypeStruct(xs.shape, xs.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),  # revisit caching needs order
             vmem_limit_bytes=100 * 1024 * 1024,  # weight blocks resident (v5e: 128M)
@@ -224,7 +291,7 @@ def _fwd_call(xs, wg, wu, wd, tile_group, tile, live=None, layer=0, name="moe_sw
             bytes_accessed=(xs.size * 2 + 3 * wg.shape[1] * D * F) * xs.dtype.itemsize,
             transcendentals=PN * F,
         ),
-    )(tile_group, meta, xs, wg, wu, wd)
+    )(tile_group, meta, *operands, wg, wu, wd)
 
 
 def moe_swiglu_rows(xs, wg, wu, wd, tile_group, tile, live, layer=0, name="moe_swiglu_grouped"):
@@ -234,6 +301,22 @@ def moe_swiglu_rows(xs, wg, wu, wd, tile_group, tile, live, layer=0, name="moe_s
     ``wd`` may carry a leading layer dimension, with ``layer`` the index into
     it. ``name`` is the call's name in a trace."""
     return _fwd_call(xs, wg, wu, wd, tile_group, tile, live, layer, name)
+
+
+def moe_swiglu_tokens(x, sort_tok, gate_sorted, wg, wu, wd, tile_group, tile, live, layer=0, name="moe_swiglu_grouped"):
+    """``moe_swiglu_rows`` over the tokens themselves, for a call whose tokens
+    fit VMEM: x [T, D] -> y [T, D] with ``y[t] = sum over the sorted rows j of
+    token t of gate_sorted[j] * expert(x[t])``, the rows being ``sort_tok``
+    [PN] in expert order (``parallel/expert.route_ragged``; a pad row names
+    token 0 with gate 0). Neither the sorted rows nor the experts' outputs
+    exist in HBM: the call reads ``x`` once, the live tiles' weights, and
+    writes ``y`` (zero where ``live`` is 0). T is padded to whole sublane
+    groups of the activations' type here."""
+    T = x.shape[0]
+    pad = -T % (32 // x.dtype.itemsize)
+    if pad:
+        x = jnp.pad(x, ((0, pad), (0, 0)))
+    return _fwd_call(x, wg, wu, wd, tile_group, tile, live, layer, name, route=(sort_tok, gate_sorted))[:T]
 
 
 def _bwd_call(xs, dy, wg, wu, wd, tile_group, tile):

@@ -578,8 +578,47 @@ def held_tile(cfg: MoEConfig, choices: int, tile: int) -> int:
     expects fewer rows than it holds (a decode step's 16 rows, a short prefill),
     and twice that beyond (a 2048-row chunk: half the tiles, and an expert's
     slab read once, not twice). On the chip at 6144 x 2048, 256 rows: 32 -> 2.18
-    ms a layer, 64 -> 1.77, 128 -> 1.74."""
+    ms a layer, 64 -> 1.77, 128 -> 1.74. In the staged form (``held_form``) the
+    bound is also a buffer: XLA writes ``x[sort_tok]`` at all of it whatever was
+    chosen (64 rows as ``[5248, 4096]``, 43 MB a layer); in the kernel's form
+    it is grid steps and two ``[bound]`` vectors and nothing else."""
     return 2 * tile if choices // cfg.num_experts >= tile else tile
+
+
+#: the most tokens a call may have for the kernel to gather its rows and sum its choices itself (``held_form``)
+HELD_IN_KERNEL_TOKENS = 512
+#: VMEM the call's tokens may take there, beside ``moe_gemm._WEIGHT_VMEM`` of weight blocks and a row tile's buffers
+_TOKENS_VMEM = 40 * 1024 * 1024
+
+
+def held_form(T: int, D: int, itemsize: int) -> str:
+    """Where a held layer's rows are gathered and its choices summed, from the
+    call's shapes alone: ``"in_kernel"`` (``moe_gemm.moe_swiglu_tokens``: the
+    grouped product takes ``x [T, D]`` and returns ``y [T, D]``; the sorted rows
+    and the experts' outputs never exist in HBM) where the tokens, twice (the
+    pipeline's two buffers), ``y`` twice and the float32 sum fit ``_TOKENS_VMEM``
+    and T is at most ``HELD_IN_KERNEL_TOKENS``: a tile's two one-hot products
+    are ``4 T tile D`` operations beside a slab of ``6 D F`` bytes, a hundredth
+    of its fetch at T = 64 and a third at 512 x 6144 x 2048. Else ``"staged"``:
+    XLA gathers ``x[sort_tok]`` into the static row bound, the product reads
+    and writes the live tiles, XLA gathers the choices back and sums them: what
+    is left when a chunk's rows outnumber what VMEM holds (a 1024- or 2048-row
+    prefill chunk: 2048 x 6144 would want 151 MB). Every decode batch of the
+    benchmark (24, 48, 64, 256 slots) and a prefill bucket of up to 512 rows
+    are in the kernel. On the chip, a layer alone, staged | in the kernel
+    (PERF.md section 6, PR 54): 64 x 4096 x 768 of 36 experts 1.24 | 0.95 ms,
+    256 x 6144 x 2048 of 16 2.14 | 1.71, 512 x 6144 x 2048 2.41 | 1.76 (the
+    largest it holds: 37.7 MB of tokens, sum and result); the kernel's form
+    was the faster at every shape tried, by more the more rows."""
+    fits = T * D * (4 * itemsize + 4) <= _TOKENS_VMEM
+    return "in_kernel" if T <= HELD_IN_KERNEL_TOKENS and fits else "staged"
+
+
+def held_ffn_form(cfg: MoEConfig, T: int, D: int, F: int, dtype) -> str:
+    """The form ``held_expert_ffn`` runs in for T tokens of this geometry:
+    ``held_form``'s where the fused kernel runs at all (``_kernel_eligible``),
+    ``"staged"`` under ``ragged_dot``. The engine counts its programs by it."""
+    return held_form(T, D, jnp.dtype(dtype).itemsize) if _kernel_eligible(cfg, D, F, jnp.dtype(dtype)) else "staged"
 
 
 def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConfig, count_mask=None,
@@ -593,11 +632,18 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     a held expert that no row chose has no row tile (``route_ragged``), so a
     step reads the held-and-chosen experts' weights and nothing else of size:
     with no choice on any held expert the kernel runs no tile and ``y`` is
-    zero. ``rows`` counts each held expert's real rows (its load this call),
-    from the tokens ``count_mask`` [T] marks (a decode step's idle slots are
-    computed like any row and counted as none). ``name``: what the fused call
-    is called in a trace (a decode step's and a prefill chunk's are told apart
-    by it). No capacity: no choice is dropped. No backward."""
+    zero. Where the fused kernel runs and ``held_form`` says ``"in_kernel"`` (a
+    decode step, a prefill bucket of up to 512 rows) the one call gathers a
+    tile's rows from ``x`` and sums the gated choices into ``y`` in VMEM;
+    otherwise (a longer prefill chunk, or ``ragged_dot`` where the kernel is
+    not eligible) the rows are staged through HBM at the static bound, before
+    the product and after.
+    The two forms round alike and sum a token's choices in another order.
+    ``rows`` counts each held expert's real rows (its load this call), from the
+    tokens ``count_mask`` [T] marks (a decode step's idle slots are computed
+    like any row and counted as none). ``name``: what the fused call is called
+    in a trace (a decode step's and a prefill chunk's are told apart by it). No
+    capacity: no choice is dropped. No backward."""
     from tony_tpu.ops import moe_gemm
 
     if cfg.held is None or cfg.dispatch != "ragged":
@@ -605,13 +651,19 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     T, D = x.shape
     K, F = cfg.top_k, w_gate.shape[-1]
     tile = held_tile(cfg, T * K, moe_gemm.TILE_M) if _kernel_eligible(cfg, D, F, x.dtype) else None
-    sort_tok, dest, gate_vals, _, group_sizes, _ = route_ragged(x[None], router_w, cfg, None, tile=tile, bias=bias)
+    sort_tok, dest, gate_vals, gate_sorted, group_sizes, _ = route_ragged(x[None], router_w, cfg, None, tile=tile, bias=bias)
     rows = sort_tok.shape[0]
     on = dest < rows                                                     # [T*K]: the choice has a row
     counted = on if count_mask is None else on & jnp.repeat(count_mask, K)
-    real = jnp.zeros((cfg.held[1],), jnp.int32).at[
-        jnp.where(counted, jnp.searchsorted(jnp.cumsum(group_sizes), dest, side="right"), cfg.held[1])
-    ].add(1, mode="drop")
+    # a held expert's real rows: the counted choices whose row lies in its span, one compare over [T*K, count]
+    # (a binary search of the spans is six dependent gathers of T*K elements, 77 us a layer at 640 choices of 36)
+    ends = jnp.cumsum(group_sizes)
+    real = (counted[:, None] & (dest[:, None] >= (ends - group_sizes)[None]) & (dest[:, None] < ends[None])).sum(0, dtype=jnp.int32)
+    if held_ffn_form(cfg, T, D, F, x.dtype) == "in_kernel":
+        tg = moe_gemm.tile_group_map(group_sizes, rows // tile, tile)
+        y = moe_gemm.moe_swiglu_tokens(x, sort_tok, gate_sorted, w_gate, w_up, w_down, tg, tile,
+                                       group_sizes.sum() // tile, layer, name)
+        return y, real
     ys = _expert_swiglu(x[sort_tok], w_gate, w_up, w_down, group_sizes, tile, layer, name)
     # a choice of an absent expert reads row 0 and is masked, never multiplied: rows past the groups are unwritten
     yc = jnp.where(on[:, None], ys[jnp.where(on, dest, 0)], 0).reshape(T, K, D)
