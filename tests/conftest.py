@@ -42,6 +42,46 @@ def tmp_tony_root(tmp_path, monkeypatch):
     return root
 
 
+#: PR 55's per-layer metrics of a serving cell judged by tokens/s, in BENCHMARK.json's order
+STARTUP_METRICS_SERVE = [
+    "submit_to_am_s", "allocate_s", "register_s", "runtime_init_s", "weights_s", "replica_warmup_s.serve",
+    "setup_compile_s.serve", "setup_cache_load_s.serve", "setup_trace_lower_s.serve", "compile_ms_per_pass.serve"]
+
+
+@pytest.fixture()
+def startup_account():
+    """check(spec, workload): the start-up account of a rehearsal that has just
+    run (benchmark/run.py --workload <tiny-*>.serve), read from what it left
+    under .bench_work/ by the readers the listed cells use: every stage metric
+    a number, the stages through `ready` no longer than the run, and the
+    window's compile time printed (0 on a sound run). Starts no fleet or job."""
+    import glob
+    import importlib
+    import json
+
+    def check(spec, workload: str) -> dict:
+        work = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), ".bench_work", workload)
+        ctl = os.path.join(work, "out", "ctl")
+        drive = {tag: json.load(open(os.path.join(ctl, f"snap.{name}.json")))
+                 for tag, name in (("snap0", "open"), ("snap1", "close"))}
+        drive["t_open"] = drive["snap0"]["t"]
+        (app_dir,) = glob.glob(os.path.join(work, "staging", "application_*"))
+        ctx = {"kind": "serve", "app_dir": app_dir, "drive": drive}
+        got = {}
+        for name in STARTUP_METRICS_SERVE:
+            m = spec.metric(name)
+            got[name] = importlib.import_module("readers." + m["reader"]).read(ctx, **m["args"])
+            assert got[name] is not None and got[name] >= 0.0, (name, got)
+        stages = sum(got[n] for n in STARTUP_METRICS_SERVE[:6])
+        led = ctx["goodput_ledger"]
+        assert 0.0 < got["runtime_init_s"] and 0.0 < got["weights_s"] and stages <= drive["t_open"] - led.t0_ms / 1000.0
+        assert got["setup_compile_s.serve"] + got["setup_cache_load_s.serve"] > 0.0 and got["setup_trace_lower_s.serve"] > 0.0
+        print(f"[startup] {workload}: " + ", ".join(f"{n}={got[n]:.3f}" for n in STARTUP_METRICS_SERVE))
+        return got
+
+    return check
+
+
 # A hang costs one test, not the run: no pytest-timeout is installed, and a
 # test that never returns holds its xdist worker until the whole run's own time
 # limit cuts it (and every test still queued behind it goes uncounted).

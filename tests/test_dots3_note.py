@@ -603,7 +603,11 @@ def test_the_cells_entries_and_files(bench):
         "moe_decode_roofline_pct.serve", "moe_prefill_roofline_pct.serve", "expert_rows_max_over_mean.serve", "held_share_pct.serve",
         "launch_s", "slots_active_mean.serve", "host_share_pct.serve", "decode_batch_mean.serve", "visible_share_pct.serve",
         "decode_step_ms.serve_tput", "chunk_period_ms.serve_tput", "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput",
-        "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput", "write_gap_pct.serve_tput"}
+        "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput", "write_gap_pct.serve_tput",
+        # PR 55: the start-up account and the compiles by source
+        "submit_to_am_s", "allocate_s", "register_s", "runtime_init_s", "weights_s", "replica_warmup_s.serve",
+        "setup_compile_s.serve", "setup_cache_load_s.serve", "setup_trace_lower_s.serve", "compile_ms_per_pass.serve",
+        }
     # the five this cell brought stand together and start at the cell's name (a later latent family's cell joins the last)
     at = next(i for i, m in enumerate(B["per_layer"]) if m["name"] == new[0])
     brought = B["per_layer"][at:at + 5]
@@ -637,7 +641,7 @@ def test_the_cells_entries_and_files(bench):
     assert all(set(v) == {"value", "why"} and v["why"] for k, v in cfg["assumed"].items() if isinstance(v, dict))
 
 
-def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
+def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench, startup_account):
     """`tiny-dots3-note.serve` through run.py: the `tony serve` path, the router,
     the replica registered through the family's hook, chunked prefill and decode
     through the latent pool, the index keys and the rings under the interpreter,
@@ -653,3 +657,6 @@ def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path):
     ctl = os.path.join(ROOT, ".bench_work", TINY + ".serve", "out", "ctl")
     names = {m["name"] for m in json.load(open(os.path.join(ctl, "snap.close.json")))["metrics"]}
     assert {"tony_serve_index_positions_total", "tony_serve_expert_rows_total", "tony_serve_visible_tokens_total"} <= names
+    # PR 55: the same run's start-up by stage (its .jhist's stamps) and its compiles by source (snap0), read by the
+    # listed cells' readers, and the window's compile time printed
+    startup_account(bench["spec"], TINY + ".serve")

@@ -524,7 +524,11 @@ def test_the_cells_entries_and_files(bench):
         "decode_batch_mean.serve", "visible_share_pct.serve", "decode_step_ms.serve_tput", "chunk_period_ms.serve_tput",
         # PR 41: the host side of a pass
         "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput",
-        "write_gap_pct.serve_tput"}
+        "write_gap_pct.serve_tput",
+        # PR 55: the start-up account and the compiles by source
+        "submit_to_am_s", "allocate_s", "register_s", "runtime_init_s", "weights_s", "replica_warmup_s.serve",
+        "setup_compile_s.serve", "setup_cache_load_s.serve", "setup_trace_lower_s.serve", "compile_ms_per_pass.serve",
+        }
     assert {m["moves"] for m in per_layer} == {"serve_out_tok_s", "setup_s"}
     assert all(spec.metric(m["name"])["moves"] == m["moves"] for m in B["per_layer"])
     # the five this cell brought start at the cell's name (a later cell of a family with a routed FFN joins four of them)
@@ -562,7 +566,7 @@ def test_the_cells_entries_and_files(bench):
     assert all(set(v) == {"value", "why"} and v["why"] for k, v in cfg["assumed"].items() if isinstance(v, dict))
 
 
-def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
+def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench, startup_account):
     """`tiny-exaone-moe.serve` through run.py: the `tony serve` path, the
     router, the replica registered through the family's hook, bucketed prefill
     and decode through pages and rings under the interpreter, and the harness's
@@ -584,3 +588,6 @@ def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
     for name in ("host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput"):
         value = registry_delta.read({"drive": drive}, **bench["spec"].metric(name)["args"])
         assert value is not None and value >= 0.0, name
+    # PR 55: the same run's start-up by stage (its .jhist's stamps) and its compiles by source (snap0), read by the
+    # listed cells' readers, and the window's compile time printed
+    startup_account(bench["spec"], TINY + ".serve")

@@ -216,16 +216,24 @@ class TestLedger:
 # satellite: randomized-history property test — the partition is EXACT
 # ---------------------------------------------------------------------------
 class TestPartitionProperty:
-    def _random_history(self, rng):
+    def _random_history(self, rng, stamps=False):
         """A randomized event/span history with restarts, resizes,
         takeovers, queue waits, snapshots — including degenerate orderings
-        a torn stream can produce."""
+        a torn stream can produce. With ``stamps`` it also carries the
+        start-up account: a client's ``submitted_ms`` (before or after the
+        AM's first event), TASK_STARTUP_STAMPS of either kind with any subset
+        of the stamps missing and their times out of order, and URL
+        registrations. Without, it draws exactly what the parent's did."""
         t = rng.randrange(0, 10_000)
         events, spans = [], []
         step = 0
+        if stamps and rng.random() < 0.6:
+            events.append(ev("APPLICATION_INITED", t + 1, **(
+                {"submitted_ms": rng.choice([t - rng.randrange(0, 3000), t + rng.randrange(0, 500), "x", None])}
+                if rng.random() < 0.8 else {})))
         for _ in range(rng.randrange(1, 40)):
             t += rng.randrange(0, 2000)
-            kind = rng.randrange(10)
+            kind = rng.randrange(13 if stamps else 10)
             if kind == 0:
                 events.append(ev("QUEUE_WAIT", t,
                                  state=rng.choice(["waiting", "admitted"])))
@@ -251,6 +259,18 @@ class TestPartitionProperty:
                     ["ckpt.save", "am.takeover", "train.first_step", "other.span"])
                 spans.append({"name": name, "start_ms": float(s0),
                               "end_ms": float(s0 + rng.randrange(0, 2500))})
+            elif kind in (10, 11):
+                names = ["child_spawned", "main_entered", "devices_ready", "weights_ready",
+                         "first_step_done", "registered"]
+                taken = {n: t - rng.randrange(-500, 6000)  # any order, some in the future
+                         for n in names if rng.random() < 0.7}
+                if rng.random() < 0.1:
+                    taken["devices_ready"] = rng.choice(["soon", None, float("nan"), True])
+                events.append(ev("TASK_STARTUP_STAMPS", t, task=f"worker:{rng.randrange(2)}",
+                                 attempt=rng.randrange(2), stamps=taken,
+                                 kind=rng.choice(["train", "serve", None])))
+            elif kind == 12:
+                events.append(ev("TASK_URL_REGISTERED", t, task=f"worker:{rng.randrange(2)}", url="u"))
             else:
                 step += rng.randrange(0, 4)
                 events.append(snap(t, **{
@@ -262,11 +282,14 @@ class TestPartitionProperty:
             events.append(ev("APPLICATION_FINISHED", t, status="SUCCEEDED"))
         return events, spans, t + rng.randrange(0, 5000)
 
-    def test_partition_is_exact_over_random_histories(self):
+    @pytest.mark.parametrize("stamps", [False, True], ids=["stampless", "stamped"])
+    def test_partition_is_exact_over_random_histories(self, stamps):
+        claimed = set()
         for seed in range(300):
             rng = random.Random(seed)
-            events, spans, now = self._random_history(rng)
+            events, spans, now = self._random_history(rng, stamps=stamps)
             led = obs_goodput.build_ledger("r", events, spans, now_ms=now)
+            claimed |= set(led.phases_ms)
             try:
                 assert_exact(led)
                 assert all(v >= 0 for v in led.phases_ms.values())
@@ -275,14 +298,157 @@ class TestPartitionProperty:
                     assert 0.0 <= led.window_fraction(w) <= 1.0
             except AssertionError as e:  # pragma: no cover - diagnostics
                 raise AssertionError(f"seed {seed}: {e}") from e
+        # the generator reaches what it is there for: every start-up stage, or none of them
+        new = {"submit", "runtime_init", "weights", "warmup"}
+        assert (claimed & new) == (new if stamps else set())
+
+    def test_a_history_without_stamps_partitions_as_the_parent_did(self):
+        """Digest of phases and episodes over the 300 stampless histories,
+        taken with the parent commit's obs/goodput.py (3966ea8) on the very
+        same draws: the stages claim nothing a `.jhist` does not stamp."""
+        import hashlib
+
+        h = hashlib.sha256()
+        for seed in range(300):
+            events, spans, now = self._random_history(random.Random(seed))
+            led = obs_goodput.build_ledger("r", events, spans, now_ms=now)
+            assert led.stamps == {}
+            h.update(json.dumps([seed, sorted(led.phases_ms.items()), led.episodes]).encode())
+        assert h.hexdigest() == "70765193ba4a669a6851bb7b13e752e4b338f9bf14ee97e484c1f43976283e03"
 
     def test_shuffled_span_order_is_irrelevant(self):
         rng = random.Random(42)
-        events, spans, now = self._random_history(rng)
+        events, spans, now = self._random_history(rng, stamps=True)
         led1 = obs_goodput.build_ledger("r", events, spans, now_ms=now)
         rng.shuffle(spans)
         led2 = obs_goodput.build_ledger("r", events, spans, now_ms=now)
         assert led1.phases_ms == led2.phases_ms
+
+
+# ---------------------------------------------------------------------------
+# the start-up account: stages claimed from the stamps the .jhist carries
+# ---------------------------------------------------------------------------
+def stamps_ev(ts, task, kind, attempt=0, **taken):
+    return ev("TASK_STARTUP_STAMPS", ts, task=task, attempt=attempt, kind=kind, stamps=taken)
+
+
+def serving_history(stamped=True, replicas=1):
+    """`tony serve`: GANG_COMPLETE at 2000, replica i ready at 9000 + 1000 i."""
+    events = [
+        ev("APPLICATION_INITED", 1000, **({"submitted_ms": 400} if stamped else {})),
+        ev("TASK_REGISTERED", 1900, task="serve:0"),
+        ev("GANG_COMPLETE", 2000, tasks=replicas),
+    ]
+    for i in range(replicas):
+        events.append(ev("TASK_URL_REGISTERED", 9000 + 1000 * i, task=f"serve:{i}", url="http://x"))
+        if stamped:
+            events.append(stamps_ev(
+                11500 + i, f"serve:{i}", "serve", child_spawned=2150, main_entered=4000,
+                devices_ready=6000 + 1000 * i, weights_ready=8500 + 1000 * i, registered=9001 + 1000 * i))
+    events += [snap(12000, **{"serve:0": 1}), snap(14000, **{"serve:0": 2}),
+               ev("APPLICATION_FINISHED", 20000, status="KILLED")]
+    return events
+
+
+class TestStartupStages:
+    @pytest.mark.parametrize("case,phases", [
+        # GANG_COMPLETE -> ready is runtime_init + weights + warmup to the ms, none of it productive
+        ("serving", {"submit": 600, "startup": 900, "registration": 100, "runtime_init": 4000,
+                     "weights": 2500, "warmup": 500, "productive": 11000}),
+        # the earlier stage wins among replicas: productive starts when the LAST one is ready
+        ("serving-2-replicas", {"submit": 600, "startup": 900, "registration": 100, "runtime_init": 5000,
+                                "weights": 2500, "warmup": 500, "productive": 10000}),
+        # what the parent's ledger says of the same job without stamps: the replica's start-up is
+        # filed under compile (to the first snapshot with a step) and productive, and t0 is the AM's
+        ("serving-stampless", {"startup": 900, "registration": 100, "compile": 10000, "productive": 8000}),
+    ])
+    def test_a_serving_job_by_stage(self, case, phases):
+        led = obs_goodput.build_ledger("s", serving_history(
+            stamped="stampless" not in case, replicas=2 if "2" in case else 1))
+        assert_exact(led)
+        assert led.phases_ms == phases
+        if "stampless" in case:
+            assert led.t0_ms == 1000 and led.stamps == {}
+            return
+        assert led.t0_ms == 400 and led.stamps["client"] == {"submitted": 400}
+        assert led.stamps["serve:0@2000"]["ready"] == 9000
+        last_ready = 10000 if "2" in case else 9000
+        assert sum(led.phases_ms[p] for p in ("runtime_init", "weights", "warmup")) == last_ready - 2000
+        assert next(start for ph, start, _end in led.episodes if ph == "productive") == last_ready
+
+    def test_a_training_job_by_stage_and_the_estimate_it_replaces(self):
+        events = [
+            ev("APPLICATION_INITED", 1000, submitted_ms=700),
+            ev("GANG_COMPLETE", 2000),
+            stamps_ev(7000, "worker:0", "train", child_spawned=2100, main_entered=3000, devices_ready=5000),
+            stamps_ev(12000, "worker:0", "train", child_spawned=2100, main_entered=3000, devices_ready=5000,
+                      weights_ready=6000, first_step_done=9000),
+            snap(15000, **{"worker:0": 10}),
+            ev("APPLICATION_FINISHED", 20000, status="SUCCEEDED"),
+        ]
+        # a traced job's first-step span is not consulted once the epoch is stamped
+        spans = [{"name": "train.first_step", "start_ms": 6500.0, "end_ms": 9050.0}]
+        led = obs_goodput.build_ledger("t", events, spans)
+        assert_exact(led)
+        assert led.phases_ms == {"submit": 300, "startup": 1000, "runtime_init": 3000, "weights": 1000,
+                                 "compile": 3000, "productive": 11000}
+        # the latest report of the task and epoch is the one read
+        assert led.stamps["worker:0@2000"]["first_step_done"] == 9000
+        stampless = [e for e in events if e.type.value != "TASK_STARTUP_STAMPS"]
+        assert obs_goodput.build_ledger("t", stampless).phases_ms["compile"] == 13000  # GC -> first snapshot
+        assert obs_goodput.build_ledger("t", stampless, spans).phases_ms["compile"] == 7050  # GC -> span's end
+
+    def test_two_gang_epochs_and_a_restart_between_devices_and_weights(self):
+        events = [
+            ev("GANG_COMPLETE", 1000),
+            stamps_ev(4000, "worker:0", "train", main_entered=2000, devices_ready=3000),
+            ev("HEARTBEAT_LOST", 5000, reason="gang restart: worker:0 FAILED"),
+            ev("GANG_COMPLETE", 6000),
+            stamps_ev(11000, "worker:0", "train", attempt=1, main_entered=6500, devices_ready=7000,
+                      weights_ready=8000, first_step_done=10000),
+            ev("APPLICATION_FINISHED", 15000, status="SUCCEEDED"),
+        ]
+        led = obs_goodput.build_ledger("t", events)
+        assert_exact(led)
+        # epoch 1 died loading its weights: that stage runs to the restart marker, and no compile
+        # is estimated from GANG_COMPLETE for an epoch that carries stamps
+        assert [e for e in led.episodes if e[1] < 5000] == [
+            ("runtime_init", 1000, 3000), ("weights", 3000, 5000)]
+        assert led.phases_ms == {"runtime_init": 3000, "weights": 3000, "startup": 1000,
+                                 "compile": 2000, "productive": 5000}
+        assert set(led.stamps) == {"worker:0@1000", "worker:0@6000"}
+
+    @pytest.mark.parametrize("taken,phases", [
+        # a live child still initialising its runtime: the stage is open to now
+        ({"main_entered": 2500}, {"runtime_init": 7000}),
+        # compiling now
+        ({"devices_ready": 3000, "weights_ready": 4000}, {"runtime_init": 2000, "weights": 1000, "compile": 4000}),
+        # an edge never stamped though the child got past it is nobody's stage: the filler's
+        ({"weights_ready": 4000, "first_step_done": 6000}, {"compile": 2000, "productive": 5000}),
+        # stamps out of order claim nothing they cannot: no weights stage runs backwards, and
+        # the compile claim lies inside the earlier stage, which wins
+        ({"devices_ready": 5000, "weights_ready": 3000, "first_step_done": 4000},
+         {"runtime_init": 4000, "productive": 3000}),
+    ])
+    def test_subsets_and_orders_of_a_live_training_childs_stamps(self, taken, phases):
+        events = [ev("GANG_COMPLETE", 1000), stamps_ev(7500, "worker:0", "train", **taken)]
+        led = obs_goodput.build_ledger("t", events, now_ms=8000)
+        assert_exact(led)
+        assert led.phases_ms == phases
+
+    def test_the_new_rows_reach_the_cli_and_json(self, capsys):
+        led = obs_goodput.build_ledger("s", serving_history())
+        from tony_tpu.cli import goodput as cli_goodput
+
+        text = cli_goodput.render(led, None, [], [], 60000)
+        rows = [ln.split()[0] for ln in text.split("phase ledger")[1].split("total")[0].splitlines()[1:] if ln.strip()]
+        assert rows == [p for p in obs_goodput.PHASE_ORDER if led.phases_ms.get(p)]
+        assert {"submit", "runtime_init", "weights", "warmup"} <= set(rows)
+        assert led.to_dict()["stamps"]["serve:0@2000"]["weights_ready"] == 8500
+        # the priorities the issue fixes: above the wide claims, below the narrow precise ones
+        pr = obs_goodput._PRIORITY
+        for new in ("submit", "runtime_init", "weights", "warmup"):
+            assert max(pr["startup"], pr["registration"], pr["productive"]) < pr[new] < min(pr["checkpoint"], pr["takeover"])
 
 
 # ---------------------------------------------------------------------------

@@ -623,7 +623,11 @@ def test_the_cells_entries_and_files(bench):
         "launch_s", "slots_active_mean.serve", "host_share_pct.serve", "decode_batch_mean.serve",
         # PR 41: the host side of a pass
         "host_gap_pct.serve_tput", "host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput",
-        "write_gap_pct.serve_tput"}
+        "write_gap_pct.serve_tput",
+        # PR 55: the start-up account and the compiles by source
+        "submit_to_am_s", "allocate_s", "register_s", "runtime_init_s", "weights_s", "replica_warmup_s.serve",
+        "setup_compile_s.serve", "setup_cache_load_s.serve", "setup_trace_lower_s.serve", "compile_ms_per_pass.serve",
+        }
     # a per-layer metric moves an end-to-end metric its cell reports, and its file says what BENCHMARK.json says
     assert {m["moves"] for m in per_layer} == {"serve_out_tok_s", "setup_s"}
     assert all(spec.metric(m["name"])["moves"] == m["moves"] for m in B["per_layer"])
@@ -643,7 +647,7 @@ def test_the_cells_entries_and_files(bench):
     assert {k: cfg[k] for k in published} == published and cfg["num_hidden_layers"]["source"] == 32
 
 
-def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
+def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench, startup_account):
     """`tiny-minicpm-sala.serve` through run.py: the `tony serve` path, the
     router, the replica registered through the family's hook, chunked prefill
     and paged decode under the interpreter, and the harness's own comparison
@@ -665,3 +669,6 @@ def test_the_rehearsal_cell_runs_end_to_end_on_the_cpu(tmp_path, bench):
     for name in ("host_offcpu_ms.serve_tput", "stream_write_ms.serve_tput", "fanout_delay_ms.serve_tput"):
         value = registry_delta.read({"drive": drive}, **bench["spec"].metric(name)["args"])
         assert value is not None and value >= 0.0, name
+    # PR 55: the same run's start-up by stage (its .jhist's stamps) and its compiles by source (snap0), read by the
+    # listed cells' readers, and the window's compile time printed
+    startup_account(bench["spec"], TINY + ".serve")

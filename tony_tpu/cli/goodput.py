@@ -1,8 +1,8 @@
 """``tony goodput <app_id>`` — where did this job's wall-clock go?
 
-Prints the exact phase partition (obs/goodput.py) of a job's wall-time —
-productive steps vs queue wait, startup, registration, compile, checkpoint,
-restart rework, resize/takeover episodes, drain — plus the badput breakdown,
+Prints the exact phase partition (obs/goodput.py) of a job's wall-time — a
+row a phase of ``obs_goodput.PHASE_ORDER`` that took any time, the one list
+of phase names there is — plus the badput breakdown,
 per-rank step-time skew (straggler attribution), and the job's alert
 history. Works on finalized jobs (artifacts only) and live jobs (artifacts
 up to "now", with the AM's ``get_goodput`` RPC adding live skew and the

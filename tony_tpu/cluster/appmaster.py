@@ -326,6 +326,8 @@ class ApplicationMaster:
         # an acknowledged-but-unapplied request.
         self._pending_resize: dict[str, int] = {}
         self._client_obs: dict[str, Any] = {}  # submitter-side registries (fleet router)
+        #: (task, gang epoch) → the start-up stamps last written to the .jhist
+        self._startup_seen: dict[tuple[str, int], dict[str, Any]] = {}
         # hot spares (tony.elastic.spares): pre-allocated, pre-registered
         # executors of the elastic jobtype parked next to the gang. spare_id →
         # {"container", "ready", "assignment"}; assignment != None means the
@@ -729,8 +731,19 @@ class ApplicationMaster:
         session = self._fenced_session(attempt)
         if session is None:
             return {"ack": False, "stale": True}
+        # the child's start-up stamps ride the push (executor._metrics_loop)
+        # and leave it here: executors re-push them until the child ends, and
+        # the .jhist takes them once a task, gang epoch and stamp taken
+        startup = metrics.pop("startup", None)
         with session.lock:
             session.get_task(job_name, index).metrics = metrics
+        if isinstance(startup, dict) and startup.get("stamps"):
+            key = (f"{job_name}:{index}", int(attempt))
+            if self._startup_seen.get(key) != startup:
+                self._startup_seen[key] = startup
+                self.events.emit(
+                    EventType.TASK_STARTUP_STAMPS, task=key[0], attempt=key[1],
+                    kind=startup.get("kind"), stamps=startup["stamps"])
         return {"ack": True}
 
     def push_client_metrics(self, identity: str, metrics: Any) -> dict[str, Any]:
@@ -1134,6 +1147,7 @@ class ApplicationMaster:
                 EventType.APPLICATION_INITED,
                 app_id=self.app_id,
                 job_types={t: self.config.instances(t) for t in self.config.job_types()},
+                **self._submit_stamp(),
             )
         host, port = self.rpc.address
         info = {"host": host, "port": port, "secret": self.secret, "pid": os.getpid()}
@@ -1150,6 +1164,16 @@ class ApplicationMaster:
             + (f", am attempt {self.am_attempt}" if self.am_attempt else "")
             + ")"
         )
+
+    def _submit_stamp(self) -> dict[str, int]:
+        """``{"submitted_ms": ...}`` as the client staged it (Client.submit,
+        before staging), or nothing: an AM launched by an older client, or by
+        hand, opens the ledger at its own first event as before."""
+        try:
+            with open(os.path.join(self.staging_dir, constants.SUBMIT_INFO_FILE)) as f:
+                return {"submitted_ms": int(json.load(f)["submitted_ms"])}
+        except (OSError, ValueError, KeyError, TypeError):
+            return {}
 
     # ------------------------------------------------- work-preserving takeover
     def _perform_takeover(self) -> bool:

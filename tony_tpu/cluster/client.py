@@ -91,6 +91,9 @@ class Client:
     def submit(self) -> ApplicationHandle:
         if not self.config.job_types():
             raise ValueError("no job types declared (set tony.<type>.instances > 0)")
+        # the goodput ledger's t0 (obs/goodput.py `submit` phase): stamped by
+        # the process that does the staging, before it starts
+        submitted_ms = int(time.time() * 1000)
         app_id = f"application_{int(time.time())}_{uuid.uuid4().hex[:8]}"
         root = self.config.get(keys.STAGING_ROOT) or constants.default_tony_root()
         staging_dir = os.path.join(root, app_id)
@@ -107,6 +110,12 @@ class Client:
         if not self.config.frozen:
             self.config.freeze()
         self.config.write_final(staging_dir)
+        # staged beside the frozen conf, which holds declared keys only: the AM
+        # carries the stamp in APPLICATION_INITED's payload
+        info_path = os.path.join(staging_dir, constants.SUBMIT_INFO_FILE)
+        with open(info_path + ".tmp", "w") as f:
+            json.dump({"submitted_ms": submitted_ms}, f)
+        os.replace(info_path + ".tmp", info_path)
 
         obs_metrics.set_enabled(self.config.get_bool(keys.METRICS_ENABLED, True))
         # structured logging (tony.log.*): the submitter's records join the

@@ -48,6 +48,10 @@ class EventType(enum.Enum):
     SPARE_READY = "SPARE_READY"        # hot-spare executor pre-registered with the AM
     SPARE_PROMOTED = "SPARE_PROMOTED"  # spare bound to a gang slot (skipped allocation)
     TASK_URL_REGISTERED = "TASK_URL_REGISTERED"
+    # the chip-holding child's start-up stamps (obs/startup.py), as the task's
+    # metrics push carried them: written when a task's stamps are first seen
+    # in a gang epoch and again only when one more has been taken
+    TASK_STARTUP_STAMPS = "TASK_STARTUP_STAMPS"
     METRICS_SNAPSHOT = "METRICS_SNAPSHOT"
     PROFILE_REQUESTED = "PROFILE_REQUESTED"    # on-demand capture fan-out began
     PROFILE_FINISHED = "PROFILE_FINISHED"      # every targeted task reported

@@ -28,6 +28,7 @@ from tony_tpu.cluster.rpc import RpcClient, RpcError
 from tony_tpu.obs import introspect as obs_introspect
 from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
+from tony_tpu.obs import startup as obs_startup
 from tony_tpu.obs import trace as obs_trace
 from tony_tpu.runtime import get_runtime
 
@@ -454,6 +455,7 @@ class TaskExecutor:
             for stale in (
                 path,
                 path + ".obs",
+                path + obs_startup.FILE_SUFFIX,
                 path + obs_introspect.CONTROL_SUFFIX,
                 path + obs_introspect.DONE_SUFFIX,
                 path + obs_introspect.DRAIN_CONTROL_SUFFIX,
@@ -468,6 +470,10 @@ class TaskExecutor:
         if src_dir:
             staged_src = os.path.join(self.staging_dir, "src")
             cwd = staged_src if os.path.isdir(staged_src) else src_dir
+        # the start-up account's opening edge on the child's side (obs/startup.py):
+        # interpreter start and the entry's imports lie between this and the
+        # child's own first stamp
+        env[constants.ENV_CHILD_SPAWNED_MS] = str(int(time.time() * 1000))
         return subprocess.Popen(
             ["/bin/bash", "-c", command],
             env=env,
@@ -549,9 +555,21 @@ class TaskExecutor:
             child_pid=self.child.pid if self.child else None,
             with_tpu=False,
         )
-        while not self._stop.wait(interval):
+        startup_pushed = None
+        while True:
+            stopped = self._stop.wait(interval)
+            # the child's start-up stamps, dropped next to its step report
+            # (obs/startup.py): the AM writes them to the .jhist when they
+            # change. Once the child has ended, one more push only if it took
+            # a stamp since the last one (a first step that closed inside the
+            # interval must not die with this loop)
+            startup = obs_startup.read_report(getattr(self, "_train_metrics_path", None))
+            if stopped and startup == startup_pushed:
+                return
             try:
                 m = sampler.sample()
+                if startup is not None:
+                    m["startup"] = startup
                 train = self._read_train_metrics()
                 if train is not None:
                     m["train"] = train
@@ -572,8 +590,11 @@ class TaskExecutor:
                     metrics=m,
                     attempt=self.attempt,
                 )
+                startup_pushed = startup
             except (RpcError, OSError):
                 pass  # metrics are best-effort; liveness is the heartbeat's job
+            if stopped:
+                return
 
     def _report_profile(self, **params) -> None:
         """Courier callback: capture status back to the AM. Raises on RPC
@@ -761,7 +782,8 @@ class TaskExecutor:
             pid=self.child.pid,
         )
         self._start_chaos_timers()
-        threading.Thread(target=self._metrics_loop, name="metrics", daemon=True).start()
+        metrics_thread = threading.Thread(target=self._metrics_loop, name="metrics", daemon=True)
+        metrics_thread.start()
 
         if self.job_name in (constants.TENSORBOARD_JOB_NAME, constants.NOTEBOOK_JOB_NAME):
             url = f"http://{self.host}:{self.port}"
@@ -794,6 +816,7 @@ class TaskExecutor:
             exit_code=rc,
         )
         self._stop.set()
+        metrics_thread.join(timeout=2.0)  # its last push: stamps the child took since the one before
         try:
             # final courier sweep: a capture the child finalized in its
             # `finally` (truncated by end-of-training) races the heartbeat

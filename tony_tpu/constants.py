@@ -18,6 +18,7 @@ TONY_DEFAULT_CONF = "tony-default.json"  # packaged defaults (tony-default.xml a
 TONY_SITE_CONF = "tony-site.json"       # cluster-level overrides
 TONY_STAGING_DIRNAME = ".tony"          # per-app staging root
 AM_INFO_FILE = "am_info.json"           # AM host/port/secret advertisement (YARN report analog)
+SUBMIT_INFO_FILE = "submit_info.json"   # the client's submit stamp, staged beside the frozen conf (goodput `submit` phase)
 AM_JOURNAL_FILE = "am_journal.jsonl"    # AM recoverable-state journal (work-preserving takeover)
 POOL_INFO_FILE = "pool_info.json"       # pool-service host/port advertisement (RM address analog)
 CONFIG_SNAPSHOT_FILE = "config.json"    # job conf written alongside history (HistoryFileUtils)
@@ -66,6 +67,9 @@ ENV_CHAOS_SEED = "TONY_CHAOS_SEED"    # from tony.chaos.seed
 ENV_TRACE_ENABLED = "TONY_TRACE_ENABLED"  # "1" → tracing on in this process tree
 ENV_TRACE_DIR = "TONY_TRACE_DIR"          # span JSONL sink dir (<staging>/trace)
 ENV_TRACE_PARENT = "TONY_TRACE_PARENT"    # parent span id for this process's root span
+# Start-up account (obs/startup.py, always on): the executor stamps the child's
+# Popen on the .jhist's clock (epoch ms) so the child's own stamps start there
+ENV_CHILD_SPAWNED_MS = "TONY_CHILD_SPAWNED_MS"
 ENV_METRICS_ENABLED = "TONY_METRICS_ENABLED"  # "0" → child metrics recording off (tony.metrics.enabled)
 # SLO contract (tony.slo.*): serve children align a TTFT histogram bucket
 # edge to this threshold so good/bad request counts are exact, not

@@ -74,6 +74,7 @@ from tony_tpu.models import registry
 from tony_tpu.models.serving import ContinuousBatcher
 from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
+from tony_tpu.obs import startup as obs_startup
 from tony_tpu.obs import trace as obs_trace
 from tony_tpu.ops.interpret import interpret
 from tony_tpu.runtime import device_facts, enable_compile_cache
@@ -1299,6 +1300,7 @@ def build_engine(args) -> ContinuousBatcher:
 
 
 def main(argv: list[str] | None = None) -> int:
+    obs_startup.begin("serve")  # main_entered: the start-up account's first stamp of this process
     # under a tony container the executor exports the structured-logging
     # contract; outside it the helpers echo to the console only
     obs_logging.init_from_env(role="serve")
@@ -1361,12 +1363,18 @@ def main(argv: list[str] | None = None) -> int:
     # tracing contract — the training child's init_from_env, reused)
     obs_trace.init_from_env()
     cache_dir = enable_compile_cache()
+    jax.devices()
+    obs_startup.stamp("devices_ready")  # JAX imported, PJRT client up
     done = threading.Event()
+    engine = build_engine(args)
+    # the stage ends when weights, caches and page pool are on the device, not when they were asked for
+    jax.block_until_ready((engine.params, engine.cache))
     srv = EngineServer(
-        build_engine(args), on_fatal=done.set,
+        engine, on_fatal=done.set,
         max_queue=args.admission_queue, request_timeout_s=args.request_timeout_s,
         role=args.role,
     ).start()
+    obs_startup.stamp("weights_ready")  # and the engine's threads up
     tokenizer = None
     if args.tokenizer:
         from transformers import AutoTokenizer
@@ -1391,6 +1399,7 @@ def main(argv: list[str] | None = None) -> int:
             f.write(url)
         os.replace(tmp, args.url_file)
     _register_with_am(url)
+    obs_startup.stamp("registered")
     stop_metrics = threading.Event()
     threading.Thread(
         target=_metrics_pump, args=(srv, stop_metrics), daemon=True

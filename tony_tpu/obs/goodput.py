@@ -10,11 +10,28 @@ into an **exact partition** of ``[t0, t1]`` into phases:
 
 ==================  =========================================================
 ``queue_wait``      queued behind other tenants (QUEUE_WAIT episodes)
+``submit``          the client's hop: ``submitted`` (Client.submit, before
+                    staging; carried in APPLICATION_INITED's payload) → the
+                    AM's APPLICATION_INITED. ``t0`` is ``submitted`` when the
+                    payload has it
 ``startup``         container allocation + executor launch, per gang epoch
 ``registration``    the gang registration barrier (first TASK_REGISTERED →
                     GANG_COMPLETE)
-``compile``         first-step XLA compile (train.first_step spans when
-                    traced, else estimated to the first step evidence)
+``runtime_init``    GANG_COMPLETE → the child's ``devices_ready``: the
+                    executor's barrier poll and spawn, the interpreter, the
+                    imports, the PJRT client and the TPU's initialisation
+``weights``         ``devices_ready`` → ``weights_ready``: weights drawn or
+                    restored (a server's caches and page pool too), blocked on
+``compile``         a training task's ``weights_ready`` → ``first_step_done``:
+                    tracing, lowering, the backend's compile or the cache's
+                    read, and the first step's execution. Only a ``.jhist``
+                    whose gang epoch carries no stamps falls back to the
+                    estimate [GANG_COMPLETE, train.first_step span's end when
+                    traced, else the first METRICS_SNAPSHOT with a step >= 1]
+``warmup``          a serving task's ``weights_ready`` → ``ready`` (the AM's
+                    TASK_URL_REGISTERED): the HTTP server bound and the URL
+                    registered. ``productive`` starts at ``ready`` for a
+                    serving gang, not at GANG_COMPLETE
 ``productive``      steps actually advancing the job — THE goodput
 ``checkpoint``      checkpoint save work on the step path (ckpt.save spans)
 ``input_wait``      step loop blocked on the input pipeline
@@ -38,6 +55,13 @@ live gang window, ``other`` outside), and the phase totals therefore sum to
 ``t1 - t0`` to the millisecond — property-tested over randomized histories in
 tests/test_goodput.py.
 
+The stages ``runtime_init`` / ``weights`` / ``compile`` / ``warmup`` are
+claimed from stamps the chip-holding child takes itself (obs/startup.py), which
+reach the ``.jhist`` as TASK_STARTUP_STAMPS events whether or not tracing is
+on, a gang epoch and task at a time. A stage whose closing stamp was never
+taken runs to the end of its epoch (the child is still in it, or died in it); a
+stage whose own edge is missing while a later one is there is nobody's.
+
 Also here: :class:`StragglerDetector` — per-task step-time skew from the
 piggybacked ``tony_train_step_seconds`` histograms, flagging ranks whose step
 time persistently exceeds the gang median — used by the AM's goodput tick
@@ -54,7 +78,8 @@ from typing import Any, Iterable, Mapping
 #: phase names in display order; ``productive`` is the goodput, the rest is
 #: the badput breakdown
 PHASE_ORDER = (
-    "productive", "queue_wait", "startup", "registration", "compile",
+    "productive", "queue_wait", "submit", "startup", "registration",
+    "runtime_init", "weights", "compile", "warmup",
     "checkpoint", "input_wait", "restart_rework", "preempt_drain", "resize",
     "takeover", "drain", "other",
 )
@@ -77,6 +102,15 @@ _PRIORITY = {
     # with tony.pool.preemption.drain-ms, not "other"
     "preempt_drain": 65,
     "queue_wait": 60,
+    # the start-up stages, stamped by the processes that do the work: narrow
+    # and precise, so above the wide startup / registration / productive
+    # claims they lie in. `submit` lies before the AM's first event, where
+    # only startup's claim from t0 reaches. Among a gang's tasks the earlier
+    # stage wins: the gang is as far as its slowest member
+    "submit": 58,
+    "runtime_init": 56,
+    "weights": 54,
+    "warmup": 52,
     "compile": 50,
     "registration": 45,
     "resize": 40,
@@ -100,6 +134,10 @@ class Ledger:
     resizes: int = 0
     takeovers: int = 0
     step_time_by_task_ms: dict[str, float] = field(default_factory=dict)
+    #: the stamps the start-up stages were claimed from: ``client`` →
+    #: ``{submitted}``, ``<task>@<epoch's GANG_COMPLETE ms>`` → the child's
+    #: stamps (+ ``ready``). Empty for a ``.jhist`` that carries none
+    stamps: dict[str, dict[str, int]] = field(default_factory=dict)
 
     @property
     def wall_ms(self) -> int:
@@ -168,6 +206,7 @@ class Ledger:
             "takeovers": self.takeovers,
             "step_time_by_task_ms": dict(self.step_time_by_task_ms),
             "skew_by_task": self.skew_by_task(),
+            "stamps": {k: dict(v) for k, v in self.stamps.items()},
         }
 
 
@@ -198,6 +237,14 @@ def _snapshot_steps(ev: Any) -> dict[str, int]:
         if isinstance(step, (int, float)) and math.isfinite(step):
             out[str(entry.get("task", "?"))] = int(step)
     return out
+
+
+def _stamp_ms(v: Any) -> int | None:
+    """A stamp as whole epoch milliseconds, or None for anything else (a torn
+    or hand-edited payload must not break the partition)."""
+    if isinstance(v, bool) or not isinstance(v, (int, float)) or not math.isfinite(v):
+        return None
+    return int(v)
 
 
 def _span_ms(s: Mapping[str, Any]) -> tuple[int, int]:
@@ -253,7 +300,10 @@ def build_ledger(
     now_ms: int | None = None,
 ) -> Ledger:
     """The exact phase partition for one job from its event stream (+ spans
-    when the job was traced).
+    when the job was traced). The start-up stages come from the stamps the
+    stream carries (module docstring); the untraced ``compile`` estimate (the
+    first METRICS_SNAPSHOT with a step >= 1) is only the fallback for a gang
+    epoch without stamps.
 
     ``events`` is the (possibly torn-truncated) ``.jhist`` stream in file
     order; ``spans`` the merged span dicts (obs/artifacts.load_spans). A job
@@ -266,6 +316,12 @@ def build_ledger(
         return Ledger(app_id, now, now, live=True, phases_ms={}, episodes=[])
 
     t0 = min(ev.timestamp_ms for ev in events)
+    stamps: dict[str, dict[str, int]] = {}
+    inited = next((ev for ev in events if _ev_type(ev) == "APPLICATION_INITED"), None)
+    submitted = _stamp_ms((inited.payload if inited else {}).get("submitted_ms"))
+    if submitted is not None:
+        stamps["client"] = {"submitted": submitted}
+        t0 = min(t0, submitted)
     finished = [ev for ev in events if _ev_type(ev) == "APPLICATION_FINISHED"]
     if finished:
         t1, live = finished[-1].timestamp_ms, False
@@ -281,6 +337,10 @@ def build_ledger(
         start, end = max(int(start), t0), min(int(end), t1)
         if end > start:
             claims.append((start, end, _PRIORITY[phase], phase))
+
+    # ---- submit: the client's hop, from its own stamp to the AM's first event
+    if submitted is not None:
+        claim("submit", submitted, inited.timestamp_ms)
 
     # ---- queue wait: waiting → admitted pairs (unterminated waits run to t1)
     wait_start: int | None = None
@@ -339,10 +399,51 @@ def build_ledger(
     for rm in resize_marks:
         claim("resize", rm, next_at_or_after(completes, rm + 1, t1))
 
-    # ---- compile: traced first-step spans, else first step evidence
+    # ---- the child's start-up stages, from its own stamps: the latest report
+    # of each task in each gang epoch (an event belongs to the epoch of the
+    # last GANG_COMPLETE before it: the AM fences pushes of older epochs)
+    reports: dict[tuple[int, str], Any] = {}
+    for ev in events:
+        if _ev_type(ev) != "TASK_STARTUP_STAMPS":
+            continue
+        gcs = [gc for gc in completes if gc <= ev.timestamp_ms]
+        if gcs:
+            reports[(max(gcs), str(ev.payload.get("task")))] = ev
+    urls = [(ev.timestamp_ms, str(ev.payload.get("task"))) for ev in events
+            if _ev_type(ev) == "TASK_URL_REGISTERED"]
+    for (gc, task), ev in reports.items():
+        epoch_end = next_at_or_after(restarts, gc + 1, t1)
+        raw = ev.payload.get("stamps")
+        st = {k: ms for k, v in (raw if isinstance(raw, dict) else {}).items()
+              if (ms := _stamp_ms(v)) is not None}
+        kind = ev.payload.get("kind")
+        if kind == "serve":
+            ready = min((ts for ts, t in urls if t == task and gc <= ts < epoch_end), default=None)
+            if ready is not None:
+                st["ready"] = ready
+        stamps[f"{task}@{gc}"] = st
+        last = {"train": ("compile", "first_step_done"), "serve": ("warmup", "ready")}.get(kind)
+        edges = [gc, st.get("devices_ready"), st.get("weights_ready"), st.get(last[1]) if last else None]
+        for i, phase in enumerate(("runtime_init", "weights", last[0] if last else None)):
+            lo, hi = edges[i], edges[i + 1]
+            if phase is None or lo is None:
+                continue
+            if hi is None:
+                if any(e is not None for e in edges[i + 2:]):
+                    continue  # an edge never stamped though the child got past it: nobody's stage
+                hi = epoch_end  # the child is still in this stage, or died in it
+            claim(phase, lo, hi)
+
+    # ---- compile, for a gang epoch whose .jhist carries no stamps (written
+    # by an older build, or by a child that takes none): traced first-step
+    # spans, else the first step evidence, a resolution of the AM's snapshot
+    # period. An epoch with stamps never takes this estimate
     first_steps = [s for s in spans if s.get("name") == "train.first_step"]
     snapshots = [ev for ev in events if _ev_type(ev) == "METRICS_SNAPSHOT"]
+    stamped_epochs = {gc for gc, _task in reports}
     for gc in completes:
+        if gc in stamped_epochs:
+            continue
         epoch_end = next_at_or_after(restarts, gc + 1, t1)
         ends = [
             _span_ms(s)[1] for s in first_steps
@@ -440,6 +541,7 @@ def build_ledger(
         resizes=len(resize_marks),
         takeovers=len(takeover_events),
         step_time_by_task_ms=step_time_by_task(events),
+        stamps=stamps,
     )
 
 

@@ -22,6 +22,7 @@ import jax
 from tony_tpu import constants
 from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
+from tony_tpu.obs import startup as obs_startup
 from tony_tpu.obs import trace as obs_trace
 from tony_tpu.ops.attention import REMAT_LADDER, named_bytes
 from tony_tpu.parallel import MeshSpec
@@ -238,6 +239,7 @@ def run_lm_training(model_module, model_cfg, loop: LoopConfig) -> dict:
     run is one span with first-step (compile) and checkpoint child spans;
     outside a container the tracer is None and nothing is recorded.
     """
+    obs_startup.begin("train")  # main_entered: the start-up account's first stamp of this process
     if os.environ.get(constants.ENV_METRICS_ENABLED) == "0":
         obs_metrics.set_enabled(False)  # the job opted out (tony.metrics.enabled)
     # structured logging (tony.log.*): this child's records join the job-wide
@@ -289,6 +291,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
     num_slices = int(os.environ.get(constants.ENV_TPU_NUM_SLICES, "1") or "1")
     mesh = spec.build(num_slices=num_slices)
     n_chips = len(jax.devices())
+    obs_startup.stamp("devices_ready")  # JAX imported, PJRT client up, the mesh built
 
     opt_cfg = OptimizerConfig(
         learning_rate=loop.learning_rate, warmup_steps=loop.warmup_steps,
@@ -303,6 +306,8 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
         )
 
     state, ckpt_mgr, start_step = restore_or_init(loop.checkpoint_dir or None, init_state)
+    jax.block_until_ready(state)  # the stage ends when the weights are on the device, not when they were asked for
+    obs_startup.stamp("weights_ready")
     if start_step:
         obs_logging.info(f"[train] resumed from checkpoint step {start_step}", step=start_step)
     # where the parameters really live: a layout that leaves a chip empty (or
@@ -469,6 +474,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
                 # the critical-path item `tony trace` reports per worker
                 jax.block_until_ready(metrics["loss"])
                 first_s = time.perf_counter() - t_first
+                obs_startup.stamp("first_step_done")
                 _FIRST_STEP_SECONDS.set(first_s)
                 obs_logging.info(f"[train] first step (compile included) {first_s:.2f}s")
                 if tracer is not None:
