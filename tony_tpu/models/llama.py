@@ -306,12 +306,11 @@ def hidden_states(
     if mesh is not None:
         x = constrain(x, mesh, P(BATCH_AXES, "context", None))
 
-    block_fn = attn_ops.remat_block(
+    x, _ = attn_ops.scan_blocks(
         partial(_block, cos=cos, sin=sin, cfg=cfg, mesh=mesh,
                 segment_ids=segment_ids, positions=positions),
-        cfg.remat, cfg.remat_policy,
+        x, params["layers"], cfg.remat, cfg.remat_policy,
     )
-    x, _ = jax.lax.scan(block_fn, x, params["layers"])
 
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps)
 

@@ -178,8 +178,7 @@ def hidden_states(
         return (x, aux_acc), None
 
     aux0 = {k: jnp.zeros((), jnp.float32) for k in ("moe_balance_loss", "moe_z_loss", "moe_dropped_frac")}
-    block_fn = attn_ops.remat_block(block, cfg.remat, cfg.remat_policy)
-    (x, aux), _ = jax.lax.scan(block_fn, (x, aux0), params["layers"])
+    (x, aux), _ = attn_ops.scan_blocks(block, (x, aux0), params["layers"], cfg.remat, cfg.remat_policy)
 
     return L.rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 

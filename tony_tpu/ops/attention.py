@@ -17,9 +17,11 @@ Only the XLA reference path broadcasts (``repeat_kv``).
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend.core import Literal, jaxpr_as_fun
 
 from tony_tpu.ops.interpret import interpret
 
@@ -708,16 +710,37 @@ _flash_trainable_seg.defvjp(_flash_seg_fwd, _flash_seg_bwd)
 #: and the two [B, T, F] products of the FFN (ops/layers.py:swiglu). A name
 #: the traced block does not hold saves nothing. train/trainer.py chooses the
 #: rung where the policy is "auto" and the loop runs on a device that reports
-#: its memory.
+#: its memory, and which of HOST_NAMES (below: what was measured) wait in the
+#: host's memory instead.
 _LADDER_STEPS = (
     ("flash_o", "flash_lse", "moe_route", "moe_gemm"),
     ("attn_res",), ("attn_qkv",), ("ffn_gate",), ("ffn_up",),
 )
 REMAT_LADDER = tuple(
     sum(_LADDER_STEPS[:i], ()) for i in range(len(_LADDER_STEPS) + 1))
+#: The names that may wait in the host's pinned memory between a layer's
+#: forward and its backward instead of being saved or replayed
+#: (``scan_blocks``; train/trainer.remat_candidates). A property of the name,
+#: not a setting: where in a layer's forward the value is formed, against
+#: what is left of that forward to send it behind. q, k and v after rope are
+#: formed in a layer's first eighth and leave under the rest of it; the FFN's
+#: two products are formed when a fifth of the layer is left, and their 29 ms
+#: of copy each (0.47 GB at 2 x 8192 x 14336) stand exposed for more than
+#: their replay costs, whichever turn of the loop sends them (PERF.md
+#: section 6, PR 56); "attn_res" buys 4 ms a GB and flash's outputs are small.
+HOST_NAMES = ("attn_qkv",)
 #: the policies that are rungs by another name ("auto" outside the train loop
 #: IS "full": only the loop knows a device's memory)
 _NAMED_RUNGS = {"full": REMAT_LADDER[0], "auto": REMAT_LADDER[0], "flash": REMAT_LADDER[1]}
+
+
+class Rung(NamedTuple):
+    """A rung with a host part: what the train loop hands a model as
+    ``remat_policy`` once it has chosen (train/loop.py). ``saved`` are the
+    names a plain tuple would hold; ``host`` names wait in the host's pinned
+    memory between a layer's forward and its backward (``scan_blocks``)."""
+    saved: tuple[str, ...]
+    host: tuple[str, ...] = ()
 
 
 def remat_block(block_fn, remat: bool, policy: str | tuple[str, ...] = "full"):
@@ -746,6 +769,148 @@ def remat_block(block_fn, remat: bool, policy: str | tuple[str, ...] = "full"):
         return jax.checkpoint(block_fn)
     return jax.checkpoint(
         block_fn, policy=jax.checkpoint_policies.save_only_these_names(*policy))
+
+
+def scan_blocks(block_fn, carry, layers, remat: bool, policy: str | tuple[str, ...] | Rung = "full"):
+    """The stacked ``layers`` through ``remat_block(block_fn, remat, policy)``:
+    ``jax.lax.scan``'s ``(carry, ys)``. A ``Rung`` with a host part takes
+    ``_scan_host_kept``; every other policy is the one ``lax.scan`` it was."""
+    if isinstance(policy, Rung):
+        if remat and policy.host:
+            return _scan_host_kept(block_fn, carry, layers, policy)
+        policy = policy.saved
+    return jax.lax.scan(remat_block(block_fn, remat, policy), carry, layers)
+
+
+def _names_made_of(jaxpr, given: list[set]) -> list[set]:
+    """The checkpoint names each output of ``jaxpr`` is made of (None stands
+    for an argument or a constant): a value the policy saved is the output of
+    the ``name`` equation that named it, and can reach the pullback through a
+    ``reduce_precision`` or a nested jit."""
+    src = dict(zip(jaxpr.invars, given))
+    src.update((v, {None}) for v in jaxpr.constvars)
+
+    def of(v):
+        return set() if isinstance(v, Literal) else src[v]
+
+    for eqn in jaxpr.eqns:
+        ins = [of(v) for v in eqn.invars]
+        inner = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name == "name":
+            outs = [{eqn.params["name"]}]
+        elif len(inner) == 1 and len(inner[0].invars) == len(ins) and not inner[0].constvars:
+            outs = _names_made_of(inner[0], ins)
+        else:
+            outs = [set().union(*ins)] * len(eqn.outvars)
+        src.update(zip(eqn.outvars, outs))
+    return [of(v) for v in jaxpr.outvars]
+
+
+def _scan_host_kept(block_fn, carry, layers, rung: Rung):
+    """``lax.scan`` of the block under ``save_only_these_names(saved + host)``
+    whose ``host`` values wait in the host's pinned memory, with the loop's
+    backward written out so that a layer's values come back WHILE THE LAYER
+    ABOVE IT runs its backward: the body of the backward's loop starts the
+    copy of layer i-1's values and hands them to the next turn in the carry.
+    ``save_and_offload_only_these_names`` inside a plain scan asks for a
+    layer's values at the top of that layer's own backward, and the compiler
+    waits for them there: 28.6 ms a layer for one FFN product at 2 x 8192 x
+    14336, against 7.8 ms to replay it (my chip runs, PR 56). The device
+    holds two layers' host values at a time (here and on its way), whatever
+    the depth. Values, types and the backward's order of operations are the
+    saved rung's: a value read back is the value that was written."""
+    block = jax.checkpoint(
+        block_fn, policy=jax.checkpoint_policies.save_only_these_names(*rung.saved, *rung.host))
+    n = jax.tree.leaves(layers)[0].shape[0]
+
+    # the block and its pullback, traced once: the outputs, then the
+    # pullback's leaves (what the backward wants of the forward)
+    trees = []
+
+    def outputs_and_kept(c, layer):
+        out, pullback = jax.vjp(block, c, layer)
+        flat_out, out_tree = jax.tree.flatten(out)
+        kept, kept_tree = jax.tree.flatten(pullback)
+        trees[:] = [out_tree, kept_tree, len(flat_out)]
+        return flat_out + kept
+
+    traced = jax.make_jaxpr(outputs_and_kept)(
+        carry, jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape[1:], a.dtype), layers))
+    out_tree, kept_tree, n_out = trees
+    block_and_kept = jaxpr_as_fun(traced)
+    kept_vars = traced.jaxpr.outvars[n_out:]
+    made_of = _names_made_of(traced.jaxpr, [{None}] * len(traced.jaxpr.invars))[n_out:]
+    # a leaf that is an argument or a constant of the trace is not kept: the
+    # backward has the argument; one made of host names alone waits on the
+    # host; any other is a value the policy saved, and stays on the device
+    given = {v: ("argument", k) for k, v in enumerate(traced.jaxpr.invars)}
+    given.update((v, ("constant", k)) for k, v in enumerate(traced.jaxpr.constvars))
+    on_host = [j for j, (v, names) in enumerate(zip(kept_vars, made_of))
+               if not isinstance(v, Literal) and v not in given and names and names <= set(rung.host)]
+    on_device = [j for j, v in enumerate(kept_vars)
+                 if not isinstance(v, Literal) and v not in given and j not in on_host]
+    host_avals = [kept_vars[j].aval for j in on_host]
+    to_host = jax.memory.Space.Host
+    to_device = jax.memory.Space.Device
+
+    # one array a width and type: two copies in flight at once wait for one
+    # another (my chip runs, PR 56), so the values travel as one
+    groups: dict[tuple, list[int]] = {}
+    for k, a in enumerate(host_avals):
+        groups.setdefault((a.shape[-1], a.dtype), []).append(k)
+
+    def pack(values):
+        return [jnp.concatenate([values[k].reshape(-1, width) for k in ks])
+                for (width, _), ks in groups.items()]
+
+    def unpack(packed):
+        out = {}
+        for ks, rows in zip(groups.values(), packed):
+            at = 0
+            for k in ks:
+                n_rows = host_avals[k].size // host_avals[k].shape[-1]
+                out[k] = rows[at:at + n_rows].reshape(host_avals[k].shape)
+                at += n_rows
+        return [out[k] for k in range(len(host_avals))]
+
+    @jax.custom_vjp
+    def run(carry, layers):
+        return jax.lax.scan(block, carry, layers)
+
+    def forward(carry, layers):
+        def body(c, layer):
+            flat = block_and_kept(*jax.tree.leaves((c, layer)))
+            (c_out, y), kept = jax.tree.unflatten(out_tree, flat[:n_out]), flat[n_out:]
+            return c_out, (y, c, [kept[j] for j in on_device],
+                           [jax.device_put(v, to_host) for v in pack([kept[j] for j in on_host])])
+        carry_out, (ys, carries, device, host) = jax.lax.scan(body, carry, layers)
+        return (carry_out, ys), (carries, device, host, layers)
+
+    def backward(kept, ct):
+        carries, device, host, layers = kept
+
+        def fetch(i):
+            return [jax.device_put(jax.lax.dynamic_index_in_dim(h, i, keepdims=False), to_device)
+                    for h in host]
+
+        def body(state, xs):
+            ct_c, here = state
+            i, c, layer, device_i, ct_y = xs
+            ahead = fetch(jnp.maximum(i - 1, 0))
+            arguments = {"argument": jax.tree.leaves((c, layer)), "constant": traced.consts}
+            leaves = [v.val if isinstance(v, Literal) else arguments[given[v][0]][given[v][1]] if v in given
+                      else None for v in kept_vars]
+            for j, v in zip(on_device + on_host, device_i + unpack(here)):
+                leaves[j] = v
+            d_c, d_layer = jax.tree.unflatten(kept_tree, leaves)((ct_c, ct_y))
+            return (d_c, ahead), d_layer
+
+        (d_carry, _), d_layers = jax.lax.scan(
+            body, (ct[0], fetch(n - 1)), (jnp.arange(n), carries, layers, device, ct[1]), reverse=True)
+        return d_carry, d_layers
+
+    run.defvjp(forward, backward)
+    return run(carry, layers)
 
 
 def named_bytes(fn, *args, has_aux: bool = False) -> dict[str, int]:

@@ -24,7 +24,7 @@ from tony_tpu.obs import logging as obs_logging
 from tony_tpu.obs import metrics as obs_metrics
 from tony_tpu.obs import startup as obs_startup
 from tony_tpu.obs import trace as obs_trace
-from tony_tpu.ops.attention import REMAT_LADDER, named_bytes
+from tony_tpu.ops.attention import HOST_NAMES, REMAT_LADDER, Rung, named_bytes
 from tony_tpu.parallel import MeshSpec
 from tony_tpu.runtime import device_facts, enable_compile_cache, init_distributed
 from tony_tpu.train.checkpoint import UrgentSaveSignal, restore_or_init
@@ -38,6 +38,7 @@ from tony_tpu.train.trainer import (
     choose_remat_rung,
     make_pp_train_step,
     make_train_step,
+    remat_candidates,
     sharded_init,
 )
 
@@ -48,6 +49,10 @@ _REMAT_SAVED_BYTES = obs_metrics.gauge(
     "tony_train_remat_saved_bytes",
     "what the decoder blocks keep of the forward for the backward, bytes a "
     "device and step (0: every layer's forward runs again in its backward)")
+_REMAT_OFFLOADED_BYTES = obs_metrics.gauge(
+    "tony_train_remat_offloaded_bytes",
+    "what of that waits in the host's pinned memory and not on the device, "
+    "bytes a device and step (0: nothing, or a policy other than auto)")
 _STEP_SECONDS = obs_metrics.histogram(
     "tony_train_step_seconds",
     "mean per-step wall time, sampled once per logging window")
@@ -141,28 +146,41 @@ def _step_memory(executable) -> int | None:
         + m.temp_size_in_bytes - m.alias_size_in_bytes)
 
 
+def _peak_flops() -> float | None:
+    """The chip's published bf16 peak; None on the CPU, which has none."""
+    return None if jax.default_backend() == "cpu" else detect_peak_flops()
+
+
 def _auto_remat_step(model_module, model_cfg, mesh, opt):
     """The train step of ``remat_policy="auto"``: the blocks save for their
     backward the highest rung of ops/attention.REMAT_LADDER that the device's
     memory holds (train/trainer.choose_remat_rung), chosen at the first call,
     when the state and a batch are there to lower the step with. What runs
-    from then on is the executable the choice compiled, not a second trace."""
+    from then on is the executable the choice compiled, not a second trace.
 
-    def step_at(policy):
-        cfg = dataclasses.replace(model_cfg, remat_policy=policy)
+    Where the backend has a ``pinned_host`` memory the ladder has host parts
+    (train/trainer.remat_candidates): ops/attention.HOST_NAMES may wait in
+    the host's memory for a layer's bytes on the device."""
+
+    def step_at(saved, host=()):
+        cfg = dataclasses.replace(model_cfg, remat_policy=Rung(saved, host) if host else saved)
         return make_train_step(functools.partial(model_module.loss_fn, cfg=cfg, mesh=mesh), opt)
 
-    def say(names, saved, free, rung, n, why):
+    def say(names, host, saved, on_host, free, rung, n, why):
         _REMAT_SAVED_BYTES.set(saved)
+        _REMAT_OFFLOADED_BYTES.set(on_host)
+        waits = f"; {', '.join(host)} wait{'s' if len(host) == 1 else ''} on the host ({on_host / 1e9:.2f} GB)" if host else ""
         obs_logging.info(
-            f"[train] remat: saves {', '.join(names) or 'nothing'} ({saved / 1e9:.2f} GB a device, "
+            f"[train] remat: saves {', '.join(names) or 'nothing'}{waits} ({saved / 1e9:.2f} GB a device, "
             f"{free / 1e9:.2f} GB free before, rung {rung} of {n}; {why})")
 
     limits = [(d.memory_stats() or {}).get("bytes_limit") for d in jax.local_devices()]
     if not all(limits):
-        say((), 0, 0, 0, len(REMAT_LADDER) - 1, "the device reports no bytes_limit")
+        say((), (), 0, 0, 0, 0, len(REMAT_LADDER) - 1, "the device reports no bytes_limit")
         return step_at(REMAT_LADDER[0])
     limit = min(limits)
+    host_names = HOST_NAMES if all(
+        any(m.kind == "pinned_host" for m in d.addressable_memories()) for d in jax.local_devices()) else ()
 
     def choose(state, batch):
         # one device's share of the batch, through the model with no mesh:
@@ -176,11 +194,6 @@ def _auto_remat_step(model_module, model_cfg, mesh, opt):
             functools.partial(model_module.loss_fn, mesh=None,
                               cfg=dataclasses.replace(model_cfg, remat_policy=REMAT_LADDER[-1])),
             jax.tree.map(lambda p: shape(p.shape, p.dtype), state.params), share, has_aux=True)
-        reckoned = [sum(named.get(name, 0) for name in rung) for rung in REMAT_LADDER]
-        # the rungs that save something the one below does not (a family's
-        # block holds the names it holds)
-        rungs = [i for i, b in enumerate(reckoned) if i == 0 or b > reckoned[i - 1]]
-        saved = [reckoned[i] for i in rungs]
 
         def on_fullest_device(tree) -> int:
             held: dict[int, int] = {}
@@ -192,31 +205,48 @@ def _auto_remat_step(model_module, model_cfg, mesh, opt):
         # rung 0 before any compile: the state, gradients the size of the
         # parameters, the batch, and one block's backward, which holds the
         # block's activations and their cotangents, some of them in float32:
-        # about three times a layer's named bytes (12.77 GB compiled at 4
-        # layers of 7B widths, of which state and gradients 9.08 and a layer's
-        # names 1.41). Where the parameters are sharded a layer's are gathered
-        # whole for its forward and for its backward, the next layer's behind
-        # each, and its gradient is whole before it is scattered: about six
-        # layers' parameters (13.44 GB compiled at 16 layers over four chips,
-        # 5.7 layers' more than this reckons without them; both compile-only,
-        # PR 47). It leans high: a report with room sends the chooser up at
-        # the price of a cache load, a compile that runs out of memory is
-        # kept by no cache and is paid at every start.
+        # two and a half times a layer's named bytes (12.49 GB by the report
+        # of rung 3 at 4 layers of 7B widths, of which state and gradients
+        # 9.08 and a layer's names 1.41: 2.4 layers'; my chip runs, PR 56).
+        # Where the parameters are sharded a layer's are gathered whole for its
+        # forward and for its backward, the next layer's behind each, and its
+        # gradient is whole before it is scattered: about six layers'
+        # parameters (13.44 GB compiled at 16 layers over four chips, 5.7
+        # layers' more than this reckons without them: compile-only, PR 47).
+        # It leans high, by little: a report with room sends the chooser up
+        # and one over the budget down, each at the price of a cache load;
+        # a compile that runs out of memory is kept by no cache and is paid
+        # at every start.
         layers = max(getattr(model_cfg, "n_layers", 1), 1)
         params_held = on_fullest_device(state.params)
         params_whole = sum(leaf.nbytes for leaf in jax.tree.leaves(state.params))
         held = (on_fullest_device(state) + params_held + on_fullest_device(batch)
-                + 3 * saved[-1] // layers
+                + 5 * sum(named.get(n, 0) for n in REMAT_LADDER[-1]) // (2 * layers)
                 + (6 * params_whole // layers if params_whole > params_held else 0))
+        # the step's own reckoned time: its flops a device over the peak. No
+        # host part where the parameters are sharded: at 16 layers over four
+        # chips q, k and v on the host compile to 0.61 GB more than rung 1
+        # (15.54 for 14.93 GB, compile-only, where one chip's 4 layers take
+        # 0.20), more than is free there, and a rung that cannot fit costs
+        # every cold start its compile (PR 56)
+        tokens = share["tokens"].shape[0] * (share["tokens"].shape[1] - 1)
+        peak = _peak_flops()
+        step_seconds = peak and flops_per_token_for_batch(
+            model_cfg, batch, batch["tokens"].shape[1] - 1) * tokens / peak
+        rungs = remat_candidates(
+            REMAT_LADDER, named, layers, host_names if params_whole == params_held else (), step_seconds)
+        saved = [held_at for _, _, held_at in rungs]
 
         def compile_rung(i):
-            executable = step_at(REMAT_LADDER[rungs[i]]).lower(state, batch).compile()
+            executable = step_at(*rungs[i][:2]).lower(state, batch).compile()
             return executable, _step_memory(executable)
 
         i, executable, why = choose_remat_rung(saved, limit, held, compile_rung)
         used = _step_memory(executable)
         free = int(limit * (1 - REMAT_MARGIN)) - (used - saved[i] if used is not None else held)
-        say(REMAT_LADDER[rungs[i]], saved[i], free, i, len(rungs) - 1, why)
+        names, host, _ = rungs[i]
+        say(names, host, sum(named.get(n, 0) for n in names), sum(named.get(n, 0) for n in host),
+            free, i, len(rungs) - 1, why)
         return executable
 
     chosen = []
@@ -345,7 +375,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
         tokens_per_step=loop.batch_size * loop.seq_len,
         flops_per_token=flops_per_token_for_batch(model_cfg, probe, loop.seq_len),
         n_chips=n_chips,
-        peak_flops=None if jax.default_backend() == "cpu" else detect_peak_flops(),
+        peak_flops=_peak_flops(),
     )
 
     key = jax.random.PRNGKey(start_step + 1)

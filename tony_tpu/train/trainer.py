@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Sequence
+from typing import Any, Callable, Mapping, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -136,6 +136,56 @@ def make_pp_train_step(
 #: copies. A constant reckoned on the chip (PERF.md section 6, PR 47), not a
 #: setting.
 REMAT_MARGIN = 0.06
+
+
+#: What the link between a v5e chip and its host's pinned memory carries each
+#: way while both ways run (10.3-10.9 GB/s in one program, four chips at once
+#: 94-99% of one alone; 13.9 in and about 14.6 out one way at a time: PERF.md
+#: section 6, PR 56), and the share of a step's reckoned time that the
+#: host-kept bytes may take each way: a layer's forward is a third of its
+#: step, and a value must leave behind the rest of the forward of the layer
+#: that formed it. Constants reckoned on the chip, not settings.
+HOST_LINK_BYTES_PER_S = 10e9
+HOST_LINK_SHARE = 0.25
+
+
+def remat_candidates(
+    ladder: Sequence[tuple[str, ...]], named: Mapping[str, int], layers: int,
+    host_names: Sequence[str], step_seconds: float | None,
+) -> list[tuple[tuple[str, ...], tuple[str, ...], int]]:
+    """The remat ladder with its host parts: ``(saved, host, bytes)`` rising in
+    the bytes a device and step holds for them, ``choose_remat_rung``'s
+    ``saved`` and what ``ops/attention.Rung`` takes.
+
+    ``ladder`` are the cumulative rungs of names, ``named[name]`` a name's
+    bytes a device and step over all ``layers``. Every rung stands as it is,
+    and again with the ``host_names`` kept on the host instead of saved or
+    replayed, whether or not the rung holds them (the host part is not bound
+    to the ladder's order): a host-kept name costs the device one layer's
+    bytes, the copy on its way back. No host part where ``step_seconds`` (the
+    step's flops a device over the peak) is None, or where the names' bytes
+    over ``HOST_LINK_BYTES_PER_S`` take more than ``HOST_LINK_SHARE`` of it.
+    A rung that saves nothing the one below does not is left out (a family's
+    block holds the names it holds). A rung with the names on the device stands
+    above the one that keeps the same on the host: it costs the link nothing."""
+    host = tuple(n for n in host_names if named.get(n))
+    moved = sum(named[n] for n in host)
+    if step_seconds is None or moved / HOST_LINK_BYTES_PER_S > HOST_LINK_SHARE * step_seconds:
+        host = ()
+    found: dict[tuple, int] = {}
+    for rung in ladder:
+        found.setdefault((rung, ()), sum(named.get(n, 0) for n in rung))
+        if host:
+            saved = tuple(n for n in rung if n not in host)
+            found.setdefault((saved, host), sum(named.get(n, 0) for n in saved) + moved // max(layers, 1))
+    out: list[tuple[tuple[str, ...], tuple[str, ...], int]] = []
+    kept: list[tuple[set[str], tuple[str, ...]]] = []
+    for (saved, on_host), held in sorted(found.items(), key=lambda kv: (kv[1], len(kv[0][1]))):
+        names = {n for n in saved + on_host if named.get(n)}
+        if not any(names <= k and on_host == h for k, h in kept):
+            out.append((saved, on_host, held))
+            kept.append((names, on_host))
+    return out
 
 
 def choose_remat_rung(
