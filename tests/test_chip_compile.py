@@ -599,3 +599,122 @@ class TestStateSpaceAtTheAssistCellsShapes:
         c = self.H * self.P + 2 * self.N
         self._named(delta_rule.short_conv_chunk, "short_conv", _s((2048, c), bf, chip), _s((3, c), bf, chip), _s((4, c), bf, chip),
                     _s((), jnp.int32, chip), _s((c,), bf, chip))
+
+
+class TestChannelGatedDeltaRuleAtTheExtractCellsShapes:
+    """`solar-open2-250b.serve_extract` (one chip's share of eight): the Pallas calls of
+    a `kda` layer at the published widths (64 heads of 128 keys and 128 values, 24,576
+    convolution channels), found in a trace by these names: a prefill chunk's blocked
+    rule (a whole chunk of 2048 rows and the smallest bucket's 256) from a request's
+    float32 state `[64, 128, 128]`, a decode step of 128 slots that reads and writes
+    each slot's state IN PLACE with its key-side vectors as lane-dense columns, the
+    convolution over q, k and v; and the routed FFN at sizes the fused call's blocks
+    had never had: experts of 1280 (blocks of 640 along the width), 40 of 320 held."""
+
+    S, H, DK, D, F, HELD, LAYERS, TOP_K = 128, 64, 128, 4096, 1280, 40, 4, 8
+    _named = TestLatentAttentionAtTheNotesCellsShapes._named
+
+    @pytest.mark.parametrize("rows", [2048, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_the_blocked_rule(self, chip, rows):
+        from tony_tpu.ops import kda
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        head_rows = lambda dtype: _s((self.H, rows, self.DK), dtype, chip)
+        args = (head_rows(bf), head_rows(bf), head_rows(bf), head_rows(f32), _s((self.H, rows), f32, chip),
+                _s((self.H, self.DK, self.DK), f32, chip), _s((), jnp.int32, chip))
+        self._named(kda.kda_chunk, "kda_chunk", *args)
+
+    def test_the_decode_step_updates_the_state_in_place(self, chip):
+        from tony_tpu.ops import kda
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        slot_heads = lambda dtype: _s((self.S, self.H, self.DK), dtype, chip)
+        state = _s((self.S, self.H, self.DK, self.DK), f32, chip)
+        args = (slot_heads(bf), slot_heads(bf), slot_heads(bf), slot_heads(f32), _s((self.S, self.H), f32, chip), state)
+        compiled = jax.jit(kda.kda_step, donate_argnums=(5,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and len(re.findall(r"%\w*kda_step[\w.]* = ", text)) == 1
+        # the key-side vectors reach the call as [slots, H / 32, dk, 128]: four vectors of 32 heads fill the lanes, nothing padded
+        assert f"f32[{self.S},{self.H // kda.HEADS},{self.DK},{4 * kda.HEADS}]" in text
+        # the state goes out in the buffer it came in: no second 537 MB, no copy of it among the temporaries
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= 4 * self.S * self.H * self.DK * self.DK and memory.temp_size_in_bytes < 64 << 20
+
+    def test_the_convolution_over_q_k_and_v(self, chip):
+        from tony_tpu.ops import delta_rule
+
+        bf, c = jnp.bfloat16, 3 * self.H * self.DK
+        self._named(delta_rule.short_conv_chunk, "short_conv", _s((2048, c), bf, chip), _s((3, c), bf, chip), _s((4, c), bf, chip),
+                    _s((), jnp.int32, chip))
+
+    @pytest.mark.parametrize("tokens,name", [(128, "moe_swiglu_decode"), (512, "moe_swiglu_prefill")], ids=["a-decode-step", "a-512-row-bucket"])
+    def test_the_routed_ffn_that_gathers_and_sums_at_experts_of_1280(self, chip, tokens, name):
+        from tony_tpu.parallel.expert import MoEConfig, held_ffn_form, held_tile
+
+        cfg = MoEConfig(num_experts=320, top_k=self.TOP_K, scoring="sigmoid", held=(0, self.HELD))
+        tile = held_tile(cfg, tokens * self.TOP_K, MG.TILE_M)
+        bound = (-(-tokens * self.TOP_K // tile) + self.HELD) * tile
+        x = _s((tokens, self.D), jnp.bfloat16, chip)
+        up = _s((self.LAYERS, self.HELD, self.D, self.F), jnp.bfloat16, chip)
+        down = _s((self.LAYERS, self.HELD, self.F, self.D), jnp.bfloat16, chip)
+        tok, gate = _s((bound,), jnp.int32, chip), _s((bound,), jnp.float32, chip)
+        tg, scalar = _s((bound // tile,), jnp.int32, chip), _s((), jnp.int32, chip)
+
+        def fn(x, tok, gate, wg, wu, wd, tg, live, layer):
+            return MG.moe_swiglu_tokens(x, tok, gate, wg, wu, wd, tg, tile, live, layer, name=name)
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setenv("TONY_PALLAS_INTERPRET", "1")            # `_kernel_eligible` asks for a TPU backend or the interpreter: the CPU's answer
+            assert held_ffn_form(cfg, tokens, self.D, self.F, jnp.bfloat16) == "in_kernel"
+        assert MG.width_block(self.D, self.F, 2) == 640          # a slab of 31.5 MB, 63 double-buffered: two blocks of 640
+        compiled = jax.jit(fn).lower(x, tok, gate, up, up, down, tg, scalar, scalar).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and name in text
+        assert f"bf16[{self.LAYERS},{self.HELD},{self.D},{self.F}]" in text and f"bf16[{bound},{self.D}]" not in text
+        assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 20
+
+    def test_the_routed_ffn_over_a_2048_row_chunks_staged_rows(self, chip):
+        from tony_tpu.parallel.expert import MoEConfig, held_form, held_tile
+
+        rows_in = 2048
+        tile = held_tile(MoEConfig(num_experts=320, top_k=self.TOP_K, held=(0, self.HELD)), rows_in * self.TOP_K, MG.TILE_M)
+        rows = (-(-rows_in * self.TOP_K // tile) + self.HELD) * tile
+        xs = _s((rows, self.D), jnp.bfloat16, chip)
+        up = _s((self.LAYERS, self.HELD, self.D, self.F), jnp.bfloat16, chip)
+        down = _s((self.LAYERS, self.HELD, self.F, self.D), jnp.bfloat16, chip)
+        tg, scalar = _s((rows // tile,), jnp.int32, chip), _s((), jnp.int32, chip)
+
+        def fn(xs, wg, wu, wd, tg, live, layer):
+            return MG.moe_swiglu_rows(xs, wg, wu, wd, tg, tile, live, layer, name="moe_swiglu_prefill")
+
+        assert held_form(rows_in, self.D, 2) == "staged" and tile == MG.TILE_M      # 51 rows an expert a chunk: under a tile, so not doubled
+        compiled = jax.jit(fn).lower(xs, up, up, down, tg, scalar, scalar).compile()
+        assert compiled.as_text().count("tpu_custom_call") == 1 and "moe_swiglu_prefill" in compiled.as_text()
+        assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20       # no copy of a layer's bank (1.26 GB)
+
+    @pytest.mark.parametrize("program", ["a-256-row-prefill-chunk", "a-decode-chunk-of-128-slots"])
+    def test_the_familys_serving_programs_at_the_cells_sizes(self, chip, program):
+        """The jitted programs the engine runs, whole, at the cell's sizes (one period of the published
+        widths, 40 of 320 experts held, an eighth of the vocabulary, 128 slots of 6,144 positions): every
+        Pallas call by its name, the slots' state aliased in and out."""
+        from tony_tpu.models import solar_open2 as SO
+
+        cfg = SO.SolarOpen2Config(vocab_size=24_576, layer_types=(SO.ATTENTION, SO.KDA, SO.KDA, SO.KDA), held=(0, 40), max_seq=6144)
+        put = lambda tree: jax.tree.map(lambda a: _s(a.shape, a.dtype, chip), tree)
+        params = put(jax.eval_shape(lambda: SO.init(jax.random.PRNGKey(0), cfg)))
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")          # `_kernel_eligible` asks the backend: the chip's answer, not this sandbox's
+            if program.startswith("a-256"):
+                staging = put(jax.eval_shape(lambda: SO._init_staging(cfg, 6144)))
+                compiled = SO.prefill_chunk.lower(params, _s((1, 256), jnp.int32, chip), staging, _s((), jnp.int32, chip), cfg).compile()
+                names = {"kda_chunk": 3, "short_conv": 3, "moe_swiglu_prefill": 4, "chunk_prefill_attention": 1}
+            else:
+                cache = put(jax.eval_shape(lambda: SO._init_cache(cfg, 128, 6144, 256, 2049)))
+                compiled = SO.decode_steps.lower(params, cache, _s((128,), jnp.int32, chip), _s((2,), jnp.uint32, chip), cfg, 8).compile()
+                names = {"kda_step": 3, "moe_swiglu_decode": 4, "paged_decode_attention": 1}
+                assert compiled.memory_analysis().alias_size_in_bytes >= 3 * 4 * 128 * 64 * 128 * 128    # the state and the pool go out where they came in
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == sum(names.values())
+        for name, calls in names.items():
+            assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
+        assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30

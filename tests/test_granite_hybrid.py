@@ -573,7 +573,7 @@ def test_the_cell_is_the_issues(bench):
         assert m["workloads"] == [CELL] and spec.metric(name)["reader"] == "family_roofline" and spec.metric(name)["args"] == {"kernel": kernel, "match": match}
     entry = next(c for c in b["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == ["num_hidden_layers", "layer_types", "num_local_experts", "vocab_size"]
-    assert entry["file"] == "benchmark/configs/granite-4.0-h-small.json" and len(b["workloads"]) == 10 and sum(c["chips"] == 4 for c in b["workloads"]) == 1
+    assert entry["file"] == "benchmark/configs/granite-4.0-h-small.json" and len(b["workloads"]) >= 10 and sum(c["chips"] == 4 for c in b["workloads"]) == 1
 
 
 # -- the family's rehearsal (benchmark/tests/test_granite_hybrid_rehearsal.py), run with the suite

@@ -395,13 +395,15 @@ def test_a_new_metric_file_names_a_reader_its_cells_and_something_the_program_re
     every = [w["name"] for w in B["workloads"]]
     want = {"all": every, "train": [c for c in every if ".train" in c], "serve": [c for c in every if ".serve" in c],
             "tput": next(e for e in B["end_to_end"] if e["name"] == "serve_out_tok_s")["workloads"]}[cells]
-    assert entry["workloads"] == want and len(want) == {"all": 10, "train": 2, "serve": 8, "tput": 7}[cells]
+    assert entry["workloads"] == want and len(want) >= {"all": 10, "train": 2, "serve": 8, "tput": 7}[cells]   # a later cell joins its kind's lists
     # every cell that lists it reports the end-to-end metric it moves
     for cell in entry["workloads"]:
         assert entry["moves"] in {e["name"] for e in spec.cell_metrics(B, cell, "end_to_end")}, cell
     assert m.get("kinds", ["train", "serve"]) == {"all": ["train", "serve"], "train": ["train"]}.get(cells, ["serve"])
-    # appended after everything the benchmark had, in the issue's order
-    assert [e["name"] for e in B["per_layer"][-len(NEW_METRICS):]] == list(NEW_METRICS)
+    # appended after everything the benchmark had then, in the issue's order (a later PR's metrics follow them)
+    names = [e["name"] for e in B["per_layer"]]
+    first = names.index(next(iter(NEW_METRICS)))
+    assert names[first:first + len(NEW_METRICS)] == list(NEW_METRICS) and first >= 45
     # what it reads is something the program has: a phase of the ledger, a registered instrument
     # with that label, or a span name the program writes
     if reader == "goodput_phase":
