@@ -601,6 +601,33 @@ class TestStateSpaceAtTheAssistCellsShapes:
                     _s((), jnp.int32, chip), _s((c,), bf, chip))
 
 
+def _one_call_on_the_state(fn, name, state, *args):
+    """`fn` compiles for the chip to ONE Mosaic call under `name` (how a trace's reader finds it) that takes `state`."""
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    calls = [line for line in text.splitlines() if re.search(rf"%\w*{name}[\w.]* = ", line)]
+    assert text.count("tpu_custom_call") == 1 and len(calls) == 1 and state in calls[0].split("custom-call(")[1], name
+
+
+class TestGatedDeltaRuleAtTheSessionsCellsShapes:
+    """`olmo-hybrid-7b.serve_sessions`: a linear layer's blocked rule at the published
+    widths (30 heads of 96 keys and 192 values), a 1024-row prefill chunk and the
+    cell's smallest bucket (256), from a request's float32 state `[30, 96, 192]`: the
+    call `delta_prefill_roofline_pct.serve` finds by this name and operand. Three
+    heads a program (the most up to `delta_rule.CHUNK_HEADS` that divide 30), about 3.8 MB
+    of blocks, states and live values: inside the default scoped VMEM."""
+
+    H, DK, DV = 30, 96, 192
+
+    @pytest.mark.parametrize("rows", [1024, 256], ids=["a-1024-row-chunk", "the-smallest-bucket"])
+    def test_the_blocked_rule(self, chip, rows):
+        from tony_tpu.ops import delta_rule
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        args = (_s((self.H, rows, self.DK), bf, chip), _s((self.H, rows, self.DK), bf, chip), _s((self.H, rows, self.DV), bf, chip),
+                _s((self.H, rows), f32, chip), _s((self.H, rows), f32, chip), _s((self.H, self.DK, self.DV), f32, chip), _s((), jnp.int32, chip))
+        _one_call_on_the_state(delta_rule.gated_delta_chunk, "delta_chunk", f"f32[{self.H},{self.DK},{self.DV}]", *args)
+
+
 class TestChannelGatedDeltaRuleAtTheExtractCellsShapes:
     """`solar-open2-250b.serve_extract` (one chip's share of eight): the Pallas calls of
     a `kda` layer at the published widths (64 heads of 128 keys and 128 values, 24,576
@@ -622,7 +649,8 @@ class TestChannelGatedDeltaRuleAtTheExtractCellsShapes:
         head_rows = lambda dtype: _s((self.H, rows, self.DK), dtype, chip)
         args = (head_rows(bf), head_rows(bf), head_rows(bf), head_rows(f32), _s((self.H, rows), f32, chip),
                 _s((self.H, self.DK, self.DK), f32, chip), _s((), jnp.int32, chip))
-        self._named(kda.kda_chunk, "kda_chunk", *args)
+        # four heads a program (`delta_rule.CHUNK_HEADS`): 4.2 MB of blocks, states and live values, inside the default scoped VMEM
+        _one_call_on_the_state(kda.kda_chunk, "kda_chunk", f"f32[{self.H},{self.DK},{self.DK}]", *args)
 
     def test_the_decode_step_updates_the_state_in_place(self, chip):
         from tony_tpu.ops import kda

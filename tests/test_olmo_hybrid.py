@@ -107,22 +107,62 @@ RULE_CASES = {
 }
 
 
+#: heads, positions, block. A program of the blocked rule holds the most heads up to `CHUNK_HEADS` (4) that divide H: 1, 2,
+#: 3 (six heads: two programs), ONE of thirteen (a prime over the bound: the one-head program), 3 of thirty, 4 of 64
+CHUNKS = {"two-heads": (2, 48, 16), "one-head": (1, 48, 16), "six-heads-in-two-programs": (6, 48, 16), "thirteen-heads-a-program-each": (13, 48, 16),
+          "thirty-heads-by-three": (30, 32, 16), "sixty-four-heads-by-four": (64, 32, 16), "three-heads-in-blocks-of-64": (3, 128, 64)}
+
+
+@pytest.mark.parametrize("chunk", list(CHUNKS))
 @pytest.mark.parametrize("case", list(RULE_CASES))
-def test_the_chunk_form_is_the_recurrence(interpreted, case):
-    """48 positions in blocks of 16 (a block's edge inside the chunk, twice), then
-    the same with 37 of them counting (a padded last chunk): outputs and state."""
+def test_the_chunk_form_is_the_recurrence(interpreted, case, chunk):
+    """Positions in blocks (a block's edge inside the chunk), then the same with
+    eleven fewer of them counting (a padded last chunk): outputs and state."""
     from tony_tpu.ops import delta_rule as D
 
     kw, tol = RULE_CASES[case]
-    q, k, v, g, beta, s0 = _rule_inputs(3, 2, 48, 8, 16, **kw)
+    H, T, block = CHUNKS[chunk]
+    q, k, v, g, beta, s0 = _rule_inputs(3, H, T, 8, 16, **kw)
     want_o, want_s = D.gated_delta_scan(q, k, v, g, beta, s0)
-    o, s = D.gated_delta_chunk(q, k, v, g, beta, s0, block=16)
+    o, s = D.gated_delta_chunk(q, k, v, g, beta, s0, block=block)
     scale = float(jnp.abs(want_o).max())
     assert float(jnp.abs(o - want_o).max()) < tol * scale and float(jnp.abs(s - want_s).max()) < tol * float(jnp.abs(want_s).max())
-    o, s = D.gated_delta_chunk(q, k, v, g, beta, s0, jnp.int32(37), block=16)
-    _, want_s = D.gated_delta_scan(*(a[:, :37] for a in (q, k, v, g, beta)), s0)
-    assert float(jnp.abs(o[:, :37] - want_o[:, :37]).max()) < tol * scale
+    valid = T - 11
+    o, s = D.gated_delta_chunk(q, k, v, g, beta, s0, jnp.int32(valid), block=block)
+    _, want_s = D.gated_delta_scan(*(a[:, :valid] for a in (q, k, v, g, beta)), s0)
+    assert float(jnp.abs(o[:, :valid] - want_o[:, :valid]).max()) < tol * scale
     assert float(jnp.abs(s - want_s).max()) < tol * float(jnp.abs(want_s).max())
+
+
+@pytest.mark.parametrize("heads", [2, 6], ids=["two-heads-a-program", "two-programs-of-three-heads"])
+@pytest.mark.parametrize("case", ["beta-near-2-keys-nearly-parallel", "strong-decay", "weak-decay"])
+@pytest.mark.parametrize("cut", [16, 32])
+def test_a_chunk_boundary_inside_a_prompt_carries_the_state(interpreted, cut, case, heads):
+    """Two chunks, the second from the first's state: the one recurrence."""
+    from tony_tpu.ops import delta_rule as D
+
+    kw, tol = RULE_CASES[case]
+    *x, s0 = _rule_inputs(4, heads, 64, 8, 16, **kw)
+    want_o, want_s = D.gated_delta_scan(*x, s0)
+    first, mid = D.gated_delta_chunk(*(a[:, :cut] for a in x), s0, block=16)
+    second, s = D.gated_delta_chunk(*(a[:, cut:] for a in x), mid, block=16)
+    assert float(jnp.abs(jnp.concatenate([first, second], axis=1) - want_o).max()) < tol * float(jnp.abs(want_o).max())
+    assert float(jnp.abs(s - want_s).max()) < tol * float(jnp.abs(want_s).max())
+
+
+@pytest.mark.parametrize("shape", [(4, 128, 64), (3, 48, 16)], ids=["four-heads-of-two-blocks", "three-heads-in-blocks-of-16"])
+@pytest.mark.parametrize("case", ["beta-near-2-keys-nearly-parallel", "strong-decay"])
+def test_a_program_of_several_heads_is_its_heads_one_at_a_time(interpreted, case, shape):
+    """ONE program of all the heads against the same inputs a head at a time (the
+    one-head program, the parent's grid): the same operations a head in the same
+    order, so the outputs and the states are equal BIT FOR BIT, not within a tolerance."""
+    from tony_tpu.ops import delta_rule as D
+
+    H, T, block = shape
+    args = _rule_inputs(7, H, T, 8, 16, **RULE_CASES[case][0])
+    o, s = D.gated_delta_chunk(*args, jnp.int32(T - 5), block=block)
+    alone = [D.gated_delta_chunk(*(a[n:n + 1] for a in args), jnp.int32(T - 5), block=block) for n in range(H)]
+    assert bool((o == jnp.concatenate([a for a, _ in alone])).all()) and bool((s == jnp.concatenate([b for _, b in alone])).all())
 
 
 @pytest.mark.parametrize("case", ["random-keys", "beta-near-2-keys-nearly-parallel", "strong-decay"])

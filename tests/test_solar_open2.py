@@ -108,7 +108,11 @@ def _rule_inputs(seed, H, T, dk, dv, case, dtype=jnp.float32):
 
 
 RULE_CASES = ["weak-forgetting", "strong-beside-weak", "every-rate-at-once", "beta-near-2"]
-SHAPES = {"two-blocks": (2, 128, 16, 32), "the-served-head": (1, 64, 128, 128), "a-short-block": (3, 16, 8, 8)}
+#: heads, positions, d_k, d_v. A program of the blocked rule holds the most heads up to `delta_rule.CHUNK_HEADS` (4) that divide
+#: H: 1, 2, 3 (six heads: two programs), ONE of thirteen (a prime over the bound: the one-head program), 3 of thirty, 4 of 64
+SHAPES = {"two-blocks": (2, 128, 16, 32), "the-served-head": (1, 64, 128, 128), "a-short-block": (3, 16, 8, 8),
+          "six-heads-in-two-programs": (6, 128, 8, 16), "thirteen-heads-a-program-each": (13, 64, 8, 8),
+          "thirty-heads-by-three": (30, 32, 8, 8), "sixty-four-heads-by-four": (64, 32, 8, 8)}
 
 
 def _close(got, want, tol=2e-5):
@@ -126,26 +130,53 @@ def test_the_chunk_form_is_the_recurrence(interpreted, case, shape):
     assert _close(got, want) and _close(new, state) and bool(jnp.isfinite(got).all())
 
 
+@pytest.mark.parametrize("heads,held", [(1, 1), (6, 3), (13, 1), (30, 3), (64, 4), (128, 4)])
+def test_a_program_holds_the_most_heads_that_divide_and_fit(heads, held):
+    """`hb` follows the input's shape: the divisors of H under the kernel's bound, and
+    the bytes a head's blocks, states and live values take against the chip's VMEM
+    (at 384 x 384 a head's states alone are 2.4 MB in the pipeline's buffers: two heads fit, not four)."""
+    from tony_tpu.ops import delta_rule
+
+    assert delta_rule._chunk_heads(heads, 64, 128, 128, 2) == held
+    assert delta_rule._chunk_heads(heads, 64, 384, 384, 2) == min(held, 2 if heads % 2 == 0 else 1)
+
+
+@pytest.mark.parametrize("shape", [(4, 128, 16, 32), (3, 64, 8, 8)], ids=["four-heads-of-two-blocks", "three-heads-of-one-block"])
+@pytest.mark.parametrize("case", ["strong-beside-weak", "beta-near-2"])
+def test_a_program_of_several_heads_is_its_heads_one_at_a_time(interpreted, case, shape):
+    """ONE program of all the heads against the same inputs a head at a time (the
+    one-head program, the parent's grid): the same operations a head in the same
+    order, so the outputs and the states are equal BIT FOR BIT, not within a tolerance."""
+    from tony_tpu.ops import kda
+
+    args = _rule_inputs(7, *shape, case)
+    got, new = kda.kda_chunk(*args, jnp.int32(shape[1] - 5))
+    alone = [kda.kda_chunk(*(a[n:n + 1] for a in args), jnp.int32(shape[1] - 5)) for n in range(shape[0])]
+    assert bool((got == jnp.concatenate([o for o, _ in alone])).all()) and bool((new == jnp.concatenate([s for _, s in alone])).all())
+
+
+@pytest.mark.parametrize("heads", [2, 6], ids=["two-heads-a-program", "two-programs-of-three-heads"])
 @pytest.mark.parametrize("case", ["strong-beside-weak", "beta-near-2"])
 @pytest.mark.parametrize("valid", [1, 11, 64, 75, 128])
-def test_a_padded_chunks_state_stops_at_valid(interpreted, valid, case):
+def test_a_padded_chunks_state_stops_at_valid(interpreted, valid, case, heads):
     """Rows past `valid` neither decay nor write: the state is the recurrence's
     after `valid` positions, and the rows before it read what they read unpadded."""
     from tony_tpu.ops import kda
 
-    args = _rule_inputs(2, 2, 128, 16, 32, case)
+    args = _rule_inputs(2, heads, 128, 16, 32, case)
     want, state = kda.kda_scan(*(a[:, :valid] for a in args[:5]), args[5])
     got, new = kda.kda_chunk(*args, jnp.int32(valid))
     assert _close(got[:, :valid], want) and _close(new, state)
 
 
+@pytest.mark.parametrize("heads", [2, 6], ids=["two-heads-a-program", "two-programs-of-three-heads"])
 @pytest.mark.parametrize("case", ["strong-beside-weak", "every-rate-at-once"])
 @pytest.mark.parametrize("cut", [64, 128])
-def test_a_chunk_boundary_inside_a_prompt_carries_the_state(interpreted, cut, case):
+def test_a_chunk_boundary_inside_a_prompt_carries_the_state(interpreted, cut, case, heads):
     """Two chunks, the second from the first's state: the one recurrence."""
     from tony_tpu.ops import kda
 
-    args = _rule_inputs(3, 2, 192, 16, 32, case)
+    args = _rule_inputs(3, heads, 192, 16, 32, case)
     want, state = kda.kda_scan(*args)
     first, mid = kda.kda_chunk(*(a[:, :cut] for a in args[:5]), args[5])
     second, new = kda.kda_chunk(*(a[:, cut:] for a in args[:5]), mid)
@@ -433,10 +464,12 @@ def test_a_slot_used_again_reads_nothing_of_its_last_tenant(tiny):
 #: parent commit (0e6c370) by the code of `_lowered` below: the two hybrids this family shares `short_conv_chunk` /
 #: `short_conv_step`, the state store's layout and `held_expert_ffn` with, and the routed family whose router it
 #: follows. This PR edits NO file those programs import but models/registry.py (one line appended); ops/kda.py is new
-#: and imports `_dot` from ops/delta_rule.py, which it does not touch; models/serving.py is not touched
+#: and imports `_dot` from ops/delta_rule.py, which it does not touch; models/serving.py is not touched.
+#: PR 58 gives a program of `delta_chunk` several heads (ops/delta_rule.py): `tiny-olmo-hybrid`'s `prefill_chunk`, the one program of
+#: the ten that calls it, a8070b28081f1aad until then; the other nine stand as they stood (no other family imports the chunk kernels)
 OLDER = {"tiny-olmo-hybrid": (16, 8), "tiny-granite-hybrid": (16,), "tiny-exaone-moe": ()}
 PARENT_LOWERED = {
-    "tiny-olmo-hybrid": {"prefill_chunk": "a8070b28081f1aad", "insert": "467f8fef5bdd71ae", "gather_prefix": "39af1ed27e7717dc", "decode_chunk": "8ef8c3cb33512f03"},
+    "tiny-olmo-hybrid": {"prefill_chunk": "d5b15725174aa157", "insert": "467f8fef5bdd71ae", "gather_prefix": "39af1ed27e7717dc", "decode_chunk": "8ef8c3cb33512f03"},
     "tiny-granite-hybrid": {"prefill_chunk": "0fce7728d40655fb", "insert": "69c05903c8d36adc", "decode_chunk": "a2c311b795fa3b8e"},
     "tiny-exaone-moe": {"prefill_chunk": "f320ace621150f5f", "insert": "275db05cc5c489ca", "decode_chunk": "04de1dc498703da3"},
 }

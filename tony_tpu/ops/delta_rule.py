@@ -14,11 +14,11 @@ Three forms of the one recurrence:
 - ``gated_delta_scan``: the literal one, a ``lax.scan`` a position. What the
   other two are tested against; no program calls it.
 - ``gated_delta_chunk``: T positions in blocks of ``BLOCK``, one Pallas program
-  (``delta_chunk``) a head with the head's state resident in VMEM from block to
-  block. Inside a block the written rows ``W`` solve ``(I + A) W = beta (V -
-  exp(G) K S_prev)``, ``A_ij = beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i``
-  (``G`` the running sum of ``g`` inside the block, every difference summed term by
-  term); then ``o_i = exp(G_i)
+  (``delta_chunk``) a group of up to ``CHUNK_HEADS`` heads (``_chunk_heads``) with
+  the group's states resident in VMEM from block to block. Inside a block the
+  written rows ``W`` solve ``(I + A) W = beta (V - exp(G) K S_prev)``, ``A_ij =
+  beta_i (k_i . k_j) exp(G_i - G_j)`` for ``j < i`` (``G`` the running sum of ``g``
+  inside the block, every difference summed term by term); then ``o_i = exp(G_i)
   S_prev^T q_i + sum_{j<=i} exp(G_i - G_j) (k_j . q_i) w_j`` and ``S_next =
   exp(G_C) S_prev + sum_j exp(G_C - G_j) k_j w_j^T`` (the WY form, section 3 of
   the paper). ``(I + A)^-1`` is taken by FORWARD SUBSTITUTION, a row at a
@@ -28,7 +28,10 @@ Three forms of the one recurrence:
   reach 1e8 at a block of 64 before they cancel, and float32 keeps seven digits:
   the row form has no such intermediate (tests/test_olmo_hybrid.py holds both
   regimes to the scan). Every ``exp(G_i - G_j)`` is taken under the ``j <= i``
-  mask, where it is at most 1.
+  mask, where it is at most 1. A head's 63 rows are ONE chain, each waiting for
+  the row before, which no layout shortens; a program therefore carries several
+  heads, whose chains are independent and stand row by row side by side
+  (``_solve_rows``), and a row is summed over the rows above it only.
 - ``gated_delta_step``: one position a slot (decode), a Pallas program
   (``delta_step``) that reads and writes the state of every slot once, in place.
 
@@ -46,6 +49,7 @@ full precision.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -53,9 +57,17 @@ import jax.numpy as jnp
 from tony_tpu.ops.interpret import interpret
 
 _HI = jax.lax.Precision.HIGHEST
-#: positions a block of ``gated_delta_chunk``: the solve is BLOCK sequential rows, the state is
+#: positions a block of ``gated_delta_chunk``: a head's solve is BLOCK sequential rows, the state is
 #: read and written once a block
 BLOCK = 64
+#: heads a program of a blocked rule (``gated_delta_chunk``, ops/kda.py's ``kda_chunk``) holds at most: their solves are
+#: independent chains that run row by row side by side. Chosen on the chip at 30 heads of 96 / 192 and 64 of 128 / 128
+#: (PERF.md section 6, PR 58): past 4 a call gains 2-6%, and every start pays more seconds to trace and lower a body that
+#: is written out a head
+CHUNK_HEADS = 4
+#: bytes of VMEM the blocks, states and live values of a chunk program's heads may take together: under the 16 MB
+#: a kernel is given on the smallest chip this runs on
+CHUNK_VMEM = 12 << 20
 
 
 def gated_delta_scan(q, k, v, g, beta, state):
@@ -83,45 +95,77 @@ def _dot(a, b, dims, exact=False):
                                preferred_element_type=jnp.float32)
 
 
+def _chunk_heads(H: int, C: int, dk: int, dv: int, itemsize: int) -> int:
+    """Heads a program of a blocked rule holds: the most up to CHUNK_HEADS that divide H and whose blocks (q, k, v, o and
+    the float32 gates, in the pipeline's two buffers), states (in and out, two buffers each) and live float32 values
+    (about sixteen arrays of a block's rows by the wider of its widths: 1.0 MB a head measured at 64 x 128 / 128)
+    fit CHUNK_VMEM. A prime H over CHUNK_HEADS runs a head a program."""
+    need = 2 * C * ((2 * dk + 2 * dv) * itemsize + 4 * (dk + 128)) + 16 * dk * dv + 64 * C * max(dk, dv, C)
+    return _heads_block(H, max(1, min(CHUNK_HEADS, CHUNK_VMEM // need)))
+
+
+@jax.jit
+def _solve_rows(At):
+    """X = (I + A)^-1 of each head of a program by rows, X_i = e_i - A_i X. At: a head a [C, C] float32, A
+    transposed (A strictly lower). The rows of X from i on are still those of I and A_ij = 0 there, so row i is summed
+    over the tiles of 8 rows that hold a row above it and ONE tile is rewritten. The rows' loop is outermost and the
+    heads inside it: a head's rows are a chain, each waiting for the row before, and the heads' chains interleave.
+    Jitted so that its 2,000 equations are traced once a process and not once a prefill bucket (the kernel's body is
+    traced anew for every grid)."""
+    C = At[0].shape[0]
+    r = math.gcd(C, 8)                                                       # rows a tile: a float32 register's sublanes
+    sub = jax.lax.broadcasted_iota(jnp.int32, (r, C), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (r, C), 1)
+    first = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
+    X = [[(sub + t == lane).astype(jnp.float32) for t in range(0, C, r)] for _ in At]
+    for i in range(1, C):
+        above = (i - 1) // r + 1                                             # tiles with a row before i
+        e, here = (first == i).astype(jnp.float32), sub == i % r
+        for x, at in zip(X, At):
+            rows = x[0] if above == 1 else jnp.concatenate(x[:above], axis=0)
+            new = e - jnp.sum(at[:above * r, i:i + 1] * rows, axis=0, keepdims=True)   # [1, C]: e_i - A_i X
+            x[i // r] = jnp.where(here, new, x[i // r])
+    return [jnp.concatenate(x, axis=0) for x in X]
+
+
 def _chunk_kernel(q_ref, k_ref, v_ref, gc_ref, gr_ref, b_ref, s0_ref, o_ref, s_ref):
-    """One block of one head. q, k [1, C, dk]; v [1, C, dv]; gc, b [1, C, 1] and gr
-    [1, 1, 1, C]: the block's log-decays as a column and as a row, beta as a column;
-    the state [1, dk, dv] stays in the output block from the head's first block to
-    its last."""
+    """One block of hb heads. q, k [hb, C, dk]; v [hb, C, dv]; gc, b [hb, C, 1] and gr
+    [hb, 1, 1, C]: the block's log-decays as a column and as a row, beta as a column;
+    the states [hb, dk, dv] stay in the output block from the heads' first block to
+    their last. A head's arithmetic is what it is alone (hb = 1): the heads share the
+    masks and the solve's loop over rows, nothing else."""
     from jax.experimental import pallas as pl
 
     @pl.when(pl.program_id(1) == 0)
     def _start():
         s_ref[...] = s0_ref[...]
 
-    q, k, v, S = q_ref[0], k_ref[0], v_ref[0], s_ref[0]
-    g, gr, beta = gc_ref[0], gr_ref[0, 0], b_ref[0]                          # [C, 1], [1, C], [C, 1]
-    C = q.shape[0]
+    hb, C = q_ref.shape[:2]
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
     # G_i - G_j = sum of g_m over j < m <= i, summed term by term (a product with a 0/1 matrix) and not as a
     # difference of running sums: a token's log-decay may be -100 and its neighbour's -0.01, and the
     # difference of two running sums near -1000 keeps three digits of the small one
     upto = (col <= row).astype(jnp.float32)                                  # [i, m]: m <= i
-    between = _dot(upto, jnp.where(row > col, g, 0.0), ((1,), (0,)), exact=True)     # [i, j]
-    decay = jnp.where(col <= row, jnp.exp(jnp.minimum(between, 0.0)), 0.0)   # exp(G_i - G_j), j <= i
-    A = jnp.where(col < row, beta * _dot(k, k, ((1,), (1,))) * decay, 0.0)
-    # X = (I + A)^-1 by rows: X_i = e_i - A_i X, the rows below i still those of I and A_ij = 0 there
-    At = A.T
-    X = (row == col).astype(jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-    for i in range(1, C):
-        new = (lane == i).astype(jnp.float32) - jnp.sum(At[:, i:i + 1] * X, axis=0, keepdims=True)   # [1, C]: e_i - A_i X
-        X = jnp.where(row == i, new, X)
-    eG = jnp.exp(jnp.sum(upto * gr, axis=1, keepdims=True))                  # [C, 1]: exp(G_i), from the block's start
-    R = beta * (v.astype(jnp.float32) - eG * _dot(k, S, ((1,), (0,)), exact=True))
-    W = _dot(X, R, ((1,), (0,)), exact=True)                                 # [C, dv]: the written rows
-    o = eG * _dot(q, S, ((1,), (0,)), exact=True) + _dot(_dot(q, k, ((1,), (1,))) * decay, W, ((1,), (0,)), exact=True)
-    to_end = jnp.exp(jnp.sum(jnp.where(col > row, gr, 0.0), axis=1, keepdims=True))   # [C, 1]: exp(G_C - G_i)
-    # the block's whole decay along the lanes first, then down the rows: Mosaic has no broadcast of [1, 1] in both at once
-    whole = jnp.exp(jnp.broadcast_to(jnp.sum(gr, axis=1, keepdims=True), (1, S.shape[1])))
-    s_ref[0] = whole * S + _dot(k.astype(jnp.float32) * to_end, W, ((0,), (0,)), exact=True)
-    o_ref[0] = o.astype(o_ref.dtype)
+    decay, At = [], []
+    for n in range(hb):
+        k, g, beta = k_ref[n], gc_ref[n], b_ref[n]                           # [C, dk], [C, 1], [C, 1]
+        between = _dot(upto, jnp.where(row > col, g, 0.0), ((1,), (0,)), exact=True)     # [i, j]
+        decay.append(jnp.where(col <= row, jnp.exp(jnp.minimum(between, 0.0)), 0.0))     # exp(G_i - G_j), j <= i
+        At.append(jnp.where(col < row, beta * _dot(k, k, ((1,), (1,))) * decay[n], 0.0).T)
+    X = _solve_rows(At)
+    for n in range(hb):
+        q, k, v, S = q_ref[n], k_ref[n], v_ref[n], s_ref[n]
+        gr, beta = gr_ref[n, 0], b_ref[n]                                    # [1, C], [C, 1]
+        eG = jnp.exp(jnp.sum(upto * gr, axis=1, keepdims=True))              # [C, 1]: exp(G_i), from the block's start
+        R = beta * (v.astype(jnp.float32) - eG * _dot(k, S, ((1,), (0,)), exact=True))
+        W = _dot(X[n], R, ((1,), (0,)), exact=True)                          # [C, dv]: the written rows
+        o = eG * _dot(q, S, ((1,), (0,)), exact=True) + _dot(_dot(q, k, ((1,), (1,))) * decay[n], W, ((1,), (0,)), exact=True)
+        to_end = jnp.exp(jnp.sum(jnp.where(col > row, gr, 0.0), axis=1, keepdims=True))   # [C, 1]: exp(G_C - G_i)
+        # the block's whole decay along the lanes first, then down the rows: Mosaic has no broadcast of [1, 1] in both at once
+        whole = jnp.exp(jnp.broadcast_to(jnp.sum(gr, axis=1, keepdims=True), (1, S.shape[1])))
+        s_ref[n] = whole * S + _dot(k.astype(jnp.float32) * to_end, W, ((0,), (0,)), exact=True)
+        o_ref[n] = o.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -140,17 +184,17 @@ def gated_delta_chunk(q, k, v, g, beta, state, valid=None, block: int = BLOCK):
     C = min(block, T)
     if T % C:
         raise ValueError(f"chunk of {T} positions does not divide into blocks of {C}")
-    nb = T // C
+    nb, hb = T // C, _chunk_heads(H, C, dk, dv, q.dtype.itemsize)
     g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
     if valid is not None:
         counts = jnp.arange(T) < valid
         g, beta = jnp.where(counts, g, 0.0), jnp.where(counts, beta, 0.0)
-    rows = lambda d: pl.BlockSpec((1, C, d), lambda h, b: (h, b, 0))
-    whole = pl.BlockSpec((1, dk, dv), lambda h, b: (h, 0, 0))
+    rows = lambda d: pl.BlockSpec((hb, C, d), lambda h, b: (h, b, 0))
+    whole = pl.BlockSpec((hb, dk, dv), lambda h, b: (h, 0, 0))
     o, state = pl.pallas_call(
         _chunk_kernel,
-        grid=(H, nb),
-        in_specs=[rows(dk), rows(dk), rows(dv), rows(1), pl.BlockSpec((1, 1, 1, C), lambda h, b: (h, b, 0, 0)), rows(1), whole],
+        grid=(H // hb, nb),
+        in_specs=[rows(dk), rows(dk), rows(dv), rows(1), pl.BlockSpec((hb, 1, 1, C), lambda h, b: (h, b, 0, 0)), rows(1), whole],
         out_specs=[rows(dv), whole],
         out_shape=[jax.ShapeDtypeStruct((H, T, dv), v.dtype), jax.ShapeDtypeStruct((H, dk, dv), jnp.float32)],
         compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),

@@ -14,8 +14,9 @@ Three forms of the one recurrence:
 - ``kda_scan``: the literal one, a ``lax.scan`` a position. What the other two are
   tested against; no program calls it.
 - ``kda_chunk``: T positions in blocks of ``BLOCK``, one Pallas program
-  (``kda_chunk``) a head with the head's state resident in VMEM from block to
-  block. With ``G_i`` the running sum of ``g`` inside the block (a vector) the
+  (``kda_chunk``) a group of up to ``delta_rule.CHUNK_HEADS`` heads (``_chunk_heads``)
+  with the group's states resident in VMEM from block to block. With ``G_i`` the
+  running sum of ``g`` inside the block (a vector) the
   written rows ``W`` solve ``(I + A) W = beta (V - (K * exp G) S_prev)``, then ``o_i =
   (q_i * exp G_i)^T S_prev + sum_{j<=i} P_ij w_j`` and ``S_next = Diag(exp G_C) S_prev
   + sum_j (k_j * exp(G_C - G_j)) w_j^T``, where ``A_ij = beta_i sum_d k_id k_jd
@@ -42,7 +43,10 @@ Three forms of the one recurrence:
   with the levels, a group's total from its halves' (``_chunk_kernel``): sums of terms of
   one sign, never a difference of two running sums (a channel may forget by -20 at
   one position and by -0.01 at the next). ``(I + A)^-1`` is forward substitution, a
-  row at a time in float32, as in ops/delta_rule.py and for its reasons.
+  row at a time in float32, as in ops/delta_rule.py and for its reasons; a head's
+  63 rows are one chain, so a program carries several heads and their chains run
+  side by side (``delta_rule._solve_rows``). The level masks do not know the head:
+  they are formed once a program.
 - ``kda_step``: one position a slot (decode), a Pallas program (``kda_step``) that
   reads and writes the state of every slot once, in place. The key-side vectors
   (the decay, ``k``, ``beta k``, ``q``) reach the kernel as COLUMNS, ``HEADS`` heads
@@ -60,12 +64,12 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from tony_tpu.ops.delta_rule import _dot, _heads_block
+from tony_tpu.ops.delta_rule import _chunk_heads, _dot, _heads_block, _solve_rows
 from tony_tpu.ops.interpret import interpret
 
 _HI = jax.lax.Precision.HIGHEST
-#: positions a block of ``kda_chunk``: the solve is BLOCK sequential rows, the pairs take log2(BLOCK) level
-#: products, the state is read and written once a block
+#: positions a block of ``kda_chunk``: a head's solve is BLOCK sequential rows, the pairs take log2(BLOCK) level
+#: products, the state is read and written once a block (32 and 128 are both slower: PERF.md section 6, PR 58)
 BLOCK = 64
 #: heads a program of ``kda_step`` holds the state of: their four key-side vectors fill 128 lanes at 32
 HEADS = 32
@@ -89,9 +93,11 @@ def kda_scan(q, k, v, g, beta, state):
 
 
 def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref):
-    """One block of one head. q, k [1, C, dk]; v [1, C, dv]; g [1, C, dk] float32, the
-    log-decays; b [1, C, 1], beta as a column; the state [1, dk, dv] stays in the
-    output block from the head's first block to its last."""
+    """One block of hb heads. q, k [hb, C, dk]; v [hb, C, dv]; g [hb, C, dk] float32, the
+    log-decays; b [hb, C, 1], beta as a column; the states [hb, dk, dv] stay in the
+    output block from the heads' first block to their last. A head's arithmetic is
+    what it is alone (hb = 1): the heads share the masks and the solve's loop over
+    rows, nothing else."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -99,9 +105,9 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref):
     def _start():
         s_ref[...] = s0_ref[...]
 
-    q, k, v, g, beta, S = q_ref[0], k_ref[0], v_ref[0], g_ref[0], b_ref[0], s_ref[0]
-    C, dk = g.shape
-    kf, qf = k.astype(jnp.float32), q.astype(jnp.float32)
+    hb, C, dk = g_ref.shape
+    heads, dtype = range(hb), k_ref.dtype
+    kf, qf = [k_ref[n].astype(jnp.float32) for n in heads], [q_ref[n].astype(jnp.float32) for n in heads]
     at = jax.lax.broadcasted_iota(jnp.int32, (C, dk), 0)
     row = jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1)
@@ -109,43 +115,40 @@ def _chunk_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s0_ref, o_ref, s_ref):
     # sum (at each of its rows), `upto` the sum from the group's first position through the row's own, `after`
     # the sum from the position after the row's to the group's last. A group of 2h is its two halves: sums of
     # terms of one sign all the way up, no difference of running sums anywhere.
-    total, upto, after = g, g, jnp.zeros_like(g)
-    pairs_k = jnp.zeros((C, C), jnp.float32)                                 # sum_d k_id k_jd exp(G_id - G_jd), j < i
-    pairs_q = jnp.zeros((C, C), jnp.float32)                                 # the same with q_i
+    total = [g_ref[n] for n in heads]
+    upto, after = list(total), [jnp.zeros_like(t) for t in total]
+    pairs_k = [jnp.zeros((C, C), jnp.float32) for _ in heads]                # sum_d k_id k_jd exp(G_id - G_jd), j < i
+    pairs_q = list(pairs_k)                                                  # the same with q_i
     h = 1
     while h < C:
         upper = (at & h) != 0                                                # the row lies in the upper half of its group of 2h
-        first = jnp.exp(upto)                                                # from the half's start through i: at most 1
-        second = jnp.where(upper, 0.0, jnp.exp(after))                       # from after j to the lower half's end
-        rows = jnp.concatenate([(kf * first).astype(k.dtype), (qf * first).astype(k.dtype)], axis=0)
-        level = _dot(rows, (kf * second).astype(k.dtype), ((1,), (1,)))      # [2C, C]
         met = (((row ^ col) & ~(2 * h - 1)) == 0) & ((row & h) != 0) & ((col & h) == 0)
-        pairs_k = pairs_k + jnp.where(met, level[:C], 0.0)
-        pairs_q = pairs_q + jnp.where(met, level[C:], 0.0)
-        below, above = pltpu.roll(total, h, 0), pltpu.roll(total, C - h, 0)  # the other half's total: total[i - h], total[i + h]
-        upto = upto + jnp.where(upper, below, 0.0)
-        after = after + jnp.where(upper, 0.0, above)
-        total = total + jnp.where(upper, below, above)
+        for n in heads:
+            first = jnp.exp(upto[n])                                         # from the half's start through i: at most 1
+            second = jnp.where(upper, 0.0, jnp.exp(after[n]))                # from after j to the lower half's end
+            rows = jnp.concatenate([(kf[n] * first).astype(dtype), (qf[n] * first).astype(dtype)], axis=0)
+            level = _dot(rows, (kf[n] * second).astype(dtype), ((1,), (1,)))   # [2C, C]
+            pairs_k[n] = pairs_k[n] + jnp.where(met, level[:C], 0.0)
+            pairs_q[n] = pairs_q[n] + jnp.where(met, level[C:], 0.0)
+            below, above = pltpu.roll(total[n], h, 0), pltpu.roll(total[n], C - h, 0)   # the other half's total: total[i - h], total[i + h]
+            upto[n] = upto[n] + jnp.where(upper, below, 0.0)
+            after[n] = after[n] + jnp.where(upper, 0.0, above)
+            total[n] = total[n] + jnp.where(upper, below, above)
         h *= 2
     # upto = G_i from the block's start, after = G_C - G_i, total = G_C at every row
-    A = beta * pairs_k
-    # X = (I + A)^-1 by rows: X_i = e_i - A_i X, the rows below i still those of I and A_ij = 0 there
-    At = A.T
-    X = (row == col).astype(jnp.float32)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, C), 1)
-    for i in range(1, C):
-        new = (lane == i).astype(jnp.float32) - jnp.sum(At[:, i:i + 1] * X, axis=0, keepdims=True)   # [1, C]: e_i - A_i X
-        X = jnp.where(row == i, new, X)
-    eG = jnp.exp(upto)                                                       # [C, dk]: exp(G_i), a channel
-    R = beta * (v.astype(jnp.float32) - _dot(kf * eG, S, ((1,), (0,)), exact=True))
-    W = _dot(X, R, ((1,), (0,)), exact=True)                                 # [C, dv]: the written rows
-    P = pairs_q + jnp.where(row == col, jnp.sum(qf * kf, axis=1, keepdims=True), 0.0)
-    o = _dot(qf * eG, S, ((1,), (0,)), exact=True) + _dot(P, W, ((1,), (0,)), exact=True)
-    # the block's whole decay a channel, along the state's lanes: g's columns summed by a product with ones (a
-    # [1, dk] row cannot be laid down a column without a transpose)
-    whole = jnp.exp(_dot(g, jnp.ones((C, S.shape[1]), jnp.float32), ((0,), (0,)), exact=True))
-    s_ref[0] = whole * S + _dot(kf * jnp.exp(after), W, ((0,), (0,)), exact=True)
-    o_ref[0] = o.astype(o_ref.dtype)
+    X = _solve_rows([(b_ref[n] * pairs_k[n]).T for n in heads])
+    for n in heads:
+        S, beta = s_ref[n], b_ref[n]
+        eG = jnp.exp(upto[n])                                                # [C, dk]: exp(G_i), a channel
+        R = beta * (v_ref[n].astype(jnp.float32) - _dot(kf[n] * eG, S, ((1,), (0,)), exact=True))
+        W = _dot(X[n], R, ((1,), (0,)), exact=True)                          # [C, dv]: the written rows
+        P = pairs_q[n] + jnp.where(row == col, jnp.sum(qf[n] * kf[n], axis=1, keepdims=True), 0.0)
+        o = _dot(qf[n] * eG, S, ((1,), (0,)), exact=True) + _dot(P, W, ((1,), (0,)), exact=True)
+        # the block's whole decay a channel, along the state's lanes: g's columns summed by a product with ones (a
+        # [1, dk] row cannot be laid down a column without a transpose)
+        whole = jnp.exp(_dot(g_ref[n], jnp.ones((C, S.shape[1]), jnp.float32), ((0,), (0,)), exact=True))
+        s_ref[n] = whole * S + _dot(kf[n] * jnp.exp(after[n]), W, ((0,), (0,)), exact=True)
+        o_ref[n] = o.astype(o_ref.dtype)
 
 
 @jax.jit
@@ -168,12 +171,13 @@ def kda_chunk(q, k, v, g, beta, state, valid=None):
     if valid is not None:
         counts = jnp.arange(T) < valid
         g, beta = jnp.where(counts[:, None], g, 0.0), jnp.where(counts, beta, 0.0)
-    rows = lambda d: pl.BlockSpec((1, C, d), lambda h, b: (h, b, 0))
-    whole = pl.BlockSpec((1, dk, dv), lambda h, b: (h, 0, 0))
+    hb = _chunk_heads(H, C, dk, dv, q.dtype.itemsize)
+    rows = lambda d: pl.BlockSpec((hb, C, d), lambda h, b: (h, b, 0))
+    whole = pl.BlockSpec((hb, dk, dv), lambda h, b: (h, 0, 0))
     levels = C.bit_length() - 1
     o, state = pl.pallas_call(
         _chunk_kernel,
-        grid=(H, T // C),
+        grid=(H // hb, T // C),
         in_specs=[rows(dk), rows(dk), rows(dv), rows(dk), rows(1), whole],
         out_specs=[rows(dv), whole],
         out_shape=[jax.ShapeDtypeStruct((H, T, dv), v.dtype), jax.ShapeDtypeStruct((H, dk, dv), jnp.float32)],
