@@ -746,3 +746,82 @@ class TestChannelGatedDeltaRuleAtTheExtractCellsShapes:
         for name, calls in names.items():
             assert len(re.findall(rf"%{name}[.\d]* = ", text)) == calls, name
         assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
+class TestParallelHybridAtTheRewriteCellsShapes:
+    """`falcon-h1-34b.serve_rewrite` (one pipeline stage of eight): the Pallas calls of a
+    layer that runs BOTH mixers, at the published widths, found in a trace by these
+    names and operands: the blocked recurrence of 32 heads of 128 over a state of 256 in
+    TWO B/C groups (a 1024-row chunk and the smallest bucket's 256) from a request's
+    float32 state `[256, 4096]`, a decode step of 48 slots that reads and writes each
+    slot's state IN PLACE with a span's columns its own group's, the convolution with a
+    bias over 5,120 channels; the paged decode attention and the chunked prefill
+    attention at FIVE query heads a kv head (20 over 4; blocks `[4, 5, 128]`, not a
+    whole tile of 8 sublanes) over the pool and the staging of nine layers."""
+
+    S, H, P, N, G, LAYERS, HQ, HKV, DH, PAGE, MAX_LEN = 48, 32, 128, 256, 2, 9, 20, 4, 128, 256, 3072
+    _named = TestLatentAttentionAtTheNotesCellsShapes._named
+
+    @pytest.mark.parametrize("rows", [1024, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_the_blocked_recurrence_in_two_groups(self, chip, rows):
+        from tony_tpu.ops import ssd
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        args = (_s((rows, self.H, self.P), bf, chip), _s((rows, self.H), f32, chip), _s((rows, self.H), f32, chip),
+                _s((rows, self.G, self.N), bf, chip), _s((rows, self.G, self.N), bf, chip), _s((self.H,), f32, chip),
+                _s((self.N, self.H * self.P), f32, chip), _s((), jnp.int32, chip))
+        text = jax.jit(ssd.ssd_chunk).lower(*args).compile().as_text()
+        calls = [line for line in text.splitlines() if " custom-call(" in line and "tpu_custom_call" in line]
+        # ONE Mosaic call, under the name and on the operand `ssd_prefill_roofline_pct.serve` finds it by; both groups' B and C side by side
+        assert len(calls) == 1 and re.search(r"%ssd_chunk[\w.]* = ", calls[0]), calls
+        assert f"f32[{self.N},{self.H * self.P}]" in calls[0].split("custom-call(")[1] and f"bf16[{rows},{self.G * self.N}]" in calls[0]
+
+    def test_the_decode_step_updates_the_state_in_place(self, chip):
+        from tony_tpu.ops import ssd
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        state = _s((self.S, self.N, self.H * self.P), f32, chip)
+        args = (_s((self.S, self.H, self.P), bf, chip), _s((self.S, self.H), f32, chip), _s((self.S, self.H), f32, chip),
+                _s((self.S, self.G, self.N), bf, chip), _s((self.S, self.G, self.N), bf, chip), _s((self.H,), f32, chip), state)
+        compiled = jax.jit(ssd.ssd_step, donate_argnums=(6,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and len(re.findall(r"%\w*ssd_step[\w.]* = ", text)) == 1
+        # both groups' columns reach the call side by side, [slots, 2 x 256, 1]; the state goes out in the buffer
+        # it came in: no second 201 MB, no copy of it among the temporaries
+        assert f"f32[{self.S},{self.G * self.N},1]" in text
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= 4 * self.S * self.N * self.H * self.P and memory.temp_size_in_bytes < 16 << 20
+
+    def test_the_convolution_with_a_bias(self, chip):
+        from tony_tpu.ops import delta_rule
+
+        bf = jnp.bfloat16
+        c = self.H * self.P + 2 * self.G * self.N
+        self._named(delta_rule.short_conv_chunk, "short_conv", _s((1024, c), bf, chip), _s((3, c), bf, chip), _s((4, c), bf, chip),
+                    _s((), jnp.int32, chip), _s((c,), bf, chip))
+
+    def test_paged_decode_at_five_query_heads_a_kv_head(self, chip):
+        """The call as the cell makes it: ONE Mosaic kernel whose operand is the whole pool of nine layers, twice."""
+        bf, i32 = jnp.bfloat16, jnp.int32
+        pool = (self.LAYERS, self.S * (self.MAX_LEN // self.PAGE) + 1, self.HKV, self.PAGE, self.DH)
+        q, cur = _s((self.S, self.HQ, self.DH), bf, chip), _s((self.S, self.HKV, self.DH), bf, chip)
+        lengths, layer, staged = _s((self.S,), i32, chip), _s((), i32, chip), _s((self.S, 8, self.HKV, self.DH), bf, chip)
+
+        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
+            return DA.paged_decode_attention(q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v,
+                                             staged_k=sk, staged_v=sv, staged_count=count)
+
+        kp = _s(pool, bf, chip)
+        compiled = jax.jit(fn).lower(q, kp, kp, lengths, _s((self.S, self.MAX_LEN // self.PAGE), i32, chip), layer, cur, cur,
+                                     staged, staged, lengths).compile()
+        whole = f"bf16[{','.join(map(str, pool))}]"
+        calls = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+        assert len(calls) == 1 and calls[0].count(whole) == 2, calls
+        assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+    @pytest.mark.parametrize("rows", [1024, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_chunked_prefill_attention_at_five_query_heads_a_kv_head(self, chip, rows):
+        bf, i32 = jnp.bfloat16, jnp.int32
+        staging = _s((self.LAYERS, 1, self.HKV, self.MAX_LEN, self.DH), bf, chip)
+        self._named(A.chunk_prefill_attention, "chunk_prefill_attention", _s((self.HQ, rows, self.DH), bf, chip), staging, staging,
+                    _s((), i32, chip), _s((), i32, chip), _s((), i32, chip))

@@ -572,7 +572,8 @@ def test_the_cell_is_the_issues(bench):
     assert {m["name"] for m in spec.cell_metrics(b, CELL, "end_to_end")} == {"serve_out_tok_s", "setup_s"}
     for name, kernel, match in (("ssd_decode_roofline_pct.serve", "ssd_decode", "ssd_step"), ("ssd_prefill_roofline_pct.serve", "ssd_prefill", "ssd_chunk")):
         m = next(m for m in b["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and spec.metric(name)["reader"] == "family_roofline" and spec.metric(name)["args"] == {"kernel": kernel, "match": match}
+        # the cell that brought the metric is its first; a later family with the kernel joins behind it (falcon-h1-34b, PR 59)
+        assert m["workloads"][0] == CELL and spec.metric(name)["reader"] == "family_roofline" and spec.metric(name)["args"] == {"kernel": kernel, "match": match}
     entry = next(c for c in b["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == ["num_hidden_layers", "layer_types", "num_local_experts", "vocab_size"]
     assert entry["file"] == "benchmark/configs/granite-4.0-h-small.json" and len(b["workloads"]) >= 10 and sum(c["chips"] == 4 for c in b["workloads"]) == 1

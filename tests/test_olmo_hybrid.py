@@ -705,7 +705,8 @@ def test_the_cell_is_the_issues(bench):
     assert {m["name"] for m in spec.cell_metrics(b, CELL, "end_to_end")} == {"serve_out_tok_s", "setup_s"}
     for name in ("delta_decode_roofline_pct.serve", "delta_prefill_roofline_pct.serve", "attn_prefill_roofline_pct.serve"):
         m = next(m for m in b["per_layer"] if m["name"] == name)
-        assert m["workloads"] == [CELL] and spec.metric(name)["reader"] == "family_roofline"
+        # the cell that brought the metric is its first; a later family with the kernel joins behind it (falcon-h1-34b, PR 59)
+        assert m["workloads"][0] == CELL and spec.metric(name)["reader"] == "family_roofline"
     entry = next(c for c in b["configs"] if c["name"] == CONFIG)
     assert entry["reduced"] == ["num_hidden_layers", "layer_types"] and entry["file"] == "benchmark/configs/olmo-hybrid-7b.json"
 

@@ -1,13 +1,16 @@
 """The state-space dual form of Mamba-2 (SSD, arXiv:2405.21060): a linear recurrence
-whose decay and step size the token chooses, over a state a head that every head
-reads through ONE pair of projections.
+whose decay and step size the token chooses, over a state a head that the heads
+of a GROUP read through one pair of projections.
 
 A head carries a float32 state ``h`` ``[P, N]`` (``P`` the head's width, ``N`` the
 state's). At a position with input ``x`` ``[P]``, step ``dt > 0``, log-decay ``g =
--exp(A_log) dt <= 0`` and the position's ``B``, ``C`` ``[N]`` (one pair for all
-heads: one group)::
+-exp(A_log) dt <= 0`` and the position's ``B``, ``C`` ``[N]`` of the head's group::
 
     h = exp(g) h + dt x B^T          y = h C + D x
+
+``B`` and ``C`` come as ``[T, N]`` (one pair for all heads: one group) or as ``[T,
+G, N]`` (``G`` groups: head ``h`` of ``H`` reads pair ``h // (H / G)``). The shape
+says which; the one-group call is the program it was before there were groups.
 
 Three forms of the one recurrence:
 
@@ -20,12 +23,14 @@ Three forms of the one recurrence:
   for ``j <= i`` and 0 above, and ``h_next = exp(G_T) h_prev + sum_j exp(G_T - G_j)
   dt_j x_j B_j^T``. Every ``exp(G_i - G_j)`` is taken under the ``j <= i`` mask,
   where it is at most 1 (no ``exp(-G_j)`` is ever formed). ``C B^T`` does not
-  know the head: it is formed once a block and group and serves the group's
-  heads; the read-out of the old state and the state's update are one product
-  each for the whole group (``C`` against the group's ``[N, HEADS x P]`` state,
-  ``B`` against the weighted inputs). Only ``L`` is a head's own.
+  know the head: it is formed once a block and program and serves the program's
+  heads, which all lie in ONE B/C group (a program's heads divide a group's);
+  the read-out of the old state and the state's update are one product each
+  for all of them (``C`` against the program's ``[N, HEADS x P]`` state, ``B``
+  against the weighted inputs). Only ``L`` is a head's own.
 - ``ssd_step``: one position a slot (decode), a Pallas program (``ssd_step``)
-  that reads and writes a slot's state once, in place.
+  that reads and writes a slot's state once, in place, a span of lanes at a
+  time; a span lies in one B/C group and takes that group's columns.
 
 THE KERNELS' STATE IS ``[N, H x P]`` (``lanes``): the state's index down the rows,
 (head, channel) along the lanes, which is where ``x`` and ``y`` lie already. A
@@ -41,9 +46,12 @@ are float32 at full precision; ``C B^T`` and the masked product take their
 operands in the activations' type with float32 accumulation. ``D x`` is added
 outside the kernels (an elementwise pass the caller's gate fuses with).
 
-``BLOCK`` and ``HEADS`` are this kernel's own constants, chosen on the chip at
-128 heads of 64 over a state of 128 and chunks of 256 to 2048 rows (PERF.md
-section 6, PR 53).
+``BLOCK``, ``HEADS`` and ``SPAN`` are this kernel's own constants, chosen on the
+chip at 128 heads of 64 over a state of 128 and chunks of 256 to 2048 rows
+(PERF.md section 6, PR 53). At 32 heads of 128 over a state of 256 in two groups
+they give a program of either kernel 2 MB of state where granite's has 0.5 and
+1 MB; that fits a v5e's scoped VMEM, and half of it (8 heads, spans of 1024)
+was no faster on the chip (PERF.md section 6, PR 59).
 """
 
 from __future__ import annotations
@@ -67,16 +75,32 @@ SPAN = 2048
 
 def ssd_scan(x, dt, g, B, C, D, state):
     """The recurrence a position at a time. x [T, H, P]; dt, g [T, H]; B, C [T,
-    N]; D [H]; state [H, P, N] float32. Returns (y [T, H, P] float32, the state
-    after T positions)."""
+    N] or [T, G, N]; D [H]; state [H, P, N] float32. Returns (y [T, H, P]
+    float32, the state after T positions)."""
+    H = x.shape[1]
+    B, C = _by_head(B, H), _by_head(C, H)
 
     def position(h, inputs):
         xt, dtt, gt, bt, ct = inputs
-        h = jnp.exp(gt)[:, None, None] * h + (dtt[:, None] * xt)[:, :, None] * bt[None, None, :]
-        return h, jnp.einsum("hpn,n->hp", h, ct, precision=_HI) + D.astype(jnp.float32)[:, None] * xt
+        h = jnp.exp(gt)[:, None, None] * h + (dtt[:, None] * xt)[:, :, None] * bt[:, None, :]
+        return h, jnp.einsum("hpn,hn->hp", h, ct, precision=_HI) + D.astype(jnp.float32)[:, None] * xt
 
     state, y = jax.lax.scan(position, state.astype(jnp.float32), tuple(a.astype(jnp.float32) for a in (x, dt, g, B, C)))
     return y, state
+
+
+def _by_head(a, H: int):
+    """B or C, [T, N] or [T, G, N], as every head reads it: [T, H, N], head h its group's (h // (H / G))."""
+    a = a[:, None] if a.ndim == 2 else a
+    return jnp.repeat(a, H // a.shape[1], axis=1)
+
+
+def _groups(B, H: int) -> int:
+    """The B/C groups a call's shapes say: 1 for [T, N], G for [T, G, N] (G divides the heads)."""
+    G = 1 if B.ndim == 2 else B.shape[1]
+    if H % G:
+        raise ValueError(f"{G} groups of B and C do not divide {H} heads")
+    return G
 
 
 def _dot(a, b, dims, exact=False):
@@ -106,7 +130,7 @@ def _chunk_kernel(x_ref, b_ref, c_ref, col_ref, row_ref, whole_ref, s0_ref, y_re
     tri = jax.lax.broadcasted_iota(jnp.int32, (C, C), 1) <= jax.lax.broadcasted_iota(jnp.int32, (C, C), 0)
     cb = _dot(Cm, Bm, ((1,), (1,)))                                          # [i, j]: C_i . B_j, the same for every head
     old = _dot(Cm, S, ((1,), (0,)), exact=True)                              # [C, heads x P]: C_i h_prev, every head at once
-    lanes = 2 * P if heads % 2 == 0 else P                                   # two heads side by side fill a tile of lanes
+    lanes = 2 * P if heads % 2 == 0 and 2 * P <= 128 else P                  # two heads side by side fill a tile of lanes (a head of 128 fills its own)
     first = jax.lax.broadcasted_iota(jnp.int32, (C, lanes), 1) < P
     weighted = []
     for at in range(0, heads, lanes // P):
@@ -133,7 +157,7 @@ def _group(H: int, P: int, most: int) -> int:
 @functools.partial(jax.jit, static_argnames=("block",))
 def ssd_chunk(x, dt, g, B, C, D, state, valid=None, block: int = BLOCK):
     """x [T, H, P]; dt, g [T, H] float32 (the step and the log-decay -exp(A_log)
-    dt); B, C [T, N]; D [H]; state [N, H x P] float32 (before the chunk's first
+    dt); B, C [T, N] or [T, G, N]; D [H]; state [N, H x P] float32 (before the chunk's first
     position; `lanes`); valid [] int32, the positions of the chunk that count
     (default all; a padded last chunk of a prompt). Returns (y [T, H, P] in x's
     type, the state after ``valid`` positions). A position past ``valid`` neither
@@ -143,12 +167,13 @@ def ssd_chunk(x, dt, g, B, C, D, state, valid=None, block: int = BLOCK):
     from jax.experimental.pallas import tpu as pltpu
 
     T, H, P = x.shape
-    N = B.shape[1]
+    N, groups = B.shape[-1], _groups(B, H)
     Cb = min(block, T)
     if T % Cb:
         raise ValueError(f"chunk of {T} positions does not divide into blocks of {Cb}")
-    nb, hb = T // Cb, _group(H, P, HEADS)
+    nb, hb = T // Cb, _group(H // groups, P, HEADS)                          # a program's heads lie in one B/C group
     ng = H // hb
+    per = ng // groups                                                       # programs a B/C group
     dt, g = dt.astype(jnp.float32), g.astype(jnp.float32)
     if valid is not None:
         counts = (jnp.arange(T) < valid)[:, None]
@@ -159,7 +184,7 @@ def ssd_chunk(x, dt, g, B, C, D, state, valid=None, block: int = BLOCK):
     col = jnp.concatenate([by_group(a) for a in (jnp.exp(G), G, to_end)], axis=2)
     row = jnp.concatenate([by_group(a) for a in (G, dt)], axis=2).transpose(0, 2, 1)
     flat = pl.BlockSpec((Cb, hb * P), lambda h, b: (b, h))
-    shared = pl.BlockSpec((Cb, N), lambda h, b: (b, 0))
+    shared = pl.BlockSpec((Cb, N), (lambda h, b: (b, 0)) if groups == 1 else (lambda h, b: (b, h // per)))
     whole = pl.BlockSpec((N, hb * P), lambda h, b: (0, h))
     y, state = pl.pallas_call(
         functools.partial(_chunk_kernel, heads=hb, width=P),
@@ -173,7 +198,8 @@ def ssd_chunk(x, dt, g, B, C, D, state, valid=None, block: int = BLOCK):
         name="ssd_chunk",
         cost_estimate=pl.CostEstimate(flops=2 * T * (ng * Cb * N + H * P * (Cb + 2 * N)), transcendentals=T * H * (Cb + 2),
                                       bytes_accessed=2 * T * H * P * x.dtype.itemsize + 2 * ng * T * N * B.dtype.itemsize + 8 * H * P * N),
-    )(x.reshape(T, H * P), B, C, col, row, jnp.repeat(jnp.exp(G[:, -1:]), P, axis=2), state.astype(jnp.float32))
+    )(x.reshape(T, H * P), B.reshape(T, groups * N), C.reshape(T, groups * N), col, row, jnp.repeat(jnp.exp(G[:, -1:]), P, axis=2),
+      state.astype(jnp.float32))
     y = y.reshape(T, H, P)
     return (y.astype(jnp.float32) + D.astype(jnp.float32)[None, :, None] * x.astype(jnp.float32)).astype(x.dtype), state
 
@@ -201,19 +227,20 @@ def lanes(state):
 
 @jax.jit
 def ssd_step(x, dt, g, B, C, D, state):
-    """One position a slot: x [S, H, P]; dt, g [S, H]; B, C [S, N]; D [H]; state
-    [S, N, H x P] float32 (`lanes`), updated in place where the caller donates
-    it. Returns (y [S, H, P] float32, the state with this position in it)."""
+    """One position a slot: x [S, H, P]; dt, g [S, H]; B, C [S, N] or [S, G, N]; D
+    [H]; state [S, N, H x P] float32 (`lanes`), updated in place where the caller
+    donates it. Returns (y [S, H, P] float32, the state with this position in it)."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     S, H, P = x.shape
-    N, HP = B.shape[1], H * P
-    L = next((l for l in (SPAN, 1024, 512, 256, 128) if HP % l == 0), HP)
+    N, HP, groups = B.shape[-1], H * P, _groups(B, H)
+    L = next((l for l in (SPAN, 1024, 512, 256, 128) if (HP // groups) % l == 0), HP // groups)    # a span lies in one B/C group
+    per = HP // groups // L                                                  # spans a B/C group
     f32 = lambda a: a.astype(jnp.float32)
     xf = f32(x)
     row = pl.BlockSpec((1, 1, L), lambda s, l: (s, 0, l))
-    column = pl.BlockSpec((1, N, 1), lambda s, l: (s, 0, 0))
+    column = pl.BlockSpec((1, N, 1), (lambda s, l: (s, 0, 0)) if groups == 1 else (lambda s, l: (s, l // per, 0)))
     span = pl.BlockSpec((1, N, L), lambda s, l: (s, 0, l))
     o, state = pl.pallas_call(
         _step_kernel,
@@ -226,6 +253,6 @@ def ssd_step(x, dt, g, B, C, D, state):
         interpret=interpret(),
         name="ssd_step",
         cost_estimate=pl.CostEstimate(flops=5 * S * HP * N, transcendentals=0, bytes_accessed=8 * S * HP * N),
-    )((f32(dt)[:, :, None] * xf).reshape(S, 1, HP), jnp.repeat(jnp.exp(f32(g)), P, axis=1)[:, None, :], f32(B)[:, :, None], f32(C)[:, :, None],
-      f32(state))
+    )((f32(dt)[:, :, None] * xf).reshape(S, 1, HP), jnp.repeat(jnp.exp(f32(g)), P, axis=1)[:, None, :], f32(B).reshape(S, groups * N)[:, :, None],
+      f32(C).reshape(S, groups * N)[:, :, None], f32(state))
     return o.reshape(S, H, P) + f32(D)[None, :, None] * xf, state
