@@ -698,7 +698,7 @@ class TestPagedEngine:
 
 
 # ---------------------------------------------------------------------------
-# The order of a pass: the chunk first, admission in its shadow
+# The order of a pass: admission in the shadow of the chunk in flight, the next chunk, then that chunk's tokens
 # ---------------------------------------------------------------------------
 def record(eng, monkeypatch):
     """Every program the engine dispatches and every host read of a chunk's
@@ -734,15 +734,25 @@ def record(eng, monkeypatch):
     return log
 
 
-def between_a_read_and_the_next_chunk(log):
-    """What was dispatched after each chunk's tokens reached the host and
-    before the next chunk went out (the last read has no next chunk)."""
-    names = [name for name, _ in log]
-    out = []
-    for i, name in enumerate(names):
-        if name == "read" and "decode_chunk" in names[i + 1:]:
-            out.append(names[i + 1:names.index("decode_chunk", i + 1)])
+def in_flight(log):
+    """[(name, chunks dispatched and not yet read when it was called), ...]:
+    what the device had to run while the host did that."""
+    out, flying = [], 0
+    for name, _ in log:
+        out.append((name, flying))
+        flying += {"decode_chunk": 1, "read": -1}.get(name, 0)
     return out
+
+
+def never_leaves_the_device_idle(log):
+    """Between the first chunk and the last read, whatever the host did, a
+    chunk was in flight: every read but the last found the next chunk
+    dispatched already (two in flight), and everything of admission went out
+    behind a chunk."""
+    first = [name for name, _ in log].index("decode_chunk")
+    reads = [n for name, n in in_flight(log) if name == "read"]
+    rest = [n for name, n in in_flight(log)[first + 1:] if name != "read"]
+    return reads[:-1] == [2] * (len(reads) - 1) and reads[-1] == 1 and min(rest) >= 1
 
 
 def chunks(log):
@@ -800,9 +810,10 @@ class TestPassOrder:
         of short ones (5, 5, 9, 5). Each short one's budget ends inside a chunk
         the host can name at its dispatch, so the next is staged, prefilled and
         inserted behind that chunk and decodes from the one after: no chunk
-        runs with a slot empty while a request waits, nothing of admission is
-        dispatched between a chunk's tokens and the next chunk, and the tokens
-        are those each request gets alone."""
+        runs with a slot empty while a request waits, every chunk's tokens are
+        read with the next chunk dispatched already, all of admission goes out
+        behind a chunk in flight, and the tokens are those each request gets
+        alone."""
         (params, cfg, kw, alone), prompts = CASES[case]()
         budgets = (30, 5, 5, 9, 5)
         eng = ContinuousBatcher(params, cfg, num_slots=2, decode_chunk=4, **kw)
@@ -814,8 +825,7 @@ class TestPassOrder:
             assert chunks(log) == [[L, A], [L, B], [L, C], [L, C], [L, D], [L], [L], [L]]
         # (elsewhere a request is ready later, and by design: a prompt of several prefill chunks advances
         # one a pass, and a prompt whose first page a staged request is about to register waits to reuse it)
-        gaps = between_a_read_and_the_next_chunk(log)
-        assert len(gaps) == len(chunks(log)) - 1 and not any(gaps), gaps
+        assert never_leaves_the_device_idle(log), in_flight(log)
         # ... and all of it was there, behind a chunk: every later request's insert ran with L decoding
         names = [name for name, _ in log]
         assert names.count("insert") == names.count("set_token") == 5
@@ -832,20 +842,21 @@ class TestPassOrder:
         (params, cfg, kw, alone), prompts = CASES["paged"]()
         eng = ContinuousBatcher(params, cfg, num_slots=2, decode_chunk=4, **kw)
         rids = [eng.submit(p, n) for p, n in zip(prompts[:3], (30, 5, 5))]
-        eng.step()                     # nothing was running: L and A admitted, first tokens taken
-        assert sorted(len(r.out) for r in eng.running.values()) == [1, 1]
+        eng.step()                     # nothing was running: L and A admitted, first tokens taken; chunk 1 goes out
+        assert sorted(len(r.out) for r in eng._decoding()) == [1, 1]
         slot = eng.request(rids[1]).slot
-        eng.step()                     # chunk 1 over L and A; B inserted behind it
-        b = eng.running[slot]
-        assert b.rid == rids[2] and b.out == [] and b.first is not None and b.slot_s == 0.0
+        eng.step()                     # B inserted behind chunk 1, chunk 2 over L and B behind that; chunk 1 read
+        b = eng.request(rids[2])       # in A's slot, and in its own last chunk already: in no slot's name by now
+        assert b.slot == slot and b.out == [] and b.first is not None and b.slot_s == 0.0
         assert eng.done[rids[1]] == alone(prompts[1], 5)
-        eng.step()                     # chunk 2 over L and B: B's first token comes with its chunk's
+        eng.step()                     # chunk 2 read: B's first token comes with its chunk's
         assert len(b.out) == 5 and b.first is None and b.slot_s > 0 and eng.done[rids[2]] == alone(prompts[2], 5)
 
     def test_pages_released_and_handed_on_inside_one_pass_give_the_right_tokens(self, monkeypatch):
         """A pool that holds L's pages and one short request's, no more: B's
-        pages ARE A's, released on the host and reserved again in the pass that
-        dispatched A's last chunk, written by B's insert behind that chunk."""
+        pages ARE A's, released on the host and reserved again in one pass, the
+        one that finds A's last chunk in flight, and written by B's insert behind
+        that chunk."""
         (params, cfg, kw, alone), prompts = CASES["paged"]()
         # L: 5 + 32 positions -> 3 pages of 16; A, B, C: 19 + 8 -> 2 pages; pool of 5 (and the sacrificial page)
         prompts = [prompts[0], prompts[1], prompts[1][::-1], prompts[1][1:] + [7]]
@@ -854,10 +865,10 @@ class TestPassOrder:
         rids = [eng.submit(p, n) for p, n in zip(prompts, (30, 5, 5, 5))]
         pages = {}
         while eng.step():
-            pages.update({r.rid: tuple(eng._slot_pages[s]) for s, r in eng.running.items()})
+            pages.update({r.rid: tuple(eng._slot_pages[r.slot]) for r in eng._decoding()})
         assert chunks(log)[:3] == [[rids[0], rids[1]], [rids[0], rids[2]], [rids[0], rids[3]]]
         assert set(pages[rids[1]]) == set(pages[rids[2]]) == set(pages[rids[3]])
-        assert not any(between_a_read_and_the_next_chunk(log))
+        assert never_leaves_the_device_idle(log), in_flight(log)
         for rid, p, n in zip(rids, prompts, (30, 5, 5, 5)):
             assert eng.done[rid] == alone(p, n)
         assert eng.allocator.live_pages() == 0
@@ -866,9 +877,12 @@ class TestPassOrder:
     def test_a_slot_is_refilled_for_the_next_chunk_when_the_host_could_foresee_the_end(self, ending, monkeypatch):
         """A's end against the chunk B first decodes in. By budget the host
         knows at the dispatch of A's last chunk: B is inserted behind it, and no
-        chunk is lost. Cancelled between two passes it knows at the next
-        dispatch: the same. An EOS it sees only in the chunk's tokens: the slot
-        stands empty for one chunk, the stated cost."""
+        chunk is lost. Cancelled between two passes, here with its last chunk in
+        flight and its slot handed back already: that chunk's tokens are
+        dropped. An EOS it sees only in
+        the chunk's tokens, by when the chunk behind has gone out with A in it:
+        the slot is lost for that chunk, the stated cost (the serial engine lost
+        the same chunk, with the slot empty)."""
         (params, cfg, kw, alone), prompts = CASES["dense"]()
         l_out, a_out, b_out = alone(prompts[0], 30), alone(prompts[1], 9), alone(prompts[2], 5)
         eos = a_out[2]                  # A's third token: its second of chunk 1
@@ -876,14 +890,14 @@ class TestPassOrder:
         eng = ContinuousBatcher(params, cfg, num_slots=2, decode_chunk=4, eos_id=eos if ending == "eos" else -1, **kw)
         log = record(eng, monkeypatch)
         L, A, B = (eng.submit(p, n) for p, n in zip(prompts, (30, 5 if ending == "budget" else 9, 5)))
-        eng.step()                      # start-up: L and A admitted with nothing running
-        eng.step()                      # chunk 1 over L and A
+        eng.step()                      # start-up: L and A admitted with nothing running, chunk 1 over them
+        eng.step()                      # chunk 2 behind it, chunk 1 read
         if ending == "cancel":
             assert eng.cancel(A)
         done = eng.run()
         assert chunks(log)[:3] == {"budget": [[L, A], [L, B], [L]],
-                                   "eos": [[L, A], [L], [L, B]],
+                                   "eos": [[L, A], [L, A], [L, B]],
                                    "cancel": [[L, A], [L, A], [L, B]]}[ending]
-        assert not any(between_a_read_and_the_next_chunk(log))
+        assert never_leaves_the_device_idle(log), in_flight(log)
         assert (done[L], done[B]) == (l_out, b_out)
         assert done.get(A) == {"budget": a_out[:5], "eos": a_out[:3], "cancel": None}[ending]

@@ -254,14 +254,13 @@ def admitted(under):
 
 def test_chunk_slot_prefill_and_admission_counters_match_a_hand_count(params):
     """Two slots, chunks of 4. Pass 1 finds nothing running: it admits A and B
-    with the device idle and takes their first tokens at once. Pass 2
-    dispatches chunk 1 over A and B; B's budget (5) ends inside it, so its slot
-    counts as free and C is staged, prefilled and inserted behind the chunk;
-    the chunk's tokens take A to 5 and finish B. Pass 3 dispatches chunk 2 over
-    A and C (whose first token is read with the chunk's): A reaches 9, C 5,
-    both done. Two chunks, two slots each; three prompts of 3 tokens, each
-    padded to the smallest bucket (16); two admissions under nothing, one
-    under a chunk."""
+    with the device idle, takes their first tokens at once and dispatches chunk
+    1 over them; B's budget (5) ends inside it, so its slot counts as free.
+    Pass 2 stages, prefills and inserts C behind chunk 1, dispatches chunk 2
+    over A and C behind that, and reads chunk 1: A at 5, B done. Pass 3 reads
+    chunk 2 (with C's first token): A reaches 9, C 5, both done. Two chunks,
+    two slots each; three prompts of 3 tokens, each padded to the smallest
+    bucket (16); two admissions under nothing, one under a chunk."""
     counters = (serving._CHUNKS, serving._DECODE_SLOTS, serving._PREFILL_TOKENS)
     before = [c.value() for c in counters] + [admitted("idle"), admitted("chunk")]
     eng = engine(params)
@@ -269,7 +268,7 @@ def test_chunk_slot_prefill_and_admission_counters_match_a_hand_count(params):
     assert [eng.request(r).max_new_tokens for r in rids] == [9, 5, 5] and eng.request(99) is None
     lengths = []
     while eng.step():
-        lengths.append([len(r.out) for r in sorted(eng.running.values(), key=lambda r: r.rid)])
+        lengths.append([len(r.out) for r in sorted(eng._decoding(), key=lambda r: r.rid)])
     # after pass 1: A and B hold their first tokens; after pass 2: A at 5, C in B's slot with nothing on the host yet
     assert lengths == [[1, 1], [5, 0]]
     assert [len(eng.done[r]) for r in rids] == [9, 5, 5]

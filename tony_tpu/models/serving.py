@@ -25,11 +25,12 @@ two implementations:
   power-of-two cache prefix covering every active slot — the r2 scheme,
   kept as the CPU/test path and fallback.
 
-Host/device split: a pass dispatches its decode chunk FIRST and does
-admission (staging, prefills, pages, inserts) while the chunk is in flight,
-queued on the device behind it (``ContinuousBatcher.step``); queueing and
-EOS/termination bookkeeping run on the host between chunks; everything
-per-token is one jitted call over all slots. Weights may be an
+Host/device split: a pass does admission (staging, prefills, pages, inserts)
+while the last pass's decode chunk is in flight, queued on the device behind
+it, dispatches the next chunk behind that, and only then reads the last
+chunk's tokens (``ContinuousBatcher.step``): queueing and EOS/termination
+bookkeeping run on the host under the chunk after the one they read;
+everything per-token is one jitted call over all slots. Weights may be an
 int8-quantized tree (ops/quant.py) for the dense family — the same ``_mm``
 dispatch as generate.py serves both.
 """
@@ -75,6 +76,10 @@ _ENGINE_OFFCPU = obs_metrics.counter(
     labelnames=("phase",))
 _CHUNKS = obs_metrics.counter(
     "tony_serve_engine_chunks_total", "decode chunks dispatched")
+_CHUNKS_AHEAD = obs_metrics.counter(
+    "tony_serve_chunks_ahead_total",
+    "decode chunks dispatched while the tokens of the chunk before had not been read: over "
+    "tony_serve_engine_chunks_total, the share of chunks the device could start without a turn of the host")
 _DECODE_SLOTS = obs_metrics.counter(
     "tony_serve_decode_slots_total",
     "running slots summed over dispatched decode chunks (over chunks = mean batch)")
@@ -118,7 +123,7 @@ _EXPERT_COUNTS = (
 
 
 # in the order a pass runs them (``ContinuousBatcher.step``)
-PHASES = ("intake", "dispatch", "admit", "prefill_wait", "decode_wait", "emit", "idle")
+PHASES = ("intake", "admit", "prefill_wait", "dispatch", "decode_wait", "emit", "idle")
 _ANNOTATION = {phase: "tony.serve." + phase for phase in PHASES}
 
 
@@ -545,6 +550,9 @@ class _Request:
     # its first token while only the device has it: a request admitted behind
     # a decode chunk takes the host copy with the tokens of its own first chunk
     first: object = None
+    # decode steps dispatched for it: what ``ends_within`` counts with, since ``out`` trails it by the chunks in flight
+    steps: int = 0
+    finished: bool = False            # ``_finish`` has handed it over: a chunk dispatched with it since is all overshoot
 
     def is_done(self, eos_id: int) -> bool:
         """THE termination predicate — budget spent, EOS emitted, or the
@@ -555,12 +563,12 @@ class _Request:
         )
 
     def ends_within(self, h: int) -> bool:
-        """What the host knows BEFORE a chunk of ``h`` tokens has run: the
-        request is cancelled, or its budget ends inside the chunk. (An EOS is
-        known only once the chunk's tokens are on the host.)"""
-        return self.cancelled or (
-            len(self.out) + (self.first is not None) + h >= self.max_new_tokens
-        )
+        """What the host knows BEFORE a chunk of ``h`` tokens has run, and
+        before it has read the chunk before: the request is cancelled, or its
+        budget (its first token and the steps dispatched so far) ends inside
+        the chunk. (An EOS is known only once its chunk's tokens are on the
+        host.)"""
+        return self.cancelled or 1 + self.steps + h >= self.max_new_tokens
 
 
 @dataclass
@@ -575,9 +583,18 @@ class _Staged:
     keys: list[tuple] = field(default_factory=list)   # cumulative prefix keys (paged)
 
 
+class _Chunk(NamedTuple):
+    """A decode chunk between its dispatch and the pass that reads its tokens."""
+
+    flying: dict                      # {slot: request} it was dispatched with
+    seq: object                       # its tokens [h, S], on the device
+    counts: tuple                     # what else the model's chunk returns (``_EXPERT_COUNTS``), on the device
+
+
 class ContinuousBatcher:
-    """Slot-based continuous batching: every pass dispatches a decode chunk,
-    admits in its shadow, then emits and retires (``step``).
+    """Slot-based continuous batching: every pass admits in the shadow of the
+    decode chunk in flight, dispatches the next chunk behind it, then emits
+    and retires the one in flight (``step``).
 
     One engine instance owns S slots over a shared static KV cache. Requests
     are admitted into free slots as they arrive (prefill padded to a bucket
@@ -747,6 +764,10 @@ class ContinuousBatcher:
         # (overlap with the in-flight decode chunk)
         self._staged: list[_Staged] = []
         self._slot_len = [0] * num_slots  # host mirror of cache.lengths
+        # decode chunks dispatched whose tokens the host has not read, oldest first: one between passes, two
+        # while a pass waits for the older (``step``)
+        self._chunks: list[_Chunk] = []
+        self._ending = 0  # slots handed back at the newest chunk's dispatch, until a pass may refill them (``slots_active``)
         #: the engine thread's phase clock; the server around the engine
         #: switches it for its own parts of a pass (intake, fan-out, idle)
         self.phase = _PhaseClock()
@@ -800,18 +821,35 @@ class ContinuousBatcher:
     def request(self, rid: int) -> _Request | None:
         """The engine's record of a request it holds (waiting, staged or
         running), newest first; None once it is done or was never there."""
-        held = itertools.chain(
-            reversed(self.pending), (e.req for e in self._staged), self.running.values())
+        held = itertools.chain(reversed(self.pending), (e.req for e in self._staged), self._decoding())
         return next((r for r in held if r.rid == rid), None)
+
+    def _decoding(self) -> list[_Request]:
+        """The requests with tokens still to come from a slot: running, or
+        given up at the dispatch of a chunk that is still in flight (a budget
+        that ends inside it), so in no slot's name and not yet in ``done``."""
+        handed_back = (req for chunk in self._chunks for slot, req in chunk.flying.items()
+                       if not req.finished and self.running.get(slot) is not req)
+        return [*self.running.values(), *handed_back]
+
+    @property
+    def slots_active(self) -> int:
+        """Slots a request decodes in: running, or handed back at the newest
+        chunk's dispatch, which the device steps until that chunk ends and
+        which the next pass's admission refills (from where they count as
+        running again). Plain reads of two values: any thread may ask."""
+        return len(self.running) + self._ending
 
     def cancel(self, rid: int) -> bool:
         """Drop a request wherever it is (same-thread as step(), like all
         engine calls). Pending → removed; staged → removed with its prefix
         pins released; running → retires at the next chunk boundary (the
         slot and its pages free through the normal retirement flush — a
-        dropped client stops costing TPU within one decode chunk). Returns
-        False for unknown/already-finished rids. A cancelled request never
-        lands in ``done``; its partial tokens are discarded."""
+        dropped client stops costing TPU once the chunk in flight and the
+        one dispatched behind it have run); in its last chunk, still in
+        flight → that chunk's tokens are discarded. Returns False for
+        unknown/already-finished rids. A cancelled request never lands in
+        ``done``; its partial tokens are discarded."""
         for i, req in enumerate(self.pending):
             if req.rid == rid:
                 self.pending.pop(i)
@@ -825,7 +863,7 @@ class ContinuousBatcher:
                 self._staged.pop(i)
                 self._stream_pos.pop(rid, None)
                 return True
-        for slot, req in self.running.items():
+        for req in self._decoding():
             if req.rid == rid:
                 req.cancelled = True  # is_done() now true → retires next chunk
                 return True
@@ -1102,6 +1140,7 @@ class ContinuousBatcher:
 
     def _finish(self, req: _Request) -> None:
         """Its last token is in ``out``: hand the answer over."""
+        req.finished = True
         if req.cancelled:
             self._stream_pos.pop(req.rid, None)  # nobody drains it again
         else:
@@ -1110,10 +1149,14 @@ class ContinuousBatcher:
     def _flush_retired(self):
         """Zero freed slots' device-side lengths in ONE update (idle slots
         would otherwise keep advancing, clamped at maxT, and the ragged
-        kernel would stream their stale cache every step). Queued behind the
-        chunk in flight, which is the old owners' last use of their slots, and
-        before this pass's admissions, which may be handed the same slots and
-        the same pages."""
+        kernel would stream their stale cache every step). Run at the head of
+        a pass, with one chunk in flight at most, and queued behind it. That
+        chunk is the old owners' last use of their slots and pages: a budget's
+        end freed the slot at that chunk's dispatch, an EOS at the read of the
+        chunk before, by when this one had been dispatched with the request
+        still in it. The chunk this pass dispatches comes after the update and
+        after this pass's admissions, which may be handed the same slots and
+        the same pages, and the device runs them in that order."""
         idle, self._retired_slots = self._retired_slots, []
         if idle:
             mask = np.zeros(self.S, bool)
@@ -1144,12 +1187,13 @@ class ContinuousBatcher:
             self._samp_dirty = False
         return self._samp_dev
 
-    def _dispatch_chunk(self):
-        """Dispatch one decode chunk over the running slots. Returns the
-        {slot: request} it was dispatched with, its tokens [h, S], still on
-        the device, and what else the model's chunk returns. A request the host already knows to end inside the chunk
-        (``ends_within``) gives its slot up HERE, so that this pass's admission
-        can refill it behind the chunk and no chunk is lost to the hand-over."""
+    def _dispatch_chunk(self) -> _Chunk:
+        """Dispatch one decode chunk over the running slots. A request the
+        host already knows to end inside the chunk (``ends_within``) gives its
+        slot up HERE, so that the next pass's admission can refill it behind
+        the chunk and no chunk is lost to the hand-over. Everything here is
+        decided from what has been dispatched (``_Request.steps``,
+        ``_slot_len``): the tokens of the chunk before may still be unread."""
         self.phase.to("dispatch")
         # constant chunk height = ONE compiled decode variant; slots whose
         # request finishes mid-chunk simply discard the overshoot tokens
@@ -1191,51 +1235,74 @@ class ContinuousBatcher:
         context = np.array([self._slot_len[s] for s in flying])[:, None] + np.arange(1, h + 1)
         _CONTEXT_TOKENS.inc(int(context.sum()))
         _VISIBLE_TOKENS.inc(int(np.sum(self.programs.visible_tokens(context))))
+        ending = 0
         for slot, req in flying.items():
             if req.ends_within(h):
                 self._free_slot(slot)
+                ending += 1
             else:
+                req.steps += h
                 self._slot_len[slot] = min(self._slot_len[slot] + h, self.max_len)
-        return flying, seq, counts
+        self._ending = ending
+        return _Chunk(flying, seq, tuple(counts))
+
+    def _emit(self, chunk: _Chunk) -> None:
+        """Block on a chunk's tokens, hand them to their requests, retire."""
+        self.phase.to("decode_wait")
+        seq_host = np.asarray(chunk.seq)  # [h, S]: ONE device→host transfer
+        self.phase.to("emit")
+        for counter, value in zip(_EXPERT_COUNTS, np.asarray(chunk.counts[0]) if chunk.counts else ()):
+            counter.inc(int(value))
+        # the slots the chunk was dispatched WITH: by now ``running`` may
+        # name a slot's next owner, whose tokens these are not
+        for slot, req in chunk.flying.items():
+            if req.finished:
+                continue  # an EOS or a cancel met in the chunk before, this one already dispatched with it: all overshoot
+            if req.first is not None:
+                self._take_first(req)  # no wait: its prefill ran before this chunk did
+            for i in range(self.decode_chunk):
+                if req.is_done(self.eos_id):
+                    break  # post-budget/post-EOS chunk tokens are discarded
+                req.out.append(int(seq_host[i, slot]))
+            if not req.is_done(self.eos_id):
+                continue
+            if self.running.get(slot) is req:
+                self._free_slot(slot)  # an EOS: not known at dispatch, refilled by the next pass's admission
+            self._finish(req)
 
     def step(self) -> bool:
         """One pass. Returns True while work remains.
 
-        (1) Dispatch the decode chunk for the slots that are running. (2) While
-        it is in flight, do everything the NEXT chunk needs: flush retirements,
-        stage, advance prefills, reserve pages, ``insert``, set the slot's
-        token. All of it queues on the device behind the chunk, and the device
-        executes in dispatch order: that order is the only synchronisation. A
-        request admitted here decodes from the next chunk. (3) Block on the
-        chunk's tokens, emit, retire. Between the tokens of one chunk reaching
-        the host and the dispatch of the next, no admission program is
-        dispatched and no admission scalar uploaded. With nothing running
-        there is no (1) and no (3): admission runs with the device idle."""
-        flying, seq, counts = self._dispatch_chunk() if self.running else ({}, None, ())
+        Two decode chunks deep. A pass finds the chunk the pass before
+        dispatched still in flight, its tokens unread. (1) While it runs, do
+        everything the NEXT chunk needs: flush retirements, stage, advance
+        prefills, reserve pages, ``insert``, set the slot's token. (2) Dispatch
+        the next chunk over the slots that are running. All of it queues on the
+        device behind the chunk in flight, and the device executes in dispatch
+        order: that order is the only synchronisation, and the device goes from
+        one chunk through admission's programs into the next with no turn of
+        the host between. (3) Block on the older chunk's tokens, emit, retire:
+        the read-back, the walk and whatever the caller does before its next
+        pass run under the chunk just dispatched. Nothing (1) and (2) use comes
+        from the host's copy of tokens: the next chunk's input tokens and the
+        cache stay on the device, and a budget's end is counted from what was
+        dispatched. Only an EOS is learnt from tokens, one chunk late: its
+        request is in the chunk behind too, whose tokens of it are discarded.
+        With no chunk in flight (start-up, after a drain) there is no (3) and
+        admission runs with the device idle; with nothing running there is no
+        (2), and ``more`` stays True until the last chunk in flight is read."""
+        waiting = bool(self._chunks)
+        self._ending = 0
         self.phase.to("admit")
         self._flush_retired()
-        self._admit("chunk" if flying else "idle")
-        if flying:
-            self.phase.to("decode_wait")
-            seq_host = np.asarray(seq)  # [h, S]: ONE device→host transfer
-            self.phase.to("emit")
-            for counter, value in zip(_EXPERT_COUNTS, np.asarray(counts[0]) if counts else ()):
-                counter.inc(int(value))
-            # the slots the chunk was dispatched WITH: by now ``running`` may
-            # name a slot's next owner, whose tokens these are not
-            for slot, req in flying.items():
-                if req.first is not None:
-                    self._take_first(req)  # no wait: its prefill ran before this chunk did
-                for i in range(self.decode_chunk):
-                    if req.is_done(self.eos_id):
-                        break  # post-budget/post-EOS chunk tokens are discarded
-                    req.out.append(int(seq_host[i, slot]))
-                if not req.is_done(self.eos_id):
-                    continue
-                if self.running.get(slot) is req:
-                    self._free_slot(slot)  # an EOS: not known at dispatch, refilled a chunk later
-                self._finish(req)
-        more = bool(self.running or self.pending or self._staged)
+        self._admit("chunk" if waiting else "idle")
+        if self.running:
+            self._chunks.append(self._dispatch_chunk())
+            if waiting:
+                _CHUNKS_AHEAD.inc()
+        if waiting:
+            self._emit(self._chunks.pop(0))
+        more = bool(self._chunks or self.running or self.pending or self._staged)
         if not more:
             # drained: zero the final chunk's retirees now — cache.lengths is
             # externally observable and must agree with _slot_len between runs
@@ -1259,7 +1326,7 @@ class ContinuousBatcher:
                 pos = self._stream_pos.pop(rid, 0)
                 out[rid] = (list(toks[pos:]), True)
                 self._stream_done.add(rid)
-        live = [e.req for e in self._staged] + list(self.pending) + list(self.running.values())
+        live = [e.req for e in self._staged] + list(self.pending) + self._decoding()
         for req in live:
             if req.rid in self._stream_done or req.rid in out:
                 continue

@@ -603,7 +603,7 @@ class EngineServer:
         up = max(time.time() - self.started_s, 1e-9)
         return {
             "slots_total": eng.S,
-            "slots_active": len(eng.running),
+            "slots_active": eng.slots_active,
             "queue_depth": self._queue_depth(),
             "requests_done": self.requests_done,
             "requests_cancelled": self.requests_cancelled,
@@ -791,12 +791,12 @@ class EngineServer:
             self._sweep_cancellations()
             self._drain_control()
             _QUEUE_DEPTH.set(self._queue_depth())
-            # one pass: dispatch the decode chunk, admit in its shadow (what
-            # intake just submitted is prefilled and inserted BEHIND the chunk
-            # and decodes from the next one), then decode_wait and emit (with
-            # the first tokens of the requests whose first chunk this was).
-            # From here to the next dispatch (fan-out below, intake above) the
-            # device has only what admission queued behind the chunk
+            # one pass: admit in the shadow of the chunk in flight (what intake
+            # just submitted is prefilled and inserted BEHIND it), dispatch the
+            # next chunk behind that, then decode_wait and emit the chunk that
+            # was in flight (with the first tokens of the requests whose first
+            # chunk it was). From here to the next dispatch (fan-out below,
+            # intake above) the device has the chunk just dispatched to run
             had_work = eng.step()
             eng.phase.to("emit")
             # export the engine's prefix-reuse win as a REAL instrument, not
