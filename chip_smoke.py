@@ -6,7 +6,7 @@ two entry points users launch, through the normal path (client -> AM ->
 executor -> a child that owns the chip), at llama-1b width:
 
   train   `tony submit` of examples/llama/pretrain.py --preset llama-1b on a
-          one-chip local pool: the real loop (native loader, prefetch thread,
+          one-chip local pool: the real loop (token loader, prefetch thread,
           logging-window sync, an async checkpoint); loss finite and lower at
           the last logged step than at the first.
   serve   `tony serve --preset llama-1b --replicas 1` with --kv unset (the
@@ -280,7 +280,7 @@ def train_job(name: str, cfg: dict, data_dir: str, *, host_chips: int, chips: in
     if len(losses) < 2 or not all(math.isfinite(x) for x in losses):
         raise PhaseFailed(f"{name}: losses not finite or too few: {losses}\n{tail(log, 40)}")
     first = re.search(r"first step \(compile included\) ([\d.]+)s", log)
-    native = re.search(r"\[train\] data: .* native=(\w+)", log)
+    data = re.search(r"\[train\] data: \d+ shards, (\d+) tokens", log)
     per_dev = re.search(r"param bytes per device: (\{.*\})", log)
     saved = sorted(d for d in os.listdir(ckpt) if d.isdigit()) if os.path.isdir(ckpt) else []
     last = steps[max(steps)]
@@ -289,7 +289,7 @@ def train_job(name: str, cfg: dict, data_dir: str, *, host_chips: int, chips: in
         "device": device_of(log), "losses": losses, "steps": sorted(steps),
         "first_step_s": float(first.group(1)) if first else None,
         "tokens_per_sec": last.get("tokens_per_sec"), "mfu": last.get("mfu"),
-        "loader": {"True": "native", "False": "python"}.get(native.group(1)) if native else None,
+        "data_tokens": int(data.group(1)) if data else None,
         "param_bytes_per_device": json.loads(per_dev.group(1)) if per_dev else None,
         "checkpoints": saved, "log": log,
     }
@@ -298,7 +298,7 @@ def train_job(name: str, cfg: dict, data_dir: str, *, host_chips: int, chips: in
 def report_train(r: dict, cfg: dict) -> None:
     say(f"[train:{r['name']}] `tony submit` SUCCEEDED app={r['app']} wall={r['wall_s']}s "
         f"preset={cfg['preset']} batch={cfg['batch']} seq={cfg['seq']} steps={cfg['steps']}")
-    say(f"[train:{r['name']}] device={json.dumps(r['device'])} loader={r['loader']} "
+    say(f"[train:{r['name']}] device={json.dumps(r['device'])} data_tokens={r['data_tokens']} "
         f"first_step_s={r['first_step_s']} (compile included) "
         f"tokens_per_sec(last window)={r['tokens_per_sec']} mfu={r['mfu']}")
     say(f"[train:{r['name']}] loss by logged step {dict(zip(r['steps'], r['losses']))} "
@@ -313,8 +313,8 @@ def phase_train(cfg: dict, data_dir: str, host_chips: int, need_platform: str) -
         raise PhaseFailed(f"train: loss did not fall: {r['losses']}")
     if not r["checkpoints"]:
         raise PhaseFailed("train: no checkpoint was written")
-    if r["loader"] is None:
-        raise PhaseFailed("train: the loop did not say which loader ran")
+    if not r["data_tokens"]:
+        raise PhaseFailed("train: the loop did not say what data it read")
     return r
 
 

@@ -11,6 +11,7 @@ import faulthandler
 import os
 import signal
 import sys
+import tempfile
 import threading
 
 # Force the CPU platform with 8 virtual devices. Both env and config are set
@@ -40,6 +41,34 @@ def tmp_tony_root(tmp_path, monkeypatch):
     root.mkdir()
     monkeypatch.setenv("TONY_ROOT", str(root))
     return root
+
+
+# -- what the family files share: each is split by subject (the kernel's forms, the program against
+# the reference, the family's files through the harness) so that `--dist loadfile` can place the parts apart
+@pytest.fixture(scope="module")
+def bench():
+    """The benchmark's modules, by name, with benchmark/ on the path for as
+    long as the asking file's tests run."""
+    before = list(sys.path)
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "benchmark"))
+    import chipside
+    import families
+    import spec
+
+    yield {"spec": spec, "families": families, "chipside": chipside}
+    sys.path[:] = before
+
+
+@pytest.fixture(scope="module")
+def monkeypatch_module():
+    mp = pytest.MonkeyPatch()
+    yield mp
+    mp.undo()
+
+
+@pytest.fixture(scope="module")
+def interpreted(monkeypatch_module):
+    monkeypatch_module.setenv("TONY_PALLAS_INTERPRET", "1")
 
 
 #: PR 55's per-layer metrics of a serving cell judged by tokens/s, in BENCHMARK.json's order
@@ -86,6 +115,8 @@ def startup_account():
 # test that never returns holds its xdist worker until the whole run's own time
 # limit cuts it (and every test still queued behind it goes uncounted).
 WATCHDOG_S = 180
+#: after the alarm, how long the main thread has to get back to the interpreter before its worker is ended
+WATCHDOG_GRACE_S = 10
 _real_stderr = None
 
 
@@ -96,30 +127,71 @@ def pytest_configure(config):
     _real_stderr = os.fdopen(os.dup(2), "w")
 
 
+def _hung_tests_file():
+    """Where a worker that ends itself names the test it hung in, for the worker
+    that xdist starts in its place (which is handed the same file again, that
+    test included). xdist exports the run's id to all its workers."""
+    run = os.environ.get("PYTEST_XDIST_TESTRUNUID")
+    if not run or not os.environ.get("PYTEST_XDIST_WORKER"):
+        return None
+    return os.path.join(tempfile.gettempdir(), f"tony-hung-tests-{run}")
+
+
+def _hung_earlier(hung, nodeid):
+    try:
+        with open(hung) as f:
+            return nodeid in f.read().splitlines()
+    except OSError:
+        return False
+
+
+def _stacks(nodeid, after):
+    print(f"\n[watchdog] {nodeid} still running after {after}s; every thread's stack:", file=_real_stderr, flush=True)
+    faulthandler.dump_traceback(file=_real_stderr, all_threads=True)
+
+
 @pytest.fixture(autouse=True)
 def hang_watchdog(request):
     """Fail the test that is still running after WATCHDOG_S seconds and print
     every thread's stack to the run's stderr. SIGALRM reaches the main thread
     of this worker only; a test that sets an alarm of its own replaces this
-    one, and is then left to it (its handler is not touched afterwards)."""
+    one, and is then left to it (its handler is not touched afterwards).
+
+    A main thread that sits in a C call never runs the alarm's handler. Under
+    xdist a timer thread then names the test in `_hung_tests_file` and ends the
+    worker: xdist reports the test as failed, and the worker it starts instead
+    fails it again at once, here, and goes on with the rest of the file."""
     if threading.current_thread() is not threading.main_thread() or _real_stderr is None:
         yield
         return
+    nodeid, hung = request.node.nodeid, _hung_tests_file()
+    if hung and _hung_earlier(hung, nodeid):
+        pytest.fail(f"watchdog: {nodeid} hung a worker of this run where no Python handler could run; not run again")
 
     def on_alarm(signum, frame):
-        print(f"\n[watchdog] {request.node.nodeid} still running after {WATCHDOG_S}s; every thread's stack:",
-              file=_real_stderr, flush=True)
-        faulthandler.dump_traceback(file=_real_stderr, all_threads=True)
+        _stacks(nodeid, WATCHDOG_S)
         pytest.fail(f"watchdog: still running after {WATCHDOG_S}s (stacks on stderr)", pytrace=True)
 
-    # should the main thread be stuck where no Python handler can run, the
-    # stacks still reach the log before the run's own limit
-    faulthandler.dump_traceback_later(WATCHDOG_S + 20, file=_real_stderr)
+    def end_the_worker():
+        _stacks(nodeid, WATCHDOG_S + WATCHDOG_GRACE_S)
+        with open(hung, "a") as f:
+            f.write(nodeid + "\n")
+        os._exit(1)
+
+    timer = threading.Timer(WATCHDOG_S + WATCHDOG_GRACE_S, end_the_worker) if hung else None
+    if timer:
+        timer.daemon = True
+        timer.start()
+    # should the timer's thread not get to run either (a C call that keeps the
+    # GIL), the stacks still reach the log before the run's own limit
+    faulthandler.dump_traceback_later(WATCHDOG_S + 2 * WATCHDOG_GRACE_S, file=_real_stderr)
     before = signal.signal(signal.SIGALRM, on_alarm)
     signal.alarm(WATCHDOG_S)
     try:
         yield
     finally:
+        if timer:
+            timer.cancel()
         faulthandler.cancel_dump_traceback_later()
         if signal.getsignal(signal.SIGALRM) is on_alarm:
             signal.alarm(0)

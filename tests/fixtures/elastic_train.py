@@ -5,7 +5,7 @@ sleeps so the test can SIGKILL one node for good. The AM's capacity re-check
 downsizes the gang (tony.worker.min-instances=1) and attempt 1 — ONE process
 — resumes from the checkpoint onto the smaller mesh and trains to step 8.
 The global-order loader replays the exact sample stream across the shard-
-count change (data/native.py contract), so the final loss matches an
+count change (data/loader.py contract), so the final loss matches an
 uninterrupted fixed-shape reference run up to reduction-order noise.
 
 Usage: elastic_train.py <data_dir> <ckpt_dir>

@@ -35,27 +35,6 @@ CELL = "dots3-note-prev.serve_notes"
 
 
 @pytest.fixture(scope="module")
-def bench():
-    """The benchmark's modules, by name, with benchmark/ on the path for as
-    long as this file's tests run."""
-    before = list(sys.path)
-    sys.path.insert(0, BENCH)
-    import chipside
-    import families
-    import spec
-
-    yield {"spec": spec, "families": families, "chipside": chipside}
-    sys.path[:] = before
-
-
-@pytest.fixture(scope="module")
-def monkeypatch_module():
-    mp = pytest.MonkeyPatch()
-    yield mp
-    mp.undo()
-
-
-@pytest.fixture(scope="module")
 def tiny(bench, monkeypatch_module):
     monkeypatch_module.setenv("TONY_PALLAS_INTERPRET", "1")
     spec, families = bench["spec"], bench["families"]

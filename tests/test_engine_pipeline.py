@@ -102,6 +102,8 @@ FAMILY = {
     "solar_open2": dict(preset="solar-open2-tiny", kw=dict(max_len=128, kv="paged", page_len=16, prefill_chunk=32)),
     "falcon_h1": dict(preset="falcon-h1-tiny", kw=dict(max_len=128, kv="paged", page_len=16, prefill_chunk=32)),
 }
+#: the families whose engine carries a state a slot beside its pages (tests/test_engine_pipeline_recurrent.py)
+RECURRENT = {"minicpm_sala", "olmo_hybrid", "granite_hybrid", "solar_open2", "falcon_h1"}
 # two slots, chunks of 4. L decodes throughout; A's budget ends inside chunk 2 (1 + 4 + 1), B waits for its slot and
 # is inserted behind that chunk, C arrives while three decode, D after two passes with nothing to do but decode
 MIX = Script({0: [("L", 20, 30), ("A", 9, 6), ("B", 12, 5)], 3: [("C", 7, 3)], 6: [("D", 18, 7)]})
@@ -123,8 +125,16 @@ CASES = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(CASES))
+@pytest.mark.parametrize("case", sorted(c for c in CASES if not c.startswith("family-")))
 def test_two_chunks_in_flight_hand_out_what_the_serial_engine_did(case, monkeypatch):
+    """The scripts, on the llama family; every family's engine under the one
+    script MIX is tests/test_engine_pipeline_families.py and
+    tests/test_engine_pipeline_recurrent.py (their compiles are most of this
+    subject's time: files of their own for `--dist loadfile`)."""
+    two_engines_hand_out_the_same(case, monkeypatch)
+
+
+def two_engines_hand_out_the_same(case, monkeypatch):
     monkeypatch.setenv("TONY_PALLAS_INTERPRET", "1")
     spec = CASES[case]
     cfg = registry.presets()[spec["model"]["preset"]]

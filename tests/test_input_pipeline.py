@@ -200,7 +200,7 @@ class TestLoopParity:
         step loop's thread and the finally-block teardown leaves no live
         producer thread behind."""
         from tony_tpu.data import write_token_shard
-        from tony_tpu.data.native import TokenLoader
+        from tony_tpu.data.loader import TokenLoader
 
         rng = np.random.default_rng(8)
         data = tmp_path / "data"

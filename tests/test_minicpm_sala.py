@@ -28,21 +28,6 @@ LOGIT_TOL = 2e-5
 
 
 @pytest.fixture(scope="module")
-def bench():
-    """The benchmark's modules, by name, with benchmark/ on the path for as
-    long as this file's tests run (its top-level names must not shadow a later
-    test file's imports)."""
-    before = list(sys.path)
-    sys.path.insert(0, BENCH)
-    import chipside
-    import families
-    import spec
-
-    yield {"spec": spec, "families": families, "chipside": chipside}
-    sys.path[:] = before
-
-
-@pytest.fixture(scope="module")
 def tiny(bench, monkeypatch_module):
     monkeypatch_module.setenv("TONY_PALLAS_INTERPRET", "1")
     spec, families = bench["spec"], bench["families"]
@@ -59,13 +44,6 @@ def tiny(bench, monkeypatch_module):
 
     return {"sizes": sizes, "module": module, "cfg": cfg, "reference": reference, "params": params,
             "ref_logits": ref_logits}
-
-
-@pytest.fixture(scope="module")
-def monkeypatch_module():
-    mp = pytest.MonkeyPatch()
-    yield mp
-    mp.undo()
 
 
 def _tokens(seed, n, vocab=256):

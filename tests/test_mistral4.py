@@ -16,7 +16,6 @@ ten times what was seen (2e-6) and far below what one wrongly chosen expert move
 import dataclasses
 import math
 import os
-import sys
 
 import jax
 import jax.numpy as jnp
@@ -28,27 +27,6 @@ BENCH = os.path.join(ROOT, "benchmark")
 TINY, MAX_LEN, PAGE = "tiny-mistral4", 128, 16
 LOGIT_TOL = 2e-5
 CONFIG, CELL = "mistral-small-4-119b", "mistral-small-4-119b.serve_docqa"
-
-
-@pytest.fixture(scope="module")
-def bench():
-    """The benchmark's modules, by name, with benchmark/ on the path for as
-    long as this file's tests run."""
-    before = list(sys.path)
-    sys.path.insert(0, BENCH)
-    import chipside
-    import families
-    import spec
-
-    yield {"spec": spec, "families": families, "chipside": chipside}
-    sys.path[:] = before
-
-
-@pytest.fixture(scope="module")
-def monkeypatch_module():
-    mp = pytest.MonkeyPatch()
-    yield mp
-    mp.undo()
 
 
 @pytest.fixture(scope="module")

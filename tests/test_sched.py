@@ -654,7 +654,11 @@ PREEMPT_CONF = {
 }
 
 
-def wait_for(cond, what, timeout=45):
+def wait_for(cond, what, timeout=120):
+    """`cond` polled until it holds. The deadline is sized for the driver's six busy
+    workers: a submitted job's child takes 10 s to its third step alone and, by
+    the builders' runs, more than the 45 s this gave it under them (the one way
+    `test_drain_checkpoints_then_yields_and_beats_the_kill_path` has failed)."""
     deadline = time.time() + timeout
     while time.time() < deadline:
         if cond():

@@ -96,3 +96,4 @@ def test_slow_marker_is_registered():
         doc = f.read()
     markers = doc.split("markers = [", 1)[1].split("]", 1)[0]
     assert '"slow:' in markers
+

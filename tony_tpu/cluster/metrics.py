@@ -64,32 +64,63 @@ def sample_tpu_metrics() -> dict[str, Any]:
     return {"devices": per_device} if per_device else {}
 
 
+class HostMetricsSampler:
+    """Whole-host CPU utilization and memory pressure, and this process's
+    resident set, from /proc; ``cpu_util_pct`` is since the previous call."""
+
+    def __init__(self):
+        self._last: tuple[int, int] | None = None
+
+    def sample(self) -> dict:
+        ncpus = os.cpu_count() or 1
+        try:
+            with open("/proc/stat") as f:
+                parts = [int(x) for x in f.readline().split()[1:9]]
+            total, idle = sum(parts), parts[3] + parts[4]
+            util = 0.0
+            if self._last and total > self._last[0]:
+                util = 100.0 * (1 - (idle - self._last[1]) / (total - self._last[0]))
+            self._last = (total, idle)
+            mem = {}
+            with open("/proc/meminfo") as f:
+                for line in f:
+                    k, v = line.split(":", 1)
+                    mem[k] = int(v.split()[0])
+            total_kb = mem.get("MemTotal", 0)
+            avail_kb = mem.get("MemAvailable", 0)
+            with open("/proc/self/statm") as f:
+                rss_pages = int(f.read().split()[1])
+            return {
+                "cpu_util_pct": round(util, 2),
+                "mem_used_pct": round(100.0 * (1 - avail_kb / total_kb), 2) if total_kb else 0.0,
+                "mem_total_mb": round(total_kb / 1024, 1),
+                "rss_mb": round(rss_pages * _PAGE / 2**20, 1),
+                "ncpus": ncpus,
+            }
+        except OSError:
+            return {"cpu_util_pct": 0.0, "mem_used_pct": 0.0, "mem_total_mb": 0.0,
+                    "rss_mb": 0.0, "ncpus": ncpus}
+
+
 class MetricsSampler:
     """Combined host+TPU snapshot builder used by the executor push loop.
 
-    Whole-host CPU utilization / memory pressure comes from the native
-    sampler (native/tonymon.cc via tony_tpu.data.native.HostMetricsSampler,
-    Python /proc fallback inside it); per-process CPU/RSS and per-device HBM
-    are sampled here.
+    Whole-host CPU utilization / memory pressure comes from
+    :class:`HostMetricsSampler`; per-process CPU/RSS and per-device HBM are
+    sampled here.
     """
 
     def __init__(self, child_pid: int | None = None, with_tpu: bool = True):
         self.child_pid = child_pid
         self.with_tpu = with_tpu
-        try:
-            from tony_tpu.data.native import HostMetricsSampler
-
-            self._host = HostMetricsSampler()
-        except Exception:  # noqa: BLE001 — metrics are strictly best-effort
-            self._host = None
+        self._host = HostMetricsSampler()
 
     def sample(self) -> dict[str, Any]:
         m = sample_host_metrics(self.child_pid)
-        if self._host is not None:
-            try:
-                m["host"] = self._host.sample()
-            except Exception:  # noqa: BLE001
-                pass
+        try:
+            m["host"] = self._host.sample()
+        except Exception:  # noqa: BLE001 — metrics are strictly best-effort
+            pass
         if self.with_tpu:
             tpu = sample_tpu_metrics()
             if tpu:

@@ -3,7 +3,7 @@
 Layout (little-endian): 8-byte magic ``TONYTOK1``, u32 dtype (0=uint16,
 1=int32), u64 token count, then the flat token payload. uint16 covers
 vocabularies <= 65535 (2 bytes/token on disk); int32 covers the rest.
-The C++ loader (native/tonyio.cc) mmaps the same format.
+``data/loader.TokenLoader`` reads windows out of memory-mapped shards.
 
 Elastic-replay primitives (docs/fault-tolerance.md "Elastic training"):
 :func:`global_slots` is the single definition of which GLOBAL sample slots a
@@ -78,8 +78,8 @@ class TokenShardWriter:
 
 def open_shard(path: str | Path) -> np.memmap:
     """Memory-map a shard's payload in its stored dtype (u16 or i32) —
-    no copy; slices convert to int32 at use (TokenLoader fallback does
-    this per window so a large corpus never materializes in RAM)."""
+    no copy; slices convert to int32 at use (TokenLoader does this per
+    window so a large corpus never materializes in RAM)."""
     path = Path(path)
     with open(path, "rb") as f:
         head = f.read(HEADER_SIZE)
@@ -101,7 +101,7 @@ def global_slots(batch_index: int, global_batch: int, shard_id: int, num_shards:
     """The GLOBAL sample slots rank ``shard_id`` of ``num_shards`` consumes
     in global batch ``batch_index`` — the deterministic repartition rule the
     elastic resize relies on (TokenLoader's global-order contract,
-    data/native.py): rank ``k`` owns the contiguous rows
+    data/loader.py): rank ``k`` owns the contiguous rows
     ``[t*G + k*b, t*G + (k+1)*b)`` where ``G = global_batch`` and
     ``b = G / num_shards``.
 

@@ -177,17 +177,15 @@ class InputPipeline:
         return item[2]
 
     # -- teardown ------------------------------------------------------------
-    def close(self) -> bool:
+    def close(self) -> None:
         """Idempotent; stops the producer, drains the queue so a producer
-        parked on ``put`` wakes, and joins the thread. Returns True when the
-        producer is known dead (or never existed) — False means it is still
-        inside ``make_batch`` (a stalled loader read) and the caller must
-        NOT tear down resources the producer may be touching."""
+        parked on ``put`` wakes, and joins the thread (for at most 5 s: a
+        producer still inside ``make_batch`` is a daemon and is left behind)."""
         if self._closed:
-            return self._thread is None or not self._thread.is_alive()
+            return
         self._closed = True
         if self._thread is None:
-            return True
+            return
         self._stop.set()
         while True:  # drain: the producer's put(timeout) re-checks _stop
             try:
@@ -195,7 +193,6 @@ class InputPipeline:
             except queue.Empty:
                 break
         self._thread.join(timeout=5.0)
-        return not self._thread.is_alive()
 
     def __enter__(self):
         return self

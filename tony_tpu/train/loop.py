@@ -403,7 +403,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
         # FIXED and starting the loader at the resumed step replays the
         # uninterrupted stream — no sample repeated or skipped — even when
         # the gang restarted at a DIFFERENT size (global-order contract,
-        # data/native.py). The consumption cursor persisted next to each
+        # data/loader.py). The consumption cursor persisted next to each
         # checkpoint proves the resumed stream IS the checkpointed one: a
         # silently changed global batch or seed fails here instead of
         # double-consuming or dropping samples across the resize.
@@ -422,8 +422,7 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
             shard_id=jax.process_index(), num_shards=procs,
             seed=loop.data_seed, start_index=start_step,
         )
-        obs_logging.info(f"[train] data: {len(paths)} shards, {loader.total_tokens} tokens, "
-                         f"native={loader.is_native}")
+        obs_logging.info(f"[train] data: {len(paths)} shards, {loader.total_tokens} tokens")
 
         def drop_cursor(next_batch: int) -> None:
             # rank 0 persists the consumption position with every checkpoint
@@ -557,21 +556,11 @@ def _run_lm_training(model_module, model_cfg, loop: LoopConfig, tracer) -> dict:
                 urgent.acknowledge(drain_req, step + 1)
     finally:
         # a failed step/save must not leak the input-pipeline thread, the
-        # loader's native prefetch threads + mmapped shards (gang restarts
-        # re-enter this function in the same process) nor a dangling
-        # profiler capture; pipeline first — its producer calls the loader
-        producer_dead = pipeline.close()
+        # loader's mmapped shards (gang restarts re-enter this function in
+        # the same process) nor a dangling profiler capture
+        pipeline.close()
         if loader is not None:
-            if producer_dead:
-                loader.close()
-            else:
-                # the producer is still inside a stalled loader read:
-                # unmapping the shards under it would segfault — leak the
-                # loader (daemon thread dies with the process) and say so
-                obs_logging.warning(
-                    "[train] input-pipeline producer did not exit within the "
-                    "close deadline; leaving the data loader open"
-                )
+            loader.close()
         profiler.stop()  # flush if the run ended inside the capture window
     if ckpt_mgr is not None:
         # skip if this step is already on disk (resume that ran no new steps)
