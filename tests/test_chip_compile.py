@@ -414,6 +414,39 @@ class TestExaoneMoeKernelsAtServedWidths:
         assert f"bf16[{layers},{held},{d},{f}]" in text and f"bf16[{bound},{d}]" not in text
         assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 20
 
+    @pytest.mark.parametrize("cell,tokens,d,f,held,experts,layers,top_k,tile", [
+        ("solar-open2-250b", 2048, 4096, 1280, 40, 320, 4, 8, 128), ("solar-open2-250b-a-1024-row-bucket", 1024, 4096, 1280, 40, 320, 4, 8, 128),
+        ("granite-4.0-h-small", 2048, 4096, 768, 36, 72, 10, 10, 256), ("k-exaone-236b", 2048, 6144, 2048, 16, 128, 4, 8, 256),
+        ("dots3-note-prev", 2048, 5120, 1536, 32, 256, 4, 8, 128), ("mistral-small-4-119b", 2048, 4096, 2048, 32, 128, 4, 4, 128)])
+    def test_grouped_swiglu_that_fetches_its_rows(self, chip, cell, tokens, d, f, held, experts, layers, top_k, tile):
+        """A long prefill chunk's form (`moe_swiglu_fetched` and `moe_choices_sum`), at every routed cell's widths:
+        the tokens and the rows' outputs stay in HBM as float32 slabs `[D / 128, 128]` a row (whole tiles of 8
+        sublanes: a DMA of one row of `[T, D]` is refused), four and five vectors of the static bound are scalars in
+        SMEM, the grouped product keeps the prefill chunk's name and the banks as operands, and no bfloat16 buffer
+        at the static row bound exists (176 MB a layer at solar-open2, 243 at granite)."""
+        from tony_tpu.parallel.expert import MoEConfig, held_form, held_tile
+
+        assert held_form(tokens, d, 2, top_k) == "fetched"
+        assert held_tile(MoEConfig(num_experts=experts, top_k=top_k, held=(0, held)), tokens * top_k, MG.TILE_M) == tile
+        bound = (-(-tokens * top_k // tile) + held) * tile
+        x = _s((tokens, d), jnp.bfloat16, chip)
+        up, down = _s((layers, held, d, f), jnp.bfloat16, chip), _s((layers, held, f, d), jnp.bfloat16, chip)
+        tok, dest, gates = _s((bound,), jnp.int32, chip), _s((tokens * top_k,), jnp.int32, chip), _s((tokens * top_k,), jnp.float32, chip)
+        tg, scalar = _s((bound // tile,), jnp.int32, chip), _s((), jnp.int32, chip)
+
+        def fn(x, tok, real, dest, gates, wg, wu, wd, tg, live, layer):
+            ys = MG.moe_swiglu_fetched(x, tok, real, wg, wu, wd, tg, tile, live, layer, name="moe_swiglu_prefill")
+            return MG.moe_choices_sum(ys, dest, gates, top_k, d, x.dtype)
+
+        compiled = jax.jit(fn).lower(x, tok, tg, dest, gates, up, up, down, tg, scalar, scalar).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 2 and len(re.findall(r"%moe_swiglu_prefill[.\d]* = ", text)) == 1
+        assert len(re.findall(r"%moe_choices_sum[.\d]* = ", text)) == 1
+        assert f"bf16[{layers},{held},{d},{f}]" in text and f"bf16[{bound},{d}]" not in text
+        assert f"f32[{bound * d // 128},128]" in text and f"f32[{tokens * d // 128},128]" in text
+        # the slabs of the rows' outputs (float32 at the bound, of which the live tiles are written) and of the tokens
+        assert compiled.memory_analysis().temp_size_in_bytes < (bound + tokens) * d * 4 + 8 * 2 ** 20
+
     def test_decode_attention_on_a_window_layers_ring(self, chip):
         """Every window layer's rings are one operand with a layer index; a block of slots goes
         through the call's own pipeline: one kernel, found by its name, and no copy of a layer's
@@ -715,7 +748,9 @@ class TestChannelGatedDeltaRuleAtTheExtractCellsShapes:
         def fn(xs, wg, wu, wd, tg, live, layer):
             return MG.moe_swiglu_rows(xs, wg, wu, wd, tg, tile, live, layer, name="moe_swiglu_prefill")
 
-        assert held_form(rows_in, self.D, 2) == "staged" and tile == MG.TILE_M      # 51 rows an expert a chunk: under a tile, so not doubled
+        # what ran at this cell until PR 62 (now `moe_swiglu_fetched`: TestExaoneMoeKernelsAtServedWidths), and what a width
+        # no row travels alone at still runs; 51 rows an expert a chunk: under a tile, so not doubled
+        assert held_form(rows_in, self.D, 2) == "fetched" and held_form(rows_in, self.D + 512, 2) == "staged" and tile == MG.TILE_M
         compiled = jax.jit(fn).lower(xs, up, up, down, tg, scalar, scalar).compile()
         assert compiled.as_text().count("tpu_custom_call") == 1 and "moe_swiglu_prefill" in compiled.as_text()
         assert compiled.memory_analysis().temp_size_in_bytes < 64 * 2 ** 20       # no copy of a layer's bank (1.26 GB)

@@ -210,7 +210,8 @@ def test_the_engine_counts_its_routed_ffn_programs_by_form(tiny, case):
     """`tony_serve_routed_ffn_programs_total{form}`: a decode chunk is counted under the form its slots give,
     a prefill chunk under the form its padded rows give (parallel/expert.held_ffn_form through
     `ServingPrograms.routed_ffn_form`): float32 rows take `ragged_dot`, so the tiny engine is staged
-    throughout; at the served widths in bfloat16 the decode batch is in the kernel and a 2048-row chunk staged."""
+    throughout; at the served widths in bfloat16 the decode batch is in the kernel and a 1024- or 2048-row chunk fetched
+    (its rows by DMA from the tokens, its choices summed from the rows that exist)."""
     import dataclasses
 
     from tony_tpu.models import granite_hybrid as GH
@@ -219,7 +220,7 @@ def test_the_engine_counts_its_routed_ffn_programs_by_form(tiny, case):
     if case == "the-served-widths":
         cfg = dataclasses.replace(tiny["cfg"], d_model=4096, d_expert=768, dtype="bfloat16")
         form = GH.serving_programs(cfg, "paged").routed_ffn_form
-        assert [form(rows) for rows in (64, 512, 1024, 2048)] == ["in_kernel", "in_kernel", "staged", "staged"]
+        assert [form(rows) for rows in (64, 512, 1024, 2048)] == ["in_kernel", "in_kernel", "fetched", "fetched"]
         assert GH.serving_programs(tiny["cfg"], "paged").routed_ffn_form(2) == "staged"
         return
     eng = _engine(tiny)

@@ -286,12 +286,12 @@ def test_the_engine_decodes_the_references_greedy_tokens_and_counts_its_experts(
 
 def test_the_routed_ffns_form_at_the_served_widths(tiny):
     """`ServingPrograms.routed_ffn_form`: float32 rows take `ragged_dot`, so the tiny engine is staged; at the
-    served widths in bfloat16 the decode batch of 128 and a 512-row bucket are in the kernel, longer chunks staged."""
+    served widths in bfloat16 the decode batch of 128 and a 512-row bucket are in the kernel, longer chunks fetched."""
     from tony_tpu.models import solar_open2 as SO
 
     cfg = dataclasses.replace(tiny["cfg"], d_model=4096, d_expert=1280, num_experts=320, held=(0, 40), top_k=8, dtype="bfloat16")
     form = SO.serving_programs(cfg, "paged").routed_ffn_form
-    assert [form(rows) for rows in (128, 512, 1024, 2048)] == ["in_kernel", "in_kernel", "staged", "staged"]
+    assert [form(rows) for rows in (128, 512, 1024, 2048)] == ["in_kernel", "in_kernel", "fetched", "fetched"]
     assert SO.serving_programs(tiny["cfg"], "paged").routed_ffn_form(2) == "staged"
 
 

@@ -108,7 +108,8 @@ _ROUTED_FFN = obs_metrics.counter(
     "tony_serve_routed_ffn_programs_total",
     "decode chunks and prefill chunks dispatched by a model whose layers hold part of their experts, by where the "
     "routed FFN gathers its rows and sums its choices at that program's row count: in the grouped product's "
-    "kernel, or staged through HBM at the static row bound (parallel/expert.held_form)",
+    "kernel, fetched by the kernels a row at a time from the tokens and the rows that exist, or staged through "
+    "HBM at the static row bound by XLA (parallel/expert.held_form)",
     labelnames=("form",))
 # a model whose layers hold part of their experts returns these four with a chunk's tokens, summed on the
 # device over the chunk's steps and routed layers, from live slots' rows (ServingPrograms.decode_chunk)
@@ -481,7 +482,7 @@ class ServingPrograms(NamedTuple):
     # (the page allocator, the matched pages) -> how many of them a request may start from; None: all. Where state
     # lies beside a prefix's pages, a match ends at the deepest page whose edge has that state kept
     prefix_usable: object = None
-    # (rows of a program: the slots of a decode chunk, a prefill chunk's padded length) -> "in_kernel" | "staged":
+    # (rows of a program: the slots of a decode chunk, a prefill chunk's padded length) -> "in_kernel" | "fetched" | "staged":
     # the form its routed FFN runs in (parallel/expert.held_ffn_form); None where no layer holds part of its experts
     routed_ffn_form: object = None
 

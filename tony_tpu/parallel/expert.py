@@ -578,10 +578,11 @@ def held_tile(cfg: MoEConfig, choices: int, tile: int) -> int:
     expects fewer rows than it holds (a decode step's 16 rows, a short prefill),
     and twice that beyond (a 2048-row chunk: half the tiles, and an expert's
     slab read once, not twice). On the chip at 6144 x 2048, 256 rows: 32 -> 2.18
-    ms a layer, 64 -> 1.77, 128 -> 1.74. In the staged form (``held_form``) the
-    bound is also a buffer: XLA writes ``x[sort_tok]`` at all of it whatever was
-    chosen (64 rows as ``[5248, 4096]``, 43 MB a layer); in the kernel's form
-    it is grid steps and two ``[bound]`` vectors and nothing else."""
+    ms a layer, 64 -> 1.77, 128 -> 1.74. Where the fused kernel runs
+    (``held_form``'s ``"in_kernel"`` and ``"fetched"``) the bound is grid steps
+    and a few ``[bound]`` vectors of scalars, and of the rows' outputs only the
+    live tiles' are written; under ``ragged_dot`` (``"staged"``) it is also a
+    buffer: XLA writes ``x[sort_tok]`` at all of it whatever was chosen."""
     return 2 * tile if choices // cfg.num_experts >= tile else tile
 
 
@@ -591,34 +592,49 @@ HELD_IN_KERNEL_TOKENS = 512
 _TOKENS_VMEM = 40 * 1024 * 1024
 
 
-def held_form(T: int, D: int, itemsize: int) -> str:
+def held_form(T: int, D: int, itemsize: int, top_k: int = 8) -> str:
     """Where a held layer's rows are gathered and its choices summed, from the
-    call's shapes alone: ``"in_kernel"`` (``moe_gemm.moe_swiglu_tokens``: the
-    grouped product takes ``x [T, D]`` and returns ``y [T, D]``; the sorted rows
-    and the experts' outputs never exist in HBM) where the tokens, twice (the
-    pipeline's two buffers), ``y`` twice and the float32 sum fit ``_TOKENS_VMEM``
-    and T is at most ``HELD_IN_KERNEL_TOKENS``: a tile's two one-hot products
-    are ``4 T tile D`` operations beside a slab of ``6 D F`` bytes, a hundredth
-    of its fetch at T = 64 and a third at 512 x 6144 x 2048. Else ``"staged"``:
-    XLA gathers ``x[sort_tok]`` into the static row bound, the product reads
-    and writes the live tiles, XLA gathers the choices back and sums them: what
-    is left when a chunk's rows outnumber what VMEM holds (a 1024- or 2048-row
-    prefill chunk: 2048 x 6144 would want 151 MB). Every decode batch of the
-    benchmark (24, 48, 64, 256 slots) and a prefill bucket of up to 512 rows
-    are in the kernel. On the chip, a layer alone, staged | in the kernel
-    (PERF.md section 6, PR 54): 64 x 4096 x 768 of 36 experts 1.24 | 0.95 ms,
-    256 x 6144 x 2048 of 16 2.14 | 1.71, 512 x 6144 x 2048 2.41 | 1.76 (the
-    largest it holds: 37.7 MB of tokens, sum and result); the kernel's form
-    was the faster at every shape tried, by more the more rows."""
-    fits = T * D * (4 * itemsize + 4) <= _TOKENS_VMEM
-    return "in_kernel" if T <= HELD_IN_KERNEL_TOKENS and fits else "staged"
+    call's shapes alone, where the fused kernel runs (``held_ffn_form``).
+    ``"in_kernel"`` (``moe_gemm.moe_swiglu_tokens``: the grouped product takes
+    ``x [T, D]`` and returns ``y [T, D]``; the sorted rows and the experts'
+    outputs never exist in HBM) where the tokens, twice (the pipeline's two
+    buffers), ``y`` twice and the float32 sum fit ``_TOKENS_VMEM`` and T is at
+    most ``HELD_IN_KERNEL_TOKENS``: a tile's two one-hot products are ``4 T tile
+    D`` operations beside a slab of ``6 D F`` bytes, a hundredth of its fetch at
+    T = 64 and a third at 512 x 6144 x 2048. Every decode batch of the benchmark
+    (24, 48, 64, 256 slots) and a prefill bucket of up to 512 rows are in the
+    kernel. Beyond (a 1024- or 2048-row prefill chunk: 2048 x 6144 would want
+    151 MB of VMEM) ``"fetched"``: the tokens stay in HBM, the grouped product
+    fetches a live tile's real rows from them by one DMA a row
+    (``moe_gemm.moe_swiglu_fetched``) and writes the live tiles' outputs, and a
+    second call sums a token's choices from the rows that exist, one DMA a row
+    again (``moe_gemm.moe_choices_sum``): nothing of the static row bound goes
+    through HBM. A row travels alone as a slab of ``D / 128`` sublanes, whole
+    tiles of 8 where D is a multiple of 1024, and the two calls' buffers (a row
+    tile of up to 256 rows as slabs, rows, sum and output; 64 tokens' ``top_k``
+    rows twice) have to fit ``_TOKENS_VMEM``: else ``"staged"``, XLA's gathers
+    at the static bound before the product and after, as under ``ragged_dot``.
+    On the chip, a layer alone, staged | in the kernel (PERF.md section 6, PR
+    54): 64 x 4096 x 768 of 36 experts 1.24 | 0.95 ms, 256 x 6144 x 2048 of 16
+    2.14 | 1.71, 512 x 6144 x 2048 2.41 | 1.76 (the largest it holds: 37.7 MB of
+    tokens, sum and result); staged | fetched (PERF.md section 6, PR 62): 2048 x
+    4096 x 1280 of 40 experts 4.00 | 2.74 ms, 1024 x 6144 x 2048 of 16 2.88 |
+    2.08, 2048 x 4096 x 768 of 36 (top 10, a tile of 256) 5.08 | 4.26."""
+    from tony_tpu.ops import moe_gemm
+
+    if T <= HELD_IN_KERNEL_TOKENS and T * D * (4 * itemsize + 4) <= _TOKENS_VMEM:
+        return "in_kernel"
+    a_tile = 2 * moe_gemm.TILE_M * D * (4 + itemsize + 4 + 2 * 4)        # slabs, rows, sum, the output's two buffers
+    a_sum = moe_gemm.SUM_TOKENS * D * (2 * top_k * 4 + 4 + 2 * itemsize)
+    return "fetched" if D % 1024 == 0 and max(a_tile, a_sum) <= _TOKENS_VMEM else "staged"
 
 
 def held_ffn_form(cfg: MoEConfig, T: int, D: int, F: int, dtype) -> str:
     """The form ``held_expert_ffn`` runs in for T tokens of this geometry:
     ``held_form``'s where the fused kernel runs at all (``_kernel_eligible``),
     ``"staged"`` under ``ragged_dot``. The engine counts its programs by it."""
-    return held_form(T, D, jnp.dtype(dtype).itemsize) if _kernel_eligible(cfg, D, F, jnp.dtype(dtype)) else "staged"
+    dtype = jnp.dtype(dtype)
+    return held_form(T, D, dtype.itemsize, cfg.top_k) if _kernel_eligible(cfg, D, F, dtype) else "staged"
 
 
 def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConfig, count_mask=None,
@@ -632,13 +648,18 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     a held expert that no row chose has no row tile (``route_ragged``), so a
     step reads the held-and-chosen experts' weights and nothing else of size:
     with no choice on any held expert the kernel runs no tile and ``y`` is
-    zero. Where the fused kernel runs and ``held_form`` says ``"in_kernel"`` (a
-    decode step, a prefill bucket of up to 512 rows) the one call gathers a
-    tile's rows from ``x`` and sums the gated choices into ``y`` in VMEM;
-    otherwise (a longer prefill chunk, or ``ragged_dot`` where the kernel is
-    not eligible) the rows are staged through HBM at the static bound, before
-    the product and after.
-    The two forms round alike and sum a token's choices in another order.
+    zero. Where the fused kernel runs, ``held_form`` says from the shapes how
+    the rows reach it and the choices leave it: ``"in_kernel"`` (a decode
+    step, a prefill bucket of up to 512 rows) the one call gathers a tile's
+    rows from ``x`` and sums the gated choices into ``y`` in VMEM;
+    ``"fetched"`` (a longer prefill chunk) the call fetches a live tile's real
+    rows from ``x`` in HBM and a second sums each token's choices from the rows
+    that exist. Under ``ragged_dot``, where the kernel is not eligible (and at
+    a width no row travels alone at), the rows are ``"staged"`` through HBM at
+    the static bound by XLA, before the product and after.
+    The three forms round alike (a row's output and the gate cast to the
+    activations' type, products summed in float32); fetched and staged sum a
+    token's choices in choice order, in the kernel in tile order.
     ``rows`` counts each held expert's real rows (its load this call), from the
     tokens ``count_mask`` [T] marks (a decode step's idle slots are computed
     like any row and counted as none). ``name``: what the fused call is called
@@ -658,12 +679,25 @@ def held_expert_ffn(x, router_w, bias, w_gate, w_up, w_down, layer, cfg: MoEConf
     # a held expert's real rows: the counted choices whose row lies in its span, one compare over [T*K, count]
     # (a binary search of the spans is six dependent gathers of T*K elements, 77 us a layer at 640 choices of 36)
     ends = jnp.cumsum(group_sizes)
-    real = (counted[:, None] & (dest[:, None] >= (ends - group_sizes)[None]) & (dest[:, None] < ends[None])).sum(0, dtype=jnp.int32)
-    if held_ffn_form(cfg, T, D, F, x.dtype) == "in_kernel":
+
+    def rows_in_spans(chosen):
+        return (chosen[:, None] & (dest[:, None] >= (ends - group_sizes)[None]) & (dest[:, None] < ends[None])).sum(0, dtype=jnp.int32)
+
+    real = rows_in_spans(counted)
+    form = held_ffn_form(cfg, T, D, F, x.dtype)
+    if form == "in_kernel":
         tg = moe_gemm.tile_group_map(group_sizes, rows // tile, tile)
         y = moe_gemm.moe_swiglu_tokens(x, sort_tok, gate_sorted, w_gate, w_up, w_down, tg, tile,
                                        group_sizes.sum() // tile, layer, name)
         return y, real
+    if form == "fetched":
+        tg = moe_gemm.tile_group_map(group_sizes, rows // tile, tile)
+        # a tile's real rows are its first: what its group holds (every choice's, counted or not) past the tile's start
+        held_rows = real if count_mask is None else rows_in_spans(on)
+        starts = jnp.arange(rows // tile, dtype=jnp.int32) * tile
+        in_tile = jnp.where(starts < ends[-1], jnp.clip((ends - group_sizes + held_rows)[tg] - starts, 0, tile), 0)
+        ys = moe_gemm.moe_swiglu_fetched(x, sort_tok, in_tile, w_gate, w_up, w_down, tg, tile, ends[-1] // tile, layer, name)
+        return moe_gemm.moe_choices_sum(ys, dest, gate_vals.reshape(T * K), K, D, x.dtype), real
     ys = _expert_swiglu(x[sort_tok], w_gate, w_up, w_down, group_sizes, tile, layer, name)
     # a choice of an absent expert reads row 0 and is masked, never multiplied: rows past the groups are unwritten
     yc = jnp.where(on[:, None], ys[jnp.where(on, dest, 0)], 0).reshape(T, K, D)
