@@ -860,3 +860,119 @@ class TestParallelHybridAtTheRewriteCellsShapes:
         staging = _s((self.LAYERS, 1, self.HKV, self.MAX_LEN, self.DH), bf, chip)
         self._named(A.chunk_prefill_attention, "chunk_prefill_attention", _s((self.HQ, rows, self.DH), bf, chip), staging, staging,
                     _s((), i32, chip), _s((), i32, chip), _s((), i32, chip))
+
+
+class TestSharedCacheAtTheManyshotCellsShapes:
+    """`phi-4-mini-flash.serve_manyshot` (the whole model on one chip): the Pallas calls of the
+    SambaY layers at the published widths, found in a trace by these names and operands: the
+    selective scan of 5,120 channels over a state of 16, a 2,048-row chunk and the smallest
+    bucket's 256 from a request's float32 state `[16, 5120]` with NOTHING of `[T, 5120, 16]`
+    staged, and a decode step of 16 slots that reads and writes each slot's state in place;
+    both maps of differential attention as ONE call of each one-map kernel over kv-head PAIRS
+    (10 pairs of 128 under 40 widened query heads): the paged decode over the pool of ONE
+    layer, the ring decode over eight window layers' rings of 528 rows, the chunked prefill
+    over the staging of 36,864 positions and the window layers' band; and the whole decode
+    chunk and prefill chunk of all 32 layers, which have to fit beside 7.7 GB of weights."""
+
+    S, E, N, H, PAIRS, WIDE, PAGE, MAX_LEN, WINDOW = 16, 5120, 16, 40, 10, 128, 256, 36_864, 512
+    _named = TestLatentAttentionAtTheNotesCellsShapes._named
+
+    @pytest.mark.parametrize("rows", [2048, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_the_selective_scan_walks_a_chunk_without_staging_it(self, chip, rows):
+        from tony_tpu.ops import selective_scan as SS
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        args = (_s((rows, self.E), bf, chip), _s((rows, self.E), f32, chip), _s((self.N, self.E), f32, chip), _s((rows, self.N), bf, chip),
+                _s((rows, self.N), bf, chip), _s((self.E,), f32, chip), _s((self.N, self.E), f32, chip), _s((), jnp.int32, chip))
+        compiled = jax.jit(SS.selective_chunk).lower(*args).compile()
+        text = compiled.as_text()
+        calls = [line for line in text.splitlines() if " custom-call(" in line and "tpu_custom_call" in line]
+        # ONE Mosaic call, under the name and on the operand `scan_prefill_roofline_pct.serve` finds it by
+        assert len(calls) == 1 and re.search(r"%selective_chunk[\w.]* = ", calls[0]) and f"f32[{self.N},{self.E}]" in calls[0].split("custom-call(")[1], calls
+        # an associative scan would stage rows x 5120 x 16 float32 (671 MB at 2,048 rows): nothing here is a tenth of it
+        assert not re.search(rf"\[{rows},({self.E},{self.N}|{self.N},{self.E})\]", text)
+        assert compiled.memory_analysis().temp_size_in_bytes < 4 * rows * self.E * self.N // 10
+
+    def test_the_decode_step_updates_the_state_in_place(self, chip):
+        from tony_tpu.ops import selective_scan as SS
+
+        bf, f32 = jnp.bfloat16, jnp.float32
+        args = (_s((self.S, self.E), bf, chip), _s((self.S, self.E), f32, chip), _s((self.N, self.E), f32, chip), _s((self.S, self.N), bf, chip),
+                _s((self.S, self.N), bf, chip), _s((self.E,), f32, chip), _s((self.S, self.N, self.E), f32, chip))
+        compiled = jax.jit(SS.selective_step, donate_argnums=(6,)).lower(*args).compile()
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and len(re.findall(r"%\w*selective_step[\w.]* = ", text)) == 1
+        memory = compiled.memory_analysis()
+        assert memory.alias_size_in_bytes >= 4 * self.S * self.N * self.E and memory.temp_size_in_bytes < 4 << 20
+
+    def _decode_args(self, chip):
+        bf, i32 = jnp.bfloat16, jnp.int32
+        cur, staged = _s((self.S, self.PAIRS, self.WIDE), bf, chip), _s((self.S, 8, self.PAIRS, self.WIDE), bf, chip)
+        return _s((self.S, self.H, self.WIDE // 2), jnp.float32, chip), _s((self.S,), i32, chip), _s((), i32, chip), cur, staged
+
+    def test_both_maps_read_the_one_layers_pool_in_one_call(self, chip):
+        """The call as the full layer and every cross layer make it: ONE Mosaic kernel whose operand is the pool of
+        pairs, whole, twice (keys and values): a page moves once a reading layer."""
+        q, lengths, layer, cur, staged = self._decode_args(chip)
+        pool = (1, self.S * (self.MAX_LEN // self.PAGE) + 1, self.PAIRS, self.PAGE, self.WIDE)
+
+        def fn(q, kp, vp, lengths, table, layer, cur_k, cur_v, sk, sv, count):
+            return DA.differential_paged_decode_attention(q, kp, vp, lengths, table, layer, cur_k=cur_k, cur_v=cur_v,
+                                                          staged_k=sk, staged_v=sv, staged_count=count)
+
+        kp = _s(pool, jnp.bfloat16, chip)
+        compiled = jax.jit(fn).lower(q, kp, kp, lengths, _s((self.S, self.MAX_LEN // self.PAGE), jnp.int32, chip), layer, cur, cur,
+                                     staged, staged, lengths).compile()
+        whole = f"bf16[{','.join(map(str, pool))}]"
+        calls = [line for line in compiled.as_text().splitlines() if "tpu_custom_call" in line and " custom-call(" in line]
+        assert len(calls) == 1 and calls[0].count(whole) == 2, calls
+        assert compiled.memory_analysis().temp_size_in_bytes < 8 * 2 ** 20
+
+    def test_both_maps_read_a_ring_of_528_rows_in_one_call(self, chip):
+        q, lengths, layer, cur, staged = self._decode_args(chip)
+        rings = _s((8, self.S, self.PAIRS, self.WINDOW + 16, self.WIDE), jnp.bfloat16, chip)
+
+        def fn(q, rk, rv, lengths, layer, cur_k, cur_v, sk, sv, count):
+            return DA.differential_ring_decode_attention(q, rk, rv, lengths, layer, cur_k=cur_k, cur_v=cur_v, window=self.WINDOW,
+                                                         staged_k=sk, staged_v=sv, staged_count=count)
+
+        self._named(fn, "ring_decode_attention", q, rings, rings, lengths, layer, cur, cur, staged, staged, lengths)
+
+    @pytest.mark.parametrize("rows", [2048, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_both_maps_of_a_prefill_chunk_over_the_staging_of_pairs(self, chip, rows):
+        i32 = jnp.int32
+        staging = _s((1, 1, self.PAIRS, self.MAX_LEN, self.WIDE), jnp.bfloat16, chip)
+        self._named(A.differential_chunk_prefill_attention, "chunk_prefill_attention", _s((rows, self.H, self.WIDE // 2), jnp.float32, chip),
+                    staging, staging, _s((), i32, chip), _s((), i32, chip), _s((), i32, chip))
+
+    @pytest.mark.parametrize("rows", [2048, 256], ids=["a-whole-chunk", "the-smallest-bucket"])
+    def test_both_maps_of_a_window_layers_band(self, chip, rows):
+        bf = jnp.bfloat16
+        pairs, tail = _s((rows, self.PAIRS, self.WIDE), bf, chip), _s((self.PAIRS, self.WINDOW, self.WIDE), bf, chip)
+        fn = lambda q, k, v, tk, tv, pos0: A.differential_window_prefill_attention(q, k, v, tk, tv, pos0, self.WINDOW)[0]
+        text = jax.jit(fn).lower(_s((rows, self.H, self.WIDE // 2), jnp.float32, chip), pairs, pairs, tail, tail, _s((), jnp.int32, chip)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1
+
+    @pytest.mark.parametrize("program", ["decode_chunk", "prefill_chunk"])
+    def test_the_whole_programs_fit_beside_the_weights(self, chip, program):
+        """All 32 layers at the published sizes (two scans of stacked periods, so a program compiles in seconds):
+        3.85 B parameters, 7.7 GB; 16 slots' pool, rings, states and tails 3.4 GB; a request's staging 0.2 GB; the
+        temporaries of a decode chunk and of a 2,048-row prefill chunk well under a GB."""
+        from tony_tpu.models import phi4_flash as M
+
+        cfg = M.Phi4FlashConfig()
+        on = lambda tree: jax.tree.map(lambda a: _s(a.shape, a.dtype, chip), tree)
+        params = on(jax.eval_shape(lambda k: M.init(k, cfg), jax.random.PRNGKey(0)))
+        assert sum(a.size for a in jax.tree.leaves(params)) == 3_852_562_944
+        if program == "decode_chunk":
+            cache = on(jax.eval_shape(lambda: M._init_cache(cfg, self.S, self.MAX_LEN, self.PAGE, self.S * (self.MAX_LEN // self.PAGE) + 1)))
+            lowered = M.decode_steps.lower(params, cache, _s((self.S,), jnp.int32, chip), _s((2,), jnp.uint32, chip), cfg, 8)
+        else:
+            staging = on(jax.eval_shape(lambda: M._init_staging(cfg, self.MAX_LEN)))
+            lowered = M.prefill_chunk.lower(params, _s((1, 2048), jnp.int32, chip), staging, _s((), jnp.int32, chip), cfg)
+        compiled = lowered.compile()
+        memory = compiled.memory_analysis()
+        assert memory.temp_size_in_bytes < 1 << 30 and memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.5e9
+        text = compiled.as_text()
+        assert ("selective_step" in text) == (program == "decode_chunk") and ("selective_chunk" in text) == (program == "prefill_chunk")
+        assert not re.search(r"f32\[2048,(5120,16|16,5120)\]", text)

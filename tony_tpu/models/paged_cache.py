@@ -25,6 +25,13 @@ Host/device split follows the engine's: the allocator (free list,
 refcounts, prefix chain, LRU reuse pool) is pure host bookkeeping between
 steps; everything per-token stays in the jitted decode step.
 
+Which layers READ a pool is the family's: ``L`` counts the layers that WRITE
+keys and values, and most families give every layer that attends a layer of
+the pool. models/phi4_flash.py has ``L = 1`` and eight readers (one layer's
+keys and values, which seven later layers read again), and its rows hold a
+PAIR of kv heads (``Hkv`` pairs of ``2 x head_dim``): the pool, the rings and
+the chunk's write below know neither.
+
 No reference counterpart (the reference does not serve); the engine-level
 contract is tested against the dense-cache engine for parity and against
 HBM/prefill accounting for the capacity and sharing wins.

@@ -18,7 +18,8 @@ import sys
 #: experts only
 SERVABLE = ("tony_tpu.models.llama", "tony_tpu.models.minicpm_sala", "tony_tpu.models.exaone_moe",
             "tony_tpu.models.dots3_note", "tony_tpu.models.mistral4", "tony_tpu.models.olmo_hybrid",
-            "tony_tpu.models.granite_hybrid", "tony_tpu.models.solar_open2", "tony_tpu.models.falcon_h1")
+            "tony_tpu.models.granite_hybrid", "tony_tpu.models.solar_open2", "tony_tpu.models.falcon_h1",
+            "tony_tpu.models.phi4_flash")
 
 
 def presets() -> dict:

@@ -12,6 +12,14 @@ GQA/MQA is kernel-native: k/v keep their [B, Hkv, T, D] shape and the
 kernels alias q heads onto kv heads through BlockSpec index maps
 (head h reads kv head h // n_rep), so K/V HBM traffic stays at Hkv size.
 Only the XLA reference path broadcasts (``repeat_kv``).
+
+``chunk_prefill_attention`` is the serving prefill's call (key tiles on the
+grid over a request's staged keys). Differential attention (two softmax maps
+a head pair, subtracted) has no kernel of its own: ``differential_queries``
+widens the queries so that a cache of kv-head PAIRS, a whole tile of 128
+lanes where a head of 64 is half of one, gives both maps in ONE call of a
+one-map kernel (the end of this file; the decode calls are at the end of
+ops/decode_attention.py).
 """
 
 from __future__ import annotations
@@ -1120,3 +1128,62 @@ def chunk_prefill_attention(q, k, v, pos0, n_keys, layer=None, *, block_q: int =
         cost_estimate=pl.CostEstimate(flops=4 * H * T * Tk * d, transcendentals=H * T * Tk,
                                       bytes_accessed=(2 * H * T * d + 2 * Hkv * nq * Tk * d) * q.dtype.itemsize),
     )(meta, q, k, v)
+
+
+# -- differential attention (arXiv:2410.05258) over the one-map kernels -------------------------------
+#
+# A head PAIR subtracts two softmax maps, both applied to the pair's values: with the query heads in two stripes
+# (q1 the even heads, q2 the odd) and the kv heads likewise (k1, v1 even; k2, v2 odd), a pair's values are Vp =
+# [v1 | v2] (2 dh wide) and
+#
+#     A1 = softmax(q1 k1^T / sqrt(dh)) Vp     A2 = softmax(q2 k2^T / sqrt(dh)) Vp     o = norm(A1 - lam A2) (1 - lam0)
+#
+# Kv heads 2p and 2p + 1 lie side by side, so a cache that holds PAIRS, [.., Hkv / 2, positions, 2 dh] with Kp = [k1
+# | k2] and Vp = [v1 | v2], is the same bytes under another shape, and a pair of 2 x 64 is a whole tile of 128 lanes
+# where a head of 64 is half of one. A query widened to the pair with zeros where the OTHER stripe's key lies, [q1 |
+# 0] and [0 | q2], has q1 . k1 and q2 . k2 as its scores against Kp: both maps are then plain grouped-query
+# attention at heads of 2 dh over Hkv / 2 kv heads, ONE call of a one-map kernel that reads every key and value once
+# (the four calls q1k1v1, q1k1v2, q2k2v1, q2k2v2 at heads of dh read each twice). The kernels scale by (2 dh) ** -0.5,
+# so the widened queries carry sqrt(2). Nothing of the kernels knows: their one-map calls are the programs they were.
+
+def differential_queries(q: jax.Array, dtype=None) -> jax.Array:
+    """q [..., H, dh] (float32 where the caller can: the sqrt(2) is then rounded once, with the cast) -> the
+    widened queries [..., H, 2 dh] in `dtype`: an even head [q sqrt(2) | 0], an odd head [0 | q sqrt(2)]."""
+    H, dh = q.shape[-2:]
+    scaled = q.astype(jnp.float32) * 2.0 ** 0.5
+    even = (jnp.arange(H) % 2 == 0)[:, None]
+    wide = jnp.concatenate([jnp.where(even, scaled, 0.0), jnp.where(even, 0.0, scaled)], axis=-1)
+    return wide.astype(q.dtype if dtype is None else dtype)
+
+
+def differential_combine(o: jax.Array, lam, lam0, weight: jax.Array, eps: float) -> jax.Array:
+    """o [..., H, 2 dh], the one-map kernel's output for the widened queries (head 2a is A1 of pair-row a, head 2a + 1
+    its A2) -> RMSNorm over 2 dh of (A1 - lam A2), times `weight` and (1 - lam0): [..., H / 2, 2 dh] float32; row a
+    reads back as heads 2a, 2a + 1 of dh."""
+    *lead, H, wide = o.shape
+    maps = o.astype(jnp.float32).reshape(*lead, H // 2, 2, wide)
+    d = maps[..., 0, :] - lam * maps[..., 1, :]
+    return d * jax.lax.rsqrt(jnp.mean(d * d, axis=-1, keepdims=True) + eps) * weight.astype(jnp.float32) * (1.0 - lam0)
+
+
+def differential_chunk_prefill_attention(q, k, v, pos0, n_keys, layer=None):
+    """Both maps of a prefill chunk over a request's staged PAIRS, one call of `chunk_prefill_attention`: q [T, H, dh]
+    (float32 or the cache's type) at positions pos0 .. pos0 + T; k, v the staging of pairs [L, 1, Hkv / 2, Tk, 2 dh]
+    with its `layer` (or [Hkv / 2, Tk, 2 dh] without). Returns [T, H, 2 dh] for `differential_combine`."""
+    wide = differential_queries(q, k.dtype).transpose(1, 0, 2)
+    return chunk_prefill_attention(wide, k, v, pos0, n_keys, layer).transpose(1, 0, 2)
+
+
+def differential_window_prefill_attention(q, k, v, tail_k, tail_v, pos0, window: int):
+    """Both maps of a prefill chunk of a WINDOW layer, one call of `flash_attention` in its band: q [T, H, dh]; k, v [T,
+    Hkv / 2, 2 dh] the chunk's own pairs; tail_k, tail_v [Hkv / 2, window, 2 dh] the pairs of positions pos0 - window
+    .. pos0 - 1 (those below 0 do not exist: a segment of their own). Returns ([T, H, 2 dh], the keys [Hkv / 2, window
+    + T, 2 dh] and the values the call saw, tail first, for the caller to keep its next tail from)."""
+    T, H = q.shape[:2]
+    ek = jnp.concatenate([tail_k, k.transpose(1, 0, 2).astype(tail_k.dtype)], axis=1)
+    ev = jnp.concatenate([tail_v, v.transpose(1, 0, 2).astype(tail_v.dtype)], axis=1)
+    wide = differential_queries(q, ek.dtype).transpose(1, 0, 2)
+    eq = jnp.concatenate([jnp.zeros((H, window, wide.shape[2]), wide.dtype), wide], axis=1)   # flash wants as many queries as keys
+    seg = jnp.concatenate([pos0 - window + jnp.arange(window) >= 0, jnp.ones((T,), bool)]).astype(jnp.int32)[None]
+    o = flash_attention(eq[None], ek[None], ev[None], causal=True, window=window, segment_ids=seg)[0, :, window:]
+    return o.transpose(1, 0, 2), ek, ev

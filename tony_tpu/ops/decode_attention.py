@@ -12,6 +12,16 @@ writes it once a chunk), and the positions a decode chunk has produced but not
 yet written arrive in a staged block. Sliding-window models read the cache
 from ``max(0, len + 1 - window)``: whole slabs below the window are skipped.
 
+Whose keys a call reads is the CALLER's: the pool, the table, the staged rows
+and the current row are operands, and nothing here says that the layer that
+attends wrote them (models/phi4_flash.py hands one layer's to eight layers).
+Nor is a "head" a published head: ``Dh`` is what a row of the cache holds for a
+kv head, a whole tile of 128 lanes for most families and, for differential
+attention at heads of 64, a PAIR of kv heads side by side
+(``differential_paged_decode_attention`` and ``differential_ring_decode_attention``
+at the end of this file: both softmax maps of a head pair as one call, with
+queries widened by zeros; ops/attention.py says how).
+
 The page walk (``_kernel``). The grid is (S,), one instance a slot, run in
 order. An instance streams its slot's ``[Hkv, chunk, Dh]`` K and V SLABS (all
 kv heads a DMA; a page is one slab) through two VMEM buffers, one computed
@@ -739,3 +749,24 @@ def sparse_paged_decode_attention(
         staged_k.transpose(0, 2, 1, 3)[:, :, :, None], staged_v.transpose(0, 2, 1, 3)[:, :, :, None], kp, vp,
     )
     return o.reshape(S, H, Dh)
+
+
+# -- differential attention at decode: both maps in one read of the pairs ----------------------------------
+#
+# ops/attention.py says why: over a cache of kv-head PAIRS a head's two softmax maps are plain grouped-query
+# attention of queries widened with zeros, so a decode step's read of a page (or of a ring) is ONE call of the
+# one-map kernel that moves every key and value once. The output goes to `attention.differential_combine`.
+
+def differential_paged_decode_attention(q, kp, vp, lengths, page_table, layer, **rest):
+    """`paged_decode_attention` for both maps: q [S, H, dh]; kp, vp a pool of pairs [L, P, Hkv / 2, page_len, 2 dh];
+    cur_k, cur_v [S, Hkv / 2, 2 dh] and the staged rows [S, W, Hkv / 2, 2 dh] pairs too. Returns [S, H, 2 dh]."""
+    from tony_tpu.ops.attention import differential_queries
+
+    return paged_decode_attention(differential_queries(q, kp.dtype), kp, vp, lengths, page_table, layer, **rest)
+
+
+def differential_ring_decode_attention(q, rk, rv, lengths, layer, **rest):
+    """`ring_decode_attention` for both maps over rings of pairs [Lw, S, Hkv / 2, ring, 2 dh]. Returns [S, H, 2 dh]."""
+    from tony_tpu.ops.attention import differential_queries
+
+    return ring_decode_attention(differential_queries(q, rk.dtype), rk, rv, lengths, layer, **rest)
