@@ -16,7 +16,9 @@ shape is read against the same work: the indexer scores every position of a
 query's context (2 x 64 x 128 operations a query-key pair; a decode step reads a
 live slot's index keys once a full layer); decode attention in the absorbed form
 reads the rows the indexer chose (a window layer: the window's), 2 bytes a number
-of the latent and the rope key, once for all heads, against heads x rows x (2 x
+of the latent and the rope key (THE NUMBERS, 576 and 1088 a row, not the 640 and
+1152 lanes the pools lay them out in: the filling is the layout's cost, and a call
+is found at either width), once for all heads, against heads x rows x (2 x
 kv_rank + rope) x 2 operations; prefill attention in the expanded form computes
 the VISIBLE pairs (a query's chosen 2048, or all while its context is shorter; a
 window layer's 513), heads x (nope + rope + v) x 2 operations each, whatever the
@@ -27,9 +29,10 @@ none is run.
 
 from __future__ import annotations
 
+from counts import laid_out_widths
 from families.exaone_moe_counts import (  # noqa: F401 - the routed FFN's kernels, by this family's sizes
     expert_params, moe_decode_call, moe_decode_calls, moe_decode_operands, moe_prefill_call, moe_prefill_calls,
-    moe_prefill_operands, n_routed)
+    moe_prefill_operands, n_routed, routed_means)
 
 FULL, SLIDING = "full_attention", "sliding_attention"
 
@@ -39,9 +42,12 @@ def n_kind(s: dict, kind: str) -> int:
 
 
 def row(s: dict, kind: str) -> int:
-    """What a layer of `kind` caches a position, as the program lays it out: the latent and the rope key in whole lanes."""
+    """The numbers a layer of `kind` caches a position: the latent and the rope key (576 a full layer, 1088 a
+    window layer). The least a step could read of it, whatever the layout: the program's pools fill a row up
+    to whole lanes (640, 1152), and a call is found at any width between (counts.laid_out_widths; until PR 64
+    this was the laid-out width, and a pool without the filling would have gone unseen)."""
     p = "" if kind == FULL else "swa_"
-    return -(-(s[p + "kv_rank"] + s[p + "rope"]) // 128) * 128
+    return s[p + "kv_rank"] + s[p + "rope"]
 
 
 def attention_params(s: dict, kind: str) -> int:
@@ -78,19 +84,18 @@ def _pair_ops(s: dict, kind: str) -> int:
 
 def window_means(delta, engine: dict) -> dict | None:
     """`delta(name=..., where=...)`: the change of one of the replica's counters over the window."""
-    chunks, slots = delta(name="tony_serve_engine_chunks_total"), delta(name="tony_serve_decode_slots_total")
+    means = routed_means(delta, engine)
     seen, context = delta(name="tony_serve_visible_tokens_total"), delta(name="tony_serve_context_tokens_total")
-    rows = delta(name="tony_serve_expert_rows_total")
-    p_tokens, p_chunks = delta(name="tony_serve_prefill_tokens_total"), delta(name="tony_serve_prefill_chunks_total")
     p_sparse = delta(name="tony_serve_prefill_chunks_total", where={"path": ["sparse"]})
     scored = {phase: delta(name="tony_serve_index_positions_total", where={"phase": [phase]}) for phase in ("decode", "prefill")}
-    if None in (chunks, slots, seen, context, rows, p_tokens, p_chunks, p_sparse, *scored.values()) or not chunks or not slots:
+    if means is None or None in (seen, context, p_sparse, *scored.values()):
         return None
     h = engine.get("decode_chunk", 8)
-    return {"live_slots": slots / chunks, "visible_per_slot": seen / (slots * h), "context_per_slot": context / (slots * h),
-            "held_rows_per_step": rows / (chunks * h), "prefill_rows_per_chunk": p_tokens / p_chunks if p_chunks else 0.0,
+    steps, slot_steps = delta(name="tony_serve_engine_chunks_total") * h, delta(name="tony_serve_decode_slots_total") * h
+    p_chunks = delta(name="tony_serve_prefill_chunks_total")
+    return {**means, "visible_per_slot": seen / slot_steps, "context_per_slot": context / slot_steps,
             "sparse_chunk_share": p_sparse / p_chunks if p_chunks else 0.0,
-            "index_positions_per_step": scored["decode"] / (chunks * h),
+            "index_positions_per_step": scored["decode"] / steps,
             "index_pairs_per_chunk": scored["prefill"] / p_chunks if p_chunks else 0.0}
 
 
@@ -140,7 +145,7 @@ def _absorbed(s: dict, kind: str, rows: float) -> tuple[float, float]:
 
 
 def latent_decode_operands(s: dict, engine: dict) -> str:
-    return rf"\[1,{engine['slots']},{s['index_topk']},{row(s, FULL)}\]"
+    return rf"\[1,{engine['slots']},{s['index_topk']},{laid_out_widths(row(s, FULL))}\]"
 
 
 def latent_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
@@ -152,7 +157,7 @@ def latent_decode_calls(s: dict, engine: dict) -> tuple[str, int]:
 
 
 def latent_ring_decode_operands(s: dict, engine: dict) -> str:
-    return rf"\[{n_kind(s, SLIDING)},{engine['slots']},\d+,{row(s, SLIDING)}\]"
+    return rf"\[{n_kind(s, SLIDING)},{engine['slots']},\d+,{laid_out_widths(row(s, SLIDING))}\]"
 
 
 def latent_ring_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
@@ -166,8 +171,9 @@ def latent_ring_decode_calls(s: dict, engine: dict) -> tuple[str, int]:
 # -- prefill attention, expanded: the visible pairs of both kinds of layer --------------------------
 
 def latent_prefill_operands(s: dict, engine: dict) -> str:
-    """A full layer's call reads the request's staged rows [max_len, row], a window layer's [tail + chunk, row]."""
-    return rf"\[({engine['max_len']},{row(s, FULL)}|\d+,{row(s, SLIDING)})\]"
+    """A full layer's call reads the request's staged rows [max_len, W], a window layer's [tail + chunk, W]: W from
+    the row's numbers up to whole lanes."""
+    return rf"\[({engine['max_len']},{laid_out_widths(row(s, FULL))}|\d+,{laid_out_widths(row(s, SLIDING))})\]"
 
 
 def latent_prefill_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:

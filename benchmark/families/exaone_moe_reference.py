@@ -57,6 +57,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.scipy.special import ndtr, ndtri
 
 CONTROL = "fp8"
 #: by the dtype the program computes in: how near the edge of the chosen set (in units of the choosing score,
@@ -77,14 +78,37 @@ def seed_key(seed: int) -> jax.Array:
     return jax.random.fold_in(jax.random.PRNGKey(seed % 65536), seed // 65536)
 
 
+def choosing_bias(key: jax.Array, layers: int, experts: int, held: int) -> jax.Array:
+    """[layers, experts] float32: every chip's `held` experts get the SAME `held` values, in an order that
+    the seed draws anew for each chip and layer. The values are the quantiles (i + 1/2) / held of what the
+    bias was drawn from until PR 64, 0.1 x a normal truncated at two deviations, so an expert's bias
+    is distributed as before; what no longer depends on the seed is the work. A router's rows are as good as
+    random here, so how many of them reach an expert follows from its bias and the set of all biases, and
+    with it how many of a chip's slabs a decode step reads: 16 biases drawn freely left this replica 12.2
+    to 14.9 slabs a layer by the seed, and `serve_out_tok_s` followed them over 6% (PERF.md section 6,
+    PR 64). Every chip of the deployment now carries the same load, which is what a trained router's
+    bias is tuned for."""
+    if experts % held:
+        raise ValueError(f"{experts} experts do not divide into chips of {held}")
+    q = (jnp.arange(held, dtype=jnp.float32) + 0.5) / held
+    lo, hi = ndtr(-2.0), ndtr(2.0)
+    values = 0.1 * ndtri(lo + q * (hi - lo))
+
+    def layer(k):
+        return jax.vmap(lambda chip: jax.random.permutation(chip, values))(jax.random.split(k, experts // held)).reshape(experts)
+
+    return jax.lax.map(layer, jax.random.split(key, layers)).astype(jnp.float32)
+
+
 def init_weights(key: jax.Array, s: dict) -> dict:
     """The parameter tree in the layout tony_tpu/models/exaone_moe.py reads:
     `dense` a list of the leading dense layers' leaves, `layers` the routed
     layers' leaves stacked, `mtp` the prediction modules'. Truncated normal,
     fan-in scaled; norms at one; the router float32 and its bias small and not
-    zero (so that choosing by s + b and weighing by s can be told apart). A
-    stacked leaf is drawn a layer at a time: the float32 draw of every layer's
-    bank at once is as large again as the weights."""
+    zero (so that choosing by s + b and weighing by s can be told apart):
+    `choosing_bias`, whose values are the same for every seed and only their
+    order is the seed's. A stacked leaf is drawn a layer at a time: the float32
+    draw of every layer's bank at once is as large again as the weights."""
     d, v, dh, dt = s["d_model"], s["vocab"], s["head_dim"], jnp.dtype(s["dtype"])
     q, kv, fe, held = s["heads"] * dh, s["kv_heads"] * dh, s["d_expert"], s["held"][1]
     ks = iter(jax.random.split(key, 8 + 8 * s["dense_layers"] + 16 * (1 + s["mtp_layers"])))
@@ -109,7 +133,7 @@ def init_weights(key: jax.Array, s: dict) -> dict:
         fs = fe * s["shared_experts"]
         return {**attention(n),
                 "router": stack(n, d, s["num_experts"], fan_in=d, dtype=jnp.float32),
-                "router_bias": stack(n, s["num_experts"], fan_in=1.0, dtype=jnp.float32, scale=0.1),
+                "router_bias": choosing_bias(next(ks), n, s["num_experts"], held),
                 "ws_gate": stack(n, d, fs, fan_in=d), "ws_up": stack(n, d, fs, fan_in=d), "ws_down": stack(n, fs, d, fan_in=fs),
                 "we_gate": stack(n, held, d, fe, fan_in=d), "we_up": stack(n, held, d, fe, fan_in=d),
                 "we_down": stack(n, held, fe, d, fan_in=fe)}

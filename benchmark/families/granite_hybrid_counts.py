@@ -28,24 +28,18 @@ shape is read against the same work and a share cannot pass 100:
   type, B and C once, the state once each way. The state's two products run in
   float32 at full precision (several passes of the matrix unit) and are counted
   as one: the share is low by construction;
-- the routed FFN in a decode step reads the slabs of the held experts that some
-  live slot's row chose, as the program COUNTED them on the device
-  (`tony_serve_experts_touched_total`, summed over the layers), each once, and the
-  rows in and out; not the expectation under even routing, which is what the two
-  older routed families' counts take and why theirs can read past 100 (ROADMAP
-  R-B11);
-- the routed FFN in a prefill chunk: 6 D F operations a row that lands on a held
-  expert (top_k x held / E of the chunk's rows a layer under even routing:
-  prefill's rows are not counted on the device) and every held expert's slabs once
-  a layer (with 256 rows or more no held expert goes unchosen).
+- the routed FFN, both phases, as families/exaone_moe_counts.py counts it for every
+  routed family (this family's was the first count to read the slabs from the
+  program's `tony_serve_experts_touched_total`; since PR 64 that count lives there).
 
 No traffic between the chips that share a layer is counted: none is run.
 """
 
 from __future__ import annotations
 
-from families.exaone_moe_counts import (  # noqa: F401 - a bank's shape in a trace and the programs that read it are that family's
-    expert_params, moe_decode_calls, moe_decode_operands, moe_prefill_calls, moe_prefill_operands)
+from families.exaone_moe_counts import (  # noqa: F401 - the routed FFN's count, by this family's sizes (every layer has one)
+    expert_params, moe_decode_call, moe_decode_calls, moe_decode_operands, moe_prefill_call, moe_prefill_calls, moe_prefill_operands)
+from families.exaone_moe_counts import routed_means as window_means  # noqa: F401 - this family's kernels need no mean but the routed FFN's
 
 MAMBA, ATTENTION = "mamba", "attention"
 #: positions a block of the chunked form counted here (the program's own: tony_tpu/ops/ssd.BLOCK)
@@ -94,18 +88,6 @@ def state_bytes(s: dict) -> int:
     return 4 * s["ssm_heads"] * s["ssm_head_dim"] * s["ssm_state"]
 
 
-def window_means(delta, engine: dict) -> dict | None:
-    """`delta(name=..., where=...)`: the change of one of the replica's counters over the window."""
-    chunks, slots = delta(name="tony_serve_engine_chunks_total"), delta(name="tony_serve_decode_slots_total")
-    rows, touched = delta(name="tony_serve_expert_rows_total"), delta(name="tony_serve_experts_touched_total")
-    p_tokens, p_chunks = delta(name="tony_serve_prefill_tokens_total"), delta(name="tony_serve_prefill_chunks_total")
-    if None in (chunks, slots, rows, touched, p_tokens, p_chunks) or not chunks or not slots:
-        return None
-    steps = chunks * engine.get("decode_chunk", 8)
-    return {"live_slots": slots / chunks, "held_rows_per_step": rows / steps, "touched_per_step": touched / steps,
-            "prefill_rows_per_chunk": p_tokens / p_chunks if p_chunks else 0.0}
-
-
 # -- the recurrence in a decode step: every live slot's state, read and written once a `mamba` layer --
 
 def ssd_decode_operands(s: dict, engine: dict) -> str:
@@ -141,19 +123,3 @@ def ssd_prefill_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
 
 def ssd_prefill_calls(s: dict, engine: dict) -> tuple[str, int]:
     return "prefill_chunk", 1
-
-
-# -- the routed FFN: every layer has one -------------------------------------------------------------
-
-def moe_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
-    """One decode step, all layers: `held_rows_per_step` and `touched_per_step` are summed over the layers
-    already, both counted on the device from live slots' rows."""
-    rows = means["held_rows_per_step"]
-    return 2.0 * expert_params(s) * rows, 2.0 * (means["touched_per_step"] * expert_params(s) + 2 * rows * s["d_model"])
-
-
-def moe_prefill_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
-    """One prefill chunk, all layers: the rows that land on a held expert under even routing, and every held expert's slabs once a layer."""
-    layers = len(s["layer_types"])
-    rows = means["prefill_rows_per_chunk"] * s["top_k"] * s["held"][1] / s["num_experts"]
-    return 2.0 * expert_params(s) * rows * layers, 2.0 * layers * (s["held"][1] * expert_params(s) + 2 * rows * s["d_model"])

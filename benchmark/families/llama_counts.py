@@ -77,7 +77,11 @@ def flash_operands(s: dict, seq: int) -> str:
 
 
 def flash_layer_step(s: dict, rows: int, seq: int) -> list[tuple[float, float]]:
-    """The flash calls one layer makes in one training step: the forward, the
-    forward once more (full remat runs it again inside the backward, and that
-    call's time is in the device time), the backward."""
-    return [flash_call(s, rows, seq, False), flash_call(s, rows, seq, False), flash_call(s, rows, seq, True)]
+    """The flash calls one layer makes in one training step, as the step runs them: the forward ONCE
+    (since PR 47 the loop saves `flash_o` / `flash_lse`, so no backward runs it again) and ONE backward of
+    five products (since PR 52): seven products of a pair where the count held nine until PR 64. The
+    reader (readers/kernel_roofline.py) divides the calls it finds in the trace by the length of this
+    list, so it has one entry a call the step makes and no more: a step under a rung that runs the
+    forward again makes three calls a layer for these two and would read 3/2 high until this list
+    follows the rung (PERF.md section 7)."""
+    return [flash_call(s, rows, seq, False), flash_call(s, rows, seq, True)]

@@ -13,10 +13,14 @@ sizes (every layer routed: `dense_layers` 0).
 
 Counted is THE MATHEMATICS, each array once, so that a later kernel of another
 shape is read against the same work. Decode attention in the absorbed form reads
-EVERY row of a live slot's context a layer, as the pool lays a row out (the latent,
-the rope key and the filling up to whole lanes: that is what a page's fetch moves),
-2 bytes a number, once for all heads, against heads x rows x (row + kv_rank) x 2
-operations (the scores over the whole row, the weighted sum over the latent).
+EVERY row of a live slot's context a layer: THE NUMBERS of a row, the latent and
+the rope key (kv_rank + rope: 320), 2 bytes each, once for all heads, whatever the
+pool lays out beside them (today's fills a row up to 384 lanes and a page's fetch
+moves the filling too: that sixth is the layout's cost, and a count that took the
+laid-out width would read 85 before and after a pool lost it), against heads x
+rows x (2 x kv_rank + rope) x 2 operations (the scores over the numbers, the
+weighted sum over the latent). A call is FOUND at any width from the numbers' to
+whole lanes' (counts.laid_out_widths).
 Prefill attention in the expanded form computes the causal pairs a chunk's queries
 see, heads x (nope + rope + v) x 2 operations each, whatever the kernel computes
 under its mask; what building keys and values from the latent costs is not
@@ -25,14 +29,16 @@ counted. No traffic between the chips that share a layer is counted: none is run
 
 from __future__ import annotations
 
+from counts import laid_out_widths
 from families.exaone_moe_counts import (  # noqa: F401 - the routed FFN's kernels, by this family's sizes
     expert_params, moe_decode_call, moe_decode_calls, moe_decode_operands, moe_prefill_call, moe_prefill_calls,
-    moe_prefill_operands, n_routed)
+    moe_prefill_operands, n_routed, routed_means)
 
 
 def row(s: dict) -> int:
-    """What a layer caches a position, as the program lays it out: the latent and the rope key in whole lanes."""
-    return -(-(s["kv_rank"] + s["rope"]) // 128) * 128
+    """The numbers a layer caches a position: the latent and the rope key. The least a step could read of it,
+    whatever the layout (until PR 64 this was the width in whole lanes, 384 for 320)."""
+    return s["kv_rank"] + s["rope"]
 
 
 def attention_params(s: dict) -> int:
@@ -60,40 +66,49 @@ def _pair_ops(s: dict) -> int:
 
 def window_means(delta, engine: dict) -> dict | None:
     """`delta(name=..., where=...)`: the change of one of the replica's counters over the window."""
-    chunks, slots = delta(name="tony_serve_engine_chunks_total"), delta(name="tony_serve_decode_slots_total")
-    context, rows = delta(name="tony_serve_context_tokens_total"), delta(name="tony_serve_expert_rows_total")
-    p_tokens, p_chunks = delta(name="tony_serve_prefill_tokens_total"), delta(name="tony_serve_prefill_chunks_total")
-    pairs = delta(name="tony_serve_prefill_pairs_total")
-    if None in (chunks, slots, context, rows, p_tokens, p_chunks, pairs) or not chunks or not slots:
+    means = routed_means(delta, engine)
+    context, pairs = delta(name="tony_serve_context_tokens_total"), delta(name="tony_serve_prefill_pairs_total")
+    if means is None or None in (context, pairs):
         return None
-    h = engine.get("decode_chunk", 8)
-    return {"live_slots": slots / chunks, "context_per_slot": context / (slots * h), "held_rows_per_step": rows / (chunks * h),
-            "prefill_rows_per_chunk": p_tokens / p_chunks if p_chunks else 0.0,
-            "prefill_pairs_per_chunk": pairs / p_chunks if p_chunks else 0.0}
+    slot_steps = delta(name="tony_serve_decode_slots_total") * engine.get("decode_chunk", 8)
+    p_chunks = delta(name="tony_serve_prefill_chunks_total")
+    return {**means, "context_per_slot": context / slot_steps, "prefill_pairs_per_chunk": pairs / p_chunks if p_chunks else 0.0}
 
 
 # -- decode attention, absorbed, over every row of the context through the page table ---------------
+# `latent_rows_decode` is what latent_paged_decode_roofline_pct.serve reads since PR 64: the numbers of a row.
 
-def latent_paged_decode_operands(s: dict, engine: dict) -> str:
-    """The whole latent pool [layers, pages, page_len, row]: only this call takes it."""
-    return rf"\[{s['layers']},\d+,{engine['page_len']},{row(s)}\]"
+def latent_rows_decode_operands(s: dict, engine: dict) -> str:
+    """The whole latent pool [layers, pages, page_len, W], W from the row's numbers up to whole lanes: only this call takes it."""
+    return rf"\[{s['layers']},\d+,{engine['page_len']},{laid_out_widths(row(s))}\]"
 
 
-def latent_paged_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
-    """One decode step, every layer: the live slots' context rows, each once for all heads."""
+def latent_rows_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
+    """One decode step, every layer: the live slots' context rows, the numbers of each once for all heads."""
     rows = means["live_slots"] * means["context_per_slot"] * s["layers"]
     return 2.0 * s["heads"] * rows * (row(s) + s["kv_rank"]), 2.0 * row(s) * rows
 
 
-def latent_paged_decode_calls(s: dict, engine: dict) -> tuple[str, int]:
+def latent_rows_decode_calls(s: dict, engine: dict) -> tuple[str, int]:
     return "decode_steps", engine.get("decode_chunk", 8)
+
+
+# The same call counted as the pool LAYS A ROW OUT (the numbers filled up to whole lanes: 384 for 320), which is
+# what the metric read until PR 64. No metric reads these three since; tests/test_mistral4.py pins their numbers,
+# and a `benchmark` PR may edit no file outside benchmark/: the next PR that may deletes them with that test's lines.
+latent_paged_decode_operands, latent_paged_decode_calls = latent_rows_decode_operands, latent_rows_decode_calls
+
+
+def latent_paged_decode_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:
+    rows, laid_out = means["live_slots"] * means["context_per_slot"] * s["layers"], -(-row(s) // 128) * 128
+    return 2.0 * s["heads"] * rows * (laid_out + s["kv_rank"]), 2.0 * laid_out * rows
 
 
 # -- prefill attention, expanded: the causal pairs ---------------------------------------------------
 
 def latent_prefill_operands(s: dict, engine: dict) -> str:
-    """A layer's call reads the request's staged rows [max_len, row]."""
-    return rf"\[{engine['max_len']},{row(s)}\]"
+    """A layer's call reads the request's staged rows [max_len, W], W from the row's numbers up to whole lanes."""
+    return rf"\[{engine['max_len']},{laid_out_widths(row(s))}\]"
 
 
 def latent_prefill_call(s: dict, engine: dict, means: dict) -> tuple[float, float]:

@@ -33,20 +33,21 @@ shape is read against the same work and a share cannot pass 100:
   full precision (several passes of the matrix unit) and count once: the share is
   low by construction. Bytes: q, k, v in and o out in the activations' type, the
   log-decay a channel in float32, the state once each way;
-- the routed FFN as families/granite_hybrid_counts.py counts it: a decode step
-  reads the slabs of the held experts that some live slot's row chose, as the
-  program COUNTED them on the device (`tony_serve_experts_touched_total`), each
-  once, and the rows in and out; a prefill chunk 6 D F operations a row that lands
-  on a held expert under even routing and every held expert's slabs once a layer.
+- the routed FFN, both phases, as families/exaone_moe_counts.py counts it for every
+  routed family: a decode step reads the slabs of the held experts that some live
+  slot's row chose, as the program COUNTED them on the device
+  (`tony_serve_experts_touched_total`), each once; a prefill chunk 6 D F operations
+  a row that lands on a held expert under even routing and every held expert's
+  slabs once a layer.
 
 No traffic between the chips that share a layer is counted: none is run.
 """
 
 from __future__ import annotations
 
-from families.exaone_moe_counts import (  # noqa: F401 - a bank's shape in a trace and the programs that read it are that family's
-    expert_params, moe_decode_calls, moe_decode_operands, moe_prefill_calls, moe_prefill_operands)
-from families.granite_hybrid_counts import moe_decode_call, moe_prefill_call, window_means  # noqa: F401 - the slabs from the counted touches
+from families.exaone_moe_counts import (  # noqa: F401 - the routed FFN's count, by this family's sizes (every layer has one)
+    expert_params, moe_decode_call, moe_decode_calls, moe_decode_operands, moe_prefill_call, moe_prefill_calls, moe_prefill_operands)
+from families.exaone_moe_counts import routed_means as window_means  # noqa: F401 - this family's kernels need no mean but the routed FFN's
 
 KDA, ATTENTION = "kda", "attention"
 #: positions a block of the chunked form counted here (the program's own: tony_tpu/ops/kda.BLOCK)

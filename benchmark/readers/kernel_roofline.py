@@ -12,7 +12,13 @@ def read(ctx, kernel, match):
     `match` is a pattern over that line and the kernel's own operand shapes,
     worked out from the cell's sizes, tell the kernels of one step apart. Both
     the shapes and the count are the family's, found by the kernel's name:
-    `<kernel>_operands` and `<kernel>_layer_step` of its counts."""
+    `<kernel>_operands` and `<kernel>_layer_step` of its counts.
+
+    The layer-steps traced are the calls of EVERY instruction found, added up, over the calls a layer
+    makes a step (the length of `layer_step`'s list): layers rolled into one loop run one instruction
+    layers x steps times, layers unrolled run an instruction each, steps times, and both read alike.
+    (Until PR 64 the least count of any one instruction stood for layers x steps, and an unrolled
+    step read a quarter of its share: PERF.md section 6.)"""
     tr, run = ctx.get("trace"), ctx["run"]
     if not tr or ctx["device"].get("platform") != "tpu":
         return None
@@ -28,12 +34,11 @@ def read(ctx, kernel, match):
     if not found:
         return None
     device_s = sum(found.values())
-    # every kernel of the layer runs once for each layer and step traced
-    calls = min(tr["op_count"][n] for n in found)
     peak = counts.peak_for(ctx["device"]["kind"], run.peaks)
     # rows of the batch one chip's attention sees: the batch is split over
     # the chips unless the layout replicates it (`batch_shards` in the file)
     rows = w["batch_size"] // w.get("batch_shards", run.chips)
-    per_layer_step = sum(counts.roofline_seconds(ops, nbytes, peak)
-                         for ops, nbytes in layer_step(run.sizes, rows, w["seq_len"]))
-    return 100.0 * per_layer_step * calls / device_s
+    calls = layer_step(run.sizes, rows, w["seq_len"])
+    layer_steps = sum(tr["op_count"][n] for n in found) / len(calls)
+    per_layer_step = sum(counts.roofline_seconds(ops, nbytes, peak) for ops, nbytes in calls)
+    return 100.0 * per_layer_step * layer_steps / device_s

@@ -5,7 +5,9 @@ as it closes (`serve_cell.drive`: `snap0`, `snap1`), so whatever the program
 registers is here without an edit to the harness. A term names one instrument,
 the field to take (`value` of a counter, `sum` or `count` of a histogram) and
 optionally the label values to keep (`where`) or to leave out (`where_not`),
-each as {label: [values]}; the samples kept are added up.
+each as {label: [values]}; the samples kept are added up. `scale_size` names one of
+the cell's own sizes (a key of `run.sizes`, a dotted path into a list: "held.1", the
+experts this replica holds) to multiply by, for a quotient whose unit is the cell's.
 """
 
 
@@ -20,11 +22,29 @@ def total(snap, name, field="value", where=None, where_not=None):
     return None
 
 
-def read(ctx, num, den, scale=1.0):
+def size(sizes: dict, path: str):
+    """The size at a dotted path; None where the cell's family has no such size (a sweep of an unlisted
+    workload scans every metric of its kind, another family's among them)."""
+    for key in path.split("."):
+        if isinstance(sizes, (list, tuple)):
+            sizes = sizes[int(key)]
+        elif key in sizes:
+            sizes = sizes[key]
+        else:
+            return None
+    return sizes
+
+
+def read(ctx, num, den, scale=1.0, scale_size=None):
     """scale x (change of `num`) / (change of `den`): a mean in ms (a histogram's
     sum over its count, x 1000), a ratio of counters, or a share (x 100). None
     when either snapshot lacks either instrument or `den` did not move."""
     d = ctx["drive"]
+    if scale_size is not None:
+        own = size(ctx["run"].sizes, scale_size)
+        if own is None:
+            return None
+        scale = scale * own
     ends = [total(d[snap], **term) for term in (num, den) for snap in ("snap0", "snap1")]
     if any(v is None for v in ends) or ends[3] <= ends[2]:
         return None

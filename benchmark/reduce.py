@@ -38,6 +38,11 @@ _COLLECTIVE_CALLED = re.compile(r"^(all-reduce|all-gather|all-to-all|reduce-scat
 CONTAINER = re.compile(r"^(while|conditional|call)$")
 
 
+#: host events that explain no gap: a thread asleep does nothing, and the replica's control threads poll by
+#: sleeping (entry/serve_replica.py: 20 and 50 ms), so their sleeps, being short, would name every gap
+ASLEEP = ("$time sleep",)
+
+
 def opcode(name: str) -> str:
     """The event's own operation. A bare name (`all-gather.12`, `%fusion.3`)
     stands for itself without its number."""
@@ -128,16 +133,20 @@ def summarise(device_ops: dict[str, list[tuple[str, float, float]]],
     planes = {p: ev for p, ev in planes.items() if ev}
     if not planes:
         return {"busy_s": 0.0, "window_s": 0.0, "planes": 0}
+    host_events = [h for h in host_events if h[0] not in ASLEEP]
     t0 = min(s for ev in planes.values() for _, s, _, _ in ev)
     t1 = max(e for ev in planes.values() for _, _, e, _ in ev)
     n = len(planes)
-    busy_s = exposed_s = coll_s = 0.0
+    busy_s = exposed_s = coll_s = in_modules_s = covered_s = 0.0
     op_time: dict[str, float] = {}
     op_count: dict[str, int] = {}
     gap_time: dict[str, float] = {}
-    for ev in planes.values():
+    for plane, ev in planes.items():
         busy = union([(s, e) for _, s, e, _ in ev])
         busy_s += total(busy) / n
+        programs = union([(s, e) for _, s, e in (modules or {}).get(plane, [])])
+        in_modules_s += total(programs)
+        covered_s += total(programs) - total(subtract(programs, busy))
         coll = [(s, e) for name, s, e, op in ev if is_collective(name, op)]
         rest = [(s, e) for name, s, e, op in ev if not is_collective(name, op)]
         coll_s += total(union(coll)) / n
@@ -158,6 +167,9 @@ def summarise(device_ops: dict[str, list[tuple[str, float, float]]],
             "idle_gaps": [[k, v] for k, v in sorted(gap_time.items(), key=lambda kv: -kv[1])[:10]],
         },
     }
+    if in_modules_s:
+        # the part of the programs' own time (the "XLA Modules" line) in which some leaf operation ran
+        out["module_cover"] = covered_s / in_modules_s
     if modules:
         out["modules"] = {}
         for ev in modules.values():
@@ -193,6 +205,32 @@ def describe(path: str) -> dict:
 
     data = ProfileData.from_file(path)
     return {p.name: {ln.name: len(list(ln.events)) for ln in p.lines} for p in data.planes}
+
+
+#: the least part of its programs' time that a capture's leaf operations may cover. A program runs its operations
+#: back to back (0.9999 in the three traced four-chip training runs of PR 64), and a capture's operations lie inside its programs,
+#: so the cover is at least the busy share of the window: 0.981-0.999 in six whole serving captures of PR 64. Now
+#: and then a capture comes back with a program that stood still under the profiler, or with a third or more of the
+#: operations' events missing, while the programs' line is whole (1.787 s of events in a 3.035 s window at PR 44,
+#: 1.636 of 3.006 at PR 64: PERF.md section 6), and every share that sets the traced executions against the
+#: operations' time then reads far over 100. Such a run fails as a run
+MIN_MODULE_COVER = 0.75
+
+
+def reduced_or_fail(search_dir: str, work: str, cpu_rehearsal: bool = False) -> dict:
+    """The reduced trace of a traced run, or JobFailed where there is none or the capture lost its events:
+    the run is then made again, and no share of it is read."""
+    import jobs
+
+    tr = reduce_in_child(search_dir, work)
+    if tr is None or not (tr.get("busy_s") or cpu_rehearsal):
+        raise jobs.JobFailed("the traced run left no device trace to reduce")
+    cover = tr.get("module_cover")
+    if cover is not None and cover < MIN_MODULE_COVER:
+        raise jobs.JobFailed(f"the capture is not whole (events lost, or a program stood still): leaf operations cover {cover:.3f} of its programs' time "
+                             f"(busy {tr['busy_s']:.3f} s of a {tr['window_s']:.3f} s window; a whole capture covers "
+                             f"{MIN_MODULE_COVER} or more)")
+    return tr
 
 
 def reduce_in_child(search_dir: str, work: str) -> dict | None:

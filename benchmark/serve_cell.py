@@ -26,6 +26,13 @@ import jobs
 import reduce as trace_reduce
 import spec
 import traffic as T
+from chipside import write_json
+
+
+#: how long the replica may take over a registry snapshot or a /stats poll: a capture's export holds much
+#: of its interpreter for 24-28 s at 64 slots and longer at 256 (PERF.md section 7), and `run` gives the
+#: same export 60 s to end
+SNAPSHOT_WAIT_S = 120.0
 
 
 def get_json(url: str, timeout: float = 10.0) -> dict:
@@ -118,18 +125,28 @@ class Fleet:
         again = client.post(first[0], n_new, "warm-again")
         return again == first[1] and len(again) == n_new
 
-    def snapshot(self, tag: str) -> dict | None:
-        ctl = os.path.join(self.run.out_dir, "ctl")
-        with open(os.path.join(ctl, f"snap.{tag}.req"), "w") as f:
+    def ask_snapshot(self, tag: str) -> None:
+        """Ask the replica for its registry: entry/serve_replica.py answers on a thread of its own."""
+        with open(os.path.join(self.run.out_dir, "ctl", f"snap.{tag}.req"), "w") as f:
             f.write("{}")
-        path = os.path.join(ctl, f"snap.{tag}.json")
-        deadline = time.time() + 5
-        while time.time() < deadline:
+
+    def snapshot(self, tag: str, asked: float | None = None, wait_s: float = SNAPSHOT_WAIT_S) -> dict:
+        """The registry the replica wrote for `tag`, asked for here unless it was at `asked`. An untraced
+        run's comes in milliseconds; a traced run's may come once the capture's export lets the replica's
+        threads run, which is why the wait is as long as an export lasts. None within `wait_s` of the
+        asking fails the RUN: a run whose counters cannot be read is made again, and no metric of it
+        reads nothing for the harness's reason."""
+        if asked is None:
+            asked = time.time()
+            self.ask_snapshot(tag)
+        path = os.path.join(self.run.out_dir, "ctl", f"snap.{tag}.json")
+        while True:
             got = jobs.read_json(path)
             if got is not None:
                 return got
+            if time.time() - asked > wait_s:
+                raise self.fail(f"the replica gave no registry snapshot {tag!r} within {wait_s:.0f} s")
             time.sleep(0.02)
-        return None
 
     def stop(self) -> tuple[int, bool]:
         rc = jobs.stop_job(self.proc, self.run.staging, "serve", wait_s=150)
@@ -146,14 +163,14 @@ def drive(fleet: Fleet, run, seconds: float, traffic: dict, seed: int, trace: bo
     a = traffic["arrivals"]
     if a["process"] == "closed":
         client.run_closed(planned, a["clients"], a.get("ramp_s", 0.0))
-    stats, stop_poll = [], threading.Event()
+    stats, poll_errors, stop_poll = [], [], threading.Event()
 
     def poll() -> None:
         while not stop_poll.wait(0.5):
             try:
-                stats.append({"t": time.time(), **get_json(fleet.replica + "/stats", 5)})
-            except OSError:
-                pass
+                stats.append({"t": time.time(), **get_json(fleet.replica + "/stats", SNAPSHOT_WAIT_S)})
+            except OSError as e:
+                poll_errors.append(f"{type(e).__name__}: {e}")
 
     snap0 = fleet.snapshot("open")
     t_open = time.time()
@@ -165,21 +182,23 @@ def drive(fleet: Fleet, run, seconds: float, traffic: dict, seed: int, trace: bo
         sender.start()
     if trace:
         time.sleep(min(2.0, seconds / 4))
-        with open(os.path.join(run.out_dir, "ctl", "trace.req"), "w") as f:
-            json.dump({"seconds": min(3.0, seconds / 2)}, f)
+        write_json(os.path.join(run.out_dir, "ctl", "trace.req"), {"seconds": min(3.0, seconds / 2)})
     time.sleep(max(0.0, t_open + seconds - time.time()))
+    # the window closes HERE: the registry is asked for, the clients and the poll are stopped at once, and
+    # only then is the answer waited for, so an answer that a capture's export delays lengthens no window
     t_close = time.time()
-    snap1 = fleet.snapshot("close")
+    fleet.ask_snapshot("close")
     client.stop.set()
     stop_poll.set()
+    snap1 = fleet.snapshot("close", asked=t_close)
+    if seconds >= 1.0 and not any(t_open <= s["t"] <= t_close for s in stats):  # the poll's period is 0.5 s
+        raise fleet.fail(f"the replica answered no /stats poll inside the window ({poll_errors[-1:]})")
     if sender is not None:
         sender.join(5)
-        left = client.join(traffic.get("drain_s", 90))
-    else:
-        left = client.join(traffic.get("drain_s", 90))  # each caller finishes the request it is in
+    left = client.join(traffic.get("drain_s", 90))  # a closed loop's callers each finish the request they are in
     return {"t_open": t_open, "t_close": t_close, "records": list(client.records), "stats": stats,
             "snap0": snap0, "snap1": snap1, "left_in_flight": left, "planned": len(planned),
-            "schedule": T.schedule_stats(planned)}
+            "schedule": T.schedule_stats(planned), "poll_errors": len(poll_errors)}
 
 
 def summarise(d: dict, seconds: float, limits: dict, timeout_ms: float = 120000.0) -> dict:
@@ -207,6 +226,25 @@ def summarise(d: dict, seconds: float, limits: dict, timeout_ms: float = 120000.
     }
 
 
+def window_means(run, d: dict) -> dict | None:
+    """The window's means as the family's counts form them from the two registry snapshots (rows and
+    slabs of a routed FFN a step, context a slot, ...): said in every run, traced or not, so that a
+    seed whose weights make more or less work of the same offered traffic is seen beside its rate.
+    None for a family whose counts form none."""
+    import families
+    from readers.registry_delta import total
+
+    form = getattr(families.counts(run.sizes), "window_means", None)
+    if form is None:
+        return None
+
+    def delta(**term):
+        ends = [total(d[snap], **term) for snap in ("snap0", "snap1")]
+        return None if None in ends else ends[1] - ends[0]
+
+    return form(delta, run.w["engine"])
+
+
 def run(run) -> dict:
     w = run.w
     say = jobs.say
@@ -223,6 +261,9 @@ def run(run) -> dict:
         deadline = time.time() + 60
         while not os.path.exists(os.path.join(run.out_dir, "ctl", "trace.done")) and time.time() < deadline:
             time.sleep(0.2)
+        done = jobs.read_json(os.path.join(run.out_dir, "ctl", "trace.done")) or {}
+        at = {k: round(done[k] - d["t_open"], 2) for k in ("start", "stop", "end") if k in done}
+        say(f"[serve] the capture, in seconds after the window opened: {json.dumps(at)} (`stop` -> `end` is the export)")
     holders, others = jobs.chip_holders(run.staging)
     off_jax = all("serve_replica.py" in c for c in holders)
     device = jobs.read_json(os.path.join(run.out_dir, "device.json"))
@@ -235,8 +276,15 @@ def run(run) -> dict:
         f"interrupt -> exit {rc}, drained cleanly={drained}")
     say(f"[serve] the schedule offered (draw_seed {w['traffic'].get('draw_seed', 0)}, the same for every --seed): "
         f"{json.dumps(d['schedule'])}")
+    say(f"[serve] registry snapshots read {d['snap0']['t'] - d['t_open']:+.3f} s of the window's opening and "
+        f"{d['snap1']['t'] - d['t_close']:+.3f} s of its close; /stats polls answered {len(d['stats'])}, failed {d['poll_errors']}")
     say(f"[serve] window {d['t_close'] - d['t_open']:.2f}s: {json.dumps(s)}; left in flight after the wait: "
         f"{d['left_in_flight']}; generator lateness mean {s['lateness']['mean_ms']:.2f} ms max {s['lateness']['max_ms']:.2f} ms")
+
+    means = window_means(run, d)
+    if means is not None:
+        say(f"[serve] the window's means by the family's counts (what the seed's weights made of the offered work): "
+            f"{json.dumps({k: round(v, 4) for k, v in means.items() if isinstance(v, (int, float))})}")
 
     # the comparison: a child of its own, now that the chip is free
     rng = np.random.default_rng(run.seed + 3)
@@ -267,9 +315,8 @@ def run(run) -> dict:
     dev_line["memory_peak_bytes"] = device.get("memory_peak_bytes", 0)
     breakdown = None
     if run.trace:
-        tr = ctx["trace"] = trace_reduce.reduce_in_child(os.path.join(run.out_dir, "trace"), run.work)
-        if tr is None or not (tr.get("busy_s") or run.cpu_rehearsal):
-            raise jobs.JobFailed("the traced run left no device trace to reduce")
+        tr = ctx["trace"] = trace_reduce.reduced_or_fail(os.path.join(run.out_dir, "trace"), run.work, run.cpu_rehearsal)
+        say(f"[trace] leaf operations cover {tr.get('module_cover')} of the captured programs' time")
         dev_line.update(busy_s=tr["busy_s"], window_s=tr["window_s"])
         breakdown = tr.get("breakdown")
     return {"correct": bool(ok), "attempted": s["attempted"], "failed": s["failed"],

@@ -10,9 +10,11 @@ import importlib
 import json
 import math
 import os
+import re
 import shutil
 import subprocess
 import sys
+import time
 import types
 
 import numpy as np
@@ -323,6 +325,32 @@ def test_summarise_busy_idle_and_exposed_collectives():
     assert s["breakdown"]["idle_gaps"][0] == ["np.asarray", pytest.approx(0.5)]
     assert len(s["breakdown"]["device_ops"]) <= 10 and s["modules"]["jit_step(1)"] == [2.0, 1.0]
     assert R.summarise({}, [])["busy_s"] == 0.0
+    # chip 0's programs ran 0-2 and 3-4 and some operation ran in all of it; chips 1 and 2 have no programs' line
+    assert s["module_cover"] == pytest.approx(1.0)
+    # a thread asleep names no gap, however short its sleep (the replica's control threads poll by sleeping)
+    s = R.summarise(ops, host + [("$time sleep", 2.45, 2.47)], None)
+    assert s["breakdown"]["idle_gaps"][0] == ["np.asarray", pytest.approx(0.5)]
+
+
+@pytest.mark.parametrize("kept,whole", [(1.0, True), (0.9, True), (0.6, False), (0.3, False)])
+def test_a_capture_that_lost_its_operations_events_fails_the_run(monkeypatch, kept, whole):
+    """Ten programs of 0.1 s, back to back operations in each; a capture that kept only `kept` of the
+    operations' events (the programs' line whole) has shares that read 1 / kept too high: the run fails."""
+    import jobs
+
+    programs = [("jit_decode_steps(3)", 0.2 * i, 0.2 * i + 0.1) for i in range(10)]
+    ops = [("fusion.1", s, s + 0.1 * kept) for _, s, _ in programs]
+    summary = R.summarise({"/device:TPU:0": ops}, [], {"/device:TPU:0": programs})
+    assert summary["module_cover"] == pytest.approx(kept) and summary["busy_s"] == pytest.approx(kept)
+    monkeypatch.setattr(R, "reduce_in_child", lambda search_dir, work: summary)
+    if whole:
+        assert R.reduced_or_fail("x", "y") is summary
+    else:
+        with pytest.raises(jobs.JobFailed, match="the capture is not whole"):
+            R.reduced_or_fail("x", "y")
+    monkeypatch.setattr(R, "reduce_in_child", lambda search_dir, work: None)
+    with pytest.raises(jobs.JobFailed, match="left no device trace"):
+        R.reduced_or_fail("x", "y")
 
 
 HLO_FUSION = ("%fusion.603 = (bf16[2,8192,14336]{2,1,0:T(8,128)(2,1)}, f32[2]{0:T(128)S(1)}) fusion("
@@ -506,24 +534,269 @@ def test_a_family_that_cannot_be_served_says_so_itself():
         families.load("mixtral").serve_install(sizes, {"config": "tiny-moe", "seed": 1, "engine": {"max_len": 64}})
 
 
+FLASH = ("%{name} = (bf16[64,8192,128]{{2,1,0}}, f32[64,8192,128]{{2,1,0}}) custom-call(bf16[64,8192,128]{{2,1,0}} %q), "
+         "custom_call_target=\"tpu_custom_call\"")
+OTHER_CALL = "%moe.2 = bf16[16384,14336]{1,0} custom-call(bf16[16384,4096]{1,0} %x), custom_call_target=\"tpu_custom_call\""
+
+
+def _train_ctx(op_time_s: dict, op_count: dict) -> dict:
+    run = types.SimpleNamespace(sizes=MISTRAL, w={"seq_len": 8192, "batch_size": 2}, chips=1, peaks=spec.load_json("peaks.json"))
+    return {"run": run, "device": {"platform": "tpu", "kind": "TPU v5 lite"}, "trace": {"op_time_s": op_time_s, "op_count": op_count}}
+
+
 def test_kernel_roofline_finds_its_count_by_the_kernel_argument():
     read = importlib.import_module("readers.kernel_roofline").read
-    flash = ("%shard_map.1 = (bf16[64,8192,128]{2,1,0}, f32[64,8192,128]{2,1,0}) custom-call(bf16[64,8192,128]{2,1,0} %q), "
-             "custom_call_target=\"tpu_custom_call\"")
-    other = "%moe.2 = bf16[16384,14336]{1,0} custom-call(bf16[16384,4096]{1,0} %x), custom_call_target=\"tpu_custom_call\""
-    run = types.SimpleNamespace(sizes=MISTRAL, w={"seq_len": 8192, "batch_size": 2}, chips=1,
-                                peaks=spec.load_json("peaks.json"))
-    ctx = {"run": run, "device": {"platform": "tpu", "kind": "TPU v5 lite"},
-           "trace": {"op_time_s": {flash: 3.0, other: 5.0}, "op_count": {flash: 20, other: 20}}}
-    peak = counts.peak_for("TPU v5 lite", run.peaks)
+    fwd_call, bwd_call = FLASH.format(name="flash_fwd.1"), FLASH.format(name="flash_bwd.11")
+    ctx = _train_ctx({fwd_call: 1.0, bwd_call: 2.0, OTHER_CALL: 5.0}, {fwd_call: 20, bwd_call: 20, OTHER_CALL: 20})
+    peak = counts.peak_for("TPU v5 lite", ctx["run"].peaks)
     fwd, bwd = (counts.roofline_seconds(*llama_counts.flash_call(MISTRAL, 2, 8192, b), peak) for b in (False, True))
-    assert read(ctx, kernel="flash", match="tpu_custom_call") == 100.0 * (2 * fwd + bwd) * 20 / 3.0
-    assert llama_counts.flash_layer_step(MISTRAL, 2, 8192) == [
-        llama_counts.flash_call(MISTRAL, 2, 8192, b) for b in (False, False, True)]
-    ctx["trace"] = {"op_time_s": {other: 5.0}, "op_count": {other: 20}}
+    # what the step runs since PR 52: the forward once and one backward, 20 layer-steps of them in 3 s
+    assert read(ctx, kernel="flash", match="tpu_custom_call") == pytest.approx(100.0 * (fwd + bwd) * 20 / 3.0)
+    assert llama_counts.flash_layer_step(MISTRAL, 2, 8192) == [llama_counts.flash_call(MISTRAL, 2, 8192, b) for b in (False, True)]
+    ctx["trace"] = {"op_time_s": {OTHER_CALL: 5.0}, "op_count": {OTHER_CALL: 20}}
     assert read(ctx, kernel="flash", match="tpu_custom_call") is None  # no call with flash's shape: nothing, never 0
     with pytest.raises(ValueError, match="llama_counts.py has no moe_gemm_operands"):
         read(ctx, kernel="moe_gemm", match="tpu_custom_call")
+
+
+@pytest.mark.parametrize("layers,steps", [(4, 5), (16, 3), (4, 1), (1, 7)])
+def test_a_rolled_and_an_unrolled_step_read_the_same_flash_share(layers, steps):
+    """One instruction a kernel run layers x steps times (the layers in a loop), and an instruction a layer run
+    steps times each (the layers unrolled): the same calls and the same device time, so the same share."""
+    read = importlib.import_module("readers.kernel_roofline").read
+    t_fwd, t_bwd = 0.0069, 0.0130  # device seconds a call
+    rolled_names = {"flash_fwd.1": t_fwd, "flash_bwd.11": t_bwd}
+    rolled = _train_ctx({FLASH.format(name=n): t * layers * steps for n, t in rolled_names.items()},
+                        {FLASH.format(name=n): layers * steps for n in rolled_names})
+    unrolled_names = {f"flash_{kind}.{i}": t for i in range(layers) for kind, t in (("fwd", t_fwd), ("bwd", t_bwd))}
+    unrolled = _train_ctx({FLASH.format(name=n): t * steps for n, t in unrolled_names.items()},
+                          {FLASH.format(name=n): steps for n in unrolled_names})
+    a, b = (read(ctx, kernel="flash", match="tpu_custom_call") for ctx in (rolled, unrolled))
+    peak = counts.peak_for("TPU v5 lite", rolled["run"].peaks)
+    least = sum(counts.roofline_seconds(*c, peak) for c in llama_counts.flash_layer_step(MISTRAL, 2, 8192))
+    assert a == pytest.approx(b, rel=1e-12) and a == pytest.approx(100.0 * least / (t_fwd + t_bwd))
+    assert 0 < a < 100
+
+
+# -- the routed FFN's count, once for the five routed families ---------------------------------------
+ROUTED_CELLS = ["k-exaone-236b.serve_reason", "dots3-note-prev.serve_notes", "mistral-small-4-119b.serve_docqa",
+                "granite-4.0-h-small.serve_assist", "solar-open2-250b.serve_extract"]
+
+
+def _cell(cell: str):
+    w = spec.workload(cell)
+    sizes = spec.model_sizes(spec.config(w["config"]), w["deployment"])
+    return w, sizes, families.counts(sizes)
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_the_routed_decode_count_is_one_and_follows_the_slabs_the_program_read(cell):
+    from families import exaone_moe_counts as E
+
+    w, s, C = _cell(cell)
+    assert C.moe_decode_call is E.moe_decode_call and C.moe_prefill_call is E.moe_prefill_call   # one count, five families
+    layers, held, slab = E.n_routed(s), s["held"][1], 3 * s["d_model"] * s["d_expert"]
+    assert layers == s["layers"] - s.get("dense_layers", 0) and slab == E.expert_params(s)
+    means = {"live_slots": 20.0, "held_rows_per_step": 100.0, "prefill_rows_per_chunk": 512.0}
+    # with the counter: the slabs the program counted, 60% of the held ones here, whatever even routing would say
+    ops, nbytes = C.moe_decode_call(s, w["engine"], {**means, "touched_per_step": 0.6 * layers * held})
+    assert ops == 2 * slab * 100 and nbytes == pytest.approx(2 * (0.6 * layers * held * slab + 2 * 100 * s["d_model"]))
+    assert C.moe_decode_call(s, w["engine"], {**means, "touched_per_step": 0.3 * layers * held})[1] < 0.51 * nbytes
+    # without it: the expectation under even routing
+    expected = layers * held * (1 - (1 - s["top_k"] / s["num_experts"]) ** 20)
+    assert C.moe_decode_call(s, w["engine"], means)[1] == pytest.approx(2 * (expected * slab + 2 * 100 * s["d_model"]))
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+@pytest.mark.parametrize("counter", [True, False])
+def test_every_routed_familys_window_means_carry_the_touched_slabs_when_the_program_counts_them(cell, counter):
+    w, s, C = _cell(cell)
+    moved = {"tony_serve_engine_chunks_total": 10, "tony_serve_decode_slots_total": 200, "tony_serve_expert_rows_total": 10 * 8 * 100,
+             "tony_serve_experts_touched_total": 10 * 8 * 55, "tony_serve_prefill_tokens_total": 5120, "tony_serve_prefill_chunks_total": 10,
+             "tony_serve_visible_tokens_total": 200 * 8 * 300, "tony_serve_context_tokens_total": 200 * 8 * 900,
+             "tony_serve_prefill_pairs_total": 10 * 1000, "tony_serve_index_positions_total": 80}
+    if not counter:
+        del moved["tony_serve_experts_touched_total"]
+    means = C.window_means(lambda name, where=None: moved.get(name), w["engine"])
+    assert means["live_slots"] == 20.0 and means["held_rows_per_step"] == 100.0 and means["prefill_rows_per_chunk"] == 512.0
+    assert means.get("touched_per_step") == (55.0 if counter else None)
+    assert C.window_means(lambda name, where=None: None, w["engine"]) is None
+
+
+@pytest.mark.parametrize("cell", ROUTED_CELLS)
+def test_the_fullest_experts_rows_over_the_mean_read_1_under_even_routing_whatever_is_held(cell):
+    """16, 32, 32, 36 and 40 held experts: every held expert gets r rows a layer and step, so the fullest
+    gets r and the ratio is 1. (A fixed scale of 16 read 36 held experts' 1.0 as 0.44.)"""
+    read = importlib.import_module("readers.registry_delta").read
+    w, s, _ = _cell(cell)
+    held, layer_steps, r = s["held"][1], 4 * 80, 7
+
+    def snap(n):
+        totals = {"tony_serve_expert_rows_max_total": n * layer_steps * r, "tony_serve_expert_rows_total": n * layer_steps * r * held}
+        return {"metrics": [{"name": k, "samples": [{"labels": {}, "value": v}]} for k, v in totals.items()]}
+
+    ctx = {"run": types.SimpleNamespace(sizes=s), "drive": {"snap0": snap(1), "snap1": snap(3)}}
+    args = spec.metric("expert_rows_max_over_mean.serve")["args"]
+    assert held in (16, 32, 36, 40) and read(ctx, **args) == pytest.approx(1.0)
+    # a straggler with three times the mean reads 3
+    ctx["drive"]["snap1"]["metrics"][0]["samples"][0]["value"] = layer_steps * r * (1 + 2 * 3)
+    assert read(ctx, **args) == pytest.approx(3.0)
+    # a family that holds no experts has nothing to read (a rehearsal scans every metric of its kind)
+    assert read({**ctx, "run": types.SimpleNamespace(sizes=MISTRAL)}, **args) is None
+
+
+# -- the seed orders a routed cell's work and does not size it ------------------------------------------
+@pytest.mark.parametrize("experts,held", [(128, 16), (8, 4), (4, 4)])
+@pytest.mark.parametrize("seed", [7, 6400000211, 2 ** 31 + 5])
+def test_every_seed_gives_every_chip_the_same_choosing_biases_in_another_order(experts, held, seed):
+    """The slabs a decode step of `serve_reason` reads follow the held experts' biases (12.2 to 14.9 a layer
+    by the seed while the 16 were drawn freely, and `serve_out_tok_s` with them: PERF.md section 6, PR 64)."""
+    from families import exaone_moe_reference as X
+
+    b = np.asarray(X.choosing_bias(X.seed_key(seed), 3, experts, held))
+    assert b.shape == (3, experts) and b.dtype == np.float32 and (b != 0).all() and np.abs(b).max() < 0.2
+    chips = np.sort(b.reshape(3, experts // held, held), axis=-1)
+    assert (chips == chips[0, 0]).all() and len(set(chips[0, 0].tolist())) == held      # one set of values, all distinct
+    assert chips[0, 0] == pytest.approx(-chips[0, 0][::-1], abs=1e-6)                    # and symmetric about nought
+    other = np.asarray(X.choosing_bias(X.seed_key(seed + 1), 3, experts, held))
+    assert (np.sort(other.reshape(chips.shape), axis=-1) == chips).all()
+    if held == 16:  # four values fall into one order once in 24 draws
+        assert (other != b).any() and (b[0] != b[1]).any() and (b[0, :held] != b[0, held:2 * held]).any()  # a seed's, a layer's, a chip's own order
+    with pytest.raises(ValueError, match="do not divide"):
+        X.choosing_bias(X.seed_key(seed), 3, experts + 1, held)
+
+
+def test_the_exaone_weights_take_their_bias_from_the_fixed_set_and_every_other_leaf_from_the_seed():
+    from families import exaone_moe_reference as X
+
+    s = spec.model_sizes(spec.config("tiny-exaone-moe"), "serve-1chip")
+    a, b = (X.init_weights(X.seed_key(seed), s) for seed in (11, 12))
+    E, held = s["num_experts"], s["held"][1]
+    for tree in (a["layers"], a["mtp"]["layers"]) if "mtp" in a else (a["layers"],):
+        rb = np.asarray(tree["router_bias"])
+        assert rb.shape[-1] == E and rb.dtype == np.float32
+        assert (np.sort(rb.reshape(-1, held), axis=-1) == np.sort(rb.reshape(-1, held), axis=-1)[0]).all()
+    assert (np.sort(np.asarray(a["layers"]["router_bias"]).reshape(-1, held)) == np.sort(np.asarray(b["layers"]["router_bias"]).reshape(-1, held))).all()
+    assert (np.asarray(a["layers"]["router"]) != np.asarray(b["layers"]["router"])).any()
+
+
+# -- the latent row: found at any laid-out width, counted by its numbers ------------------------------
+@pytest.mark.parametrize("cell,kernel,numbers,shape", [
+    ("mistral-small-4-119b.serve_docqa", "latent_rows_decode", 320, "bf16[5,577,1024,{w}]"),
+    ("mistral-small-4-119b.serve_docqa", "latent_prefill", 320, "bf16[35840,{w}]"),
+    ("dots3-note-prev.serve_notes", "latent_decode", 576, "bf16[1,24,2048,{w}]"),
+    ("dots3-note-prev.serve_notes", "latent_ring_decode", 1088, "bf16[3,24,640,{w}]"),
+    ("dots3-note-prev.serve_notes", "latent_prefill", 576, "bf16[67584,{w}]"),
+    ("dots3-note-prev.serve_notes", "latent_prefill", 1088, "bf16[2560,{w}]"),
+])
+def test_a_latent_call_is_found_with_its_filling_and_without_and_counted_by_its_numbers(cell, kernel, numbers, shape):
+    w, s, C = _cell(cell)
+    pat = re.compile(getattr(C, kernel + "_operands")(s, w["engine"]))
+    lanes = -(-numbers // 128) * 128
+    for width in (numbers, (numbers + lanes) // 2, lanes):      # no filling, some, whole lanes (today's pools)
+        assert pat.search(shape.format(w=width)), width
+    for width in (numbers - 1, lanes + 1, lanes + 128):
+        assert not pat.search(shape.format(w=width)), width
+    assert spec.metric(f"{'latent_paged_decode' if kernel == 'latent_rows_decode' else kernel}_roofline_pct.serve")["args"]["kernel"] == kernel
+
+
+def test_the_latent_decode_counts_bytes_are_the_numbers_at_either_width():
+    """The least a step could read: 2 bytes a number of a row, 320 (mistral4), 576 and 1088 (dots3_note), once
+    for all heads; the filling up to 384, 640 and 1152 lanes is the layout's cost and is in the device time only."""
+    w, s, C = _cell("mistral-small-4-119b.serve_docqa")
+    means = {"live_slots": 48.0, "context_per_slot": 33_000.0}
+    rows = 48 * 33_000 * 5
+    assert C.row(s) == 320 and C.latent_rows_decode_call(s, w["engine"], means) == (2.0 * 32 * rows * (320 + 256), 2.0 * 320 * rows)
+    assert C.latent_rows_decode_call(s, w["engine"], means)[1] * 384 == C.latent_paged_decode_call(s, w["engine"], means)[1] * 320
+    w, s, C = _cell("dots3-note-prev.serve_notes")
+    assert (C.row(s, C.FULL), C.row(s, C.SLIDING)) == (576, 1088)
+    means = {"live_slots": 24.0, "visible_per_slot": (2 * 2048 + 3 * 513) / 5, "context_per_slot": 40_000.0}
+    assert C.latent_decode_call(s, w["engine"], means)[1] == pytest.approx(2 * 576 * 24 * 2048 * 2)
+    assert C.latent_ring_decode_call(s, w["engine"], means)[1] == pytest.approx(2 * 1088 * 24 * 513 * 3)
+
+
+# -- a traced run keeps its registry ---------------------------------------------------------------
+def _fleet(tmp_path):
+    run = types.SimpleNamespace(out_dir=str(tmp_path), staging=str(tmp_path / "staging"), w={"engine": {}})
+    os.makedirs(tmp_path / "ctl")
+    fleet = serve_cell.Fleet(run)
+    fleet.out_path = str(tmp_path / "serve.out")
+    open(fleet.out_path, "w").close()
+    return fleet
+
+
+@pytest.mark.parametrize("late_s", [0.0, 0.3])
+def test_a_snapshot_that_comes_late_is_returned(tmp_path, late_s):
+    """A fake control directory and no replica: the answer is written `late_s` after it was asked for, as a
+    capture's export delays it, and is returned with the registry's own stamp."""
+    import threading
+
+    fleet = _fleet(tmp_path)
+    answer = {"t": 123.5, "metrics": [{"name": "tony_serve_engine_chunks_total", "samples": [{"labels": {}, "value": 9.0}]}]}
+
+    def replica():
+        req = tmp_path / "ctl" / "snap.close.req"
+        while not req.exists():
+            time.sleep(0.005)
+        time.sleep(late_s)
+        os.remove(req)
+        serve_cell.write_json(str(tmp_path / "ctl" / "snap.close.json"), answer)
+
+    threading.Thread(target=replica, daemon=True).start()
+    t0 = time.time()
+    assert fleet.snapshot("close", wait_s=5.0) == answer and time.time() - t0 >= late_s
+
+
+@pytest.mark.parametrize("asked_ago_s", [0.0, 10.0])
+def test_a_snapshot_that_never_comes_fails_the_run(tmp_path, asked_ago_s):
+    """No replica answers: the run fails (JobFailed: run.py exits 1 and prints no result line), where it returned
+    None until PR 64 and thirteen metrics read nothing. The wait counts from the asking, not from the collecting."""
+    import jobs
+
+    fleet = _fleet(tmp_path)
+    t0 = time.time()
+    with pytest.raises(jobs.JobFailed, match="no registry snapshot 'close' within"):
+        if asked_ago_s:
+            fleet.ask_snapshot("close")
+            fleet.snapshot("close", asked=time.time() - asked_ago_s, wait_s=asked_ago_s + 0.2)
+        else:
+            fleet.snapshot("close", wait_s=0.2)
+    assert time.time() - t0 < 2.0 and os.path.exists(tmp_path / "ctl" / "snap.close.req")
+    assert serve_cell.SNAPSHOT_WAIT_S >= 60  # as long as a capture's export is given to end (serve_cell.run)
+
+
+def test_the_replica_answers_snapshots_on_a_thread_that_waits_for_no_capture(tmp_path):
+    """entry/serve_replica.py: `snapshots` is a loop of its own, apart from the one that traces, and stamps the
+    registry with the time it was read."""
+    import threading
+
+    sys.path.insert(0, os.path.join(BENCH, "entry"))
+    try:
+        replica = importlib.import_module("serve_replica")
+    finally:
+        sys.path.remove(os.path.join(BENCH, "entry"))
+    ctl = tmp_path / "ctl"
+    os.makedirs(ctl)
+    threading.Thread(target=replica.snapshots, args=(str(ctl),), daemon=True).start()  # ends when the directory goes
+    fleet = serve_cell.Fleet(types.SimpleNamespace(out_dir=str(tmp_path), w={"engine": {}}))
+    t0 = time.time()
+    for tag in ("open", "close"):
+        got = fleet.snapshot(tag, wait_s=10.0)
+        assert t0 <= got["t"] <= time.time() and isinstance(got["metrics"], list)
+        assert not os.path.exists(ctl / f"snap.{tag}.req")
+    assert "trace.req" not in replica.snapshots.__code__.co_consts  # the capture is the other loop's
+
+
+def test_the_routers_added_time_ends_where_the_closing_snapshot_was_read():
+    read = importlib.import_module("readers.router_added").read
+    hist = lambda s, n: {"metrics": [{"name": "h", "samples": [{"labels": {}, "sum": s, "count": n}]}]}
+    rec = lambda sent, first: types.SimpleNamespace(sent_t=sent, arrivals=[(first, 1)])
+    records = [rec(1.0, 1.5), rec(2.0, 2.5), rec(39.8, 40.6)]            # the third's first token comes after the close
+    d = {"snap0": hist(0.0, 0), "snap1": {**hist(0.8, 2), "t": 40.01}, "records": records, "t_close": 40.0}
+    assert read({"drive": d}, histogram="h") == pytest.approx(1000 * (0.5 - 0.4))
+    # a closing snapshot read late counted the third request's first token too, and so do the clients
+    d["snap1"] = {**hist(1.6, 3), "t": 41.0}
+    assert read({"drive": d}, histogram="h") == pytest.approx(1000 * ((0.5 + 0.5 + 0.8) / 3 - 1.6 / 3))
 
 
 def _copy_of_the_benchmark(tmp_path) -> str:

@@ -191,10 +191,8 @@ def run(run) -> dict:
     dev_line["memory_peak_bytes"] = device.get("memory_peak_bytes", 0)
     breakdown = None
     if run.trace:
-        ctx["trace"] = trace_reduce.reduce_in_child(os.path.join(run.staging), run.work)
-        tr = ctx["trace"]
-        if tr is None or not (tr.get("busy_s") or run.cpu_rehearsal):
-            raise jobs.JobFailed("the traced run left no device trace to reduce")
+        tr = ctx["trace"] = trace_reduce.reduced_or_fail(run.staging, run.work, run.cpu_rehearsal)
+        say(f"[trace] leaf operations cover {tr.get('module_cover')} of the captured programs' time")
         dev_line.update(busy_s=tr["busy_s"], window_s=tr["window_s"])
         breakdown = tr.get("breakdown")
     return {"correct": ok, "attempted": len(window), "failed": 0 if ok else 1,
